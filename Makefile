@@ -43,8 +43,11 @@ test:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow'
 
 # Paged-KV decode rows (concurrency per pool byte, mixed-prompt TTFT
-# p99 chunked vs monolithic) -> BENCH_SERVE.json. Drop BENCH_ARGS to
-# run on the attached accelerator; CI boxes use the CPU backend.
+# p99 chunked vs monolithic) -> BENCH_SERVE.json.
+# BENCH_ARGS defaults to --cpu: every bench-* target below runs on the
+# CPU backend unless it is overridden, so their rows are counts and
+# mechanisms, never chip times (PERF.md). `make bench-x BENCH_ARGS=` runs
+# on the attached accelerator; the chip check is `python chip_smoke.py`.
 BENCH_ARGS ?= --cpu
 bench-paged:
 	python bench_decode.py --sections paged $(BENCH_ARGS)
@@ -59,7 +62,8 @@ bench-sharded:
 # Speculative-decoding rows (ISSUE 16): accept-rate x tokens/s per
 # prompt mix at the self-draft / tiny-draft brackets, the sampled
 # (device-sampler) fallback, and the host-vs-device sampler step
-# delta -> BENCH_SERVE.json. CPU-host caveats: BENCH_NOTES.md.
+# delta -> BENCH_SERVE.json. CPU-host caveats: BENCH_NOTES.md,
+# "Speculative decoding (PR 16)".
 bench-spec:
 	python bench_decode.py --sections spec $(BENCH_ARGS)
 
@@ -67,7 +71,8 @@ bench-spec:
 # inter-token p99 vs the colocated fleet, handoff descriptor bytes +
 # publish->adopt latency, and pages_leaked=0 under prefill-replica
 # SIGKILL churn -> BENCH_SERVE.json, merge-preserving. CPU-host rows
-# measure the splice mechanism, not speedup (BENCH_NOTES.md).
+# measure the splice mechanism, not speedup (BENCH_NOTES.md,
+# "Disaggregated prefill/decode rows (PR 17)").
 bench-disagg:
 	python bench_serve.py --sections disagg $(BENCH_ARGS)
 

@@ -191,7 +191,9 @@ def pipeline_overhead_rows(quick: bool, platform: str = ""):
                                     (8, 65)).astype(np.int32)}, 8)
 
     rows = []
-    ray_tpu.init(num_cpus=8)
+    # TPU: 0 skips init()'s chip probe: this driver already holds the chip
+    # (the decode row ran in-process) and the stages are CPU workers.
+    ray_tpu.init(num_cpus=8, resources={"TPU": 0})
     try:
         plane = PipelinePlane(cfg, params, n_stages=2, n_microbatches=8,
                               lr=1e-3, window=2, name="obs-pipe",

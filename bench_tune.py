@@ -18,12 +18,12 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import shutil
 import time
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/ray_tpu_bench_jax_cache")
+from ray_tpu.util import compile_cache
+
+compile_cache.configure()
 
 
 def vit_train_loop(config):
@@ -61,8 +61,10 @@ def vit_train_loop(config):
             ckpt, (params, opt_state))
         start_iter = int(meta.get("step", 0))
 
-    peak = peak_flops_per_chip(
-        getattr(jax.devices()[0], "device_kind", ""))
+    # MFU is a chip metric: off the TPU (--quick) it is not computed.
+    dev = jax.devices()[0]
+    peak = (peak_flops_per_chip(dev.device_kind)
+            if dev.platform == "tpu" else None)
     fpi = vit.flops_per_image(cfg)
 
     def body(carry, batch_d):
@@ -93,7 +95,8 @@ def vit_train_loop(config):
         dt = (_time.perf_counter() - t0) / steps
         metrics = {
             "loss": round(loss, 4),
-            "mfu": round(100.0 * batch * fpi / dt / peak, 2),
+            "mfu": (round(100.0 * batch * fpi / dt / peak, 2)
+                    if peak else None),
             "step_time_s": round(dt, 4),
             "lr": lr,
             "iter": it + 1,
@@ -176,9 +179,9 @@ def main() -> None:
         solo = trainer.fit()
         t_solo = time.perf_counter() - t0
         assert solo.error is None, solo.error
-        solo_mfu = max(m["metrics"]["mfu"] for m in solo.metrics_history
-                       if not m["metrics"]["compiled_this_iter"]) \
-            if len(solo.metrics_history) > 1 else None
+        solo_mfu = max((m["metrics"]["mfu"] for m in solo.metrics_history
+                        if not m["metrics"]["compiled_this_iter"]
+                        and m["metrics"]["mfu"] is not None), default=None)
 
         # ---- the PBT sweep
         scheduler = LoggingPBT(
@@ -204,7 +207,8 @@ def main() -> None:
             hist = [m for m in r.metrics_history]
             best_loss = min((m["loss"] for m in hist), default=None)
             mfus = [m["mfu"] for m in hist
-                    if not m.get("compiled_this_iter")]
+                    if not m.get("compiled_this_iter")
+                    and m["mfu"] is not None]
             trials.append({
                 "trial_id": r.trial_id,
                 "final_config": r.config,

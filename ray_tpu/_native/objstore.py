@@ -9,13 +9,20 @@ here the pin is an explicit refcount dropped by ``ShmView.release`` or GC).
 from __future__ import annotations
 
 import ctypes
+import logging
 import mmap
 import os
+import resource
 from typing import Optional
 
 from ray_tpu._native.build import build_library
 
+logger = logging.getLogger(__name__)
+
 _lib = None
+# Below this a store cannot hold one object past the inline threshold plus
+# its free-list bookkeeping: refuse with the reason instead of limping.
+_MIN_CAPACITY = 1 << 20
 
 
 def _load():
@@ -38,6 +45,8 @@ def _load():
     lib.shm_store_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
                                      ctypes.c_uint64]
     lib.shm_store_create.restype = ctypes.c_int
+    lib.shm_store_file_size.argtypes = [ctypes.c_uint64, ctypes.c_uint64]
+    lib.shm_store_file_size.restype = ctypes.c_uint64
     lib.shm_store_open.argtypes = [ctypes.c_char_p]
     lib.shm_store_open.restype = ctypes.c_void_p
     lib.shm_store_close.argtypes = [ctypes.c_void_p]
@@ -68,6 +77,36 @@ def _load():
     lib.shm_num_objects.restype = ctypes.c_uint64
     _lib = lib
     return lib
+
+
+def _fit_capacity(lib, directory: str, capacity: int, n_slots: int) -> int:
+    """``capacity``, or as much of it as this process may create here.
+
+    The store is one file: ftruncate past the file-size limit
+    (RLIMIT_FSIZE, ``ulimit -f``) is EFBIG, and a page written past the
+    free space of the backing filesystem (a container's 64 MiB /dev/shm) is
+    a SIGBUS in whichever process touches it. Both are visible up front, so
+    the store is cut to the smaller of them, with a warning that names it;
+    objects that no longer fit take the spill path like any store-full put.
+    """
+    overhead = lib.shm_store_file_size(0, n_slots)
+    st = os.statvfs(directory)
+    room, reason = st.f_bavail * st.f_frsize, f"free space in {directory}"
+    soft, _hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+    if soft != resource.RLIM_INFINITY and soft < room:
+        room, reason = soft, "the file-size limit (RLIMIT_FSIZE, ulimit -f)"
+    if overhead + capacity <= room:
+        return capacity
+    fit = max(0, room - overhead) & ~4095  # whole pages: no align_up growth
+    if fit < _MIN_CAPACITY:
+        raise OSError(
+            f"cannot create an object store in {directory}: {reason} allows "
+            f"a {room}-byte file, and the store needs {overhead} bytes of "
+            f"index plus at least {_MIN_CAPACITY} of data")
+    logger.warning(
+        "object store cut from %d to %d MiB: %s allows a %d-byte file",
+        capacity >> 20, fit >> 20, reason, room)
+    return fit
 
 
 class ShmView:
@@ -141,9 +180,12 @@ class ShmStore:
     def create(path: str, capacity: int, n_slots: int = 0) -> "ShmStore":
         lib = _load()
         os.makedirs(os.path.dirname(path), exist_ok=True)
+        capacity = _fit_capacity(lib, os.path.dirname(path), capacity,
+                                 n_slots)
         rc = lib.shm_store_create(path.encode(), capacity, n_slots)
         if rc != 0:
-            raise OSError(f"shm_store_create({path}) failed: {rc}")
+            raise OSError(-rc, f"shm_store_create({capacity} bytes) failed: "
+                               f"{os.strerror(-rc)}", path)
         return ShmStore(path)
 
     # ------------------------------------------------------------ writer

@@ -277,19 +277,28 @@ int evict_for(Handle* h, uint64_t needed) {
   return evicted;
 }
 
+uint64_t round_slots(uint64_t n_slots) {
+  if (n_slots == 0) n_slots = 1 << 16;
+  uint64_t p2 = 1;
+  while (p2 < n_slots) p2 <<= 1;
+  return p2;
+}
+
 }  // namespace
 
 extern "C" {
 
-// Create (or recreate) a store file of `capacity` data bytes. Returns 0 on
-// success.
-int shm_store_create(const char* path, uint64_t capacity, uint64_t n_slots) {
-  if (n_slots == 0) n_slots = 1 << 16;
-  // round n_slots to power of two
-  uint64_t p2 = 1;
-  while (p2 < n_slots) p2 <<= 1;
-  n_slots = p2;
+// Size of the store file that holds `capacity` data bytes: what the creator
+// must be allowed to ftruncate (RLIMIT_FSIZE) and the filesystem to back.
+uint64_t shm_store_file_size(uint64_t capacity, uint64_t n_slots) {
+  return align_up(sizeof(Header) + round_slots(n_slots) * sizeof(Slot)) +
+         align_up(capacity);
+}
 
+// Create (or recreate) a store file of `capacity` data bytes. Returns 0 on
+// success, -errno otherwise.
+int shm_store_create(const char* path, uint64_t capacity, uint64_t n_slots) {
+  n_slots = round_slots(n_slots);
   uint64_t data_off = align_up(sizeof(Header) + n_slots * sizeof(Slot));
   uint64_t total = data_off + align_up(capacity);
   int fd = open(path, O_RDWR | O_CREAT | O_TRUNC, 0600);

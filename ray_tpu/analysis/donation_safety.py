@@ -1,15 +1,16 @@
 """Donation-aliasing family (#13): donated jit programs, statically.
 
-Two real wrong-numbers bugs drive these rules. PR 14: a donated
-executable reloaded from the persistent XLA disk cache segfaults or
-returns wrong numbers (jaxlib 0.4.37), so the decode engine routes
-every donated program's FIRST dispatch through ``_dispatch_fresh``,
-which detaches the disk cache for that compile. PR 16: ``np.asarray``
-over a jax dispatch result (or donated device state) returns a host
-VIEW of the device buffer — the next donated dispatch clobbers it in
-place, silently corrupting tokens already handed to clients; the
-convention is ``np.array`` (an owning copy). Both were convention-only
-across 60+ sites; these rules pin them:
+Two conventions of the decode engine drive these rules. Every donated
+program is dispatched through ``_dispatch_fresh``, which records the
+FIRST dispatch of each program key as a compile in the step log — the
+"no recompiles at steady state" serving property is only checkable
+(``chip_smoke.py`` asserts it on the chip) if no donated program can be
+dispatched around that accounting. PR 16: ``np.asarray`` over a jax
+dispatch result (or donated device state) returns a host VIEW of the
+device buffer — the next donated dispatch clobbers it in place,
+silently corrupting tokens already handed to clients; the convention is
+``np.array`` (an owning copy). Both were convention-only across 60+
+sites; these rules pin them:
 
 **donation-unguarded-dispatch** — a program constructed with
 ``jit(..., donate_argnums=...)`` (recognized through wrapper calls
@@ -169,11 +170,10 @@ def _check_unguarded(index: _Index, findings: List[Finding]) -> None:
                 path=info.file.relpath, line=node.lineno,
                 symbol=info.qualname,
                 message=(f"donated program {prog} dispatched outside "
-                         f"the fresh-compile guard "
+                         f"the compile-accounting wrapper "
                          f"({'/'.join(rules.DONATED_DISPATCH_GUARDS)}):"
-                         f" its first dispatch may reload the donated "
-                         f"executable from the persistent XLA cache "
-                         f"(jaxlib 0.4.37: segfault or wrong numbers)"
+                         f" its first dispatch would compile without "
+                         f"a jit-compile event in the step log"
                          f" — wrap it as self._dispatch_fresh(key, "
                          f"lambda: ...)")))
 

@@ -553,12 +553,11 @@ FENCED_PAYLOAD_RULES = {
 
 # --------------------------------- v4: donated-buffer aliasing (#13)
 
-# Guard wrappers a donated program's dispatch must flow through:
-# ``self._dispatch_fresh(key, lambda: self._prog(...))`` detaches the
-# persistent XLA cache on the FIRST dispatch (jaxlib 0.4.37, PR 14: a
-# donated executable reloaded from the disk cache segfaults or
-# returns wrong numbers). Dispatch inside the guard's own body is the
-# guard working, not a violation.
+# Wrappers a donated program's dispatch must flow through:
+# ``self._dispatch_fresh(key, lambda: self._prog(...))`` records the
+# FIRST dispatch of each program key as a compile (the engine's
+# jit-compile step-log event). Dispatch inside the wrapper's own body
+# is the wrapper working, not a violation.
 DONATED_DISPATCH_GUARDS = ("_dispatch_fresh",)
 # Keyword spellings that mark a jit construction as donating.
 DONATION_JIT_KWARGS = ("donate_argnums", "donate")

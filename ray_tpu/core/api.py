@@ -62,6 +62,14 @@ def init(
             return get_core_worker()
         raise RayTpuError("ray_tpu.init() called twice; "
                           "pass ignore_reinit_error=True to allow")
+    if address is None:
+        # Before any state is touched: a failed chip probe raises out of
+        # init() and must leave nothing to undo.
+        node_resources = dict(resources or {})
+        if num_cpus is not None:
+            node_resources["CPU"] = float(num_cpus)
+        node_resources.setdefault("CPU", float(os.cpu_count() or 1))
+        _autodetect_tpu(node_resources, labels := dict(labels or {}))
     global _config_snapshot
     _config_snapshot = config.snapshot()
     if _system_config:
@@ -71,11 +79,6 @@ def init(
         from ray_tpu.core.controller import Controller
         from ray_tpu.core.node import Node
 
-        node_resources = dict(resources or {})
-        if num_cpus is not None:
-            node_resources["CPU"] = float(num_cpus)
-        node_resources.setdefault("CPU", float(os.cpu_count() or 1))
-        _autodetect_tpu(node_resources, labels := dict(labels or {}))
         controller = Controller()
         node = Node(controller.address, node_resources, labels)
         _local_cluster = (controller, node)
@@ -113,16 +116,16 @@ def _autodetect_tpu(resources: Dict[str, float], labels: Dict[str, str]) -> None
     TPUAcceleratorManager; here detection is JAX-native)."""
     if "TPU" in resources:
         return
-    try:
-        from ray_tpu.tpu import detect_chip_count
+    from ray_tpu.tpu import detect_chip_count
 
-        chips, pod_type = detect_chip_count()
-        if chips:
-            resources["TPU"] = float(chips)
-            if pod_type:
-                labels.setdefault("tpu_pod_type", pod_type)
-    except Exception:  # graftlint: disable=swallowed-exception (TPU autodetect probe: absence of TPU metadata is the common case)
-        pass
+    # No except here: on a machine with accelerator device files a failed
+    # probe raises TpuProbeError out of init() with the probe's stderr —
+    # a cluster that silently comes up CPU-only never places a TPU lease.
+    chips, pod_type = detect_chip_count()
+    if chips:
+        resources["TPU"] = float(chips)
+        if pod_type:
+            labels.setdefault("tpu_pod_type", pod_type)
 
 
 def shutdown() -> None:

@@ -41,13 +41,12 @@ This module builds that substrate ONCE:
   ``num_processes`` otherwise hangs ``jax.distributed.initialize``
   itself), then joins the coordinator.
 
-The CPU box cannot run multiprocess collectives (jaxlib 0.4.37), so
-the testable contract is everything AROUND the collective: gang
-spawn/teardown, death reconciliation, coordinator failover, epoch
+The contract this module owns is everything AROUND the collective:
+gang spawn/teardown, death reconciliation, coordinator failover, epoch
 fencing, hash-mismatch refusal, and single-process virtual-mesh parity
 (a 1-host group is bit-identical to calling the engine directly).
-``tests/test_multihost.py`` keeps the real-collective path for real
-rigs.
+``tests/test_multihost.py`` runs the real cross-process collective
+path (two worker processes on the CPU backend).
 
 Fault-injection sites: ``multihost.barrier.<group>.<member>`` (member-
 side barrier entry) and ``multihost.member.<group>.<member>.beat``
@@ -1411,9 +1410,8 @@ class HostGroup:
                              timeout=timeout)
 
     def form_mesh(self, *, timeout: float = 120.0) -> List[int]:
-        """Join every member into one global jax runtime (real rigs;
-        the CPU backend cannot run the resulting collectives — jaxlib
-        0.4.37). Uses each member's own aligned context."""
+        """Join every member into one global jax runtime. Uses each
+        member's own aligned context."""
         return self.call_all("join_jax", timeout=timeout)
 
     def status(self) -> Dict[str, Any]:

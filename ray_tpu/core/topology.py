@@ -167,7 +167,17 @@ class SliceGrid:
         out = [shape]
         if shape[::-1] != shape:
             out.append(shape[::-1])
-        return [s for s in out if self._fits(s)]
+        fits = [s for s in out if self._fits(s)]
+        if not fits:
+            # A logical mesh shape need not be the physical rectangle:
+            # a (1, 4) serving mesh lays onto a 2x2 host (mesh_utils
+            # orders its devices along the ICI ring). When the grid can
+            # never hold the rectangle as given, fold it to the
+            # most-square block of the same chip count.
+            sq = most_square(shape[0] * shape[1])
+            fits = [s for s in dict.fromkeys((sq, sq[::-1]))
+                    if self._fits(s)]
+        return fits
 
     def reserve(self, shape: Tuple[int, int],
                 owner: str = "") -> Optional[SubSlice]:

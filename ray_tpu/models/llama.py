@@ -31,6 +31,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.norms import rms_norm
@@ -262,9 +263,7 @@ def _decoder_layer(config: LlamaConfig, x, layer, cos, sin, q_offset):
                              "context with a seq-sharded mesh")
         attn = ring_attention(q, k, v, mesh)
     elif c.attention_impl == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention
-
-        attn = flash_attention(q, k, v, causal=True, q_offset=q_offset)
+        attn = _flash_attention(q, k, v, q_offset)
     else:
         attn = attention(q, k, v, causal=True, q_offset=q_offset,
                          impl=c.attention_impl)
@@ -295,6 +294,37 @@ def _decoder_layer(config: LlamaConfig, x, layer, cos, sin, q_offset):
     down = jnp.einsum("bsm,me->bse", ffn, layer["w_down"].astype(h2.dtype))
     return x + constrain(down, ("batch", "length", "act_embed")), jnp.zeros(
         (), jnp.float32)
+
+
+def _flash_attention(q, k, v, q_offset):
+    """Pallas flash attention. Under a multi-device ``axis_rules`` context
+    the kernel runs inside ``shard_map`` on each device's (batch, heads)
+    shard: GSPMD cannot partition a Mosaic kernel (lowering raises
+    "Mosaic kernels cannot be automatically partitioned")."""
+    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.parallel.sharding import (mesh_extent, current_mesh,
+                                           resolved_spec)
+
+    def fn(q, k, v):
+        return flash_attention(q, k, v, causal=True, q_offset=q_offset)
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(q, k, v)
+    q_spec = list(resolved_spec(q, ("batch", "length", "heads"))) + [None] * 3
+    kv_spec = list(resolved_spec(k, ("batch", "length", "kv_heads"))) \
+        + [None] * 3
+    if q_spec[1] is not None and mesh_extent(mesh, q_spec[1]) > 1:
+        raise ValueError(
+            "attention_impl='flash' needs each device to hold whole "
+            "sequences; on a seq-sharded mesh use attention_impl='ring'")
+    if q_spec[2] != kv_spec[2]:
+        # GQA maps query head h to kv head h // group: only a split that
+        # cuts both head axes the same way keeps that mapping local.
+        q_spec[2] = kv_spec[2] = None
+    q_spec, kv_spec = P(*q_spec[:3]), P(*kv_spec[:3])
+    return jax.shard_map(fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(q, k, v)
 
 
 def _embed_matmul(table: jax.Array, tokens: jax.Array,

@@ -292,9 +292,9 @@ def decode_chunk(params: Dict[str, Any], cache: Cache, tokens: jax.Array,
     step's argmax feeds the next. Returns (tokens (k, B), cache).
 
     This is the dispatch-amortization lever for serving: one device call
-    per K tokens instead of per token — on dispatch-floor-bound rigs
-    (tunneled chips, small models) it multiplies decode throughput by
-    ~K. The continuous batcher uses it between admission points (greedy
+    per K tokens instead of per token — where the per-call dispatch
+    floor dominates (small models) it amortizes that floor over K
+    tokens. The continuous batcher uses it between admission points (greedy
     requests only; sampling stays per-step)."""
     def body(carry, _):
         cache, tok = carry

@@ -36,32 +36,25 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on pure-CPU builds)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _LANE = 128  # TPU lane width: minor dim of every block must divide into it
+_SUBLANE = 8  # second-minor tile of a 32-bit block
 
 
 def _interpret() -> bool:
+    """Off-TPU (the CPU tests) the kernels run in the Pallas interpreter;
+    on the chip they lower through Mosaic (``chip_smoke.py`` checks the
+    train step's HLO for the ``tpu_custom_call``)."""
     return jax.default_backend() != "tpu"
 
 
 def _block_spec(block_shape, index_map):
-    if _VMEM is None:
-        return pl.BlockSpec(block_shape, index_map)
-    return pl.BlockSpec(block_shape, index_map, memory_space=_VMEM)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _scratch(shape, dtype):
-    if pltpu is None:
-        return pl.MemoryRef(shape, dtype) if hasattr(pl, "MemoryRef") else None
     return pltpu.VMEM(shape, dtype)
 
 
@@ -97,10 +90,22 @@ def _mask_scores(s, i, j, block_q, block_k, q_offset, causal, window,
     if window is not None:
         s = jnp.where(rows - cols < window, s, _NEG_INF)
     if seg_q is not None:
-        # seg ids ride as fp32 rows (exact for ids < 2^24); equality only.
-        s = jnp.where(seg_q.reshape(block_q, 1) == seg_k.reshape(1, block_k),
-                      s, _NEG_INF)
+        # seg ids ride as fp32 (exact for ids < 2^24), equality only:
+        # seg_q (block_q, LANE) read at lane 0, seg_k (SUBLANE, block_k)
+        # read at sublane 0 — the tileable layouts _seg_layout builds.
+        s = jnp.where(seg_q[:, 0:1] == seg_k[0:1, :], s, _NEG_INF)
     return s
+
+
+def _seg_layout(seg_q, seg_k):
+    """(B, S) segment ids -> layouts Mosaic can tile: query ids broadcast
+    along the lane axis (B, Sq, LANE), key ids along the sublane axis
+    (B, SUBLANE, Sk). A (1, block) block over a (B, S) array is refused
+    (second-minor block dim 1 is neither a multiple of 8 nor B)."""
+    b, sq = seg_q.shape
+    sk = seg_k.shape[1]
+    return (jnp.broadcast_to(seg_q[:, :, None], (b, sq, _LANE)),
+            jnp.broadcast_to(seg_k[:, None, :], (b, _SUBLANE, sk)))
 
 
 # ---------------------------------------------------------------- forward
@@ -188,10 +193,11 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, window, q_offset,
     args = [q, k, v]
     if segmented:
         in_specs += [
-            _block_spec((1, block_q), lambda b_, h_, i, j: (b_, i)),
-            _block_spec((1, block_k), lambda b_, h_, i, j: (b_, j)),
+            _block_spec((1, block_q, _LANE), lambda b_, h_, i, j: (b_, i, 0)),
+            _block_spec((1, _SUBLANE, block_k),
+                        lambda b_, h_, i, j: (b_, 0, j)),
         ]
-        args += [seg_q, seg_k]
+        args += list(_seg_layout(seg_q, seg_k))
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -349,7 +355,7 @@ def _bwd(q, k, v, seg_q, seg_k, out, lse, do, dlse, scale, causal, window,
         extra.append(jnp.broadcast_to(
             dlse.astype(jnp.float32)[..., None], (b, h, sq, _LANE)))
     if segmented:
-        extra += [seg_q, seg_k]
+        extra += list(_seg_layout(seg_q, seg_k))
 
     def lane_spec(index_map):
         return _block_spec((1, 1, block_q, _LANE), index_map)
@@ -376,8 +382,9 @@ def _bwd(q, k, v, seg_q, seg_k, out, lse, do, dlse, scale, causal, window,
         in_specs.append(lane_spec(qmap))
     if segmented:
         in_specs += [
-            _block_spec((1, block_q), lambda b_, h_, j, i: (b_, i)),
-            _block_spec((1, block_k), lambda b_, h_, j, i: (b_, j)),
+            _block_spec((1, block_q, _LANE), lambda b_, h_, j, i: (b_, i, 0)),
+            _block_spec((1, _SUBLANE, block_k),
+                        lambda b_, h_, j, i: (b_, 0, j)),
         ]
     dk, dv = pl.pallas_call(
         dkv_kernel,
@@ -424,8 +431,9 @@ def _bwd(q, k, v, seg_q, seg_k, out, lse, do, dlse, scale, causal, window,
         in_specs.append(lane_spec(qmap2))
     if segmented:
         in_specs += [
-            _block_spec((1, block_q), lambda b_, h_, i, j: (b_, i)),
-            _block_spec((1, block_k), lambda b_, h_, i, j: (b_, j)),
+            _block_spec((1, block_q, _LANE), lambda b_, h_, i, j: (b_, i, 0)),
+            _block_spec((1, _SUBLANE, block_k),
+                        lambda b_, h_, i, j: (b_, 0, j)),
         ]
     dq = pl.pallas_call(
         dq_kernel,

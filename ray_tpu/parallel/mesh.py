@@ -92,8 +92,7 @@ class MeshSpec:
         if self.dcn_data > 1:
             ici = self._ici_sizes(devices.size // self.dcn_data)
             dcn = (self.dcn_data, 1, 1, 1, 1)
-            on_tpu = any(getattr(d, "platform", "") == "tpu"
-                         for d in devices.flat)
+            on_tpu = _on_tpu(devices)
             try:
                 from jax.experimental import mesh_utils
 
@@ -111,14 +110,29 @@ class MeshSpec:
                     sizes)
             dev_array = dev_array.reshape(sizes)
             return Mesh(dev_array, AXES)
-        try:
-            from jax.experimental import mesh_utils
+        return Mesh(_ici_device_array(sizes, devices), AXES)
 
-            dev_array = mesh_utils.create_device_mesh(
-                sizes, devices=list(devices.flat))
-        except Exception:
-            dev_array = devices.reshape(sizes)
-        return Mesh(dev_array, AXES)
+
+def _on_tpu(devices: np.ndarray) -> bool:
+    return any(getattr(d, "platform", "") == "tpu" for d in devices.flat)
+
+
+def _ici_device_array(shape: Tuple[int, ...], devices: np.ndarray
+                      ) -> np.ndarray:
+    """Devices laid out so mesh neighbors are ICI neighbors. On TPU a
+    ``create_device_mesh`` failure means the shape does not fit the
+    slice topology and is re-raised — a silent reshape would build a
+    mesh that ignores ICI order. Virtual/CPU devices carry no topology:
+    plain reshape."""
+    from jax.experimental import mesh_utils
+
+    try:
+        return mesh_utils.create_device_mesh(
+            shape, devices=list(devices.flat))
+    except Exception:
+        if _on_tpu(devices):
+            raise
+        return devices.reshape(shape)
 
 
 def single_device_mesh() -> Mesh:
@@ -150,14 +164,7 @@ def decode_mesh(shape: Tuple[int, int],
         raise ValueError(
             f"decode mesh {b}x{m} needs {b * m} devices, have "
             f"{devices.size}")
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(
-            (b, m), devices=list(devices.flat))
-    except Exception:
-        dev_array = devices.reshape((b, m))
-    return Mesh(dev_array, DECODE_AXES)
+    return Mesh(_ici_device_array((b, m), devices), DECODE_AXES)
 
 
 # Topology presets keyed by (pod type prefix, device count) intent. These are

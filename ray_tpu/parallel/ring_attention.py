@@ -27,28 +27,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.8 moved shard_map to the top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from ray_tpu.ops.attention import (
     attention_block_stats,
     finalize_attention,
     merge_attention_stats,
 )
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static ring size inside shard_map. ``jax.lax.axis_size`` only
-    exists on newer jax; on older versions ``psum(1, axis)`` of a Python
-    literal constant-folds to a static int under shard_map, which is what
-    the ring's ``range(n)``/permutation construction needs."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def ring_attention_local(q, k, v, axis_name: str = "seq",
@@ -58,7 +44,7 @@ def ring_attention_local(q, k, v, axis_name: str = "seq",
     Shapes are per-device: q/k/v (B, S_local, H, D) with the global sequence
     laid out contiguously across the ``axis_name`` ring.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     s_local = q.shape[1]
     q_offset = rank * s_local
@@ -124,7 +110,7 @@ def ring_flash_attention_local(q, k, v, axis_name: str = "seq",
     """
     from ray_tpu.ops.flash_attention import flash_attention_stats
 
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     scale = d ** -0.5
@@ -206,13 +192,8 @@ def ring_attention(q, k, v, mesh: Mesh, causal: bool = True,
     kwargs = {}
     if impl == "flash":
         # pallas_call inside shard_map can't declare varying-mesh-axes
-        # metadata; skip the replication check for the kernel path. The
-        # parameter is check_vma on jax>=0.8's top-level shard_map and
-        # check_rep on the older experimental one.
-        import inspect as _inspect
-
-        params = _inspect.signature(shard_map).parameters
-        kwargs["check_vma" if "check_vma" in params else "check_rep"] = False
+        # metadata; skip the replication check for the kernel path.
+        kwargs["check_vma"] = False
     fn = shard_map(
         partial(local, axis_name=axis_name, causal=causal),
         mesh=mesh,

@@ -187,29 +187,38 @@ def constrain(x: jax.Array, logical_axes: Sequence[Optional[str]]):
     no axis_rules context is active (single-device paths, tests).
 
     A mesh axis that does not divide the tensor's actual dim is dropped
-    for that dim (replicate instead): jaxlib 0.4.37 rejects uneven
-    shardings outright, and the decode plane traces the same constraint
+    for that dim (replicate instead): jax rejects uneven shardings
+    outright, and the decode plane traces the same constraint
     sites at many batch sizes (admission waves of 1..slots rows) — a
     2-row wave on an 8-way batch axis must replicate, not crash."""
     ctx = getattr(_ctx, "value", None)
     if ctx is None:
         return x
-    mesh, rules = ctx
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(ctx[0], resolved_spec(x, logical_axes)))
+
+
+def resolved_spec(x: jax.Array, logical_axes: Sequence[Optional[str]]) -> P:
+    """The PartitionSpec ``constrain`` applies to ``x`` under the active
+    axis_rules context: the rule table's mesh axes, minus any that do
+    not divide the tensor's actual dim."""
+    mesh, rules = _ctx.value
     spec = spec_for(logical_axes, rules)
     parts = list(spec) + [None] * (x.ndim - len(spec))
     for i, part in enumerate(parts):
-        if part is None:
-            continue
-        axes = part if isinstance(part, tuple) else (part,)
-        size = 1
-        for ax in axes:
-            size *= mesh.shape.get(ax, 1)
-        if size and x.shape[i] % size:
+        if part is not None and x.shape[i] % mesh_extent(mesh, part):
             parts[i] = None
     while parts and parts[-1] is None:
         parts.pop()
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*parts)))
+    return P(*parts)
+
+
+def mesh_extent(mesh: Mesh, part) -> int:
+    """Devices a PartitionSpec entry (an axis name or a tuple) spans."""
+    size = 1
+    for ax in (part if isinstance(part, tuple) else (part,)):
+        size *= mesh.shape.get(ax, 1)
+    return size
 
 
 def current_mesh() -> Optional[Mesh]:

@@ -46,10 +46,28 @@ def init_sharded_params(init_fn: Callable[[jax.Array], Any],
 
 
 def init_optimizer_state(optimizer: optax.GradientTransformation, params):
-    """optimizer.init jitted over committed-sharded params: XLA propagates
-    param shardings into mu/nu etc. (ZeRO optimizer-state sharding for free —
-    the 'no separate code path' cell of SURVEY §2.4's FSDP row)."""
-    return jax.jit(optimizer.init)(params)
+    """optimizer.init jitted with every params-shaped subtree of the state
+    (adam's mu/nu, momentum, ...) pinned to the params' own shardings and
+    the rest (step counts) replicated — ZeRO optimizer-state sharding
+    without a separate code path. The shardings must be given: the state
+    is built from ``zeros_like``, which depends on no input data, so XLA
+    has nothing to propagate from and an unpinned jit leaves the WHOLE
+    state on device 0 (seen on a four-chip v5e: device-0 peak 16.4 GB for
+    a 1.2B model, 3.5 GB on the others)."""
+    sharding = jax.tree.leaves(params)[0].sharding
+    if not isinstance(sharding, NamedSharding):
+        return jax.jit(optimizer.init)(params)
+    param_shardings = jax.tree.map(lambda p: p.sharding, params)
+    params_def = jax.tree.structure(params)
+    replicated_sh = NamedSharding(sharding.mesh, P())
+
+    def like_params(node) -> bool:
+        return jax.tree.structure(node) == params_def
+
+    shardings = jax.tree.map(
+        lambda node: param_shardings if like_params(node) else replicated_sh,
+        jax.eval_shape(optimizer.init, params), is_leaf=like_params)
+    return jax.jit(optimizer.init, out_shardings=shardings)(params)
 
 
 def build_train_step(
@@ -71,8 +89,8 @@ def build_train_step(
     microbatches and accumulates fp32 gradients over a ``lax.scan`` before
     ONE optimizer update. The fp32->bf16 parameter cast is hoisted out of
     the microbatch loop, so both the cast and the (bandwidth-bound on TPU)
-    optimizer pass amortize over ``accum_steps`` times more tokens — worth
-    several MFU points on memory-limited parts (see BENCH_NOTES.md).
+    optimizer pass amortize over ``accum_steps`` times more tokens (its
+    worth on the chip is not measured yet — ROADMAP S6).
 
     On a multi-chip mesh keep ``batch_size / accum_steps`` a multiple of
     the batch-sharding mesh extent (data x fsdp), or XLA resorts to
@@ -138,7 +156,7 @@ def zero1_state_shardings(mesh: Mesh, opt_state: Any,
     """NamedShardings for an optimizer-state pytree: each array leaf
     shards its FIRST axis-divisible dim over the ZeRO-1 mesh axis; leaves
     with no divisible dim (scalars like adam's ``count``, tiny norms)
-    replicate — jax 0.4.37 rejects uneven shardings, and a ragged shard
+    replicate — jax rejects uneven shardings, and a ragged shard
     would waste the padding anyway. Works on concrete arrays or
     ``jax.eval_shape`` structs.
 

@@ -24,13 +24,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from jax.experimental.shard_map import shard_map
 
 
 def _attention_local(q, k, v, causal: bool, q_offset: int, impl: str):
-    if impl == "flash" and jax.default_backend() == "tpu":
+    if impl == "flash":
+        # Off-TPU the kernel runs in Pallas interpret mode
+        # (flash_attention._interpret) — the one CPU fallback there is.
         from ray_tpu.ops.flash_attention import flash_attention
 
         return flash_attention(q, k, v, causal=causal, q_offset=q_offset)
@@ -74,4 +75,4 @@ def ulysses_attention(
 
     spec = P(None, seq_axis, None, None)
     return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+                     out_specs=spec, check_vma=False)(q, k, v)

@@ -1,8 +1,7 @@
 """Learner host: bounded shard intake + pjit updates + weight fan-out.
 
-The Podracer learner half. One driver-process "learner host" (the CPU
-backend cannot run multiprocess collectives, so the sebulba learner
-role collapses into this process) drives:
+The Podracer learner half. One driver-process "learner host" (the
+sebulba learner role is a single process here) drives:
 
 * a :class:`RolloutPlane` — the rollout-actor fleet with one in-flight
   ``collect()`` per actor and an intake thread that moves shard
@@ -13,7 +12,7 @@ role collapses into this process) drives:
 * a :class:`LearnerState` — params/opt-state with the jitted update
   running over the 8-device virtual mesh: batches are device_put with a
   ``data``-axis NamedSharding (leading dims that don't divide the axis
-  replicate — jax 0.4.37 rejects uneven shardings), params stay
+  replicate — jax rejects uneven shardings), params stay
   replicated, one jit call per update;
 * the versioned weight fan-out (``fanout.py``) plus the plane's
   metrics — all through ``util/metrics`` (no ad-hoc client-side lists),
@@ -285,7 +284,7 @@ class LearnerState:
     def shard_batch(self, batch: Dict[str, Any]) -> Dict[str, Any]:
         """device_put each leaf with a ``data``-axis sharding on its
         leading dim when it divides the axis, replicated otherwise
-        (0.4.37 rejects uneven shardings outright). This is what makes
+        (jax rejects uneven shardings outright). This is what makes
         the single jit call a pjit program: XLA reads the operand
         shardings and emits the data-parallel update."""
         if self.mesh is None:

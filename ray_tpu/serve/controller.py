@@ -868,7 +868,11 @@ class ServeController:
                          "sub_slice": ({
                              "origin": r.sub_slice["origin"],
                              "shape": r.sub_slice["shape"],
-                         } if r.sub_slice else None)}
+                         } if r.sub_slice else None),
+                         # What the replica's own process reports it
+                         # runs on (platform, kind, device ids, memory,
+                         # compiles); None until its first stats reply.
+                         "device": r.last_stats.get("device")}
                         for r in rec.replicas],
                 }
                 for name, rec in self._deployments.items()

@@ -26,10 +26,10 @@ from __future__ import annotations
 
 import itertools
 import logging
+import os
 import queue
 import threading
 import time
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -41,32 +41,6 @@ from ray_tpu.core.errors import (DeadlineExceededError, OverloadedError,
 logger = logging.getLogger(__name__)
 
 _req_ids = itertools.count(1)
-
-
-@contextmanager
-def _no_persistent_cache(jax_mod):
-    """Fresh-compile guard: run the body with the persistent XLA
-    compilation cache detached (config dir -> None + live cache handle
-    reset), restoring both afterwards. jaxlib 0.4.37 reloads of DONATED
-    executables from the disk cache segfault or return wrong numbers
-    (pinned by PR 14's pipeline tests); every donated program this
-    module compiles while a cache dir is configured routes its FIRST
-    dispatch through here so it can only ever compile fresh. Resetting
-    the handle matters: ``config.update(None)`` alone does not detach
-    an already-initialized cache."""
-    old = jax_mod.config.jax_compilation_cache_dir
-    if old is None:
-        yield
-        return
-    from jax._src import compilation_cache as _cc
-
-    jax_mod.config.update("jax_compilation_cache_dir", None)
-    _cc.reset_cache()
-    try:
-        yield
-    finally:
-        jax_mod.config.update("jax_compilation_cache_dir", old)
-        _cc.reset_cache()
 
 
 @dataclass(eq=False)  # identity semantics: the generated __eq__ would
@@ -265,6 +239,13 @@ class DecodeEngine:
             self.cache = jax.device_put(self.cache, self._cache_sharding)
         else:
             self._cache_sharding = None
+        # Devices this engine's programs run on (what stats() reports):
+        # the mesh's, or the process default device.
+        self._devices = (list(mesh.devices.flat) if mesh is not None
+                         else jax.devices()[:1])
+        from ray_tpu.util.compile_cache import compile_watch
+
+        self._compile_watch = compile_watch()
         self._free = list(range(slots))
         self._active: Dict[int, _Request] = {}
         self._prefilling: Dict[int, _Request] = {}  # chunked, mid-prefill
@@ -761,18 +742,14 @@ class DecodeEngine:
         return toks, cache
 
     def _dispatch_fresh(self, key: tuple, call):
-        """First dispatch of one of this PR's donated programs compiles
-        with the persistent XLA compilation cache DETACHED (jaxlib
-        0.4.37 pin, PR 14: a donated executable reloaded from the disk
-        cache segfaults or returns wrong numbers — the tier-1 conftest
-        only dodges it because sub-second debug-model compiles never
-        persist). Later dispatches hit the live in-process jit cache
-        and never touch disk."""
-        if key in self._compiled:
-            return call()
+        """Dispatch one of the engine's donated programs, marking the
+        FIRST dispatch of each program key as a compile in the step
+        log. The compile goes through JAX's persistent cache like any
+        other program: jax/jaxlib 0.9.0 reload donated executables
+        correctly (compile, exit, reload in a new process, identical
+        results — checked on the CPU backend and on a TPU v5e)."""
         self._mark_compile(key)
-        with _no_persistent_cache(self._jax):
-            return call()
+        return call()
 
     # --------------------------------------------- paged page accounting
 
@@ -2379,9 +2356,7 @@ class DecodeEngine:
         idle engine: paged writes route to the scratch page (idle block
         tables are all zeros), contiguous junk lands on idle rows the
         next admission overwrites, and the parked KV lengths are
-        restored afterwards. Donated programs take their first dispatch
-        HERE through the fresh-compile guard, so the jaxlib 0.4.37
-        donated-reload footgun is burned off before traffic."""
+        restored afterwards."""
         import jax.numpy as jnp
 
         toks = jnp.asarray(self._tokens)
@@ -2524,6 +2499,7 @@ class DecodeEngine:
             # prompt mid-chunked-prefill.
             "load": active + prefilling + queued + backlog // max(1,
                                                                  denom),
+            "device": self.device_stats(),
         }
         if self.paged:
             out.update(self._pages.stats())
@@ -2554,6 +2530,28 @@ class DecodeEngine:
             out["step_timeline_rows"] = len(self.steplog._rows)
             out["step_timeline_dropped"] = self.steplog.dropped
         return out
+
+    def device_stats(self) -> Dict[str, Any]:
+        """Where this engine runs, as JAX reports it in THIS process
+        (the one that holds the chips): platform, device kind, the
+        devices the engine's programs span with their live and peak
+        memory, and the process's compile counters. Read-only."""
+        d0 = self._devices[0]
+        mem = [d.memory_stats() or {} for d in self._devices]
+        return {
+            "platform": d0.platform,
+            "device_kind": d0.device_kind,
+            "device_count": len(self._jax.devices()),
+            "device_ids": [d.id for d in self._devices],
+            # Chips the node's lease made visible to this process (None
+            # = all local chips): two one-chip replicas both report
+            # device id 0, so this is what tells their chips apart.
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "bytes_in_use": [m.get("bytes_in_use") for m in mem],
+            "peak_bytes_in_use": [m.get("peak_bytes_in_use") for m in mem],
+            "pid": os.getpid(),
+            **self._compile_watch.snapshot(),
+        }
 
     def set_metrics_deployment(self, name: str) -> None:
         """Re-label this engine's SLO metrics (benches separate their
@@ -2627,7 +2625,9 @@ class LlamaDecodeDeployment:
 
         from ray_tpu.core.config import config as rt_config
         from ray_tpu.models import llama
+        from ray_tpu.util.compile_cache import compile_watch
 
+        compile_watch()  # count the replica's compiles from its first one
         cfg = config or llama.PRESETS[preset]
         self.cfg = cfg
         self._sub_slice: Optional[Dict[str, Any]] = None
@@ -2698,7 +2698,8 @@ class LlamaDecodeDeployment:
                                "prefill_backlog_tokens":
                                s["prefill_backlog_tokens"],
                                "chips": s["chips"],
-                               "mesh_shape": s["mesh_shape"]}
+                               "mesh_shape": s["mesh_shape"],
+                               "device": s["device"]}
         sub = getattr(self, "_sub_slice", None)  # tests build bare
         #   instances around an engine without running __init__
         if sub is not None:

@@ -194,35 +194,22 @@ def init_process(
     import jax
 
     if platform:
-        # Post-import config update: overrides any platform selection a
-        # plugin registration forced (env vars are read before plugins run).
+        # The environment's JAX_PLATFORMS was read when jax was imported
+        # (possibly long before this call); the live config is what
+        # backend creation consults.
         jax.config.update("jax_platforms", platform)
 
     from jax._src import distributed as _distributed
 
-    already = getattr(_distributed.global_state, "client", None) is not None
-    if not already:
-        if _backends_initialized():
-            # A forked worker inherited the parent's initialized backend;
-            # distributed init must precede backend creation.
-            from jax.extend.backend import clear_backends
-
-            clear_backends()
+    if getattr(_distributed.global_state, "client", None) is None:
+        # Must precede backend creation; jax raises a RuntimeError that
+        # says so when this process has already run a computation.
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
             process_id=process_id,
         )
     return len(jax.devices())
-
-
-def _backends_initialized() -> bool:
-    try:
-        from jax._src import xla_bridge
-
-        return xla_bridge.backends_are_initialized()
-    except Exception:
-        return False
 
 
 def shutdown_process() -> None:

@@ -51,6 +51,11 @@ def test_fsdp_training_step_runs_and_learns():
         jax.random.key(0))
     opt = optax.adamw(1e-3)
     opt_state = ts.init_optimizer_state(opt, params)
+    # adam's moments are sharded like the params they mirror, not piled on
+    # device 0 (zeros_like gives XLA nothing to propagate a sharding from)
+    for moments in (opt_state[0].mu, opt_state[0].nu):
+        assert all(m.sharding == p.sharding for m, p in zip(
+            jax.tree.leaves(moments), jax.tree.leaves(params)))
     step = ts.build_train_step(
         lambda p, b: llama.loss_fn(p, b, CFG), opt, mesh)
     batch = ts.shard_batch(_batch(jax.random.key(1), CFG, batch=8), mesh)
@@ -233,3 +238,34 @@ def test_multislice_dcn_mesh_loss_matches():
     # rtol matches the other loss-parity tests: reduction-order drift
     # on the virtual CPU mesh is ~1.5e-3 relative for this layout.
     np.testing.assert_allclose(sharded_loss, dense_loss, rtol=2e-3)
+
+
+def test_flash_attention_under_a_sharded_mesh_matches_xla():
+    """attention_impl="flash" inside a GSPMD step: the Pallas call runs
+    under shard_map on each device's (batch, heads) shard (on the chip
+    GSPMD refuses to partition a Mosaic kernel), GQA heads cut the same
+    way on both head axes — the sharded loss matches the unsharded XLA
+    path and the backward traces through the shard_map."""
+    import dataclasses
+
+    cfg = dataclasses.replace(CFG, dtype=jnp.float32)  # 4 heads / 2 kv
+    flash = dataclasses.replace(cfg, attention_impl="flash")
+    params = llama.init_params(cfg, jax.random.key(0))
+    batch = _batch(jax.random.key(1), cfg, batch=4, seq=16)
+    want = float(llama.loss_fn(params, batch, cfg))
+
+    mesh = MeshSpec(fsdp=4, tensor=2).build()
+    sharded = jax.tree.map(jax.device_put, params,
+                           tree_shardings(mesh, llama.param_axes()))
+    loss_fn = ts.build_eval_step(lambda p, b: llama.loss_fn(p, b, flash),
+                                 mesh)
+    got = float(loss_fn(sharded, ts.shard_batch(batch, mesh)))
+    assert abs(got - want) < 1e-4 * max(1.0, abs(want)), (got, want)
+    with axis_rules(mesh):
+        grads = jax.eval_shape(
+            jax.grad(lambda p: llama.loss_fn(p, batch, flash)), params)
+    assert jax.tree.structure(grads) == jax.tree.structure(params)
+    # a seq-sharded mesh must use ring attention, not per-shard flash
+    with pytest.raises(ValueError, match="ring"):
+        with axis_rules(MeshSpec(fsdp=4, seq=2).build()):
+            jax.eval_shape(lambda p: llama.loss_fn(p, batch, flash), params)

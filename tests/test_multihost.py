@@ -10,10 +10,9 @@ modeled on the reference's process-group setup test surface
 Since ISSUE 13 the bootstrap routes through the multihost gang
 substrate (``core/multihost.py``): group registration + the barrier'd
 bootstrap-fingerprint check precede ``jax.distributed.initialize``.
-The two collective-running tests stay skip-marked on this image
-(jaxlib 0.4.37 CPU backend), but the routing itself and the REAL
-2-process bootstrap (which does work on CPU — only collectives fail)
-are exercised un-skipped below."""
+The installed jaxlib's CPU backend runs cross-process collectives
+(gloo), so the collective-running tests run here too; the 18 s
+train-and-restore one is slow-marked for the tier-1 budget."""
 
 import os
 
@@ -22,19 +21,6 @@ import pytest
 import ray_tpu
 from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 from ray_tpu.train.jax_backend import JaxConfig
-
-# Environment-bound (triaged PR 3): the multi-process mesh forms and the
-# jax.distributed bootstrap succeeds, but this image's jaxlib (0.4.37)
-# fails any cross-process collective on the CPU backend with
-# "INVALID_ARGUMENT: Multiprocess computations aren't implemented on the
-# CPU backend" — the code path under test NEEDS a backend with
-# cross-process collectives (TPU pod slice, or a jaxlib whose CPU
-# backend ships gloo collectives). Skip, don't fail: a red tier-1 run
-# must mean a code regression, not a known image limitation.
-_multiprocess_cpu_skip = pytest.mark.skip(
-    reason="jaxlib 0.4.37 CPU backend cannot run multiprocess "
-           "computations (XLA INVALID_ARGUMENT); needs TPU or a "
-           "gloo-enabled jaxlib")
 
 
 def test_worker_group_bootstrap_routes_through_multihost(monkeypatch):
@@ -102,7 +88,6 @@ def test_real_two_process_bootstrap_forms_through_gang(
     assert multihost.registry_state(gang_id) is None
 
 
-@_multiprocess_cpu_skip
 def test_worker_group_forms_global_mesh(ray_start_regular):
     """Two worker processes x virtual CPU devices -> one global device view;
     a jitted psum crosses the process boundary."""
@@ -146,7 +131,7 @@ def test_worker_group_forms_global_mesh(ray_start_regular):
     assert result.metrics["total"] == pytest.approx(n * 2 * 4)
 
 
-@_multiprocess_cpu_skip
+@pytest.mark.slow  # 18 s: tier-1 rebudget
 def test_multiprocess_fsdp_tp_train_and_restore(ray_start_regular, tmp_path):
     """Debug Llama with FSDP+TP sharding over a 2-process mesh, orbax
     multi-host checkpoint save + sharded restore, through JaxTrainer

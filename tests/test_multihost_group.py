@@ -1,7 +1,7 @@
 """Multi-host gang contract (ISSUE 13, ROADMAP #3).
 
-The CPU box cannot run multiprocess collectives (jaxlib 0.4.37), so
-these tests prove everything AROUND the collective: gang spawn and
+These tests prove everything AROUND the collective (the real
+cross-process collective path is tests/test_multihost.py): gang spawn and
 teardown with aligned member contexts, one-member-death reconciling the
 WHOLE group (sub-slice released exactly once), coordinator failover
 with epoch fencing (the deposed coordinator's stale-epoch write is

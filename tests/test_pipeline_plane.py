@@ -214,18 +214,6 @@ def test_zero1_state_bytes_and_parity():
     batch = {"tokens": rng.integers(0, cfg.vocab_size,
                                     (8, 17)).astype(np.int32)}
     opt = optax.adam(1e-2)
-    # jaxlib 0.4.37: DONATED executables reloaded from the persistent
-    # compile cache segfault or return silently wrong outputs (cold
-    # compiles are fine; only warm cross-run cache hits break — minimal
-    # repro in BENCH_NOTES.md PR 14). The cache is test infra, not the
-    # feature under test: compile this test's programs fresh every run.
-    # config.update alone is NOT enough — the cache object is lazily
-    # initialized into a module global, so reset it explicitly.
-    from jax._src import compilation_cache as _cc
-
-    old_cache = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    _cc.reset_cache()
 
     def lf(p, b):
         return llama.loss_fn(p, b, cfg)
@@ -239,51 +227,41 @@ def test_zero1_state_bytes_and_parity():
             jax.tree.map(lambda x: np.array(x), base_params),
             jax.tree.map(lambda _: rep, base_params))
 
+    # State-bytes sweep: init only.
     ratios = {}
-    try:
-        # State-bytes sweep: init only (no donation — donated
-        # executables on SUBSET-device meshes are unstable on this
-        # jaxlib, see below).
-        for n_data in (2, 4, 8):
-            mesh = MeshSpec(data=n_data, fsdp=1).build(
-                jax.devices()[:n_data])
-            rep = NamedSharding(mesh, P())
-            params = fresh_replicated(rep)
-            st_plain = ts.init_optimizer_state(opt, params)
-            per_plain = ts.per_replica_state_bytes(st_plain)
-            st_z1 = ts.init_zero1_opt_state(opt, params, mesh)
-            ratios[n_data] = ts.per_replica_state_bytes(st_z1) \
-                / per_plain
-
-        # Parity: donated steps on the FULL 8-device mesh — the one
-        # donation configuration this jaxlib build runs reliably (the
-        # whole trainer suite exercises it; donated executables on
-        # subset meshes SIGABRT/corrupt intermittently, warm cache or
-        # not).
-        mesh = MeshSpec(data=8, fsdp=1).build()
+    for n_data in (2, 4, 8):
+        mesh = MeshSpec(data=n_data, fsdp=1).build(
+            jax.devices()[:n_data])
         rep = NamedSharding(mesh, P())
-        step_plain = ts.build_train_step(lf, opt, mesh)
         params = fresh_replicated(rep)
-        step_z1 = ts.build_zero1_train_step(lf, opt, mesh, params)
-        p0, p1 = fresh_replicated(rep), fresh_replicated(rep)
-        s0 = ts.init_optimizer_state(opt, p0)
-        s1 = ts.init_zero1_opt_state(opt, p1, mesh)
-        per_z1 = ts.per_replica_state_bytes(s1)
-        plain_losses, z1_losses = [], []
-        for _ in range(3):
-            p0, s0, m0 = step_plain(p0, s0, batch)
-            p1, s1, m1 = step_z1(p1, s1, batch)
-            plain_losses.append(float(m0["loss"]))
-            z1_losses.append(float(m1["loss"]))
-        np.testing.assert_allclose(z1_losses, plain_losses, rtol=2e-4)
-        # State stays sharded THROUGH the step (donated in/out),
-        # params stay replicated (the once-per-step all-gather).
-        assert ts.per_replica_state_bytes(s1) == per_z1
-        assert all(l.sharding.is_fully_replicated
-                   for l in jax.tree.leaves(p1))
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old_cache)
-        _cc.reset_cache()
+        st_plain = ts.init_optimizer_state(opt, params)
+        per_plain = ts.per_replica_state_bytes(st_plain)
+        st_z1 = ts.init_zero1_opt_state(opt, params, mesh)
+        ratios[n_data] = ts.per_replica_state_bytes(st_z1) \
+            / per_plain
+
+    # Parity: donated steps on the full 8-device mesh.
+    mesh = MeshSpec(data=8, fsdp=1).build()
+    rep = NamedSharding(mesh, P())
+    step_plain = ts.build_train_step(lf, opt, mesh)
+    params = fresh_replicated(rep)
+    step_z1 = ts.build_zero1_train_step(lf, opt, mesh, params)
+    p0, p1 = fresh_replicated(rep), fresh_replicated(rep)
+    s0 = ts.init_optimizer_state(opt, p0)
+    s1 = ts.init_zero1_opt_state(opt, p1, mesh)
+    per_z1 = ts.per_replica_state_bytes(s1)
+    plain_losses, z1_losses = [], []
+    for _ in range(3):
+        p0, s0, m0 = step_plain(p0, s0, batch)
+        p1, s1, m1 = step_z1(p1, s1, batch)
+        plain_losses.append(float(m0["loss"]))
+        z1_losses.append(float(m1["loss"]))
+    np.testing.assert_allclose(z1_losses, plain_losses, rtol=2e-4)
+    # State stays sharded THROUGH the step (donated in/out),
+    # params stay replicated (the once-per-step all-gather).
+    assert ts.per_replica_state_bytes(s1) == per_z1
+    assert all(l.sharding.is_fully_replicated
+               for l in jax.tree.leaves(p1))
 
     # ~1/N + the all-gather working buffers: the acceptance bound is
     # 0.6x at data=2; deeper meshes keep shrinking (indivisible tiny

@@ -9,9 +9,8 @@ boundaries, perfect-draft step compression, mesh-sharded spec replicas,
 rejection-rollback page accounting under a randomized soak with
 cancels/deadlines mid-round, composition with prefix-cache and chunked
 prefill, the draftless/mixed-temperature fallbacks with draft resync,
-the fused device sampler's greedy parity, warmup pre-dispatch, and the
-jaxlib 0.4.37 donated-executable fresh-compile guard. All CPU, tiny
-configs — tier-1 safe."""
+the fused device sampler's greedy parity, and warmup pre-dispatch. All
+CPU, tiny configs — tier-1 safe."""
 
 import numpy as np
 import pytest
@@ -451,54 +450,6 @@ def test_warmup_predispatches_step_programs(model, draft):
     plain.shutdown()
     got = _outputs(spec, prompts, 10)
     assert np.array_equal(want[0], got[0])
-    spec.shutdown()
-
-
-# ------------------------------------- donated-executable compile guard
-
-
-def test_no_persistent_cache_guard_scopes_and_restores():
-    """The jaxlib 0.4.37 pin (PR 14): donated executables reloaded from
-    the persistent XLA compile cache are corrupt. _dispatch_fresh must
-    detach the disk cache for exactly the FIRST dispatch of a donated
-    program and restore it after — including on error."""
-    import jax
-
-    from ray_tpu.serve.decode import _no_persistent_cache
-
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        jax.config.update("jax_compilation_cache_dir", "/tmp/_specpc")
-        with _no_persistent_cache(jax):
-            assert jax.config.jax_compilation_cache_dir is None
-        assert jax.config.jax_compilation_cache_dir == "/tmp/_specpc"
-        with pytest.raises(RuntimeError):
-            with _no_persistent_cache(jax):
-                assert jax.config.jax_compilation_cache_dir is None
-                raise RuntimeError("boom")
-        assert jax.config.jax_compilation_cache_dir == "/tmp/_specpc"
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-
-
-def test_dispatch_fresh_detaches_only_first_dispatch(model, draft):
-    import jax
-
-    spec = _spec_engine(model, draft, k=2)
-    prev = jax.config.jax_compilation_cache_dir
-    seen = []
-    try:
-        jax.config.update("jax_compilation_cache_dir", "/tmp/_specpc")
-        spec._dispatch_fresh(
-            ("probe",),
-            lambda: seen.append(jax.config.jax_compilation_cache_dir))
-        spec._dispatch_fresh(
-            ("probe",),
-            lambda: seen.append(jax.config.jax_compilation_cache_dir))
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-    assert seen == [None, "/tmp/_specpc"]
-    assert ("probe",) in spec._compiled
     spec.shutdown()
 
 
