@@ -181,11 +181,18 @@ def row_parallel_weights(train: Dict[str, Axes], decode: Dict[str, Axes],
 # ------------------------------------------------- operand resolution
 
 def _peel(expr: ast.AST) -> ast.AST:
-    """Strip ``.astype(...)`` wrappers: they change dtype, not axes."""
-    while isinstance(expr, ast.Call) \
-            and isinstance(expr.func, ast.Attribute) \
-            and expr.func.attr == "astype":
-        expr = expr.func.value
+    """Strip ``.astype(...)`` wrappers and the decode model's
+    ``_cast(w, dtype)`` (the same convert under a trace scope): they
+    change dtype, not axes."""
+    while isinstance(expr, ast.Call):
+        if isinstance(expr.func, ast.Attribute) \
+                and expr.func.attr == "astype":
+            expr = expr.func.value
+        elif isinstance(expr.func, ast.Name) \
+                and expr.func.id == "_cast" and expr.args:
+            expr = expr.args[0]
+        else:
+            break
     return expr
 
 

@@ -70,16 +70,34 @@ def init_cache(config: LlamaConfig, batch: int, capacity: int,
     }
 
 
+def _cast(w: jax.Array, dtype) -> jax.Array:
+    """A weight in the compute dtype. The scope names the f32 -> bf16
+    converts in a device trace (``weight_cast``); for weights already in
+    ``dtype`` it is nothing at all."""
+    with jax.named_scope("weight_cast"):
+        return w.astype(dtype)
+
+
+def _paged_gather(k_p, v_p, block_tables, config: LlamaConfig):
+    """Each row's pages back in logical order, ``(B, W * T, KV, D)``: the
+    view the paged attention reads. Named for the device trace."""
+    B, W = block_tables.shape
+    shape = (B, W * k_p.shape[1], config.n_kv_heads, config.head_dim)
+    with jax.named_scope("paged_gather"):
+        return (k_p[block_tables].reshape(shape),
+                v_p[block_tables].reshape(shape))
+
+
 def _qkv(layer, h, config: LlamaConfig):
     c = config
     if "wqkv" in layer:
-        qkv = jnp.einsum("bse,ehd->bshd", h, layer["wqkv"].astype(h.dtype))
+        qkv = jnp.einsum("bse,ehd->bshd", h, _cast(layer["wqkv"], h.dtype))
         return (qkv[:, :, :c.n_heads],
                 qkv[:, :, c.n_heads:c.n_heads + c.n_kv_heads],
                 qkv[:, :, c.n_heads + c.n_kv_heads:])
-    q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
-    k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
-    v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
+    q = jnp.einsum("bse,ehd->bshd", h, _cast(layer["wq"], h.dtype))
+    k = jnp.einsum("bse,ehd->bshd", h, _cast(layer["wk"], h.dtype))
+    v = jnp.einsum("bse,ehd->bshd", h, _cast(layer["wv"], h.dtype))
     return q, k, v
 
 
@@ -87,18 +105,18 @@ def _mlp(layer, x, config: LlamaConfig):
     h2 = rms_norm(x, layer["mlp_norm"], config.norm_eps)
     if "w_gate_up" in layer:
         gate_up = jnp.einsum("bse,em->bsm", h2,
-                             layer["w_gate_up"].astype(h2.dtype))
+                             _cast(layer["w_gate_up"], h2.dtype))
         gate, up = jnp.split(gate_up, 2, axis=-1)
     else:
         gate = jnp.einsum("bse,em->bsm", h2,
-                          layer["w_gate"].astype(h2.dtype))
-        up = jnp.einsum("bse,em->bsm", h2, layer["w_up"].astype(h2.dtype))
+                          _cast(layer["w_gate"], h2.dtype))
+        up = jnp.einsum("bse,em->bsm", h2, _cast(layer["w_up"], h2.dtype))
     ffn = jax.nn.silu(gate) * up
     # Pre-contraction anchor (see llama._decoder_layer): under DECODE
     # rules this all-gathers the mlp-sharded hidden so the w_down
     # reduction is never split across the mesh (bit-exactness contract).
     ffn = constrain(ffn, ("batch", "length", "mlp_hidden"))
-    down = jnp.einsum("bsm,me->bse", ffn, layer["w_down"].astype(h2.dtype))
+    down = jnp.einsum("bsm,me->bse", ffn, _cast(layer["w_down"], h2.dtype))
     return x + down
 
 
@@ -124,7 +142,7 @@ def prefill(params: Dict[str, Any], tokens: jax.Array, cache: Cache,
                          f"{capacity}")
     if lengths is None:
         lengths = jnp.full((B,), S, jnp.int32)
-    x = params["tok_embed"].astype(c.dtype)[tokens]
+    x = _cast(params["tok_embed"], c.dtype)[tokens]
     cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
 
     def body(x, layer):
@@ -149,7 +167,7 @@ def prefill(params: Dict[str, Any], tokens: jax.Array, cache: Cache,
     x_last = jnp.take_along_axis(
         x, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     logits = jnp.einsum("be,ev->bv", x_last,
-                        params["lm_head"].astype(c.dtype),
+                        _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
     return logits, {"k": new_k, "v": new_v, "length": lengths}
 
@@ -181,7 +199,7 @@ def prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
     B, S = tokens.shape
     capacity = cache["k"].shape[2]
     cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
-    x = params["tok_embed"].astype(c.dtype)[tokens]        # (B, S, E)
+    x = _cast(params["tok_embed"], c.dtype)[tokens]        # (B, S, E)
     abs_pos = prefix_lens[:, None] + jnp.arange(S)[None, :]  # (B, S)
     kv_groups = c.n_heads // c.n_kv_heads
     scale = c.head_dim ** -0.5
@@ -211,7 +229,7 @@ def prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
         att = att.transpose(0, 3, 1, 2, 4).reshape(
             B, S, c.n_heads, c.head_dim).astype(x.dtype)
         att = constrain(att, ("batch", "length", "attn_heads", "head_dim"))
-        out = jnp.einsum("bshd,hde->bse", att, layer["wo"].astype(x.dtype))
+        out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
         x = x + out
         x = _mlp(layer, x, c)
         return x, (k_c, v_c)
@@ -223,7 +241,7 @@ def prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
     x_last = jnp.take_along_axis(
         x, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     logits = jnp.einsum("be,ev->bv", x_last,
-                        params["lm_head"].astype(c.dtype),
+                        _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
     return logits, {"k": new_k, "v": new_v, "length": lengths}
 
@@ -240,7 +258,7 @@ def decode_step(params: Dict[str, Any], cache: Cache, tokens: jax.Array,
     B = tokens.shape[0]
     pos = cache["length"]  # (B,)
     cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
-    x = params["tok_embed"].astype(c.dtype)[tokens][:, None]  # (B, 1, E)
+    x = _cast(params["tok_embed"], c.dtype)[tokens][:, None]  # (B, 1, E)
     capacity = cache["k"].shape[2]
     kv_groups = c.n_heads // c.n_kv_heads
     scale = c.head_dim ** -0.5
@@ -271,7 +289,7 @@ def decode_step(params: Dict[str, Any], cache: Cache, tokens: jax.Array,
         att = jnp.einsum("bkgc,bckd->bkgd", probs.astype(v_c.dtype), v_c)
         att = att.reshape(B, 1, c.n_heads, c.head_dim).astype(x.dtype)
         att = constrain(att, ("batch", "length", "attn_heads", "head_dim"))
-        out = jnp.einsum("bshd,hde->bse", att, layer["wo"].astype(x.dtype))
+        out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
         x = x + out
         x = _mlp(layer, x, c)
         return x, (k_c, v_c)
@@ -280,7 +298,7 @@ def decode_step(params: Dict[str, Any], cache: Cache, tokens: jax.Array,
         body, x, (params["layers"], cache["k"], cache["v"]))
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     logits = jnp.einsum("be,ev->bv", x[:, 0],
-                        params["lm_head"].astype(c.dtype),
+                        _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
     return logits, {"k": new_k, "v": new_v, "length": pos + 1}
 
@@ -395,7 +413,7 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
     W = block_tables.shape[1]
     C = W * T
     cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
-    x = params["tok_embed"].astype(c.dtype)[tokens]          # (B, S, E)
+    x = _cast(params["tok_embed"], c.dtype)[tokens]          # (B, S, E)
     abs_pos = prefix_lens[:, None] + jnp.arange(S)[None, :]  # (B, S)
     kv_groups = c.n_heads // c.n_kv_heads
     scale = c.head_dim ** -0.5
@@ -427,18 +445,18 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
         # Gather AFTER the scatter so the suffix's own causal K/V is in
         # view; layout is logical position order, like the contiguous
         # rows, so attention below is the exact prefill_suffix math.
-        k_c = k_p[block_tables].reshape(B, C, c.n_kv_heads, c.head_dim)
-        v_c = v_p[block_tables].reshape(B, C, c.n_kv_heads, c.head_dim)
-        qg = q.reshape(B, S, c.n_kv_heads, kv_groups, c.head_dim)
-        scores = jnp.einsum("bskgd,bckd->bkgsc", qg, k_c,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        att = jnp.einsum("bkgsc,bckd->bkgsd", probs.astype(v_c.dtype), v_c)
+        k_c, v_c = _paged_gather(k_p, v_p, block_tables, c)
+        with jax.named_scope("paged_attn"):
+            qg = q.reshape(B, S, c.n_kv_heads, kv_groups, c.head_dim)
+            scores = jnp.einsum("bskgd,bckd->bkgsc", qg, k_c,
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            att = jnp.einsum("bkgsc,bckd->bkgsd", probs.astype(v_c.dtype), v_c)
         att = att.transpose(0, 3, 1, 2, 4).reshape(
             B, S, c.n_heads, c.head_dim).astype(x.dtype)
         att = constrain(att, ("batch", "length", "attn_heads", "head_dim"))
-        out = jnp.einsum("bshd,hde->bse", att, layer["wo"].astype(x.dtype))
+        out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
         x = x + out
         x = _mlp(layer, x, c)
         return x, (k_p, v_p)
@@ -450,7 +468,7 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
     x_last = jnp.take_along_axis(
         x, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     logits = jnp.einsum("be,ev->bv", x_last,
-                        params["lm_head"].astype(c.dtype),
+                        _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 
@@ -470,7 +488,7 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
     C = W * T
     pos = lengths                                            # (B,)
     cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
-    x = params["tok_embed"].astype(c.dtype)[tokens][:, None]  # (B, 1, E)
+    x = _cast(params["tok_embed"], c.dtype)[tokens][:, None]  # (B, 1, E)
     kv_groups = c.n_heads // c.n_kv_heads
     scale = c.head_dim ** -0.5
     rows = jnp.arange(B)
@@ -495,17 +513,17 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
                           ("batch", "length", "kv_heads", "head_dim"))
         k_p = k_p.at[page, off].set(k_new[:, 0].astype(k_p.dtype))
         v_p = v_p.at[page, off].set(v_new[:, 0].astype(v_p.dtype))
-        k_c = k_p[block_tables].reshape(B, C, c.n_kv_heads, c.head_dim)
-        v_c = v_p[block_tables].reshape(B, C, c.n_kv_heads, c.head_dim)
-        qg = q[:, 0].reshape(B, c.n_kv_heads, kv_groups, c.head_dim)
-        scores = jnp.einsum("bkgd,bckd->bkgc", qg, k_c,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        att = jnp.einsum("bkgc,bckd->bkgd", probs.astype(v_c.dtype), v_c)
+        k_c, v_c = _paged_gather(k_p, v_p, block_tables, c)
+        with jax.named_scope("paged_attn"):
+            qg = q[:, 0].reshape(B, c.n_kv_heads, kv_groups, c.head_dim)
+            scores = jnp.einsum("bkgd,bckd->bkgc", qg, k_c,
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            att = jnp.einsum("bkgc,bckd->bkgd", probs.astype(v_c.dtype), v_c)
         att = att.reshape(B, 1, c.n_heads, c.head_dim).astype(x.dtype)
         att = constrain(att, ("batch", "length", "attn_heads", "head_dim"))
-        out = jnp.einsum("bshd,hde->bse", att, layer["wo"].astype(x.dtype))
+        out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
         x = x + out
         x = _mlp(layer, x, c)
         return x, (k_p, v_p)
@@ -514,7 +532,7 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
         body, x, (params["layers"], pool["k"], pool["v"]))
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     logits = jnp.einsum("be,ev->bv", x[:, 0],
-                        params["lm_head"].astype(c.dtype),
+                        _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
     return logits, {"k": new_k, "v": new_v}, pos + 1
 
@@ -575,7 +593,7 @@ def paged_verify(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
     W = block_tables.shape[1]
     C = W * T
     cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
-    x = params["tok_embed"].astype(c.dtype)[tokens]          # (B, S, E)
+    x = _cast(params["tok_embed"], c.dtype)[tokens]          # (B, S, E)
     abs_pos = prefix_lens[:, None] + jnp.arange(S)[None, :]  # (B, S)
     kv_groups = c.n_heads // c.n_kv_heads
     scale = c.head_dim ** -0.5
@@ -600,18 +618,18 @@ def paged_verify(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
                           ("batch", "length", "kv_heads", "head_dim"))
         k_p = k_p.at[pages, offs].set(k_new.astype(k_p.dtype))
         v_p = v_p.at[pages, offs].set(v_new.astype(v_p.dtype))
-        k_c = k_p[block_tables].reshape(B, C, c.n_kv_heads, c.head_dim)
-        v_c = v_p[block_tables].reshape(B, C, c.n_kv_heads, c.head_dim)
-        qg = q.reshape(B, S, c.n_kv_heads, kv_groups, c.head_dim)
-        scores = jnp.einsum("bskgd,bckd->bkgsc", qg, k_c,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        att = jnp.einsum("bkgsc,bckd->bkgsd", probs.astype(v_c.dtype), v_c)
+        k_c, v_c = _paged_gather(k_p, v_p, block_tables, c)
+        with jax.named_scope("paged_attn"):
+            qg = q.reshape(B, S, c.n_kv_heads, kv_groups, c.head_dim)
+            scores = jnp.einsum("bskgd,bckd->bkgsc", qg, k_c,
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            att = jnp.einsum("bkgsc,bckd->bkgsd", probs.astype(v_c.dtype), v_c)
         att = att.transpose(0, 3, 1, 2, 4).reshape(
             B, S, c.n_heads, c.head_dim).astype(x.dtype)
         att = constrain(att, ("batch", "length", "attn_heads", "head_dim"))
-        out = jnp.einsum("bshd,hde->bse", att, layer["wo"].astype(x.dtype))
+        out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
         x = x + out
         x = _mlp(layer, x, c)
         return x, (k_p, v_p)
@@ -620,7 +638,7 @@ def paged_verify(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
         body, x, (params["layers"], pool["k"], pool["v"]))
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     logits = jnp.einsum("bse,ev->bsv", x,
-                        params["lm_head"].astype(c.dtype),
+                        _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 

@@ -198,26 +198,31 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, window, q_offset,
                         lambda b_, h_, i, j: (b_, 0, j)),
         ]
         args += list(_seg_layout(seg_q, seg_k))
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            _block_spec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            _block_spec((1, 1, block_q, _LANE),
-                        lambda b_, h_, i, j: (b_, h_, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, _LANE), jnp.float32),
-        ],
-        scratch_shapes=[
-            _scratch((block_q, d), jnp.float32),
-            _scratch((block_q, 128), jnp.float32),
-            _scratch((block_q, 128), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(*args)
+    # The scope and the kernel's name are what a device trace carries: a
+    # reader finds the three kernels by them, not by XLA's numbering.
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                _block_spec((1, 1, block_q, d),
+                            lambda b_, h_, i, j: (b_, h_, i, 0)),
+                _block_spec((1, 1, block_q, _LANE),
+                            lambda b_, h_, i, j: (b_, h_, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+                jax.ShapeDtypeStruct((b, h, sq, _LANE), jnp.float32),
+            ],
+            scratch_shapes=[
+                _scratch((block_q, d), jnp.float32),
+                _scratch((block_q, 128), jnp.float32),
+                _scratch((block_q, 128), jnp.float32),
+            ],
+            interpret=_interpret(),
+            name="flash_fwd",
+        )(*args)
     # Keep only lane 0 (the value; other lanes are the tiling broadcast) so
     # the residual saved for the backward is (B, H, S), not 128x that.
     return out, lse[..., 0]
@@ -386,26 +391,28 @@ def _bwd(q, k, v, seg_q, seg_k, out, lse, do, dlse, scale, causal, window,
             _block_spec((1, _SUBLANE, block_k),
                         lambda b_, h_, j, i: (b_, 0, j)),
         ]
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=grid_dkv,
-        in_specs=in_specs,
-        out_specs=[
-            _block_spec((1, 1, block_k, d),
-                        lambda b_, h_, j, i: (b_, h_, j, 0)),
-            _block_spec((1, 1, block_k, d),
-                        lambda b_, h_, j, i: (b_, h_, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
-        ],
-        scratch_shapes=[
-            _scratch((block_k, d), jnp.float32),
-            _scratch((block_k, d), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta, *extra)
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            dkv_kernel,
+            grid=grid_dkv,
+            in_specs=in_specs,
+            out_specs=[
+                _block_spec((1, 1, block_k, d),
+                            lambda b_, h_, j, i: (b_, h_, j, 0)),
+                _block_spec((1, 1, block_k, d),
+                            lambda b_, h_, j, i: (b_, h_, j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
+                jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
+            ],
+            scratch_shapes=[
+                _scratch((block_k, d), jnp.float32),
+                _scratch((block_k, d), jnp.float32),
+            ],
+            interpret=_interpret(),
+            name="flash_bwd_dkv",
+        )(q, k, v, do, lse, delta, *extra)
     if group > 1:
         dk = dk.reshape(b, hkv, group, sk, d).sum(axis=2)
         dv = dv.reshape(b, hkv, group, sk, d).sum(axis=2)
@@ -435,17 +442,19 @@ def _bwd(q, k, v, seg_q, seg_k, out, lse, do, dlse, scale, causal, window,
             _block_spec((1, _SUBLANE, block_k),
                         lambda b_, h_, i, j: (b_, 0, j)),
         ]
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=grid_dq,
-        in_specs=in_specs,
-        out_specs=[
-            _block_spec((1, 1, block_q, d), qmap2),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype)],
-        scratch_shapes=[_scratch((block_q, d), jnp.float32)],
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta, *extra)[0]
+    with jax.named_scope("flash_bwd_dq"):
+        dq = pl.pallas_call(
+            dq_kernel,
+            grid=grid_dq,
+            in_specs=in_specs,
+            out_specs=[
+                _block_spec((1, 1, block_q, d), qmap2),
+            ],
+            out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype)],
+            scratch_shapes=[_scratch((block_q, d), jnp.float32)],
+            interpret=_interpret(),
+            name="flash_bwd_dq",
+        )(q, k, v, do, lse, delta, *extra)[0]
     return dq, dk, dv
 
 

@@ -115,7 +115,9 @@ def build_train_step(
         grads = jax.tree.map(lambda g: g / accum_steps, grads)
         return losses.mean(), grads
 
-    def step(params, opt_state, batch):
+    # Jitted under its own name: the program is ``train_step`` in a
+    # profiler trace and in the lowered module, whatever builds it.
+    def train_step(params, opt_state, batch):
         with axis_rules(mesh, rules):
             if accum_steps > 1:
                 loss, grads = _grads_accum(params, batch)
@@ -130,9 +132,9 @@ def build_train_step(
         return new_params, new_opt_state, metrics
 
     if out_shardings is not None:
-        return jax.jit(step, donate_argnums=(0, 1),
+        return jax.jit(train_step, donate_argnums=(0, 1),
                        out_shardings=out_shardings)
-    return jax.jit(step, donate_argnums=(0, 1))
+    return jax.jit(train_step, donate_argnums=(0, 1))
 
 
 # --------------------------------------------------------------- ZeRO-1

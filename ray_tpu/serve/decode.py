@@ -24,6 +24,7 @@ the serial consumer), matching how a chip is actually scheduled.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import logging
 import os
@@ -419,50 +420,55 @@ class DecodeEngine:
             # ``width`` (suffix) = static leading block-table columns the
             # wave touches — cost scales with prefix+suffix, not max
             # context, exactly like the contiguous ``lim``.
-            self._paged_prefill = self._mesh_scoped(jax.jit(
-                self._paged_prefill_impl, static_argnames=("n", "bucket"),
+            self._paged_prefill = self._mesh_scoped(self._program(
+                "paged_prefill", self._paged_prefill_impl,
+                static_argnames=("n", "bucket"),
                 donate_argnums=(1,), **cache_out))
-            self._paged_suffix = self._mesh_scoped(jax.jit(
-                self._paged_suffix_impl,
+            self._paged_suffix = self._mesh_scoped(self._program(
+                "paged_suffix", self._paged_suffix_impl,
                 static_argnames=("n", "bucket", "width"),
                 donate_argnums=(1,), **cache_out))
-            self._decode = self._mesh_scoped(jax.jit(
-                self._paged_decode_impl, donate_argnums=(1,),
+            self._decode = self._mesh_scoped(self._program(
+                "decode", self._paged_decode_impl, donate_argnums=(1,),
                 **cache_out))
             # Disaggregated adopt: scatter handed-off page payloads into
             # the pool (pure data movement, no model math) and park the
             # slot cursor at the committed length. Cache-only output, so
             # mesh engines pin just the cache sharding (the
             # draft_cache_only precedent below).
-            self._adopt_pages = self._mesh_scoped(jax.jit(
-                self._adopt_pages_impl, static_argnames=("width",),
+            self._adopt_pages = self._mesh_scoped(self._program(
+                "adopt_pages", self._adopt_pages_impl,
+                static_argnames=("width",),
                 donate_argnums=(0,),
                 **({"out_shardings": self._cache_sharding}
                    if self.mesh is not None else {})))
         else:
-            self._prefill_many = self._mesh_scoped(jax.jit(
-                self._prefill_many_impl, static_argnames=("n", "bucket"),
+            self._prefill_many = self._mesh_scoped(self._program(
+                "prefill", self._prefill_many_impl,
+                static_argnames=("n", "bucket"),
                 donate_argnums=(1,), **cache_out))
             # Prefix-hit admission: splice pool entries into the wave's
             # slots and prefill only the suffixes — one program per
             # (n, bucket) power-of-two pair, like _prefill_many. Pool
             # insert copies a freshly prefilled slot's leading positions
             # into a pool row.
-            self._prefill_suffix_many = self._mesh_scoped(jax.jit(
-                self._prefill_suffix_many_impl,
+            self._prefill_suffix_many = self._mesh_scoped(self._program(
+                "suffix", self._prefill_suffix_many_impl,
                 static_argnames=("n", "bucket"), donate_argnums=(1,),
                 **cache_out))
-            self._pool_insert = self._mesh_scoped(jax.jit(
-                self._pool_insert_impl, donate_argnums=(1, 2),
+            self._pool_insert = self._mesh_scoped(self._program(
+                "pool_insert", self._pool_insert_impl,
+                donate_argnums=(1, 2),
                 **pool_ins))
-            self._decode = self._mesh_scoped(jax.jit(
-                self._decode_impl, donate_argnums=(1,), **cache_out))
+            self._decode = self._mesh_scoped(self._program(
+                "decode", self._decode_impl, donate_argnums=(1,),
+                **cache_out))
         # K greedy steps per device call (dispatch amortization); chunking
         # only engages when no admissions are pending and every active
         # request is greedy — sampling and joins stay per-token exact.
         self.decode_chunk = max(1, int(decode_chunk))
-        self._decode_k = self._mesh_scoped(jax.jit(
-            self._paged_decode_chunk_impl if self.paged
+        self._decode_k = self._mesh_scoped(self._program(
+            "decode_k", self._paged_decode_chunk_impl if self.paged
             else self._decode_chunk_impl,
             static_argnames=("k",), donate_argnums=(1,), **cache_out))
         # Speculative programs: target verify (all-position argmax over
@@ -481,15 +487,17 @@ class DecodeEngine:
             else:
                 draft_out = {}
                 draft_cache_only = {}
-            self._spec_verify = self._mesh_scoped(jax.jit(
-                self._spec_verify_impl, donate_argnums=(1,),
+            self._spec_verify = self._mesh_scoped(self._program(
+                "spec_verify", self._spec_verify_impl,
+                donate_argnums=(1,),
                 **cache_out))
-            self._spec_draft = self._mesh_scoped(jax.jit(
-                self._spec_draft_impl, static_argnames=("k",),
+            self._spec_draft = self._mesh_scoped(self._program(
+                "spec_draft", self._spec_draft_impl,
+                static_argnames=("k",),
                 donate_argnums=(1,), **draft_out),
                 rules=self._draft_rules)
-            self._draft_prefill = self._mesh_scoped(jax.jit(
-                self._draft_prefill_impl,
+            self._draft_prefill = self._mesh_scoped(self._program(
+                "draft_prefill", self._draft_prefill_impl,
                 static_argnames=("n", "bucket"), donate_argnums=(1,),
                 **draft_cache_only), rules=self._draft_rules)
         # Fused device sampler (paged and contiguous flavors): one
@@ -497,7 +505,8 @@ class DecodeEngine:
         # argmax vs categorical, the PRNG key derives from the step
         # counter in-program.
         if self._device_sampler:
-            self._decode_sampled = self._mesh_scoped(jax.jit(
+            self._decode_sampled = self._mesh_scoped(self._program(
+                "decode_sampled",
                 self._paged_decode_sampled_impl if self.paged
                 else self._decode_sampled_impl, donate_argnums=(1,),
                 **cache_out))
@@ -505,10 +514,13 @@ class DecodeEngine:
         self.tokens_out = 0
         # ---------------------------------------------- observability
         # SLO metrics + trace spans are per-REQUEST (terminal outcomes,
-        # admission, per-wave prefills) and the step recorder is one
-        # deque append per step — nothing here touches the per-token
-        # path, so the decode loop's cost is unchanged at steady state
-        # (bench_decode.py --sections trace_overhead pins <2%).
+        # admission, per-wave prefills) and the step recorder is about
+        # ten slices and one deque append per STEP — nothing here
+        # touches the per-token path. Measured on a TPU v5e (PERF.md,
+        # PR 24, InternLM2-1.8B at 14.5 active slots): the median
+        # decode step is 81.14 ms with the slices against 81.20 ms
+        # without them, no difference; the slices themselves cost
+        # under 50 us a step (tests/test_step_slices.py).
         from ray_tpu.serve.replica import replica_ident
         from ray_tpu.serve.steplog import StepTimeline
 
@@ -531,6 +543,24 @@ class DecodeEngine:
         self.handoffs_adopted = 0    # adopted seats completed
         self._handoff_phases: List[Dict[str, Any]] = []  # pending steplog
         #   phase rows, drained into the next _steplog_row
+
+    @staticmethod
+    def _program(name: str, impl, **jit_kwargs):
+        """``jax.jit(impl)`` under the stable name ``engine_<name>``,
+        ``name`` being the first element of the key the program is
+        dispatched under (``_dispatch_fresh``): the name a profiler
+        trace's per-program line and the lowered module carry, where
+        the method's own name (``_paged_decode_impl``) would change
+        with the next refactor. A name is metadata: the program and
+        its outputs are what they were."""
+        import jax
+
+        @functools.wraps(impl)
+        def program(*args, **kwargs):
+            return impl(*args, **kwargs)
+
+        program.__name__ = program.__qualname__ = f"engine_{name}"
+        return jax.jit(program, **jit_kwargs)
 
     def _mesh_scoped(self, fn, rules=None):
         """Mesh engines trace every program inside the decode axis-rules
@@ -741,15 +771,37 @@ class DecodeEngine:
         toks = self._ld.sample_batch(logits, temps, key)
         return toks, cache
 
-    def _dispatch_fresh(self, key: tuple, call):
+    def _dispatch_fresh(self, key: tuple, call,
+                        then: Optional[str] = None, **attrs):
         """Dispatch one of the engine's donated programs, marking the
         FIRST dispatch of each program key as a compile in the step
         log. The compile goes through JAX's persistent cache like any
         other program: jax/jaxlib 0.9.0 reload donated executables
         correctly (compile, exit, reload in a new process, identical
-        results — checked on the CPU backend and on a TPU v5e)."""
+        results — checked on the CPU backend and on a TPU v5e).
+
+        With the step log on, the dispatch is the step's ``launch``
+        slice (the upload of its arguments until the call returns),
+        tagged ``program=key[0]`` — or the ROLE the caller names, where
+        one program serves two (``prefill_chunk``) — and ``attrs``. A
+        dispatch whose output nobody fetches names in ``then`` the
+        slice the step goes on with."""
         self._mark_compile(key)
-        return call()
+        if not self.steplog.enabled:
+            return call()
+        attrs.setdefault("program", key[0])
+        self.steplog.begin("launch", **attrs)
+        out = call()
+        if then is not None:
+            self.steplog.begin(then)
+        return out
+
+    def _slice(self, name: str, **attrs: Any) -> None:
+        """The step goes on in slice ``name`` (``serve/steplog.py``).
+        Round a blocking ``np.array(...)`` it is ``fetch`` before — the
+        host waits for the device there — and the next slice after."""
+        if self.steplog.enabled:
+            self.steplog.begin(name, **attrs)
 
     # --------------------------------------------- paged page accounting
 
@@ -862,9 +914,6 @@ class DecodeEngine:
         self._draft_slot_pages[slot] = []
         self._draft_bt[slot, :] = 0
         self._draft_committed[slot] = -1
-        if self.steplog.enabled:
-            self.steplog.event("spec-draftless",
-                               request=req.request_id)
 
     def _draft_evict_one(self, keep: int) -> bool:
         """Make room in the draft pool by demoting the youngest OTHER
@@ -914,6 +963,12 @@ class DecodeEngine:
         if not cands:
             return False
         slot, req = max(cands, key=lambda it: it[1].submitted_at)
+        # What the preemption throws away, counted before the slot goes:
+        # the prompt tokens this seat prefilled (a spliced prefix cost
+        # nothing) and the pages it held.
+        discarded = (req.prefilled if slot in self._prefilling
+                     else len(req.tokens)) - req.prefix_len
+        held = len(self._slot_pages[slot]) if self.paged else 0
         self._active.pop(slot, None)
         self._prefilling.pop(slot, None)
         self._release_slot(slot)
@@ -933,7 +988,8 @@ class DecodeEngine:
             smetrics.PREEMPTIONS.inc(1.0, self._mtags)
         if self.steplog.enabled:
             self.steplog.event("preempt", request=req.request_id,
-                               tokens=req.generated)
+                               tokens=req.generated,
+                               prefilled=discarded, pages=held)
         if self._obs_spans and req.trace is not None:
             from ray_tpu.util import tracing
 
@@ -1384,11 +1440,9 @@ class DecodeEngine:
             lambda: self._adopt_pages(
                 self.cache, jnp.asarray(k_pad), jnp.asarray(v_pad),
                 jnp.asarray(ids), jnp.asarray([slot], np.int32),
-                jnp.asarray([clen], np.int32), width=width))
-        self._wave_span("adopt", t0, [req], pages=len(pages))
+                jnp.asarray([clen], np.int32), width=width),
+            then="admit")
         if self.steplog.enabled:
-            self.steplog.event("handoff-adopt", slot=slot,
-                               pages=len(pages), committed=clen)
             self._handoff_phases.append(
                 {"phase": "handoff", "t0": t0, "t1": time.time(),
                  "slot": slot, "pages": len(pages)})
@@ -1407,7 +1461,8 @@ class DecodeEngine:
         now = time.monotonic()
         self._tokens_dev = None
         tok = int(req.adopt["first_token"])
-        req.first_token_at = now
+        if req.first_token_at is None:
+            req.first_token_at = now
         self._emit(req, tok)
         self._tokens[slot] = tok
         self._active[slot] = req
@@ -1455,8 +1510,11 @@ class DecodeEngine:
                 lambda: self._paged_prefill(
                     self.params, self.cache, jnp.asarray(rows),
                     jnp.asarray(lengths), jnp.asarray(bt),
-                    jnp.asarray(slot_ids), n=n, bucket=bucket))
+                    jnp.asarray(slot_ids), n=n, bucket=bucket),
+                tokens=sum(len(r.tokens) for r in group))
+            self._slice("fetch", program="paged_prefill")
             logits = np.array(logits)
+            self._slice("admit")
             self._wave_span("prefill", t0, group, n=len(group),
                             bucket=bucket)
             self._post_admit(group, [r.slot for r in group], logits)
@@ -1511,8 +1569,11 @@ class DecodeEngine:
                     self.params, self.cache, jnp.asarray(rows),
                     jnp.asarray(plens), jnp.asarray(lengths),
                     jnp.asarray(bt), jnp.asarray(slot_ids),
-                    n=n, bucket=bucket, width=width))
+                    n=n, bucket=bucket, width=width),
+                tokens=sum(len(r.tokens) - r.prefix_len for r in group))
+            self._slice("fetch", program="paged_suffix")
             logits = np.array(logits)
+            self._slice("admit")
             self._wave_span("suffix-prefill", t0, group, n=len(group),
                             bucket=bucket)
             self._post_admit(group, [r.slot for r in group], logits)
@@ -1560,7 +1621,8 @@ class DecodeEngine:
                 jnp.asarray([req.prefilled], np.int32),
                 jnp.asarray([req.prefilled + step_tok], np.int32),
                 jnp.asarray(bt), jnp.asarray([slot], np.int32),
-                n=1, bucket=bucket, width=width))
+                n=1, bucket=bucket, width=width),
+            then="admit", program="prefill_chunk", tokens=step_tok)
         self.prefill_chunks += 1
         self._wave_span("prefill-chunk", t0, [req], tokens=step_tok,
                         prefilled=req.prefilled + step_tok,
@@ -1568,7 +1630,10 @@ class DecodeEngine:
         req.prefilled += step_tok
         if req.prefilled >= len(req.tokens):
             self._prefilling.pop(slot)
-            self._post_admit([req], [slot], np.array(logits))
+            self._slice("fetch", program="prefill_chunk")
+            logits = np.array(logits)
+            self._slice("admit")
+            self._post_admit([req], [slot], logits)
 
     def _retire(self, req: _Request, status: str) -> None:
         """Terminal exit for a request that never held a slot."""
@@ -1665,8 +1730,11 @@ class DecodeEngine:
                 lambda: self._prefill_many(
                     self.params, self.cache, jnp.asarray(rows),
                     jnp.asarray(lengths), jnp.asarray(slot_ids),
-                    n=n, bucket=bucket))
+                    n=n, bucket=bucket),
+                tokens=sum(len(r.tokens) for r in group))
+            self._slice("fetch", program="prefill")
             logits = np.array(logits)
+            self._slice("admit")
             self._wave_span("prefill", t0, group, n=len(group),
                             bucket=bucket)
             self._post_admit(group, slots, logits)
@@ -1715,8 +1783,11 @@ class DecodeEngine:
                     self._pool["v"], jnp.asarray(entries),
                     jnp.asarray(slot_ids), jnp.asarray(rows),
                     jnp.asarray(plens), jnp.asarray(lengths),
-                    n=n, bucket=bucket))
+                    n=n, bucket=bucket),
+                tokens=sum(len(r.tokens) - r.prefix_len for r in group))
+            self._slice("fetch", program="suffix")
             logits = np.array(logits)
+            self._slice("admit")
             self._wave_span("suffix-prefill", t0, group, n=len(group),
                             bucket=bucket)
             for req in group:
@@ -1745,7 +1816,10 @@ class DecodeEngine:
         for i, req in enumerate(group):
             tok = self._sample_host(logits[i], req)
             req.slot = slots[i]
-            req.first_token_at = now
+            if req.first_token_at is None:
+                # Set once: a preempted request comes through here
+                # again, and its first token was long delivered.
+                req.first_token_at = now
             if req.prefill_only:
                 # Disaggregated prefill terminal: the deliverable is the
                 # slot's filled pages + the sampled first token, not an
@@ -1778,7 +1852,8 @@ class DecodeEngine:
                             ("pool_insert",),
                             lambda: self._pool_insert(
                                 self.cache, self._pool["k"],
-                                self._pool["v"], slot, row))
+                                self._pool["v"], slot, row),
+                            then="admit")
         if self.spec:
             self._draft_seat([r for r in group if not r.done.is_set()])
 
@@ -1807,8 +1882,6 @@ class DecodeEngine:
         }
         self.handoffs_published += 1
         if self.steplog.enabled:
-            self.steplog.event("handoff", slot=slot, pages=len(ids),
-                               nbytes=req.handoff["nbytes"])
             self._handoff_phases.append(
                 {"phase": "handoff", "t0": t0, "t1": time.time(),
                  "slot": slot, "pages": int(len(ids))})
@@ -1854,7 +1927,6 @@ class DecodeEngine:
         rows = np.zeros((1, bucket), np.int32)
         rows[0, :len(seq)] = seq
         bt = self._draft_bt[slot:slot + 1, :wp]
-        t0 = time.time()
         self._draft_cache = self._dispatch_fresh(
             ("draft_prefill", 1, bucket),
             lambda: self._draft_prefill(
@@ -1862,9 +1934,9 @@ class DecodeEngine:
                 jnp.asarray(rows),
                 jnp.asarray([len(seq)], np.int32),
                 jnp.asarray(bt), jnp.asarray([slot], np.int32),
-                n=1, bucket=bucket))
+                n=1, bucket=bucket),
+            then="admit", tokens=len(seq))
         self._draft_committed[slot] = len(seq)
-        self._wave_span("draft-prefill", t0, [req], tokens=len(seq))
         return True
 
     def _draft_resync(self, slot: int, req: _Request) -> bool:
@@ -2035,18 +2107,26 @@ class DecodeEngine:
         When the step recorder is on (``decode_step_timeline``), the
         step's phases (admission prefills, interleaved prefill chunk,
         decode) land as one ring row with batch occupancy — the "why
-        was this token slow" record. Recording costs a few clock reads
-        and one deque append per STEP; with the ring off this path is
-        byte-identical to the uninstrumented loop."""
+        was this token slow" record — and the row's ``slices`` say
+        where the HOST's time went: ``park`` (since the previous step
+        ended), then ``reap``, ``admit``, ``pages``, ``launch``,
+        ``fetch``, ``sample_emit`` and ``finish`` tile the step with no
+        hole (``serve/steplog.py``). Recording costs one clock read and
+        one profiler annotation per slice and one deque append per
+        STEP; with the ring off this path is the uninstrumented
+        loop."""
         import jax.numpy as jnp
 
         rec = self.steplog.enabled
+        sl = self.steplog
         phases: List[Dict[str, Any]] = []
-        t_step0 = time.time() if rec else 0.0
+        t_step0 = sl.step_begin() if rec else 0.0
         if rec:
             w0 = self._prefill_waves
             c0 = self.prefill_chunks
         self._reap()
+        if rec:
+            sl.begin("admit")
         self._admit()
         if rec and self._prefill_waves > w0:
             phases.append({"phase": "admit", "t0": t_step0,
@@ -2066,6 +2146,8 @@ class DecodeEngine:
             # static across the draft/verify calls). The target ensure
             # may preempt the youngest request; the draft ensure only
             # ever demotes draft seats.
+            if rec:
+                sl.begin("pages")
             self._ensure_decode_pages(self.spec_k + 1)
             if not self._active:
                 self._steplog_row(t_step0, phases)
@@ -2073,6 +2155,8 @@ class DecodeEngine:
             self._ensure_draft_pages(self.spec_k)
             if self._spec_ready():
                 return self._spec_step(t_step0, phases, rec)
+        if rec:
+            sl.begin("pages")
         chunk = self._pick_chunk()
         if self.paged:
             # Page the next k tokens in BEFORE the program runs: the
@@ -2084,6 +2168,7 @@ class DecodeEngine:
                 return 0
             chunk = min(chunk, self._pick_chunk())
         stepped = len(self._active)
+        ctx = self._ctx_tokens() if rec else None
         if chunk > 1:
             t_d0 = time.time() if rec else 0.0
             if self.paged:
@@ -2092,14 +2177,20 @@ class DecodeEngine:
                     lambda: self._decode_k(
                         self.params, self.cache,
                         jnp.asarray(self._tokens),
-                        jnp.asarray(self._block_tables), k=chunk))
+                        jnp.asarray(self._block_tables), k=chunk),
+                    batch=stepped, ctx_tokens=ctx)
             else:
                 toks, self.cache = self._dispatch_fresh(
                     ("decode_k", chunk),
                     lambda: self._decode_k(
                         self.params, self.cache,
-                        jnp.asarray(self._tokens), k=chunk))
+                        jnp.asarray(self._tokens), k=chunk),
+                    batch=stepped, ctx_tokens=ctx)
+            if rec:
+                sl.begin("fetch", program="decode_k")
             toks = np.array(toks)  # (chunk, slots)
+            if rec:
+                sl.begin("sample_emit")
             if rec:
                 phases.append({"phase": "decode", "t0": t_d0,
                                "t1": time.time(), "batch": stepped,
@@ -2114,26 +2205,32 @@ class DecodeEngine:
                     if req.generated >= req.max_new_tokens or (
                             req.eos_id is not None
                             and tok == req.eos_id):
-                        self._finish(slot)
+                        self._finish_in_step(slot)
                         break
-            self._steplog_row(t_step0, phases)
+            self._steplog_row(t_step0, phases, ctx)
             return stepped
         if self._device_sampler:
-            return self._sampled_step(t_step0, phases, rec)
+            return self._sampled_step(t_step0, phases, rec, ctx)
         t_d0 = time.time() if rec else 0.0
         if self.paged:
             logits, self.cache = self._dispatch_fresh(
                 ("decode",),
                 lambda: self._decode(
                     self.params, self.cache, jnp.asarray(self._tokens),
-                    jnp.asarray(self._block_tables)))
+                    jnp.asarray(self._block_tables)),
+                batch=stepped, ctx_tokens=ctx)
         else:
             logits, self.cache = self._dispatch_fresh(
                 ("decode",),
                 lambda: self._decode(
                     self.params, self.cache,
-                    jnp.asarray(self._tokens)))
+                    jnp.asarray(self._tokens)),
+                batch=stepped, ctx_tokens=ctx)
+        if rec:
+            sl.begin("fetch", program="decode")
         logits = np.array(logits)
+        if rec:
+            sl.begin("sample_emit")
         if rec:
             phases.append({"phase": "decode", "t0": t_d0,
                            "t1": time.time(), "batch": stepped, "k": 1})
@@ -2145,9 +2242,23 @@ class DecodeEngine:
             self._tokens[slot] = tok
             if req.generated >= req.max_new_tokens or (
                     req.eos_id is not None and tok == req.eos_id):
-                self._finish(slot)
-        self._steplog_row(t_step0, phases)
+                self._finish_in_step(slot)
+        self._steplog_row(t_step0, phases, ctx)
         return stepped
+
+    def _ctx_tokens(self) -> int:
+        """KV positions the decode about to be dispatched really needs:
+        the active slots' context lengths, the new token included. What
+        the capacity-wide gather reads beyond it is waste."""
+        return sum(r.prompt_len + r.generated
+                   for r in self._active.values())
+
+    def _finish_in_step(self, slot: int) -> None:
+        """``_finish`` from a step's per-slot loop, as the ``finish``
+        slice; the loop goes on in ``sample_emit``."""
+        self._slice("finish")
+        self._finish(slot)
+        self._slice("sample_emit")
 
     def _spec_ready(self) -> bool:
         """Spec rounds engage only when every active request is greedy
@@ -2179,6 +2290,7 @@ class DecodeEngine:
 
         k = self.spec_k
         stepped = len(self._active)
+        ctx = self._ctx_tokens() if rec else None
         # ---- draft: bounded catch-up rows + k proposals per slot
         catchup = np.zeros((self.slots, 2), np.int32)
         clens = np.ones((self.slots,), np.int32)
@@ -2204,10 +2316,13 @@ class DecodeEngine:
             lambda: self._spec_draft(
                 self._draft_params, self._draft_cache,
                 jnp.asarray(catchup), jnp.asarray(clens),
-                jnp.asarray(self._draft_bt), k=k))
+                jnp.asarray(self._draft_bt), k=k),
+            batch=stepped)
+        self._slice("fetch", program="spec_draft")
         # np.array (never asarray): the next donated dispatch must not
         # clobber an aliased host view of these tokens (PR 14 pin).
         toks_d = np.array(toks_d)                          # (slots, k)
+        self._slice("launch", program="spec_verify")  # ... its rows
         if rec:
             phases.append({"phase": "draft", "t0": t_d0,
                            "t1": time.time(), "batch": stepped, "k": k})
@@ -2221,8 +2336,11 @@ class DecodeEngine:
             ("spec_verify", k),
             lambda: self._spec_verify(
                 self.params, self.cache, jnp.asarray(rows),
-                jnp.asarray(self._block_tables)))
+                jnp.asarray(self._block_tables)),
+            batch=stepped, ctx_tokens=ctx)
+        self._slice("fetch", program="spec_verify")
         toks_v = np.array(toks_v)                          # (slots, k+1)
+        self._slice("sample_emit")
         # ---- host: longest-matching-prefix acceptance + rollback
         self.steps += 1
         self.spec_rounds += 1
@@ -2254,7 +2372,8 @@ class DecodeEngine:
                     finished = True
                     break
             if finished:
-                self._finish(slot)  # frees both pools' tails wholesale
+                # frees both pools' tails wholesale
+                self._finish_in_step(slot)
                 continue
             committed = L + emitted
             if self._draft_committed[slot] >= 0:
@@ -2276,11 +2395,12 @@ class DecodeEngine:
             phases.append({"phase": "verify", "t0": t_v0,
                            "t1": time.time(), "batch": stepped, "k": k,
                            "accepted": round_accepted})
-        self._steplog_row(t_step0, phases)
+        self._steplog_row(t_step0, phases, ctx)
         return stepped
 
     def _sampled_step(self, t_step0: float,
-                      phases: List[Dict[str, Any]], rec: bool) -> int:
+                      phases: List[Dict[str, Any]], rec: bool,
+                      ctx: Optional[int]) -> int:
         """Single decode step with sampling fused into the device
         program: the (slots, vocab) logits never cross the host
         boundary — only (slots,) token ids do — and consecutive sampled
@@ -2303,14 +2423,18 @@ class DecodeEngine:
                 lambda: self._decode_sampled(
                     self.params, self.cache, tin,
                     jnp.asarray(self._block_tables), jnp.asarray(temps),
-                    jnp.asarray(self.steps, jnp.int32)))
+                    jnp.asarray(self.steps, jnp.int32)),
+                batch=stepped, ctx_tokens=ctx)
         else:
             toks_dev, self.cache = self._dispatch_fresh(
                 ("decode_sampled",),
                 lambda: self._decode_sampled(
                     self.params, self.cache, tin, jnp.asarray(temps),
-                    jnp.asarray(self.steps, jnp.int32)))
+                    jnp.asarray(self.steps, jnp.int32)),
+                batch=stepped, ctx_tokens=ctx)
+        self._slice("fetch", program="decode_sampled")
         toks = np.array(toks_dev)  # np.array: next dispatch donates
+        self._slice("sample_emit")
         self._tokens_dev = toks_dev
         if rec:
             phases.append({"phase": "decode", "t0": t_d0,
@@ -2324,12 +2448,12 @@ class DecodeEngine:
             self._tokens[slot] = tok
             if req.generated >= req.max_new_tokens or (
                     req.eos_id is not None and tok == req.eos_id):
-                self._finish(slot)
-        self._steplog_row(t_step0, phases)
+                self._finish_in_step(slot)
+        self._steplog_row(t_step0, phases, ctx)
         return stepped
 
-    def _steplog_row(self, t0: float, phases: List[Dict[str, Any]]
-                     ) -> None:
+    def _steplog_row(self, t0: float, phases: List[Dict[str, Any]],
+                     ctx_tokens: Optional[int] = None) -> None:
         """Close the step's timeline row; idle steps with no phases and
         no pending events record nothing (an idle engine must not churn
         useful rows out of the bounded ring)."""
@@ -2339,15 +2463,21 @@ class DecodeEngine:
             # row shows the handoff slice of the step.
             phases = phases + self._handoff_phases
             self._handoff_phases = []
-        if not self.steplog.enabled or not (phases
-                                            or self.steplog.pending_events):
+        if not self.steplog.enabled:
+            return
+        if not (phases or self.steplog.pending_events):
+            self.steplog.park(len(self._active))
             return
         self.steplog.record(
             self.steps, t0, time.time(), phases,
             active=len(self._active), prefilling=len(self._prefilling),
             queued=max(0, self._pending.qsize() + len(self._requeue)
                        - self._queued_cancelled),
-            pages_free=self._pages.free_count if self.paged else None)
+            pages_free=self._pages.free_count if self.paged else None,
+            pages_pinned=(self.prefix.pinned_pages
+                          if self.paged and self.prefix is not None
+                          else None),
+            ctx_tokens=ctx_tokens)
 
     def warmup(self) -> None:
         """Pre-dispatch the step-loop programs (decode, the chunk grid,

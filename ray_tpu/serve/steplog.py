@@ -9,12 +9,28 @@ the round's accepted-token count — and on a disaggregated decode fleet
 ``handoff``, the adopt splice of a prefill fleet's published KV pages,
 tagged with the page count) and batch occupancy, plus
 the discrete events that explain latency cliffs — page alloc/free,
-recompute preemption, draft-seat demotions (``spec-draftless``), jit
-compiles (first dispatch of a program key).
+recompute preemption (with the prompt tokens and pages it throws
+away), jit compiles (first dispatch of a program key).
 
-Recording is a deque append + a few ``monotonic()`` reads per STEP
-(never per token), so the decode loop pays microseconds against a
-device call that costs milliseconds. The ring is host memory only; it
+Beside the phases a row carries ``slices``: named host intervals that
+TILE the step with no hole (``reap``, ``admit``, ``pages``, ``launch``,
+``fetch``, ``sample_emit``, ``finish``), led by ``park``, the time from
+the end of the previous step to this one's start. Phases say what the
+device was asked to do; slices say where the host's time went, so an
+idle gap of the device has an owner. Every slice is also entered as a
+``jax.profiler.TraceAnnotation("engine:<name>")``: in a profiler trace
+the same slices lie on the profiler's clock beside the device
+operations (attributes such as ``program`` and ``tokens`` become the
+event's stats), and the jitted programs there are named
+``jit_engine_<program>``. A row also counts what the step worked on:
+``ctx_tokens`` (KV positions the decode really needs) and
+``pages_pinned`` (pages the prefix index holds).
+
+Recording is a deque append + one ``time.time()`` read and one
+annotation per slice, per STEP (never per token), so the decode loop
+pays microseconds against a device call that costs milliseconds
+(``tests/test_step_slices.py`` holds it under 50 us a step).
+The ring is host memory only; it
 is dumped on demand through ``engine.timeline()`` -> the replica RPC ->
 ``python -m ray_tpu timeline --serve``, which merges every replica's
 rows into the cross-process Chrome trace.
@@ -37,13 +53,24 @@ class StepTimeline:
     snapshots via list() which is atomic enough for a diagnostic read
     from the actor RPC thread (rows are immutable once appended)."""
 
-    __slots__ = ("capacity", "_rows", "_events", "dropped")
+    __slots__ = ("capacity", "_rows", "_events", "dropped", "_slices",
+                 "_open", "_ann", "_annotate")
 
     def __init__(self, capacity: int = 256):
         self.capacity = max(0, int(capacity))
         self._rows: deque = deque(maxlen=self.capacity or None)
         self._events: List[Dict[str, Any]] = []  # pending, next row's
         self.dropped = 0
+        self._slices: List[Dict[str, Any]] = []  # the row being built
+        self._open: Optional[Dict[str, Any]] = None  # slice being timed
+        self._ann = None  # ... and its annotation on the profiler's clock
+        self._annotate = None
+        if self.capacity:
+            # Only a recording engine pays the import; the timeline CLI
+            # renders dumps without JAX.
+            from jax.profiler import TraceAnnotation
+
+            self._annotate = TraceAnnotation
 
     @property
     def enabled(self) -> bool:
@@ -65,14 +92,61 @@ class StepTimeline:
             e.update(attrs)
         self._events.append(e)
 
+    # ------------------------------------------------------------ slices
+
+    def begin(self, name: str, **attrs: Any) -> float:
+        """End the open slice and start ``name``, on one clock read, so
+        consecutive slices tile with no hole. Returns that instant.
+        ``attrs`` ride on the slice and on its annotation. Callers gate
+        on ``enabled``, as they do for events."""
+        now = time.time()
+        self._switch(name, now, attrs)
+        return now
+
+    def _switch(self, name: str, now: float, attrs: Dict[str, Any]
+                ) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if self._open is not None:
+            self._open["t1"] = now
+        s = {"name": name, "t0": now, "t1": now}
+        if attrs:
+            s.update(attrs)
+        self._slices.append(s)
+        self._open = s
+        self._ann = ann = self._annotate("engine:" + name, **attrs)
+        ann.__enter__()
+
+    def step_begin(self) -> float:
+        """Start a step's row: ``park`` (open since the last step ended)
+        closes and ``reap`` opens at the returned instant, the row's
+        ``t0``. Slices begun outside a step (warm-up dispatches) are
+        annotated but belong to no row."""
+        park = self._open
+        now = self.begin("reap")
+        self._slices = [self._open]
+        if park is not None and park["name"] == "park":
+            self._slices.insert(0, park)
+        return now
+
+    def park(self, active: int = 0) -> None:
+        """End a step that records no row: its slices are dropped and
+        ``park`` opens, as ``record`` opens it after a row."""
+        self._slices = []
+        self._switch("park", time.time(), {"active": active})
+
     # -------------------------------------------------------------- rows
 
     def record(self, step: int, t0: float, t1: float, phases:
                List[Dict[str, Any]], active: int, prefilling: int,
-               queued: int, pages_free: Optional[int] = None) -> None:
+               queued: int, pages_free: Optional[int] = None,
+               pages_pinned: Optional[int] = None,
+               ctx_tokens: Optional[int] = None) -> None:
         """One engine step: ``phases`` are the step's timed sub-slices
         ([{phase, t0, t1, ...attrs}]); occupancy is sampled at the step
-        boundary; queued events ride along and clear."""
+        boundary; queued events ride along and clear. The slices begun
+        since ``step_begin`` close at ``t1`` and ride along too, and
+        ``park`` opens for the time until the next step."""
         if not self.capacity:
             self._events.clear()
             return
@@ -83,6 +157,14 @@ class StepTimeline:
                "queued": queued}
         if pages_free is not None:
             row["pages_free"] = pages_free
+        if pages_pinned is not None:
+            row["pages_pinned"] = pages_pinned
+        if ctx_tokens is not None:
+            row["ctx_tokens"] = ctx_tokens
+        if self._open is not None:
+            self._switch("park", t1, {"active": active})
+            row["slices"] = self._slices[:-1]
+            self._slices = self._slices[-1:]
         if self._events:
             row["events"] = self._events
             self._events = []
@@ -96,10 +178,21 @@ class StepTimeline:
 def timeline_chrome_events(dump: Dict[str, Any], pid: str
                            ) -> List[Dict[str, Any]]:
     """Render one engine's timeline dump as Chrome trace events: phase
-    slices on an ``engine-step`` track, occupancy as counters, discrete
-    events as instants. Shared by the timeline CLI and trace-demo."""
+    slices on an ``engine-step`` track, the host slices that tile the
+    step on an ``engine-host`` track below it, occupancy as counters,
+    discrete events as instants. Shared by the timeline CLI and
+    trace-demo."""
     out: List[Dict[str, Any]] = []
     for row in dump.get("rows", []):
+        for sl in row.get("slices", []):
+            out.append({
+                "name": sl["name"], "cat": "engine-host", "ph": "X",
+                "ts": sl["t0"] * 1e6,
+                "dur": max(0.0, (sl["t1"] - sl["t0"]) * 1e6),
+                "pid": pid, "tid": "engine-host",
+                "args": {k: v for k, v in sl.items()
+                         if k not in ("name", "t0", "t1")},
+            })
         for ph in row.get("phases", []):
             out.append({
                 "name": ph.get("phase", "step"),

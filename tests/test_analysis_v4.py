@@ -439,7 +439,8 @@ def test_mutation_decode_unwrapped_dispatch():
                 ("decode_sampled",),
                 lambda: self._decode_sampled(
                     self.params, self.cache, tin, jnp.asarray(temps),
-                    jnp.asarray(self.steps, jnp.int32)))""",
+                    jnp.asarray(self.steps, jnp.int32)),
+                batch=stepped, ctx_tokens=ctx)""",
         """toks_dev, self.cache = self._decode_sampled(
                 self.params, self.cache, tin, jnp.asarray(temps),
                 jnp.asarray(self.steps, jnp.int32))""")

@@ -1,0 +1,436 @@
+"""PR 24: the engine step tells its own time. A row's ``slices`` tile the
+step on the step log's clock (and are ``engine:<name>`` annotations on the
+profiler's), the phases stay what they were, the ``preempt`` event counts
+what it throws away, ``first_token_at`` is set once, the jitted programs and
+the kernels carry stable names, and the whole of it costs microseconds a
+step. All CPU, tiny configs: tier-1."""
+
+import re
+import time
+
+import numpy as np
+import pytest
+
+SLICE_NAMES = {"park", "reap", "admit", "pages", "launch", "fetch",
+               "sample_emit", "finish"}
+
+
+def _tiny(max_seq_len=512):
+    import jax
+
+    from ray_tpu.models import llama
+
+    cfg = llama.LlamaConfig(vocab_size=61, dim=32, n_layers=2, n_heads=4,
+                            n_kv_heads=2, mlp_dim=64,
+                            max_seq_len=max_seq_len)
+    return cfg, llama.init_params(cfg, jax.random.key(0))
+
+
+def _engine(**kw):
+    from ray_tpu.serve.decode import DecodeEngine
+
+    cfg, params = _tiny()
+    args = dict(slots=4, capacity=256, page_tokens=16, pool_pages=40,
+                prefill_bucket=16, prefix_pool_entries=0)
+    args.update(kw)
+    return cfg, DecodeEngine(params, cfg, **args)
+
+
+def _drive(eng, reqs, budget=3000):
+    for _ in range(budget):
+        if all(r.done.is_set() for r in reqs):
+            return
+        eng.step()
+    raise AssertionError(f"not done: {[r.status for r in reqs]}")
+
+
+def _prompts(cfg, lengths, seed=7):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+
+
+SCENARIOS = {
+    # name: (engine arguments, prompt lengths, new tokens, submit arguments)
+    "admission": ({}, [10, 30, 12], 12, {}),
+    "admission_wave_of_equal_prompts": ({}, [12, 12, 12, 12], 6, {}),
+    "chunked_prefill": ({"prefill_chunk_tokens": 32}, [100, 20, 70], 10, {}),
+    "preemption": ({"pool_pages": 20}, [30, 30, 30, 30], 90, {}),
+    "preemption_mid_prefill": (
+        {"pool_pages": 12, "prefill_chunk_tokens": 32}, [10, 70, 30, 90],
+        20, {}),
+    "sampled_path": ({"device_sampler": True}, [10, 30], 12,
+                     {"temperature": 0.7}),
+    "decode_chunks": ({"decode_chunk": 4}, [10, 14], 16, {}),
+    "contiguous": ({"page_tokens": 0}, [10, 30, 12], 8, {}),
+}
+
+
+def _run(name):
+    kw, lengths, new, sub = SCENARIOS[name]
+    cfg, eng = _engine(**kw)
+    reqs = [eng.submit(p, max_new_tokens=new, **sub)
+            for p in _prompts(cfg, lengths)]
+    _drive(eng, reqs)
+    rows = eng.steplog.dump()["rows"]
+    eng.shutdown()
+    return eng, reqs, rows
+
+
+@pytest.mark.parametrize("name", sorted(SCENARIOS))
+def test_slices_tile_the_step_with_no_hole_or_overlap(name):
+    eng, reqs, rows = _run(name)
+    assert rows and all(r.status == "completed" for r in reqs)
+    if name.startswith("preemption"):
+        assert eng.preempted > 0
+    seen = set()
+    for i, row in enumerate(rows):
+        slices = row["slices"]
+        seen |= {s["name"] for s in slices}
+        assert {s["name"] for s in slices} <= SLICE_NAMES
+        body = [s for s in slices if s["name"] != "park"]
+        # ``park`` leads, outside the row: previous step's end to t0.
+        assert slices[:len(slices) - len(body)] == [
+            s for s in slices if s["name"] == "park"][:1]
+        if slices[0]["name"] == "park":
+            assert slices[0]["t1"] == row["t0"]
+            assert slices[0]["t0"] <= slices[0]["t1"]
+            if i:
+                assert slices[0]["t0"] >= rows[i - 1]["t1"]
+        assert body[0]["name"] == "reap"
+        assert body[0]["t0"] == row["t0"] and body[-1]["t1"] == row["t1"]
+        for a, b in zip(body, body[1:]):
+            assert a["t1"] == b["t0"] and a["t0"] <= a["t1"]
+        total = sum(s["t1"] - s["t0"] for s in body)
+        assert total == pytest.approx(row["t1"] - row["t0"], abs=1e-9)
+        # Every device call of the step is a launch, tagged by program.
+        for s in body:
+            if s["name"] in ("launch", "fetch"):
+                assert isinstance(s["program"], str) and s["program"]
+    assert {"reap", "admit", "launch", "fetch", "sample_emit",
+            "finish"} <= seen
+    assert ("pages" in seen) and ("park" in seen)
+
+
+@pytest.mark.parametrize("name", ["admission", "chunked_prefill",
+                                  "sampled_path"])
+def test_phases_are_what_they_were(name):
+    _, _, rows = _run(name)
+    kinds = set()
+    for row in rows:
+        for ph in row["phases"]:
+            kinds.add(ph["phase"])
+            assert row["t0"] <= ph["t0"] <= ph["t1"] <= row["t1"]
+            if ph["phase"] == "admit":
+                # From the step's start, over reap and the whole admission.
+                assert ph["t0"] == row["t0"] and ph["waves"] >= 1
+            if ph["phase"] == "decode":
+                assert ph["k"] == 1 and ph["batch"] >= 1
+                run = [s for s in row["slices"] if s.get("program") in (
+                    "decode", "decode_sampled")]
+                # From before the dispatch to after the blocking fetch.
+                assert [s["name"] for s in run] == ["launch", "fetch"]
+                assert ph["t0"] <= run[0]["t0"] and run[1]["t1"] <= ph["t1"]
+                if name == "sampled_path":
+                    assert ph["sampler"] == "device"
+    assert kinds == ({"admit", "decode", "prefill_chunk"}
+                     if name == "chunked_prefill" else {"admit", "decode"})
+    assert all(set(r) >= {"step", "t0", "t1", "phases", "active",
+                          "prefilling", "queued", "pages_free"}
+               for r in rows)
+
+
+def test_launch_slices_say_program_role_and_tokens():
+    _, reqs, rows = _run("chunked_prefill")
+    launches = [s for r in rows for s in r["slices"]
+                if s["name"] == "launch"]
+    by = {}
+    for s in launches:
+        by.setdefault(s["program"], []).append(s)
+    # 100 and 70 tokens go in chunks of 32 (one jitted program,
+    # ``paged_suffix``, in the ROLE ``prefill_chunk``); 20 fits one wave.
+    assert sorted(s["tokens"] for s in by["prefill_chunk"]) == sorted(
+        [32, 32, 32, 4, 32, 32, 6])
+    assert [s["tokens"] for s in by["paged_prefill"]] == [20]
+    assert "paged_suffix" not in by
+    decode = by["decode"]
+    assert all(s["batch"] >= 1 and s["ctx_tokens"] >= s["batch"]
+               for s in decode)
+    # Row counters: the KV positions the decode needs, the pinned pages.
+    for r in rows:
+        d = [s for s in r["slices"] if s.get("program") == "decode"
+             and s["name"] == "launch"]
+        if d:
+            assert r["ctx_tokens"] == d[0]["ctx_tokens"]
+        assert "pages_pinned" not in r      # no prefix index here
+    # ctx_tokens is the contexts' sum, the new token included.
+    first = next(r for r in rows if "ctx_tokens" in r)
+    assert first["ctx_tokens"] == 20 + 1
+
+
+def test_pages_pinned_rides_on_rows_with_a_prefix_index():
+    cfg, eng = _engine(prefix_pool_entries=8)
+    shared = _prompts(cfg, [48])[0]
+    reqs = [eng.submit(shared + [i], max_new_tokens=4) for i in range(3)]
+    _drive(eng, reqs)
+    rows = eng.steplog.dump()["rows"]
+    assert all("pages_pinned" in r for r in rows)
+    assert max(r["pages_pinned"] for r in rows) >= 2
+    assert rows[-1]["pages_pinned"] == eng.prefix.pinned_pages
+    eng.shutdown()
+
+
+def test_preempt_event_counts_what_it_throws_away():
+    """One 64-token prompt decoding, then a 160-token prompt, both
+    prefilled in chunks of 32, against a pool that cannot hold both: the
+    younger one is preempted mid-prefill, and its chunks so far are the
+    discarded work."""
+    import sys
+
+    sys.path.insert(0, __file__.rsplit("/tests/", 1)[0])
+    from benchmarks import progtrace
+
+    cfg, eng = _engine(pool_pages=12, prefill_chunk_tokens=32)
+    old, young = _prompts(cfg, [64, 160])
+    r_old = eng.submit(old, max_new_tokens=120)
+    for _ in range(4):
+        eng.step()
+    r_young = eng.submit(young, max_new_tokens=4)
+    while not eng.preempted:
+        eng.step()
+    rows = eng.steplog.dump()["rows"]
+    ev = [e for r in rows for e in r.get("events", [])
+          if e["kind"] == "preempt"]
+    assert len(ev) == 1 and ev[0]["request"] == r_young.request_id
+    launched = sum(s["tokens"] for r in rows for s in r["slices"]
+                   if s["name"] == "launch"
+                   and s["program"] == "prefill_chunk")
+    thrown = launched - 64       # the older prompt's two chunks stay
+    assert ev[0]["tokens"] == 0                      # nothing emitted
+    assert ev[0]["prefilled"] == thrown > 0          # all of it redone
+    assert ev[0]["pages"] == -(-thrown // 16)
+    assert progtrace.prefill_useful_ratio(rows) == pytest.approx(
+        1 - thrown / launched)
+    # The slice that did it is ``pages``.
+    row = next(r for r in rows if any(e["kind"] == "preempt"
+                                      for e in r.get("events", [])))
+    pages = next(s for s in row["slices"] if s["name"] == "pages")
+    assert pages["t0"] <= ev[0]["ts"] <= pages["t1"]
+    eng.shutdown()
+    assert r_old.status != "failed"
+
+
+def test_first_token_at_survives_a_readmission():
+    cfg, eng = _engine(pool_pages=20)
+    reqs = [eng.submit(p, max_new_tokens=90)
+            for p in _prompts(cfg, [30] * 4)]
+    firsts = {}
+    for _ in range(3000):
+        if all(r.done.is_set() for r in reqs):
+            break
+        eng.step()
+        for r in reqs:
+            if r.first_token_at is not None:
+                firsts.setdefault(r.request_id, r.first_token_at)
+    again = [r for r in reqs if r.preemptions]
+    assert again and all(r.status == "completed" for r in reqs)
+    for r in reqs:
+        assert r.first_token_at == firsts[r.request_id]
+        assert r.submitted_at <= r.admitted_at <= r.first_token_at \
+            <= r.finished_at
+    eng.shutdown()
+
+
+def test_ring_off_records_and_annotates_nothing():
+    cfg, eng = _engine(step_timeline=0)
+    reqs = [eng.submit(p, max_new_tokens=6)
+            for p in _prompts(cfg, [10, 30])]
+    _drive(eng, reqs)
+    assert not eng.steplog.enabled and eng.steplog._annotate is None
+    assert eng.steplog._slices == [] and eng.steplog._open is None
+    assert eng.steplog.dump()["rows"] == []
+    eng.shutdown()
+
+
+def test_warmup_dispatches_belong_to_no_row():
+    cfg, eng = _engine()
+    eng.warmup()
+    reqs = [eng.submit(p, max_new_tokens=4) for p in _prompts(cfg, [10])]
+    _drive(eng, reqs)
+    first = eng.steplog.dump()["rows"][0]
+    assert first["slices"][0]["name"] == "reap"
+    assert first["slices"][0]["t0"] == first["t0"]
+    eng.shutdown()
+
+
+def test_timeline_renders_slices_on_a_second_track():
+    from ray_tpu.serve.steplog import timeline_chrome_events
+
+    _, _, rows = _run("admission")
+    ev = timeline_chrome_events({"rows": rows}, pid="engine:t")
+    host = [e for e in ev if e.get("tid") == "engine-host"]
+    assert {e["name"] for e in host} >= {"reap", "admit", "launch", "fetch",
+                                         "sample_emit", "park"}
+    launch = next(e for e in host if e["name"] == "launch")
+    assert launch["ph"] == "X" and launch["args"]["program"]
+    assert any(e.get("tid") == "engine-step" and e["name"] == "decode"
+               for e in ev)
+
+
+def test_slices_are_annotations_on_the_profilers_clock(tmp_path):
+    """What the step log records is also in a profiler trace, by name and
+    with the attributes as stats (read with JAX's own reader)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    cfg, eng = _engine(prefill_chunk_tokens=32)
+    warm = [eng.submit(p, max_new_tokens=3) for p in _prompts(cfg, [70, 9])]
+    _drive(eng, warm)
+    jax.profiler.start_trace(str(tmp_path))
+    reqs = [eng.submit(p, max_new_tokens=3) for p in _prompts(cfg, [70, 9])]
+    _drive(eng, reqs)
+    jax.profiler.stop_trace()
+    eng.shutdown()
+    path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0]
+    events = [(e.name, dict(e.stats))
+              for p in ProfileData.from_file(path).planes
+              for line in p.lines for e in line.events
+              if e.name.startswith("engine:")]
+    names = {n for n, _ in events}
+    assert names >= {"engine:" + s for s in (
+        "park", "reap", "admit", "pages", "launch", "fetch", "sample_emit",
+        "finish")}
+    programs = {st.get("program") for n, st in events
+                if n == "engine:launch"}
+    assert programs >= {"prefill_chunk", "paged_prefill", "decode"}
+    assert any(st.get("tokens") == 32 for n, st in events
+               if n == "engine:launch")
+    assert any(n.startswith("PjitFunction(engine_decode)")
+               for p in ProfileData.from_file(path).planes
+               for line in p.lines for n in {e.name for e in line.events})
+
+
+# ------------------------------------------------------------------ names
+
+
+def test_engine_programs_are_jitted_under_their_keys_name():
+    import jax.numpy as jnp
+
+    cfg, eng = _engine(prefill_chunk_tokens=32, device_sampler=True,
+                       decode_chunk=2)
+    toks = jnp.zeros((4,), jnp.int32)
+    bt = jnp.asarray(eng._block_tables)
+    one = jnp.zeros((1,), jnp.int32)
+    lowered = {
+        "decode": eng._decode.lower(eng.params, eng.cache, toks, bt),
+        "decode_k": eng._decode_k.lower(eng.params, eng.cache, toks, bt,
+                                        k=2),
+        "decode_sampled": eng._decode_sampled.lower(
+            eng.params, eng.cache, toks, bt, jnp.zeros((4,), jnp.float32),
+            jnp.asarray(0, jnp.int32)),
+        "paged_prefill": eng._paged_prefill.lower(
+            eng.params, eng.cache, jnp.zeros((1, 16), jnp.int32), one,
+            bt[:1, :1], one, n=1, bucket=16),
+        "paged_suffix": eng._paged_suffix.lower(
+            eng.params, eng.cache, jnp.zeros((1, 16), jnp.int32), one, one,
+            bt[:1, :2], one, n=1, bucket=16, width=2),
+    }
+    for key, low in lowered.items():
+        head = low.as_text().split("\n", 1)[0]
+        assert f"@jit_engine_{key} " in head, head
+    text = lowered["decode"].as_text(debug_info=True)
+    for scope in ("paged_gather", "paged_attn", "weight_cast"):
+        assert re.search(rf'loc\("[^"]*{scope}', text), scope
+    for key in ("paged_suffix", "decode_k"):
+        text = lowered[key].as_text(debug_info=True)
+        assert "paged_gather" in text and "paged_attn" in text
+    eng.shutdown()
+    # The contiguous engine's programs go by the same rule.
+    cfg, eng = _engine(page_tokens=0)
+    low = eng._decode.lower(eng.params, eng.cache, toks)
+    assert "@jit_engine_decode " in low.as_text().split("\n", 1)[0]
+    eng.shutdown()
+
+
+def test_spec_programs_are_named_and_scoped():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    cfg, params = _tiny()
+    pool = ld.init_page_pool(cfg, 8, 16)
+    bt = jnp.zeros((2, 4), jnp.int32)
+    low = jax.jit(lambda p, pool: ld.paged_verify(
+        p, jnp.zeros((2, 3), jnp.int32), pool, bt, cfg,
+        jnp.zeros((2,), jnp.int32))).lower(params, pool)
+    text = low.as_text(debug_info=True)
+    assert "paged_gather" in text and "paged_attn" in text
+
+
+def test_flash_kernels_and_the_train_step_carry_their_names():
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.parallel import train_step as ts
+    from ray_tpu.parallel.mesh import MeshSpec
+
+    q = jnp.zeros((1, 128, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=128,
+                               block_k=128).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).as_text(debug_info=True)
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert re.search(rf'loc\("[^"]*{name}', text), name
+    mesh = MeshSpec(data=1).build(jax.devices()[:1])
+    step = ts.build_train_step(lambda p, b: (p["w"] * b["x"]).sum(),
+                               optax.sgd(0.1), mesh)
+    p = {"w": jnp.ones((4,))}
+    low = step.lower(p, optax.sgd(0.1).init(p), {"x": jnp.ones((4,))})
+    assert "@jit_train_step " in low.as_text().split("\n", 1)[0]
+
+
+# ------------------------------------------------------------------- cost
+
+
+def test_slices_cost_under_50_us_a_step():
+    """Ten slices and a row, as a decode step with 32 active slots makes
+    them, against the same row without slices: the best of five rounds,
+    so a neighbour on the core does not decide it."""
+    from ray_tpu.serve.steplog import StepTimeline
+
+    phases = [{"phase": "decode", "t0": 0.0, "t1": 0.0, "batch": 32, "k": 1}]
+
+    def step(sl, sliced):
+        t0 = sl.step_begin() if sliced else time.time()
+        if sliced:
+            sl.begin("admit")
+            sl.begin("pages")
+            sl.begin("launch", program="decode", batch=32, ctx_tokens=9000)
+            sl.begin("fetch", program="decode")
+            sl.begin("sample_emit")
+            sl.begin("finish")
+            sl.begin("sample_emit")
+        sl.record(1, t0, time.time(), phases, active=32, prefilling=0,
+                  queued=0, pages_free=3, pages_pinned=80, ctx_tokens=9000)
+
+    def per_step_us(sliced, n=3000):
+        sl = StepTimeline(256)
+        for _ in range(200):
+            step(sl, sliced)
+        best = float("inf")
+        for _ in range(5):
+            a = time.perf_counter()
+            for _ in range(n):
+                step(sl, sliced)
+            best = min(best, (time.perf_counter() - a) / n * 1e6)
+        return best
+
+    cost = per_step_us(True) - per_step_us(False)
+    assert cost < 50.0, f"slices cost {cost:.1f} us a step"

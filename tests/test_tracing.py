@@ -128,15 +128,21 @@ def test_profile_cpu_flamegraph_of_live_worker(ray_start_regular):
         return hot_loop(_t.monotonic() + seconds)
 
     ref = burn.remote(4.0)
-    time.sleep(0.5)  # let the task start
     core = get_core_worker()
     nodes = core.controller.call("list_nodes")
-    workers = []
-    for n in nodes:
-        nc = RpcClient(tuple(n["addr"]))
-        workers += nc.call("list_workers")
-        nc.close()
-    busy = [w for w in workers if not w["idle"]]
+    # Poll for the busy worker: on a loaded machine the task may take
+    # seconds to start (a fixed 0.5 s wait failed in the suite's run).
+    busy, workers = [], []
+    deadline = time.monotonic() + 30.0
+    while not busy and time.monotonic() < deadline:
+        workers = []
+        for n in nodes:
+            nc = RpcClient(tuple(n["addr"]))
+            workers += nc.call("list_workers")
+            nc.close()
+        busy = [w for w in workers if not w["idle"]]
+        if not busy:
+            time.sleep(0.05)
     assert busy, workers
     wc = RpcClient(tuple(busy[0]["addr"]))
     folded = wc.call("profile_cpu", 1.5, 100.0, timeout=30.0)
