@@ -59,6 +59,9 @@ class _Request:
     generated: int = 0
     error: Optional[str] = None
     on_token_error: Optional[str] = None   # first on_token callback failure
+    on_done: Optional[Callable[["_Request"], None]] = None  # told the
+    #   moment the request ends, whichever way (and when on_token fails):
+    #   a stream wakes on it instead of polling ``done``
     submitted_at: float = field(default_factory=time.monotonic)
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
@@ -95,18 +98,26 @@ class _Request:
     admitted_at: Optional[float] = None    # first prefill dispatch
     preemptions: int = 0                   # times requeued by page pressure
 
-    def raise_for_status(self) -> None:
-        """Re-raise this request's terminal outcome as its typed error."""
+    def terminal_error(self) -> Optional[BaseException]:
+        """This request's terminal outcome as its typed error (None for
+        a request that completed, or has not ended)."""
         if self.status == "cancelled":
-            raise RequestCancelledError(
+            return RequestCancelledError(
                 f"request {self.request_id} cancelled after "
                 f"{self.generated} tokens")
         if self.status == "deadline_exceeded":
-            raise DeadlineExceededError(
+            return DeadlineExceededError(
                 f"request {self.request_id} exceeded its deadline after "
                 f"{self.generated} tokens")
         if self.error:
-            raise RuntimeError(self.error)
+            return RuntimeError(self.error)
+        return None
+
+    def raise_for_status(self) -> None:
+        """Re-raise this request's terminal outcome as its typed error."""
+        err = self.terminal_error()
+        if err is not None:
+            raise err
 
 
 class DecodeEngine:
@@ -255,6 +266,7 @@ class DecodeEngine:
         self._tokens = np.zeros((slots,), np.int32)
         self._rng = np.random.default_rng(0)
         self._stop = threading.Event()
+        self._loop_thread: Optional[threading.Thread] = None  # serve_forever
         self._work = threading.Event()
         # ------------------------------------------- request lifecycle
         # Bounded admission: past queue_max pending requests, submit()
@@ -1021,10 +1033,12 @@ class DecodeEngine:
                deadline_s: Optional[float] = None,
                request_id: Optional[str] = None,
                prefill_only: bool = False,
-               adopt: Optional[Dict[str, Any]] = None) -> _Request:
+               adopt: Optional[Dict[str, Any]] = None,
+               on_done: Optional[Callable[[_Request], None]] = None
+               ) -> _Request:
         req = _Request(np.asarray(prompt_tokens, np.int32).reshape(-1),
                        int(max_new_tokens), float(temperature), eos_id,
-                       on_token)
+                       on_token, on_done=on_done)
         req.request_id = request_id or f"req-{next(_req_ids)}"
         req.prompt_len = len(req.tokens)
         if prefill_only and not self.paged:
@@ -1646,7 +1660,7 @@ class DecodeEngine:
         self._observe_terminal(req, status)
         with self._reqs_lock:
             self._requests.pop(req.request_id, None)
-        req.done.set()
+        self._end(req)
 
     def _purge_pending(self) -> None:
         """Drop dead entries (cancelled / deadline-expired) from the
@@ -1995,6 +2009,24 @@ class DecodeEngine:
                         "on_token callback failed (slot %d, %d tokens "
                         "emitted): %s", req.slot, req.generated,
                         req.on_token_error, exc_info=True)
+                # The consumer gets no more tokens: let it find out now,
+                # not when the request has run to its end.
+                self._tell_done(req)
+
+    def _end(self, req: _Request) -> None:
+        """The one exit of every request, whichever way it ends: waiters
+        on ``done`` and the ``on_done`` hook learn of it here."""
+        req.done.set()
+        self._tell_done(req)
+
+    def _tell_done(self, req: _Request) -> None:
+        if req.on_done is None:
+            return
+        try:
+            req.on_done(req)
+        except Exception:  # noqa: BLE001 — as for on_token: the loop lives
+            logger.warning("on_done callback failed (request %s)",
+                           req.request_id, exc_info=True)
 
     def _release_slot(self, slot: int) -> None:
         """Slot teardown shared by _finish and preemption: paged mode
@@ -2048,7 +2080,7 @@ class DecodeEngine:
         self._observe_terminal(req, status)
         with self._reqs_lock:
             self._requests.pop(req.request_id, None)
-        req.done.set()
+        self._end(req)
 
     def _reap(self) -> None:
         """Free slots whose requests are dead (cancelled, or past their
@@ -2558,17 +2590,48 @@ class DecodeEngine:
     def serve_forever(self, idle_wait_s: float = 0.05) -> None:
         """Decode loop for a replica thread: steps while work exists,
         parks on an event while idle."""
-        while not self._stop.is_set():
-            if (self._active or self._prefilling or self._requeue
-                    or not self._pending.empty()):
-                self.step()
-            else:
-                self._work.clear()
-                self._work.wait(timeout=idle_wait_s)
+        self._loop_thread = threading.current_thread()
+        try:
+            while not self._stop.is_set():
+                if (self._active or self._prefilling or self._requeue
+                        or not self._pending.empty()):
+                    self.step()
+                else:
+                    self._work.clear()
+                    self._work.wait(timeout=idle_wait_s)
+        finally:
+            self._loop_thread = None
+            self._abort_open()
 
     def shutdown(self) -> None:
+        """Stop the loop and end every request still open as cancelled:
+        nothing steps them again, so whoever waits on one (a stream's
+        pull, ``_wait_done``) must hear of it now. Engine state belongs
+        to the loop's thread, so a running loop does this on its way
+        out; without one the caller does."""
         self._stop.set()
         self._work.set()
+        if self._loop_thread in (None, threading.current_thread()):
+            self._abort_open()
+
+    def _abort_open(self) -> None:
+        with self._reqs_lock:
+            open_ids = list(self._requests)
+        try:
+            for request_id in open_ids:
+                self.cancel(request_id)
+            if open_ids:
+                self._purge_pending()   # the queued and the requeued
+                self._reap()            # the seated: slots, pages return
+        finally:
+            # What that could not reach (the loop died of a step that
+            # raised, perhaps mid-admission) still ends for its waiters.
+            with self._reqs_lock:
+                stranded = list(self._requests.values())
+                self._requests.clear()
+            for req in stranded:
+                req.status = "cancelled"
+                self._end(req)
 
     # ------------------------------------------------------------ stats
 
@@ -2867,7 +2930,8 @@ class LlamaDecodeDeployment:
 
     def _submit(self, request: Dict[str, Any], on_token=None,
                 prefill_only: bool = False,
-                adopt: Optional[Dict[str, Any]] = None) -> _Request:
+                adopt: Optional[Dict[str, Any]] = None,
+                on_done=None) -> _Request:
         """Admission with the request's deadline attached: explicit
         ``deadline_s`` in the payload wins, else the deadline the serve
         stack propagated with this call (proxy header / handle
@@ -2886,7 +2950,8 @@ class LlamaDecodeDeployment:
             deadline_s=deadline_s,
             request_id=request.get("request_id"),
             prefill_only=prefill_only,
-            adopt=adopt)
+            adopt=adopt,
+            on_done=on_done)
 
     def _wait_done(self, req: _Request) -> None:
         """Block until the engine finishes the request; a wedged decode
@@ -3055,59 +3120,46 @@ class LlamaDecodeDeployment:
     def stream_adopted(self, request: Dict[str, Any],
                        desc: Dict[str, Any]):
         """Streaming twin of ``decode_adopted``. Adoption (object-plane
-        fetch + engine submit) runs EAGERLY in this call, not in the
-        returned generator, so the replica's synchronous ``start_stream``
-        surfaces adopt failures as retryable call errors and the router
-        can discharge the prefill lease the moment the stream id comes
+        fetch + engine submit) runs in this call, like every stream's
+        submit, so the replica's synchronous ``start_stream`` surfaces
+        adopt failures as retryable call errors and the router can
+        discharge the prefill lease the moment the stream id comes
         back."""
-        q: "queue.Queue" = queue.Queue()
-        req = self._submit(request, on_token=q.put,
-                           adopt=self._fetch_adopt(desc))
-
-        def _gen():
-            try:
-                while True:
-                    try:
-                        yield q.get(timeout=0.5)
-                        continue
-                    except queue.Empty:
-                        pass
-                    if req.done.is_set():
-                        while not q.empty():
-                            yield q.get()
-                        req.raise_for_status()
-                        break
-            finally:
-                if not req.done.is_set():
-                    self.engine.cancel(req.request_id)
-
-        return _gen()
+        return self._token_stream(request, adopt=self._fetch_adopt(desc))
 
     def stream(self, request: Dict[str, Any]):
-        """Streaming generator: yields tokens as the engine emits them
-        (drive via a streaming handle / HTTP chunked response). Closing
-        the generator (client disconnect anywhere up the stack) cancels
-        the engine request: the slot frees at the next step and queued-
-        but-unadmitted requests never touch the device."""
-        q: "queue.Queue" = queue.Queue()
-        req = self._submit(request, on_token=q.put)
-        try:
-            while True:
-                try:
-                    yield q.get(timeout=0.5)
-                    continue
-                except queue.Empty:
-                    pass
-                if req.done.is_set():
-                    while not q.empty():
-                        yield q.get()
-                    # A mid-stream deadline/cancel surfaces as the typed
-                    # error instead of silently truncating the stream.
-                    req.raise_for_status()
-                    break
-        finally:
-            if not req.done.is_set():
-                self.engine.cancel(req.request_id)
+        """The request's tokens as the engine emits them (drive via a
+        streaming handle / HTTP chunked response). The request is
+        submitted in this call: a shed or an expired deadline raises
+        here, before any stream exists. Closing the stream (client
+        disconnect anywhere up the stack) cancels the engine request:
+        the slot frees at the next step and queued-but-unadmitted
+        requests never touch the device."""
+        return self._token_stream(request)
+
+    def _token_stream(self, request: Dict[str, Any],
+                      adopt: Optional[Dict[str, Any]] = None):
+        """One engine request behind a ``StreamQueue`` that the engine's
+        thread fills: ``on_token`` puts, and ``on_done`` ends it on every
+        way the request can end, with the typed error of a mid-stream
+        deadline/cancel instead of a silently truncated stream. So the
+        consumer sees a token the moment it exists and the end together
+        with the last one."""
+        from ray_tpu.serve.replica import StreamQueue
+
+        out = StreamQueue()
+
+        def ended(req: _Request) -> None:
+            out.end(RuntimeError(f"on_token failed: {req.on_token_error}")
+                    if req.on_token_error else req.terminal_error())
+
+        req = self._submit(request, on_token=out.put, on_done=ended,
+                           adopt=adopt)
+        out.on_close = lambda: (req.done.is_set()
+                                or self.engine.cancel(req.request_id))
+        # For an ending that forgets to tell: ``done`` is set, no hook ran.
+        out.backstop = lambda: req.done.is_set() and ended(req)
+        return out
 
     def health(self) -> Dict[str, Any]:
         return self.engine.stats()

@@ -799,8 +799,12 @@ class _Router:
     def _stream_plain(self, method: str, args: tuple, kwargs: dict,
                       model_id: str = "", chunk_items: int = 16,
                       timeout_s: Optional[float] = None):
-        """Generator of streamed items from one replica: the replica's
-        generator suspends between pulls (consumer-paced). The replica's
+        """Generator of streamed items from one replica, one pull
+        (``next_chunks``) after another: a pull returns as soon as the
+        replica's stream holds one item, with whatever else it holds by
+        then, up to ``chunk_items`` — so this yields a token when it
+        exists, and sixteen at a time only from a producer that is ahead
+        (``ReplicaActor``'s "streaming sessions"). The replica's
         in-flight slot and this router's count are held for the stream's
         lifetime (autoscaling sees streams as load).
 

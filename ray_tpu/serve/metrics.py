@@ -58,6 +58,14 @@ HTTP_LATENCY = Histogram(
     "labeled by resolved deployment.",
     boundaries=_HTTP_BUCKETS, tag_keys=("deployment",))
 
+STREAM_PULL_ITEMS = Histogram(
+    "serve_stream_pull_items",
+    "Items one next_chunks reply carried from a replica's stream to the "
+    "router: ~1 when the consumer keeps up with the producer (one token "
+    "a decode step), up to the delivery cap (16) when the producer is "
+    "ahead. Recorded once per stream, when it ends.",
+    boundaries=(1, 2, 4, 8, 15, 16), tag_keys=("deployment",))
+
 REQUESTS = Counter(
     "serve_requests_total",
     "Engine request outcomes: completed | cancelled | deadline_exceeded "
@@ -160,6 +168,7 @@ _HISTOGRAMS = {
     "inter_token_s": "serve_inter_token_s",
     "queue_wait_s": "serve_queue_wait_s",
     "http_request_s": "serve_http_request_s",
+    "stream_pull_items": "serve_stream_pull_items",
     "spec_accept_rate": "serve_spec_accept_rate",
     "handoff_bytes": "serve_handoff_bytes",
     "handoff_latency_s": "serve_handoff_latency_s",

@@ -167,6 +167,9 @@ def make_handler(in_flight: _InFlight, routes: _RouteTable):
 
     class _ProxyHandler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"  # chunked transfer needs 1.1
+        # TCP_NODELAY on the accepted socket: a streamed token is a few
+        # bytes and must not wait in the kernel for the one after it.
+        disable_nagle_algorithm = True
 
         def send_response(self, code, message=None):  # noqa: A003
             self._status = code  # observed by the request metrics below
@@ -372,8 +375,9 @@ def make_handler(in_flight: _InFlight, routes: _RouteTable):
             self.end_headers()
 
             def chunk(data: bytes) -> None:
-                self.wfile.write(f"{len(data):x}\r\n".encode())
-                self.wfile.write(data + b"\r\n")
+                # Size line, data and CRLF in ONE write: wfile is
+                # unbuffered, so every write is a send of its own.
+                self.wfile.write(b"%x\r\n%s\r\n" % (len(data), data))
 
             try:
                 if first is not _STREAM_END:

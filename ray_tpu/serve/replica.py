@@ -11,8 +11,10 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from collections import OrderedDict
-from typing import Any, Dict, List, Optional
+from collections import OrderedDict, deque
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from ray_tpu.core.errors import RequestCancelledError
 
 logger = logging.getLogger(__name__)
 
@@ -113,6 +115,164 @@ def loaded_model_ids(instance) -> List[str]:
     return out
 
 
+class StreamQueue:
+    """What one stream's producer has made and its consumer has not taken
+    yet. The producer ``put``s items and ``end``s the stream; the
+    consumer ``take``s: it blocks until ONE item exists (or the stream
+    has ended) and leaves with that item and whatever else is already
+    here, never waiting for an item that does not exist yet. ``close``
+    is the consumer going away.
+
+    Two producers fill it. A deployment that makes its items on a thread
+    of its own returns one from its streaming method and fills it
+    directly (``LlamaDecodeDeployment.stream``: the engine's ``on_token``
+    is ``put``, its end-of-request hook is ``end``). Any other iterable
+    gets one from ``pumping``: a thread, started at the first ``take``,
+    runs the iterator and stays at most one delivery ahead of the
+    consumer, so a fast producer ships ``max_items`` at a time and a
+    slow one ships each item as it appears.
+
+    It iterates (``next`` takes one item, ``close`` as on a generator),
+    so code that held a generator still works."""
+
+    # A producer that forgets to ``end`` is caught by asking ``backstop``
+    # this often; no ending the code knows of waits for it.
+    BACKSTOP_S = 0.5
+
+    def __init__(self) -> None:
+        self._cond = threading.Condition()
+        self._items: deque = deque()
+        self._ended = False      # producer: nothing more will be put
+        self._error: Optional[BaseException] = None
+        self._closed = False     # consumer: nothing more will be taken
+        self._source: Optional[tuple] = None  # pumping(): not started yet
+        self._room = 0           # pumped: items the pump may run ahead
+        self.backstop: Optional[Callable[[], None]] = None
+        self.on_close: Optional[Callable[[], None]] = None
+        self.pulls: List[int] = []  # items each take() left with
+
+    @classmethod
+    def pumping(cls, iterator, model_id: str = "",
+                deadline: Optional[float] = None) -> "StreamQueue":
+        """A queue that a thread fills from ``iterator`` once the first
+        ``take`` says how many items one delivery holds; the iterator's
+        body sees ``model_id`` and ``deadline`` as its request's."""
+        out = cls()
+        out._source = (iterator, model_id, deadline)
+        return out
+
+    # ------------------------------------------------------------ producer
+
+    def put(self, item: Any) -> None:
+        with self._cond:
+            self._items.append(item)
+            self._cond.notify_all()
+
+    def end(self, error: Optional[BaseException] = None) -> None:
+        """No further item follows. With ``error`` the consumer is
+        raised it once it has taken what was put before. The first call
+        wins."""
+        with self._cond:
+            if not self._ended:
+                self._ended, self._error = True, error
+                self._cond.notify_all()
+
+    def _pump(self, iterator, model_id: str,
+              deadline: Optional[float]) -> None:
+        _current_model_id.value = model_id  # the iterator's body runs here
+        _current_deadline.value = deadline
+        error: Optional[BaseException] = None
+        try:
+            while True:
+                with self._cond:
+                    while (len(self._items) >= self._room
+                           and not self._closed):
+                        self._cond.wait(self.BACKSTOP_S)
+                    if self._closed:
+                        break
+                try:
+                    item = next(iterator)
+                except StopIteration:
+                    break
+                self.put(item)
+        except BaseException as e:  # noqa: BLE001 — the consumer's to see
+            error = e
+        finally:
+            _close_iterator(iterator)
+        self.end(error)
+
+    # ------------------------------------------------------------ consumer
+
+    def take(self, max_items: int = 1) -> Tuple[List[Any], bool]:
+        """(items, done): block for the first item, then leave with up to
+        ``max_items`` of what is here. ``done`` says nothing follows.
+        Raises the producer's error once the items before it are taken,
+        ``RequestCancelledError`` if the stream is closed meanwhile."""
+        with self._cond:
+            if self._source is not None:
+                source, self._source = self._source, None
+                self._room = max(1, max_items)
+                threading.Thread(target=self._pump, args=source,
+                                 name="stream-pump", daemon=True).start()
+            while not (self._items or self._ended or self._closed):
+                if (not self._cond.wait(self.BACKSTOP_S)
+                        and self.backstop is not None):
+                    self.backstop()
+            if self._closed:
+                raise RequestCancelledError("stream closed by its consumer")
+            items = [self._items.popleft()
+                     for _ in range(min(max_items, len(self._items)))]
+            if self._room:
+                self._cond.notify_all()  # room again: wake the pump
+            done = self._ended and not self._items
+            if done and self._error is not None:
+                if not items:
+                    raise self._error
+                done = False  # the next take raises it
+            self.pulls.append(len(items))
+            return items, done
+
+    def close(self) -> None:
+        """The consumer is gone: wake a blocked ``take``, stop the pump
+        (it closes its iterator from its own thread), tell the producer
+        through ``on_close``. Idempotent."""
+        with self._cond:
+            if self._closed:
+                return
+            self._closed = True
+            source, self._source = self._source, None
+            self._cond.notify_all()
+        if source is not None:  # never pumped: nothing else will close it
+            _close_iterator(source[0])
+        if self.on_close is not None:
+            self.on_close()
+
+    def __iter__(self) -> "StreamQueue":
+        return self
+
+    def __next__(self) -> Any:
+        items, _ = self.take(1)
+        if not items:
+            raise StopIteration
+        return items[0]
+
+
+def _close_iterator(iterator) -> None:
+    """Run a generator's clean-up (engine cancel, slot free)."""
+    close = getattr(iterator, "close", None)
+    if close is None:
+        return
+    try:
+        close()
+    except Exception:
+        from ray_tpu.util.ratelimit import log_every
+
+        # A failure here can strand whatever the generator's finally
+        # would have released.
+        log_every("replica.stream_close", 10.0, logger,
+                  "closing stream generator failed", exc_info=True)
+
+
 class ReplicaActor:
     def __init__(self, cls_blob: bytes, args: tuple, kwargs: dict,
                  replica_id: str = "", owner_epoch: int = 0,
@@ -135,6 +295,7 @@ class ReplicaActor:
         self._ongoing = 0
         self._total = 0
         self._lock = threading.Lock()
+        self._streams: Dict[str, StreamQueue] = {}
         self._started = time.monotonic()
         # The controller epoch that owns this replica: assigned at
         # spawn, re-pushed by a restarted controller when it ADOPTS the
@@ -180,11 +341,22 @@ class ReplicaActor:
 
     # ------------------------------------------------- streaming sessions
     #
-    # A generator-returning callable streams INCREMENTALLY: the consumer
-    # pulls batches with next_chunks (actor calls), so the generator is
-    # suspended between pulls and production is backpressured by the
-    # consumer (reference: proxy.py's streaming responses over
-    # ASGI receive/send; here the handle is the transport).
+    # A streaming callable's items reach the consumer INCREMENTALLY: every
+    # stream has one ``StreamQueue`` between its producer and the
+    # consumer's pulls (``next_chunks``, actor calls). A pull blocks until
+    # the queue holds ONE item and returns with it and whatever else the
+    # queue holds by then, up to ``max_items``: an item that exists is
+    # never held for one that does not yet. So a producer slower than its
+    # consumer (a decode engine: one token a step) ships each item alone,
+    # and one that is ahead ships ``max_items`` a delivery. The callable
+    # either returns its own ``StreamQueue``, filled from its own thread
+    # (the decode engine's ``on_token``), or any other iterable, which a
+    # pump thread runs at most one delivery ahead of the consumer, so
+    # production is still backpressured by the pulls. A stream ends when
+    # its producer calls ``end`` (the engine: on every way a request can
+    # end), not when a timed wait notices (reference: proxy.py's
+    # streaming responses over ASGI receive/send; here the handle is the
+    # transport).
 
     def start_stream(self, method: str, args: tuple, kwargs: dict,
                      multiplexed_model_id: str = "",
@@ -202,7 +374,9 @@ class ReplicaActor:
             target = (self._instance if method == "__call__"
                       else getattr(self._instance, method))
             result = target(*args, **kwargs)
-            iterator = iter(result)
+            if not isinstance(result, StreamQueue):
+                result = StreamQueue.pumping(iter(result),
+                                             multiplexed_model_id, deadline)
         except BaseException:
             with self._lock:
                 self._ongoing -= 1
@@ -211,58 +385,50 @@ class ReplicaActor:
             _current_model_id.value = ""
             _current_deadline.value = None
         sid = uuid.uuid4().hex[:16]
-        self._streams = getattr(self, "_streams", {})
-        self._streams[sid] = (iterator, multiplexed_model_id, deadline)
+        self._streams[sid] = result
         return sid
 
-    def next_chunks(self, stream_id: str, max_items: int = 16,
-                    deadline_s: float = 2.0):
-        """Pull up to ``max_items``, returning EARLY with whatever arrived
-        once ``deadline_s`` elapses — a slow-but-healthy producer must
-        stream partial batches, not stall the consumer's RPC timeout until
-        the full batch exists. Returns (items, done); the stream's ongoing
-        slot frees when the iterator is exhausted."""
-        entry = getattr(self, "_streams", {}).get(stream_id)
-        if entry is None:
+    def next_chunks(self, stream_id: str, max_items: int = 16):
+        """One delivery: (items, done). Blocks for the stream's next item
+        and returns it with whatever else is ready, at most ``max_items``;
+        an error the producer ended with is raised once the items before
+        it are delivered. The stream's ongoing slot frees when it is
+        done."""
+        stream = self._streams.get(stream_id)
+        if stream is None:
             raise KeyError(f"unknown stream {stream_id}")
-        iterator, model_id, req_deadline = entry
-        items = []
-        done = False
-        deadline = time.monotonic() + deadline_s
-        _current_model_id.value = model_id  # generator body resumes here
-        _current_deadline.value = req_deadline
         try:
-            for _ in range(max_items):
-                items.append(next(iterator))
-                if time.monotonic() > deadline:
-                    break
-        except StopIteration:
-            done = True
+            items, done = stream.take(max_items)
         except BaseException:
             self.cancel_stream(stream_id)
             raise
-        finally:
-            _current_model_id.value = ""
-            _current_deadline.value = None
         if done:
             self.cancel_stream(stream_id)
         return items, done
 
     def cancel_stream(self, stream_id: str) -> None:
-        entry = getattr(self, "_streams", {}).pop(stream_id, None)
-        if entry is not None:
-            try:
-                entry[0].close()
-            except Exception:
-                from ray_tpu.util.ratelimit import log_every
+        stream = self._streams.pop(stream_id, None)
+        if stream is None:
+            return
+        try:
+            stream.close()
+        except Exception:
+            from ray_tpu.util.ratelimit import log_every
 
-                # close() runs the generator's cleanup (engine cancel,
-                # slot free) — a failure here can strand engine state.
-                log_every("replica.stream_close", 10.0, logger,
-                          "closing stream generator failed",
-                          exc_info=True)
-            with self._lock:
-                self._ongoing -= 1
+            # close() is the producer's clean-up (engine cancel, slot
+            # free) — a failure here can strand engine state.
+            log_every("replica.stream_close", 10.0, logger,
+                      "closing stream failed", exc_info=True)
+        with self._lock:
+            self._ongoing -= 1
+        from ray_tpu.core.config import config as rt_config
+
+        if rt_config.serve_metrics_enabled and stream.pulls:
+            from ray_tpu.serve import metrics as smetrics
+
+            # Once a stream, not once a pull: a pull costs one append.
+            smetrics.STREAM_PULL_ITEMS.observe_many(
+                stream.pulls, {"deployment": _replica_ident["deployment"]})
 
     def set_topology(self, assignment: Dict[str, Any]) -> None:
         """Sub-slice assignment from the serve controller (which chips
