@@ -96,3 +96,45 @@ def served_token_margins(params, cfg, prompts: List[List[int]],
             row = lg[i, len(p) + j - 1]
             out.append(float(row.max() - row[tok]))
     return out
+
+
+def greedy_answers(params, cfg, prompts: List[List[int]], n: int
+                   ) -> List[List[int]]:
+    """``n`` greedy tokens after each prompt under this reference: the
+    reference put in the program's place. One padded width for every step,
+    so one compile."""
+    width = max(len(p) for p in prompts) + n
+    rows = np.zeros((len(prompts), width), np.int32)
+    for i, p in enumerate(prompts):
+        rows[i, :len(p)] = p
+    step = jax.jit(lambda pr, t: logits(pr, t, cfg))
+    answers: List[List[int]] = [[] for _ in prompts]
+    for j in range(n):
+        best = np.asarray(jnp.argmax(step(params, jnp.asarray(rows)), -1))
+        for i, p in enumerate(prompts):
+            tok = int(best[i, len(p) + j - 1])
+            answers[i].append(tok)
+            rows[i, len(p) + j] = tok
+    return answers
+
+
+def rounded_weights(params, bits: int = 8):
+    """Every matrix rounded to ``bits``-bit integers, symmetric, one scale
+    per layer and per index of its last axis, and back to float32: what
+    weight-only quantization would serve. Norm scales stay. The control of
+    ``correct``: the replica computes in bfloat16, and int8 is the nearest
+    precision below it."""
+    top = 2.0 ** (bits - 1) - 1
+
+    def one(path, a):
+        name = str(getattr(path[-1], "key", path[-1]))
+        if name.endswith("norm"):
+            return a
+        a = a.astype(jnp.float32)
+        # A layer's tensors are stacked along a leading layer axis.
+        axes = tuple(range(1 if a.ndim > 2 else 0, a.ndim - 1))
+        scale = jnp.max(jnp.abs(a), axis=axes, keepdims=True) / top
+        scale = jnp.where(scale == 0, 1.0, scale)
+        return jnp.round(a / scale) * scale
+
+    return jax.tree_util.tree_map_with_path(one, params)

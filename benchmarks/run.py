@@ -32,7 +32,7 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmarks import traffic  # noqa: E402
+from benchmarks import families, traffic  # noqa: E402
 
 GAP_NAMES = {"engine_step": "engine_step: host (sample, emit, admit)"}
 GAP_OUTSIDE = {"serve": "between steps: engine loop waits for work",
@@ -157,6 +157,14 @@ def main() -> None:
         fail(f"cannot import ray_tpu ({e}): run from a whole checkout")
     if not args.rehearse and not tpu.accelerator_device_files():
         fail("no accelerator: this machine has no TPU device files")
+
+    if kind == "serve":
+        # What the cell needs of the served architecture; a family that
+        # only trains fails here, before the runtime starts.
+        try:
+            cell["serve"] = families.serve(cell["config"])
+        except ValueError as e:
+            fail(str(e))
 
     work_dir = os.path.join(ROOT, ".bench_work", args.workload)
     shutil.rmtree(work_dir, ignore_errors=True)

@@ -1,5 +1,8 @@
-"""A serve cell: one replica of ``BenchDecodeDeployment`` on one chip behind
-the HTTP proxy, loaded by ``client.py`` from this process."""
+"""A serve cell: one replica of the family's deployment class under the
+benchmark's hooks (``serve_replica.py``) on one chip behind the HTTP proxy,
+loaded by ``client.py`` from this process. What belongs to the served
+architecture (model config, deployment class, reference, tolerance, the
+check's sizes) comes from the ``Serve`` of the configuration's family."""
 
 from __future__ import annotations
 
@@ -9,18 +12,10 @@ import statistics
 import time
 from typing import Dict, List
 
-from benchmarks import client, families, stats, traffic
+from benchmarks import client, stats, traffic
 
 READY_TIMEOUT_S = 900
 TRACE_SECONDS = 5.0
-CHECK_PROMPTS, CHECK_TOKENS = 4, 8
-# A served token's reference logit may lie this far below its position's
-# maximum. The replica computes in bf16 (8 bits of mantissa) through 24
-# layers; with random weights the logits are ~N(0, 1) over 92,544 entries
-# and the top two lie ~0.05 apart, so equality of tokens cannot be asked.
-# Measured on the v5e (PR 23): the largest margin of any run was 0.047; a
-# replica that drops a layer or mis-places the cache lands whole units away.
-LOGIT_TOLERANCE = 0.25
 
 
 def _wait_replica(serve, name: str) -> Dict:
@@ -34,12 +29,21 @@ def _wait_replica(serve, name: str) -> Dict:
     raise TimeoutError(f"replica of {name} not up in {READY_TIMEOUT_S}s")
 
 
-def _check(addr, route, vocab, handle, seed) -> Dict:
-    """A few seeded prompts through the HTTP path, then the reference."""
+def check_requests(sizes: Dict, seed: int) -> List[traffic.Request]:
+    """The check's prompts: ``sizes`` as ``families.SERVE_CHECK`` has them,
+    lengths and token ids from the seed."""
     rng = random.Random(seed)
-    reqs = [traffic.Request(i, "check", 0.0, rng.randrange(16, 129),
-                            CHECK_TOKENS, rng.getrandbits(48))
-            for i in range(CHECK_PROMPTS)]
+    low, high = sizes["prompt_len"]
+    return [traffic.Request(i, "check", 0.0, rng.randrange(low, high + 1),
+                            sizes["tokens"], rng.getrandbits(48))
+            for i in range(sizes["prompts"])]
+
+
+def _check(addr, route, fam, handle, seed) -> Dict:
+    """A few seeded prompts through the HTTP path, then the family's
+    reference."""
+    vocab = fam.vocab
+    reqs = check_requests(fam.check, seed)
 
     async def go():
         sink: List[client.Outcome] = []
@@ -56,9 +60,11 @@ def _check(addr, route, vocab, handle, seed) -> Dict:
         margins = handle.bench_reference_margins.remote(
             [r.tokens(vocab) for r in reqs],
             [o.tokens for o in outs]).result(timeout=600)
-    return {"attempted": len(reqs), "failed": len(bad),
-            "max_margin": max(margins), "errors": bad,
-            "ok": not bad and max(margins) <= LOGIT_TOLERANCE}
+    worst = max(margins)
+    return {"attempted": len(reqs), "failed": len(bad), "errors": bad,
+            "ok": not bad and worst <= fam.tolerance,
+            "said": f"served-token margin {worst:.4f} <= {fam.tolerance} "
+                    f"({fam.reference})"}
 
 
 def _print_halves(measured, dump, marks, seconds) -> None:
@@ -89,17 +95,16 @@ def _print_halves(measured, dump, marks, seconds) -> None:
 def run(cell: Dict, args, t_proc_wall: float, work_dir: str) -> Dict:
     from ray_tpu import serve
 
-    from benchmarks.serve_replica import BenchDecodeDeployment
+    from benchmarks.serve_replica import bench_deployment
 
-    cfg, mix = cell["config"], cell["traffic"]
+    cfg, mix, fam = cell["config"], cell["traffic"], cell["serve"]
     layout = dict(cfg["serve"]["layouts"][mix.get("layout", "default")])
     max_ongoing = layout.pop("max_ongoing_requests")
-    model_cfg = families.load(cfg["family"]).model_config(cfg["model"])
-    vocab = model_cfg.vocab_size
-    dep = serve.deployment(BenchDecodeDeployment).options(
+    vocab = fam.vocab
+    dep = serve.deployment(bench_deployment(cfg["family"])).options(
         max_ongoing_requests=max_ongoing,
         ray_actor_options={"resources": {"TPU": 1}}).bind(
-            config=model_cfg, seed=args.seed % (2 ** 31 - 1), **layout)
+            config=fam.model_cfg, seed=args.seed % (2 ** 31 - 1), **layout)
     serve.run(dep, name="llm", ready_timeout_s=READY_TIMEOUT_S)
     addr = serve.start_http()
     _wait_replica(serve, "llm")
@@ -112,16 +117,23 @@ def run(cell: Dict, args, t_proc_wall: float, work_dir: str) -> Dict:
     # ---- set-up: every prefill shape of this cell's traffic, once -------
     open_loop = mix["loop"] == "open"
     if open_loop:
-        schedule = traffic.open_loop_schedule(mix, args.seed, args.seconds)
-        lengths = [r.prompt_len for r in schedule]
+        planned = schedule = traffic.open_loop_schedule(mix, args.seed,
+                                                        args.seconds)
     else:
-        requests = traffic.closed_loop_requests(mix, args.seed)
-        lengths = [r.prompt_len for r in requests]
+        planned = requests = traffic.closed_loop_requests(mix, args.seed)
+    lengths = [r.prompt_len for r in planned]
     chunk = layout["prefill_chunk_tokens"]
-    singles = traffic.warm_lengths(lengths, chunk, layout["kv_page_tokens"])
+    page = layout["kv_page_tokens"]
+    singles = traffic.warm_lengths(lengths, chunk, page)
     groups = [[n] for n in singles]
     for wave in mix.get("warm_waves", []):
         groups += [[n] * wave for n in singles if n <= chunk]
+    if mix.get("warm_resumed"):
+        have = set().union(*(traffic.prefill_programs(n, chunk, page)
+                             for n in singles))
+        longest = max(r.prompt_len + r.answer_len for r in planned)
+        call("bench_warm_resumed", traffic.resumed_prefills(
+            longest, chunk, page, have), vocab, timeout=1500.0)
     call("bench_warm", groups, vocab, timeout=1500.0)
 
     # ---- lead-in, window, drain ---------------------------------------
@@ -178,7 +190,7 @@ def run(cell: Dict, args, t_proc_wall: float, work_dir: str) -> Dict:
     asyncio.run(load())
     outcomes = sink
     dump = call("bench_dump")
-    check = _check(addr, route, vocab, handle, args.seed)
+    check = _check(addr, route, fam, handle, args.seed)
 
     # ---- end-to-end numbers, from the client's clock alone ----------------
     credits = []
@@ -198,8 +210,7 @@ def run(cell: Dict, args, t_proc_wall: float, work_dir: str) -> Dict:
               f"{no_gap} left out of tpot (all tokens in the first "
               f"delivery); ttft median {statistics.median(ttft):.4f} ms, "
               f"tpot median {statistics.median(tpot):.4f} ms over "
-              f"{len(tpot)}; served-token margin "
-              f"{check['max_margin']:.4f} <= {LOGIT_TOLERANCE}", flush=True)
+              f"{len(tpot)}; {check['said']}", flush=True)
         attempted = n_window
         for o in measured:
             if not o.ok:
@@ -216,10 +227,15 @@ def run(cell: Dict, args, t_proc_wall: float, work_dir: str) -> Dict:
         print(f"[bench] {len(done_in)} requests ended inside the window, "
               f"median request "
               f"{statistics.median([o.ended - o.sent for o in done_in]):.4f}"
-              f" s, {failed} failed; served-token margin "
-              f"{check['max_margin']:.4f} <= {LOGIT_TOLERANCE}", flush=True)
+              f" s, {failed} failed; {check['said']}", flush=True)
         attempted = len(inside)
     e2e["setup_s"] = marks["open_wall"] - t_proc_wall
+    compiles = marks["close"]["compiles"] - marks["open"]["compiles"]
+    print(f"[bench] inside the window: {compiles} compiles, "
+          f"{marks['close']['preempted'] - marks['open']['preempted']} "
+          f"preemptions, {marks['close']['steps'] - marks['open']['steps']} "
+          f"engine steps; at its close {marks['close']['active']} active, "
+          f"{marks['close']['queued']} queued", flush=True)
     events = [e for r in dump["rows"] for e in r.get("events", [])
               if marks["open_wall"] <= e["ts"] < marks["open_wall"] + seconds
               and e["kind"] in ("jit-compile", "preempt")]
@@ -236,8 +252,7 @@ def run(cell: Dict, args, t_proc_wall: float, work_dir: str) -> Dict:
         "end_to_end": e2e, "outcomes": outcomes, "clocks": dump["clocks"],
         "rows": dump["rows"], "marks": marks,
         "window": (t_open, t_close),
-        "compiles_in_window": (marks["close"]["compiles"]
-                               - marks["open"]["compiles"]),
+        "compiles_in_window": compiles,
         "trace_dir": trace_dir if traced else None,
         "device": {"platform": mark["platform"], "kind": mark["device_kind"],
                    "count": mark["device_count"],

@@ -1,4 +1,5 @@
-"""The deployment the serve cells run: ``LlamaDecodeDeployment`` itself, plus
+"""The deployment the serve cells run: the program's own deployment class,
+which the cell's family names (``families/<family>.py``, ``Serve``), plus
 the hooks a measurement needs inside the process that holds the chip. It
 adds no behaviour to a request's path except a lock that ``engine.step``
 takes (uncontended outside set-up) and, in a traced run, a
@@ -10,10 +11,21 @@ import threading
 import time
 from typing import Any, Dict, List
 
-from ray_tpu.serve.decode import LlamaDecodeDeployment
+from benchmarks import families
 
 
-class BenchDecodeDeployment(LlamaDecodeDeployment):
+def bench_deployment(family: str):
+    """The family's deployment class under the hooks below. The class is
+    made here, per family, and goes to the replica by value (the runtime
+    ships a deployment's class with cloudpickle); ``bench_family`` tells
+    the replica whose reference checks its answers."""
+    base = families.load(family).Serve.deployment_class()
+    return type(f"Bench{base.__name__}", (BenchDecodeDeployment, base),
+                {"bench_family": family})
+
+
+class BenchDecodeDeployment:
+    """The hooks: a mix-in in front of the program's deployment class."""
 
     def __init__(self, *args, **kwargs):
         super().__init__(*args, **kwargs)
@@ -41,23 +53,42 @@ class BenchDecodeDeployment(LlamaDecodeDeployment):
 
     # ------------------------------------------------------------ set-up
 
+    def _bench_admit(self, prompts: List[List[int]]) -> None:
+        """``prompts`` as ONE admission wave (the step gate is held while
+        they are queued), two tokens each, waited for."""
+        with self._bench_gate:
+            reqs = [self.engine.submit(p, max_new_tokens=2) for p in prompts]
+        for req in reqs:
+            if not req.done.wait(600):
+                raise TimeoutError("warm-up request did not finish")
+            req.raise_for_status()
+
     def bench_warm(self, groups: List[List[int]], vocab: int) -> Dict:
-        """Send each group of prompt lengths as ONE admission wave (the
-        step gate is held while the group is queued), two tokens each, so
+        """Send each group of prompt lengths as ONE admission wave, so
         that every prefill shape of the cell's traffic has run once."""
         import random
 
         rng = random.Random(0)
         for group in groups:
-            with self._bench_gate:
-                reqs = [self.engine.submit(
-                    [rng.randrange(vocab) for _ in range(n)],
-                    max_new_tokens=2) for n in group]
-            for req in reqs:
-                if not req.done.wait(600):
-                    raise TimeoutError("warm-up request did not finish")
-                req.raise_for_status()
+            self._bench_admit([[rng.randrange(vocab) for _ in range(n)]
+                               for n in group])
         return self.engine.device_stats()
+
+    def bench_warm_resumed(self, pairs: List[List[int]], vocab: int) -> None:
+        """For each (prefix, suffix) of ``traffic.resumed_prefills``: a
+        prompt whose first ``prefix`` tokens are in the prefix index and
+        whose last ``suffix`` are new, as a request preempted for pages
+        comes back. One long prompt goes first and puts every prefix into
+        the index; the longest prefixes follow at once, before other
+        entries push its tail out."""
+        import random
+
+        rng = random.Random(1)
+        base = [rng.randrange(vocab) for _ in range(max(p for p, _ in pairs))]
+        self._bench_admit([base])
+        for prefix, suffix in sorted(pairs, reverse=True):
+            self._bench_admit([base[:prefix] + [rng.randrange(vocab)
+                                                for _ in range(suffix)]])
 
     # ------------------------------------------------------- measurement
 
@@ -119,7 +150,6 @@ class BenchDecodeDeployment(LlamaDecodeDeployment):
         """Teacher-force the plain float32 reference on prompt + answer
         with THIS replica's weights; for every served token return how far
         its reference logit lies below that position's maximum."""
-        from benchmarks.reference import llama_ref
-
-        return llama_ref.served_token_margins(
-            self.engine.params, self.cfg, prompts, answers)
+        serve = families.load(self.bench_family).Serve
+        return serve.reference_margins(self.engine.params, self.cfg,
+                                       prompts, answers)

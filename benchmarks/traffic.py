@@ -174,3 +174,32 @@ def warm_lengths(lengths: Sequence[int], chunk: int, page: int
             out.append(n)
             needed -= new
     return out
+
+
+def resumed_prefills(longest: int, chunk: int, page: int, have: set
+                     ) -> List[Tuple[int, int]]:
+    """(cached prefix, suffix) lengths that between them run every prefill
+    program through which a request preempted for pages can come back and
+    that ``have`` (a set of ``prefill_programs``) lacks. The prefix index
+    keeps a prompt's pages up to the largest power of two within it, so
+    the request returns with a cached prefix that is a power of two of at
+    least one page, and prefills the rest (its prompt's tail and its
+    answer so far), a chunk at most at a time: a bucket from 16 to the
+    chunk at the block-table width that prefix + bucket cover. ``longest``
+    is the most a request can hold, prompt and answer. Above the knee
+    preemption is the rule, and a program met first inside the window
+    compiles there."""
+    out, seen = [], set(have)
+    prefix = page
+    while prefix < longest:
+        bucket = 16
+        # A suffix of ``bucket // 2 + 1`` tokens already runs ``bucket``.
+        while bucket <= chunk and prefix + (bucket > 16) * bucket // 2 \
+                < longest:
+            program = (bucket, _pow2(-(-(prefix + bucket) // page), 1))
+            if program not in seen:
+                seen.add(program)
+                out.append((prefix, min(bucket, longest - prefix)))
+            bucket *= 2
+        prefix *= 2
+    return out

@@ -1,10 +1,13 @@
 """The benchmark's own arithmetic, on the CPU, in seconds: traffic
 generation, the client-side metric rules, whole-steps throughput, the trace
 reduction on a small trace recorded on a TPU v5e, the peaks table, and the
-runner's refusal to measure without a chip. The end-to-end rehearsals
-(every cell at a toy size, and a dummy configuration, traffic mix and
-metric added as files) are marked slow."""
+runner's refusal to measure without a chip, and the seam through which a
+serve cell reaches its family (reference, tolerance, check sizes, deployment
+class). The end-to-end rehearsals (every cell at a toy size; a dummy
+configuration, traffic mix and metric, and a dummy SERVE family with its own
+reference, added as files) are marked slow."""
 
+import ast
 import json
 import os
 import random
@@ -253,11 +256,171 @@ def test_benchmark_json_names_files_that_exist():
     assert sum(w["chips"] == 4 for w in bench["workloads"]) <= 1
 
 
-def _run(args, env_extra=None, cwd=ROOT, timeout=600):
+# ------------------------------------------------------- the serve seam
+
+TOY = {"family": "llama", "model": {
+    "hidden_size": 32, "num_hidden_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "intermediate_size": 64, "vocab_size": 61,
+    "max_position_embeddings": 64, "rope_theta": 10000,
+    "rms_norm_eps": 1e-5}}
+
+
+def test_llama_serve_is_the_reference_and_tolerance_of_the_parent():
+    import jax
+
+    from ray_tpu.models import llama
+    from ray_tpu.serve.decode import LlamaDecodeDeployment
+
+    from benchmarks import families
+    from benchmarks.reference import llama_ref
+
+    fam = families.serve(TOY)
+    assert (fam.tolerance, fam.reference) == (0.25, "llama_ref")
+    assert fam.vocab == 61 and fam.model_cfg.n_layers == 2
+    assert fam.deployment_class() is LlamaDecodeDeployment
+    params = llama.init_params(fam.model_cfg, jax.random.key(3))
+    prompts, answers = [[5, 9, 2, 40], [7, 7, 1]], [[3, 60], [0, 12]]
+    want = llama_ref.served_token_margins(params, fam.model_cfg, prompts,
+                                          answers)
+    got = families.load("llama").Serve.reference_margins(
+        params, fam.model_cfg, prompts, answers)
+    assert got == want and len(got) == 4 and min(got) >= 0.0
+
+
+def test_check_sizes_default_to_the_parents_draws_and_a_file_overrides():
+    from benchmarks import families, serve_cell
+
+    assert families.serve(TOY).check == families.SERVE_CHECK == {
+        "prompts": 4, "prompt_len": [16, 128], "tokens": 8}
+    # What serve_cell._check drew before the seam, draw for draw.
+    rng = random.Random(3000000019)
+    old = [(rng.randrange(16, 129), 8, rng.getrandbits(48))
+           for _ in range(4)]
+    new = serve_cell.check_requests(families.SERVE_CHECK, 3000000019)
+    assert [(r.prompt_len, r.answer_len, r.token_seed) for r in new] == old
+    over = dict(TOY, serve={"check": {"prompts": 2, "prompt_len": [3, 3]}})
+    sizes = families.serve(over).check
+    assert sizes == {"prompts": 2, "prompt_len": [3, 3], "tokens": 8}
+    reqs = serve_cell.check_requests(sizes, 5)
+    assert [(r.prompt_len, r.answer_len) for r in reqs] == [(3, 8), (3, 8)]
+
+
+def test_the_control_of_correct_fails_and_the_reference_itself_passes():
+    """The reference in the program's place answers with margin 0; with
+    its weights rounded coarsely enough for this toy size (2 bits; the
+    chip's control rounds to 8, ``benchmarks/control.py``) the same
+    comparison comes out as not correct."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.models import llama
+
+    from benchmarks import families
+    from benchmarks.reference import llama_ref
+
+    fam = families.serve(TOY)
+    params = llama.init_params(fam.model_cfg, jax.random.key(7))
+    prompts = [[5, 9, 2, 40, 11], [7, 7, 1]]
+    answers = llama_ref.greedy_answers(params, fam.model_cfg, prompts, 4)
+    assert [len(a) for a in answers] == [4, 4]
+    assert max(llama_ref.served_token_margins(
+        params, fam.model_cfg, prompts, answers)) == 0.0
+    assert max(fam.control_margins(7, prompts, 4, 2)) > fam.tolerance
+    w = params["layers"]["w_down"]
+    r = llama_ref.rounded_weights(params, 8)["layers"]["w_down"]
+    step = np.abs(np.asarray(w)).max(axis=1, keepdims=True) / 127
+    assert 0 < np.abs(np.asarray(r - w)).max() <= step.max() / 2 * 1.001
+    assert np.array_equal(
+        llama_ref.rounded_weights(params)["final_norm"],
+        params["final_norm"])
+
+
+@pytest.mark.parametrize("module", ["serve_cell.py", "serve_replica.py"])
+def test_serve_halves_reach_the_architecture_through_the_family(module):
+    """No import of a reference, of a family by name, or of a class of
+    ``ray_tpu.serve.decode``: those belong to ``families/<family>.py``."""
+    with open(os.path.join(ROOT, "benchmarks", module)) as f:
+        tree = ast.parse(f.read())
+    imported = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported += [(a.name, None) for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            imported += [(node.module or "", a.name) for a in node.names]
+    for mod, name in imported:
+        whole = f"{mod}.{name}" if name else mod
+        assert "benchmarks.reference" not in whole, whole
+        assert not whole.startswith("benchmarks.families."), whole
+        assert not whole.startswith("ray_tpu.serve.decode"), whole
+    names = {n.id for n in ast.walk(tree) if isinstance(n, ast.Name)} | \
+        {n.attr for n in ast.walk(tree) if isinstance(n, ast.Attribute)}
+    assert not {"llama_ref", "vit_ref", "LlamaDecodeDeployment",
+                "LOGIT_TOLERANCE"} & names
+
+
+def test_bench_deployment_wraps_the_familys_class_and_ships_by_value():
+    from ray_tpu.core import serialization
+    from ray_tpu.serve.decode import LlamaDecodeDeployment
+
+    from benchmarks import serve_replica
+
+    cls = serve_replica.bench_deployment("llama")
+    assert cls.__mro__[1:3] == (serve_replica.BenchDecodeDeployment,
+                                LlamaDecodeDeployment)
+    assert cls.bench_family == "llama"
+    back = serialization.loads_function(serialization.dumps_function(cls))
+    assert back.bench_family == "llama"
+    assert issubclass(back, LlamaDecodeDeployment)
+    assert back.bench_reference_margins is not None
+
+
+def test_resumed_warm_up_runs_the_programs_a_preempted_request_returns_by():
+    """``traffic.resumed_prefills`` names (cached prefix, suffix) pairs and
+    ``bench_warm_resumed`` sends them; the engine then has run the suffix
+    prefill of every (bucket, width) a power-of-two prefix can lead to."""
+    pairs = traffic.resumed_prefills(2463, 512, 64, {(512, 16), (16, 32)})
+    programs = {(traffic._pow2(s, 16), traffic._pow2(-(-(p + traffic._pow2(
+        s, 16)) // 64), 1)) for p, s in pairs}
+    assert len(programs) == len(pairs)
+    assert {(16, 2), (64, 2), (128, 4), (256, 8), (16, 16), (512, 64),
+            (16, 64)} <= programs
+    assert not {(512, 16), (16, 32), (512, 8), (128, 2)} & programs
+    assert all(p + s <= 2463 for p, s in pairs)
+
+    from benchmarks import families, serve_replica
+
+    fam = families.serve(dict(TOY, model=dict(
+        TOY["model"], max_position_embeddings=512)))
+    dep = serve_replica.bench_deployment("llama")(
+        config=fam.model_cfg, seed=0, slots=4, capacity=256,
+        kv_page_tokens=8, kv_pool_pages=96, prefill_chunk_tokens=32)
+    try:
+        pairs = traffic.resumed_prefills(136, 32, 8, set())
+        dep.bench_warm_resumed(pairs, fam.vocab)
+        ran = {k[2:] for k in dep.engine._compiled
+               if k[:2] == ("paged_suffix", 1)}
+    finally:
+        dep.engine.shutdown()
+    # Prefixes of 32, 64 and 128 tokens in pages of 8 (one of 8 is under
+    # the 16 tokens from which the program's index matches).
+    assert pairs == [(8, 16), (8, 32), (32, 16), (64, 16), (64, 32),
+                     (128, 8)]
+    assert {(16, 8), (16, 16), (32, 16), (16, 32)} <= ran
+
+
+def _copy_of_the_benchmark(tmp_path):
+    root = tmp_path / "copy"
+    shutil.copytree(os.path.join(ROOT, "benchmarks"), root / "benchmarks",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return root, json.load(f)
+
+
+def _run(args, env_extra=None, cwd=ROOT, timeout=600, run=RUN):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env.update(env_extra or {})
-    return subprocess.run([sys.executable, RUN] + args, env=env, cwd=cwd,
+    return subprocess.run([sys.executable, run] + args, env=env, cwd=cwd,
                           capture_output=True, text=True, timeout=timeout)
 
 
@@ -266,6 +429,26 @@ def test_runner_refuses_to_measure_on_a_cpu_backend():
               "1", "--trace", "0"], {"JAX_PLATFORMS": "cpu"}, timeout=120)
     assert p.returncode != 0
     assert "needs the chip" in p.stderr
+    assert not p.stdout.strip().startswith("{")
+
+
+def test_a_serve_cell_on_a_family_without_serve_fails_at_once(tmp_path):
+    from benchmarks import families
+
+    with pytest.raises(ValueError, match="family 'vit' has no Serve"):
+        families.serve({"family": "vit", "model": {}})
+    root, bench = _copy_of_the_benchmark(tmp_path)
+    bench["workloads"].append({
+        "name": "vit-b16.chat_steady", "config": "vit-b16",
+        "traffic": "chat_steady", "chips": 1, "why": "cannot be"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    p = _run(["--workload", "vit-b16.chat_steady", "--seed", "1",
+              "--seconds", "1", "--trace", "0", "--rehearse",
+              "--bench-root", str(root)], timeout=120)
+    assert p.returncode != 0
+    assert "family 'vit' has no Serve" in p.stderr
+    assert "a serve cell cannot run on it" in p.stderr
+    assert "Traceback" not in p.stderr
     assert not p.stdout.strip().startswith("{")
 
 
@@ -310,11 +493,7 @@ def test_a_cell_is_added_with_files_and_entries_only(tmp_path):
     """A dummy configuration, traffic mix and per-layer metric, each a new
     file plus a new entry in a temporary copy; no file that was there is
     edited, and the rehearsal runs the new cell and reports the metric."""
-    root = tmp_path / "copy"
-    shutil.copytree(os.path.join(ROOT, "benchmarks"), root / "benchmarks",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    root, bench = _copy_of_the_benchmark(tmp_path)
     with open(root / "benchmarks" / "configs" / "vit-b16.json") as f:
         conf = json.load(f)
     conf["name"] = "vit-dummy"
@@ -352,3 +531,94 @@ def test_a_cell_is_added_with_files_and_entries_only(tmp_path):
     assert line["correct"]
     assert line["metrics"]["steps_in_window"]["value"] > 0
     assert "train_step_ms_p50" in line["metrics"]
+
+
+DUMMY_FAMILY = '''"""A second decoder family: the program's deployment,
+a reference and a tolerance of its own."""
+from benchmarks.families import llama
+from benchmarks.reference import dummy_ref
+
+
+class Serve(llama.Serve):
+    reference = "dummy_ref"
+    tolerance = dummy_ref.TOLERANCE
+    reference_margins = staticmethod(dummy_ref.served_token_margins)
+'''
+
+DUMMY_REFERENCE = '''"""The dummy family's reference: the decoder's, and
+a refusal of any check that is not of the sizes its configuration states."""
+from benchmarks.reference import llama_ref
+
+TOLERANCE = 0.125
+
+
+def served_token_margins(params, cfg, prompts, answers):
+    sizes = [(len(p), len(a)) for p, a in zip(prompts, answers)]
+    if len(sizes) != 2 or any(not 8 <= p <= 24 or a != 3 for p, a in sizes):
+        raise ValueError(f"not the check of serve.check: {sizes}")
+    return llama_ref.served_token_margins(params, cfg, prompts, answers)
+'''
+
+
+@pytest.mark.slow
+def test_a_serve_family_is_added_with_files_and_entries_only(tmp_path):
+    """The twin of the test above for serving: a dummy SERVE family
+    (``families/dummy.py``, ``reference/dummy_ref.py`` with its own
+    tolerance), a configuration of it with a ``serve.check`` override, a
+    traffic mix and a metric, all new files plus new entries; the rehearsal
+    is correct and its ``[bench]`` line names the dummy's reference and
+    tolerance."""
+    root, bench = _copy_of_the_benchmark(tmp_path)
+    b = root / "benchmarks"
+    before = {str(p): p.read_bytes() for p in b.rglob("*") if p.is_file()}
+    (b / "families" / "dummy.py").write_text(DUMMY_FAMILY)
+    (b / "reference" / "dummy_ref.py").write_text(DUMMY_REFERENCE)
+    with open(b / "configs" / "internlm2-1.8b.json") as f:
+        conf = json.load(f)
+    conf.update(name="dummy-1.8b", family="dummy")
+    conf["serve"]["check"] = {"prompts": 2, "prompt_len": [8, 24],
+                              "tokens": 3}
+    (b / "configs" / "dummy-1.8b.json").write_text(json.dumps(conf))
+    with open(b / "traffic" / "chat_steady.json") as f:
+        mix = json.load(f)
+    mix["rehearse"]["rate_rps"] = 3
+    (b / "traffic" / "chat_slow.json").write_text(json.dumps(mix))
+    (b / "metrics" / "requests_in_window.py").write_text(
+        "from benchmarks.metrics import _common\n\n\n"
+        "def read(ctx):\n    return float(len(_common.measured(ctx)))\n")
+    cell = "dummy-1.8b.chat_slow"
+    bench["configs"].append({
+        "name": "dummy-1.8b", "source": conf["source"],
+        "file": "benchmarks/configs/dummy-1.8b.json", "reduced": [],
+        "why": "dummy"})
+    bench["workloads"].append({
+        "name": cell, "config": "dummy-1.8b", "traffic": "chat_slow",
+        "chips": 1, "why": "dummy"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "internlm2-1.8b.chat_steady" in m.get("workloads", []):
+            m["workloads"].append(cell)
+    bench["per_layer"].append({
+        "name": "requests_in_window", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "load generator (benchmark)",
+        "moves": "ttft_p90_ms", "workloads": [cell]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    assert {str(p): p.read_bytes() for p in b.rglob("*")
+            if p.is_file() and str(p) in before} == before
+    # Run the COPY's run.py as a whole checkout (the program linked in):
+    # the new family and its reference exist only there, and the replica
+    # has to import them too.
+    os.symlink(os.path.join(ROOT, "ray_tpu"), root / "ray_tpu")
+    p = _run(["--workload", cell, "--seed", "5", "--seconds", "3",
+              "--trace", "1", "--rehearse"], run=str(b / "run.py"),
+             cwd=str(root), env_extra={"JAX_COMPILATION_CACHE_DIR":
+                                       os.path.join(ROOT, ".jax_cache")})
+    assert p.returncode == 0, p.stderr[-2000:]
+    line = _last_json(p.stdout)
+    assert line["correct"] and line["failed"] == 0
+    assert line["attempted"] == 3 * 3 + 2      # the window's, the check's
+    assert line["metrics"]["requests_in_window"]["value"] == 9
+    assert "decode_step_ms_p50" in line["metrics"]
+    said = [ln for ln in p.stdout.splitlines()
+            if ln.startswith("[bench]") and "served-token margin" in ln]
+    assert len(said) == 1 and said[0].rstrip().endswith(
+        "<= 0.125 (dummy_ref)"), said
