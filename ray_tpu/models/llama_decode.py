@@ -78,6 +78,47 @@ def _cast(w: jax.Array, dtype) -> jax.Array:
         return w.astype(dtype)
 
 
+# The leaves the programs below read through ``_cast``, at the top of the
+# tree and under ``layers`` (tests/test_engine_weights.py holds this list
+# to the call sites). The norm scales are not among them: ``rms_norm``
+# lifts those to float32 itself.
+CAST_LEAVES = ("tok_embed", "lm_head")
+CAST_LAYER_LEAVES = ("wq", "wk", "wv", "wqkv", "wo",
+                     "w_gate", "w_up", "w_gate_up", "w_down")
+
+
+def compute_weights(params: Dict[str, Any], config: LlamaConfig,
+                    donate: bool = False) -> Dict[str, Any]:
+    """``params`` with every leaf that ``_cast`` converts held in
+    ``config.dtype``, so a program run on the result converts nothing: a
+    serving replica never updates its weights, and rounds them once here
+    instead of once a program call. A leaf already in that dtype is the
+    same array and every other leaf is passed through, so float32 compute
+    changes nothing. ``params`` and its arrays are left as they are,
+    unless the caller owns them and says ``donate``: then each converted
+    leaf's source is deleted as soon as its copy exists, and the
+    transient is one leaf, not the tree."""
+    dtype = jnp.dtype(config.dtype)
+
+    def held(w):
+        if w.dtype == dtype:
+            return w
+        out = jnp.asarray(w, dtype=dtype)
+        if donate:
+            # Wait for the copy: a delete behind an unfinished convert
+            # frees nothing yet, and the next leaf's copy would stack up.
+            out.block_until_ready()
+            w.delete()
+        return out
+
+    def over(tree, names):
+        return {k: held(v) if k in names else v for k, v in tree.items()}
+
+    out = over(params, CAST_LEAVES)
+    out["layers"] = over(params["layers"], CAST_LAYER_LEAVES)
+    return out
+
+
 def _paged_gather(k_p, v_p, block_tables, config: LlamaConfig):
     """Each row's pages back in logical order, ``(B, W * T, KV, D)``: the
     view the paged attention reads. Named for the device trace."""

@@ -157,7 +157,11 @@ class DecodeEngine:
 
         self._jax = jax
         self._ld = ld
-        self.params = params
+        # The one tree every program reads, held in the compute dtype:
+        # rounded once here, not in every decode step and prefill chunk.
+        # The caller's arrays are left alone (a test's reference reads
+        # them afterwards); whoever owns the masters frees them.
+        self.params = params = ld.compute_weights(params, config)
         self.config = config
         self.slots = slots
         self.capacity = capacity
@@ -372,12 +376,13 @@ class DecodeEngine:
             # (the draft pool could not seat it — the slot rides spec
             # rounds with junk proposals that simply get rejected).
             self._draft_committed = [0] * slots
-            self._draft_params = spec_draft_params
+            self._draft_params = ld.compute_weights(
+                spec_draft_params, spec_draft_config)
             self._draft_rules = None
             self._draft_cache_sharding = None
             if self.mesh is not None:
                 self._draft_params, dsh = ld.shard_decode_state(
-                    spec_draft_params, spec_draft_config, mesh)
+                    self._draft_params, spec_draft_config, mesh)
                 self._draft_rules = dsh["rules"]
                 self._draft_cache_sharding = dict(dsh["pool"])
                 self._draft_cache = jax.device_put(
@@ -2742,6 +2747,13 @@ class DecodeEngine:
             "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
             "bytes_in_use": [m.get("bytes_in_use") for m in mem],
             "peak_bytes_in_use": [m.get("peak_bytes_in_use") for m in mem],
+            # The tree the programs read (the draft's not counted): its
+            # bytes over all devices, and the dtype of the leaves the
+            # model casts, which is the compute dtype once the engine
+            # holds them.
+            "weights_bytes": sum(
+                w.nbytes for w in self._jax.tree.leaves(self.params)),
+            "weights_dtype": str(self.params["lm_head"].dtype),
             "pid": os.getpid(),
             **self._compile_watch.snapshot(),
         }
@@ -2818,13 +2830,18 @@ class LlamaDecodeDeployment:
 
         from ray_tpu.core.config import config as rt_config
         from ray_tpu.models import llama
+        from ray_tpu.models import llama_decode as ld
         from ray_tpu.util.compile_cache import compile_watch
 
         compile_watch()  # count the replica's compiles from its first one
         cfg = config or llama.PRESETS[preset]
         self.cfg = cfg
         self._sub_slice: Optional[Dict[str, Any]] = None
-        params = llama.init_params(cfg, jax.random.key(seed))
+        # A replica never updates its weights, so the float32 masters
+        # serve nothing here: this process owns them, and each leaves the
+        # device as soon as its compute-dtype copy exists.
+        params = ld.compute_weights(
+            llama.init_params(cfg, jax.random.key(seed)), cfg, donate=True)
         # Draft model for speculative decoding: a (smaller) preset named
         # by knob. Seeded independently of the target — the contract
         # never depends on draft quality, only on verification.
@@ -2840,8 +2857,9 @@ class LlamaDecodeDeployment:
                     f"({draft_cfg.vocab_size}) != target vocab "
                     f"({cfg.vocab_size}) — proposals must share the "
                     f"token space the target verifies")
-            draft_params = llama.init_params(draft_cfg,
-                                             jax.random.key(seed + 1))
+            draft_params = ld.compute_weights(
+                llama.init_params(draft_cfg, jax.random.key(seed + 1)),
+                draft_cfg, donate=True)
         self.engine = DecodeEngine(
             params, cfg, slots=slots, capacity=capacity,
             decode_chunk=decode_chunk,

@@ -340,8 +340,16 @@ def test_engine_programs_are_jitted_under_their_keys_name():
         head = low.as_text().split("\n", 1)[0]
         assert f"@jit_engine_{key} " in head, head
     text = lowered["decode"].as_text(debug_info=True)
-    for scope in ("paged_gather", "paged_attn", "weight_cast"):
+    for scope in ("paged_gather", "paged_attn"):
         assert re.search(rf'loc\("[^"]*{scope}', text), scope
+    # The engine holds its weights in the compute dtype, so on its own
+    # tree ``weight_cast`` names nothing; it still names the converts of a
+    # program given float32 parameters.
+    assert "weight_cast" not in text
+    masters = _tiny()[1]
+    text = eng._decode.lower(masters, eng.cache, toks, bt).as_text(
+        debug_info=True)
+    assert re.search(r'loc\("[^"]*weight_cast', text)
     for key in ("paged_suffix", "decode_k"):
         text = lowered[key].as_text(debug_info=True)
         assert "paged_gather" in text and "paged_attn" in text
