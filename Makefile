@@ -4,9 +4,7 @@
 # tests/test_analysis_v3.py).
 
 .PHONY: lint lint-diff lint-stats lint-stubs-check gen-stubs test \
-	bench-paged bench-sharded bench-trace trace-demo bench-rl-dist \
-	bench-obs bench-chaos bench-gang bench-pipeline bench-spec \
-	bench-disagg
+	trace-demo bench-rl-dist bench-chaos bench-gang bench-pipeline
 
 # The full gate: regenerate-and-diff the typed RPC stubs, then the
 # strict 14-family run WITH the stats.json refresh folded in (one
@@ -42,52 +40,9 @@ gen-stubs:
 test:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow'
 
-# Paged-KV decode rows (concurrency per pool byte, mixed-prompt TTFT
-# p99 chunked vs monolithic) -> BENCH_SERVE.json.
-# BENCH_ARGS defaults to --cpu: every bench-* target below runs on the
-# CPU backend unless it is overridden, so their rows are counts and
-# mechanisms, never chip times (PERF.md). `make bench-x BENCH_ARGS=` runs
-# on the attached accelerator; the chip check is `python chip_smoke.py`.
-BENCH_ARGS ?= --cpu
-bench-paged:
-	python bench_decode.py --sections paged $(BENCH_ARGS)
-
-# GSPMD model-parallel decode rows (sharded-vs-single-chip tokens/s +
-# HBM-per-chip headroom on a (2,4) batch x model mesh) ->
-# BENCH_SERVE.json. On CPU hosts the 8-device mesh is the forced
-# virtual one; logits bit-exactness is pinned by tests, not here.
-bench-sharded:
-	python bench_decode.py --sections sharded $(BENCH_ARGS)
-
-# Speculative-decoding rows (ISSUE 16): accept-rate x tokens/s per
-# prompt mix at the self-draft / tiny-draft brackets, the sampled
-# (device-sampler) fallback, and the host-vs-device sampler step
-# delta -> BENCH_SERVE.json. CPU-host caveats: BENCH_NOTES.md,
-# "Speculative decoding (PR 16)".
-bench-spec:
-	python bench_decode.py --sections spec $(BENCH_ARGS)
-
-# Disaggregated prefill/decode rows (ISSUE 17): mixed-length TTFT p99 +
-# inter-token p99 vs the colocated fleet, handoff descriptor bytes +
-# publish->adopt latency, and pages_leaked=0 under prefill-replica
-# SIGKILL churn -> BENCH_SERVE.json, merge-preserving. CPU-host rows
-# measure the splice mechanism, not speedup (BENCH_NOTES.md,
-# "Disaggregated prefill/decode rows (PR 17)").
-bench-disagg:
-	python bench_serve.py --sections disagg $(BENCH_ARGS)
-
-# Tracing/metrics overhead on the decode step loop (instrumented vs
-# stripped engine; acceptance bar <2%) -> BENCH_SERVE.json.
-bench-trace:
-	python bench_decode.py --sections trace_overhead $(BENCH_ARGS)
-
-# Core-plane instrumentation overhead (ISSUE 11 + 15): RPC microbench
-# hot path + decode step loop with core_metrics_enabled on vs off ->
-# BENCH_SERVE.json, plus the pipeline 1F1B step loop traced-vs-
-# untraced and flight-recorder-on-vs-off -> BENCH_TUNE.json (all rows
-# merge-preserving; bar <2% everywhere).
-bench-obs:
-	python bench_obs.py $(BENCH_ARGS)
+# The bench-* targets below run on the CPU backend: their rows are
+# counts and mechanisms, never chip times (PERF.md). The chip check is
+# `python chip_smoke.py`; the chip benchmark is `benchmarks/run.py`.
 
 # Control-plane MTTR (ISSUE 12): SIGKILL the serve controller under
 # live streams via util/faultinject (never ad-hoc kills), measure

@@ -181,12 +181,13 @@ _FLAG_DEFS: Dict[str, tuple] = {
         "callers before being reaped (reference: "
         "maximum_gcs_destroyed_actor_cached_count, ray_config_def.h)."),
     "prefix_pool_entries": (int, 8,
-        "Entries in a DecodeEngine's device-resident prefix KV pool "
-        "(serve/prefix_cache.py): cached prompt prefixes spliced into a "
-        "request's slot at admission so only the uncached suffix is "
-        "prefilled (vLLM/SGLang-style prefix caching on static buckets). "
-        "Each entry costs 2 * L * C_prefix * KV * D cache bytes. "
-        "0 disables the prefix cache."),
+        "On/off switch of a DecodeEngine's prefix index "
+        "(serve/paging.py): pages of completed prompts stay pinned in "
+        "the KV pool and are spliced into a later request's block table "
+        "at admission, so only the uncached suffix is prefilled "
+        "(vLLM/SGLang-style prefix caching on static buckets). Any "
+        "positive value turns it on (kv_prefix_max_pages bounds what it "
+        "pins); 0 disables the prefix cache."),
     "prefix_match_min_tokens": (int, 16,
         "Minimum shared-prefix length (tokens) for a prefix-cache hit; "
         "prompts shorter than this are neither matched nor inserted "
@@ -213,44 +214,42 @@ _FLAG_DEFS: Dict[str, tuple] = {
         "Base backoff before a handle retry; doubles each attempt with "
         "+/-50% jitter so a replica death under load heals instead of "
         "amplifying into a synchronized retry storm on the survivors."),
-    "kv_page_tokens": (int, 0,
-        "Page size (tokens) of a DecodeEngine's paged KV pool. >0 switches "
-        "the engine from per-slot monolithic cache rows to a shared device "
-        "pool of fixed-size pages addressed through per-slot block tables "
-        "(vLLM-style paged attention on static shapes): slots consume only "
-        "the pages their sequence actually covers, prefix sharing splices "
-        "block-table entries with zero device copies, and eviction frees "
-        "page-granular tail segments. Must divide the engine capacity. "
-        "0 = contiguous whole-row cache (pre-paging behavior)."),
+    "kv_page_tokens": (int, 64,
+        "Page size (tokens) of a DecodeEngine's KV pool: K/V of all slots "
+        "live in one device pool of fixed-size pages addressed through "
+        "per-slot block tables (vLLM-style paged attention on static "
+        "shapes): slots consume only the pages their sequence actually "
+        "covers, prefix sharing splices block-table entries with zero "
+        "device copies, and eviction frees page-granular tail segments. "
+        "Must be positive and divide the engine capacity."),
     "kv_pool_pages": (int, 0,
-        "Pages in a paged DecodeEngine's device KV pool. The pool may be "
+        "Pages in a DecodeEngine's device KV pool. The pool may be "
         "OVERCOMMITTED (pages < slots * capacity / kv_page_tokens): more "
         "concurrent sequences fit the same HBM bytes, and when the pool "
         "truly runs dry the engine reclaims prefix-cache pins first and "
         "then preempts the youngest request (recompute-style requeue). "
         "0 = slots * capacity / kv_page_tokens (no overcommit)."),
     "kv_prefix_max_pages": (int, 0,
-        "Cap on pool pages pinned by the paged prefix index (cached "
+        "Cap on pool pages pinned by the prefix index (cached "
         "prompt prefixes kept resident after their request completes). "
         "Past it, least-recently-used tail pages unpin first. "
         "0 = kv_pool_pages // 4."),
     "prefill_chunk_tokens": (int, 0,
-        "Chunked-prefill interleaving for paged DecodeEngines: prompt "
+        "Chunked-prefill interleaving for DecodeEngines: prompt "
         "prefills longer than this run as a sequence of at most one "
         "chunk-sized prefill program per decode step, scheduled between "
         "decode steps — a long admission can stall active streams for at "
         "most ONE chunk instead of its whole prefill. 0 disables "
-        "(monolithic prefill at admission, pre-chunking behavior)."),
+        "(one whole-prompt prefill at admission)."),
     "spec_k": (int, 0,
         "Speculative-decoding depth for DecodeEngines given a draft "
         "model: a small draft model proposes k tokens per active slot "
         "per step and the target model verifies all k+1 positions in "
         "ONE batched forward (the paged ragged-position gather), so a "
-        "step emits 1..k+1 tokens per slot. Greedy output is "
-        "bit-identical to non-speculative decode (longest-matching-"
-        "prefix acceptance); sampled (temperature > 0) requests fall "
-        "back to per-token decode. Requires paged KV (kv_page_tokens "
-        "> 0). 0 disables (pre-spec behavior, byte-identical)."),
+        "step emits 1..k+1 tokens per slot. Greedy output is that of "
+        "the same engine without a draft (longest-matching-prefix "
+        "acceptance); sampled (temperature > 0) requests fall back to "
+        "per-token decode. 0 disables."),
     "spec_draft_model": (str, "",
         "Draft-model preset name (models/llama.PRESETS) for "
         "LlamaDecodeDeployment's speculative mode — a model a few times "
@@ -329,7 +328,7 @@ _FLAG_DEFS: Dict[str, tuple] = {
         "deliver latency + subscriber lag, controller scheduling/heartbeat "
         "instruments. Hot paths pay plain attribute increments only; the "
         "registry is touched at snapshot time by collectors. Off = the "
-        "pre-instrumentation fast path (bench_obs.py measures the delta)."),
+        "pre-instrumentation fast path."),
     "metrics_max_series": (int, 2000,
         "Per-process cap on metric series included in one registry "
         "snapshot push. Past it, overflow series are dropped from the "

@@ -26,6 +26,13 @@ Design for the XLA/TPU execution model:
   HBM-bandwidth-bound (read K/V once); a flash kernel cannot beat the
   plain masked dot XLA emits, so the Pallas path is reserved for prefill
   (``attention_impl="flash"`` with ``q_offset`` chunked prefill).
+
+Two sets of forwards. ``init_cache`` / ``prefill`` / ``decode_step`` /
+``generate`` over a contiguous per-row cache are the plain reference the
+tests hold the engine to (``prefill`` is also the causal half of
+``paged_prefill``). ``serve/decode.py`` runs the six over the paged pool:
+``paged_prefill``, ``paged_prefill_suffix``, ``paged_decode_step``,
+``paged_decode_chunk``, ``paged_verify``, ``paged_spec_draft``.
 """
 
 from __future__ import annotations
@@ -213,80 +220,6 @@ def prefill(params: Dict[str, Any], tokens: jax.Array, cache: Cache,
     return logits, {"k": new_k, "v": new_v, "length": lengths}
 
 
-def prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
-                   cache: Cache, config: LlamaConfig,
-                   prefix_lens: jax.Array, lengths: jax.Array
-                   ) -> Tuple[jax.Array, Cache]:
-    """Suffix-only prefill: process right-padded suffix ``tokens`` (B, S)
-    starting at ``pos = prefix_lens`` against cache rows whose first
-    ``prefix_lens`` positions are ALREADY populated (spliced from a
-    prefix pool — the serve-plane prefix cache's other half).
-
-    ``lengths`` is each row's TOTAL length (prefix + real suffix); the
-    real suffix length is ``lengths - prefix_lens``. Shapes stay static
-    (one program per (B, S) bucket pair); prefix offsets are traced, so
-    the compiled program set does not grow with prefix lengths.
-
-    Masking is exact for the spliced region: a suffix query at absolute
-    position p attends key positions <= p — the cached prefix plus the
-    causal part of the suffix. Stale positions beyond the written suffix
-    are causally invisible here and masked by ``length`` at decode time.
-    Suffix K/V scatters past the padded tail land out of bounds and are
-    dropped by XLA (never clamped into live rows).
-
-    Returns ``(last_logits (B, V) fp32, cache)`` with ``last_logits``
-    taken at each row's final REAL token, exactly like ``prefill``."""
-    c = config
-    B, S = tokens.shape
-    capacity = cache["k"].shape[2]
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
-    x = _cast(params["tok_embed"], c.dtype)[tokens]        # (B, S, E)
-    abs_pos = prefix_lens[:, None] + jnp.arange(S)[None, :]  # (B, S)
-    kv_groups = c.n_heads // c.n_kv_heads
-    scale = c.head_dim ** -0.5
-    rows = jnp.arange(B)
-    valid = (jnp.arange(capacity)[None, None, :]
-             <= abs_pos[:, :, None])                        # (B, S, C)
-
-    def body(x, inp):
-        layer, k_c, v_c = inp                # k_c/v_c: (B, C, KV, D)
-        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-        q, k_new, v_new = _qkv(layer, h, c)  # (B, S, H/KV, D)
-        q = apply_rope(q, cos, sin, positions=abs_pos)
-        k_new = apply_rope(k_new, cos, sin, positions=abs_pos)
-        q = constrain(q, ("batch", "length", "heads", "head_dim"))
-        k_new = constrain(k_new,
-                          ("batch", "length", "kv_heads", "head_dim"))
-        v_new = constrain(v_new,
-                          ("batch", "length", "kv_heads", "head_dim"))
-        k_c = k_c.at[rows[:, None], abs_pos].set(k_new.astype(k_c.dtype))
-        v_c = v_c.at[rows[:, None], abs_pos].set(v_new.astype(v_c.dtype))
-        qg = q.reshape(B, S, c.n_kv_heads, kv_groups, c.head_dim)
-        scores = jnp.einsum("bskgd,bckd->bkgsc", qg, k_c,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        att = jnp.einsum("bkgsc,bckd->bkgsd", probs.astype(v_c.dtype), v_c)
-        att = att.transpose(0, 3, 1, 2, 4).reshape(
-            B, S, c.n_heads, c.head_dim).astype(x.dtype)
-        att = constrain(att, ("batch", "length", "attn_heads", "head_dim"))
-        out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
-        x = x + out
-        x = _mlp(layer, x, c)
-        return x, (k_c, v_c)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
-    idx = jnp.clip(lengths - prefix_lens - 1, 0, S - 1)
-    x_last = jnp.take_along_axis(
-        x, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = jnp.einsum("be,ev->bv", x_last,
-                        _cast(params["lm_head"], c.dtype),
-                        preferred_element_type=jnp.float32)
-    return logits, {"k": new_k, "v": new_v, "length": lengths}
-
-
 def decode_step(params: Dict[str, Any], cache: Cache, tokens: jax.Array,
                 config: LlamaConfig) -> Tuple[jax.Array, Cache]:
     """Append one token per slot and return next-token logits.
@@ -344,41 +277,19 @@ def decode_step(params: Dict[str, Any], cache: Cache, tokens: jax.Array,
     return logits, {"k": new_k, "v": new_v, "length": pos + 1}
 
 
-def decode_chunk(params: Dict[str, Any], cache: Cache, tokens: jax.Array,
-                 config: LlamaConfig, k: int
-                 ) -> Tuple[jax.Array, Cache]:
-    """``k`` greedy decode steps in ONE jitted program (lax.scan): each
-    step's argmax feeds the next. Returns (tokens (k, B), cache).
-
-    This is the dispatch-amortization lever for serving: one device call
-    per K tokens instead of per token — where the per-call dispatch
-    floor dominates (small models) it amortizes that floor over K
-    tokens. The continuous batcher uses it between admission points (greedy
-    requests only; sampling stays per-step)."""
-    def body(carry, _):
-        cache, tok = carry
-        logits, cache = decode_step(params, cache, tok, config)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (cache, nxt), nxt
-
-    (cache, _), toks = jax.lax.scan(body, (cache, tokens), None, length=k)
-    return toks, cache
-
-
 # --------------------------------------------------------------- paged KV
 #
 # vLLM-style paged attention on XLA-friendly static shapes: K/V for ALL
 # slots live in one device pool of ``(pages, page_tokens)`` blocks, and a
 # per-slot block table (int32 page ids, static width) maps logical token
 # positions to pool pages. Attention gathers a slot's pages back into
-# logical order — the gathered layout is value-for-value identical to the
-# contiguous cache, so the masked-dot attention below is BIT-EXACT vs the
-# monolithic path (same values, same reduction order, same masks).
+# logical order — value for value the layout of the reference's
+# contiguous cache (``init_cache``), under the same masked-dot attention.
 #
 # Page id 0 is a reserved scratch page: block-table entries for positions
 # a slot never allocated point at it, so pad writes land somewhere
-# harmless (never read — masking is by per-slot ``length``/causality,
-# exactly like the contiguous path). The host-side allocator
+# harmless (never read — masking is by per-slot ``length``/causality).
+# The host-side allocator
 # (``serve/paging.py``) hands out ids 1..pages.
 
 
@@ -403,9 +314,8 @@ def paged_prefill(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
                   ) -> Tuple[jax.Array, Cache]:
     """Full prefill of right-padded prompts (B, S) scattered into pool
     pages. The attention itself is the plain causal ``prefill`` (a fresh
-    prompt attends only to itself — no pool reads), so logits are
-    bit-exact vs the contiguous path; only the K/V destination differs:
-    position ``p`` of row ``b`` lands in page
+    prompt attends only to itself — no pool reads); only the K/V
+    destination differs: position ``p`` of row ``b`` lands in page
     ``block_tables[b, p // T]`` at offset ``p % T``.
 
     ``block_tables``: (B, W) int32 with ``W * T >= S``. Pad positions
@@ -484,8 +394,7 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
         k_p = k_p.at[pages, offs].set(k_new.astype(k_p.dtype))
         v_p = v_p.at[pages, offs].set(v_new.astype(v_p.dtype))
         # Gather AFTER the scatter so the suffix's own causal K/V is in
-        # view; layout is logical position order, like the contiguous
-        # rows, so attention below is the exact prefill_suffix math.
+        # view; layout is logical position order.
         k_c, v_c = _paged_gather(k_p, v_p, block_tables, c)
         with jax.named_scope("paged_attn"):
             qg = q.reshape(B, S, c.n_kv_heads, kv_groups, c.head_dim)
@@ -521,7 +430,7 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
     """One decode token per slot against paged context. ``tokens``: (B,)
     int32 written at position ``lengths[b]`` of each row's block-mapped
     sequence; attention sees positions ``<= length`` across the row's
-    gathered pages — value-for-value the contiguous ``decode_step``."""
+    gathered pages — value for value the reference ``decode_step``."""
     c = config
     B = tokens.shape[0]
     T = pool["k"].shape[2]
@@ -728,18 +637,19 @@ def paged_spec_draft(params: Dict[str, Any], pool: Cache,
 # with in/out shardings — XLA inserts the collectives (no hand-rolled
 # ring/all-reduce anywhere in the serve plane). The sharding rules
 # (``parallel.sharding.DECODE_RULES``) never partition a contraction
-# dim, so sharded logits are BIT-EXACT vs the single-chip programs:
-# model size scales with the "model" axis (HBM per chip drops), slot
-# count with the "batch" axis, and correctness is byte-identical.
+# dim: model size scales with the "model" axis (HBM per chip drops),
+# slot count with the "batch" axis, and sharded logits are the
+# single-chip programs' within a float tolerance
+# (tests/test_sharded_decode.py).
 
 
 def decode_shardings(config: LlamaConfig, mesh) -> Dict[str, Any]:
     """Sharding bundle for a decode replica on ``mesh`` (a
     ``parallel.mesh.decode_mesh``): NamedShardings for the params pytree,
-    the contiguous KV cache, the paged pool, the contiguous prefix pool,
-    and host-facing (replicated) outputs, plus the resolved rule table.
+    the paged KV pool and host-facing (replicated) outputs, plus the
+    resolved rule table.
 
-    ``cache["length"]`` stays replicated: it is a few bytes, every
+    ``pool["length"]`` stays replicated: it is a few bytes, every
     decode step scatters it at a traced slot index, and the host reads
     it back for admission accounting."""
     from jax.sharding import NamedSharding, PartitionSpec
@@ -752,16 +662,12 @@ def decode_shardings(config: LlamaConfig, mesh) -> Dict[str, Any]:
     def ns(*axes):
         return NamedSharding(mesh, spec_for(axes, rules))
 
-    kv_row = ("layers", "batch", None, "kv_heads", "head_dim")
     pool_row = ("layers", None, None, "kv_heads", "head_dim")
     return {
         "rules": rules,
         "params": tree_shardings(mesh, decode_param_axes(config), rules),
-        "cache": {"k": ns(*kv_row), "v": ns(*kv_row),
-                  "length": NamedSharding(mesh, PartitionSpec())},
         "pool": {"k": ns(*pool_row), "v": ns(*pool_row),
                  "length": NamedSharding(mesh, PartitionSpec())},
-        "prefix_pool": {"k": ns(*pool_row), "v": ns(*pool_row)},
         "replicated": NamedSharding(mesh, PartitionSpec()),
     }
 
@@ -831,9 +737,11 @@ def generate(params: Dict[str, Any], tokens, config: LlamaConfig,
              key=None, eos_id: Optional[int] = None,
              lengths=None) -> jax.Array:
     """Generate ``max_new_tokens`` per prompt row as ONE jitted program
-    (prefill + scanned decode): the benchmark/offline path. Serving uses
-    ``prefill``/``decode_step`` directly through ``serve/decode.py`` so
-    requests can join/leave the batch between steps."""
+    (``prefill`` + scanned ``decode_step`` over an ``init_cache`` row):
+    the plain single-sequence reference that the tests hold engine
+    streams to. No engine calls it: ``serve/decode.py`` serves through
+    the paged programs above, so requests can join/leave the batch
+    between steps."""
     tokens = jnp.asarray(tokens, jnp.int32)
     if tokens.ndim == 1:
         tokens = tokens[None]

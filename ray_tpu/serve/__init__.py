@@ -25,11 +25,6 @@ from ray_tpu.serve.decode import (  # noqa: F401
     LlamaDecodeDeployment,
 )
 from ray_tpu.serve.build import deploy_config  # noqa: F401
-from ray_tpu.serve.prefix_cache import (  # noqa: F401
-    PrefixCache,
-    candidate_hashes,
-    prefix_hash,
-)
 from ray_tpu.serve.deployment import (  # noqa: F401
     AutoscalingConfig,
     Deployment,

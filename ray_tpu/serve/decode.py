@@ -7,9 +7,9 @@ engine is TPU-native and owns the jitted programs directly:
 
 * ONE decode program per (slots, capacity) bucket, compiled once. Requests
   join and leave the running batch between decode steps (continuous
-  batching) — a joining request's prompt is prefetched into its slot by a
-  single-row prefill program, then the shared ``decode_step`` advances
-  every active slot together.
+  batching) — a joining request's prompt is prefilled into the pages of
+  the shared KV pool that its slot's block table maps, then the shared
+  ``paged_decode_step`` advances every active slot together.
 * Static shapes throughout: slot count and cache capacity are fixed at
   engine construction (pick the bucket for your SLO); per-slot ``length``
   masking makes ragged occupancy exact, so there are NO recompiles at
@@ -65,9 +65,7 @@ class _Request:
     submitted_at: float = field(default_factory=time.monotonic)
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
-    prefix_entry: int = -1                 # prefix-pool row spliced in
     prefix_len: int = 0                    # cached tokens NOT re-prefilled
-    # ------------------------------------------------------- paged mode
     prefix_pages: List[int] = field(default_factory=list)  # spliced pages
     prompt_len: int = 0       # ORIGINAL prompt length (tokens grows when
     #   a preempted request re-queues with its emitted tokens absorbed)
@@ -123,8 +121,8 @@ class _Request:
 class DecodeEngine:
     """Continuous batcher over ``llama_decode`` programs.
 
-    ``slots`` concurrent sequences share one KV cache of ``capacity``
-    tokens per slot. ``step()`` advances every active slot one token;
+    ``slots`` concurrent sequences of up to ``capacity`` tokens share
+    one paged KV pool. ``step()`` advances every active slot one token;
     ``submit()`` enqueues a request (prefilled into a free slot at the
     next step boundary). Run ``serve_forever`` in a thread inside a
     replica, or drive ``step()`` manually in tests."""
@@ -133,7 +131,6 @@ class DecodeEngine:
                  capacity: int = 1024, prefill_bucket: int = 128,
                  decode_chunk: int = 1,
                  prefix_pool_entries: Optional[int] = None,
-                 prefix_capacity: Optional[int] = None,
                  prefix_match_min_tokens: Optional[int] = None,
                  queue_max: Optional[int] = None,
                  page_tokens: Optional[int] = None,
@@ -153,7 +150,7 @@ class DecodeEngine:
 
         from ray_tpu.core.config import config as rt_config
         from ray_tpu.models import llama_decode as ld
-        from ray_tpu.serve.prefix_cache import PrefixCache
+        from ray_tpu.serve.paging import PageAllocator, PagedPrefixIndex
 
         self._jax = jax
         self._ld = ld
@@ -172,8 +169,7 @@ class DecodeEngine:
         # KV state and activations carry NamedShardings; the jitted
         # programs below get out_shardings and trace under the decode
         # axis rules (parallel.sharding.DECODE_RULES) — XLA inserts all
-        # collectives, and because no contraction dim is ever
-        # partitioned, logits stay BIT-EXACT vs the single-chip engine.
+        # collectives, and no contraction dim is ever partitioned.
         if mesh is None:
             ms = mesh_shape
             if ms is None and rt_config.decode_mesh_shape:
@@ -190,7 +186,7 @@ class DecodeEngine:
             if slots % batch_ax:
                 raise ValueError(
                     f"slots ({slots}) must be a multiple of the mesh "
-                    f"batch axis ({batch_ax}) — per-slot cache rows "
+                    f"batch axis ({batch_ax}) — per-slot activations "
                     f"shard over it")
             self.params, self._shardings = ld.shard_decode_state(
                 params, config, mesh)
@@ -199,59 +195,51 @@ class DecodeEngine:
             self._shardings = None
             self._rules = None
         # -------------------------------------------------- paged KV pool
-        # page_tokens > 0 switches from per-slot monolithic cache rows to
-        # a shared device pool of fixed-size pages addressed through
-        # per-slot block tables: slots hold only the pages their sequence
-        # covers, prefix hits splice page ids with zero copies, and the
-        # pool may be overcommitted (more slots than whole rows fit).
+        # K/V for all slots live in one device pool of fixed-size pages
+        # addressed through per-slot block tables: slots hold only the
+        # pages their sequence covers, prefix hits splice page ids with
+        # zero copies, and the pool may be overcommitted (more slots
+        # than whole rows fit).
         pt = (rt_config.kv_page_tokens if page_tokens is None
               else page_tokens)
         self.page_tokens = int(pt)
-        self.paged = self.page_tokens > 0
+        if self.page_tokens <= 0:
+            raise ValueError(
+                f"kv_page_tokens must be positive, got {self.page_tokens}")
+        if capacity % self.page_tokens:
+            raise ValueError(
+                f"capacity ({capacity}) must be a multiple of "
+                f"kv_page_tokens ({self.page_tokens})")
+        # Chunked-prefill interleaving rides on the suffix program (a
+        # chunk IS a suffix prefill from pos=prefilled).
         chunk_tok = (rt_config.prefill_chunk_tokens
                      if prefill_chunk_tokens is None
                      else prefill_chunk_tokens)
-        # Chunked-prefill interleaving rides on the paged suffix program
-        # (a chunk IS a suffix prefill from pos=prefilled); contiguous
-        # engines ignore it and keep monolithic admission.
-        self.prefill_chunk_tokens = (int(chunk_tok) if self.paged else 0)
+        self.prefill_chunk_tokens = int(chunk_tok)
         if self.prefill_chunk_tokens:
             c = 1
             while c * 2 <= self.prefill_chunk_tokens:
                 c *= 2
             self.prefill_chunk_tokens = c  # pow2: bounds the bucket set
-        if self.paged:
-            if capacity % self.page_tokens:
-                raise ValueError(
-                    f"capacity ({capacity}) must be a multiple of "
-                    f"kv_page_tokens ({self.page_tokens})")
-            from ray_tpu.serve.paging import PageAllocator
-
-            self.slot_pages_max = capacity // self.page_tokens
-            pp = (rt_config.kv_pool_pages if pool_pages is None
-                  else pool_pages)
-            self.pool_pages = int(pp) or slots * self.slot_pages_max
-            self._pages = PageAllocator(self.pool_pages)
-            pool = ld.init_page_pool(config, self.pool_pages,
-                                     self.page_tokens)
-            self.cache = {"k": pool["k"], "v": pool["v"],
-                          "length": jax.numpy.zeros((slots,),
-                                                    jax.numpy.int32)}
-            self._block_tables = np.zeros(
-                (slots, self.slot_pages_max), np.int32)
-            self._slot_pages: List[List[int]] = [[] for _ in range(slots)]
-        else:
-            self._pages = None
-            self.cache = ld.init_cache(config, slots, capacity)
+        self.slot_pages_max = capacity // self.page_tokens
+        pp = (rt_config.kv_pool_pages if pool_pages is None
+              else pool_pages)
+        self.pool_pages = int(pp) or slots * self.slot_pages_max
+        self._pages = PageAllocator(self.pool_pages)
+        pool = ld.init_page_pool(config, self.pool_pages,
+                                 self.page_tokens)
+        self.cache = {"k": pool["k"], "v": pool["v"],
+                      "length": jax.numpy.zeros((slots,),
+                                                jax.numpy.int32)}
+        self._block_tables = np.zeros(
+            (slots, self.slot_pages_max), np.int32)
+        self._slot_pages: List[List[int]] = [[] for _ in range(slots)]
         if self.mesh is not None:
             # Commit the KV state onto the mesh: the shared page pool
             # shards its kv-head dim over "model" (HBM-per-chip drops
-            # with the model axis); contiguous rows additionally shard
-            # slots over "batch". ``length`` stays replicated (bytes,
+            # with the model axis). ``length`` stays replicated (bytes,
             # host-read every step).
-            self._cache_sharding = dict(
-                self._shardings["pool"] if self.paged
-                else self._shardings["cache"])
+            self._cache_sharding = dict(self._shardings["pool"])
             self.cache = jax.device_put(self.cache, self._cache_sharding)
         else:
             self._cache_sharding = None
@@ -296,45 +284,22 @@ class DecodeEngine:
         self.prefill_chunks = 0     # chunked-prefill programs dispatched
         self._ema_request_s = 0.0   # EMA of admitted-request service time
         self._last_purge = 0.0      # dead-entry queue-purge throttle
-        # Prefix KV cache. Contiguous mode: a device-resident pool of
-        # cached prompt-prefix K/V (P entries x C_prefix tokens) indexed
-        # by a host-side trie; admission splices an entry row into the
-        # slot and prefills only the suffix. Paged mode: the index pins
-        # PAGE RANGES of the shared pool instead (PagedPrefixIndex) —
-        # inserts and splices are zero-copy block-table edits.
+        # Prefix KV cache: the index pins PAGE RANGES of the shared pool
+        # (PagedPrefixIndex) — inserts and splices are zero-copy
+        # block-table edits. ``prefix_pool_entries`` 0 turns it off.
         entries = (rt_config.prefix_pool_entries
                    if prefix_pool_entries is None else prefix_pool_entries)
         min_tokens = (rt_config.prefix_match_min_tokens
                       if prefix_match_min_tokens is None
                       else prefix_match_min_tokens)
-        if prefix_capacity is None:
-            prefix_capacity = 1
-            while prefix_capacity * 2 <= capacity // 2:
-                prefix_capacity *= 2
         self.prefix = None
-        self._pool = None
-        if self.paged:
-            if entries > 0:
-                from ray_tpu.serve.paging import PagedPrefixIndex
-
-                pmax = (rt_config.kv_prefix_max_pages
-                        if prefix_max_pages is None else prefix_max_pages)
-                self.prefix = PagedPrefixIndex(
-                    self._pages, self.page_tokens,
-                    max_pages=int(pmax) or max(1, self.pool_pages // 4),
-                    min_tokens=min_tokens)
-        elif entries > 0 and prefix_capacity >= max(2, min_tokens):
-            self.prefix = PrefixCache(entries, prefix_capacity,
-                                      min_tokens=min_tokens)
-            c = config
-            pool_shape = (c.n_layers, entries, prefix_capacity,
-                          c.n_kv_heads, c.head_dim)
-            import jax.numpy as jnp
-            self._pool = {"k": jnp.zeros(pool_shape, c.dtype),
-                          "v": jnp.zeros(pool_shape, c.dtype)}
-            if self.mesh is not None:
-                self._pool = jax.device_put(
-                    self._pool, self._shardings["prefix_pool"])
+        if entries > 0:
+            pmax = (rt_config.kv_prefix_max_pages
+                    if prefix_max_pages is None else prefix_max_pages)
+            self.prefix = PagedPrefixIndex(
+                self._pages, self.page_tokens,
+                max_pages=int(pmax) or max(1, self.pool_pages // 4),
+                min_tokens=min_tokens)
         # ------------------------------------------- speculative decoding
         # A draft model proposes spec_k tokens per active slot per step;
         # the target verifies all k+1 positions in ONE batched forward
@@ -350,12 +315,6 @@ class DecodeEngine:
         self.spec_k = int(sk)
         self.spec = self.spec_k > 0 and spec_draft_params is not None
         if self.spec:
-            if not self.paged:
-                raise ValueError(
-                    "speculative decoding requires paged KV "
-                    "(kv_page_tokens > 0): the verify forward and the "
-                    "rollback cursor are page-table operations")
-            from ray_tpu.serve.paging import PageAllocator
             self._draft_config = spec_draft_config
             dpp = (rt_config.spec_draft_pool_pages
                    if spec_draft_pool_pages is None
@@ -409,10 +368,9 @@ class DecodeEngine:
         # whole point is that the suffix is short, so padding it back up
         # to prefill_bucket would refund most of the win.
         self._suffix_bucket_min = max(8, min(16, prefill_bucket))
-        # Per-(bucket) jitted single-slot prefill: writes one row of the
-        # shared cache. Donating the cache makes the slot insert in-place.
         # Params are ARGUMENTS (not closure captures), or jit would bake
-        # the weights into the program as constants.
+        # the weights into the program as constants; donating the cache
+        # makes every KV write in-place.
         # Mesh engines pin program outputs to the committed shardings
         # (logits/token outputs replicated for the host sampler, KV
         # state staying exactly where device_put placed it, so
@@ -422,71 +380,43 @@ class DecodeEngine:
         if self.mesh is not None:
             rep = self._shardings["replicated"]
             cache_out = {"out_shardings": (rep, self._cache_sharding)}
-            pool_ins = {"out_shardings": (
-                self._shardings["prefix_pool"]["k"],
-                self._shardings["prefix_pool"]["v"])}
         else:
             cache_out = {}
-            pool_ins = {}
-        if self.paged:
-            # Paged programs: same (n, bucket) jit-bucket discipline, but
-            # admission scatters K/V into pool pages through the wave's
-            # block tables, the suffix program doubles as the chunked-
-            # prefill continuation, and decode gathers each slot's pages
-            # back into logical order (bit-exact vs the contiguous dot).
-            # ``width`` (suffix) = static leading block-table columns the
-            # wave touches — cost scales with prefix+suffix, not max
-            # context, exactly like the contiguous ``lim``.
-            self._paged_prefill = self._mesh_scoped(self._program(
-                "paged_prefill", self._paged_prefill_impl,
-                static_argnames=("n", "bucket"),
-                donate_argnums=(1,), **cache_out))
-            self._paged_suffix = self._mesh_scoped(self._program(
-                "paged_suffix", self._paged_suffix_impl,
-                static_argnames=("n", "bucket", "width"),
-                donate_argnums=(1,), **cache_out))
-            self._decode = self._mesh_scoped(self._program(
-                "decode", self._paged_decode_impl, donate_argnums=(1,),
-                **cache_out))
-            # Disaggregated adopt: scatter handed-off page payloads into
-            # the pool (pure data movement, no model math) and park the
-            # slot cursor at the committed length. Cache-only output, so
-            # mesh engines pin just the cache sharding (the
-            # draft_cache_only precedent below).
-            self._adopt_pages = self._mesh_scoped(self._program(
-                "adopt_pages", self._adopt_pages_impl,
-                static_argnames=("width",),
-                donate_argnums=(0,),
-                **({"out_shardings": self._cache_sharding}
-                   if self.mesh is not None else {})))
-        else:
-            self._prefill_many = self._mesh_scoped(self._program(
-                "prefill", self._prefill_many_impl,
-                static_argnames=("n", "bucket"),
-                donate_argnums=(1,), **cache_out))
-            # Prefix-hit admission: splice pool entries into the wave's
-            # slots and prefill only the suffixes — one program per
-            # (n, bucket) power-of-two pair, like _prefill_many. Pool
-            # insert copies a freshly prefilled slot's leading positions
-            # into a pool row.
-            self._prefill_suffix_many = self._mesh_scoped(self._program(
-                "suffix", self._prefill_suffix_many_impl,
-                static_argnames=("n", "bucket"), donate_argnums=(1,),
-                **cache_out))
-            self._pool_insert = self._mesh_scoped(self._program(
-                "pool_insert", self._pool_insert_impl,
-                donate_argnums=(1, 2),
-                **pool_ins))
-            self._decode = self._mesh_scoped(self._program(
-                "decode", self._decode_impl, donate_argnums=(1,),
-                **cache_out))
+        # One program per (n, bucket) power-of-two pair: admission
+        # scatters K/V into pool pages through the wave's block tables,
+        # the suffix program doubles as the chunked-prefill
+        # continuation, and decode gathers each slot's pages back into
+        # logical order. ``width`` (suffix) = static leading block-table
+        # columns the wave touches — cost scales with prefix+suffix,
+        # not max context.
+        self._paged_prefill = self._mesh_scoped(self._program(
+            "paged_prefill", self._paged_prefill_impl,
+            static_argnames=("n", "bucket"),
+            donate_argnums=(1,), **cache_out))
+        self._paged_suffix = self._mesh_scoped(self._program(
+            "paged_suffix", self._paged_suffix_impl,
+            static_argnames=("n", "bucket", "width"),
+            donate_argnums=(1,), **cache_out))
+        self._decode = self._mesh_scoped(self._program(
+            "decode", self._paged_decode_impl, donate_argnums=(1,),
+            **cache_out))
+        # Disaggregated adopt: scatter handed-off page payloads into
+        # the pool (pure data movement, no model math) and park the
+        # slot cursor at the committed length. Cache-only output, so
+        # mesh engines pin just the cache sharding (the
+        # draft_cache_only precedent below).
+        self._adopt_pages = self._mesh_scoped(self._program(
+            "adopt_pages", self._adopt_pages_impl,
+            static_argnames=("width",),
+            donate_argnums=(0,),
+            **({"out_shardings": self._cache_sharding}
+               if self.mesh is not None else {})))
         # K greedy steps per device call (dispatch amortization); chunking
         # only engages when no admissions are pending and every active
         # request is greedy — sampling and joins stay per-token exact.
         self.decode_chunk = max(1, int(decode_chunk))
         self._decode_k = self._mesh_scoped(self._program(
-            "decode_k", self._paged_decode_chunk_impl if self.paged
-            else self._decode_chunk_impl,
+            "decode_k", self._paged_decode_chunk_impl,
             static_argnames=("k",), donate_argnums=(1,), **cache_out))
         # Speculative programs: target verify (all-position argmax over
         # the slot's pages, donated KV) and the draft's own prefill +
@@ -517,16 +447,13 @@ class DecodeEngine:
                 "draft_prefill", self._draft_prefill_impl,
                 static_argnames=("n", "bucket"), donate_argnums=(1,),
                 **draft_cache_only), rules=self._draft_rules)
-        # Fused device sampler (paged and contiguous flavors): one
-        # program returning sampled token ids; per-row temperatures pick
-        # argmax vs categorical, the PRNG key derives from the step
-        # counter in-program.
+        # Fused device sampler: one program returning sampled token ids;
+        # per-row temperatures pick argmax vs categorical, the PRNG key
+        # derives from the step counter in-program.
         if self._device_sampler:
             self._decode_sampled = self._mesh_scoped(self._program(
-                "decode_sampled",
-                self._paged_decode_sampled_impl if self.paged
-                else self._decode_sampled_impl, donate_argnums=(1,),
-                **cache_out))
+                "decode_sampled", self._paged_decode_sampled_impl,
+                donate_argnums=(1,), **cache_out))
         self.steps = 0
         self.tokens_out = 0
         # ---------------------------------------------- observability
@@ -599,71 +526,6 @@ class DecodeEngine:
         return scoped
 
     # ------------------------------------------------------ jitted bodies
-
-    def _prefill_many_impl(self, params, cache, tokens_rows, lengths,
-                           slot_ids, n, bucket):
-        """Batched admission: prefill ``n`` rows in ONE device call and
-        scatter their K/V into the shared cache at ``slot_ids``. One
-        compiled program per (n, bucket) power-of-two pair — dispatch
-        overhead amortizes over the whole admission wave."""
-        ld, cfg = self._ld, self.config
-        batch = ld.init_cache(cfg, n, self.capacity)
-        logits, batch = ld.prefill(params, tokens_rows[:, :bucket],
-                                   batch, cfg, lengths=lengths)
-        s = batch["k"].shape[2]
-        new = {
-            "k": cache["k"].at[:, slot_ids, :s].set(batch["k"]),
-            "v": cache["v"].at[:, slot_ids, :s].set(batch["v"]),
-            "length": cache["length"].at[slot_ids].set(lengths),
-        }
-        return logits, new
-
-    def _prefill_suffix_many_impl(self, params, cache, pool_k, pool_v,
-                                  entry_ids, slot_ids, suffix_rows,
-                                  prefix_lens, lengths, n, bucket):
-        """Prefix-hit admission in ONE device call: gather the wave's
-        slot rows, splice the matched pool entries over their leading
-        ``C_prefix`` positions, suffix-prefill from ``pos=prefix_lens``,
-        and scatter the rows back. The splice copies the WHOLE entry
-        region unconditionally (static shape): positions past the match
-        are overwritten by the suffix or causally masked, never read."""
-        ld = self._ld
-        cp = pool_k.shape[2]
-        # Every read/write in this program lands below prefix+suffix
-        # (prefix_lens <= C_prefix, suffix spans `bucket`), so the
-        # gather, attention, and scatter run over that STATIC bound
-        # instead of the full capacity — the suffix path's cost scales
-        # with what it touches, not with the engine's max context.
-        lim = min(self.capacity, cp + bucket)
-        rows_k = cache["k"][:, slot_ids, :lim]    # (L, n, lim, KV, D)
-        rows_v = cache["v"][:, slot_ids, :lim]
-        rows_k = rows_k.at[:, :, :cp].set(pool_k[:, entry_ids])
-        rows_v = rows_v.at[:, :, :cp].set(pool_v[:, entry_ids])
-        row_cache = {"k": rows_k, "v": rows_v, "length": lengths}
-        logits, row_cache = ld.prefill_suffix(
-            params, suffix_rows[:, :bucket], row_cache, self.config,
-            prefix_lens, lengths)
-        new = {
-            "k": cache["k"].at[:, slot_ids, :lim].set(row_cache["k"]),
-            "v": cache["v"].at[:, slot_ids, :lim].set(row_cache["v"]),
-            "length": cache["length"].at[slot_ids].set(lengths),
-        }
-        return logits, new
-
-    def _pool_insert_impl(self, cache, pool_k, pool_v, slot, entry):
-        cp = pool_k.shape[2]
-        new_k = pool_k.at[:, entry].set(cache["k"][:, slot, :cp])
-        new_v = pool_v.at[:, entry].set(cache["v"][:, slot, :cp])
-        return new_k, new_v
-
-    def _decode_impl(self, params, cache, tokens):
-        return self._ld.decode_step(params, cache, tokens, self.config)
-
-    def _decode_chunk_impl(self, params, cache, tokens, k):
-        return self._ld.decode_chunk(params, cache, tokens, self.config,
-                                     k)
-
-    # ------------------------------------------------ paged jitted bodies
 
     def _paged_prefill_impl(self, params, cache, tokens_rows, lengths,
                             bt, slot_ids, n, bucket):
@@ -778,15 +640,6 @@ class DecodeEngine:
         key = jax.random.fold_in(jax.random.key(0), step)
         toks = self._ld.sample_batch(logits, temps, key)
         return toks, {"k": pool["k"], "v": pool["v"], "length": lens}
-
-    def _decode_sampled_impl(self, params, cache, tokens, temps, step):
-        import jax
-
-        logits, cache = self._ld.decode_step(params, cache, tokens,
-                                             self.config)
-        key = jax.random.fold_in(jax.random.key(0), step)
-        toks = self._ld.sample_batch(logits, temps, key)
-        return toks, cache
 
     def _dispatch_fresh(self, key: tuple, call,
                         then: Optional[str] = None, **attrs):
@@ -985,7 +838,7 @@ class DecodeEngine:
         # nothing) and the pages it held.
         discarded = (req.prefilled if slot in self._prefilling
                      else len(req.tokens)) - req.prefix_len
-        held = len(self._slot_pages[slot]) if self.paged else 0
+        held = len(self._slot_pages[slot])
         self._active.pop(slot, None)
         self._prefilling.pop(slot, None)
         self._release_slot(slot)
@@ -1046,14 +899,11 @@ class DecodeEngine:
                        on_token, on_done=on_done)
         req.request_id = request_id or f"req-{next(_req_ids)}"
         req.prompt_len = len(req.tokens)
-        if prefill_only and not self.paged:
-            raise ValueError("prefill_only handoff requires a paged "
-                             "engine (kv_page_tokens > 0)")
         req.prefill_only = bool(prefill_only)
         if adopt is not None:
             self._validate_adopt(req, adopt)
             req.adopt = dict(adopt)
-        if self.paged and self._seq_pages(
+        if self._seq_pages(
                 len(req.tokens) + req.max_new_tokens) > self.pool_pages:
             # A request no amount of preemption can seat must fail fast,
             # not live forever in the requeue list.
@@ -1130,9 +980,6 @@ class DecodeEngine:
         geometry and head layout, and must cover exactly the prompt."""
         from ray_tpu.core.errors import HandoffAdoptError
 
-        if not self.paged:
-            raise HandoffAdoptError(
-                "adopt requires a paged engine (kv_page_tokens > 0)")
         if int(adopt["page_tokens"]) != self.page_tokens:
             raise HandoffAdoptError(
                 f"handoff page_tokens ({adopt['page_tokens']}) != this "
@@ -1321,26 +1168,11 @@ class DecodeEngine:
                     live.append(req)
             if not live:
                 continue
-            if self.paged:
-                if not self._admit_paged(live):
-                    return  # pool dry: stop admitting this step
-                continue
-            hits: List[_Request] = []
-            misses: List[_Request] = []
-            for req in live:
-                m = (self.prefix.match(req.tokens)
-                     if self.prefix is not None else None)
-                if m is not None:
-                    req.prefix_entry, req.prefix_len = m
-                    hits.append(req)
-                else:
-                    misses.append(req)
-            self._mark_admitted(live)
-            self._admit_full(misses)
-            self._admit_suffix(hits)
+            if not self._admit_paged(live):
+                return  # pool dry: stop admitting this step
 
     def _admit_paged(self, live: List[_Request]) -> bool:
-        """Seat a wave in paged mode: prefix pages splice into the slot's
+        """Seat a wave: prefix pages splice into the slot's
         block table with ZERO device copies, fresh pages come from the
         allocator, and long prefills hand off to the chunked-prefill
         interleaver instead of running one monolithic program. Returns
@@ -1714,118 +1546,16 @@ class DecodeEngine:
                 self._retire(req, "cancelled" if dead
                              else "deadline_exceeded")
 
-    def _admit_full(self, reqs: List[_Request]) -> None:
-        import jax.numpy as jnp
-
-        ld = self._ld
-        by_bucket: Dict[int, List[_Request]] = {}
-        for req in reqs:
-            bucket = min(ld.cache_bucket(len(req.tokens),
-                                         self.prefill_bucket),
-                         self.capacity)
-            by_bucket.setdefault(bucket, []).append(req)
-        for bucket, group in by_bucket.items():
-            slots = [self._free.pop() for _ in group]
-            # Pad the admission count to a power of two (bounded
-            # program set); pad rows REPEAT the last real row into
-            # the same slot — an idempotent overwrite.
-            n = 1
-            while n < len(group):
-                n *= 2
-            rows = np.zeros((n, bucket), np.int32)
-            lengths = np.zeros((n,), np.int32)
-            slot_ids = np.full((n,), slots[-1], np.int32)
-            for i, req in enumerate(group):
-                rows[i, :len(req.tokens)] = req.tokens
-                lengths[i] = len(req.tokens)
-                slot_ids[i] = slots[i]
-            for i in range(len(group), n):  # idempotent pad rows
-                rows[i] = rows[len(group) - 1]
-                lengths[i] = lengths[len(group) - 1]
-            self._prefill_waves += 1
-            t0 = time.time()
-            logits, self.cache = self._dispatch_fresh(
-                ("prefill", n, bucket),
-                lambda: self._prefill_many(
-                    self.params, self.cache, jnp.asarray(rows),
-                    jnp.asarray(lengths), jnp.asarray(slot_ids),
-                    n=n, bucket=bucket),
-                tokens=sum(len(r.tokens) for r in group))
-            self._slice("fetch", program="prefill")
-            logits = np.array(logits)
-            self._slice("admit")
-            self._wave_span("prefill", t0, group, n=len(group),
-                            bucket=bucket)
-            self._post_admit(group, slots, logits)
-
-    def _admit_suffix(self, reqs: List[_Request]) -> None:
-        """Prefix-hit admissions: splice the matched pool entry into each
-        request's slot and prefill only the uncached suffix."""
-        import jax.numpy as jnp
-
-        ld = self._ld
-        by_bucket: Dict[int, List[_Request]] = {}
-        for req in reqs:
-            suffix_len = len(req.tokens) - req.prefix_len
-            bucket = min(ld.cache_bucket(suffix_len,
-                                         self._suffix_bucket_min),
-                         self.capacity)
-            by_bucket.setdefault(bucket, []).append(req)
-        for bucket, group in by_bucket.items():
-            slots = [self._free.pop() for _ in group]
-            n = 1
-            while n < len(group):
-                n *= 2
-            rows = np.zeros((n, bucket), np.int32)
-            plens = np.zeros((n,), np.int32)
-            lengths = np.zeros((n,), np.int32)
-            entries = np.zeros((n,), np.int32)
-            slot_ids = np.full((n,), slots[-1], np.int32)
-            for i, req in enumerate(group):
-                suffix = req.tokens[req.prefix_len:]
-                rows[i, :len(suffix)] = suffix
-                plens[i] = req.prefix_len
-                lengths[i] = len(req.tokens)
-                entries[i] = req.prefix_entry
-                slot_ids[i] = slots[i]
-            for i in range(len(group), n):  # idempotent pad rows
-                rows[i] = rows[len(group) - 1]
-                plens[i] = plens[len(group) - 1]
-                lengths[i] = lengths[len(group) - 1]
-                entries[i] = entries[len(group) - 1]
-            self._prefill_waves += 1
-            t0 = time.time()
-            logits, self.cache = self._dispatch_fresh(
-                ("suffix", n, bucket),
-                lambda: self._prefill_suffix_many(
-                    self.params, self.cache, self._pool["k"],
-                    self._pool["v"], jnp.asarray(entries),
-                    jnp.asarray(slot_ids), jnp.asarray(rows),
-                    jnp.asarray(plens), jnp.asarray(lengths),
-                    n=n, bucket=bucket),
-                tokens=sum(len(r.tokens) - r.prefix_len for r in group))
-            self._slice("fetch", program="suffix")
-            logits = np.array(logits)
-            self._slice("admit")
-            self._wave_span("suffix-prefill", t0, group, n=len(group),
-                            bucket=bucket)
-            for req in group:
-                # The splice program holding the entry is dispatched (and
-                # device order is program order), so the row may now be
-                # recycled without racing the read.
-                self.prefix.release(req.prefix_entry)
-            self._post_admit(group, slots, logits)
-
     def _post_admit(self, group: List[_Request], slots: List[int],
                     logits: np.ndarray) -> None:
-        # Paged prefix insert runs BEFORE the emit/finish loop: a
+        # The prefix insert runs BEFORE the emit/finish loop: a
         # request that completes on its very first token (max_new=1 /
         # instant EOS) is _finish-ed inside that loop, which FREES its
         # pages — pinning them afterwards would pin recycled (soon
         # overwritten) pages. Inserting first pins the slot's pages
         # while the slot still owns them; _finish then drops only the
         # slot's own references.
-        if self.prefix is not None and self.paged:
+        if self.prefix is not None:
             for req, slot in zip(group, slots):
                 self.prefix.insert(req.tokens, self._slot_pages[slot],
                                    matched_len=req.prefix_len)
@@ -1855,24 +1585,6 @@ class DecodeEngine:
             if req.generated >= req.max_new_tokens or (
                     req.eos_id is not None and tok == req.eos_id):
                 self._finish(slots[i])
-        # Contiguous insert stays AFTER: it copies the slot's leading
-        # positions into a separate pool row on device, and the rows
-        # still hold the full prompt K/V (a _finish only parks
-        # ``length``). Pool inserts dedup on the token key either way,
-        # and run before any later admission can recycle these slots.
-        if self.prefix is not None and not self.paged:
-            for req, slot in zip(group, slots):
-                ins = self.prefix.insert(req.tokens,
-                                         matched_len=req.prefix_len)
-                if ins is not None:
-                    row, _ins_len = ins
-                    self._pool["k"], self._pool["v"] = \
-                        self._dispatch_fresh(
-                            ("pool_insert",),
-                            lambda: self._pool_insert(
-                                self.cache, self._pool["k"],
-                                self._pool["v"], slot, row),
-                            then="admit")
         if self.spec:
             self._draft_seat([r for r in group if not r.done.is_set()])
 
@@ -2034,18 +1746,17 @@ class DecodeEngine:
                            req.request_id, exc_info=True)
 
     def _release_slot(self, slot: int) -> None:
-        """Slot teardown shared by _finish and preemption: paged mode
-        drops the slot's page references (shared prefix pages survive on
-        the index's pins; exclusively-owned pages recycle immediately)
-        and parks the block-table row on the scratch page."""
-        if self.paged:
-            pages = self._slot_pages[slot]
-            self._slot_pages[slot] = []
-            self._block_tables[slot, :] = 0
-            self._pages.free(pages)
-            if pages and self.steplog.enabled:
-                self.steplog.event("page-free", n=len(pages),
-                                   free=self._pages.free_count)
+        """Slot teardown shared by _finish and preemption: drops the
+        slot's page references (shared prefix pages survive on the
+        index's pins; exclusively-owned pages recycle immediately) and
+        parks the block-table row on the scratch page."""
+        pages = self._slot_pages[slot]
+        self._slot_pages[slot] = []
+        self._block_tables[slot, :] = 0
+        self._pages.free(pages)
+        if pages and self.steplog.enabled:
+            self.steplog.event("page-free", n=len(pages),
+                               free=self._pages.free_count)
         if self.spec:
             dpages = self._draft_slot_pages[slot]
             self._draft_slot_pages[slot] = []
@@ -2169,12 +1880,11 @@ class DecodeEngine:
             phases.append({"phase": "admit", "t0": t_step0,
                            "t1": time.time(),
                            "waves": self._prefill_waves - w0})
-        if self.paged:
-            t0 = time.time() if rec else 0.0
-            self._prefill_tick()
-            if rec and self.prefill_chunks > c0:
-                phases.append({"phase": "prefill_chunk", "t0": t0,
-                               "t1": time.time()})
+        t0 = time.time() if rec else 0.0
+        self._prefill_tick()
+        if rec and self.prefill_chunks > c0:
+            phases.append({"phase": "prefill_chunk", "t0": t0,
+                           "t1": time.time()})
         if not self._active:
             self._steplog_row(t_step0, phases)
             return 0
@@ -2195,34 +1905,25 @@ class DecodeEngine:
         if rec:
             sl.begin("pages")
         chunk = self._pick_chunk()
-        if self.paged:
-            # Page the next k tokens in BEFORE the program runs: the
-            # block tables are static across the call. May preempt the
-            # youngest request (and so shrink the active set).
-            self._ensure_decode_pages(chunk)
-            if not self._active:
-                self._steplog_row(t_step0, phases)
-                return 0
-            chunk = min(chunk, self._pick_chunk())
+        # Page the next k tokens in BEFORE the program runs: the block
+        # tables are static across the call. May preempt the youngest
+        # request (and so shrink the active set).
+        self._ensure_decode_pages(chunk)
+        if not self._active:
+            self._steplog_row(t_step0, phases)
+            return 0
+        chunk = min(chunk, self._pick_chunk())
         stepped = len(self._active)
         ctx = self._ctx_tokens() if rec else None
         if chunk > 1:
             t_d0 = time.time() if rec else 0.0
-            if self.paged:
-                toks, self.cache = self._dispatch_fresh(
-                    ("decode_k", chunk),
-                    lambda: self._decode_k(
-                        self.params, self.cache,
-                        jnp.asarray(self._tokens),
-                        jnp.asarray(self._block_tables), k=chunk),
-                    batch=stepped, ctx_tokens=ctx)
-            else:
-                toks, self.cache = self._dispatch_fresh(
-                    ("decode_k", chunk),
-                    lambda: self._decode_k(
-                        self.params, self.cache,
-                        jnp.asarray(self._tokens), k=chunk),
-                    batch=stepped, ctx_tokens=ctx)
+            toks, self.cache = self._dispatch_fresh(
+                ("decode_k", chunk),
+                lambda: self._decode_k(
+                    self.params, self.cache,
+                    jnp.asarray(self._tokens),
+                    jnp.asarray(self._block_tables), k=chunk),
+                batch=stepped, ctx_tokens=ctx)
             if rec:
                 sl.begin("fetch", program="decode_k")
             toks = np.array(toks)  # (chunk, slots)
@@ -2249,20 +1950,12 @@ class DecodeEngine:
         if self._device_sampler:
             return self._sampled_step(t_step0, phases, rec, ctx)
         t_d0 = time.time() if rec else 0.0
-        if self.paged:
-            logits, self.cache = self._dispatch_fresh(
-                ("decode",),
-                lambda: self._decode(
-                    self.params, self.cache, jnp.asarray(self._tokens),
-                    jnp.asarray(self._block_tables)),
-                batch=stepped, ctx_tokens=ctx)
-        else:
-            logits, self.cache = self._dispatch_fresh(
-                ("decode",),
-                lambda: self._decode(
-                    self.params, self.cache,
-                    jnp.asarray(self._tokens)),
-                batch=stepped, ctx_tokens=ctx)
+        logits, self.cache = self._dispatch_fresh(
+            ("decode",),
+            lambda: self._decode(
+                self.params, self.cache, jnp.asarray(self._tokens),
+                jnp.asarray(self._block_tables)),
+            batch=stepped, ctx_tokens=ctx)
         if rec:
             sl.begin("fetch", program="decode")
         logits = np.array(logits)
@@ -2454,21 +2147,13 @@ class DecodeEngine:
         tin = (self._tokens_dev if self._tokens_dev is not None
                else jnp.asarray(self._tokens))
         t_d0 = time.time() if rec else 0.0
-        if self.paged:
-            toks_dev, self.cache = self._dispatch_fresh(
-                ("decode_sampled",),
-                lambda: self._decode_sampled(
-                    self.params, self.cache, tin,
-                    jnp.asarray(self._block_tables), jnp.asarray(temps),
-                    jnp.asarray(self.steps, jnp.int32)),
-                batch=stepped, ctx_tokens=ctx)
-        else:
-            toks_dev, self.cache = self._dispatch_fresh(
-                ("decode_sampled",),
-                lambda: self._decode_sampled(
-                    self.params, self.cache, tin, jnp.asarray(temps),
-                    jnp.asarray(self.steps, jnp.int32)),
-                batch=stepped, ctx_tokens=ctx)
+        toks_dev, self.cache = self._dispatch_fresh(
+            ("decode_sampled",),
+            lambda: self._decode_sampled(
+                self.params, self.cache, tin,
+                jnp.asarray(self._block_tables), jnp.asarray(temps),
+                jnp.asarray(self.steps, jnp.int32)),
+            batch=stepped, ctx_tokens=ctx)
         self._slice("fetch", program="decode_sampled")
         toks = np.array(toks_dev)  # np.array: next dispatch donates
         self._slice("sample_emit")
@@ -2510,85 +2195,66 @@ class DecodeEngine:
             active=len(self._active), prefilling=len(self._prefilling),
             queued=max(0, self._pending.qsize() + len(self._requeue)
                        - self._queued_cancelled),
-            pages_free=self._pages.free_count if self.paged else None,
+            pages_free=self._pages.free_count,
             pages_pinned=(self.prefix.pinned_pages
-                          if self.paged and self.prefix is not None
-                          else None),
+                          if self.prefix is not None else None),
             ctx_tokens=ctx_tokens)
 
     def warmup(self) -> None:
         """Pre-dispatch the step-loop programs (decode, the chunk grid,
         the fused sampler, the spec round, one admission bucket) so the
         first real request never pays their jit compiles. Safe on an
-        idle engine: paged writes route to the scratch page (idle block
-        tables are all zeros), contiguous junk lands on idle rows the
-        next admission overwrites, and the parked KV lengths are
-        restored afterwards."""
+        idle engine: writes route to the scratch page (idle block
+        tables are all zeros), and the parked KV lengths are restored
+        afterwards."""
         import jax.numpy as jnp
 
         toks = jnp.asarray(self._tokens)
         zero_t = jnp.zeros((self.slots,), jnp.float32)
         step0 = jnp.asarray(0, jnp.int32)
-        if self.paged:
-            bt = jnp.asarray(self._block_tables)
-            bucket = self.prefill_bucket
-            wp = max(1, -(-bucket // self.page_tokens))
+        bt = jnp.asarray(self._block_tables)
+        bucket = self.prefill_bucket
+        wp = max(1, -(-bucket // self.page_tokens))
+        _, self.cache = self._dispatch_fresh(
+            ("paged_prefill", 1, bucket),
+            lambda: self._paged_prefill(
+                self.params, self.cache,
+                jnp.zeros((1, bucket), jnp.int32),
+                jnp.asarray([0], jnp.int32),
+                jnp.asarray(self._block_tables[:1, :wp]),
+                jnp.asarray([0], jnp.int32), n=1, bucket=bucket))
+        _, self.cache = self._dispatch_fresh(
+            ("decode",),
+            lambda: self._decode(self.params, self.cache, toks, bt))
+        c = 2
+        while c <= self.decode_chunk:
             _, self.cache = self._dispatch_fresh(
-                ("paged_prefill", 1, bucket),
-                lambda: self._paged_prefill(
+                ("decode_k", c),
+                lambda: self._decode_k(self.params, self.cache,
+                                       toks, bt, k=c))
+            c *= 2
+        if self._device_sampler:
+            _, self.cache = self._dispatch_fresh(
+                ("decode_sampled",),
+                lambda: self._decode_sampled(
+                    self.params, self.cache, toks, bt, zero_t,
+                    step0))
+        if self.spec:
+            k = self.spec_k
+            _, self._draft_cache = self._dispatch_fresh(
+                ("spec_draft", k),
+                lambda: self._spec_draft(
+                    self._draft_params, self._draft_cache,
+                    jnp.zeros((self.slots, 2), jnp.int32),
+                    jnp.ones((self.slots,), jnp.int32),
+                    jnp.asarray(self._draft_bt), k=k))
+            _, self.cache = self._dispatch_fresh(
+                ("spec_verify", k),
+                lambda: self._spec_verify(
                     self.params, self.cache,
-                    jnp.zeros((1, bucket), jnp.int32),
-                    jnp.asarray([0], jnp.int32),
-                    jnp.asarray(self._block_tables[:1, :wp]),
-                    jnp.asarray([0], jnp.int32), n=1, bucket=bucket))
-            _, self.cache = self._dispatch_fresh(
-                ("decode",),
-                lambda: self._decode(self.params, self.cache, toks, bt))
-            c = 2
-            while c <= self.decode_chunk:
-                _, self.cache = self._dispatch_fresh(
-                    ("decode_k", c),
-                    lambda: self._decode_k(self.params, self.cache,
-                                           toks, bt, k=c))
-                c *= 2
-            if self._device_sampler:
-                _, self.cache = self._dispatch_fresh(
-                    ("decode_sampled",),
-                    lambda: self._decode_sampled(
-                        self.params, self.cache, toks, bt, zero_t,
-                        step0))
-            if self.spec:
-                k = self.spec_k
-                _, self._draft_cache = self._dispatch_fresh(
-                    ("spec_draft", k),
-                    lambda: self._spec_draft(
-                        self._draft_params, self._draft_cache,
-                        jnp.zeros((self.slots, 2), jnp.int32),
-                        jnp.ones((self.slots,), jnp.int32),
-                        jnp.asarray(self._draft_bt), k=k))
-                _, self.cache = self._dispatch_fresh(
-                    ("spec_verify", k),
-                    lambda: self._spec_verify(
-                        self.params, self.cache,
-                        jnp.zeros((self.slots, k + 1), jnp.int32), bt))
-                self._draft_cache["length"] = \
-                    self._draft_cache["length"].at[:].set(0)
-        else:
-            _, self.cache = self._dispatch_fresh(
-                ("decode",),
-                lambda: self._decode(self.params, self.cache, toks))
-            c = 2
-            while c <= self.decode_chunk:
-                _, self.cache = self._dispatch_fresh(
-                    ("decode_k", c),
-                    lambda: self._decode_k(self.params, self.cache,
-                                           toks, k=c))
-                c *= 2
-            if self._device_sampler:
-                _, self.cache = self._dispatch_fresh(
-                    ("decode_sampled",),
-                    lambda: self._decode_sampled(
-                        self.params, self.cache, toks, zero_t, step0))
+                    jnp.zeros((self.slots, k + 1), jnp.int32), bt))
+            self._draft_cache["length"] = \
+                self._draft_cache["length"].at[:].set(0)
         self.cache["length"] = self.cache["length"].at[:].set(0)
         self._tokens_dev = None
 
@@ -2699,14 +2365,13 @@ class DecodeEngine:
                                                                  denom),
             "device": self.device_stats(),
         }
-        if self.paged:
-            out.update(self._pages.stats())
-            out["page_tokens"] = self.page_tokens
-            out["pages_pinned"] = (self.prefix.pinned_pages
-                                   if self.prefix is not None else 0)
-            out["kv_fragmentation"] = self._fragmentation()
-            out["handoffs_published"] = self.handoffs_published
-            out["handoffs_adopted"] = self.handoffs_adopted
+        out.update(self._pages.stats())
+        out["page_tokens"] = self.page_tokens
+        out["pages_pinned"] = (self.prefix.pinned_pages
+                               if self.prefix is not None else 0)
+        out["kv_fragmentation"] = self._fragmentation()
+        out["handoffs_published"] = self.handoffs_published
+        out["handoffs_adopted"] = self.handoffs_adopted
         if self.spec:
             # Fleet-visible acceptance: proposed/accepted feed the same
             # counters Prometheus sees; accept_rate is the cumulative
@@ -2771,7 +2436,6 @@ class DecodeEngine:
         out = self.steplog.dump()
         out["deployment"] = self._mtags["deployment"]
         out["replica_id"] = self._replica_id
-        out["paged"] = self.paged
         out["slots"] = self.slots
         out["spec_k"] = self.spec_k if self.spec else 0
         return out
@@ -2814,7 +2478,6 @@ class LlamaDecodeDeployment:
                  capacity: int = 1024, seed: int = 0,
                  config=None, decode_chunk: int = 1,
                  prefix_pool_entries: Optional[int] = None,
-                 prefix_capacity: Optional[int] = None,
                  prefix_match_min_tokens: Optional[int] = None,
                  queue_max: Optional[int] = None,
                  kv_page_tokens: Optional[int] = None,
@@ -2864,7 +2527,6 @@ class LlamaDecodeDeployment:
             params, cfg, slots=slots, capacity=capacity,
             decode_chunk=decode_chunk,
             prefix_pool_entries=prefix_pool_entries,
-            prefix_capacity=prefix_capacity,
             prefix_match_min_tokens=prefix_match_min_tokens,
             queue_max=queue_max,
             page_tokens=kv_page_tokens, pool_pages=kv_pool_pages,
@@ -2916,14 +2578,13 @@ class LlamaDecodeDeployment:
         if sub is not None:
             out["sub_slice"] = dict(sub)
             out["slice_id"] = sub.get("slice_id")
-        if self.engine.paged:
-            # Page-pool health, controller-aggregated into
-            # serve.status(): free/pinned pages and fragmentation say
-            # whether the replica can admit, what the prefix cache
-            # holds, and whether page_tokens is sized right.
-            for key in ("pages_total", "pages_free", "pages_in_use",
-                        "pages_pinned", "kv_fragmentation", "preempted"):
-                out[key] = s[key]
+        # Page-pool health, controller-aggregated into serve.status():
+        # free/pinned pages and fragmentation say whether the replica
+        # can admit, what the prefix cache holds, and whether
+        # page_tokens is sized right.
+        for key in ("pages_total", "pages_free", "pages_in_use",
+                    "pages_pinned", "kv_fragmentation", "preempted"):
+            out[key] = s[key]
         if self.engine.spec:
             out["spec"] = s["spec"]
         if self.engine.prefix is not None:

@@ -161,7 +161,7 @@ def _affinity_hashes(args: tuple):
     if tokens is None:
         return None
     try:
-        from ray_tpu.serve.prefix_cache import candidate_hashes
+        from ray_tpu.serve.paging import candidate_hashes
 
         return candidate_hashes(
             tokens, rt_config.prefix_match_min_tokens) or None

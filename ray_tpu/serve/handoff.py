@@ -38,8 +38,7 @@ from typing import Any, Dict, List, Optional
 # Budget on the serialized DESCRIPTOR (refs + block geometry + first
 # token — never the page payload, which rides the object store): the
 # router splice forwards it inline with the request, so it must stay
-# RPC-header-sized. bench_serve --sections disagg records the observed
-# p99 against this.
+# RPC-header-sized.
 HANDOFF_DESC_BYTE_BUDGET = 8192
 
 

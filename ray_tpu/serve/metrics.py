@@ -11,8 +11,7 @@ cluster controller, and read back identically by:
 
 * the HTTP proxy's ``/metrics`` route (Prometheus exposition text),
 * ``serve.status()``'s per-deployment ``slo`` summaries,
-* the dashboard's serve panel,
-* ``bench_serve.py`` / ``bench_decode.py`` percentile rows.
+* the dashboard's serve panel.
 
 One registry, one aggregation path (``slo_summary``), one answer.
 """

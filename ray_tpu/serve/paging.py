@@ -2,7 +2,13 @@
 
 The paging half of the serve plane's memory story (``serve/decode.py``
 owns the device arrays and jitted programs; ``models/llama_decode.py``
-owns the paged attention math). Two pieces:
+owns the paged attention math). Three pieces:
+
+* ``prefix_hash`` / ``bucket_lengths`` / ``candidate_hashes`` — the
+  prefix FORMAT the index and the serve router share: replicas advertise
+  the hashes of their resident entries, and routers hash a request's
+  leading power-of-two token buckets to find the replica whose pool
+  already holds the prompt (prefix-affinity routing).
 
 * ``PageAllocator`` — a refcounted free-list over device pool page ids.
   A page is handed out with refcount 1; sharing (prefix splices, prefix-
@@ -15,9 +21,7 @@ owns the paged attention math). Two pieces:
   per page-aligned prefix length, keyed by the hash of ALL tokens up to
   that page's end, each pinning exactly ONE pool page. Inserting a
   completed prompt is ZERO-COPY: the slot's own pages are increfed and
-  recorded (no device traffic at all — contrast PR 2's whole-row pool,
-  which copied ``C_prefix`` tokens of K/V per insert and pinned a full
-  capacity-sized row per entry). A hit splices page ids into the new
+  recorded (no device traffic at all). A hit splices page ids into the new
   request's block table; eviction unpins page-granular TAIL segments
   (leaf entries first), so a long cached prefix shrinks gracefully
   instead of vanishing whole.
@@ -34,13 +38,44 @@ transferred on every path.
 
 from __future__ import annotations
 
+import hashlib
 from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from ray_tpu.serve.prefix_cache import prefix_hash
-
 SCRATCH_PAGE = 0  # reserved pool page for pad writes; never allocated
+
+
+def prefix_hash(tokens) -> str:
+    """Stable short hash of a token-id sequence (router <-> replica
+    affinity key; also the index's dedup identity)."""
+    arr = np.ascontiguousarray(np.asarray(tokens, np.int32))
+    return hashlib.blake2b(arr.tobytes(), digest_size=8).hexdigest()
+
+
+def bucket_lengths(n: int, min_tokens: int,
+                   cap: Optional[int] = None) -> List[int]:
+    """Power-of-two prefix lengths <= n (>= min_tokens, <= cap),
+    DESCENDING — the grid on which entries are inserted and affinity
+    hashes computed."""
+    out: List[int] = []
+    b = 1
+    while b * 2 <= n:
+        b *= 2
+    while b >= max(1, min_tokens):
+        if cap is None or b <= cap:
+            out.append(b)
+        b //= 2
+    return out
+
+
+def candidate_hashes(tokens, min_tokens: int,
+                     cap: Optional[int] = None) -> List[str]:
+    """Hashes of a prompt's leading buckets, longest first: the router
+    probes these against replicas' advertised prefix sets."""
+    toks = np.asarray(tokens, np.int32).reshape(-1)
+    return [prefix_hash(toks[:b])
+            for b in bucket_lengths(len(toks), min_tokens, cap)]
 
 
 class PageAllocator:
@@ -128,7 +163,7 @@ class PagedPrefixIndex:
     page by page and hands back the page ids ALREADY INCREFED for the
     caller's block table (the caller owns one reference per page and
     releases by freeing them with its slot — there is no separate
-    release step, unlike PR 2's entry pins). ``insert`` pins a completed
+    release step). ``insert`` pins a completed
     slot's own pages (zero-copy). Eviction drops LEAF entries (no longer
     chain through them) in LRU order, freeing tail pages first."""
 
@@ -201,8 +236,9 @@ class PagedPrefixIndex:
         The insert length is the largest power of two <= the prompt
         length (>= max(min_tokens, T)): the same grid the router's
         affinity hashes probe, kept so hot prefixes dedup across
-        replicas. ``matched_len`` gating as in PR 2: skip unless
-        coverage at least doubles (per-request random suffixes must not
+        replicas. ``matched_len`` gating: skip unless coverage at least
+        doubles (a hot shared prefix followed by per-request random
+        suffixes must not insert a never-deduped entry per request and
         thrash the index)."""
         toks = np.asarray(tokens, np.int32).reshape(-1)
         T = self.page_tokens

@@ -364,8 +364,8 @@ def test_mutation_dropped_anchor_caught():
         '.astype(x.dtype)')
     found = run_checker(sharding_safety.check, project)
     hits = [f for f in found if f.rule == rules.SHARDING_ANCHOR]
-    # the dropped line is shared verbatim by the contiguous and paged
-    # decode steps: both wo reductions lose their anchor
+    # the dropped line is shared verbatim by the reference's and the
+    # paged decode step: both wo reductions lose their anchor
     assert sorted({f.symbol for f in hits}) == [
         "decode_step.body", "paged_decode_step.body"], \
         [f.render() for f in found]
@@ -388,8 +388,8 @@ def test_mutation_verify_rules_partition_caught():
 
 def test_mutation_verify_dropped_anchor_caught():
     """The S-shaped attention anchor line is shared verbatim by the
-    contiguous suffix, paged suffix and spec verify forwards: dropping
-    it loses the pre-wo anchor in all three."""
+    paged suffix and spec verify forwards: dropping it loses the pre-wo
+    anchor in both."""
     project = repo_project_with(
         "ray_tpu/models/llama_decode.py",
         '        att = att.transpose(0, 3, 1, 2, 4).reshape(\n'
@@ -401,8 +401,8 @@ def test_mutation_verify_dropped_anchor_caught():
     found = run_checker(sharding_safety.check, project)
     hits = [f for f in found if f.rule == rules.SHARDING_ANCHOR]
     assert sorted({f.symbol for f in hits}) == [
-        "paged_prefill_suffix.body", "paged_verify.body",
-        "prefill_suffix.body"], [f.render() for f in found]
+        "paged_prefill_suffix.body", "paged_verify.body"], \
+        [f.render() for f in found]
 
 
 def test_spec_programs_clean_under_decode_rules():

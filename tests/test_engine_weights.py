@@ -22,7 +22,6 @@ NORM_LEAVES = ("attn_norm", "mlp_norm")
 
 # name: engine arguments (the draft's and the mesh's are added in _build).
 KINDS = {
-    "contiguous": dict(page_tokens=0, prefix_pool_entries=0),
     "paged_chunked": dict(page_tokens=16, pool_pages=40,
                           prefill_chunk_tokens=16, prefix_pool_entries=0),
     "paged_prefix": dict(page_tokens=16, pool_pages=40),
@@ -84,8 +83,7 @@ def _cast_leaves(params):
 
 
 PROGRAMS = ("_decode", "_decode_k", "_paged_prefill", "_paged_suffix",
-            "_prefill_many", "_prefill_suffix_many", "_spec_verify",
-            "_spec_draft")
+            "_spec_verify", "_spec_draft")
 
 
 def _serve(eng):
@@ -199,7 +197,7 @@ def test_leaf_already_in_compute_dtype_is_the_same_array(model):
     mixed = dict(params, layers=dict(params["layers"]))
     mixed["layers"]["wo"] = params["layers"]["wo"].astype(jnp.bfloat16)
     mixed["lm_head"] = params["lm_head"].astype(jnp.bfloat16)
-    eng = DecodeEngine(mixed, cfg, slots=2, capacity=64, page_tokens=0)
+    eng = DecodeEngine(mixed, cfg, slots=2, capacity=64)
     assert eng.params["layers"]["wo"] is mixed["layers"]["wo"]
     assert eng.params["lm_head"] is mixed["lm_head"]
     assert eng.params["layers"]["attn_norm"] is params["layers"]["attn_norm"]
@@ -349,8 +347,7 @@ def test_streams_and_logits_are_those_of_the_float32_tree(kind, model,
     for i, ((name, got), (_, want)) in enumerate(zip(calls, want_calls)):
         assert np.array_equal(got, want), (i, name)
     names = {n for n, _ in calls}
-    need = {"contiguous": {"_prefill_many", "_decode"},
-            "paged_chunked": {"_paged_prefill", "_paged_suffix", "_decode"},
+    need = {"paged_chunked": {"_paged_prefill", "_paged_suffix", "_decode"},
             "paged_prefix": {"_paged_prefill", "_paged_suffix", "_decode"},
             "speculative": {"_paged_prefill", "_spec_verify", "_spec_draft"},
             "mesh_1x2": {"_paged_prefill", "_decode"}}[kind]

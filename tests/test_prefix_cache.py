@@ -1,16 +1,17 @@
-"""Prefix KV cache: trie match/insert/evict + refcount-vs-evict on the
-host index (``serve/prefix_cache.py``), suffix-only prefill equivalence
-vs full prefill (``llama_decode.prefill_suffix``), the decode engine's
-splice + suffix-prefill admission path, and prefix-affinity routing.
-All CPU, tiny configs — tier-1 safe."""
+"""Prefix KV cache: the prefix format the index and the router share
+(``serve/paging.py``; the index's own units are in test_paged_kv.py), the
+decode engine's splice + suffix-prefill admission path against the solo
+reference, and prefix-affinity routing. All CPU, tiny configs — tier-1
+safe."""
 
 import threading
 
 import numpy as np
 import pytest
 
-from ray_tpu.serve.prefix_cache import (PrefixCache, bucket_lengths,
-                                        candidate_hashes, prefix_hash)
+from ray_tpu.serve.paging import (PageAllocator, PagedPrefixIndex,
+                                  bucket_lengths, candidate_hashes,
+                                  prefix_hash)
 
 
 def _tiny():
@@ -34,125 +35,20 @@ def test_bucket_lengths_grid():
     assert bucket_lengths(100, 16, cap=32) == [32, 16]
 
 
-def test_match_insert_dedup():
-    pc = PrefixCache(entries=4, capacity=32, min_tokens=4)
-    toks = list(range(10, 30))  # 20 tokens
-    assert pc.match(toks) is None
-    row, ins_len = pc.insert(toks)
-    assert ins_len == 16  # largest power of two <= 20
-    assert pc.insert(toks) is None  # dedup on the token key
-    m = pc.match(toks)
-    assert m == (row, 16)
-    pc.release(row)
-    assert pc.stats()["hit_rate"] == 0.5  # 1 hit / 2 queries
-
-
-def test_partial_match_and_min_tokens():
-    pc = PrefixCache(4, 32, min_tokens=4)
-    toks = list(range(100, 132))
-    pc.insert(toks)  # 32-token entry
-    # A request sharing only the first 7 tokens still matches (the
-    # splice + suffix overwrite makes partial donors correct).
-    m = pc.match(toks[:7] + [999] * 20)
-    assert m is not None and m[1] == 7
-    pc.release(m[0])
-    # Below min_tokens: no hit.
-    assert pc.match(toks[:3] + [5, 6, 7, 8]) is None
-
-
-def test_match_leaves_one_suffix_token():
-    pc = PrefixCache(4, 32, min_tokens=4)
-    toks = list(range(8))
-    pc.insert(toks)
-    m = pc.match(toks)  # identical prompt: next-token logits still need
-    assert m is not None and m[1] == 7  # >= 1 real suffix token
-    pc.release(m[0])
-
-
-def test_nested_entries():
-    pc = PrefixCache(4, 32, min_tokens=2)
-    long = list(range(16))
-    r_long, _ = pc.insert(long)
-    short = pc.insert(long[:8])  # strict prefix of an existing entry
-    assert short is not None and short[1] == 8
-    assert len(pc) == 2
-
-
-def test_lru_eviction_prunes_trie():
-    pc = PrefixCache(2, 32, min_tokens=2)
-    a, b, c = [1] * 4, [2] * 4, [3] * 4
-    pc.insert(a)
-    row_b, _ = pc.insert(b)
-    m = pc.match(a + [9])  # touch a: b becomes LRU
-    pc.release(m[0])
-    row_c, _ = pc.insert(c)
-    assert row_c == row_b  # b's row recycled
-    assert pc.evictions == 1
-    assert pc.match(b + [9]) is None  # b's trie path pruned
-    assert pc.match(a + [9]) is not None
-
-
-def test_refcount_blocks_eviction():
-    """The refcount-vs-evict race: a row pinned by an in-flight splice
-    must never be recycled, even when it is the LRU victim."""
-    pc = PrefixCache(1, 32, min_tokens=2)
-    row, _ = pc.insert([1] * 4)
-    m = pc.match([1, 1, 1, 1, 9])  # acquires the only row
-    assert m is not None
-    assert pc.insert([2] * 4) is None  # every row pinned: insert refused
-    pc.release(m[0])
-    replacement = pc.insert([2] * 4)
-    assert replacement is not None and replacement[0] == row
-
-
 def test_candidate_hashes_match_advertised_entries():
-    """The router's candidate grid and the pool's insert grid agree, so
-    an advertised entry hash is discoverable from the raw prompt."""
+    """The router's candidate grid and the index's insert grid agree, so
+    every advertised hash is discoverable from the raw prompt, and the
+    longest is the router's first candidate."""
     toks = list(range(100))
-    pc = PrefixCache(4, 64, min_tokens=16)
-    pc.insert(toks)  # entry at length 64
-    assert pc.hashes() == [candidate_hashes(toks, 16)[0]]
-    assert prefix_hash(toks[:64]) == pc.hashes()[0]
+    pa = PageAllocator(8)
+    idx = PagedPrefixIndex(pa, page_tokens=16, max_pages=8, min_tokens=16)
+    idx.insert(toks, pa.alloc(7))  # chain of 4 pages up to length 64
+    cands = candidate_hashes(toks, 16)
+    assert cands[0] == prefix_hash(toks[:64])
+    assert set(idx.hashes()) == set(cands)  # lengths 64, 32, 16
 
 
-# ------------------------------------------- suffix-prefill equivalence
-
-
-def test_suffix_prefill_matches_full_prefill():
-    """Greedy tokens are identical whether a prompt is prefilled whole
-    or spliced (prefix from cache) + suffix-prefilled: the mask over the
-    spliced region is exact."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models import llama_decode as ld
-
-    cfg, params = _tiny()
-    rng = np.random.default_rng(0)
-    prompt = rng.integers(0, cfg.vocab_size, 24).astype(np.int32)
-    full = ld.init_cache(cfg, 1, 64)
-    logits_full, full = ld.prefill(params, jnp.asarray(prompt[None]),
-                                   full, cfg)
-    p = 16
-    spliced = ld.init_cache(cfg, 1, 64)
-    _, spliced = ld.prefill(params, jnp.asarray(prompt[None, :p]),
-                            spliced, cfg)
-    suffix = np.zeros((1, 16), np.int32)
-    suffix[0, :len(prompt) - p] = prompt[p:]
-    logits_suf, spliced = ld.prefill_suffix(
-        params, jnp.asarray(suffix), spliced, cfg,
-        jnp.array([p], np.int32), jnp.array([len(prompt)], np.int32))
-    np.testing.assert_allclose(np.asarray(logits_suf),
-                               np.asarray(logits_full),
-                               rtol=2e-2, atol=2e-2)
-    # Greedy continuation is token-for-token identical.
-    ta = jnp.argmax(logits_full, -1).astype(jnp.int32)
-    tb = jnp.argmax(logits_suf, -1).astype(jnp.int32)
-    for _ in range(6):
-        assert int(ta[0]) == int(tb[0])
-        la, full = ld.decode_step(params, full, ta, cfg)
-        lb, spliced = ld.decode_step(params, spliced, tb, cfg)
-        ta = jnp.argmax(la, -1).astype(jnp.int32)
-        tb = jnp.argmax(lb, -1).astype(jnp.int32)
+# ------------------------------------------------ engine admission
 
 
 def test_engine_prefix_hits_bit_exact():
@@ -170,7 +66,7 @@ def test_engine_prefix_hits_bit_exact():
     # Partial hit: diverges inside the cached entry.
     prompts.append(shared[:9] + rng.integers(0, cfg.vocab_size,
                                              8).tolist())
-    eng = DecodeEngine(params, cfg, slots=2, capacity=64,
+    eng = DecodeEngine(params, cfg, slots=2, capacity=64, page_tokens=4,
                        prefix_pool_entries=4, prefix_match_min_tokens=4)
     hits = 0
     for p in prompts:
@@ -186,8 +82,9 @@ def test_engine_prefix_hits_bit_exact():
     assert hits == 3  # all but the cold first admission
     stats = eng.prefix.stats()
     assert stats["hits"] == 3 and stats["prefill_tokens_saved"] > 0
-    # Partial-hit request matched at the divergence point, not beyond.
-    assert prompts[-1][:9] == shared[:9]
+    # Partial-hit request matched up to the last whole page before the
+    # divergence point, not beyond.
+    assert req.prefix_len == 8
     eng.shutdown()
 
 
@@ -200,7 +97,7 @@ def test_engine_prefix_batched_hit_wave():
     cfg, params = _tiny()
     rng = np.random.default_rng(2)
     shared = rng.integers(0, cfg.vocab_size, 16).tolist()
-    eng = DecodeEngine(params, cfg, slots=4, capacity=64,
+    eng = DecodeEngine(params, cfg, slots=4, capacity=64, page_tokens=4,
                        prefix_pool_entries=4, prefix_match_min_tokens=4)
     warm = eng.submit(shared + [7, 7], max_new_tokens=1)
     while not warm.done.is_set():
@@ -226,14 +123,16 @@ def test_engine_disabled_pool_allocates_nothing():
     cfg, params = _tiny()
     eng = DecodeEngine(params, cfg, slots=2, capacity=64,
                        prefix_pool_entries=0)
-    assert eng.prefix is None and eng._pool is None
+    assert eng.prefix is None
     req = eng.submit([1, 2, 3], max_new_tokens=3)
     for _ in range(10):
         if req.done.is_set():
             break
         eng.step()
     assert len(req.output) == 3
-    assert "prefix" not in eng.stats()
+    stats = eng.stats()
+    assert "prefix" not in stats and stats["pages_pinned"] == 0
+    assert stats["pages_free"] == stats["pages_total"]  # nothing pinned
     eng.shutdown()
 
 

@@ -280,6 +280,7 @@ def test_prefix_residency_published_to_router(serve_cluster):
     serve.run(
         serve.deployment(LlamaDecodeDeployment).options(
             max_concurrency=4).bind(config=cfg, slots=2, capacity=64,
+                                    kv_page_tokens=16,
                                     prefix_pool_entries=4,
                                     prefix_match_min_tokens=4),
         name="llm_prefix")
@@ -314,7 +315,7 @@ def test_submit_rejects_over_capacity_budget():
     from ray_tpu.serve.decode import DecodeEngine
 
     cfg, params = _tiny()
-    eng = DecodeEngine(params, cfg, slots=2, capacity=32)
+    eng = DecodeEngine(params, cfg, slots=2, capacity=32, page_tokens=16)
     with pytest.raises(ValueError):
         eng.submit(list(range(1, 26)), max_new_tokens=10)  # 25 + 10 > 32
     # Exactly at the budget is admitted and completes.

@@ -149,11 +149,12 @@ def test_cancel_queued_request_never_touches_device():
 
 def test_cancel_active_frees_slot_within_one_step_and_prefix_pins():
     """Cancelling an active request frees its slot at the next step and
-    leaves every prefix-pool row unpinned (refcounts back to zero)."""
+    returns its borrow of every prefix page (the index's pin is the
+    only reference left)."""
     from ray_tpu.serve.decode import DecodeEngine
 
     cfg, params = _tiny()
-    eng = DecodeEngine(params, cfg, slots=2, capacity=64,
+    eng = DecodeEngine(params, cfg, slots=2, capacity=64, page_tokens=16,
                        prefix_pool_entries=4, prefix_match_min_tokens=4)
     # Seed the prefix pool with a long prompt, then hit it.
     seed = eng.submit(list(range(1, 25)), max_new_tokens=2)
@@ -167,9 +168,11 @@ def test_cancel_active_frees_slot_within_one_step_and_prefix_pins():
     assert victim.done.is_set() and victim.status == "cancelled"
     assert eng.stats()["free_slots"] == 2
     assert eng.stats()["cancelled"] == 1
-    # Every pool row's splice pin has been released.
-    refcounts = [e.refcount for e in eng.prefix._entries.values()]
-    assert refcounts and all(rc == 0 for rc in refcounts), refcounts
+    # Every spliced page's borrow has been released.
+    refcounts = [eng._pages.refcount(p)
+                 for p in eng.prefix.pinned_page_ids()]
+    assert refcounts and all(rc == 1 for rc in refcounts), refcounts
+    assert eng.stats()["pages_in_use"] == len(refcounts)
     eng.shutdown()
 
 

@@ -50,7 +50,8 @@ def _prompts(cfg, lengths, seed=7):
 
 
 SCENARIOS = {
-    # name: (engine arguments, prompt lengths, new tokens, submit arguments)
+    # name: (engine arguments, prompt lengths, new tokens, submit arguments
+    #        [, leading tokens every prompt shares with the first])
     "admission": ({}, [10, 30, 12], 12, {}),
     "admission_wave_of_equal_prompts": ({}, [12, 12, 12, 12], 6, {}),
     "chunked_prefill": ({"prefill_chunk_tokens": 32}, [100, 20, 70], 10, {}),
@@ -61,15 +62,18 @@ SCENARIOS = {
     "sampled_path": ({"device_sampler": True}, [10, 30], 12,
                      {"temperature": 0.7}),
     "decode_chunks": ({"decode_chunk": 4}, [10, 14], 16, {}),
-    "contiguous": ({"page_tokens": 0}, [10, 30, 12], 8, {}),
+    "prefix_hit": ({"slots": 1, "prefix_pool_entries": 4,
+                    "prefix_match_min_tokens": 16}, [40, 44, 38], 6, {}, 32),
 }
 
 
 def _run(name):
-    kw, lengths, new, sub = SCENARIOS[name]
+    kw, lengths, new, sub, *shared = SCENARIOS[name]
     cfg, eng = _engine(**kw)
-    reqs = [eng.submit(p, max_new_tokens=new, **sub)
-            for p in _prompts(cfg, lengths)]
+    prompts = _prompts(cfg, lengths)
+    if shared:
+        prompts = [prompts[0][:shared[0]] + p[shared[0]:] for p in prompts]
+    reqs = [eng.submit(p, max_new_tokens=new, **sub) for p in prompts]
     _drive(eng, reqs)
     rows = eng.steplog.dump()["rows"]
     eng.shutdown()
@@ -82,6 +86,8 @@ def test_slices_tile_the_step_with_no_hole_or_overlap(name):
     assert rows and all(r.status == "completed" for r in reqs)
     if name.startswith("preemption"):
         assert eng.preempted > 0
+    if name == "prefix_hit":  # the suffix admission behind spliced pages
+        assert eng.prefix.stats()["hits"] == 2
     seen = set()
     for i, row in enumerate(rows):
         slices = row["slices"]
@@ -353,11 +359,6 @@ def test_engine_programs_are_jitted_under_their_keys_name():
     for key in ("paged_suffix", "decode_k"):
         text = lowered[key].as_text(debug_info=True)
         assert "paged_gather" in text and "paged_attn" in text
-    eng.shutdown()
-    # The contiguous engine's programs go by the same rule.
-    cfg, eng = _engine(page_tokens=0)
-    low = eng._decode.lower(eng.params, eng.cache, toks)
-    assert "@jit_engine_decode " in low.as_text().split("\n", 1)[0]
     eng.shutdown()
 
 

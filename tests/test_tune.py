@@ -60,9 +60,20 @@ def test_tuner_trial_error_isolated(ray_start_regular):
 
 def test_asha_stops_bad_trials(ray_start_regular):
     def trainable(config):
+        import time
+
         from ray_tpu import tune as t
 
+        # ASHA stops a trial only where two better results stand at a rung
+        # BEFORE its own arrives, so which trials stop is the order of
+        # arrival. Unpaced, a trial's 20 reports land in one poll reply
+        # and are judged in a row (six xdist workers on one machine
+        # stretch a poll): no trial ever stops. Paced, and the bad ones
+        # ten times slower, the good ones stand at rung 16 (0.4 s) long
+        # before a bad one gets there (3.2 s).
+        pace = 0.02 if config["quality"] < 1 else 0.2
         for step in range(20):
+            time.sleep(pace)
             t.report({"loss": config["quality"] + step * 0.001})
 
     scheduler = ASHAScheduler(metric="loss", mode="min", max_t=20,
