@@ -136,6 +136,44 @@ def _paged_gather(k_p, v_p, block_tables, config: LlamaConfig):
                 v_p[block_tables].reshape(shape))
 
 
+def _scan_layers(body, x, params: Dict[str, Any], pool: Cache):
+    """The layer loop of a paged forward, with the pool in its CARRY:
+    ``body((x, k_pool, v_pool), (layer, base)) -> ((x, k_pool, v_pool),
+    None)``. Returns ``(x, pool)``, the pool in the shape it came in.
+
+    Inside the loop the pool is flat, ``(L * (P + 1), T, KV, D)``: layers
+    and pages on one axis (a bitcast; neither axis is ever sharded), page
+    ``p`` of layer ``l`` at row ``base + p`` with ``base = l * (P + 1)``.
+    A layer's write and its gather so index the whole pool and no layer
+    is sliced out of it, and XLA updates a loop carry where it lies: with
+    the pool donated at the jit boundary, a program stores its few new
+    rows into the caller's buffer. The pool must not ride the scan's
+    ``xs``/``ys``: a stacked output cannot alias a scanned input, and
+    every call then writes a new pool whole (tests/test_paged_kv.py
+    guards the compiled text)."""
+    shape = pool["k"].shape
+    flat = (shape[0] * shape[1],) + shape[2:]
+    bases = jnp.arange(shape[0], dtype=jnp.int32) * shape[1]
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, pool["k"].reshape(flat), pool["v"].reshape(flat)),
+        (params["layers"], bases))
+    return x, {"k": k_pool.reshape(shape), "v": v_pool.reshape(shape)}
+
+
+def _pool_store(k_pool, v_pool, base, pages, offs, k_new, v_new,
+                block_tables, config: LlamaConfig):
+    """One layer's KV traffic on the flat pool of ``_scan_layers``:
+    scatter the new positions' K/V to ``(base + pages, offs)``, then
+    gather the rows' pages, so that the new tokens are in view. Returns
+    ``(k_pool, v_pool, k_view, v_view)``. The write stays outside the
+    ``paged_gather`` scope: a trace bills it to neither gather nor
+    attention."""
+    k_pool = k_pool.at[base + pages, offs].set(k_new.astype(k_pool.dtype))
+    v_pool = v_pool.at[base + pages, offs].set(v_new.astype(v_pool.dtype))
+    k_c, v_c = _paged_gather(k_pool, v_pool, base + block_tables, config)
+    return k_pool, v_pool, k_c, v_c
+
+
 def _qkv(layer, h, config: LlamaConfig):
     c = config
     if "wqkv" in layer:
@@ -357,7 +395,9 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
     scales with what the wave touches, not the engine's max context.
     Suffix K/V additionally scatters into the pool at the absolute
     positions (always pages owned exclusively by the row: sharing is
-    full-page and writes start past the shared region)."""
+    full-page and writes start past the shared region). The pool rides
+    the layer loop as its carry and is written in place
+    (``_scan_layers``); it comes back in the shape it was given."""
     c = config
     B, S = tokens.shape
     T = pool["k"].shape[2]
@@ -380,8 +420,9 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
     valid = (jnp.arange(C)[None, None, :]
              <= abs_pos[:, :, None])                         # (B, S, C)
 
-    def body(x, inp):
-        layer, k_p, v_p = inp               # pool slices (P+1, T, KV, D)
+    def body(carry, inp):
+        x, k_p, v_p = carry                 # the flat pool: _scan_layers
+        layer, base = inp
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
         q, k_new, v_new = _qkv(layer, h, c)  # (B, S, H/KV, D)
         q = apply_rope(q, cos, sin, positions=abs_pos)
@@ -391,11 +432,10 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
                           ("batch", "length", "kv_heads", "head_dim"))
         v_new = constrain(v_new,
                           ("batch", "length", "kv_heads", "head_dim"))
-        k_p = k_p.at[pages, offs].set(k_new.astype(k_p.dtype))
-        v_p = v_p.at[pages, offs].set(v_new.astype(v_p.dtype))
-        # Gather AFTER the scatter so the suffix's own causal K/V is in
-        # view; layout is logical position order.
-        k_c, v_c = _paged_gather(k_p, v_p, block_tables, c)
+        # The gather follows the scatter, so the suffix's own causal K/V
+        # is in view; layout is logical position order.
+        k_p, v_p, k_c, v_c = _pool_store(k_p, v_p, base, pages, offs,
+                                         k_new, v_new, block_tables, c)
         with jax.named_scope("paged_attn"):
             qg = q.reshape(B, S, c.n_kv_heads, kv_groups, c.head_dim)
             scores = jnp.einsum("bskgd,bckd->bkgsc", qg, k_c,
@@ -409,10 +449,9 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
         out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
         x = x + out
         x = _mlp(layer, x, c)
-        return x, (k_p, v_p)
+        return (x, k_p, v_p), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"]))
+    x, pool = _scan_layers(body, x, params, pool)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     idx = jnp.clip(lengths - prefix_lens - 1, 0, S - 1)
     x_last = jnp.take_along_axis(
@@ -420,7 +459,7 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
     logits = jnp.einsum("be,ev->bv", x_last,
                         _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    return logits, pool
 
 
 def paged_decode_step(params: Dict[str, Any], pool: Cache,
@@ -430,7 +469,10 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
     """One decode token per slot against paged context. ``tokens``: (B,)
     int32 written at position ``lengths[b]`` of each row's block-mapped
     sequence; attention sees positions ``<= length`` across the row's
-    gathered pages — value for value the reference ``decode_step``."""
+    gathered pages — value for value the reference ``decode_step``. The
+    pool rides the layer loop as its carry (``_scan_layers``): each layer
+    scatters its ``B`` new rows into the donated buffer, and the program
+    holds no second pool."""
     c = config
     B = tokens.shape[0]
     T = pool["k"].shape[2]
@@ -450,8 +492,9 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
     off = pos % T
     valid = (jnp.arange(C)[None, :] <= pos[:, None])         # (B, C)
 
-    def body(x, inp):
-        layer, k_p, v_p = inp
+    def body(carry, inp):
+        x, k_p, v_p = carry                 # the flat pool: _scan_layers
+        layer, base = inp
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
         q, k_new, v_new = _qkv(layer, h, c)       # (B, 1, H/KV, D)
         q = apply_rope(q, cos, sin, positions=pos[:, None])
@@ -461,9 +504,9 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
                           ("batch", "length", "kv_heads", "head_dim"))
         v_new = constrain(v_new,
                           ("batch", "length", "kv_heads", "head_dim"))
-        k_p = k_p.at[page, off].set(k_new[:, 0].astype(k_p.dtype))
-        v_p = v_p.at[page, off].set(v_new[:, 0].astype(v_p.dtype))
-        k_c, v_c = _paged_gather(k_p, v_p, block_tables, c)
+        k_p, v_p, k_c, v_c = _pool_store(k_p, v_p, base, page, off,
+                                         k_new[:, 0], v_new[:, 0],
+                                         block_tables, c)
         with jax.named_scope("paged_attn"):
             qg = q[:, 0].reshape(B, c.n_kv_heads, kv_groups, c.head_dim)
             scores = jnp.einsum("bkgd,bckd->bkgc", qg, k_c,
@@ -476,15 +519,14 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
         out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
         x = x + out
         x = _mlp(layer, x, c)
-        return x, (k_p, v_p)
+        return (x, k_p, v_p), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"]))
+    x, pool = _scan_layers(body, x, params, pool)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     logits = jnp.einsum("be,ev->bv", x[:, 0],
                         _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
-    return logits, {"k": new_k, "v": new_v}, pos + 1
+    return logits, pool, pos + 1
 
 
 def paged_decode_chunk(params: Dict[str, Any], pool: Cache,
@@ -536,7 +578,8 @@ def paged_verify(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
     committed by the same program that scores it — rejected tails are
     plain junk past the rolled-back ``length`` cursor, masked exactly
     like pad writes and overwritten by the next round's scatter before
-    any gather can see them."""
+    any gather can see them. As in the other paged forwards, the pool is
+    the layer loop's carry and is written in place (``_scan_layers``)."""
     c = config
     B, S = tokens.shape
     T = pool["k"].shape[2]
@@ -555,8 +598,9 @@ def paged_verify(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
     valid = (jnp.arange(C)[None, None, :]
              <= abs_pos[:, :, None])                         # (B, S, C)
 
-    def body(x, inp):
-        layer, k_p, v_p = inp               # pool slices (P+1, T, KV, D)
+    def body(carry, inp):
+        x, k_p, v_p = carry                 # the flat pool: _scan_layers
+        layer, base = inp
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
         q, k_new, v_new = _qkv(layer, h, c)  # (B, S, H/KV, D)
         q = apply_rope(q, cos, sin, positions=abs_pos)
@@ -566,9 +610,8 @@ def paged_verify(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
                           ("batch", "length", "kv_heads", "head_dim"))
         v_new = constrain(v_new,
                           ("batch", "length", "kv_heads", "head_dim"))
-        k_p = k_p.at[pages, offs].set(k_new.astype(k_p.dtype))
-        v_p = v_p.at[pages, offs].set(v_new.astype(v_p.dtype))
-        k_c, v_c = _paged_gather(k_p, v_p, block_tables, c)
+        k_p, v_p, k_c, v_c = _pool_store(k_p, v_p, base, pages, offs,
+                                         k_new, v_new, block_tables, c)
         with jax.named_scope("paged_attn"):
             qg = q.reshape(B, S, c.n_kv_heads, kv_groups, c.head_dim)
             scores = jnp.einsum("bskgd,bckd->bkgsc", qg, k_c,
@@ -582,15 +625,14 @@ def paged_verify(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
         out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
         x = x + out
         x = _mlp(layer, x, c)
-        return x, (k_p, v_p)
+        return (x, k_p, v_p), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"]))
+    x, pool = _scan_layers(body, x, params, pool)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     logits = jnp.einsum("bse,ev->bsv", x,
                         _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    return logits, pool
 
 
 def paged_spec_draft(params: Dict[str, Any], pool: Cache,

@@ -369,8 +369,12 @@ class DecodeEngine:
         # to prefill_bucket would refund most of the win.
         self._suffix_bucket_min = max(8, min(16, prefill_bucket))
         # Params are ARGUMENTS (not closure captures), or jit would bake
-        # the weights into the program as constants; donating the cache
-        # makes every KV write in-place.
+        # the weights into the program as constants. Donating the cache
+        # hands a program the pool's own buffer; that it WRITES there is
+        # the forwards' doing: they carry the pool through their layer
+        # loop and scatter the new rows into it
+        # (``llama_decode._scan_layers``), so no program holds or copies
+        # a second pool. Its shape is the same outside every program.
         # Mesh engines pin program outputs to the committed shardings
         # (logits/token outputs replicated for the host sampler, KV
         # state staying exactly where device_put placed it, so

@@ -238,6 +238,146 @@ def test_paged_suffix_prefill_token_exact():
     assert toks == _solo(params, cfg, prompt.tolist(), 6)
 
 
+# ------------------------------------------- the pool is written in place
+#
+# The forwards that carry the pool through their layer loop, at one small
+# geometry: three rows over pages of four tokens, a pool of twelve pages
+# of which 4, 11 and 12 belong to no row. Each call writes three positions
+# a row (one a row for the single decode step); row 2's run past its page
+# window, where the scratch-page rule takes over.
+
+IN_PLACE = ("paged_decode_step", "paged_prefill_suffix", "paged_verify",
+            "paged_decode_chunk")
+_BT = np.array([[1, 2, 3, 0], [5, 6, 0, 0], [7, 8, 9, 10]], np.int32)
+_LENS = np.array([9, 5, 14], np.int32)
+_PAGES, _T = 12, 4
+
+
+def _in_place_call(name, cfg):
+    """``(fn(params, pool) -> (.., pool, ..), positions written a row)``
+    for forward ``name``; the pool is argument 1, as the engine's programs
+    take it, so ``donate_argnums=(1,)`` is the engine's donation."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    bt, lens = jnp.asarray(_BT), jnp.asarray(_LENS)
+    rows = np.random.default_rng(5).integers(
+        0, cfg.vocab_size, (3, 3)).astype(np.int32)
+    if name == "paged_decode_step":
+        return (lambda params, pool: ld.paged_decode_step(
+            params, pool, bt, lens, jnp.asarray(rows[:, 0]), cfg)), 1
+    if name == "paged_decode_chunk":
+        return (lambda params, pool: ld.paged_decode_chunk(
+            params, pool, bt, lens, jnp.asarray(rows[:, 0]), cfg, 3)), 3
+    if name == "paged_prefill_suffix":
+        return (lambda params, pool: ld.paged_prefill_suffix(
+            params, jnp.asarray(rows), pool, bt, cfg, lens, lens + 3)), 3
+    return (lambda params, pool: ld.paged_verify(
+        params, jnp.asarray(rows), pool, bt, cfg, lens)), 3
+
+
+def _random_pool(cfg, dtype=None):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    zeros = ld.init_page_pool(cfg, _PAGES, _T, dtype=dtype)
+    kk, kv = jax.random.split(jax.random.key(7))
+    return {"k": jax.random.normal(kk, zeros["k"].shape, zeros["k"].dtype),
+            "v": jax.random.normal(kv, zeros["v"].shape, zeros["v"].dtype)}
+
+
+@pytest.mark.parametrize("name", IN_PLACE)
+def test_pool_is_not_copied_to_be_written(name):
+    """The mechanism's guard: jitted with the pool donated, the COMPILED
+    program of each forward holds no ``copy`` and no
+    ``dynamic-update-slice`` whose result has the pool's element count.
+    With the pool as a scanned input and a stacked output of the layer
+    loop there were two of each: every call copied the whole pool to
+    store a few rows of it. This is the CPU compiler's answer at a debug
+    geometry; the proof is the chip's: no pool-sized operation in the
+    ``breakdown.device_ops`` of a traced benchmark run (PERF.md)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    cfg, params = _tiny()
+    # float32: the CPU backend scatters bfloat16 through a float32 copy
+    # of the operand, which says nothing about the loop.
+    pool = _random_pool(cfg, jnp.float32)
+    fn, _ = _in_place_call(name, cfg)
+    text = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool).compile().as_text()
+    assert "while" in text                    # the layer loop is there
+    size = int(np.prod(pool["k"].shape))
+    moved = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* (copy|dynamic-update-slice)\(",
+                      line)
+        if m and np.prod([int(d) for d in m[1].split(",")]) == size:
+            moved.append(line.strip()[:120])
+    assert not moved, moved
+
+
+@pytest.mark.parametrize("name", IN_PLACE)
+def test_in_place_write_touches_only_its_positions(name):
+    """From a pool of random values, one call changes the positions it
+    writes (in every layer: a write at the wrong layer index would leave
+    one untouched) and the scratch page 0, and nothing else: pages no
+    block table maps and every mapped position below the rows' lengths
+    are the values they were. On a two-device mesh the same holds and
+    the returned pool keeps ``decode_shardings``' placement with nothing
+    pinning it: the flat view inside the program shards like the pool."""
+    import jax
+
+    from ray_tpu.models import llama_decode as ld
+    from ray_tpu.parallel.mesh import decode_mesh
+    from ray_tpu.parallel.sharding import axis_rules
+
+    cfg, params = _tiny()
+    fn, n_written = _in_place_call(name, cfg)
+    # One pool per call (the same values each time): a call donates its own.
+    before = {n: np.asarray(a, np.float32)
+              for n, a in _random_pool(cfg).items()}
+    written = np.zeros((_PAGES + 1, _T), bool)
+    for b, start in enumerate(_LENS):
+        for p in range(start, start + n_written):
+            if p < _BT.shape[1] * _T:
+                written[_BT[b, p // _T], p % _T] = True
+    assert not written[[0, 4, 11, 12]].any()
+    assert written.sum() == (3 if n_written == 1 else 3 + 3 + 2)
+    pages, offs = written.nonzero()
+    untouched = ~written
+    untouched[0] = False                       # the scratch page may change
+
+    def check(out_pool):
+        for n in ("k", "v"):
+            after = np.asarray(out_pool[n], np.float32)
+            assert after.shape == before[n].shape
+            np.testing.assert_array_equal(after[:, untouched],
+                                          before[n][:, untouched])
+            changed = (after != before[n]).any(axis=(-1, -2))  # (L, P, T)
+            assert changed[:, pages, offs].all()
+
+    out = jax.jit(fn, donate_argnums=(1,))(params, _random_pool(cfg))
+    check(out[1])
+
+    mesh = decode_mesh((1, 2))
+    sparams, sh = ld.shard_decode_state(params, cfg, mesh)
+    assert sh["rules"]["kv_heads"] == "model"  # the pool really is split
+    pool_sh = {n: sh["pool"][n] for n in ("k", "v")}
+    with axis_rules(mesh, sh["rules"]):
+        out = jax.jit(fn, donate_argnums=(1,))(
+            sparams, jax.device_put(_random_pool(cfg), pool_sh))
+    check(out[1])
+    for n in ("k", "v"):
+        assert out[1][n].sharding.is_equivalent_to(pool_sh[n], 5), \
+            out[1][n].sharding
+
+
 # ------------------------------------------------ engine bit-exactness
 
 
