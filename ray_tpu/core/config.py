@@ -273,12 +273,14 @@ _FLAG_DEFS: Dict[str, tuple] = {
         "which is why this is opt-in. Requests needing host-side logit "
         "processing keep the host path regardless."),
     "decode_warmup": (bool, False,
-        "Pre-dispatch a DecodeEngine's steady-state program set (decode, "
-        "decode-chunk grid, spec draft/verify, device sampler) at "
-        "deployment construction so jit compiles land before traffic "
-        "instead of under the first requests' latency. The steplog's "
-        "jit-compile events then show only prefill buckets (which stay "
-        "lazy — their grid depends on the live prompt mix)."),
+        "Pre-dispatch a DecodeEngine's OPTIONAL steady-state programs "
+        "(decode-chunk grid, spec draft/verify, one admission bucket) "
+        "at deployment construction so jit compiles land before traffic "
+        "instead of under the first requests' latency. The one-token "
+        "decode's ladder of view widths is warmed whatever this says. "
+        "The steplog's jit-compile events then show only prefill "
+        "buckets (which stay lazy — their grid depends on the live "
+        "prompt mix)."),
     "decode_mesh_shape": (str, "",
         "Default (batch, model) decode mesh for DecodeEngines that are "
         "not given an explicit mesh_shape, e.g. '2x4': the engine spans "

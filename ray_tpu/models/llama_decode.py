@@ -22,10 +22,13 @@ Design for the XLA/TPU execution model:
   (``(B, KV, G, D) x (B, C, KV, D)``) so grouped-query models never
   materialize repeated K/V — the cache stays at KV-head width, which is
   the whole point of GQA for decode bandwidth.
-* **Decode is one fused dot per layer**: at ``S_q = 1`` attention is
+* **Decode is a masked dot per layer**: at ``S_q = 1`` attention is
   HBM-bandwidth-bound (read K/V once); a flash kernel cannot beat the
   plain masked dot XLA emits, so the Pallas path is reserved for prefill
-  (``attention_impl="flash"`` with ``q_offset`` chunked prefill).
+  (``attention_impl="flash"`` with ``q_offset`` chunked prefill). What
+  decides a step's cost is how much K/V the dot is given: the paged step
+  reads the pages its slots hold (``live_page_view``), not every slot's
+  whole window.
 
 Two sets of forwards. ``init_cache`` / ``prefill`` / ``decode_step`` /
 ``generate`` over a contiguous per-row cache are the plain reference the
@@ -42,6 +45,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.norms import rms_norm
@@ -128,7 +132,11 @@ def compute_weights(params: Dict[str, Any], config: LlamaConfig,
 
 def _paged_gather(k_p, v_p, block_tables, config: LlamaConfig):
     """Each row's pages back in logical order, ``(B, W * T, KV, D)``: the
-    view the paged attention reads. Named for the device trace."""
+    view the paged attention reads. A row is a request and ``W`` its
+    window (the chunk, the verify), or one live page and ``W`` = 1 (the
+    decode step's list, ``live_page_view``). Named for the device trace:
+    one K and one V operation a layer, which is what the benchmark's
+    roofline share counts."""
     B, W = block_tables.shape
     shape = (B, W * k_p.shape[1], config.n_kv_heads, config.head_dim)
     with jax.named_scope("paged_gather"):
@@ -320,9 +328,11 @@ def decode_step(params: Dict[str, Any], cache: Cache, tokens: jax.Array,
 # vLLM-style paged attention on XLA-friendly static shapes: K/V for ALL
 # slots live in one device pool of ``(pages, page_tokens)`` blocks, and a
 # per-slot block table (int32 page ids, static width) maps logical token
-# positions to pool pages. Attention gathers a slot's pages back into
+# positions to pool pages. The prefills gather a slot's pages back into
 # logical order — value for value the layout of the reference's
-# contiguous cache (``init_cache``), under the same masked-dot attention.
+# contiguous cache (``init_cache``), under the same masked-dot attention;
+# the decode step gathers the flat list of the pages its slots hold and
+# takes each slot's softmax across its pages (``live_page_view``).
 #
 # Page id 0 is a reserved scratch page: block-table entries for positions
 # a slot never allocated point at it, so pad writes land somewhere
@@ -462,35 +472,82 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
     return logits, pool
 
 
+def live_page_view(block_tables, counts, rows: int):
+    """The decode step's view of the pool, built on the host: a flat list
+    of the pages that the stepping slots hold, ``(3, rows)`` int32 with a
+    row ``(pool page, owning slot, index of the page in the slot's
+    sequence)`` for each of the first ``counts[slot]`` entries of
+    ``block_tables[slot]``, slot by slot. A slot that does not decode in
+    this step (idle, mid-prefill) has count 0 and owns no row; a page
+    that two slots share (a prefix hit) is a row of each. The rows past
+    the list pad it to ``rows``, the static width the program was
+    compiled for: the scratch page, owned by slot -1, which is nobody.
+
+    So the step gathers and attends over what its contexts hold, rounded
+    up to ``rows``, and not over ``slots x pages a slot may hold``."""
+    tables = np.asarray(block_tables)
+    counts = np.asarray(counts)
+    slot, index = np.nonzero(
+        np.arange(tables.shape[1])[None, :] < counts[:, None])
+    n = len(slot)
+    if n > rows:
+        raise ValueError(f"{n} live pages do not fit a view of {rows}")
+    view = np.zeros((3, rows), np.int32)
+    view[1] = -1
+    view[0, :n] = tables[slot, index]
+    view[1, :n] = slot
+    view[2, :n] = index
+    return view
+
+
 def paged_decode_step(params: Dict[str, Any], pool: Cache,
-                      block_tables: jax.Array, lengths: jax.Array,
+                      view: jax.Array, lengths: jax.Array,
                       tokens: jax.Array, config: LlamaConfig
                       ) -> Tuple[jax.Array, Cache, jax.Array]:
     """One decode token per slot against paged context. ``tokens``: (B,)
-    int32 written at position ``lengths[b]`` of each row's block-mapped
-    sequence; attention sees positions ``<= length`` across the row's
-    gathered pages — value for value the reference ``decode_step``. The
-    pool rides the layer loop as its carry (``_scan_layers``): each layer
+    int32 written at position ``lengths[b]`` of each slot's sequence;
+    attention sees positions ``<= length`` across the slot's pages: the
+    reference ``decode_step`` in another order of summation.
+
+    ``view`` is ``live_page_view``'s ``(3, N)`` list of the pages the
+    stepping slots hold. Each layer gathers those ``N`` pages (one K and
+    one V gather, ``(N, T, KV, D)``), scores every row against its
+    owner's query, and takes the softmax ACROSS the rows of one slot from
+    the usual two statistics (the maximum and the sum, reduced over the
+    slot's rows through the ``(B, N)`` membership mask), so the work
+    follows the sum of the contexts and not ``B`` times the longest.
+    Scores, statistics and the sum of a slot's partial outputs are
+    float32; probabilities are rounded to the pool's dtype before the
+    value product, as the reference rounds them.
+
+    A slot that owns no row (idle, mid-prefill: static ``B``) writes its
+    token's K/V to the scratch page and gets finite junk logits. The pool
+    rides the layer loop as its carry (``_scan_layers``): each layer
     scatters its ``B`` new rows into the donated buffer, and the program
     holds no second pool."""
     c = config
     B = tokens.shape[0]
     T = pool["k"].shape[2]
-    W = block_tables.shape[1]
-    C = W * T
+    pages, owner, index = view[0], view[1], view[2]          # (N,) each
     pos = lengths                                            # (B,)
     cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
     x = _cast(params["tok_embed"], c.dtype)[tokens][:, None]  # (B, 1, E)
     kv_groups = c.n_heads // c.n_kv_heads
     scale = c.head_dim ** -0.5
-    rows = jnp.arange(B)
-    # Idle/mid-prefill slots also flow through this program (static B);
-    # their parked cursor can sit past the page window — route those
-    # writes to the scratch page instead of clamping into a live page.
-    page = jnp.where(pos < C,
-                     block_tables[rows, jnp.minimum(pos // T, W - 1)], 0)
+    member = owner[None, :] == jnp.arange(B)[:, None]        # (B, N)
+    # The page a slot writes is its row at index pos // T. Without such a
+    # row (the slot does not step, or its cursor is parked past its
+    # pages) the sum is 0, the scratch page: never a live one.
+    page = jnp.sum(jnp.where(
+        member & (index[None, :] == (pos // T)[:, None]),
+        pages[None, :], 0), axis=1)
     off = pos % T
-    valid = (jnp.arange(C)[None, :] <= pos[:, None])         # (B, C)
+    # A pad row reads slot 0's query and cursor; ``valid`` masks it whole.
+    of_row = jnp.maximum(owner, 0)                           # (N,)
+    valid = ((owner >= 0)[:, None]
+             & (index[:, None] * T + jnp.arange(T)[None, :]
+                <= pos[of_row][:, None]))                    # (N, T)
+    member_f32 = member.astype(jnp.float32)
 
     def body(carry, inp):
         x, k_p, v_p = carry                 # the flat pool: _scan_layers
@@ -506,14 +563,27 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
                           ("batch", "length", "kv_heads", "head_dim"))
         k_p, v_p, k_c, v_c = _pool_store(k_p, v_p, base, page, off,
                                          k_new[:, 0], v_new[:, 0],
-                                         block_tables, c)
+                                         pages[:, None], c)  # (N, T, KV, D)
         with jax.named_scope("paged_attn"):
             qg = q[:, 0].reshape(B, c.n_kv_heads, kv_groups, c.head_dim)
-            scores = jnp.einsum("bkgd,bckd->bkgc", qg, k_c,
+            scores = jnp.einsum("nkgd,ntkd->nkgt", qg[of_row], k_c,
                                 preferred_element_type=jnp.float32) * scale
             scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            att = jnp.einsum("bkgc,bckd->bkgd", probs.astype(v_c.dtype), v_c)
+            # The two statistics of a slot's softmax, over its rows.
+            top = jnp.max(jnp.where(member[:, :, None, None],
+                                    scores.max(-1)[None], -1e30), axis=1)
+            e = jnp.where(valid[:, None, None, :],
+                          jnp.exp(scores - top[of_row][..., None]), 0.0)
+            total = jnp.sum(jnp.where(member[:, :, None, None],
+                                      e.sum(-1)[None], 0.0), axis=1)
+            total = jnp.where(total > 0.0, total, 1.0)   # a slot of no rows
+            probs = e / total[of_row][..., None]             # (N, KV, G, T)
+            part = jnp.einsum("nkgt,ntkd->nkgd", probs.astype(v_c.dtype),
+                              v_c, preferred_element_type=jnp.float32)
+            # A one-hot matrix at full precision adds a slot's rows up in
+            # float32 and rounds nothing.
+            att = jnp.einsum("bn,nkgd->bkgd", member_f32, part,
+                             precision=jax.lax.Precision.HIGHEST)
         att = att.reshape(B, 1, c.n_heads, c.head_dim).astype(x.dtype)
         att = constrain(att, ("batch", "length", "attn_heads", "head_dim"))
         out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
@@ -530,16 +600,16 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
 
 
 def paged_decode_chunk(params: Dict[str, Any], pool: Cache,
-                       block_tables: jax.Array, lengths: jax.Array,
+                       view: jax.Array, lengths: jax.Array,
                        tokens: jax.Array, config: LlamaConfig, k: int
                        ) -> Tuple[jax.Array, Cache, jax.Array]:
     """``k`` greedy paged decode steps in ONE jitted program (the
-    dispatch-amortization lever, paged flavor). The block tables are
-    static across the chunk: the caller must have pages allocated to
-    cover ``length + k`` for every stepping slot."""
+    dispatch-amortization lever, paged flavor). The view
+    (``live_page_view``) is static across the chunk: it must list pages
+    that cover ``length + k`` for every stepping slot."""
     def body(carry, _):
         pool, lens, tok = carry
-        logits, pool, lens = paged_decode_step(params, pool, block_tables,
+        logits, pool, lens = paged_decode_step(params, pool, view,
                                                lens, tok, config)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (pool, lens, nxt), nxt
@@ -636,9 +706,9 @@ def paged_verify(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
 
 
 def paged_spec_draft(params: Dict[str, Any], pool: Cache,
-                     block_tables: jax.Array, lengths: jax.Array,
-                     catchup: jax.Array, catchup_lens: jax.Array,
-                     config: LlamaConfig, k: int
+                     block_tables: jax.Array, view: jax.Array,
+                     lengths: jax.Array, catchup: jax.Array,
+                     catchup_lens: jax.Array, config: LlamaConfig, k: int
                      ) -> Tuple[jax.Array, Cache]:
     """Draft-model propose step: ingest the ragged ``catchup`` rows
     (B, 2) — the true tokens the draft has not yet committed, 1 normally
@@ -647,9 +717,11 @@ def paged_spec_draft(params: Dict[str, Any], pool: Cache,
     proposals. Returns ``(proposals (B, k) int32, pool)``. The caller
     owns the draft ``length`` cursors (host-side rollback after
     acceptance); pages must cover ``lengths + catchup_lens + k - 1``
-    positions. A 1-long catch-up row's pad slot writes junk one past
-    the real token — the first proposal's decode step rewrites that
-    exact position before anything gathers it."""
+    positions, in ``block_tables`` (the ingest reads a window a slot, as
+    ``paged_verify`` does) and in ``view`` (the proposals are decode
+    steps: ``live_page_view``). A 1-long catch-up row's pad slot writes
+    junk one past the real token — the first proposal's decode step
+    rewrites that exact position before anything gathers it."""
     logits, pool = paged_verify(params, catchup, pool, block_tables,
                                 config, lengths)
     last = jnp.take_along_axis(
@@ -660,7 +732,7 @@ def paged_spec_draft(params: Dict[str, Any], pool: Cache,
 
     def body(carry, _):
         pool, lens, tok = carry
-        logits, pool, lens = paged_decode_step(params, pool, block_tables,
+        logits, pool, lens = paged_decode_step(params, pool, view,
                                                lens, tok, config)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (pool, lens, nxt), nxt

@@ -5,15 +5,17 @@ replica call path + streaming (``serve/_private/replica.py:231``,
 ``proxy.py:761``) and leaves batching to vLLM-style engines; here the
 engine is TPU-native and owns the jitted programs directly:
 
-* ONE decode program per (slots, capacity) bucket, compiled once. Requests
-  join and leave the running batch between decode steps (continuous
-  batching) — a joining request's prompt is prefilled into the pages of
-  the shared KV pool that its slot's block table maps, then the shared
-  ``paged_decode_step`` advances every active slot together.
+* ONE decode program per rung of a fixed ladder of view widths, each
+  compiled once. Requests join and leave the running batch between decode
+  steps (continuous batching) — a joining request's prompt is prefilled
+  into the pages of the shared KV pool that its slot's block table maps,
+  then the shared ``paged_decode_step`` advances every active slot
+  together, over the flat list of the pages those slots hold.
 * Static shapes throughout: slot count and cache capacity are fixed at
-  engine construction (pick the bucket for your SLO); per-slot ``length``
-  masking makes ragged occupancy exact, so there are NO recompiles at
-  steady state — the serving property that matters on TPU.
+  engine construction (pick the bucket for your SLO), and the ladder
+  follows from them; per-slot ``length`` masking makes ragged occupancy
+  exact, so there are NO recompiles at steady state — the serving
+  property that matters on TPU.
 * Streaming: each emitted token is pushed to the request's callback;
   ``serve``'s streaming HTTP path turns that into chunked responses.
 
@@ -234,6 +236,16 @@ class DecodeEngine:
         self._block_tables = np.zeros(
             (slots, self.slot_pages_max), np.int32)
         self._slot_pages: List[List[int]] = [[] for _ in range(slots)]
+        # The widths a decode step's view of the pool may take: the step
+        # reads the pages its slots hold, padded up to the next rung, and
+        # each rung is one compiled program. Powers of two from 64 rows
+        # to every page of every slot, so a step reads at most twice
+        # what it needs and the set stays small (six at 32 x 64).
+        top = slots * self.slot_pages_max
+        rungs = [64]
+        while rungs[-1] * 2 < top:
+            rungs.append(rungs[-1] * 2)
+        self._view_ladder = tuple(n for n in rungs if n < top) + (top,)
         if self.mesh is not None:
             # Commit the KV state onto the mesh: the shared page pool
             # shards its kv-head dim over "model" (HBM-per-chip drops
@@ -389,10 +401,11 @@ class DecodeEngine:
         # One program per (n, bucket) power-of-two pair: admission
         # scatters K/V into pool pages through the wave's block tables,
         # the suffix program doubles as the chunked-prefill
-        # continuation, and decode gathers each slot's pages back into
-        # logical order. ``width`` (suffix) = static leading block-table
-        # columns the wave touches — cost scales with prefix+suffix,
-        # not max context.
+        # continuation, and decode gathers the flat list of the pages
+        # its slots hold (``_live_view``: one program a rung of
+        # ``_view_ladder``). ``width`` (suffix) = static leading
+        # block-table columns the wave touches — cost scales with
+        # prefix+suffix, not max context.
         self._paged_prefill = self._mesh_scoped(self._program(
             "paged_prefill", self._paged_prefill_impl,
             static_argnames=("n", "bucket"),
@@ -563,10 +576,10 @@ class DecodeEngine:
             "length": cache["length"].at[slot_ids].set(lengths),
         }
 
-    def _paged_decode_impl(self, params, cache, tokens, bt):
+    def _paged_decode_impl(self, params, cache, tokens, view):
         pool = {"k": cache["k"], "v": cache["v"]}
         logits, pool, lens = self._ld.paged_decode_step(
-            params, pool, bt, cache["length"], tokens, self.config)
+            params, pool, view, cache["length"], tokens, self.config)
         return logits, {"k": pool["k"], "v": pool["v"], "length": lens}
 
     def _adopt_pages_impl(self, cache, k_pages, v_pages, ids, slot_ids,
@@ -583,10 +596,10 @@ class DecodeEngine:
             "length": cache["length"].at[slot_ids].set(lengths),
         }
 
-    def _paged_decode_chunk_impl(self, params, cache, tokens, bt, k):
+    def _paged_decode_chunk_impl(self, params, cache, tokens, view, k):
         pool = {"k": cache["k"], "v": cache["v"]}
         toks, pool, lens = self._ld.paged_decode_chunk(
-            params, pool, bt, cache["length"], tokens, self.config, k)
+            params, pool, view, cache["length"], tokens, self.config, k)
         return toks, {"k": pool["k"], "v": pool["v"], "length": lens}
 
     # ------------------------------------------- speculative jitted bodies
@@ -608,15 +621,15 @@ class DecodeEngine:
                       "length": cache["length"]}
 
     def _spec_draft_impl(self, params, cache, catchup, catchup_lens,
-                         bt, k):
+                         bt, view, k):
         """Draft propose: ingest each slot's 1-2 catch-up tokens from
         ``pos = length`` and greedily roll ``k`` proposals against the
         draft pool. ``length`` is host-owned (rolled back with the
         target's cursor after acceptance) — returned unchanged."""
         pool = {"k": cache["k"], "v": cache["v"]}
         toks, pool = self._ld.paged_spec_draft(
-            params, pool, bt, cache["length"], catchup, catchup_lens,
-            self._draft_config, k)
+            params, pool, bt, view, cache["length"], catchup,
+            catchup_lens, self._draft_config, k)
         return toks, {"k": pool["k"], "v": pool["v"],
                       "length": cache["length"]}
 
@@ -634,13 +647,13 @@ class DecodeEngine:
         return {"k": pool["k"], "v": pool["v"],
                 "length": cache["length"].at[slot_ids].set(lengths)}
 
-    def _paged_decode_sampled_impl(self, params, cache, tokens, bt,
+    def _paged_decode_sampled_impl(self, params, cache, tokens, view,
                                    temps, step):
         import jax
 
         pool = {"k": cache["k"], "v": cache["v"]}
         logits, pool, lens = self._ld.paged_decode_step(
-            params, pool, bt, cache["length"], tokens, self.config)
+            params, pool, view, cache["length"], tokens, self.config)
         key = jax.random.fold_in(jax.random.key(0), step)
         toks = self._ld.sample_batch(logits, temps, key)
         return toks, {"k": pool["k"], "v": pool["v"], "length": lens}
@@ -710,6 +723,21 @@ class DecodeEngine:
 
     def _seq_pages(self, tokens: int) -> int:
         return -(-tokens // self.page_tokens)
+
+    def _live_view(self, tables: np.ndarray,
+                   slot_pages: List[List[int]]) -> np.ndarray:
+        """The view the decode about to be dispatched reads
+        (``llama_decode.live_page_view``): the pages ``slot_pages`` of
+        the ACTIVE slots, on the smallest rung of the ladder that holds
+        them. Count after ``_ensure_decode_pages``: the pages must cover
+        every token the program writes. Idle and mid-prefill slots own
+        no row of it."""
+        counts = np.zeros((self.slots,), np.int32)
+        for slot in self._active:
+            counts[slot] = len(slot_pages[slot])
+        live = int(counts.sum())
+        rung = next(n for n in self._view_ladder if n >= live)
+        return self._ld.live_page_view(tables, counts, rung)
 
     def _ensure_decode_pages(self, k: int) -> None:
         """Every active slot can write its next ``k`` tokens. Oldest
@@ -1220,12 +1248,14 @@ class DecodeEngine:
                 req.prefilled = req.prefix_len
                 # Park the device cursor at the spliced length NOW: the
                 # slot may sit un-ticked for several steps (one chunk
-                # per step, FIFO), and each decode step scribbles its
-                # idle-row junk at pos=length — at 0 that would land
-                # INSIDE a shared prefix page and corrupt it for every
-                # borrower. At prefix_len it lands in the slot's own
-                # (or scratch) territory, overwritten by the first
-                # chunk's scatter.
+                # per step, FIFO), and a spec round's verify, which
+                # reads the whole block table, scribbles its idle-row
+                # junk at pos=length — at 0 that would land INSIDE a
+                # shared prefix page and corrupt it for every borrower.
+                # At prefix_len it lands in the slot's own (or scratch)
+                # territory, overwritten by the first chunk's scatter.
+                # (A plain decode step writes a slot outside its view to
+                # the scratch page, wherever the cursor is.)
                 self.cache["length"] = \
                     self.cache["length"].at[slot].set(req.prefix_len)
                 self._prefilling[slot] = req
@@ -1919,15 +1949,17 @@ class DecodeEngine:
         chunk = min(chunk, self._pick_chunk())
         stepped = len(self._active)
         ctx = self._ctx_tokens() if rec else None
+        view = self._live_view(self._block_tables, self._slot_pages)
+        rung = view.shape[1]
         if chunk > 1:
             t_d0 = time.time() if rec else 0.0
             toks, self.cache = self._dispatch_fresh(
-                ("decode_k", chunk),
+                ("decode_k", chunk, rung),
                 lambda: self._decode_k(
                     self.params, self.cache,
-                    jnp.asarray(self._tokens),
-                    jnp.asarray(self._block_tables), k=chunk),
-                batch=stepped, ctx_tokens=ctx)
+                    jnp.asarray(self._tokens), jnp.asarray(view),
+                    k=chunk),
+                batch=stepped, ctx_tokens=ctx, view_pages=rung)
             if rec:
                 sl.begin("fetch", program="decode_k")
             toks = np.array(toks)  # (chunk, slots)
@@ -1949,17 +1981,17 @@ class DecodeEngine:
                             and tok == req.eos_id):
                         self._finish_in_step(slot)
                         break
-            self._steplog_row(t_step0, phases, ctx)
+            self._steplog_row(t_step0, phases, ctx, rung)
             return stepped
         if self._device_sampler:
-            return self._sampled_step(t_step0, phases, rec, ctx)
+            return self._sampled_step(t_step0, phases, rec, ctx, view)
         t_d0 = time.time() if rec else 0.0
         logits, self.cache = self._dispatch_fresh(
-            ("decode",),
+            ("decode", rung),
             lambda: self._decode(
                 self.params, self.cache, jnp.asarray(self._tokens),
-                jnp.asarray(self._block_tables)),
-            batch=stepped, ctx_tokens=ctx)
+                jnp.asarray(view)),
+            batch=stepped, ctx_tokens=ctx, view_pages=rung)
         if rec:
             sl.begin("fetch", program="decode")
         logits = np.array(logits)
@@ -1977,13 +2009,16 @@ class DecodeEngine:
             if req.generated >= req.max_new_tokens or (
                     req.eos_id is not None and tok == req.eos_id):
                 self._finish_in_step(slot)
-        self._steplog_row(t_step0, phases, ctx)
+        self._steplog_row(t_step0, phases, ctx, rung)
         return stepped
 
     def _ctx_tokens(self) -> int:
         """KV positions the decode about to be dispatched really needs:
-        the active slots' context lengths, the new token included. What
-        the capacity-wide gather reads beyond it is waste."""
+        the active slots' context lengths, the new token included. The
+        view it reads is ``view_pages`` pages wide (``_live_view``): the
+        last page of each slot is part empty and the rung is rounded up,
+        and ``ctx_tokens / (view_pages x page_tokens)`` is the share of
+        the view that is context."""
         return sum(r.prompt_len + r.generated
                    for r in self._active.values())
 
@@ -2044,14 +2079,17 @@ class DecodeEngine:
             for j in range(cl):
                 catchup[slot, j] = self._token_at(req, D + j)
             clens[slot] = cl
+        # The draft's proposals are decode steps over the DRAFT pool's
+        # live pages; a draftless slot holds none and writes to scratch.
+        dview = self._live_view(self._draft_bt, self._draft_slot_pages)
         t_d0 = time.time() if rec else 0.0
         toks_d, self._draft_cache = self._dispatch_fresh(
-            ("spec_draft", k),
+            ("spec_draft", k, dview.shape[1]),
             lambda: self._spec_draft(
                 self._draft_params, self._draft_cache,
                 jnp.asarray(catchup), jnp.asarray(clens),
-                jnp.asarray(self._draft_bt), k=k),
-            batch=stepped)
+                jnp.asarray(self._draft_bt), jnp.asarray(dview), k=k),
+            batch=stepped, view_pages=dview.shape[1])
         self._slice("fetch", program="spec_draft")
         # np.array (never asarray): the next donated dispatch must not
         # clobber an aliased host view of these tokens (PR 14 pin).
@@ -2134,7 +2172,7 @@ class DecodeEngine:
 
     def _sampled_step(self, t_step0: float,
                       phases: List[Dict[str, Any]], rec: bool,
-                      ctx: Optional[int]) -> int:
+                      ctx: Optional[int], view: np.ndarray) -> int:
         """Single decode step with sampling fused into the device
         program: the (slots, vocab) logits never cross the host
         boundary — only (slots,) token ids do — and consecutive sampled
@@ -2151,13 +2189,14 @@ class DecodeEngine:
         tin = (self._tokens_dev if self._tokens_dev is not None
                else jnp.asarray(self._tokens))
         t_d0 = time.time() if rec else 0.0
+        rung = view.shape[1]
         toks_dev, self.cache = self._dispatch_fresh(
-            ("decode_sampled",),
+            ("decode_sampled", rung),
             lambda: self._decode_sampled(
                 self.params, self.cache, tin,
-                jnp.asarray(self._block_tables), jnp.asarray(temps),
+                jnp.asarray(view), jnp.asarray(temps),
                 jnp.asarray(self.steps, jnp.int32)),
-            batch=stepped, ctx_tokens=ctx)
+            batch=stepped, ctx_tokens=ctx, view_pages=rung)
         self._slice("fetch", program="decode_sampled")
         toks = np.array(toks_dev)  # np.array: next dispatch donates
         self._slice("sample_emit")
@@ -2175,11 +2214,12 @@ class DecodeEngine:
             if req.generated >= req.max_new_tokens or (
                     req.eos_id is not None and tok == req.eos_id):
                 self._finish_in_step(slot)
-        self._steplog_row(t_step0, phases, ctx)
+        self._steplog_row(t_step0, phases, ctx, rung)
         return stepped
 
     def _steplog_row(self, t0: float, phases: List[Dict[str, Any]],
-                     ctx_tokens: Optional[int] = None) -> None:
+                     ctx_tokens: Optional[int] = None,
+                     view_pages: Optional[int] = None) -> None:
         """Close the step's timeline row; idle steps with no phases and
         no pending events record nothing (an idle engine must not churn
         useful rows out of the bounded ring)."""
@@ -2202,21 +2242,55 @@ class DecodeEngine:
             pages_free=self._pages.free_count,
             pages_pinned=(self.prefix.pinned_pages
                           if self.prefix is not None else None),
-            ctx_tokens=ctx_tokens)
+            ctx_tokens=ctx_tokens, view_pages=view_pages)
+
+    def warm_decode(self) -> None:
+        """Dispatch the step loop's one-token decode once at every rung
+        of the view ladder, so that no step of a serving engine meets a
+        rung for the first time: which rung a step takes follows the
+        traffic, but the ladder is fixed by the engine's geometry, and
+        whatever builds an engine for traffic calls this first. On an
+        idle engine: the view is empty, so every write goes to the
+        scratch page, and the cursors are parked at 0 afterwards."""
+        import jax.numpy as jnp
+
+        toks = jnp.asarray(self._tokens)
+        for rung, view in self._empty_views():
+            if self._device_sampler:
+                _, self.cache = self._dispatch_fresh(
+                    ("decode_sampled", rung),
+                    lambda: self._decode_sampled(
+                        self.params, self.cache, toks, view,
+                        jnp.zeros((self.slots,), jnp.float32),
+                        jnp.asarray(0, jnp.int32)))
+            else:
+                _, self.cache = self._dispatch_fresh(
+                    ("decode", rung),
+                    lambda: self._decode(self.params, self.cache, toks,
+                                         view))
+        self.cache["length"] = self.cache["length"].at[:].set(0)
+        self._tokens_dev = None
+
+    def _empty_views(self):
+        """``(rung, view)`` up the ladder, each view listing no page."""
+        import jax.numpy as jnp
+
+        none = np.zeros((self.slots,), np.int32)
+        for rung in self._view_ladder:
+            yield rung, jnp.asarray(self._ld.live_page_view(
+                self._block_tables, none, rung))
 
     def warmup(self) -> None:
-        """Pre-dispatch the step-loop programs (decode, the chunk grid,
-        the fused sampler, the spec round, one admission bucket) so the
-        first real request never pays their jit compiles. Safe on an
-        idle engine: writes route to the scratch page (idle block
-        tables are all zeros), and the parked KV lengths are restored
+        """Pre-dispatch the step-loop programs (``warm_decode``'s ladder,
+        and the optional ones: the chunk grid and the spec round at
+        every rung, one admission bucket) so the first real request
+        never pays their jit compiles. Safe on an idle engine: writes
+        route to the scratch page (idle block tables are all zeros, the
+        views are empty), and the parked KV lengths are restored
         afterwards."""
         import jax.numpy as jnp
 
         toks = jnp.asarray(self._tokens)
-        zero_t = jnp.zeros((self.slots,), jnp.float32)
-        step0 = jnp.asarray(0, jnp.int32)
-        bt = jnp.asarray(self._block_tables)
         bucket = self.prefill_bucket
         wp = max(1, -(-bucket // self.page_tokens))
         _, self.cache = self._dispatch_fresh(
@@ -2227,36 +2301,32 @@ class DecodeEngine:
                 jnp.asarray([0], jnp.int32),
                 jnp.asarray(self._block_tables[:1, :wp]),
                 jnp.asarray([0], jnp.int32), n=1, bucket=bucket))
-        _, self.cache = self._dispatch_fresh(
-            ("decode",),
-            lambda: self._decode(self.params, self.cache, toks, bt))
-        c = 2
-        while c <= self.decode_chunk:
-            _, self.cache = self._dispatch_fresh(
-                ("decode_k", c),
-                lambda: self._decode_k(self.params, self.cache,
-                                       toks, bt, k=c))
-            c *= 2
-        if self._device_sampler:
-            _, self.cache = self._dispatch_fresh(
-                ("decode_sampled",),
-                lambda: self._decode_sampled(
-                    self.params, self.cache, toks, bt, zero_t,
-                    step0))
+        self.warm_decode()
+        for rung, view in self._empty_views():
+            c = 2
+            while c <= self.decode_chunk:
+                _, self.cache = self._dispatch_fresh(
+                    ("decode_k", c, rung),
+                    lambda: self._decode_k(self.params, self.cache,
+                                           toks, view, k=c))
+                c *= 2
+            if self.spec:
+                k = self.spec_k
+                _, self._draft_cache = self._dispatch_fresh(
+                    ("spec_draft", k, rung),
+                    lambda: self._spec_draft(
+                        self._draft_params, self._draft_cache,
+                        jnp.zeros((self.slots, 2), jnp.int32),
+                        jnp.ones((self.slots,), jnp.int32),
+                        jnp.asarray(self._draft_bt), view, k=k))
         if self.spec:
             k = self.spec_k
-            _, self._draft_cache = self._dispatch_fresh(
-                ("spec_draft", k),
-                lambda: self._spec_draft(
-                    self._draft_params, self._draft_cache,
-                    jnp.zeros((self.slots, 2), jnp.int32),
-                    jnp.ones((self.slots,), jnp.int32),
-                    jnp.asarray(self._draft_bt), k=k))
             _, self.cache = self._dispatch_fresh(
                 ("spec_verify", k),
                 lambda: self._spec_verify(
                     self.params, self.cache,
-                    jnp.zeros((self.slots, k + 1), jnp.int32), bt))
+                    jnp.zeros((self.slots, k + 1), jnp.int32),
+                    jnp.asarray(self._block_tables)))
             self._draft_cache["length"] = \
                 self._draft_cache["length"].at[:].set(0)
         self.cache["length"] = self.cache["length"].at[:].set(0)
@@ -2540,8 +2610,15 @@ class LlamaDecodeDeployment:
             spec_k=sk if draft_params is not None else 0,
             spec_draft_pool_pages=spec_draft_pool_pages,
             device_sampler=device_sampler)
+        # The decode ladder is warmed whatever the knob says: which rung
+        # a step takes follows the traffic, and a rung met first under
+        # load is seconds of compile in one request's latency. The knob
+        # governs the optional programs (chunk grid, spec round, one
+        # admission bucket), which ``warmup`` adds to the ladder.
         if (rt_config.decode_warmup if warmup is None else warmup):
             self.engine.warmup()
+        else:
+            self.engine.warm_decode()
         # Prefill->decode handoff lease ledger (disaggregated serving):
         # tracks published-but-undischarged KV-page handoffs so the TTL
         # sweep (riding replica_metrics) can return refs nobody claimed.

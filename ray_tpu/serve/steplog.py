@@ -23,7 +23,8 @@ the same slices lie on the profiler's clock beside the device
 operations (attributes such as ``program`` and ``tokens`` become the
 event's stats), and the jitted programs there are named
 ``jit_engine_<program>``. A row also counts what the step worked on:
-``ctx_tokens`` (KV positions the decode really needs) and
+``ctx_tokens`` (KV positions the decode really needs), ``view_pages``
+(pages wide the view it read: the rung of the engine's ladder) and
 ``pages_pinned`` (pages the prefix index holds).
 
 Recording is a deque append + one ``time.time()`` read and one
@@ -141,7 +142,8 @@ class StepTimeline:
                List[Dict[str, Any]], active: int, prefilling: int,
                queued: int, pages_free: Optional[int] = None,
                pages_pinned: Optional[int] = None,
-               ctx_tokens: Optional[int] = None) -> None:
+               ctx_tokens: Optional[int] = None,
+               view_pages: Optional[int] = None) -> None:
         """One engine step: ``phases`` are the step's timed sub-slices
         ([{phase, t0, t1, ...attrs}]); occupancy is sampled at the step
         boundary; queued events ride along and clear. The slices begun
@@ -161,6 +163,8 @@ class StepTimeline:
             row["pages_pinned"] = pages_pinned
         if ctx_tokens is not None:
             row["ctx_tokens"] = ctx_tokens
+        if view_pages is not None:
+            row["view_pages"] = view_pages
         if self._open is not None:
             self._switch("park", t1, {"active": active})
             row["slices"] = self._slices[:-1]

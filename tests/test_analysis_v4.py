@@ -436,15 +436,15 @@ def test_mutation_decode_unwrapped_dispatch():
     found, graph = mutant_findings(
         donation_safety.check, "ray_tpu/serve/decode.py",
         """toks_dev, self.cache = self._dispatch_fresh(
-            ("decode_sampled",),
+            ("decode_sampled", rung),
             lambda: self._decode_sampled(
                 self.params, self.cache, tin,
-                jnp.asarray(self._block_tables), jnp.asarray(temps),
+                jnp.asarray(view), jnp.asarray(temps),
                 jnp.asarray(self.steps, jnp.int32)),
-            batch=stepped, ctx_tokens=ctx)""",
+            batch=stepped, ctx_tokens=ctx, view_pages=rung)""",
         """toks_dev, self.cache = self._decode_sampled(
             self.params, self.cache, tin,
-            jnp.asarray(self._block_tables), jnp.asarray(temps),
+            jnp.asarray(view), jnp.asarray(temps),
             jnp.asarray(self.steps, jnp.int32))""")
     hits = [f for f in found if f.rule == rules.DONATION_UNGUARDED]
     assert hits and hits[0].path == "ray_tpu/serve/decode.py"

@@ -172,11 +172,12 @@ def test_index_hashes_on_pow2_grid():
 
 
 def test_paged_matches_contiguous_across_boundaries():
-    """Paged prefill + decode logits are BIT-EXACT vs the reference's
-    contiguous cache (``ld.prefill`` / ``ld.decode_step``, same
-    capacity) while the sequence crosses page and bucket boundaries:
-    the gather through the block table restores the reference's layout
-    value for value, and the attention over it is the same dot."""
+    """Paged prefill logits are BIT-EXACT vs the reference's contiguous
+    cache (``ld.prefill``, same capacity): the attention is the same
+    program, only the K/V destination differs. The paged decode step's
+    are the reference ``ld.decode_step``'s within ``LOGITS_ATOL`` while
+    the sequence crosses page and bucket boundaries: the same softmax,
+    summed page by page over the list of the row's live pages."""
     import jax.numpy as jnp
 
     from ray_tpu.models import llama_decode as ld
@@ -200,9 +201,10 @@ def test_paged_matches_contiguous_across_boundaries():
     for i in range(20):
         assert int(ta[0]) == int(tb[0]), f"token diverged at step {i}"
         la, cont = ld.decode_step(params, cont, ta, cfg)
+        view = ld.live_page_view(bt, [-(-(14 + i) // T)], 8)
         lb, pool, lens = ld.paged_decode_step(
-            params, pool, jnp.asarray(bt), lens, tb, cfg)
-        assert jnp.array_equal(la, lb), f"decode logits diverged at {i}"
+            params, pool, jnp.asarray(view), lens, tb, cfg)
+        assert_logits_close(lb, la)
         ta = jnp.argmax(la, -1).astype(jnp.int32)
         tb = jnp.argmax(lb, -1).astype(jnp.int32)
 
@@ -230,9 +232,10 @@ def test_paged_suffix_prefill_token_exact():
     toks = [int(jnp.argmax(logits, -1)[0])]
     lens = jnp.asarray([24], jnp.int32)
     t = jnp.argmax(logits, -1).astype(jnp.int32)
+    view = jnp.asarray(ld.live_page_view(bt, [4], 4))
     for _ in range(5):
         logits, pool, lens = ld.paged_decode_step(
-            params, pool, jnp.asarray(bt), lens, t, cfg)
+            params, pool, view, lens, t, cfg)
         t = jnp.argmax(logits, -1).astype(jnp.int32)
         toks.append(int(t[0]))
     assert toks == _solo(params, cfg, prompt.tolist(), 6)
@@ -262,14 +265,15 @@ def _in_place_call(name, cfg):
     from ray_tpu.models import llama_decode as ld
 
     bt, lens = jnp.asarray(_BT), jnp.asarray(_LENS)
+    view = jnp.asarray(ld.live_page_view(_BT, (_BT > 0).sum(1), 16))
     rows = np.random.default_rng(5).integers(
         0, cfg.vocab_size, (3, 3)).astype(np.int32)
     if name == "paged_decode_step":
         return (lambda params, pool: ld.paged_decode_step(
-            params, pool, bt, lens, jnp.asarray(rows[:, 0]), cfg)), 1
+            params, pool, view, lens, jnp.asarray(rows[:, 0]), cfg)), 1
     if name == "paged_decode_chunk":
         return (lambda params, pool: ld.paged_decode_chunk(
-            params, pool, bt, lens, jnp.asarray(rows[:, 0]), cfg, 3)), 3
+            params, pool, view, lens, jnp.asarray(rows[:, 0]), cfg, 3)), 3
     if name == "paged_prefill_suffix":
         return (lambda params, pool: ld.paged_prefill_suffix(
             params, jnp.asarray(rows), pool, bt, cfg, lens, lens + 3)), 3
@@ -376,6 +380,282 @@ def test_in_place_write_touches_only_its_positions(name):
     for n in ("k", "v"):
         assert out[1][n].sharding.is_equivalent_to(pool_sh[n], 5), \
             out[1][n].sharding
+
+
+# ------------------------------------- the decode step's live-page view
+#
+# ``paged_decode_step`` reads a flat list of the pages its slots hold
+# (``live_page_view``), ``N`` rows on a ladder, and takes each slot's
+# softmax across its rows. Pages of four tokens; four slots of 32 pages
+# make the ladder (64, 128), as the engine derives it.
+
+_LP_T, _LP_CAP, _LP_LADDER = 4, 128, (64, 128)
+
+
+def _lp_prompts(cfg, lengths, seed=11):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+            for n in lengths]
+
+
+def _lp_rows(prompts):
+    rows = np.zeros((len(prompts), max(map(len, prompts))), np.int32)
+    for b, p in enumerate(prompts):
+        rows[b, :len(p)] = p
+    return rows, np.array([len(p) for p in prompts], np.int32)
+
+
+def _lp_reference(cfg, params, prompts):
+    """The reference's contiguous cache filled with ``prompts``:
+    ``(last logits, cache)``."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    rows, lens = _lp_rows(prompts)
+    return ld.prefill(params, jnp.asarray(rows),
+                      ld.init_cache(cfg, len(prompts), _LP_CAP), cfg,
+                      lengths=jnp.asarray(lens))
+
+
+def _lp_view(tables, lens, ahead, stepping=None):
+    """The view a step that writes ``ahead`` tokens a slot needs, on the
+    smallest rung of the ladder: ``(view, rung)``."""
+    from ray_tpu.models import llama_decode as ld
+
+    counts = -(-(np.asarray(lens) + ahead) // _LP_T)
+    if stepping is not None:
+        counts = np.where(stepping, counts, 0)
+    rung = next(n for n in _LP_LADDER if n >= counts.sum())
+    return ld.live_page_view(tables, counts, rung), rung
+
+
+def _lp_lockstep(cfg, params, prompts, pool, tables, steps):
+    """Teacher-forced on the reference's greedy tokens, the paged step's
+    logits are the reference's at every step; returns the rungs used and
+    the pool."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    logits, cont = _lp_reference(cfg, params, prompts)
+    lens = jnp.asarray([len(p) for p in prompts], jnp.int32)
+    rungs = set()
+    for _ in range(steps):
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        view, rung = _lp_view(tables, np.asarray(lens), 1)
+        rungs.add(rung)
+        logits, cont = ld.decode_step(params, cont, tok, cfg)
+        got, pool, lens = ld.paged_decode_step(
+            params, pool, jnp.asarray(view), lens, tok, cfg)
+        assert_logits_close(got, logits)
+    return rungs, pool
+
+
+def _lp_own_pages(slots, pages_each=_LP_CAP // _LP_T):
+    """Block tables in which every slot owns its whole window."""
+    return np.arange(1, 1 + slots * pages_each, dtype=np.int32).reshape(
+        slots, pages_each)
+
+
+def _lp_ragged_two_rungs(cfg, params):
+    """Contexts of 3 to 118 tokens fill 64 pages, then 65: the lowest
+    rung and the one above it, one set of logits."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    prompts = _lp_prompts(cfg, (3, 39, 90, 118))
+    tables = _lp_own_pages(4)
+    rows, lens = _lp_rows(prompts)
+    _, pool = ld.paged_prefill(
+        params, jnp.asarray(rows), ld.init_page_pool(cfg, 128, _LP_T),
+        jnp.asarray(tables), cfg, lengths=jnp.asarray(lens))
+    rungs, _ = _lp_lockstep(cfg, params, prompts, pool, tables, 6)
+    assert rungs == {64, 128}
+
+
+def _lp_shared_page(cfg, params):
+    """Two slots borrow pages 1 and 2 (a prefix hit): a row of the view
+    each, read by both, written by neither."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    head, = _lp_prompts(cfg, (8,))
+    tails = _lp_prompts(cfg, (3, 5), seed=12)
+    prompts = [np.concatenate([head, t]) for t in tails]
+    tables = np.zeros((2, _LP_CAP // _LP_T), np.int32)
+    tables[0, :5] = [1, 2, 3, 6, 7]
+    tables[1, :6] = [1, 2, 4, 5, 8, 9]
+    pool = ld.init_page_pool(cfg, 9, _LP_T)
+    _, pool = ld.paged_prefill(params, jnp.asarray(prompts[0][None]), pool,
+                               jnp.asarray(tables[:1, :3]), cfg)
+    _, pool = ld.paged_prefill_suffix(
+        params, jnp.asarray(tails[1][None]), pool,
+        jnp.asarray(tables[1:, :4]), cfg, jnp.asarray([8], jnp.int32),
+        jnp.asarray([13], jnp.int32))
+    view, _ = _lp_view(tables, [11, 13], 1)
+    assert sorted(view[0][view[1] >= 0].tolist()) == [1, 1, 2, 2, 3, 4, 5]
+    before = np.asarray(pool["k"][:, 1:3], np.float32)
+    _, pool = _lp_lockstep(cfg, params, prompts, pool, tables, 7)
+    np.testing.assert_array_equal(
+        np.asarray(pool["k"][:, 1:3], np.float32), before)
+
+
+def _lp_idle_and_prefilling(cfg, params):
+    """Slots 0 and 2 decode beside an idle slot and one mid-prefill,
+    whose cursor is parked INSIDE its second page: neither owns a row,
+    both get finite logits, both write to the scratch page, and the
+    mid-prefill slot's pages are the values they were."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    prompts = _lp_prompts(cfg, (6, 13))
+    tables = np.zeros((4, _LP_CAP // _LP_T), np.int32)
+    tables[0, :2] = [1, 2]
+    tables[2, :4] = [3, 4, 5, 6]
+    tables[3, :2] = [9, 10]
+    pool = _random_pool(cfg)
+    rows, lens = _lp_rows(prompts)
+    _, pool = ld.paged_prefill(
+        params, jnp.asarray(rows), pool, jnp.asarray(tables[[0, 2], :4]),
+        cfg, lengths=jnp.asarray(lens))
+    logits, cont = _lp_reference(cfg, params, prompts)
+    tok = np.zeros((4,), np.int32)
+    tok[[0, 2]] = np.argmax(logits, -1)
+    lens4 = np.array([6, 0, 13, 6], np.int32)
+    stepping = np.array([True, False, True, False])
+    view, rung = _lp_view(tables, lens4, 1, stepping)
+    assert rung == 64 and set(view[1].tolist()) == {-1, 0, 2}
+    before = {n: np.asarray(pool[n], np.float32) for n in ("k", "v")}
+    want, _ = ld.decode_step(params, cont, jnp.asarray(tok[[0, 2]]), cfg)
+    got, pool, lens_out = ld.paged_decode_step(
+        params, pool, jnp.asarray(view), jnp.asarray(lens4),
+        jnp.asarray(tok), cfg)
+    assert np.isfinite(np.asarray(got)).all()
+    assert_logits_close(np.asarray(got)[[0, 2]], want)
+    assert np.asarray(lens_out).tolist() == [7, 1, 14, 7]
+    written = np.zeros((_PAGES + 1, _LP_T), bool)
+    written[2, 2] = written[6, 1] = True        # positions 6 and 13
+    written[0] = True                           # the scratch page may change
+    for n in ("k", "v"):
+        after = np.asarray(pool[n], np.float32)
+        np.testing.assert_array_equal(after[:, ~written],
+                                      before[n][:, ~written])
+        assert (after[:, 0] != before[n][:, 0]).any()
+
+
+def _lp_decode_k_crosses_page(cfg, params):
+    """``paged_decode_chunk`` over a view that lists the pages of
+    ``length + k``: both slots cross a page boundary inside the chunk,
+    and the chunk's tokens are the reference's."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    prompts = _lp_prompts(cfg, (6, 11))
+    tables = _lp_own_pages(2)
+    rows, lens = _lp_rows(prompts)
+    logits, pool = ld.paged_prefill(
+        params, jnp.asarray(rows), ld.init_page_pool(cfg, 64, _LP_T),
+        jnp.asarray(tables), cfg, lengths=jnp.asarray(lens))
+    first = jnp.argmax(logits, -1).astype(jnp.int32)
+    view, _ = _lp_view(tables, lens, 4)
+    assert (view[1] == 0).sum() == 3 and (view[1] == 1).sum() == 4
+    toks, _, lens_out = ld.paged_decode_chunk(
+        params, pool, jnp.asarray(view), jnp.asarray(lens), first, cfg, 4)
+    assert np.asarray(lens_out).tolist() == [10, 15]
+    for b, prompt in enumerate(prompts):
+        served = [int(first[b])] + np.asarray(toks)[:, b].tolist()
+        assert_stream_is_the_references(params, cfg, prompt, served)
+
+
+def _lp_preempted_returns(cfg, params):
+    """An engine on this geometry under page pressure: the youngest
+    request is preempted and returns through a prefill of prompt +
+    emitted tokens, then decodes on through the view; every stream is
+    the reference's, and every step read a rung of the ladder."""
+    from ray_tpu.serve.decode import DecodeEngine
+
+    eng = DecodeEngine(params, cfg, slots=4, capacity=_LP_CAP,
+                       page_tokens=_LP_T, pool_pages=40,
+                       prefix_pool_entries=0)
+    assert eng._view_ladder == _LP_LADDER
+    prompts = [p.tolist() for p in _lp_prompts(cfg, (30, 30, 30, 30))]
+    reqs = [eng.submit(p, max_new_tokens=60) for p in prompts]
+    _drive(eng, reqs, budget=3000)
+    assert eng.preempted > 0
+    for p, r in zip(prompts, reqs):
+        assert r.status == "completed" and len(r.output) == 60
+        assert_stream_is_the_references(params, cfg, p, r.output)
+    widths = {r["view_pages"] for r in eng.timeline()["rows"]
+              if "view_pages" in r}
+    assert widths and widths <= set(_LP_LADDER)
+    assert eng.stats()["pages_in_use"] == 0
+    eng.shutdown()
+
+
+LIVE_PAGE_CASES = {
+    "ragged_two_rungs": _lp_ragged_two_rungs,
+    "shared_page": _lp_shared_page,
+    "idle_and_prefilling": _lp_idle_and_prefilling,
+    "decode_k_crosses_page": _lp_decode_k_crosses_page,
+    "preempted_returns": _lp_preempted_returns,
+}
+
+
+@pytest.mark.parametrize("case", LIVE_PAGE_CASES)
+def test_live_page_step_is_the_references(case):
+    """The live-page decode step against the reference ``decode_step``
+    (``LOGITS_ATOL`` / ``MARGIN`` of ``stream_reference.py``)."""
+    cfg, params = _tiny(max_seq_len=512)
+    LIVE_PAGE_CASES[case](cfg, params)
+
+
+def test_decode_at_the_lowest_rung_holds_nothing_capacity_wide():
+    """The mechanism's guard, beside the in-place one above: compiled at
+    the lowest rung (64 rows, where 4 slots x 32 pages are 128), no
+    operation of the decode step has a result of the capacity-wide
+    shapes, the gathered view ``[128, T, KV, D]`` (``[B, 32 x T, KV,
+    D]`` a slot) or scores ``[B, KV, G, 32 x T]``, and the layer loop holds exactly one K and one V
+    gather under ``paged_gather``, each with a 4-d result of the rung's
+    rows: what the benchmark's ``paged_attn_roofline_pct`` credits one
+    layer's K or V for (``benchmarks/kernel_counts.py``), so a second
+    gather a layer would count the useful bytes twice. This is the CPU
+    compiler's answer; the chip's is in the traced runs (PERF.md)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as ld
+
+    cfg, params = _tiny(max_seq_len=512)
+    slots, width = 4, _LP_CAP // _LP_T
+    pool = ld.init_page_pool(cfg, 40, _LP_T, dtype=jnp.float32)
+    view = ld.live_page_view(_lp_own_pages(slots), np.full(slots, 5), 64)
+    text = jax.jit(
+        lambda params, pool: ld.paged_decode_step(
+            params, pool, jnp.asarray(view), jnp.full((slots,), 19),
+            jnp.zeros((slots,), jnp.int32), cfg),
+        donate_argnums=(1,)).lower(params, pool).compile().as_text()
+    kv, d = cfg.n_kv_heads, cfg.head_dim
+    wide = {(slots * width, _LP_T, kv, d), (slots, _LP_CAP, kv, d),
+            (slots, kv, cfg.n_heads // kv, _LP_CAP)}
+    gathers = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* ([\w\-]+)\(", line)
+        if not m:
+            continue
+        # Unit axes dropped: the CPU compiler keeps the gather's window
+        # axis, ``[64, 1, T, KV, D]``, where the chip's folds it away.
+        shape = tuple(int(x) for x in m[1].split(",") if x != "1")
+        assert shape not in wide, line.strip()[:160]
+        if m[2] == "gather" and "paged_gather" in line:
+            gathers.append(shape)
+    assert gathers == [(64, _LP_T, kv, d)] * 2, gathers
 
 
 # ------------------------------------------------ engine bit-exactness

@@ -90,8 +90,11 @@ def _paged_logits(model, prompts, shardings=None):
     pd = jax.jit(partial(ld.paged_decode_step, config=cfg), **jit_kw(2))
     lens = jnp.full((B,), S, jnp.int32)
     tok = jnp.argmax(lg, -1).astype(jnp.int32)
+    # The step's view: every page of every row, padded to a wider rung.
+    view = jnp.asarray(ld.live_page_view(
+        np.asarray(bt), np.full((B,), W), 2 * B * W))
     for _ in range(4):
-        lg, pool, lens = pd(params, pool, bt, lens, tok)
+        lg, pool, lens = pd(params, pool, view, lens, tok)
         tok = jnp.argmax(lg, -1).astype(jnp.int32)
     out["decode"] = np.asarray(lg)
 

@@ -422,8 +422,10 @@ def test_warmup_predispatches_step_programs(model, draft):
     spec = _spec_engine(model, draft, k=3, decode_chunk=4,
                         device_sampler=True)
     spec.warmup()
-    for key in [("decode",), ("decode_k", 2), ("decode_k", 4),
-                ("decode_sampled",), ("spec_draft", 3),
+    # 4 slots x 16 pages: the ladder of view widths is the one rung 64.
+    # With the sampler fused, ``decode_sampled`` is the one-token decode.
+    for key in [("decode_sampled", 64), ("decode_k", 2, 64),
+                ("decode_k", 4, 64), ("spec_draft", 3, 64),
                 ("spec_verify", 3), ("paged_prefill", 1, 128)]:
         assert key in spec._compiled, key
     assert _np.asarray(spec.cache["length"]).sum() == 0

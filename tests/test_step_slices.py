@@ -173,6 +173,83 @@ def test_launch_slices_say_program_role_and_tokens():
     assert first["ctx_tokens"] == 20 + 1
 
 
+class _ViewSpy:
+    """``llama_decode`` as the engine sees it, noting each view built."""
+
+    def __init__(self, ld):
+        self._ld = ld
+        self.views = []          # (live pages, rows of the view)
+
+    def __getattr__(self, name):
+        return getattr(self._ld, name)
+
+    def live_page_view(self, tables, counts, rows):
+        self.views.append((int(np.sum(counts)), rows))
+        return self._ld.live_page_view(tables, counts, rows)
+
+
+def test_decode_reads_the_smallest_rung_that_holds_its_pages():
+    """Pages of 4 tokens and 4 slots of 32 pages make the ladder (64,
+    128), from the geometry alone. Contexts that grow past 64 pages
+    are read on the lower rung, then on the upper; the decode's
+    ``launch`` slice and its row say which (``view_pages``)."""
+    cfg, eng = _engine(page_tokens=4, capacity=128, pool_pages=128)
+    assert eng._view_ladder == (64, 128)
+    eng._ld = spy = _ViewSpy(eng._ld)
+    reqs = [eng.submit(p, max_new_tokens=10)
+            for p in _prompts(cfg, [3, 39, 88, 110])]
+    _drive(eng, reqs)
+    rows = eng.steplog.dump()["rows"]
+    eng.shutdown()
+    assert spy.views and {n for _, n in spy.views} == {64, 128}
+    for live, n in spy.views:
+        assert n == (64 if live <= 64 else 128), (live, n)
+    widths = []
+    for r in rows:
+        d = [s for s in r["slices"] if s["name"] == "launch"
+             and s.get("program") == "decode"]
+        assert ("view_pages" in r) == bool(d)
+        if d:
+            assert r["view_pages"] == d[0]["view_pages"]
+            # The view holds the context: fill is at most 1.
+            assert r["ctx_tokens"] <= r["view_pages"] * 4
+            widths.append(r["view_pages"])
+    assert widths == [n for _, n in spy.views]
+
+
+def test_a_deployment_meets_no_rung_for_the_first_time_under_traffic():
+    """Built with the program's defaults, a deployment has dispatched
+    ``decode`` at every rung before it takes a request: requests of mixed
+    lengths then climb the ladder, and every ``jit-compile`` event of a
+    ``decode`` key is older than the first of them."""
+    from ray_tpu.serve.decode import LlamaDecodeDeployment
+
+    cfg, _ = _tiny()
+    dep = LlamaDecodeDeployment(config=cfg, slots=4, capacity=128,
+                                kv_page_tokens=4, kv_pool_pages=128)
+    eng = dep.engine
+    try:
+        assert {("decode", 64), ("decode", 128)} <= eng._compiled
+        assert np.asarray(eng.cache["length"]).sum() == 0
+        keys = {k for k in eng._compiled if k[0] == "decode"}
+        t_ready = time.time()
+        reqs = [eng.submit(p, max_new_tokens=10)
+                for p in _prompts(cfg, [3, 39, 88, 110])]
+        for r in reqs:
+            assert r.done.wait(60) and r.status == "completed"
+        rows = eng.timeline()["rows"]
+    finally:
+        eng.shutdown()
+    assert {r["view_pages"] for r in rows if "view_pages" in r} == {64, 128}
+    assert {k for k in eng._compiled if k[0] == "decode"} == keys
+    compiled = [e for r in rows for e in r.get("events", ())
+                if e["kind"] == "jit-compile"]
+    assert any(e["key"] == "decode/128" for e in compiled)
+    assert all(e["ts"] < t_ready for e in compiled
+               if e["key"].startswith("decode"))
+    assert any(e["ts"] > t_ready for e in compiled)     # the prefills
+
+
 def test_pages_pinned_rides_on_rows_with_a_prefix_index():
     cfg, eng = _engine(prefix_pool_entries=8)
     shared = _prompts(cfg, [48])[0]
@@ -327,14 +404,15 @@ def test_engine_programs_are_jitted_under_their_keys_name():
                        decode_chunk=2)
     toks = jnp.zeros((4,), jnp.int32)
     bt = jnp.asarray(eng._block_tables)
+    view = jnp.asarray(eng._live_view(eng._block_tables, eng._slot_pages))
     one = jnp.zeros((1,), jnp.int32)
     lowered = {
-        "decode": eng._decode.lower(eng.params, eng.cache, toks, bt),
-        "decode_k": eng._decode_k.lower(eng.params, eng.cache, toks, bt,
+        "decode": eng._decode.lower(eng.params, eng.cache, toks, view),
+        "decode_k": eng._decode_k.lower(eng.params, eng.cache, toks, view,
                                         k=2),
         "decode_sampled": eng._decode_sampled.lower(
-            eng.params, eng.cache, toks, bt, jnp.zeros((4,), jnp.float32),
-            jnp.asarray(0, jnp.int32)),
+            eng.params, eng.cache, toks, view,
+            jnp.zeros((4,), jnp.float32), jnp.asarray(0, jnp.int32)),
         "paged_prefill": eng._paged_prefill.lower(
             eng.params, eng.cache, jnp.zeros((1, 16), jnp.int32), one,
             bt[:1, :1], one, n=1, bucket=16),
@@ -353,7 +431,7 @@ def test_engine_programs_are_jitted_under_their_keys_name():
     # program given float32 parameters.
     assert "weight_cast" not in text
     masters = _tiny()[1]
-    text = eng._decode.lower(masters, eng.cache, toks, bt).as_text(
+    text = eng._decode.lower(masters, eng.cache, toks, view).as_text(
         debug_info=True)
     assert re.search(r'loc\("[^"]*weight_cast', text)
     for key in ("paged_suffix", "decode_k"):
