@@ -1,0 +1,9 @@
+"""(token, expert) pairs a held expert that was hit computed, over the
+window's decode steps: ``moe_pairs`` / ``moe_experts_hit`` of the step-log
+rows' ``launch`` slices."""
+
+from benchmarks import deepseek_counts
+
+
+def read(ctx):
+    return deepseek_counts.moe_tokens_per_expert_mean(ctx)

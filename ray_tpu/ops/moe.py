@@ -17,6 +17,7 @@ standard Switch behavior. Gates are renormalized over the selected top-k.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -134,3 +135,112 @@ def moe_param_axes() -> Dict[str, Any]:
         "w_up": ("expert", "embed", "mlp"),
         "w_down": ("expert", "mlp", "embed"),
     }
+
+
+# ------------------------------------------------- dropless, held experts
+#
+# The serving form (``models/deepseek_decode.py``): the router is data,
+# the layer is told which experts it holds, routes over all of them and
+# computes its own experts' part of the result. No capacity, so no token
+# is dropped; what the absent experts would add is left out (the chips
+# that hold them add it in a deployment), and no code stands in for them.
+
+
+@dataclasses.dataclass(frozen=True)
+class Router:
+    """How tokens choose experts, by the softmax of their logits.
+    ``experts`` is the router's width (all experts of the layer, held here
+    or not). With ``groups`` > 1 the
+    experts form that many equal groups, each scored by its best expert,
+    and only the ``top_groups`` best groups may be chosen from
+    (DeepSeek-V2's ``group_limited_greedy``)."""
+
+    experts: int
+    top_k: int
+    groups: int = 1
+    top_groups: int = 1
+    renormalise: bool = False     # weights of the chosen sum to 1
+    scale: float = 1.0            # times this (``routed_scaling_factor``)
+
+
+def route(logits: jax.Array, router: Router
+          ) -> Tuple[jax.Array, jax.Array]:
+    """``logits`` (T, experts) float32 -> the chosen experts ``(T, top_k)``
+    int32 and their weights ``(T, top_k)`` float32."""
+    r = router
+    logits = logits.astype(jnp.float32)
+    scores = jax.nn.softmax(logits, axis=-1)
+    choose = scores
+    if r.groups > 1:
+        t = scores.shape[0]
+        best = scores.reshape(t, r.groups, -1).max(-1)        # (T, G)
+        _, kept = jax.lax.top_k(best, r.top_groups)
+        open_ = jnp.zeros((t, r.groups), bool).at[
+            jnp.arange(t)[:, None], kept].set(True)
+        choose = jnp.where(
+            jnp.repeat(open_, r.experts // r.groups, axis=1), scores, 0.0)
+    weights, idx = jax.lax.top_k(choose, r.top_k)
+    if r.renormalise:
+        weights = weights / jnp.maximum(
+            weights.sum(-1, keepdims=True), 1e-20)
+    return idx.astype(jnp.int32), weights * r.scale
+
+
+def held_experts_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
+                     experts: Dict[str, jax.Array],
+                     held: Tuple[int, int],
+                     keep: Optional[jax.Array] = None,
+                     layer: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The part of a routed SwiGLU layer that the held experts give.
+
+    ``x`` (T, D); ``idx``/``weights`` (T, k) from ``route``; ``experts``
+    holds ``w_gate``/``w_up`` (H, D, M) and ``w_down`` (H, M, D) of the
+    ``H = held[1]`` experts ``held[0] .. held[0] + H - 1``; ``keep`` (T,)
+    bool leaves a token's pairs out (padding, a slot that does not step).
+    Returns ``(y (T, D), group_sizes (H,) int32)``: the sum over a token's
+    HELD experts of weight x expert(x), and how many pairs each held
+    expert computed.
+
+    The (token, expert) pairs are sorted by expert, the pairs of absent
+    experts last, and the three matmuls are ragged over the groups
+    (``jax.lax.ragged_dot``): every pair is computed whatever the load
+    looks like. The rows past the last group (the absent experts' pairs)
+    belong to no group, and what the chip's kernel leaves there is not
+    defined: they are SELECTED away below, never multiplied by a zero
+    weight (on a TPU v5e one run in eleven met a NaN there, PR 36).
+
+    The experts' leaves may be stacked over layers, ``(L, H, ...)``, with
+    ``layer`` (a traced index) naming the one to use: the matmuls then run
+    ragged over all ``L * H`` groups, the other layers' groups empty. A
+    layer loop can so hand the stack in whole; a layer sliced out of it to
+    feed the matmul's kernel is a copy (1.9 GB a layer at DeepSeek-V2's
+    served size)."""
+    t, k = idx.shape
+    first, count = held
+    local = idx - first
+    mine = (local >= 0) & (local < count)
+    if keep is not None:
+        mine &= keep[:, None]
+    group = jnp.where(mine, local, count).reshape(-1)          # (T * k,)
+    order = jnp.argsort(group, stable=True)
+    token = order // k
+    sizes = jnp.bincount(group, length=count + 1)[:count].astype(jnp.int32)
+    xs = x[token]                                              # (T * k, D)
+    groups = sizes
+    if layer is not None:
+        n_layers = experts["w_gate"].shape[0]
+        experts = {name: w.reshape((n_layers * count,) + w.shape[2:])
+                   for name, w in experts.items()}
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * count,), jnp.int32), sizes,
+            (layer * count,))
+    gate = jax.lax.ragged_dot(xs, experts["w_gate"], groups)
+    up = jax.lax.ragged_dot(xs, experts["w_up"], groups)
+    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, experts["w_down"],
+                             groups)
+    # Back in the tokens' order by a gather (the inverse permutation),
+    # then a token's k pairs are added up in float32 under its weights.
+    back = out[jnp.argsort(order)].reshape(t, k, -1).astype(jnp.float32)
+    y = jnp.where(mine[..., None], back * weights[..., None], 0.0).sum(1)
+    return y.astype(x.dtype), sizes

@@ -120,8 +120,26 @@ class _Request:
             raise err
 
 
+def _pool_of(cache: Dict[str, Any]) -> Dict[str, Any]:
+    """The model's pool out of the engine's cache: every leaf but the
+    slots' cursors."""
+    return {name: leaf for name, leaf in cache.items()
+            if name != "length"}
+
+
 class DecodeEngine:
-    """Continuous batcher over ``llama_decode`` programs.
+    """Continuous batcher over one model's paged programs.
+
+    ``model`` is the module (or object) the programs come from;
+    ``ray_tpu.models.llama_decode`` unless one is given. It provides
+    ``compute_weights``, ``init_page_pool``, ``paged_prefill``,
+    ``paged_prefill_suffix``, ``paged_decode_step``, ``live_page_view``,
+    ``cache_bucket`` and ``sample_batch``, and may provide
+    ``paged_decode_chunk`` (``decode_chunk > 1``), ``paged_verify`` and
+    ``paged_spec_draft`` (``spec_k > 0``) and ``shard_decode_state`` (a
+    mesh): an option whose program the model lacks is refused at
+    construction. The pool is the model's pytree, carried whole
+    (docs/SERVING.md, "The model seam").
 
     ``slots`` concurrent sequences of up to ``capacity`` tokens share
     one paged KV pool. ``step()`` advances every active slot one token;
@@ -147,15 +165,36 @@ class DecodeEngine:
                  spec_draft_params=None, spec_draft_config=None,
                  spec_k: Optional[int] = None,
                  spec_draft_pool_pages: Optional[int] = None,
-                 device_sampler: Optional[bool] = None):
+                 device_sampler: Optional[bool] = None,
+                 model=None):
         import jax
 
         from ray_tpu.core.config import config as rt_config
-        from ray_tpu.models import llama_decode as ld
         from ray_tpu.serve.paging import PageAllocator, PagedPrefixIndex
 
+        if model is None:
+            from ray_tpu.models import llama_decode as model
         self._jax = jax
-        self._ld = ld
+        self._ld = ld = model
+        # A model may lack the optional programs; an option that needs
+        # one is refused here, before anything is built for it.
+        sk_asked = rt_config.spec_k if spec_k is None else spec_k
+        for asked, needs in (
+                (int(decode_chunk) > 1, ("paged_decode_chunk",)),
+                (int(sk_asked) > 0 and spec_draft_params is not None,
+                 ("paged_verify", "paged_spec_draft")),
+                (mesh is not None or mesh_shape is not None
+                 or bool(rt_config.decode_mesh_shape),
+                 ("shard_decode_state",))):
+            lacks = [n for n in needs if not hasattr(ld, n)]
+            if asked and lacks:
+                raise ValueError(
+                    f"model {ld.__name__} has no {' / '.join(lacks)}: "
+                    f"this engine cannot run the option that needs it")
+        # Counters a model's decode step returns beside its logits
+        # (a float32 vector, one entry a name); they reach the host as
+        # one more row of the logits, in the same transfer.
+        self._step_stats = tuple(getattr(ld, "STEP_STATS", ()))
         # The one tree every program reads, held in the compute dtype:
         # rounded once here, not in every decode step and prefill chunk.
         # The caller's arrays are left alone (a test's reference reads
@@ -228,9 +267,14 @@ class DecodeEngine:
               else pool_pages)
         self.pool_pages = int(pp) or slots * self.slot_pages_max
         self._pages = PageAllocator(self.pool_pages)
+        # The pool is the model's own pytree (leaves ``[layers,
+        # pages + 1, page_tokens, ...]``: K and V per head for llama,
+        # one latent row a token for deepseek); the engine carries it
+        # whole beside the slots' cursors and never looks inside.
         pool = ld.init_page_pool(config, self.pool_pages,
                                  self.page_tokens)
-        self.cache = {"k": pool["k"], "v": pool["v"],
+        self._pool_names = tuple(pool)
+        self.cache = {**pool,
                       "length": jax.numpy.zeros((slots,),
                                                 jax.numpy.int32)}
         self._block_tables = np.zeros(
@@ -337,7 +381,7 @@ class DecodeEngine:
                                       self.draft_pool_pages,
                                       self.page_tokens)
             self._draft_cache = {
-                "k": dpool["k"], "v": dpool["v"],
+                **dpool,
                 "length": jax.numpy.zeros((slots,), jax.numpy.int32)}
             self._draft_bt = np.zeros((slots, self.slot_pages_max),
                                       np.int32)
@@ -550,14 +594,12 @@ class DecodeEngine:
         ONE device call, K/V scattered into the pool pages ``bt`` maps
         (one program per (n, bucket) power-of-two pair)."""
         ld = self._ld
-        pool = {"k": cache["k"], "v": cache["v"]}
+        pool = _pool_of(cache)
         logits, pool = ld.paged_prefill(params, tokens_rows[:, :bucket],
                                         pool, bt, self.config,
                                         lengths=lengths)
         return logits, {
-            "k": pool["k"], "v": pool["v"],
-            "length": cache["length"].at[slot_ids].set(lengths),
-        }
+            **pool, "length": cache["length"].at[slot_ids].set(lengths)}
 
     def _paged_suffix_impl(self, params, cache, tokens_rows, prefix_lens,
                            lengths, bt, slot_ids, n, bucket, width):
@@ -567,22 +609,27 @@ class DecodeEngine:
         ``bt`` is pre-sliced to ``width`` leading page columns so
         gather/attention cost scales with prefix + suffix."""
         ld = self._ld
-        pool = {"k": cache["k"], "v": cache["v"]}
+        pool = _pool_of(cache)
         logits, pool = ld.paged_prefill_suffix(
             params, tokens_rows[:, :bucket], pool, bt, self.config,
             prefix_lens, lengths)
         return logits, {
-            "k": pool["k"], "v": pool["v"],
-            "length": cache["length"].at[slot_ids].set(lengths),
-        }
+            **pool, "length": cache["length"].at[slot_ids].set(lengths)}
 
     def _paged_decode_impl(self, params, cache, tokens, view):
-        pool = {"k": cache["k"], "v": cache["v"]}
-        logits, pool, lens = self._ld.paged_decode_step(
+        pool = _pool_of(cache)
+        logits, pool, lens, *stats = self._ld.paged_decode_step(
             params, pool, view, cache["length"], tokens, self.config)
-        return logits, {"k": pool["k"], "v": pool["v"], "length": lens}
+        if self._step_stats:
+            import jax.numpy as jnp
 
-    def _adopt_pages_impl(self, cache, k_pages, v_pages, ids, slot_ids,
+            row = jnp.zeros((1, logits.shape[1]), logits.dtype)
+            logits = jnp.concatenate(
+                [logits, row.at[0, :len(self._step_stats)].set(
+                    stats[0].astype(logits.dtype))])
+        return logits, {**pool, "length": lens}
+
+    def _adopt_pages_impl(self, cache, payload, ids, slot_ids,
                           lengths, width):
         """Adopt a handed-off prefill: scatter ``width`` page payloads
         into the pool at ``ids`` and park the slot cursor at the
@@ -591,16 +638,16 @@ class DecodeEngine:
         Pad columns target the scratch page (id 0, never read) with
         zero payloads; ``width`` is the pow-2 compile bucket."""
         return {
-            "k": cache["k"].at[:, ids].set(k_pages),
-            "v": cache["v"].at[:, ids].set(v_pages),
+            **{name: cache[name].at[:, ids].set(pages)
+               for name, pages in payload.items()},
             "length": cache["length"].at[slot_ids].set(lengths),
         }
 
     def _paged_decode_chunk_impl(self, params, cache, tokens, view, k):
-        pool = {"k": cache["k"], "v": cache["v"]}
+        pool = _pool_of(cache)
         toks, pool, lens = self._ld.paged_decode_chunk(
             params, pool, view, cache["length"], tokens, self.config, k)
-        return toks, {"k": pool["k"], "v": pool["v"], "length": lens}
+        return toks, {**pool, "length": lens}
 
     # ------------------------------------------- speculative jitted bodies
 
@@ -613,12 +660,11 @@ class DecodeEngine:
         cursor and rolls it forward only over the accepted run."""
         import jax.numpy as jnp
 
-        pool = {"k": cache["k"], "v": cache["v"]}
+        pool = _pool_of(cache)
         logits, pool = self._ld.paged_verify(
             params, rows, pool, bt, self.config, cache["length"])
         toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return toks, {"k": pool["k"], "v": pool["v"],
-                      "length": cache["length"]}
+        return toks, {**pool, "length": cache["length"]}
 
     def _spec_draft_impl(self, params, cache, catchup, catchup_lens,
                          bt, view, k):
@@ -626,12 +672,11 @@ class DecodeEngine:
         ``pos = length`` and greedily roll ``k`` proposals against the
         draft pool. ``length`` is host-owned (rolled back with the
         target's cursor after acceptance) — returned unchanged."""
-        pool = {"k": cache["k"], "v": cache["v"]}
+        pool = _pool_of(cache)
         toks, pool = self._ld.paged_spec_draft(
             params, pool, bt, view, cache["length"], catchup,
             catchup_lens, self._draft_config, k)
-        return toks, {"k": pool["k"], "v": pool["v"],
-                      "length": cache["length"]}
+        return toks, {**pool, "length": cache["length"]}
 
     def _draft_prefill_impl(self, params, cache, tokens_rows, lengths,
                             bt, slot_ids, n, bucket):
@@ -640,23 +685,23 @@ class DecodeEngine:
         it — the draft pool has no prefix index), which is fine because
         the draft is the model chosen to be cheap."""
         ld = self._ld
-        pool = {"k": cache["k"], "v": cache["v"]}
+        pool = _pool_of(cache)
         _, pool = ld.paged_prefill(params, tokens_rows[:, :bucket],
                                    pool, bt, self._draft_config,
                                    lengths=lengths)
-        return {"k": pool["k"], "v": pool["v"],
+        return {**pool,
                 "length": cache["length"].at[slot_ids].set(lengths)}
 
     def _paged_decode_sampled_impl(self, params, cache, tokens, view,
                                    temps, step):
         import jax
 
-        pool = {"k": cache["k"], "v": cache["v"]}
+        pool = _pool_of(cache)
         logits, pool, lens = self._ld.paged_decode_step(
-            params, pool, view, cache["length"], tokens, self.config)
+            params, pool, view, cache["length"], tokens, self.config)[:3]
         key = jax.random.fold_in(jax.random.key(0), step)
         toks = self._ld.sample_batch(logits, temps, key)
-        return toks, {"k": pool["k"], "v": pool["v"], "length": lens}
+        return toks, {**pool, "length": lens}
 
     def _dispatch_fresh(self, key: tuple, call,
                         then: Optional[str] = None, **attrs):
@@ -735,7 +780,9 @@ class DecodeEngine:
         counts = np.zeros((self.slots,), np.int32)
         for slot in self._active:
             counts[slot] = len(slot_pages[slot])
-        live = int(counts.sum())
+        # A model may lay its rows out in groups (``view_rows``).
+        rows_of = getattr(self._ld, "view_rows", None)
+        live = int(counts.sum()) if rows_of is None else rows_of(counts)
         rung = next(n for n in self._view_ladder if n >= live)
         return self._ld.live_page_view(tables, counts, rung)
 
@@ -1020,14 +1067,15 @@ class DecodeEngine:
             raise HandoffAdoptError(
                 f"handoff committed_len ({adopt['committed_len']}) != "
                 f"prompt length ({len(req.tokens)})")
-        k = adopt["k"]
-        pool = self.cache["k"].shape  # (L, pages+1, T, KV, D)
-        if (k.ndim != 5 or k.shape[0] != pool[0]
-                or tuple(k.shape[2:]) != tuple(pool[2:])
-                or k.shape[1] != self._seq_pages(len(req.tokens))):
-            raise HandoffAdoptError(
-                f"handoff payload shape {tuple(k.shape)} does not fit "
-                f"this engine's pool {tuple(pool)}")
+        for name in self._pool_names:
+            got = adopt[name]
+            pool = self.cache[name].shape  # (L, pages+1, T, ...)
+            if (got.ndim != len(pool) or got.shape[0] != pool[0]
+                    or tuple(got.shape[2:]) != tuple(pool[2:])
+                    or got.shape[1] != self._seq_pages(len(req.tokens))):
+                raise HandoffAdoptError(
+                    f"handoff payload shape {tuple(got.shape)} does not "
+                    f"fit this engine's pool {tuple(pool)}")
 
     def retry_after_estimate_s(self) -> float:
         """How long a shed caller should wait before retrying, from the
@@ -1313,18 +1361,18 @@ class DecodeEngine:
             width *= 2
         ids = np.zeros((width,), np.int32)
         ids[:len(pages)] = pages
-        L = self.cache["k"].shape[0]
-        tail = self.cache["k"].shape[2:]
-        k_pad = np.zeros((L, width) + tuple(tail), adopt["k"].dtype)
-        v_pad = np.zeros((L, width) + tuple(tail), adopt["v"].dtype)
-        k_pad[:, :len(pages)] = adopt["k"]
-        v_pad[:, :len(pages)] = adopt["v"]
+        padded = {}
+        for name in self._pool_names:
+            shape = self.cache[name].shape
+            pad = np.zeros((shape[0], width) + tuple(shape[2:]),
+                           adopt[name].dtype)
+            pad[:, :len(pages)] = adopt[name]
+            padded[name] = jnp.asarray(pad)
         t0 = time.time()
         self.cache = self._dispatch_fresh(
             ("adopt_pages", width),
             lambda: self._adopt_pages(
-                self.cache, jnp.asarray(k_pad), jnp.asarray(v_pad),
-                jnp.asarray(ids), jnp.asarray([slot], np.int32),
+                self.cache, padded, jnp.asarray(ids), jnp.asarray([slot], np.int32),
                 jnp.asarray([clen], np.int32), width=width),
             then="admit")
         if self.steplog.enabled:
@@ -1636,14 +1684,14 @@ class DecodeEngine:
         # np.array (never asarray): the payload outlives later donated
         # dispatches, so it must OWN its bytes — a host view of the
         # cache would be clobbered in place (the PR 16 pin).
-        k = np.array(self.cache["k"][:, ids])
-        v = np.array(self.cache["v"][:, ids])
+        pages = {name: np.array(self.cache[name][:, ids])
+                 for name in self._pool_names}
         req.handoff = {
-            "k": k, "v": v,
+            **pages,
             "committed_len": int(req.prompt_len),
             "first_token": int(first_token),
             "page_tokens": self.page_tokens,
-            "nbytes": int(k.nbytes + v.nbytes),
+            "nbytes": int(sum(a.nbytes for a in pages.values())),
         }
         self.handoffs_published += 1
         if self.steplog.enabled:
@@ -1995,8 +2043,18 @@ class DecodeEngine:
         if rec:
             sl.begin("fetch", program="decode")
         logits = np.array(logits)
+        counted = {}
+        if self._step_stats:
+            # The model's counters came as one more row of the logits.
+            counted = {name: int(v) for name, v in zip(
+                self._step_stats, logits[self.slots])}
+            logits = logits[:self.slots]
         if rec:
-            sl.begin("sample_emit")
+            # They belong to the launch (its row says so); its
+            # annotation closed before they existed, so in a profiler
+            # trace they ride on the slice that follows the fetch.
+            sl.amend("launch", **counted)
+            sl.begin("sample_emit", **counted)
         if rec:
             phases.append({"phase": "decode", "t0": t_d0,
                            "t1": time.time(), "batch": stepped, "k": 1})
@@ -2546,7 +2604,20 @@ class LlamaDecodeDeployment:
     """Serve deployment wrapping a DecodeEngine: POST {"tokens": [...],
     "max_new_tokens": N} -> {"tokens": [...]} with streaming support
     (generator handle path). Replica-per-chip: schedule with
-    ``ray_actor_options={"resources": {"TPU": 1}}``."""
+    ``ray_actor_options={"resources": {"TPU": 1}}``.
+
+    The class serves whatever ``model_modules`` names: the llama family
+    here, another family in a subclass that overrides it and nothing
+    else (``DeepseekDecodeDeployment``)."""
+
+    @staticmethod
+    def model_modules():
+        """``(model, decode)``: the module that has ``PRESETS`` and
+        ``init_params(config, key)``, and the one the engine takes its
+        programs from (``DecodeEngine``'s ``model``)."""
+        from ray_tpu.models import llama, llama_decode
+
+        return llama, llama_decode
 
     def __init__(self, preset: str = "debug", slots: int = 4,
                  capacity: int = 1024, seed: int = 0,
@@ -2566,10 +2637,9 @@ class LlamaDecodeDeployment:
         import jax
 
         from ray_tpu.core.config import config as rt_config
-        from ray_tpu.models import llama
-        from ray_tpu.models import llama_decode as ld
         from ray_tpu.util.compile_cache import compile_watch
 
+        llama, ld = self.model_modules()
         compile_watch()  # count the replica's compiles from its first one
         cfg = config or llama.PRESETS[preset]
         self.cfg = cfg
@@ -2609,7 +2679,7 @@ class LlamaDecodeDeployment:
             spec_draft_params=draft_params, spec_draft_config=draft_cfg,
             spec_k=sk if draft_params is not None else 0,
             spec_draft_pool_pages=spec_draft_pool_pages,
-            device_sampler=device_sampler)
+            device_sampler=device_sampler, model=ld)
         # The decode ladder is warmed whatever the knob says: which rung
         # a step takes follows the traffic, and a rung met first under
         # load is seconds of compile in one request's latency. The knob
@@ -2768,8 +2838,8 @@ class LlamaDecodeDeployment:
                 f"handoff payload")
         desc = {
             "handoff_id": _uuid.uuid4().hex[:16],
-            "k_ref": ray_tpu.put(payload["k"]),
-            "v_ref": ray_tpu.put(payload["v"]),
+            **{f"{name}_ref": ray_tpu.put(payload[name])
+               for name in self.engine._pool_names},
             "committed_len": payload["committed_len"],
             "first_token": payload["first_token"],
             "page_tokens": payload["page_tokens"],
@@ -2818,7 +2888,8 @@ class LlamaDecodeDeployment:
 
         desc = entry["desc"]
         try:
-            ray_tpu.free([desc.get("k_ref"), desc.get("v_ref")])
+            ray_tpu.free([desc.get(f"{name}_ref")
+                          for name in self.engine._pool_names])
         except Exception:
             logger.warning("freeing handoff %s refs failed",
                            desc.get("handoff_id"), exc_info=True)
@@ -2854,13 +2925,14 @@ class LlamaDecodeDeployment:
 
         timeout = request_deadline_s() or 30.0
         try:
-            k, v = ray_tpu.get([desc["k_ref"], desc["v_ref"]],
-                               timeout=max(1.0, timeout))
+            names = self.engine._pool_names
+            pages = ray_tpu.get([desc[f"{name}_ref"] for name in names],
+                                timeout=max(1.0, timeout))
         except Exception as e:
             raise HandoffAdoptError(
                 f"handoff {desc.get('handoff_id')} page payload "
                 f"unavailable: {e!r}") from e
-        return {"k": k, "v": v,
+        return {**dict(zip(names, pages)),
                 "committed_len": desc["committed_len"],
                 "first_token": desc["first_token"],
                 "page_tokens": desc["page_tokens"]}
@@ -2923,3 +2995,16 @@ class LlamaDecodeDeployment:
 
     def health(self) -> Dict[str, Any]:
         return self.engine.stats()
+
+
+class DeepseekDecodeDeployment(LlamaDecodeDeployment):
+    """The same deployment over DeepSeek-V2 (``models/deepseek.py``): a
+    latent paged pool, absorbed decode, held experts. The model has none
+    of the engine's optional programs, so ``decode_chunk > 1``, ``spec_k
+    > 0`` and a mesh are refused by the engine."""
+
+    @staticmethod
+    def model_modules():
+        from ray_tpu.models import deepseek, deepseek_decode
+
+        return deepseek, deepseek_decode

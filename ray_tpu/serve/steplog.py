@@ -104,6 +104,18 @@ class StepTimeline:
         self._switch(name, now, attrs)
         return now
 
+    def amend(self, name: str, **attrs: Any) -> None:
+        """Add ``attrs`` to the newest slice ``name`` of the row being
+        built: what a launch counted is known only once its output is
+        fetched. The slice's annotation has closed by then, so the
+        profiler's trace does not get them from here."""
+        if not attrs:
+            return
+        for s in reversed(self._slices):
+            if s["name"] == name:
+                s.update(attrs)
+                return
+
     def _switch(self, name: str, now: float, attrs: Dict[str, Any]
                 ) -> None:
         if self._ann is not None:
