@@ -263,15 +263,6 @@ _FLAG_DEFS: Dict[str, tuple] = {
         "fraction of the bytes. Size it >= kv_pool_pages or draft-pool "
         "pressure preempts requests the target pool could still seat. "
         "0 = match kv_pool_pages."),
-    "decode_device_sampler": (bool, False,
-        "Fold sampling into the decode program (device-side argmax / "
-        "per-row categorical under out_shardings) so each step returns "
-        "token ids instead of round-tripping (slots, vocab) logits to "
-        "the host sampler. Greedy rows are bit-identical to the host "
-        "sampler; temperature > 0 rows draw from the device RNG stream "
-        "(a DIFFERENT stream than the host sampler's numpy generator), "
-        "which is why this is opt-in. Requests needing host-side logit "
-        "processing keep the host path regardless."),
     "decode_warmup": (bool, False,
         "Pre-dispatch a DecodeEngine's OPTIONAL steady-state programs "
         "(decode-chunk grid, spec draft/verify, one admission bucket) "

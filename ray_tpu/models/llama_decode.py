@@ -805,17 +805,21 @@ def _sample(logits: jax.Array, temperature: float, key) -> jax.Array:
 
 def sample_batch(logits: jax.Array, temperatures: jax.Array,
                  key) -> jax.Array:
-    """Per-row sampling fused into decode programs: greedy argmax where
-    ``temperatures[b] <= 0`` else categorical at that row's temperature.
-    The greedy lane is bit-identical to host ``np.argmax`` (both take
-    the first maximum); the sampled lane draws from the device RNG
-    stream, which intentionally differs from the host sampler's numpy
-    stream — callers opt in via the ``decode_device_sampler`` knob."""
+    """The sample every engine program ends in: per row the greedy
+    argmax where ``temperatures[b] <= 0`` (the first maximum, as
+    ``np.argmax`` takes it), else a draw from ``softmax(logits / T)`` at
+    that row's temperature on the stream ``key`` starts. A batch with no
+    temperature, which is most of them, draws nothing: the noise over
+    the whole vocabulary is made only in the branch that uses it."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    temps = jnp.maximum(temperatures, 1e-6)[:, None]
-    sampled = jax.random.categorical(
-        key, logits / temps, axis=-1).astype(jnp.int32)
-    return jnp.where(temperatures <= 0.0, greedy, sampled)
+
+    def draw():
+        temps = jnp.maximum(temperatures, 1e-6)[:, None]
+        sampled = jax.random.categorical(
+            key, logits / temps, axis=-1).astype(jnp.int32)
+        return jnp.where(temperatures <= 0.0, greedy, sampled)
+
+    return jax.lax.cond(jnp.any(temperatures > 0.0), draw, lambda: greedy)
 
 
 @partial(jax.jit, static_argnames=("config", "max_new_tokens",

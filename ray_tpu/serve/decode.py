@@ -165,7 +165,6 @@ class DecodeEngine:
                  spec_draft_params=None, spec_draft_config=None,
                  spec_k: Optional[int] = None,
                  spec_draft_pool_pages: Optional[int] = None,
-                 device_sampler: Optional[bool] = None,
                  model=None):
         import jax
 
@@ -192,8 +191,8 @@ class DecodeEngine:
                     f"model {ld.__name__} has no {' / '.join(lacks)}: "
                     f"this engine cannot run the option that needs it")
         # Counters a model's decode step returns beside its logits
-        # (a float32 vector, one entry a name); they reach the host as
-        # one more row of the logits, in the same transfer.
+        # (a float32 vector of whole numbers, one entry a name); they
+        # reach the host behind the token ids, in the same transfer.
         self._step_stats = tuple(getattr(ld, "STEP_STATS", ()))
         # The one tree every program reads, held in the compute dtype:
         # rounded once here, not in every decode step and prefill chunk.
@@ -311,8 +310,11 @@ class DecodeEngine:
         self._prefilling: Dict[int, _Request] = {}  # chunked, mid-prefill
         self._requeue: List[_Request] = []  # preempted/pushed-back, FIFO
         self._pending: "queue.Queue[_Request]" = queue.Queue()
+        # What the one-token decode samples from, a slot each: the token
+        # it reads and the temperature it draws at (0 = greedy). The
+        # host's copies; the device's are ``_state_dev``, ``_temps_dev``.
         self._tokens = np.zeros((slots,), np.int32)
-        self._rng = np.random.default_rng(0)
+        self._temps = np.zeros((slots,), np.float32)
         self._stop = threading.Event()
         self._loop_thread: Optional[threading.Thread] = None  # serve_forever
         self._work = threading.Event()
@@ -407,19 +409,17 @@ class DecodeEngine:
             self.spec_accepted = 0
         else:
             self.spec = False
-        # Device-side sampling: the decode program returns token ids
-        # (argmax / per-row categorical fused under out_shardings)
-        # instead of (slots, vocab) logits — the host stops paying a
-        # full-vocab transfer per step. Opt-in: greedy rows are
-        # bit-identical either way, sampled rows move to the device RNG
-        # stream.
-        self._device_sampler = bool(
-            rt_config.decode_device_sampler if device_sampler is None
-            else device_sampler)
-        self._tokens_dev = None  # device-resident next-token vector:
-        #   valid between consecutive device-sampled steps (the program's
-        #   output feeds the next call without a host->device upload);
-        #   ANY host-side token write invalidates it.
+        # A program that ends in a sample returns the sample: token ids
+        # cross to the host, never (slots, vocab) logits. The decode's
+        # whole result is one int32 vector, ``[ids | the model's
+        # counters | the step counter]``, and it is the next decode's
+        # input as it lies on the device: a step that follows a step
+        # uploads its view and nothing else. ANY host write to
+        # ``_tokens`` drops ``_state_dev`` (the next step uploads the
+        # host's tokens and ``steps``); the temperatures go up again
+        # only when a slot's changed.
+        self._state_dev = None
+        self._temps_dev = None
         # Suffix prefills bucket on a finer grid than full prefills: the
         # whole point is that the suffix is short, so padding it back up
         # to prefill_bucket would refund most of the win.
@@ -432,7 +432,7 @@ class DecodeEngine:
         # (``llama_decode._scan_layers``), so no program holds or copies
         # a second pool. Its shape is the same outside every program.
         # Mesh engines pin program outputs to the committed shardings
-        # (logits/token outputs replicated for the host sampler, KV
+        # (token outputs replicated for the host to read, KV
         # state staying exactly where device_put placed it, so
         # donation reuses the sharded buffers); single-chip engines
         # pass no shardings at all — their jaxprs are byte-identical
@@ -508,13 +508,6 @@ class DecodeEngine:
                 "draft_prefill", self._draft_prefill_impl,
                 static_argnames=("n", "bucket"), donate_argnums=(1,),
                 **draft_cache_only), rules=self._draft_rules)
-        # Fused device sampler: one program returning sampled token ids;
-        # per-row temperatures pick argmax vs categorical, the PRNG key
-        # derives from the step counter in-program.
-        if self._device_sampler:
-            self._decode_sampled = self._mesh_scoped(self._program(
-                "decode_sampled", self._paged_decode_sampled_impl,
-                donate_argnums=(1,), **cache_out))
         self.steps = 0
         self.tokens_out = 0
         # ---------------------------------------------- observability
@@ -588,46 +581,71 @@ class DecodeEngine:
 
     # ------------------------------------------------------ jitted bodies
 
+    def _sample(self, logits, temps, stream: int, counter):
+        """The ids a program returns in place of its ``logits``
+        (``sample_batch``: argmax, or a draw where a row has a
+        temperature). The engine's randomness is two counter-based
+        streams, one a kind of program: ``stream`` 0 the decode steps
+        (``counter`` = ``steps``), 1 the prefills (``counter`` = the
+        prefill programs dispatched so far). The keys are XLA's own
+        bit generator's (``unsafe_rbg``): one operation in each of the
+        engine's ~35 programs where threefry is some hundred traced
+        and compiled in each, which a replica's start pays for."""
+        import jax
+
+        key = jax.random.fold_in(
+            jax.random.key(stream, impl="unsafe_rbg"), counter)
+        return self._ld.sample_batch(logits, temps, key)
+
     def _paged_prefill_impl(self, params, cache, tokens_rows, lengths,
-                            bt, slot_ids, n, bucket):
+                            bt, slot_ids, temps, wave, n, bucket):
         """Batched paged admission: causal prefill of ``n`` prompts in
         ONE device call, K/V scattered into the pool pages ``bt`` maps
-        (one program per (n, bucket) power-of-two pair)."""
+        (one program per (n, bucket) power-of-two pair). Returns each
+        prompt's first token, sampled at ``temps``."""
         ld = self._ld
         pool = _pool_of(cache)
         logits, pool = ld.paged_prefill(params, tokens_rows[:, :bucket],
                                         pool, bt, self.config,
                                         lengths=lengths)
-        return logits, {
+        return self._sample(logits, temps, 1, wave), {
             **pool, "length": cache["length"].at[slot_ids].set(lengths)}
 
     def _paged_suffix_impl(self, params, cache, tokens_rows, prefix_lens,
-                           lengths, bt, slot_ids, n, bucket, width):
+                           lengths, bt, slot_ids, temps, wave, n, bucket,
+                           width):
         """Suffix prefill over paged context: the prefix-hit splice
         (shared pages arrive through ``bt`` — the block table IS the
         splice, no copies) and the chunked-prefill continuation step.
         ``bt`` is pre-sliced to ``width`` leading page columns so
-        gather/attention cost scales with prefix + suffix."""
+        gather/attention cost scales with prefix + suffix. Returns the
+        token after each row's last, sampled at ``temps``; nobody
+        fetches it from a chunk that is not its prompt's last."""
         ld = self._ld
         pool = _pool_of(cache)
         logits, pool = ld.paged_prefill_suffix(
             params, tokens_rows[:, :bucket], pool, bt, self.config,
             prefix_lens, lengths)
-        return logits, {
+        return self._sample(logits, temps, 1, wave), {
             **pool, "length": cache["length"].at[slot_ids].set(lengths)}
 
-    def _paged_decode_impl(self, params, cache, tokens, view):
+    def _paged_decode_impl(self, params, cache, state, view, temps):
+        """THE decode step. ``state`` is the int32 vector ``[a token a
+        slot | the model's ``STEP_STATS`` | the step counter]``; the
+        result is the same vector one step on: the ids sampled at
+        ``temps`` on the counter's key, this step's counters, the
+        counter plus one. All the host fetches of a step, and the next
+        step's input where it lies."""
+        import jax.numpy as jnp
+
         pool = _pool_of(cache)
         logits, pool, lens, *stats = self._ld.paged_decode_step(
-            params, pool, view, cache["length"], tokens, self.config)
-        if self._step_stats:
-            import jax.numpy as jnp
-
-            row = jnp.zeros((1, logits.shape[1]), logits.dtype)
-            logits = jnp.concatenate(
-                [logits, row.at[0, :len(self._step_stats)].set(
-                    stats[0].astype(logits.dtype))])
-        return logits, {**pool, "length": lens}
+            params, pool, view, cache["length"], state[:self.slots],
+            self.config)
+        toks = self._sample(logits, temps, 0, state[-1])
+        return jnp.concatenate(
+            [toks, *(s.astype(jnp.int32) for s in stats),
+             state[-1:] + 1]), {**pool, "length": lens}
 
     def _adopt_pages_impl(self, cache, payload, ids, slot_ids,
                           lengths, width):
@@ -691,17 +709,6 @@ class DecodeEngine:
                                    lengths=lengths)
         return {**pool,
                 "length": cache["length"].at[slot_ids].set(lengths)}
-
-    def _paged_decode_sampled_impl(self, params, cache, tokens, view,
-                                   temps, step):
-        import jax
-
-        pool = _pool_of(cache)
-        logits, pool, lens = self._ld.paged_decode_step(
-            params, pool, view, cache["length"], tokens, self.config)[:3]
-        key = jax.random.fold_in(jax.random.key(0), step)
-        toks = self._ld.sample_batch(logits, temps, key)
-        return toks, {**pool, "length": lens}
 
     def _dispatch_fresh(self, key: tuple, call,
                         then: Optional[str] = None, **attrs):
@@ -1392,12 +1399,11 @@ class DecodeEngine:
             self.prefix.insert(req.tokens, self._slot_pages[slot],
                                matched_len=0)
         now = time.monotonic()
-        self._tokens_dev = None
         tok = int(req.adopt["first_token"])
         if req.first_token_at is None:
             req.first_token_at = now
         self._emit(req, tok)
-        self._tokens[slot] = tok
+        self._seat(slot, tok, req.temperature)
         self._active[slot] = req
         self.handoffs_adopted += 1
         req.adopt = None  # drop the multi-MB payload promptly
@@ -1438,19 +1444,18 @@ class DecodeEngine:
                 bt[i] = bt[len(group) - 1]
             self._prefill_waves += 1
             t0 = time.time()
-            logits, self.cache = self._dispatch_fresh(
+            ids, self.cache = self._dispatch_fresh(
                 ("paged_prefill", n, bucket),
                 lambda: self._paged_prefill(
                     self.params, self.cache, jnp.asarray(rows),
                     jnp.asarray(lengths), jnp.asarray(bt),
-                    jnp.asarray(slot_ids), n=n, bucket=bucket),
+                    jnp.asarray(slot_ids), *self._draw_args(group, n),
+                    n=n, bucket=bucket),
                 tokens=sum(len(r.tokens) for r in group))
-            self._slice("fetch", program="paged_prefill")
-            logits = np.array(logits)
-            self._slice("admit")
+            ids = self._fetch_ids(ids, "paged_prefill")
             self._wave_span("prefill", t0, group, n=len(group),
                             bucket=bucket)
-            self._post_admit(group, [r.slot for r in group], logits)
+            self._post_admit(group, [r.slot for r in group], ids)
 
     def _admit_paged_suffix(self, reqs: List[_Request]) -> None:
         """Prefix-hit paged admissions: the shared pages are already in
@@ -1496,20 +1501,19 @@ class DecodeEngine:
                 bt[i] = bt[len(group) - 1]
             self._prefill_waves += 1
             t0 = time.time()
-            logits, self.cache = self._dispatch_fresh(
+            ids, self.cache = self._dispatch_fresh(
                 ("paged_suffix", n, bucket, width),
                 lambda: self._paged_suffix(
                     self.params, self.cache, jnp.asarray(rows),
                     jnp.asarray(plens), jnp.asarray(lengths),
                     jnp.asarray(bt), jnp.asarray(slot_ids),
+                    *self._draw_args(group, n),
                     n=n, bucket=bucket, width=width),
                 tokens=sum(len(r.tokens) - r.prefix_len for r in group))
-            self._slice("fetch", program="paged_suffix")
-            logits = np.array(logits)
-            self._slice("admit")
+            ids = self._fetch_ids(ids, "paged_suffix")
             self._wave_span("suffix-prefill", t0, group, n=len(group),
                             bucket=bucket)
-            self._post_admit(group, [r.slot for r in group], logits)
+            self._post_admit(group, [r.slot for r in group], ids)
 
     def _prefill_tick(self) -> None:
         """Chunked-prefill interleaving: advance the OLDEST mid-prefill
@@ -1546,27 +1550,26 @@ class DecodeEngine:
         rows[0, :step_tok] = req.tokens[req.prefilled:
                                         req.prefilled + step_tok]
         bt = self._block_tables[slot:slot + 1, :width]
+        self.prefill_chunks += 1
         t0 = time.time()
-        logits, self.cache = self._dispatch_fresh(
+        ids, self.cache = self._dispatch_fresh(
             ("paged_suffix", 1, bucket, width),
             lambda: self._paged_suffix(
                 self.params, self.cache, jnp.asarray(rows),
                 jnp.asarray([req.prefilled], np.int32),
                 jnp.asarray([req.prefilled + step_tok], np.int32),
                 jnp.asarray(bt), jnp.asarray([slot], np.int32),
+                *self._draw_args([req], 1),
                 n=1, bucket=bucket, width=width),
             then="admit", program="prefill_chunk", tokens=step_tok)
-        self.prefill_chunks += 1
         self._wave_span("prefill-chunk", t0, [req], tokens=step_tok,
                         prefilled=req.prefilled + step_tok,
                         prompt=len(req.tokens))
         req.prefilled += step_tok
         if req.prefilled >= len(req.tokens):
             self._prefilling.pop(slot)
-            self._slice("fetch", program="prefill_chunk")
-            logits = np.array(logits)
-            self._slice("admit")
-            self._post_admit([req], [slot], logits)
+            self._post_admit(
+                [req], [slot], self._fetch_ids(ids, "prefill_chunk"))
 
     def _retire(self, req: _Request, status: str) -> None:
         """Terminal exit for a request that never held a slot."""
@@ -1628,8 +1631,27 @@ class DecodeEngine:
                 self._retire(req, "cancelled" if dead
                              else "deadline_exceeded")
 
+    def _draw_args(self, group: List[_Request], n: int):
+        """What a prefill program samples its ``n`` rows with: the
+        rows' temperatures (pad rows repeat the last) and the counter
+        of the prefills' random stream, the prefill programs dispatched
+        so far, this one included. Host arrays: they go up with the
+        call."""
+        temps = [max(0.0, req.temperature) for req in group]
+        temps += temps[-1:] * (n - len(temps))
+        return (np.asarray(temps, np.float32),
+                np.int32(self._prefill_waves + self.prefill_chunks))
+
+    def _fetch_ids(self, ids, program: str) -> np.ndarray:
+        """The first tokens a prefill ``program`` sampled, on the host:
+        the one wait of an admission, its ``fetch`` slice."""
+        self._slice("fetch", program=program, bytes=int(ids.nbytes))
+        ids = np.array(ids)
+        self._slice("admit")
+        return ids
+
     def _post_admit(self, group: List[_Request], slots: List[int],
-                    logits: np.ndarray) -> None:
+                    ids: np.ndarray) -> None:
         # The prefix insert runs BEFORE the emit/finish loop: a
         # request that completes on its very first token (max_new=1 /
         # instant EOS) is _finish-ed inside that loop, which FREES its
@@ -1642,10 +1664,8 @@ class DecodeEngine:
                 self.prefix.insert(req.tokens, self._slot_pages[slot],
                                    matched_len=req.prefix_len)
         now = time.monotonic()
-        self._tokens_dev = None  # host writes below invalidate the
-        #   device-resident token vector (sampled-path feedback)
         for i, req in enumerate(group):
-            tok = self._sample_host(logits[i], req)
+            tok = int(ids[i])
             req.slot = slots[i]
             if req.first_token_at is None:
                 # Set once: a preempted request comes through here
@@ -1662,7 +1682,7 @@ class DecodeEngine:
                 self._finish(slots[i])
                 continue
             self._emit(req, tok)
-            self._tokens[slots[i]] = tok
+            self._seat(slots[i], tok, req.temperature)
             self._active[slots[i]] = req
             if req.generated >= req.max_new_tokens or (
                     req.eos_id is not None and tok == req.eos_id):
@@ -1774,14 +1794,16 @@ class DecodeEngine:
         return (int(req.tokens[p]) if p < req.prompt_len
                 else int(req.output[p - req.prompt_len]))
 
-    def _sample_host(self, logits: np.ndarray, req: _Request) -> int:
-        if req.temperature <= 0.0:
-            return int(np.argmax(logits))
-        z = logits / req.temperature
-        z = z - z.max()
-        p = np.exp(z)
-        p /= p.sum()
-        return int(self._rng.choice(len(p), p=p))
+    def _seat(self, slot: int, tok: int, temperature: float = 0.0) -> None:
+        """The HOST writes what ``slot`` decodes from next, token and
+        temperature: the device's copy of the tokens is stale from
+        here, and that of the temperatures if this one differs."""
+        self._tokens[slot] = tok
+        self._state_dev = None
+        temperature = max(0.0, temperature)
+        if self._temps[slot] != temperature:
+            self._temps[slot] = temperature
+            self._temps_dev = None
 
     _last_cb_log = 0.0  # class-wide rate limit for callback-failure logs
 
@@ -1851,8 +1873,7 @@ class DecodeEngine:
         # Park the freed slot at length 0 so idle slots don't walk their
         # cursor toward the capacity edge while others decode.
         self.cache["length"] = self.cache["length"].at[slot].set(0)
-        self._tokens[slot] = 0
-        self._tokens_dev = None
+        self._seat(slot, 0)
 
     def _finish(self, slot: int, status: str = "completed") -> None:
         req = self._active.pop(slot, None)
@@ -2029,46 +2050,70 @@ class DecodeEngine:
                             and tok == req.eos_id):
                         self._finish_in_step(slot)
                         break
+            self._state_dev = None
             self._steplog_row(t_step0, phases, ctx, rung)
             return stepped
-        if self._device_sampler:
-            return self._sampled_step(t_step0, phases, rec, ctx, view)
         t_d0 = time.time() if rec else 0.0
-        logits, self.cache = self._dispatch_fresh(
+        # ``uploads``: the arrays this dispatch puts on the device. The
+        # view always; the state and the temperatures only when the
+        # host has written to them since the last step.
+        state, self.cache = self._dispatch_fresh(
             ("decode", rung),
-            lambda: self._decode(
-                self.params, self.cache, jnp.asarray(self._tokens),
-                jnp.asarray(view)),
-            batch=stepped, ctx_tokens=ctx, view_pages=rung)
+            lambda: self._decode(self.params, self.cache,
+                                 *self._decode_inputs(view)),
+            batch=stepped, ctx_tokens=ctx, view_pages=rung,
+            uploads=1 + (self._state_dev is None)
+            + (self._temps_dev is None))
         if rec:
-            sl.begin("fetch", program="decode")
-        logits = np.array(logits)
-        counted = {}
-        if self._step_stats:
-            # The model's counters came as one more row of the logits.
+            sl.begin("fetch", program="decode", bytes=int(state.nbytes))
+        got = np.array(state)  # ids, the model's counters, the counter
+        self._state_dev = state
+        if rec:
+            # The model's counters belong to the launch (its row says
+            # so); its annotation closed before they existed, so in a
+            # profiler trace they ride on the slice after the fetch.
             counted = {name: int(v) for name, v in zip(
-                self._step_stats, logits[self.slots])}
-            logits = logits[:self.slots]
-        if rec:
-            # They belong to the launch (its row says so); its
-            # annotation closed before they existed, so in a profiler
-            # trace they ride on the slice that follows the fetch.
+                self._step_stats, got[self.slots:])}
             sl.amend("launch", **counted)
             sl.begin("sample_emit", **counted)
-        if rec:
             phases.append({"phase": "decode", "t0": t_d0,
                            "t1": time.time(), "batch": stepped, "k": 1})
         self.steps += 1
         for slot in list(self._active):
             req = self._active[slot]
-            tok = self._sample_host(logits[slot], req)
+            tok = int(got[slot])
             self._emit(req, tok)
-            self._tokens[slot] = tok
+            self._tokens[slot] = tok  # as ``_state_dev`` has it
             if req.generated >= req.max_new_tokens or (
                     req.eos_id is not None and tok == req.eos_id):
                 self._finish_in_step(slot)
         self._steplog_row(t_step0, phases, ctx, rung)
         return stepped
+
+    def _put(self, host: np.ndarray):
+        """``host`` on the device(s) the programs run on, a copy."""
+        if self.mesh is not None:
+            return self._jax.device_put(host,
+                                        self._shardings["replicated"])
+        return self._jax.numpy.array(host)
+
+    def _host_state(self) -> np.ndarray:
+        """The decode's ``state`` as the host knows it: its tokens, no
+        counts yet, the steps taken so far."""
+        return np.concatenate(
+            [self._tokens, np.zeros(len(self._step_stats), np.int32),
+             np.asarray([self.steps], np.int32)])
+
+    def _decode_inputs(self, view: np.ndarray):
+        """``(state, view, temps)`` on the device for the decode about
+        to be dispatched. A step that follows a step finds the state
+        where the last one left it and uploads the view alone."""
+        if self._state_dev is None:
+            self._state_dev = self._put(self._host_state())
+        if self._temps_dev is None:
+            self._temps_dev = self._put(self._temps)
+        return self._state_dev, self._jax.numpy.asarray(view), \
+            self._temps_dev
 
     def _ctx_tokens(self) -> int:
         """KV positions the decode about to be dispatched really needs:
@@ -2090,11 +2135,11 @@ class DecodeEngine:
     def _spec_ready(self) -> bool:
         """Spec rounds engage only when every active request is greedy
         (the acceptance rule compares ARGMAX tokens, which is exactly
-        the sequential greedy choice — sampled requests must take the
-        plain path, host or device sampler, to keep their RNG stream
-        intact) AND at least one slot still holds a draft seat: an
-        all-draftless batch would pay the k+1-wide verify forward for
-        guaranteed-rejected junk, so it takes the plain path instead."""
+        the sequential greedy choice — sampled requests take the plain
+        step, which draws on its own random stream) AND at least one
+        slot still holds a draft seat: an all-draftless batch would pay
+        the k+1-wide verify forward for guaranteed-rejected junk, so it
+        takes the plain path instead."""
         return (self.spec and bool(self._active)
                 and all(r.temperature <= 0.0
                         for r in self._active.values())
@@ -2174,7 +2219,7 @@ class DecodeEngine:
         # ---- host: longest-matching-prefix acceptance + rollback
         self.steps += 1
         self.spec_rounds += 1
-        self._tokens_dev = None
+        self._state_dev = None
         round_accepted = 0
         upd: List[Tuple[int, int, int]] = []   # (slot, L', D')
         for slot in list(self._active):
@@ -2228,53 +2273,6 @@ class DecodeEngine:
         self._steplog_row(t_step0, phases, ctx)
         return stepped
 
-    def _sampled_step(self, t_step0: float,
-                      phases: List[Dict[str, Any]], rec: bool,
-                      ctx: Optional[int], view: np.ndarray) -> int:
-        """Single decode step with sampling fused into the device
-        program: the (slots, vocab) logits never cross the host
-        boundary — only (slots,) token ids do — and consecutive sampled
-        steps feed the device-resident token vector straight back in.
-        Greedy rows are bit-identical to the host sampler (both argmax
-        with first-max tiebreak); sampled rows draw from the program's
-        counter-based RNG stream instead of the host generator."""
-        import jax.numpy as jnp
-
-        stepped = len(self._active)
-        temps = np.zeros((self.slots,), np.float32)
-        for slot, req in self._active.items():
-            temps[slot] = max(0.0, req.temperature)
-        tin = (self._tokens_dev if self._tokens_dev is not None
-               else jnp.asarray(self._tokens))
-        t_d0 = time.time() if rec else 0.0
-        rung = view.shape[1]
-        toks_dev, self.cache = self._dispatch_fresh(
-            ("decode_sampled", rung),
-            lambda: self._decode_sampled(
-                self.params, self.cache, tin,
-                jnp.asarray(view), jnp.asarray(temps),
-                jnp.asarray(self.steps, jnp.int32)),
-            batch=stepped, ctx_tokens=ctx, view_pages=rung)
-        self._slice("fetch", program="decode_sampled")
-        toks = np.array(toks_dev)  # np.array: next dispatch donates
-        self._slice("sample_emit")
-        self._tokens_dev = toks_dev
-        if rec:
-            phases.append({"phase": "decode", "t0": t_d0,
-                           "t1": time.time(), "batch": stepped, "k": 1,
-                           "sampler": "device"})
-        self.steps += 1
-        for slot in list(self._active):
-            req = self._active[slot]
-            tok = int(toks[slot])
-            self._emit(req, tok)
-            self._tokens[slot] = tok
-            if req.generated >= req.max_new_tokens or (
-                    req.eos_id is not None and tok == req.eos_id):
-                self._finish_in_step(slot)
-        self._steplog_row(t_step0, phases, ctx, rung)
-        return stepped
-
     def _steplog_row(self, t0: float, phases: List[Dict[str, Any]],
                      ctx_tokens: Optional[int] = None,
                      view_pages: Optional[int] = None) -> None:
@@ -2310,24 +2308,12 @@ class DecodeEngine:
         whatever builds an engine for traffic calls this first. On an
         idle engine: the view is empty, so every write goes to the
         scratch page, and the cursors are parked at 0 afterwards."""
-        import jax.numpy as jnp
-
-        toks = jnp.asarray(self._tokens)
         for rung, view in self._empty_views():
-            if self._device_sampler:
-                _, self.cache = self._dispatch_fresh(
-                    ("decode_sampled", rung),
-                    lambda: self._decode_sampled(
-                        self.params, self.cache, toks, view,
-                        jnp.zeros((self.slots,), jnp.float32),
-                        jnp.asarray(0, jnp.int32)))
-            else:
-                _, self.cache = self._dispatch_fresh(
-                    ("decode", rung),
-                    lambda: self._decode(self.params, self.cache, toks,
-                                         view))
+            _, self.cache = self._dispatch_fresh(
+                ("decode", rung),
+                lambda: self._decode(self.params, self.cache,
+                                     *self._decode_inputs(view)))
         self.cache["length"] = self.cache["length"].at[:].set(0)
-        self._tokens_dev = None
 
     def _empty_views(self):
         """``(rung, view)`` up the ladder, each view listing no page."""
@@ -2358,7 +2344,8 @@ class DecodeEngine:
                 jnp.zeros((1, bucket), jnp.int32),
                 jnp.asarray([0], jnp.int32),
                 jnp.asarray(self._block_tables[:1, :wp]),
-                jnp.asarray([0], jnp.int32), n=1, bucket=bucket))
+                jnp.asarray([0], jnp.int32), np.zeros((1,), np.float32),
+                np.int32(0), n=1, bucket=bucket))
         self.warm_decode()
         for rung, view in self._empty_views():
             c = 2
@@ -2388,7 +2375,6 @@ class DecodeEngine:
             self._draft_cache["length"] = \
                 self._draft_cache["length"].at[:].set(0)
         self.cache["length"] = self.cache["length"].at[:].set(0)
-        self._tokens_dev = None
 
     def serve_forever(self, idle_wait_s: float = 0.05) -> None:
         """Decode loop for a replica thread: steps while work exists,
@@ -2632,7 +2618,6 @@ class LlamaDecodeDeployment:
                  spec_draft_model: Optional[str] = None,
                  spec_k: Optional[int] = None,
                  spec_draft_pool_pages: Optional[int] = None,
-                 device_sampler: Optional[bool] = None,
                  warmup: Optional[bool] = None):
         import jax
 
@@ -2678,8 +2663,7 @@ class LlamaDecodeDeployment:
             mesh_shape=mesh_shape,
             spec_draft_params=draft_params, spec_draft_config=draft_cfg,
             spec_k=sk if draft_params is not None else 0,
-            spec_draft_pool_pages=spec_draft_pool_pages,
-            device_sampler=device_sampler, model=ld)
+            spec_draft_pool_pages=spec_draft_pool_pages, model=ld)
         # The decode ladder is warmed whatever the knob says: which rung
         # a step takes follows the traffic, and a rung met first under
         # load is seconds of compile in one request's latency. The knob

@@ -435,20 +435,18 @@ def test_mutation_decode_unwrapped_dispatch():
     donation-unguarded-dispatch (the PR 14 reload footgun reopened)."""
     found, graph = mutant_findings(
         donation_safety.check, "ray_tpu/serve/decode.py",
-        """toks_dev, self.cache = self._dispatch_fresh(
-            ("decode_sampled", rung),
-            lambda: self._decode_sampled(
-                self.params, self.cache, tin,
-                jnp.asarray(view), jnp.asarray(temps),
-                jnp.asarray(self.steps, jnp.int32)),
-            batch=stepped, ctx_tokens=ctx, view_pages=rung)""",
-        """toks_dev, self.cache = self._decode_sampled(
-            self.params, self.cache, tin,
-            jnp.asarray(view), jnp.asarray(temps),
-            jnp.asarray(self.steps, jnp.int32))""")
+        """state, self.cache = self._dispatch_fresh(
+            ("decode", rung),
+            lambda: self._decode(self.params, self.cache,
+                                 *self._decode_inputs(view)),
+            batch=stepped, ctx_tokens=ctx, view_pages=rung,
+            uploads=1 + (self._state_dev is None)
+            + (self._temps_dev is None))""",
+        """state, self.cache = self._decode(
+            self.params, self.cache, *self._decode_inputs(view))""")
     hits = [f for f in found if f.rule == rules.DONATION_UNGUARDED]
     assert hits and hits[0].path == "ray_tpu/serve/decode.py"
-    assert "_decode_sampled" in hits[0].message
+    assert "_decode" in hits[0].message
     # --diff slice coverage for the donation family
     sliced = donation_safety.check(
         graph, emit_files={"ray_tpu/serve/decode.py"})

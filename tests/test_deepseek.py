@@ -167,6 +167,54 @@ def test_the_step_log_carries_the_expert_counters(model):
     assert sum(s["moe_pairs"] for s in launches) > 0
 
 
+def test_the_counters_ride_with_the_ids_and_equal_a_direct_calls(model):
+    """The decode returns ids, not logits, and the three ``STEP_STATS``
+    come in the same small vector: each step's ``launch`` row and its
+    ``sample_emit`` slice (the annotation the trace readers find) read
+    what ``paged_decode_step`` itself counts on the same arguments, and
+    the step's one fetch is 4 bytes a slot, a counter and the step."""
+    import jax
+
+    from ray_tpu.models import deepseek_decode as dd
+    from ray_tpu.serve.decode import _pool_of
+
+    cfg = model[0]
+    rng = np.random.default_rng(9)
+    eng = _engine(model, step_timeline=64)
+    direct = jax.jit(dd.paged_decode_step, static_argnums=(5,))
+    decode, counted = eng._decode, []
+
+    def spied(params, cache, state, view, temps):
+        logits, _, _, stats = direct(
+            params, _pool_of(cache), view, cache["length"],
+            state[:eng.slots], cfg)
+        out, cache = decode(params, cache, state, view, temps)
+        got = np.asarray(out)
+        assert got.shape == (eng.slots + len(dd.STEP_STATS) + 1,)
+        assert np.array_equal(got[:eng.slots],
+                              np.asarray(logits).argmax(-1))
+        counted.append([int(v) for v in np.asarray(stats)])
+        return out, cache
+
+    eng._decode = spied
+    reqs = [eng.submit(_prompt(rng, n), max_new_tokens=5)
+            for n in (12, 7, 20)]
+    _drive(eng, reqs)
+    rows = [r for r in eng.steplog.dump()["rows"] if any(
+        s["name"] == "launch" and s.get("program") == "decode"
+        for s in r["slices"])]
+    assert len(rows) == len(counted) >= 4
+    for row, want in zip(rows, counted):
+        by = {}  # the decode's slices, the first ``sample_emit`` of the
+        for s in row["slices"]:  # loop: a finish re-opens it bare
+            if s.get("program", "decode") == "decode":
+                by.setdefault(s["name"], s)
+        for name in ("launch", "sample_emit"):
+            assert [by[name][k] for k in dd.STEP_STATS] == want, name
+        assert by["fetch"]["bytes"] == 4 * (eng.slots + 3 + 1)
+    assert sum(w[0] for w in counted) > 0
+
+
 # ------------------------- (b) absorbed decode = up-projected prefill
 
 

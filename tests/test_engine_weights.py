@@ -386,7 +386,6 @@ def test_lowered_programs_convert_no_weight(kind, model, served):
         shapes.add(tuple(w.shape))
         if w.shape[0] == cfg.n_layers:
             shapes.add(tuple(w.shape[1:]))
-    toks = jnp.zeros((4,), jnp.int32)
     bt = jnp.zeros((4, eng.slot_pages_max), jnp.int32)
     one = jnp.ones((1,), jnp.int32)
 
@@ -394,11 +393,14 @@ def test_lowered_programs_convert_no_weight(kind, model, served):
         # Through a jit of the engine's callable: a mesh engine's program
         # is traced inside its axis rules, and only that wrapper knows them.
         return {
-            "decode": jax.jit(eng._decode).lower(tree, eng.cache, toks, bt),
+            "decode": jax.jit(eng._decode).lower(
+                tree, eng.cache, jnp.asarray(eng._host_state()), bt,
+                jnp.zeros((4,), jnp.float32)),
             "paged_suffix": jax.jit(functools.partial(
                 eng._paged_suffix, n=1, bucket=16, width=2)).lower(
                     tree, eng.cache, jnp.zeros((1, 16), jnp.int32), one,
-                    one, bt[:1, :2], one),
+                    one, bt[:1, :2], one, jnp.zeros((1,), jnp.float32),
+                    jnp.asarray(0, jnp.int32)),
         }
 
     for key, low in lower(eng.params).items():

@@ -380,36 +380,128 @@ def test_spec_draftless_fallback_stays_exact(model, draft):
 
 @pytest.mark.parametrize("page_tokens", [16, 64])
 def test_device_sampler_greedy_parity(model, page_tokens):
-    """Fused device sampling returns the SAME greedy streams as the
-    host sampler (argmax with first-max tiebreak on both sides), at a
-    small page and at the default one."""
+    """The ids a program returns are the argmax of the model's own
+    logits, first maximum first: each engine dispatch is held to
+    ``paged_prefill`` / ``paged_decode_step`` called directly on the
+    same arguments and ``np.argmax``-ed on the host, at a small page and
+    at the default one; the streams are the plain reference's."""
+    import jax
+
+    from ray_tpu.models import llama_decode as ld
+    from ray_tpu.serve.decode import _pool_of
+    from tests.stream_reference import assert_stream_is_the_references
+
+    cfg, params = model
+    eng = _plain_engine(model, page_tokens=page_tokens)
+    direct_prefill = jax.jit(ld.paged_prefill, static_argnums=(4,))
+    direct_step = jax.jit(ld.paged_decode_step, static_argnums=(5,))
+    checked = {"prefill": 0, "decode": 0}
+    prefill, decode = eng._paged_prefill, eng._decode
+
+    def spied_prefill(p, cache, rows, lengths, bt, slot_ids, temps, wave,
+                      n, bucket):
+        want = np.asarray(direct_prefill(
+            p, rows[:, :bucket], _pool_of(cache), bt, cfg,
+            lengths=lengths)[0]).argmax(-1)
+        ids, cache = prefill(p, cache, rows, lengths, bt, slot_ids, temps,
+                             wave, n=n, bucket=bucket)
+        assert np.array_equal(np.asarray(ids), want)
+        checked["prefill"] += 1
+        return ids, cache
+
+    def spied_decode(p, cache, state, view, temps):
+        want = np.asarray(direct_step(
+            p, _pool_of(cache), view, cache["length"], state[:eng.slots],
+            cfg)[0]).argmax(-1)
+        out, cache = decode(p, cache, state, view, temps)
+        assert np.array_equal(np.asarray(out)[:eng.slots], want)
+        checked["decode"] += 1
+        return out, cache
+
+    eng._paged_prefill, eng._decode = spied_prefill, spied_decode
     rng = np.random.default_rng(23)
     prompts = [rng.integers(1, 60, size=n).tolist() for n in (4, 12, 27)]
-    host = _plain_engine(model, page_tokens=page_tokens)
-    want = _outputs(host, prompts, 18)
-    host.shutdown()
-    dev = _plain_engine(model, page_tokens=page_tokens,
-                        device_sampler=True)
-    got = _outputs(dev, prompts, 18)
-    for w, g in zip(want, got):
-        assert np.array_equal(w, g)
-    dev.shutdown()
+    got = _outputs(eng, prompts, 18)
+    assert checked["prefill"] >= 1 and checked["decode"] >= 17
+    for prompt, served in zip(prompts, got):
+        assert len(served) == 18
+        assert_stream_is_the_references(params, cfg, prompt, served)
+    eng.shutdown()
 
 
 def test_device_sampler_sampled_rows_deterministic(model):
-    """Sampled rows move to the program's counter-based RNG stream:
-    still deterministic (two identical engines agree token-for-token),
-    just not the host numpy stream."""
+    """Rows with a temperature draw on the programs' counter-based
+    streams: deterministic (two identical engines agree token for
+    token), and nothing of numpy's."""
     rng = np.random.default_rng(29)
     prompts = [rng.integers(1, 60, size=8).tolist()]
     outs = []
     for _ in range(2):
-        eng = _plain_engine(model, device_sampler=True)
+        eng = _plain_engine(model)
+        assert not hasattr(eng, "_rng")
         outs.append(_outputs(eng, prompts, 12,
                              temperature=0.8)[0])
         eng.shutdown()
     assert np.array_equal(outs[0], outs[1])
     assert all(0 <= t < _tiny()[0].vocab_size for t in outs[0])
+
+
+def test_sample_batch_draws_from_the_softmax_at_its_temperature():
+    """4,000 draws of one 16-way row at T = 0.7 follow
+    ``softmax(logits / T)`` (chi-square, 15 degrees of freedom: 37.7 is
+    the 0.1% point), and a temperature row among greedy rows leaves the
+    greedy rows what they were."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama_decode import sample_batch
+
+    rng = np.random.default_rng(41)
+    row = rng.normal(size=16).astype(np.float32)
+    n, temp = 4000, 0.7
+    draws = np.asarray(sample_batch(
+        jnp.broadcast_to(row, (n, 16)), jnp.full((n,), temp, jnp.float32),
+        jax.random.key(5)))
+    p = np.exp((row - row.max()) / temp)
+    p /= p.sum()
+    seen = np.bincount(draws, minlength=16)
+    assert seen.sum() == n and (n * p).min() > 5
+    chi2 = float((((seen - n * p) ** 2) / (n * p)).sum())
+    assert chi2 < 37.7, (chi2, seen.tolist())
+    # ...and not the untempered softmax: the statistic tells them apart.
+    p1 = np.exp(row - row.max())
+    p1 /= p1.sum()
+    assert float((((seen - n * p1) ** 2) / (n * p1)).sum()) > 37.7
+    logits = jnp.asarray(rng.normal(size=(6, 16)).astype(np.float32))
+    logits = logits.at[2, 3].set(logits[2].max())  # a tie: the first wins
+    greedy = np.asarray(sample_batch(
+        logits, jnp.zeros((6,), jnp.float32), jax.random.key(0)))
+    assert np.array_equal(greedy, np.asarray(logits).argmax(-1))
+    for seed in range(8):
+        mixed = np.asarray(sample_batch(
+            logits, jnp.asarray([0, 0, 0, 5.0, 0, 0], jnp.float32),
+            jax.random.key(seed)))
+        assert np.array_equal(np.delete(mixed, 3), np.delete(greedy, 3))
+
+
+def test_a_temperature_compiles_nothing_in_a_warmed_engine(model):
+    """The temperatures are an argument of the programs: a request with
+    one, admitted into an engine that served greedy requests, adds no
+    program key and no compile."""
+    from ray_tpu.util.compile_cache import compile_watch
+
+    rng = np.random.default_rng(43)
+    prompts = [rng.integers(1, 60, size=9).tolist() for _ in range(2)]
+    eng = _plain_engine(model)
+    eng.warm_decode()
+    _outputs(eng, prompts, 6)
+    keys = set(eng._compiled)
+    compiles = compile_watch().snapshot()["compiles"]
+    got = _outputs(eng, prompts, 6, temperature=0.9)
+    assert set(eng._compiled) == keys
+    assert compile_watch().snapshot()["compiles"] == compiles
+    assert all(len(g) == 6 for g in got)
+    eng.shutdown()
 
 
 @pytest.mark.slow  # PR 20 rebudget (8.2s): warmup perf property
@@ -419,12 +511,10 @@ def test_warmup_predispatches_step_programs(model, draft):
     first real requests emit the exact greedy streams."""
     import numpy as _np
 
-    spec = _spec_engine(model, draft, k=3, decode_chunk=4,
-                        device_sampler=True)
+    spec = _spec_engine(model, draft, k=3, decode_chunk=4)
     spec.warmup()
     # 4 slots x 16 pages: the ladder of view widths is the one rung 64.
-    # With the sampler fused, ``decode_sampled`` is the one-token decode.
-    for key in [("decode_sampled", 64), ("decode_k", 2, 64),
+    for key in [("decode", 64), ("decode_k", 2, 64),
                 ("decode_k", 4, 64), ("spec_draft", 3, 64),
                 ("spec_verify", 3), ("paged_prefill", 1, 128)]:
         assert key in spec._compiled, key

@@ -59,8 +59,7 @@ SCENARIOS = {
     "preemption_mid_prefill": (
         {"pool_pages": 12, "prefill_chunk_tokens": 32}, [10, 70, 30, 90],
         20, {}),
-    "sampled_path": ({"device_sampler": True}, [10, 30], 12,
-                     {"temperature": 0.7}),
+    "sampled_path": ({}, [10, 30], 12, {"temperature": 0.7}),
     "decode_chunks": ({"decode_chunk": 4}, [10, 14], 16, {}),
     "prefix_hit": ({"slots": 1, "prefix_pool_entries": 4,
                     "prefix_match_min_tokens": 16}, [40, 44, 38], 6, {}, 32),
@@ -131,13 +130,11 @@ def test_phases_are_what_they_were(name):
                 assert ph["t0"] == row["t0"] and ph["waves"] >= 1
             if ph["phase"] == "decode":
                 assert ph["k"] == 1 and ph["batch"] >= 1
-                run = [s for s in row["slices"] if s.get("program") in (
-                    "decode", "decode_sampled")]
+                run = [s for s in row["slices"]
+                       if s.get("program") == "decode"]
                 # From before the dispatch to after the blocking fetch.
                 assert [s["name"] for s in run] == ["launch", "fetch"]
                 assert ph["t0"] <= run[0]["t0"] and run[1]["t1"] <= ph["t1"]
-                if name == "sampled_path":
-                    assert ph["sampler"] == "device"
     assert kinds == ({"admit", "decode", "prefill_chunk"}
                      if name == "chunked_prefill" else {"admit", "decode"})
     assert all(set(r) >= {"step", "t0", "t1", "phases", "active",
@@ -158,6 +155,7 @@ def test_launch_slices_say_program_role_and_tokens():
         [32, 32, 32, 4, 32, 32, 6])
     assert [s["tokens"] for s in by["paged_prefill"]] == [20]
     assert "paged_suffix" not in by
+
     decode = by["decode"]
     assert all(s["batch"] >= 1 and s["ctx_tokens"] >= s["batch"]
                for s in decode)
@@ -171,6 +169,42 @@ def test_launch_slices_say_program_role_and_tokens():
     # ctx_tokens is the contexts' sum, the new token included.
     first = next(r for r in rows if "ctx_tokens" in r)
     assert first["ctx_tokens"] == 20 + 1
+
+
+@pytest.mark.parametrize("name", ["admission", "chunked_prefill",
+                                  "sampled_path"])
+def test_a_step_fetches_ids_and_uploads_what_the_host_changed(name):
+    """The counters that say the sample is taken in the program: every
+    ``fetch`` reads ``bytes``, 4 a slot plus the step counter for a
+    decode (no model counters here) and 4 a prompt for a prefill, never
+    a row of the vocabulary; the decode's ``launch`` reads ``uploads``,
+    1 (the view) on a step that follows a step with nothing written in
+    between, and more only after the host wrote tokens (an admission, a
+    finish) or a temperature."""
+    eng, reqs, rows = _run(name)
+    uploads = []
+    for row in rows:
+        wrote = False  # did the HOST seat or free a slot before the decode
+        for s in row["slices"]:
+            if s["name"] == "fetch" and s["program"] == "decode":
+                assert s["bytes"] == 4 * (eng.slots + 1)
+            elif s["name"] == "fetch":
+                assert 4 <= s["bytes"] <= 4 * eng.slots
+                wrote = True
+            elif s["name"] == "launch" and s["program"] == "decode":
+                uploads.append((s["uploads"], wrote))
+        if any(s["name"] == "finish" for s in row["slices"]):
+            uploads.append((None, True))  # the next decode re-uploads
+    assert uploads and all(
+        s.get("bytes", 0) < 4 * 61 for r in rows for s in r["slices"])
+    follows = [n for (n, wrote), (_, before) in zip(uploads[1:], uploads)
+               if n is not None and not wrote and not before]
+    assert len(follows) >= 5 and set(follows) == {1}, uploads
+    after = [n for n, wrote in uploads if n is not None and wrote]
+    assert after and all(n in (2, 3) for n in after), uploads
+    # A temperature goes up when a slot's changes, not every step.
+    threes = sum(1 for n, _ in uploads if n == 3)
+    assert threes <= (3 if name == "sampled_path" else 1), uploads
 
 
 class _ViewSpy:
@@ -398,31 +432,46 @@ def test_slices_are_annotations_on_the_profilers_clock(tmp_path):
 
 
 def test_engine_programs_are_jitted_under_their_keys_name():
+    import jax
     import jax.numpy as jnp
 
-    cfg, eng = _engine(prefill_chunk_tokens=32, device_sampler=True,
-                       decode_chunk=2)
+    cfg, eng = _engine(prefill_chunk_tokens=32, decode_chunk=2)
     toks = jnp.zeros((4,), jnp.int32)
+    state = jnp.asarray(eng._host_state())
+    temps = jnp.zeros((4,), jnp.float32)
     bt = jnp.asarray(eng._block_tables)
     view = jnp.asarray(eng._live_view(eng._block_tables, eng._slot_pages))
     one = jnp.zeros((1,), jnp.int32)
+    draw = (jnp.zeros((1,), jnp.float32), jnp.asarray(0, jnp.int32))
     lowered = {
-        "decode": eng._decode.lower(eng.params, eng.cache, toks, view),
+        "decode": eng._decode.lower(eng.params, eng.cache, state, view,
+                                    temps),
         "decode_k": eng._decode_k.lower(eng.params, eng.cache, toks, view,
                                         k=2),
-        "decode_sampled": eng._decode_sampled.lower(
-            eng.params, eng.cache, toks, view,
-            jnp.zeros((4,), jnp.float32), jnp.asarray(0, jnp.int32)),
         "paged_prefill": eng._paged_prefill.lower(
             eng.params, eng.cache, jnp.zeros((1, 16), jnp.int32), one,
-            bt[:1, :1], one, n=1, bucket=16),
+            bt[:1, :1], one, *draw, n=1, bucket=16),
         "paged_suffix": eng._paged_suffix.lower(
             eng.params, eng.cache, jnp.zeros((1, 16), jnp.int32), one, one,
-            bt[:1, :2], one, n=1, bucket=16, width=2),
+            bt[:1, :2], one, *draw, n=1, bucket=16, width=2),
     }
+    assert not hasattr(eng, "_decode_sampled")
     for key, low in lowered.items():
         head = low.as_text().split("\n", 1)[0]
         assert f"@jit_engine_{key} " in head, head
+    # A program that ends in a sample returns the sample: on the program
+    # text, no result of the four is as wide as the vocabulary (the
+    # logits stay inside), and the decode's is ids + the step counter.
+    for key, low in lowered.items():
+        main = next(line for line in low.as_text().splitlines()
+                    if "@main(" in line)
+        results = re.findall(r"tensor<([\dx]*)x?[a-z]\w*>",
+                             main.split("->", 1)[1])
+        assert results, main
+        for dims in results:
+            assert str(cfg.vocab_size) not in dims.split("x"), (key, dims)
+    assert [tuple(o.shape) for o in jax.tree.leaves(
+        lowered["decode"].out_info)][0] == (4 + 1,)
     text = lowered["decode"].as_text(debug_info=True)
     for scope in ("paged_gather", "paged_attn"):
         assert re.search(rf'loc\("[^"]*{scope}', text), scope
@@ -431,8 +480,8 @@ def test_engine_programs_are_jitted_under_their_keys_name():
     # program given float32 parameters.
     assert "weight_cast" not in text
     masters = _tiny()[1]
-    text = eng._decode.lower(masters, eng.cache, toks, view).as_text(
-        debug_info=True)
+    text = eng._decode.lower(masters, eng.cache, state, view,
+                             temps).as_text(debug_info=True)
     assert re.search(r'loc\("[^"]*weight_cast', text)
     for key in ("paged_suffix", "decode_k"):
         text = lowered[key].as_text(debug_info=True)
