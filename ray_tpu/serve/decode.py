@@ -33,6 +33,7 @@ import os
 import queue
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -533,6 +534,10 @@ class DecodeEngine:
         self.steplog = StepTimeline(
             rt_config.decode_step_timeline
             if step_timeline is None else step_timeline)
+        # Step-log events from other threads (a stream's record, closed
+        # on a pull's thread): the log is this loop's alone, so they
+        # wait here for the next step's ``reap``.
+        self._inbox: deque = deque()
         self._compiled: set = set()  # program keys dispatched once
         self._prefill_waves = 0      # prefill programs dispatched
         # Disaggregated handoff accounting (engine side; the per-replica
@@ -1167,6 +1172,13 @@ class DecodeEngine:
             if self.steplog.enabled:
                 self.steplog.event("jit-compile", key="/".join(
                     str(k) for k in key))
+
+    def post_event(self, kind: str, **attrs: Any) -> None:
+        """A step-log event from ANY thread: it rides on the row of the
+        next step, which a parked loop wakes for. Stamped here."""
+        if self.steplog.enabled:
+            self._inbox.append((kind, {"ts": time.time(), **attrs}))
+            self._work.set()
 
     def _observe_terminal(self, req: "_Request", status: str) -> None:
         """Terminal bookkeeping shared by _finish and _retire: outcome
@@ -1906,7 +1918,11 @@ class DecodeEngine:
         deadline): runs at every step boundary, so a dead request costs
         at most ONE more decode step — its slot and its place in the
         batch go back to live traffic immediately (the property Orca-
-        style iteration-level scheduling is for)."""
+        style iteration-level scheduling is for). What other threads
+        posted for the step log (``post_event``) is entered here."""
+        while self._inbox:
+            kind, attrs = self._inbox.popleft()
+            self.steplog.event(kind, **attrs)
         now = time.monotonic()
         if (self._queued_cancelled > 0
                 or (now - self._last_purge > 0.5
@@ -2291,7 +2307,7 @@ class DecodeEngine:
             self.steplog.park(len(self._active))
             return
         self.steplog.record(
-            self.steps, t0, time.time(), phases,
+            t0, time.time(), phases,
             active=len(self._active), prefilling=len(self._prefilling),
             queued=max(0, self._pending.qsize() + len(self._requeue)
                        - self._queued_cancelled),
@@ -2383,7 +2399,7 @@ class DecodeEngine:
         try:
             while not self._stop.is_set():
                 if (self._active or self._prefilling or self._requeue
-                        or not self._pending.empty()):
+                        or not self._pending.empty() or self._inbox):
                     self.step()
                 else:
                     self._work.clear()
@@ -2975,7 +2991,35 @@ class LlamaDecodeDeployment:
                                 or self.engine.cancel(req.request_id))
         # For an ending that forgets to tell: ``done`` is set, no hook ran.
         out.backstop = lambda: req.done.is_set() and ended(req)
+        out.on_record = lambda record: self._stream_ended(req, record)
         return out
+
+    def _stream_ended(self, req: _Request, record: Dict[str, Any]) -> None:
+        """A stream's closed record (``StreamQueue``) joined with its
+        request's clocks, once: to the step log as a ``stream-end`` event
+        (through the engine's inbox: this is a pull's thread) and to the
+        request's trace as the span ``stream``, delivery beside
+        ``decode``."""
+        # mono -> wall. A thread switch between the two reads makes the
+        # difference too small, never too large: the largest of three.
+        off = max(time.time() - time.monotonic() for _ in range(3))
+        record.update(
+            request=req.request_id,
+            # A stream closed before its request ended is being cancelled.
+            outcome=req.status if req.done.is_set() else "cancelled",
+            submitted=req.submitted_at + off,
+            admitted=(None if req.admitted_at is None
+                      else req.admitted_at + off))
+        self.engine.post_event("stream-end", **record)
+        if req.trace is not None and record["first_put"] is not None:
+            from ray_tpu.util import tracing
+
+            tracing.record_span(
+                "stream", record["first_put"],
+                record["last_ack"] or record["last_put"], ctx=req.trace,
+                request=req.request_id, items=record["items"],
+                pulls=record["pulls"],
+                deliver_s_max=record["deliver_s_max"])
 
     def health(self) -> Dict[str, Any]:
         return self.engine.stats()

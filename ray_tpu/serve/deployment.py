@@ -804,9 +804,10 @@ class _Router:
         replica's stream holds one item, with whatever else it holds by
         then, up to ``chunk_items`` — so this yields a token when it
         exists, and sixteen at a time only from a producer that is ahead
-        (``ReplicaActor``'s "streaming sessions"). The replica's
-        in-flight slot and this router's count are held for the stream's
-        lifetime (autoscaling sees streams as load).
+        (``ReplicaActor``'s "streaming sessions"). Every pull after the
+        first says when the consumer was done with the delivery before
+        it. The replica's in-flight slot and this router's count are held
+        for the stream's lifetime (autoscaling sees streams as load).
 
         Replica death is retried (budget + backoff) only BEFORE the
         first item: once any token has been yielded the stream has
@@ -864,13 +865,19 @@ class _Router:
                     self._count_retry()
                     time.sleep(pause)
                     continue
+                acked_at = None
                 while True:
                     items, done = ray_tpu.get(handle.next_chunks.remote(
-                        sid, chunk_items), timeout=70.0)
+                        sid, chunk_items, acked_at), timeout=70.0)
                     yield from items
                     if done:
                         sid = None
                         return
+                    # The consumer has taken the whole delivery (the
+                    # proxy: written it): the acknowledgement rides on
+                    # the pull that is made anyway (the stream's record,
+                    # ``serve.replica.StreamQueue``).
+                    acked_at = time.time()
             finally:
                 if sid is not None:  # consumer bailed early: free the
                     try:             # slot + cancel the engine request

@@ -36,7 +36,11 @@ _HTTP_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
 TTFT = Histogram(
     "serve_ttft_s",
     "Time to first token: engine submit -> first emitted token "
-    "(includes queue wait and prefill).",
+    "(includes queue wait and prefill). The ENGINE's interval: it "
+    "leaves out what comes before the submit (HTTP read, routing, the "
+    "start_stream call: serve_ingress_s) and after the emit (the "
+    "stream's wake-up, the reply and the socket write: "
+    "serve_stream_delivery_s).",
     boundaries=_TTFT_BUCKETS, tag_keys=("deployment",))
 
 INTER_TOKEN = Histogram(
@@ -62,8 +66,30 @@ STREAM_PULL_ITEMS = Histogram(
     "Items one next_chunks reply carried from a replica's stream to the "
     "router: ~1 when the consumer keeps up with the producer (one token "
     "a decode step), up to the delivery cap (16) when the producer is "
-    "ahead. Recorded once per stream, when it ends.",
+    "ahead. Recorded once per stream, when it ends, from the stream's "
+    "record: one observation a reply, each at the stream's mean (count "
+    "and sum are exact, a quantile is one of streams' means).",
     boundaries=(1, 2, 4, 8, 15, 16), tag_keys=("deployment",))
+
+STREAM_DELIVERY = Histogram(
+    "serve_stream_delivery_s",
+    "One delivery of a stream, from the put of its oldest item on the "
+    "replica to the consumer's acknowledgement (the router is back for "
+    "more: for the HTTP proxy, after the socket write): the wake-up of "
+    "the pull, the reply and the write. Recorded once per stream from "
+    "its record: one observation an acknowledged delivery, the slowest "
+    "at its own time and the others at their mean (count and sum are "
+    "exact). The last delivery of a stream is never acknowledged.",
+    boundaries=_TOKEN_BUCKETS, tag_keys=("deployment",))
+
+INGRESS = Histogram(
+    "serve_ingress_s",
+    "What a streamed request spends before the engine has it: the "
+    "start of its root span in the calling process (the proxy's "
+    "http:<route>) -> engine submit. HTTP read and parse, routing, the "
+    "start_stream actor call. Needs serve_trace_spans and one clock "
+    "across the two processes (one host).",
+    boundaries=_TTFT_BUCKETS, tag_keys=("deployment",))
 
 REQUESTS = Counter(
     "serve_requests_total",
@@ -159,6 +185,22 @@ REPLICA_EPOCH = Gauge(
     "at all — is serving traffic nobody reconciles (orphan-replica).",
     tag_keys=("deployment",))
 
+
+
+def observe_stream(record: Dict[str, Any], tags: Dict[str, str]) -> None:
+    """The three series a closed stream record feeds (``StreamQueue``'s;
+    ``submitted`` is there when an engine joined its clocks)."""
+    pulls, acked = record["pulls"], record["acked"]
+    STREAM_PULL_ITEMS.observe_many([record["items"] / pulls] * pulls, tags)
+    if acked:
+        worst = record["deliver_s_max"]
+        rest = (record["deliver_s_sum"] - worst) / max(1, acked - 1)
+        STREAM_DELIVERY.observe_many([worst] + [rest] * (acked - 1), tags)
+    received, submitted = record["received"], record.get("submitted")
+    if received is not None and submitted is not None:
+        INGRESS.observe(submitted - received, tags)
+
+
 # Outcomes worth a counter key even at zero; keeps dashboards stable.
 OUTCOMES = ("completed", "cancelled", "deadline_exceeded", "shed", "error")
 
@@ -168,6 +210,8 @@ _HISTOGRAMS = {
     "queue_wait_s": "serve_queue_wait_s",
     "http_request_s": "serve_http_request_s",
     "stream_pull_items": "serve_stream_pull_items",
+    "stream_delivery_s": "serve_stream_delivery_s",
+    "ingress_s": "serve_ingress_s",
     "spec_accept_rate": "serve_spec_accept_rate",
     "handoff_bytes": "serve_handoff_bytes",
     "handoff_latency_s": "serve_handoff_latency_s",

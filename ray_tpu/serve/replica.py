@@ -133,7 +133,17 @@ class StreamQueue:
     slow one ships each item as it appears.
 
     It iterates (``next`` takes one item, ``close`` as on a generator),
-    so code that held a generator still works."""
+    so code that held a generator still works.
+
+    It also keeps the stream's one RECORD (``docs/OBSERVABILITY.md``,
+    "Stream record"): every item is stamped as it is put (one
+    ``time.time()``, kept beside the item), a ``take`` adds how long its
+    oldest item lay here (``dwell``) and how long it waited with nothing
+    here (``blocked``), and a consumer that says when it was done with
+    the delivery before (``acked_at`` on its next ``take``) gives that
+    delivery's ``put`` -> acknowledged time. Sums and extremes only:
+    nothing grows with the stream. ``close`` closes the record, once,
+    and hands it to ``on_record``."""
 
     # A producer that forgets to ``end`` is caught by asking ``backstop``
     # this often; no ending the code knows of waits for it.
@@ -149,7 +159,19 @@ class StreamQueue:
         self._room = 0           # pumped: items the pump may run ahead
         self.backstop: Optional[Callable[[], None]] = None
         self.on_close: Optional[Callable[[], None]] = None
-        self.pulls: List[int] = []  # items each take() left with
+        # The record (wall clock), whole from the start: ``stamp`` adds
+        # the replica's stamps, ``take`` the rest. ``close`` publishes it
+        # as ``record`` after ``on_record`` had it, which may add to it
+        # (the engine's clocks).
+        self._rec: Dict[str, Any] = dict(
+            received=None, started=None, first_put=None, last_put=None,
+            first_ack=None, last_ack=None, items=0, pulls=0, acked=0,
+            dwell_s_sum=0.0, dwell_s_max=0.0, deliver_s_sum=0.0,
+            deliver_s_max=0.0, first_deliver_s=None, blocked_s_sum=0.0)
+        self._unacked: Optional[float] = None  # oldest put of the
+        #   delivery that left last, until its acknowledgement comes
+        self.on_record: Optional[Callable[[Dict[str, Any]], None]] = None
+        self.record: Optional[Dict[str, Any]] = None  # set by close()
 
     @classmethod
     def pumping(cls, iterator, model_id: str = "",
@@ -164,8 +186,9 @@ class StreamQueue:
     # ------------------------------------------------------------ producer
 
     def put(self, item: Any) -> None:
+        now = time.time()
         with self._cond:
-            self._items.append(item)
+            self._items.append((item, now))
             self._cond.notify_all()
 
     def end(self, error: Optional[BaseException] = None) -> None:
@@ -203,49 +226,93 @@ class StreamQueue:
 
     # ------------------------------------------------------------ consumer
 
-    def take(self, max_items: int = 1) -> Tuple[List[Any], bool]:
+    def take(self, max_items: int = 1, acked_at: Optional[float] = None
+             ) -> Tuple[List[Any], bool]:
         """(items, done): block for the first item, then leave with up to
         ``max_items`` of what is here. ``done`` says nothing follows.
         Raises the producer's error once the items before it are taken,
-        ``RequestCancelledError`` if the stream is closed meanwhile."""
+        ``RequestCancelledError`` if the stream is closed meanwhile.
+        ``acked_at`` is when the consumer was done with the delivery
+        before this one (``time.time()`` on this host)."""
         with self._cond:
+            if acked_at is not None and self._unacked is not None:
+                self._acknowledge(acked_at)
             if self._source is not None:
                 source, self._source = self._source, None
                 self._room = max(1, max_items)
                 threading.Thread(target=self._pump, args=source,
                                  name="stream-pump", daemon=True).start()
+            waited_from = None
             while not (self._items or self._ended or self._closed):
+                if waited_from is None:
+                    waited_from = time.time()
                 if (not self._cond.wait(self.BACKSTOP_S)
                         and self.backstop is not None):
                     self.backstop()
             if self._closed:
                 raise RequestCancelledError("stream closed by its consumer")
-            items = [self._items.popleft()
-                     for _ in range(min(max_items, len(self._items)))]
+            stamped = [self._items.popleft()
+                       for _ in range(min(max_items, len(self._items)))]
             if self._room:
                 self._cond.notify_all()  # room again: wake the pump
             done = self._ended and not self._items
             if done and self._error is not None:
-                if not items:
+                if not stamped:
                     raise self._error
                 done = False  # the next take raises it
-            self.pulls.append(len(items))
-            return items, done
+            now = time.time()
+            rec = self._rec
+            if waited_from is not None:
+                rec["blocked_s_sum"] += now - waited_from
+            rec["pulls"] += 1
+            if stamped:
+                oldest = stamped[0][1]
+                if rec["first_put"] is None:
+                    rec["first_put"] = oldest
+                rec["last_put"] = stamped[-1][1]
+                rec["items"] += len(stamped)
+                self._unacked = oldest
+                dwell = now - oldest
+                rec["dwell_s_sum"] += dwell
+                rec["dwell_s_max"] = max(rec["dwell_s_max"], dwell)
+            return [item for item, _ in stamped], done
+
+    def _acknowledge(self, acked_at: float) -> None:
+        rec = self._rec
+        took = acked_at - self._unacked
+        self._unacked = None
+        rec["acked"] += 1
+        rec["deliver_s_sum"] += took
+        rec["deliver_s_max"] = max(rec["deliver_s_max"], took)
+        if rec["first_ack"] is None:
+            rec["first_ack"], rec["first_deliver_s"] = acked_at, took
+        rec["last_ack"] = acked_at
+
+    def stamp(self, **stamps: Optional[float]) -> None:
+        """Stamps of the record that only the queue's holder knows
+        (``received``, ``started``)."""
+        self._rec.update(stamps)
 
     def close(self) -> None:
         """The consumer is gone: wake a blocked ``take``, stop the pump
         (it closes its iterator from its own thread), tell the producer
-        through ``on_close``. Idempotent."""
+        through ``on_close``, close the record. Idempotent."""
         with self._cond:
             if self._closed:
                 return
             self._closed = True
             source, self._source = self._source, None
             self._cond.notify_all()
+            # Whole, or none for a stream that no delivery ever left
+            # (cancelled before its first item).
+            record = self._rec if self._rec["pulls"] else None
         if source is not None:  # never pumped: nothing else will close it
             _close_iterator(source[0])
         if self.on_close is not None:
             self.on_close()
+        if record is not None and self.on_record is not None:
+            self.on_record(record)
+        self.record = record
 
     def __iter__(self) -> "StreamQueue":
         return self
@@ -363,6 +430,7 @@ class ReplicaActor:
                      deadline_s: Optional[float] = None) -> str:
         import uuid
 
+        started = time.time()
         with self._lock:
             self._ongoing += 1
             self._total += 1
@@ -384,21 +452,27 @@ class ReplicaActor:
         finally:
             _current_model_id.value = ""
             _current_deadline.value = None
+        from ray_tpu.util import tracing
+
+        result.stamp(received=tracing.received(), started=started)
         sid = uuid.uuid4().hex[:16]
         self._streams[sid] = result
         return sid
 
-    def next_chunks(self, stream_id: str, max_items: int = 16):
+    def next_chunks(self, stream_id: str, max_items: int = 16,
+                    acked_at: Optional[float] = None):
         """One delivery: (items, done). Blocks for the stream's next item
         and returns it with whatever else is ready, at most ``max_items``;
         an error the producer ended with is raised once the items before
         it are delivered. The stream's ongoing slot frees when it is
-        done."""
+        done. ``acked_at``: when the caller's consumer was done with the
+        delivery before this one (``time.time()``; the router sends it,
+        the stream's record keeps it)."""
         stream = self._streams.get(stream_id)
         if stream is None:
             raise KeyError(f"unknown stream {stream_id}")
         try:
-            items, done = stream.take(max_items)
+            items, done = stream.take(max_items, acked_at)
         except BaseException:
             self.cancel_stream(stream_id)
             raise
@@ -423,12 +497,12 @@ class ReplicaActor:
             self._ongoing -= 1
         from ray_tpu.core.config import config as rt_config
 
-        if rt_config.serve_metrics_enabled and stream.pulls:
+        if rt_config.serve_metrics_enabled and stream.record is not None:
             from ray_tpu.serve import metrics as smetrics
 
-            # Once a stream, not once a pull: a pull costs one append.
-            smetrics.STREAM_PULL_ITEMS.observe_many(
-                stream.pulls, {"deployment": _replica_ident["deployment"]})
+            # Once a stream, from its closed record: never once a pull.
+            smetrics.observe_stream(
+                stream.record, {"deployment": _replica_ident["deployment"]})
 
     def set_topology(self, assignment: Dict[str, Any]) -> None:
         """Sub-slice assignment from the serve controller (which chips

@@ -10,7 +10,10 @@ the round's accepted-token count — and on a disaggregated decode fleet
 tagged with the page count) and batch occupancy, plus
 the discrete events that explain latency cliffs — page alloc/free,
 recompute preemption (with the prompt tokens and pages it throws
-away), jit compiles (first dispatch of a program key).
+away), jit compiles (first dispatch of a program key), and
+``stream-end``: a closed stream's record (``serve/replica.py``), which
+a pull's thread posts through ``DecodeEngine.post_event`` and the
+loop enters at its next step.
 
 Beside the phases a row carries ``slices``: named host intervals that
 TILE the step with no hole (``reap``, ``admit``, ``pages``, ``launch``,
@@ -150,7 +153,7 @@ class StepTimeline:
 
     # -------------------------------------------------------------- rows
 
-    def record(self, step: int, t0: float, t1: float, phases:
+    def record(self, t0: float, t1: float, phases:
                List[Dict[str, Any]], active: int, prefilling: int,
                queued: int, pages_free: Optional[int] = None,
                pages_pinned: Optional[int] = None,
@@ -166,7 +169,7 @@ class StepTimeline:
             return
         if len(self._rows) == self._rows.maxlen:
             self.dropped += 1
-        row = {"step": step, "t0": t0, "t1": t1, "phases": phases,
+        row = {"t0": t0, "t1": t1, "phases": phases,
                "active": active, "prefilling": prefilling,
                "queued": queued}
         if pages_free is not None:

@@ -2,12 +2,14 @@
 
 Runs a tiny serve session (debug-model decode deployment, two
 replicas, real HTTP proxy), issues traced requests through the proxy
-with the client's own span propagated via ``X-Trace-Id`` headers,
-merges the task-event spans with every replica's engine step timeline
-into one Chrome trace JSON, and VALIDATES it: the file must load as
-JSON and contain at least one cross-process parent/child span pair —
-the invariant that makes the trace causally linked rather than a pile
-of disconnected slices.
+(the last one streamed) with the client's own span propagated via
+``X-Trace-Id`` headers, merges the task-event spans with every
+replica's engine step timeline into one Chrome trace JSON, and
+VALIDATES it: the file must load as JSON and contain at least one
+cross-process parent/child span pair — the invariant that makes the
+trace causally linked rather than a pile of disconnected slices — and
+the streamed request's delivery: its ``stream`` span and the
+``stream-end`` event on its engine's row.
 
 Standalone::
 
@@ -45,6 +47,10 @@ def validate_trace(trace: List[Dict[str, Any]]) -> Dict[str, Any]:
         "span_pids": sorted({t["pid"] for t in spans}),
         "engine_slices": sum(1 for t in trace
                              if t.get("cat") == "engine-step"),
+        "stream_spans": sum(1 for t in spans if t["name"] == "stream"),
+        "stream_ends": sum(1 for t in trace
+                           if t.get("cat") == "engine-event"
+                           and t["name"] == "stream-end"),
         "cross_process_links": cross,
     }
 
@@ -72,17 +78,22 @@ def run_demo(output: Optional[str] = None, init: bool = True,
         host, port = serve.start_http()
         url = f"http://{host}:{port}/trace_demo"
         for i in range(requests):
+            streamed = i == requests - 1  # JSON lines, chunked
             with tracing.trace("client-request", i=i):
                 ctx = tracing.current()
+                headers = {"Content-Type": "application/json",
+                           "X-Trace-Id": ctx[0], "X-Parent-Span": ctx[1]}
+                if streamed:
+                    headers["X-Serve-Stream"] = "1"
                 req = urllib.request.Request(
                     url,
                     data=json.dumps({"tokens": [1, 2, 3, 4 + i],
-                                     "max_new_tokens": 4}).encode(),
-                    headers={"Content-Type": "application/json",
-                             "X-Trace-Id": ctx[0],
-                             "X-Parent-Span": ctx[1]})
+                                     "max_new_tokens": 4,
+                                     "stream": streamed}).encode(),
+                    headers=headers)
                 with urllib.request.urlopen(req, timeout=60) as resp:
-                    json.loads(resp.read())
+                    for line in resp.read().splitlines():
+                        json.loads(line)
         core = get_core_worker()
         # Spans flush on each process's own cadence; poll until the
         # trace validates (or the deadline names what's missing).
@@ -96,7 +107,8 @@ def run_demo(output: Optional[str] = None, init: bool = True,
             report = validate_trace(trace)
             if (len(report["span_pids"]) >= 3
                     and report["cross_process_links"]
-                    and report["engine_slices"] >= 1):
+                    and report["engine_slices"] >= 1
+                    and report["stream_spans"] and report["stream_ends"]):
                 break
             time.sleep(0.3)
         if output:
@@ -114,6 +126,10 @@ def run_demo(output: Optional[str] = None, init: bool = True,
                 "no cross-process parent/child span pair in the trace")
         if report.get("engine_slices", 0) < 1:
             raise AssertionError("no engine step-timeline slices merged")
+        if not (report.get("stream_spans") and report.get("stream_ends")):
+            raise AssertionError(
+                "the streamed request left no `stream` span or no "
+                "`stream-end` event on its engine's row")
         return report
     finally:
         try:
@@ -135,7 +151,9 @@ def main(argv=None) -> int:
     print(f"trace OK: {report['spans']} spans across "
           f"{len(report['span_pids'])} processes, "
           f"{len(report['cross_process_links'])} cross-process links, "
-          f"{report['engine_slices']} engine slices -> {args.output}")
+          f"{report['engine_slices']} engine slices, "
+          f"{report['stream_spans']} stream span(s), "
+          f"{report['stream_ends']} stream-end event(s) -> {args.output}")
     return 0
 
 

@@ -29,14 +29,29 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, Optional
 
-# (trace_id_hex, span_id_hex) of the active span, or None.
+# (trace_id_hex, span_id_hex, received) of the active span, or None.
+# ``received`` is the wall-clock start of the first span the request's
+# calling process opened (the proxy's ``http:<route>``); it rides with the
+# context into every task spec, so a replica can time a request from where
+# it entered the system. A context built from a client's headers has only
+# the first two.
 _ctx: contextvars.ContextVar[Optional[tuple]] = contextvars.ContextVar(
     "ray_tpu_trace", default=None)
 
 
 def current() -> Optional[tuple]:
-    """(trace_id, span_id) of the active span, if any."""
+    """(trace_id, span_id, received) of the active span, if any."""
     return _ctx.get()
+
+
+def received() -> Optional[float]:
+    """When the active trace's root span started in the process that
+    opened it (``time.time()``), or None without a trace."""
+    return _received_of(_ctx.get())
+
+
+def _received_of(ctx: Optional[tuple]) -> Optional[float]:
+    return ctx[2] if ctx is not None and len(ctx) > 2 else None
 
 
 def traced() -> bool:
@@ -52,16 +67,19 @@ def _new_id() -> str:
     return os.urandom(8).hex()
 
 
-def context_for_spec() -> Optional[Dict[str, str]]:
+def context_for_spec() -> Optional[Dict[str, Any]]:
     """Serializable span context to embed in an outgoing task spec."""
     cur = _ctx.get()
     if cur is None:
         return None
-    return {"trace_id": cur[0], "parent_span": cur[1]}
+    out = {"trace_id": cur[0], "parent_span": cur[1]}
+    if _received_of(cur) is not None:
+        out["received"] = cur[2]
+    return out
 
 
 @contextmanager
-def activate(spec_ctx: Optional[Dict[str, str]], name: Optional[str] = None):
+def activate(spec_ctx: Optional[Dict[str, Any]], name: Optional[str] = None):
     """Worker-side: enter the caller's trace (new child span) for the
     duration of a task's execution. With ``name``, the execution itself
     is recorded as a SPAN parented under the caller's span — the link
@@ -71,7 +89,8 @@ def activate(spec_ctx: Optional[Dict[str, str]], name: Optional[str] = None):
         yield
         return
     span_id = _new_id()
-    token = _ctx.set((spec_ctx["trace_id"], span_id))
+    token = _ctx.set((spec_ctx["trace_id"], span_id,
+                      spec_ctx.get("received")))
     start = time.time()
     try:
         yield
@@ -141,8 +160,9 @@ def trace(name: str, **attrs: Any):
     parent = _ctx.get()
     trace_id = parent[0] if parent else _new_id()
     span_id = _new_id()
-    token = _ctx.set((trace_id, span_id))
     start = time.time()
+    root = _received_of(parent)
+    token = _ctx.set((trace_id, span_id, start if root is None else root))
     try:
         yield (trace_id, span_id)
     finally:

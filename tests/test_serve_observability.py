@@ -292,7 +292,7 @@ def test_step_timeline_ring_bounded_with_phases_and_compiles():
     phases = {p["phase"] for row in tl["rows"] for p in row["phases"]}
     assert "decode" in phases
     row = tl["rows"][-1]
-    assert {"step", "t0", "t1", "active", "prefilling",
+    assert {"t0", "t1", "phases", "active", "prefilling",
             "queued"} <= set(row)
     eng.shutdown()
     # jit-compile events fired for first dispatches (admit ran inside
@@ -587,7 +587,7 @@ def test_build_chrome_trace_links_and_engine_merge():
          "worker": "wb"},
     ]
     timelines = {"dep": {"dep#0": {"rows": [
-        {"step": 1, "t0": t0, "t1": t0 + 0.01,
+        {"t0": t0, "t1": t0 + 0.01,
          "phases": [{"phase": "decode", "t0": t0, "t1": t0 + 0.01,
                      "batch": 2, "k": 1}],
          "active": 2, "prefilling": 0, "queued": 0,

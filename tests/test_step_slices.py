@@ -137,7 +137,7 @@ def test_phases_are_what_they_were(name):
                 assert ph["t0"] <= run[0]["t0"] and run[1]["t1"] <= ph["t1"]
     assert kinds == ({"admit", "decode", "prefill_chunk"}
                      if name == "chunked_prefill" else {"admit", "decode"})
-    assert all(set(r) >= {"step", "t0", "t1", "phases", "active",
+    assert all(set(r) >= {"t0", "t1", "phases", "slices", "active",
                           "prefilling", "queued", "pages_free"}
                for r in rows)
 
@@ -553,7 +553,7 @@ def test_slices_cost_under_50_us_a_step():
             sl.begin("sample_emit")
             sl.begin("finish")
             sl.begin("sample_emit")
-        sl.record(1, t0, time.time(), phases, active=32, prefilling=0,
+        sl.record(t0, time.time(), phases, active=32, prefilling=0,
                   queued=0, pages_free=3, pages_pinned=80, ctx_tokens=9000)
 
     def per_step_us(sliced, n=3000):

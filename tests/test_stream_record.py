@@ -1,0 +1,491 @@
+"""A streamed request is timed where it passes (ISSUE 39).
+
+One record a stream, kept by its ``StreamQueue`` and closed once: the
+stamps of the proxy's receive, the replica's ``start_stream``, the engine's
+submit and admission, the queue's puts and the consumer's acknowledgements
+(which ride on the next pull), all on ``time.time()``. It goes to the step
+log as a ``stream-end`` event, to the request's trace as the span
+``stream`` and to two series. First in one process, where the queue can be
+looked at; then through ``serve.run`` and the HTTP proxy, where the stamps
+come from two processes.
+"""
+
+import json
+import threading
+import time
+import urllib.request
+
+import pytest
+
+import ray_tpu
+from ray_tpu import serve
+from ray_tpu.core import serialization
+from ray_tpu.core.errors import OverloadedError, RequestCancelledError
+from ray_tpu.core.runtime import get_core_worker
+from ray_tpu.serve.decode import LlamaDecodeDeployment
+from ray_tpu.serve.replica import ReplicaActor, StreamQueue
+from ray_tpu.util import metrics as um
+from ray_tpu.util import tracing
+
+PROMPT = 5.0
+STEP_S = 0.02
+
+FIELDS = {"received", "started", "first_put", "last_put", "first_ack",
+          "last_ack", "items", "pulls", "acked", "dwell_s_sum",
+          "dwell_s_max", "deliver_s_sum", "deliver_s_max",
+          "first_deliver_s", "blocked_s_sum"}
+ENGINE_FIELDS = FIELDS | {"request", "outcome", "submitted", "admitted"}
+
+
+def _tiny_cfg():
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig(vocab_size=61, dim=32, n_layers=2, n_heads=4,
+                             n_kv_heads=2, mlp_dim=64, max_seq_len=128)
+
+
+class _Paced(LlamaDecodeDeployment):
+    """The tiny engine with a decode step of ``step_s`` (0 = as fast as
+    the CPU steps it, which is faster than a pull comes back round)."""
+
+    def __init__(self, step_s=STEP_S, **kwargs):
+        super().__init__(config=_tiny_cfg(), capacity=64,
+                         prefix_pool_entries=0, kv_page_tokens=8,
+                         warmup=False, **kwargs)
+        inner = self.engine._decode
+
+        def paced(*a, **k):
+            if step_s:
+                time.sleep(step_s)
+            return inner(*a, **k)
+
+        self.engine._decode = paced
+
+
+class _Count:
+    def __call__(self, n):
+        yield from range(n)
+
+
+def _replica(cls, *args, **kwargs) -> ReplicaActor:
+    return ReplicaActor(serialization.dumps_function(cls), args, kwargs)
+
+
+def _request(n, **extra):
+    return dict({"tokens": [5, 9, 2], "max_new_tokens": n}, **extra)
+
+
+def _pull_like_the_router(rep, sid, max_items=16):
+    """What ``_Router._stream_plain`` does: every pull after the first
+    says when the delivery before it was done with."""
+    got, acked_at, done = [], None, False
+    while not done:
+        items, done = rep.next_chunks(sid, max_items, acked_at)
+        got.extend(items)
+        acked_at = time.time()
+    return got
+
+
+def _stream_ends(dump, request_id=None):
+    return [e for row in dump["rows"] for e in row.get("events", [])
+            if e["kind"] == "stream-end"
+            and request_id in (None, e["request"])]
+
+
+def _wait_for(fn, timeout=PROMPT):
+    deadline = time.monotonic() + timeout
+    while True:
+        out = fn()
+        if out or time.monotonic() > deadline:
+            return out
+        time.sleep(0.01)
+
+
+def _in_order(rec, names):
+    stamps = [rec[n] for n in names]
+    assert all(s is not None for s in stamps), dict(zip(names, stamps))
+    assert stamps == sorted(stamps), dict(zip(names, stamps))
+
+
+def _histogram(name, deployment=""):
+    for m in um._Registry.get().snapshot(run_collectors=False):
+        if (m["name"] == name
+                and m.get("tags", {}).get("deployment", "") == deployment):
+            return m
+    return {"count": 0, "sum": 0.0}
+
+
+# ------------------------------------------------ the queue's bookkeeping
+
+
+def test_a_record_adds_up_what_each_delivery_took():
+    q = StreamQueue()
+    q.put("a")
+    time.sleep(0.02)
+    q.put("b")
+    items, done = q.take(16)            # one delivery of two, none waited
+    assert items == ["a", "b"] and not done
+    time.sleep(0.03)
+    took = threading.Timer(0.05, q.put, args=("c",))
+    took.start()
+    items, done = q.take(16, acked_at=time.time())  # blocks ~50 ms for c
+    assert items == ["c"] and not done
+    q.end()
+    items, done = q.take(16, acked_at=time.time())
+    assert items == [] and done
+    q.close()
+    rec = q.record
+    assert set(rec) == FIELDS
+    assert (rec["items"], rec["pulls"], rec["acked"]) == (3, 3, 2)
+    _in_order(rec, ["first_put", "last_put", "last_ack"])
+    _in_order(rec, ["first_put", "first_ack", "last_ack"])
+    # The first delivery's oldest item lay there 20 ms and was done with
+    # 30 ms later; the second was taken the moment it was put.
+    assert 0.02 <= rec["dwell_s_max"] < 0.05
+    assert rec["dwell_s_sum"] >= rec["dwell_s_max"]
+    assert 0.05 <= rec["first_deliver_s"] == rec["deliver_s_max"] < 0.2
+    assert rec["deliver_s_sum"] - rec["first_deliver_s"] < 0.02
+    assert 0.03 <= rec["blocked_s_sum"] < 0.2
+    assert rec["received"] is None and rec["started"] is None
+
+
+def test_close_hands_the_record_over_once():
+    seen = []
+    q = StreamQueue()
+    q.on_record = seen.append
+    q.put(1)
+    assert q.take(1) == ([1], False)
+    q.close()
+    q.close()
+    assert len(seen) == 1 and seen[0] is q.record
+    q.record["request"] = "added by the hook's owner"  # theirs to add to
+
+
+@pytest.mark.parametrize("how", ["never pulled", "closed while it waits"])
+def test_a_stream_no_delivery_left_has_no_record(how):
+    seen = []
+    q = StreamQueue()
+    q.on_record = seen.append
+    if how == "closed while it waits":
+        threading.Timer(0.05, q.close).start()
+        with pytest.raises(RequestCancelledError):
+            q.take(1)
+    else:
+        q.put(1)
+    q.close()
+    assert q.record is None and seen == []
+
+
+def test_bookkeeping_costs_under_two_microseconds_an_item():
+    """put + take + acknowledgement, against the same queue without the
+    stamps and sums (the parent's ``put`` and ``take``), one thread."""
+
+    class Bare(StreamQueue):
+        def put(self, item):
+            with self._cond:
+                self._items.append(item)
+                self._cond.notify_all()
+
+        def take(self, max_items=1, acked_at=None):
+            with self._cond:
+                items = [self._items.popleft() for _ in range(
+                    min(max_items, len(self._items)))]
+                return items, self._ended and not self._items
+
+    def per_item_us(cls, n=20000):
+        best = float("inf")
+        for _ in range(7):
+            q = cls()
+            acked_at = None
+            a = time.perf_counter()
+            for i in range(n):
+                q.put(i)
+                q.take(16, acked_at)
+                acked_at = a  # a float, as the router's
+            best = min(best, (time.perf_counter() - a) / n * 1e6)
+        return best
+
+    extra = per_item_us(StreamQueue) - per_item_us(Bare)
+    assert extra < 2.0, f"{extra:.2f} us an item"
+
+
+# ------------------------------------------------- the trace's context
+
+
+def test_the_roots_start_rides_with_the_context():
+    assert tracing.received() is None
+    t0 = time.time()
+    with tracing.trace("root"):
+        root = tracing.received()
+        assert t0 <= root <= time.time()
+        time.sleep(0.01)
+        with tracing.trace("child"):
+            assert tracing.received() == root   # not the child's start
+            spec = tracing.context_for_spec()
+            held = tracing.current()
+    assert spec["received"] == root and tracing.received() is None
+    with tracing.activate(spec):                # the executing worker
+        assert tracing.received() == root
+        assert tracing.current()[0] == spec["trace_id"]
+    with tracing.resume(held):                  # a router's pool thread
+        assert tracing.received() == root
+    # A client's headers name a trace and a span, no more: the first span
+    # opened under them is where this process received the request.
+    with tracing.resume(("t" * 16, "s" * 16)):
+        assert tracing.received() is None
+        with tracing.trace("http:/x"):
+            assert tracing.received() >= root + 0.01
+    with tracing.activate({"trace_id": "t", "parent_span": "s"}):
+        assert tracing.received() is None
+
+
+# --------------------------------------------- a replica in this process
+
+
+@pytest.fixture
+def paced_replica():
+    rep = _replica(_Paced, slots=1, queue_max=1)
+    yield rep
+    rep._instance.engine.shutdown()
+
+
+def test_the_record_reaches_a_row_though_the_engine_parks(paced_replica):
+    rep = paced_replica
+    engine = rep._instance.engine
+    before = _histogram("serve_stream_delivery_s")
+    with tracing.trace("caller") as _:
+        received = tracing.received()
+        sid = rep.start_stream("stream", (_request(6, request_id="r1"),), {})
+    stream = rep._streams[sid]
+    got = _pull_like_the_router(rep, sid)
+    assert len(got) == 6 and not rep._streams
+    # Nothing is seated or queued now: only the posted record wakes the
+    # loop, and its step records a row that holds nothing else.
+    ends = _wait_for(lambda: _stream_ends(engine.timeline(), "r1"))
+    assert len(ends) == 1, engine.timeline()["rows"][-3:]
+    rec = ends[0]
+    assert set(rec) == ENGINE_FIELDS | {"kind", "ts"}
+    assert {k: rec[k] for k in FIELDS} == {
+        k: stream.record[k] for k in FIELDS}
+    assert rec["received"] == received and rec["outcome"] == "completed"
+    _in_order(rec, ["received", "started", "submitted", "admitted",
+                    "first_put", "first_ack", "last_ack", "ts"])
+    assert rec["items"] == 6 and rec["acked"] == rec["pulls"] - 1
+    assert rec["items"] <= rec["pulls"] <= 7  # a 20 ms step: one a pull
+    assert rec["blocked_s_sum"] > 3 * STEP_S  # the engine was the slow side
+    after = _histogram("serve_stream_delivery_s")
+    assert after["count"] - before["count"] == rec["acked"]
+    assert after["sum"] - before["sum"] == pytest.approx(
+        rec["deliver_s_sum"])
+    time.sleep(0.1)
+    assert len(_stream_ends(engine.timeline())) == 1  # entered once
+
+
+def test_pulls_that_say_nothing_behave_as_before(paced_replica):
+    rep = paced_replica
+    before = _histogram("serve_ingress_s")
+    sid = rep.start_stream("stream", (_request(5, request_id="r2"),), {})
+    got, done = [], False
+    while not done:
+        items, done = rep.next_chunks(sid)   # the signature of PR 26
+        got.extend(items)
+    assert len(got) == 5 and rep._ongoing == 0
+    rec = _wait_for(lambda: _stream_ends(
+        rep._instance.engine.timeline(), "r2"))[0]
+    assert rec["items"] == 5 and rec["acked"] == 0
+    assert rec["first_ack"] is None and rec["last_ack"] is None
+    assert rec["first_deliver_s"] is None and rec["deliver_s_sum"] == 0.0
+    assert rec["received"] is None          # no trace around the call
+    _in_order(rec, ["started", "submitted", "admitted", "first_put",
+                    "last_put"])
+    assert _histogram("serve_ingress_s")["count"] == before["count"]
+
+
+def test_a_hung_up_stream_leaves_a_whole_record(paced_replica):
+    rep = paced_replica
+    sid = rep.start_stream("stream", (_request(50, request_id="r3"),), {})
+    items, done = rep.next_chunks(sid, 16)
+    items2, done2 = rep.next_chunks(sid, 16, time.time())
+    assert items and items2 and not done and not done2
+    rep.cancel_stream(sid)                  # the router's finally
+    rec = _wait_for(lambda: _stream_ends(
+        rep._instance.engine.timeline(), "r3"))[0]
+    assert set(rec) == ENGINE_FIELDS | {"kind", "ts"}
+    assert rec["outcome"] == "cancelled"
+    assert rec["items"] == len(items) + len(items2)
+    assert (rec["pulls"], rec["acked"]) == (2, 1)
+    _in_order(rec, ["started", "submitted", "admitted", "first_put",
+                    "first_ack"])
+
+
+def test_a_shed_and_an_unserved_stream_leave_none(paced_replica):
+    rep = paced_replica
+    engine = rep._instance.engine
+    hog = rep.start_stream("stream", (_request(50, request_id="hog"),), {})
+    rep.next_chunks(hog)                    # seated: the one slot is taken
+    queued = rep.start_stream(
+        "stream", (_request(4, request_id="queued"),), {})
+    with pytest.raises(OverloadedError):    # queue_max=1: no stream exists
+        rep.start_stream("stream", (_request(4, request_id="shed"),), {})
+    rep.cancel_stream(queued)               # before its first token
+    rep.cancel_stream(hog)
+    assert _wait_for(lambda: _stream_ends(engine.timeline(), "hog"))
+    assert {e["request"] for e in _stream_ends(engine.timeline())} == {"hog"}
+    assert rep._ongoing == 0 and not rep._streams
+
+
+def test_a_pumped_generator_keeps_the_same_record():
+    """No engine behind it: no step log and no ``submitted``, the queue's
+    part of the record and the two series all the same."""
+    before = _histogram("serve_stream_pull_items")
+    rep = _replica(_Count)
+    sid = rep.start_stream("__call__", (1000,), {})
+    stream = rep._streams[sid]
+    got = _pull_like_the_router(rep, sid)
+    assert got == list(range(1000))
+    rec = stream.record
+    assert set(rec) == FIELDS
+    assert rec["items"] == 1000 and rec["acked"] == rec["pulls"] - 1
+    assert rec["pulls"] <= 1000 // 16 + 8
+    _in_order(rec, ["started", "first_put", "first_ack", "last_ack"])
+    after = _histogram("serve_stream_pull_items")
+    assert after["count"] - before["count"] == rec["pulls"]
+    assert after["sum"] - before["sum"] == pytest.approx(1000)
+
+
+# ------------------------------------- two processes: proxy and replica
+
+
+@pytest.fixture(scope="module")
+def served():
+    ray_tpu.init(num_cpus=6)
+    try:
+        serve.run(serve.deployment(_Paced).bind(step_s=STEP_S, slots=2),
+                  name="slow")
+        serve.run(serve.deployment(_Paced).bind(step_s=0.0, slots=2),
+                  name="fast")
+        host, port = serve.start_http()
+        for name in ("slow", "fast"):       # compiles, route table
+            _post_stream(host, port, name, _request(3, request_id="warm"))
+        yield host, port
+    finally:
+        try:
+            serve.shutdown()
+        finally:
+            ray_tpu.shutdown()
+
+
+def _post_stream(host, port, route, payload):
+    req = urllib.request.Request(
+        f"http://{host}:{port}/{route}",
+        data=json.dumps(dict(payload, stream=True)).encode(),
+        headers={"X-Serve-Stream": "1"})
+    with urllib.request.urlopen(req, timeout=60) as resp:
+        return [json.loads(line) for line in resp if line.strip()]
+
+
+def _record_of(name, request_id):
+    handle = serve.get_deployment_handle(name)
+    ends = _wait_for(
+        lambda: _stream_ends(handle.timeline.remote().result(timeout=30),
+                             request_id), timeout=15.0)
+    assert len(ends) == 1, ends
+    return ends[0]
+
+
+@pytest.mark.timeout_s(300)
+@pytest.mark.parametrize("name,tokens", [("slow", 8), ("fast", 40)])
+def test_stamps_of_two_processes_are_in_order(served, name, tokens):
+    host, port = served
+    t0 = time.time()
+    lines = _post_stream(host, port, name,
+                         _request(tokens, request_id=f"{name}-1"))
+    t1 = time.time()
+    assert len(lines) == tokens and all(isinstance(x, int) for x in lines)
+    rec = _record_of(name, f"{name}-1")
+    assert rec["outcome"] == "completed"
+    # ``received`` is the proxy's (the start of its http span), ``started``
+    # and all after it the replica's, the acknowledgements the proxy's
+    # again: one host, one clock.
+    _in_order(rec, ["received", "started", "submitted", "admitted",
+                    "first_put", "first_ack", "last_ack"])
+    assert t0 <= rec["received"] and rec["last_ack"] <= t1
+    assert rec["items"] == tokens           # the tokens served
+    assert rec["acked"] == rec["pulls"] - 1
+    assert rec["first_put"] <= rec["last_put"]
+    assert 0 < rec["first_deliver_s"] <= rec["deliver_s_max"] < 5.0
+    assert rec["deliver_s_sum"] >= rec["deliver_s_max"]
+    if name == "slow":
+        assert rec["pulls"] >= tokens       # a token travels alone
+        assert rec["blocked_s_sum"] > 3 * STEP_S
+    else:
+        assert rec["pulls"] < tokens        # the engine is ahead of a pull
+    # The program's own time to the first token lies inside the client's.
+    assert rec["first_ack"] - rec["received"] < t1 - t0
+
+
+@pytest.mark.timeout_s(300)
+def test_the_trace_shows_delivery_and_the_proxy_serves_the_series(served):
+    host, port = served
+    with tracing.trace("client") as (trace_id, span_id):
+        req = urllib.request.Request(
+            f"http://{host}:{port}/slow",
+            data=json.dumps(_request(6, request_id="traced",
+                                     stream=True)).encode(),
+            headers={"X-Serve-Stream": "1", "X-Trace-Id": trace_id,
+                     "X-Parent-Span": span_id})
+        with urllib.request.urlopen(req, timeout=60) as resp:
+            assert len([ln for ln in resp if ln.strip()]) == 6
+    rec = _record_of("slow", "traced")
+    core = get_core_worker()
+
+    def spans():
+        events = core.controller.call("list_task_events", 10000)
+        mine = {e["desc"]: e for e in events if e.get("state") == "SPAN"
+                and e.get("trace_id") == trace_id}
+        return mine if {"stream", "decode", "http:/slow"} <= set(mine) \
+            else None
+
+    mine = _wait_for(spans, timeout=30.0)
+    assert mine, "no stream span under the client's trace"
+    stream = mine["stream"]
+    assert stream["lease_ts"] == rec["first_put"]
+    assert stream["end_ts"] == rec["last_ack"]
+    assert stream["attrs"]["items"] == 6
+    assert stream["attrs"]["pulls"] == rec["pulls"]
+    assert stream["attrs"]["deliver_s_max"] == rec["deliver_s_max"]
+    # Beside ``decode``: both hang under the span the engine captured.
+    assert stream["parent_span"] == mine["decode"]["parent_span"]
+    # The client's span is the trace's root, the proxy's is where THIS
+    # system received the request.
+    assert rec["received"] == mine["http:/slow"]["lease_ts"]
+
+    # ``ray_tpu timeline --serve`` draws both: the span in the request's
+    # tree, the record as an instant on the engine's row.
+    from ray_tpu.scripts import build_chrome_trace
+
+    drawn = build_chrome_trace(
+        core.controller.call("list_task_events", 10000), serve.timelines())
+    assert [t["args"]["parent_span"] for t in drawn
+            if t.get("cat") == "span" and t["name"] == "stream"
+            and t["args"]["trace_id"] == trace_id] == [stream["parent_span"]]
+    ends = [t for t in drawn if t.get("cat") == "engine-event"
+            and t["name"] == "stream-end"
+            and t["args"]["request"] == "traced"]
+    assert len(ends) == 1 and ends[0]["pid"].startswith("engine:slow")
+    assert ends[0]["args"]["items"] == 6
+
+    def text():
+        with urllib.request.urlopen(f"http://{host}:{port}/metrics",
+                                    timeout=30) as resp:
+            lines = resp.read().decode().splitlines()
+        return all(any(ln.startswith(f"{series}_count")
+                       and 'deployment="slow"' in ln for ln in lines)
+                   for series in ("serve_stream_delivery_s",
+                                  "serve_ingress_s",
+                                  "serve_stream_pull_items"))
+
+    assert _wait_for(text, timeout=30.0), "the series are not on /metrics"
+    slo = _wait_for(lambda: serve.status()["slow"].get("slo", {}).get(
+        "ingress_s"), timeout=30.0)
+    assert slo and slo["count"] >= 1 and slo["mean"] < 5.0

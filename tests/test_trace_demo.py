@@ -37,6 +37,10 @@ def test_trace_demo_emits_causally_linked_trace(ray_start_regular,
     assert any(n.startswith("router:") for n in names), names
     assert "attempt" in names
     assert {"queue-wait", "decode", "engine-request"} <= names, names
+    # The last request is streamed: its delivery shows beside ``decode``
+    # and its record on the engine's row (PR 39).
+    assert "stream" in names and "stream-attempt" in names, names
+    assert report["stream_spans"] == 1 and report["stream_ends"] == 1
     # Cross-process causality includes the proxy->replica hop.
     assert any(child.startswith("actor:")
                or parent.startswith("attempt")
