@@ -12,7 +12,7 @@ be written in place); as K and V per head the same tokens would take 57
 times the room. Pages, block tables and the scratch page 0 are those of
 ``llama_decode`` (a page is a page: the allocator and the prefix index
 never look inside one); the decode step's list of live pages is laid out
-in groups of one slot's pages (``live_page_view`` below).
+in groups of one slot's pages (``moe_decode.live_page_view``).
 
 One attention, two formulations of the same mathematics:
 
@@ -44,9 +44,15 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ray_tpu.models.deepseek import NORM_LEAVES, DeepseekConfig
+# Shared with ``mimo_decode``, and part of what this module provides: the
+# decode's view in groups of one slot's pages (``live_page_view``,
+# ``view_rows``), the cast of the weights, the expert layers' counters.
+from ray_tpu.models.moe_decode import (MOE_STEP_STATS,  # noqa: F401
+                                       VIEW_GROUP, cast_weights,
+                                       live_page_view, moe_step_stats,
+                                       view_rows)
 # Shared with every model the engine runs, and part of what this module
 # provides: the prefill buckets and the fused sampler.
 from ray_tpu.models.llama_decode import (cache_bucket,  # noqa: F401
@@ -59,10 +65,9 @@ from ray_tpu.parallel.sharding import constrain
 
 Pool = Dict[str, jax.Array]
 
-VIEW_GROUP = 16   # pages of ONE slot that a decode step scores together
 # What ``paged_decode_step`` counts beside its logits, summed over the
 # expert layers (the step log's ``launch`` slice carries them).
-STEP_STATS = ("moe_pairs", "moe_experts_hit", "moe_max_load")
+STEP_STATS = MOE_STEP_STATS
 
 
 def compute_weights(params: Dict[str, Any], config: DeepseekConfig,
@@ -71,19 +76,7 @@ def compute_weights(params: Dict[str, Any], config: DeepseekConfig,
     stay float32). ``deepseek.init_params`` already makes them so, and a
     leaf in that dtype is passed through; ``donate`` deletes a converted
     leaf's source as soon as its copy exists."""
-    dtype = jnp.dtype(config.dtype)
-
-    def held(path, w):
-        name = str(getattr(path[-1], "key", path[-1]))
-        if name in NORM_LEAVES or w.dtype == dtype:
-            return w
-        out = jnp.asarray(w, dtype=dtype)
-        if donate:
-            out.block_until_ready()
-            w.delete()
-        return out
-
-    return jax.tree_util.tree_map_with_path(held, params)
+    return cast_weights(params, config.dtype, NORM_LEAVES, donate)
 
 
 def init_page_pool(config: DeepseekConfig, pages: int, page_tokens: int,
@@ -177,12 +170,6 @@ def _moe_ffn(layer, x, c: DeepseekConfig, keep):
     return x + routed.reshape(shape) + shared, sizes
 
 
-def _stats(sizes: jax.Array) -> jax.Array:
-    """One expert layer's ``STEP_STATS`` from its ``sizes``."""
-    return jnp.stack([sizes.sum(), (sizes > 0).sum(),
-                      sizes.max()]).astype(jnp.float32)
-
-
 def _scan_layers(body, x, params: Dict[str, Any], pool: Pool,
                  c: DeepseekConfig):
     """The two layer loops of a forward with the pool in their CARRY
@@ -228,46 +215,6 @@ def _head(params, x, c: DeepseekConfig):
     x = _normed(x, params["final_norm"], c)
     return jnp.einsum("be,ev->bv", x, params["lm_head"],
                       preferred_element_type=jnp.float32)
-
-
-# ------------------------------------------------------- the decode's view
-
-
-def view_rows(counts) -> int:
-    """Rows ``live_page_view`` needs for these page counts: each slot's
-    pages rounded up to whole groups. The engine picks the rung from
-    it."""
-    counts = np.asarray(counts)
-    return int((-(-counts // VIEW_GROUP) * VIEW_GROUP).sum())
-
-
-def live_page_view(block_tables, counts, rows: int):
-    """``llama_decode.live_page_view`` with every slot's rows padded up to
-    a multiple of ``VIEW_GROUP``: ``(3, rows)`` int32, a row ``(pool page,
-    owning slot, index of the page in the slot's sequence)``. A row that
-    pads a slot's last group is the scratch page under the slot's own
-    name at an index past its pages, so the position mask hides it whole;
-    the rows past the list are the scratch page, owned by slot -1. Every
-    aligned group of ``VIEW_GROUP`` rows so has ONE owner, and the decode
-    step can score a group against one slot's queries and add it up as
-    one matmul, with no per-row partial output."""
-    tables = np.asarray(block_tables)
-    counts = np.asarray(counts)
-    padded = -(-counts // VIEW_GROUP) * VIEW_GROUP
-    width = max(int(padded.max(initial=0)), 1)
-    slot, index = np.nonzero(np.arange(width)[None, :] < padded[:, None])
-    n = len(slot)
-    if n > rows:
-        raise ValueError(f"{n} rows of live pages do not fit a view of "
-                         f"{rows}")
-    view = np.zeros((3, rows), np.int32)
-    view[1] = -1
-    real = index < counts[slot]
-    view[0, :n] = np.where(
-        real, tables[slot, np.minimum(index, tables.shape[1] - 1)], 0)
-    view[1, :n] = slot
-    view[2, :n] = index
-    return view
 
 
 # ------------------------------------------------------------------ prefill
@@ -332,7 +279,7 @@ def paged_prefill_suffix(params: Dict[str, Any], tokens: jax.Array,
         if not moe_layer:
             return _dense_ffn(layer, x, c), flat, 0.0
         x, sizes = _moe_ffn(layer, x, c, keep)
-        return x, flat, _stats(sizes)
+        return x, flat, moe_step_stats(sizes)
 
     x, pool, _ = _scan_layers(body, x, params, pool, c)
     idx = jnp.clip(lengths - prefix_lens - 1, 0, S - 1)
@@ -451,7 +398,7 @@ def paged_decode_step(params: Dict[str, Any], pool: Pool, view: jax.Array,
         if not moe_layer:
             return _dense_ffn(layer, x, c), flat, 0.0
         x, sizes = _moe_ffn(layer, x, c, steps[:, None])
-        return x, flat, _stats(sizes)
+        return x, flat, moe_step_stats(sizes)
 
     x, pool, stats = _scan_layers(body, x, params, pool, c)
     return _head(params, x[:, 0], c), pool, pos + 1, stats

@@ -1,0 +1,202 @@
+"""Pallas TPU kernel for a CHUNK's attention over plain (not latent) paged
+keys and values, as ``models/mimo_decode.py`` prefills: grouped-query
+heads, keys wider than values (192 against 128), queries at a traced
+offset, and two static variants of one body:
+
+* **full**: causal over every key; the tiles above a query tile's
+  frontier are skipped and their fetch is not issued (the block index is
+  clamped to the last live one), as in ``ops/latent_attention.py``;
+* **window**: a query at position ``i`` sees the keys ``j`` with ``0 <= i -
+  j < window``. The key axis of the grid is then RELATIVE: a query tile
+  visits the ``ceil((block_q + window - 1) / block_k) + 1`` key tiles from
+  its first live one on, and not every tile of the row's keys (at 2,048
+  queries over 2,304 keys that is 3 steps a query tile where the absolute
+  axis has 9, most of them dead).
+
+Both take a **sink**: one learned logit a head that joins the softmax's
+denominator and takes no value, ``p_ij = exp(a_ij - m) / (exp(s_h - m) +
+sum_j exp(a_ij - m))``. The running softmax starts from it (``m = s_h``,
+``l = 1``, nothing accumulated); a head without one starts from ``-1e30``,
+which the first live tile's correction wipes out.
+
+The keys of row ``b`` start at absolute position ``k_offsets[b]`` (a window
+layer hands in the few pages round its chunk, not the sequence from 0) and
+its queries at ``q_offsets[b]``; both are scalar-prefetched, so one
+compiled program serves every prefix.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NO_SINK = -1e30
+_LANE = 128
+BLOCK_Q = 512
+BLOCK_K = 1024
+# The window variant's tiles: a tile of 256 queries has 383 live keys.
+WINDOW_BLOCK = 256
+
+
+def _first_tile(q0, k0, window, block_k):
+    """The first key tile a query tile starting at ``q0`` can see, of keys
+    that start at position ``k0``."""
+    if window is None:
+        return 0
+    return jnp.maximum(q0 - (window - 1) - k0, 0) // block_k
+
+
+def _kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
+            acc_ref, m_ref, l_ref, *, scale, block_q, block_k, window,
+            key_tiles):
+    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    q0 = qoff_ref[b] + i * block_q
+    k0 = koff_ref[b]
+    tile = _first_tile(q0, k0, window, block_k) + j
+    c0 = k0 + tile * block_k
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.broadcast_to(sink_ref[0], m_ref.shape)
+        l_ref[...] = jnp.ones_like(l_ref)
+
+    live = (tile < key_tiles) & (q0 + block_q - 1 >= c0)
+    if window is not None:
+        live &= c0 + block_k - 1 > q0 - window
+
+    @pl.when(live)
+    def _tile():
+        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        rows = q0 + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        cols = c0 + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        seen = rows >= cols
+        if window is not None:
+            seen &= rows - cols < window
+        s = jnp.where(seen, s * scale, NO_SINK)
+        m_prev = m_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # A row that has seen no key yet and has no sink stands at
+        # ``NO_SINK``, where a masked score's exp would be 1.
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:, 0:1] = l_ref[:, 0:1] * corr + jnp.sum(p, axis=-1,
+                                                       keepdims=True)
+        m_ref[:, 0:1] = m_new
+        v = v_ref[0, 0]
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _final():
+        # ``l`` is 1 or more where there is a sink and where no key was
+        # seen; nothing divides by zero.
+        o_ref[0, 0] = (acc_ref[...] / l_ref[:, 0:1]).astype(o_ref.dtype)
+
+
+def _interpret() -> bool:
+    """Off the TPU (the CPU tests) the kernel runs in the Pallas
+    interpreter."""
+    return jax.default_backend() != "tpu"
+
+
+def _pad_to(x, axis: int, multiple: int):
+    short = -x.shape[axis] % multiple
+    if not short:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, short)
+    return jnp.pad(x, pad)
+
+
+def chunk_attention(q, k, v, q_offsets, k_offsets, scale: float,
+                    window: Optional[int] = None,
+                    sink: Optional[jax.Array] = None):
+    """Attention of ``S`` queries a row over ``C`` keys.
+
+    ``q`` (B, H, S, D), ``k`` (B, KV, C, D), ``v`` (B, KV, C, Dv), ``H`` a
+    multiple of ``KV`` (head ``h`` reads key head ``h // (H / KV)``);
+    ``q_offsets``, ``k_offsets`` (B,) int32: query ``i`` of row ``b`` sits
+    at position ``q_offsets[b] + i``, key ``j`` at ``k_offsets[b] + j``. A
+    query sees the keys at positions up to its own and, under ``window``,
+    no further back than ``window - 1``. ``sink`` (H,) float32 joins each
+    head's softmax denominator. Scores are ``q . k * scale``; softmax and
+    accumulation in float32, probabilities rounded to ``v``'s dtype for
+    the value product. Returns (B, H, S, Dv) in ``q``'s dtype. The keys are
+    padded here to whole tiles and ``D`` to whole lanes (zeros, which a
+    score does not see; a padded key lies past every query)."""
+    B, H, S, _ = q.shape
+    KV, dv = k.shape[1], v.shape[-1]
+    groups = H // KV
+    if window is None:
+        block_q, block_k = math.gcd(S, BLOCK_Q), BLOCK_K
+    else:
+        block_q = math.gcd(S, WINDOW_BLOCK)
+        block_k = WINDOW_BLOCK
+    block_k = min(block_k, -(-k.shape[2] // _LANE) * _LANE)
+    q, k = _pad_to(q, 3, _LANE), _pad_to(_pad_to(k, 3, _LANE), 2, block_k)
+    v = _pad_to(v, 2, block_k)
+    d, key_tiles = q.shape[-1], k.shape[2] // block_k
+    steps = key_tiles
+    if window is not None:
+        steps = min(key_tiles, -(-(block_q + window - 1) // block_k) + 1)
+    if sink is None:
+        sink = jnp.full((H,), NO_SINK, jnp.float32)
+    sink = jnp.broadcast_to(sink.astype(jnp.float32)[:, None, None],
+                            (H, 1, _LANE))
+
+    def q_map(b, h, i, j, qoff, koff):
+        return b, h, i, 0
+
+    def k_map(b, h, i, j, qoff, koff):
+        q0 = qoff[b] + i * block_q
+        last = jnp.clip((q0 + block_q - 1 - koff[b]) // block_k, 0,
+                        key_tiles - 1)
+        tile = _first_tile(q0, koff[b], window, block_k) + j
+        return b, h // groups, jnp.minimum(tile, last), 0
+
+    def spec(shape, index_map):
+        return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
+
+    kernel = functools.partial(_kernel, scale=scale, block_q=block_q,
+                               block_k=block_k, window=window,
+                               key_tiles=key_tiles)
+    name = "chunk_attn_full" if window is None else "chunk_attn_window"
+    with jax.named_scope(name):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(B, H, S // block_q, steps),
+                in_specs=[
+                    spec((1, 1, block_q, d), q_map),
+                    spec((1, 1, block_k, d), k_map),
+                    spec((1, 1, block_k, dv), k_map),
+                    spec((1, 1, _LANE),
+                         lambda b, h, i, j, qoff, koff: (h, 0, 0)),
+                ],
+                out_specs=spec((1, 1, block_q, dv), q_map),
+                scratch_shapes=[
+                    pltpu.VMEM((block_q, dv), jnp.float32),
+                    pltpu.VMEM((block_q, _LANE), jnp.float32),
+                    pltpu.VMEM((block_q, _LANE), jnp.float32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((B, H, S, dv), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            interpret=_interpret(),
+            name=name,
+        )(q_offsets.astype(jnp.int32), k_offsets.astype(jnp.int32),
+          q, k, v, sink)

@@ -148,7 +148,9 @@ def moe_param_axes() -> Dict[str, Any]:
 
 @dataclasses.dataclass(frozen=True)
 class Router:
-    """How tokens choose experts, by the softmax of their logits.
+    """How tokens choose experts, by a score of their logits: their
+    softmax (DeepSeek-V2) or, under ``score="sigmoid"``, each logit's own
+    sigmoid (``noaux_tc``: DeepSeek-V3, MiMo-V2).
     ``experts`` is the router's width (all experts of the layer, held here
     or not). With ``groups`` > 1 the
     experts form that many equal groups, each scored by its best expert,
@@ -161,17 +163,31 @@ class Router:
     top_groups: int = 1
     renormalise: bool = False     # weights of the chosen sum to 1
     scale: float = 1.0            # times this (``routed_scaling_factor``)
+    score: str = "softmax"        # or "sigmoid"
 
 
-def route(logits: jax.Array, router: Router
+def route(logits: jax.Array, router: Router,
+          bias: Optional[jax.Array] = None
           ) -> Tuple[jax.Array, jax.Array]:
     """``logits`` (T, experts) float32 -> the chosen experts ``(T, top_k)``
-    int32 and their weights ``(T, top_k)`` float32."""
+    int32 and their weights ``(T, top_k)`` float32. ``bias`` (experts,)
+    is the layer's selection bias (``e_score_correction_bias``): data, as
+    the router's matrix is; it is added to the scores for the CHOICE
+    only, and the weights are the scores themselves."""
     r = router
     logits = logits.astype(jnp.float32)
-    scores = jax.nn.softmax(logits, axis=-1)
-    choose = scores
+    if r.score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif r.score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router score {r.score!r}")
+    choose = scores if bias is None else scores + bias.astype(jnp.float32)
     if r.groups > 1:
+        if bias is not None:
+            raise ValueError("a selection bias with expert groups (a "
+                             "group's score is then its two best) is not "
+                             "implemented")
         t = scores.shape[0]
         best = scores.reshape(t, r.groups, -1).max(-1)        # (T, G)
         _, kept = jax.lax.top_k(best, r.top_groups)
@@ -180,6 +196,8 @@ def route(logits: jax.Array, router: Router
         choose = jnp.where(
             jnp.repeat(open_, r.experts // r.groups, axis=1), scores, 0.0)
     weights, idx = jax.lax.top_k(choose, r.top_k)
+    if bias is not None:
+        weights = jnp.take_along_axis(scores, idx, axis=-1)
     if r.renormalise:
         weights = weights / jnp.maximum(
             weights.sum(-1, keepdims=True), 1e-20)

@@ -140,13 +140,20 @@ class DecodeEngine:
     ``paged_spec_draft`` (``spec_k > 0``) and ``shard_decode_state`` (a
     mesh): an option whose program the model lacks is refused at
     construction. The pool is the model's pytree, carried whole
-    (docs/SERVING.md, "The model seam").
+    (docs/SERVING.md, "The model seam"). A model may also say
+    ``page_kinds(config)``: which kinds of page its pool has and what
+    each keeps (all tokens, or the last ``window``); the engine then
+    keeps an allocator and a block table a kind (``_windows``), and such
+    a model runs without the prefix index.
 
     ``slots`` concurrent sequences of up to ``capacity`` tokens share
     one paged KV pool. ``step()`` advances every active slot one token;
     ``submit()`` enqueues a request (prefilled into a free slot at the
     next step boundary). Run ``serve_forever`` in a thread inside a
     replica, or drive ``step()`` manually in tests."""
+
+    # The name of the one page kind of a model that names none.
+    DEFAULT_KIND = "full"
 
     def __init__(self, params, config, slots: int = 4,
                  capacity: int = 1024, prefill_bucket: int = 128,
@@ -170,7 +177,8 @@ class DecodeEngine:
         import jax
 
         from ray_tpu.core.config import config as rt_config
-        from ray_tpu.serve.paging import PageAllocator, PagedPrefixIndex
+        from ray_tpu.serve.paging import (PageAllocator, PagedPrefixIndex,
+                                          WindowPages)
 
         if model is None:
             from ray_tpu.models import llama_decode as model
@@ -267,13 +275,51 @@ class DecodeEngine:
               else pool_pages)
         self.pool_pages = int(pp) or slots * self.slot_pages_max
         self._pages = PageAllocator(self.pool_pages)
+        # Page kinds. A model that names none has ONE, which keeps every
+        # token (``_pages``, ``_block_tables``, ``_slot_pages``: all of
+        # the engine before kinds). A model whose ``page_kinds`` names
+        # more has that one first and, behind it, kinds that keep a
+        # WINDOW of tokens (sliding-window layers): each with an
+        # allocator and a table a slot of its own (``WindowPages``),
+        # asked for pages wherever the first kind is and handed its dead
+        # pages back at the step that passes them (``_trim_windows``).
+        # A window kind's pool holds what the slots keep between steps
+        # and the prefills in flight, which are written through pages: a
+        # chunk's, or an admission wave's whole prompts.
+        kinds = (ld.page_kinds(config) if hasattr(ld, "page_kinds")
+                 else {self.DEFAULT_KIND: {"window": None, "leaves": None}})
+        self._kind, *others = kinds
+        if kinds[self._kind]["window"] is not None or any(
+                kinds[k]["window"] is None for k in others):
+            raise ValueError(
+                f"model {ld.__name__}: the first page kind keeps every "
+                f"token and the others a window, got {kinds}")
+        self._windows: Dict[str, WindowPages] = {}
+        for name in others:
+            keep = -(-int(kinds[name]["window"]) // self.page_tokens) + 1
+            in_flight = (self._seq_pages(self.prefill_chunk_tokens)
+                         if self.prefill_chunk_tokens
+                         else self.slot_pages_max)
+            pages = min(slots * self.slot_pages_max,
+                        slots * keep + max(4, slots // 4) * in_flight)
+            self._windows[name] = WindowPages(
+                pages, slots, self.slot_pages_max, self.page_tokens,
+                kinds[name]["window"])
         # The pool is the model's own pytree (leaves ``[layers,
         # pages + 1, page_tokens, ...]``: K and V per head for llama,
-        # one latent row a token for deepseek); the engine carries it
-        # whole beside the slots' cursors and never looks inside.
-        pool = ld.init_page_pool(config, self.pool_pages,
-                                 self.page_tokens)
+        # one latent row a token for deepseek, K and V a kind for mimo);
+        # the engine carries it whole beside the slots' cursors and
+        # never looks inside.
+        pool = ld.init_page_pool(
+            config, {self._kind: self.pool_pages,
+                     **{k: w.alloc.pages for k, w in self._windows.items()}}
+            if self._windows else self.pool_pages, self.page_tokens)
         self._pool_names = tuple(pool)
+        # Which kind's page ids index a leaf (the handoff's leaf map).
+        self._leaf_kind = {
+            leaf: next((k for k, d in kinds.items()
+                        if d["leaves"] and leaf in d["leaves"]), self._kind)
+            for leaf in pool}
         self.cache = {**pool,
                       "length": jax.numpy.zeros((slots,),
                                                 jax.numpy.int32)}
@@ -352,7 +398,15 @@ class DecodeEngine:
                       if prefix_match_min_tokens is None
                       else prefix_match_min_tokens)
         self.prefix = None
-        if entries > 0:
+        if entries > 0 and self._windows:
+            # A shared boundary would need the full pages up to it AND
+            # the window pages that hold the ``window`` tokens before it,
+            # which the index does not pin: a model with a window kind
+            # runs without it (docs/SERVING.md, "Page kinds").
+            logger.info(
+                "model %s has page kinds %s: this engine runs without the "
+                "prefix index", ld.__name__, list(kinds))
+        elif entries > 0:
             pmax = (rt_config.kv_prefix_max_pages
                     if prefix_max_pages is None else prefix_max_pages)
             self.prefix = PagedPrefixIndex(
@@ -661,7 +715,8 @@ class DecodeEngine:
         Pad columns target the scratch page (id 0, never read) with
         zero payloads; ``width`` is the pow-2 compile bucket."""
         return {
-            **{name: cache[name].at[:, ids].set(pages)
+            **{name: cache[name].at[:, ids[self._leaf_kind[name]] if
+                                    isinstance(ids, dict) else ids].set(pages)
                for name, pages in payload.items()},
             "length": cache["length"].at[slot_ids].set(lengths),
         }
@@ -734,6 +789,16 @@ class DecodeEngine:
         if not self.steplog.enabled:
             return call()
         attrs.setdefault("program", key[0])
+        if self._windows and "view_pages" in attrs:
+            # A decode over page kinds: what the window kinds' lists hold
+            # beside the first kind's, their length in pages and the
+            # tokens in the stepping slots' windows.
+            attrs["window_pages"] = sum(
+                self.slots * w.keep for w in self._windows.values())
+            attrs["window_tokens"] = sum(
+                min(r.prompt_len + r.generated, w.window)
+                for r in self._active.values()
+                for w in self._windows.values())
         self.steplog.begin("launch", **attrs)
         out = call()
         if then is not None:
@@ -759,7 +824,7 @@ class DecodeEngine:
             return None
         try:
             if self.steplog.enabled:
-                self.steplog.event("page-alloc", n=n,
+                self.steplog.event("page-alloc", n=n, page_kind=self._kind,
                                    free=self._pages.free_count)
         except BaseException:
             # Exception-safety for the lease: an event-recording
@@ -781,6 +846,55 @@ class DecodeEngine:
     def _seq_pages(self, tokens: int) -> int:
         return -(-tokens // self.page_tokens)
 
+    # ------------------------------------------------- window page kinds
+
+    def _windows_missing(self, slot: int, tokens: int) -> bool:
+        """Whether some window kind lacks the free pages to cover the
+        slot's first ``tokens`` positions."""
+        return any(w.missing(slot, tokens) > w.alloc.free_count
+                   for w in self._windows.values())
+
+    def _grow_windows(self, slot: int, tokens: int) -> None:
+        """Every window kind covers the slot's positions below
+        ``tokens`` (the caller has checked ``_windows_missing``)."""
+        for kind, w in self._windows.items():
+            n = w.grow(slot, tokens)
+            if n and self.steplog.enabled:
+                self.steplog.event("page-alloc", n=n, page_kind=kind,
+                                   free=w.alloc.free_count)
+
+    def _trim_windows(self, slot: int, position: int) -> None:
+        """The slot's next query is at ``position``: its window pages
+        wholly behind that query's window go back to their allocator."""
+        for kind, w in self._windows.items():
+            n = w.trim(slot, position)
+            if n and self.steplog.enabled:
+                self.steplog.event("page-free", n=n, page_kind=kind,
+                                   free=w.alloc.free_count)
+
+    def _prefill_tables(self, slots: List[int], positions: List[int],
+                        bt: np.ndarray, bucket: int):
+        """The block tables a prefill program takes: ``bt`` itself for a
+        model of one kind; for one with window kinds a dict, the first
+        kind's ``bt`` under its name and, a window kind, the columns
+        that cover ``bucket`` tokens from each row's position and its
+        window before it, with the index of their first page
+        (``<kind>_first``). Pad rows repeat the last."""
+        import jax.numpy as jnp
+
+        if not self._windows:
+            return jnp.asarray(bt)
+        out = {self._kind: jnp.asarray(bt)}
+        for kind, w in self._windows.items():
+            width = -(-(bucket + w.window) // self.page_tokens) + 1
+            cols = [w.columns(s, p, width)
+                    for s, p in zip(slots, positions)]
+            cols += cols[-1:] * (len(bt) - len(cols))
+            out[kind] = jnp.asarray(np.stack([c for c, _ in cols]))
+            out[f"{kind}_first"] = jnp.asarray(
+                np.asarray([f for _, f in cols], np.int32))
+        return out
+
     def _live_view(self, tables: np.ndarray,
                    slot_pages: List[List[int]]) -> np.ndarray:
         """The view the decode about to be dispatched reads
@@ -796,7 +910,25 @@ class DecodeEngine:
         rows_of = getattr(self._ld, "view_rows", None)
         live = int(counts.sum()) if rows_of is None else rows_of(counts)
         rung = next(n for n in self._view_ladder if n >= live)
-        return self._ld.live_page_view(tables, counts, rung)
+        return self._view(tables, counts, rung)
+
+    def _view(self, tables: np.ndarray, counts: np.ndarray, rung: int):
+        """The model's ``live_page_view`` on ``rung`` rows. For a model
+        with window kinds it is given, and gives, a dict a kind: the
+        first kind's list and, a window kind, the pages the stepping
+        slots hold, a fixed ``keep`` a slot."""
+        if not self._windows:
+            return self._ld.live_page_view(tables, counts, rung)
+        stepping = counts > 0
+        return self._ld.live_page_view(
+            {self._kind: tables,
+             **{k: w.table for k, w in self._windows.items()}},
+            {self._kind: counts,
+             **{k: (np.asarray(w.first),
+                    np.where(stepping, np.asarray(w.held), 0))
+                for k, w in self._windows.items()}},
+            {self._kind: rung,
+             **{k: w.keep for k, w in self._windows.items()}})
 
     def _ensure_decode_pages(self, k: int) -> None:
         """Every active slot can write its next ``k`` tokens. Oldest
@@ -810,14 +942,19 @@ class DecodeEngine:
                 req = self._active.get(slot)
                 if req is None:
                     break  # preempted while serving an older slot
-                need = self._seq_pages(req.prompt_len + req.generated
-                                       - 1 + k) \
-                    - len(self._slot_pages[slot])
-                if need <= 0:
+                upto = req.prompt_len + req.generated - 1 + k
+                # The step's first query is at ``upto - k``: what lies
+                # behind its window goes back before anything is asked.
+                self._trim_windows(slot, upto - k)
+                need = self._seq_pages(upto) - len(self._slot_pages[slot])
+                dry = self._windows_missing(slot, upto)
+                if need <= 0 and not dry:
+                    self._grow_windows(slot, upto)
                     break
-                got = self._alloc_pages(need)
+                got = None if dry else self._alloc_pages(need)
                 if got is not None:
                     self._grow_slot(slot, got)
+                    self._grow_windows(slot, upto)
                     break
                 if not self._preempt_one():
                     break  # nothing left to preempt: caller's slot only
@@ -929,7 +1066,8 @@ class DecodeEngine:
         # nothing) and the pages it held.
         discarded = (req.prefilled if slot in self._prefilling
                      else len(req.tokens)) - req.prefix_len
-        held = len(self._slot_pages[slot])
+        held = len(self._slot_pages[slot]) + sum(
+            w.held[slot] for w in self._windows.values())
         self._active.pop(slot, None)
         self._prefilling.pop(slot, None)
         self._release_slot(slot)
@@ -1082,9 +1220,12 @@ class DecodeEngine:
         for name in self._pool_names:
             got = adopt[name]
             pool = self.cache[name].shape  # (L, pages+1, T, ...)
+            w = self._windows.get(self._leaf_kind[name])
+            want = (self._seq_pages(len(req.tokens)) if w is None
+                    else w.span(len(req.tokens))[1])
             if (got.ndim != len(pool) or got.shape[0] != pool[0]
                     or tuple(got.shape[2:]) != tuple(pool[2:])
-                    or got.shape[1] != self._seq_pages(len(req.tokens))):
+                    or got.shape[1] != want):
                 raise HandoffAdoptError(
                     f"handoff payload shape {tuple(got.shape)} does not "
                     f"fit this engine's pool {tuple(pool)}")
@@ -1312,6 +1453,8 @@ class DecodeEngine:
                 req.slot = slot  # ownership on the request before any
                 #   fallible call: a raise must not strand the lease
                 self._set_slot_pages(slot, req.prefix_pages)
+                for w in self._windows.values():
+                    w.seat(slot, req.prefix_len)
                 req.prefilled = req.prefix_len
                 # Park the device cursor at the spliced length NOW: the
                 # slot may sit un-ticked for several steps (one chunk
@@ -1329,7 +1472,14 @@ class DecodeEngine:
                 seated.append(req)
                 continue
             need = self._seq_pages(len(req.tokens)) - len(req.prefix_pages)
-            pages = self._alloc_pages(need)
+            # A whole prefill is written through the window kinds' pages
+            # too (trimmed when it has run): every kind has room, or the
+            # wave stops here.
+            slot = self._free[-1]
+            for w in self._windows.values():
+                w.seat(slot, req.prefix_len)
+            pages = (None if self._windows_missing(slot, len(req.tokens))
+                     else self._alloc_pages(need))
             if pages is None:
                 # Dry: drop the splice pins, push this and the rest of
                 # the wave back (front, original order) and pause.
@@ -1346,6 +1496,7 @@ class DecodeEngine:
             slot = self._free.pop()
             req.slot = slot
             self._set_slot_pages(slot, req.prefix_pages + pages)
+            self._grow_windows(slot, len(req.tokens))
             seated.append(req)
             (suffix_group if req.prefix_len else full_group).append(req)
         self._mark_admitted(seated)
@@ -1364,34 +1515,48 @@ class DecodeEngine:
 
         adopt = req.adopt
         clen = int(adopt["committed_len"])
-        pages = self._alloc_pages(self._seq_pages(clen))
+        slot = self._free[-1]
+        for w in self._windows.values():
+            w.seat(slot, clen)      # holds nothing, window at ``clen``
+        pages = (None if self._windows_missing(slot, clen)
+                 else self._alloc_pages(self._seq_pages(clen)))
         if pages is None:
             return False
-        slot = self._free.pop()
+        self._free.pop()
         req.slot = slot  # ownership on the request before any fallible
         #   call: a raise must not strand the pages
         self._set_slot_pages(slot, pages)
+        self._grow_windows(slot, clen)
         req.prefix_pages, req.prefix_len = [], 0
         req.prefilled = clen
-        # Pow-2 page-count bucket: one compiled adopt program per width,
-        # pad columns scatter zero payloads into the scratch page.
-        width = 1
-        while width < len(pages):
-            width *= 2
-        ids = np.zeros((width,), np.int32)
-        ids[:len(pages)] = pages
+        # Pow-2 page-count bucket: one compiled adopt program per width
+        # (a kind), pad columns scatter zero payloads into the scratch
+        # page.
+        held = {self._kind: pages,
+                **{k: w.slot_pages(slot) for k, w in self._windows.items()}}
+        ids = {}
+        for kind, mine in held.items():
+            n = 1
+            while n < len(mine):
+                n *= 2
+            ids[kind] = np.zeros((n,), np.int32)
+            ids[kind][:len(mine)] = mine
+        width = len(ids[self._kind])
         padded = {}
         for name in self._pool_names:
             shape = self.cache[name].shape
-            pad = np.zeros((shape[0], width) + tuple(shape[2:]),
+            mine = ids[self._leaf_kind[name]]
+            pad = np.zeros((shape[0], len(mine)) + tuple(shape[2:]),
                            adopt[name].dtype)
-            pad[:, :len(pages)] = adopt[name]
+            pad[:, :adopt[name].shape[1]] = adopt[name]
             padded[name] = jnp.asarray(pad)
+        index = ({k: jnp.asarray(v) for k, v in ids.items()}
+                 if self._windows else jnp.asarray(ids[self._kind]))
         t0 = time.time()
         self.cache = self._dispatch_fresh(
-            ("adopt_pages", width),
+            ("adopt_pages",) + tuple(len(v) for v in ids.values()),
             lambda: self._adopt_pages(
-                self.cache, padded, jnp.asarray(ids), jnp.asarray([slot], np.int32),
+                self.cache, padded, index, jnp.asarray([slot], np.int32),
                 jnp.asarray([clen], np.int32), width=width),
             then="admit")
         if self.steplog.enabled:
@@ -1460,10 +1625,14 @@ class DecodeEngine:
                 ("paged_prefill", n, bucket),
                 lambda: self._paged_prefill(
                     self.params, self.cache, jnp.asarray(rows),
-                    jnp.asarray(lengths), jnp.asarray(bt),
+                    jnp.asarray(lengths),
+                    self._prefill_tables([r.slot for r in group],
+                                         [0] * len(group), bt, bucket),
                     jnp.asarray(slot_ids), *self._draw_args(group, n),
                     n=n, bucket=bucket),
                 tokens=sum(len(r.tokens) for r in group))
+            for req in group:
+                self._trim_windows(req.slot, len(req.tokens))
             ids = self._fetch_ids(ids, "paged_prefill")
             self._wave_span("prefill", t0, group, n=len(group),
                             bucket=bucket)
@@ -1518,10 +1687,15 @@ class DecodeEngine:
                 lambda: self._paged_suffix(
                     self.params, self.cache, jnp.asarray(rows),
                     jnp.asarray(plens), jnp.asarray(lengths),
-                    jnp.asarray(bt), jnp.asarray(slot_ids),
+                    self._prefill_tables(
+                        [r.slot for r in group],
+                        [r.prefix_len for r in group], bt, bucket),
+                    jnp.asarray(slot_ids),
                     *self._draw_args(group, n),
                     n=n, bucket=bucket, width=width),
                 tokens=sum(len(r.tokens) - r.prefix_len for r in group))
+            for req in group:
+                self._trim_windows(req.slot, len(req.tokens))
             ids = self._fetch_ids(ids, "paged_suffix")
             self._wave_span("suffix-prefill", t0, group, n=len(group),
                             bucket=bucket)
@@ -1549,11 +1723,14 @@ class DecodeEngine:
                      self.prefill_chunk_tokens)
         need = self._seq_pages(req.prefilled + step_tok) \
             - len(self._slot_pages[slot])
+        if self._windows_missing(slot, req.prefilled + step_tok):
+            return
         if need > 0:
             got = self._alloc_pages(need)
             if got is None:
                 return
             self._grow_slot(slot, got)
+        self._grow_windows(slot, req.prefilled + step_tok)
         width = 1
         while width * T < req.prefilled + bucket:
             width *= 2
@@ -1570,10 +1747,15 @@ class DecodeEngine:
                 self.params, self.cache, jnp.asarray(rows),
                 jnp.asarray([req.prefilled], np.int32),
                 jnp.asarray([req.prefilled + step_tok], np.int32),
-                jnp.asarray(bt), jnp.asarray([slot], np.int32),
+                self._prefill_tables([slot], [req.prefilled], bt, bucket),
+                jnp.asarray([slot], np.int32),
                 *self._draw_args([req], 1),
                 n=1, bucket=bucket, width=width),
-            then="admit", program="prefill_chunk", tokens=step_tok)
+            then="admit", program="prefill_chunk", tokens=step_tok,
+            prefix=req.prefilled)
+        # The chunk's own window pages, but for the window of the next
+        # position, are dead the moment the program is dispatched.
+        self._trim_windows(slot, req.prefilled + step_tok)
         self._wave_span("prefill-chunk", t0, [req], tokens=step_tok,
                         prefilled=req.prefilled + step_tok,
                         prompt=len(req.tokens))
@@ -1712,12 +1894,17 @@ class DecodeEngine:
         decode side emits it, keeping the client-visible stream
         identical to the colocated path."""
         t0 = time.time()
-        ids = np.asarray(self._slot_pages[slot], np.int32)
+        ids = {self._kind: np.asarray(self._slot_pages[slot], np.int32),
+               **{k: np.asarray(w.slot_pages(slot), np.int32)
+                  for k, w in self._windows.items()}}
         # np.array (never asarray): the payload outlives later donated
         # dispatches, so it must OWN its bytes — a host view of the
-        # cache would be clobbered in place (the PR 16 pin).
-        pages = {name: np.array(self.cache[name][:, ids])
-                 for name in self._pool_names}
+        # cache would be clobbered in place (the PR 16 pin). A leaf
+        # carries the pages of ITS kind: all of the prompt's, or the
+        # window's (``WindowPages.span`` of the committed length).
+        pages = {name: np.array(
+            self.cache[name][:, ids[self._leaf_kind[name]]])
+            for name in self._pool_names}
         req.handoff = {
             **pages,
             "committed_len": int(req.prompt_len),
@@ -1729,7 +1916,7 @@ class DecodeEngine:
         if self.steplog.enabled:
             self._handoff_phases.append(
                 {"phase": "handoff", "t0": t0, "t1": time.time(),
-                 "slot": slot, "pages": int(len(ids))})
+                 "slot": slot, "pages": int(len(ids[self._kind]))})
 
     def _draft_seat(self, reqs: List[_Request]) -> None:
         """Give each freshly-admitted slot its draft-side state: draft
@@ -1872,7 +2059,13 @@ class DecodeEngine:
         self._pages.free(pages)
         if pages and self.steplog.enabled:
             self.steplog.event("page-free", n=len(pages),
+                               page_kind=self._kind,
                                free=self._pages.free_count)
+        for kind, w in self._windows.items():
+            n = w.release(slot)
+            if n and self.steplog.enabled:
+                self.steplog.event("page-free", n=n, page_kind=kind,
+                                   free=w.alloc.free_count)
         if self.spec:
             dpages = self._draft_slot_pages[slot]
             self._draft_slot_pages[slot] = []
@@ -2035,7 +2228,7 @@ class DecodeEngine:
         stepped = len(self._active)
         ctx = self._ctx_tokens() if rec else None
         view = self._live_view(self._block_tables, self._slot_pages)
-        rung = view.shape[1]
+        rung = (view[self._kind] if self._windows else view).shape[1]
         if chunk > 1:
             t_d0 = time.time() if rec else 0.0
             toks, self.cache = self._dispatch_fresh(
@@ -2128,8 +2321,8 @@ class DecodeEngine:
             self._state_dev = self._put(self._host_state())
         if self._temps_dev is None:
             self._temps_dev = self._put(self._temps)
-        return self._state_dev, self._jax.numpy.asarray(view), \
-            self._temps_dev
+        return self._state_dev, self._jax.tree.map(
+            self._jax.numpy.asarray, view), self._temps_dev
 
     def _ctx_tokens(self) -> int:
         """KV positions the decode about to be dispatched really needs:
@@ -2314,7 +2507,14 @@ class DecodeEngine:
             pages_free=self._pages.free_count,
             pages_pinned=(self.prefix.pinned_pages
                           if self.prefix is not None else None),
-            ctx_tokens=ctx_tokens, view_pages=view_pages)
+            ctx_tokens=ctx_tokens, view_pages=view_pages,
+            # A model with page kinds: the pages in use a kind, and the
+            # tokens whose keys and values the seated slots hold.
+            **({**{f"pages_{k}": n
+                   for k, n in self.pages_in_use().items()},
+                "kv_tokens": self._ctx_tokens() - len(self._active) + sum(
+                    r.prefilled for r in self._prefilling.values())}
+               if self._windows else {}))
 
     def warm_decode(self) -> None:
         """Dispatch the step loop's one-token decode once at every rung
@@ -2337,7 +2537,7 @@ class DecodeEngine:
 
         none = np.zeros((self.slots,), np.int32)
         for rung in self._view_ladder:
-            yield rung, jnp.asarray(self._ld.live_page_view(
+            yield rung, self._jax.tree.map(jnp.asarray, self._view(
                 self._block_tables, none, rung))
 
     def warmup(self) -> None:
@@ -2359,7 +2559,8 @@ class DecodeEngine:
                 self.params, self.cache,
                 jnp.zeros((1, bucket), jnp.int32),
                 jnp.asarray([0], jnp.int32),
-                jnp.asarray(self._block_tables[:1, :wp]),
+                self._prefill_tables([0], [0],
+                                     self._block_tables[:1, :wp], bucket),
                 jnp.asarray([0], jnp.int32), np.zeros((1,), np.float32),
                 np.int32(0), n=1, bucket=bucket))
         self.warm_decode()
@@ -2500,6 +2701,11 @@ class DecodeEngine:
             "device": self.device_stats(),
         }
         out.update(self._pages.stats())
+        if self._windows:
+            out["pages_in_use_by_kind"] = self.pages_in_use()
+            out["pages_total_by_kind"] = {
+                self._kind: self._pages.pages,
+                **{k: w.alloc.pages for k, w in self._windows.items()}}
         out["page_tokens"] = self.page_tokens
         out["pages_pinned"] = (self.prefix.pinned_pages
                                if self.prefix is not None else 0)
@@ -2527,6 +2733,11 @@ class DecodeEngine:
             out["step_timeline_rows"] = len(self.steplog._rows)
             out["step_timeline_dropped"] = self.steplog.dropped
         return out
+
+    def pages_in_use(self) -> Dict[str, int]:
+        """Pool pages handed out, a page kind."""
+        return {self._kind: self._pages.in_use,
+                **{k: w.alloc.in_use for k, w in self._windows.items()}}
 
     def device_stats(self) -> Dict[str, Any]:
         """Where this engine runs, as JAX reports it in THIS process
@@ -3036,3 +3247,17 @@ class DeepseekDecodeDeployment(LlamaDecodeDeployment):
         from ray_tpu.models import deepseek, deepseek_decode
 
         return deepseek, deepseek_decode
+
+
+class MimoDecodeDeployment(LlamaDecodeDeployment):
+    """The same deployment over MiMo-V2 (``models/mimo.py``): full and
+    window layers over two kinds of page, held experts behind a sigmoid
+    router. The model has none of the engine's optional programs, so
+    ``decode_chunk > 1``, ``spec_k > 0`` and a mesh are refused by the
+    engine; its window kind turns the prefix index off."""
+
+    @staticmethod
+    def model_modules():
+        from ray_tpu.models import mimo, mimo_decode
+
+        return mimo, mimo_decode

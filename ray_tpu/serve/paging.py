@@ -2,7 +2,7 @@
 
 The paging half of the serve plane's memory story (``serve/decode.py``
 owns the device arrays and jitted programs; ``models/llama_decode.py``
-owns the paged attention math). Three pieces:
+owns the paged attention math). Four pieces:
 
 * ``prefix_hash`` / ``bucket_lengths`` / ``candidate_hashes`` — the
   prefix FORMAT the index and the serve router share: replicas advertise
@@ -17,6 +17,9 @@ owns the paged attention math). Three pieces:
   full-page-aligned and sequence writes are append-only past the shared
   region — which is the copy-on-write discipline without ever needing
   the copy.
+* ``WindowPages`` — the tables of a second KIND of page, one that only
+  has to outlive a window of tokens (sliding-window layers): its own
+  allocator, and pages handed back from the front of a sequence.
 * ``PagedPrefixIndex`` — vLLM-style hash-chained prefix cache: one entry
   per page-aligned prefix length, keyed by the hash of ALL tokens up to
   that page's end, each pinning exactly ONE pool page. Inserting a
@@ -137,6 +140,104 @@ class PageAllocator:
         return {"pages_total": self.pages,
                 "pages_free": len(self._free_ids),
                 "pages_in_use": self.in_use}
+
+
+class WindowPages:
+    """Host state of a page KIND that is read no further back than
+    ``window`` - 1 tokens (a model's sliding-window layers;
+    ``DecodeEngine``, "page kinds"): the kind's own ``PageAllocator``, a
+    block table a slot over the same logical page indices as the full
+    kind's, and for each slot the run of indices it holds, ``[first,
+    first + held)``. Pages are appended as the sequence grows (``grow``)
+    and handed back from the front once every token on them lies behind
+    the window of the next position (``trim``), so between steps a slot
+    holds at most ``keep`` = ``ceil(window / page_tokens) + 1`` of them,
+    and a prefill's own on top while that prefill is in flight. Single-
+    threaded, as everything here."""
+
+    def __init__(self, pages: int, slots: int, slot_pages_max: int,
+                 page_tokens: int, window: int):
+        self.alloc = PageAllocator(pages)
+        self.page_tokens = int(page_tokens)
+        self.window = int(window)
+        self.keep = -(-self.window // self.page_tokens) + 1
+        self.table = np.zeros((slots, slot_pages_max), np.int32)
+        # Plain ints: the step loop asks every slot every step, and
+        # mostly the answer is "nothing to do".
+        self.first: List[int] = [0] * slots
+        self.held: List[int] = [0] * slots
+
+    def _dead_before(self, position: int) -> int:
+        """Logical pages wholly behind the window of ``position``: the
+        query there reads the keys from ``position - window + 1`` on."""
+        return max(0, position - self.window + 1) // self.page_tokens
+
+    def span(self, tokens: int) -> Tuple[int, int]:
+        """``(first index, pages)`` a slot holds whose next position is
+        ``tokens``: what a handoff of that many tokens carries."""
+        first = self._dead_before(tokens)
+        return first, -(-tokens // self.page_tokens) - first
+
+    def seat(self, slot: int, position: int) -> None:
+        """An empty slot whose next token is at ``position``."""
+        self.first[slot] = self._dead_before(position)
+        self.held[slot] = 0
+
+    def missing(self, slot: int, tokens: int) -> int:
+        """Pages ``grow`` would take to cover the first ``tokens``."""
+        return max(0, -(-tokens // self.page_tokens)
+                   - self.first[slot] - self.held[slot])
+
+    def grow(self, slot: int, tokens: int) -> Optional[int]:
+        """Cover the positions below ``tokens``: the pages taken, or None
+        (and nothing taken) if the allocator has not that many."""
+        need = self.missing(slot, tokens)
+        if not need:
+            return 0
+        got = self.alloc.alloc(need)
+        if got is None:
+            return None
+        end = self.first[slot] + self.held[slot]
+        self.table[slot, end:end + need] = got
+        self.held[slot] += need
+        return need
+
+    def trim(self, slot: int, position: int) -> int:
+        """Free the slot's pages wholly behind the window of the query at
+        ``position``; returns how many."""
+        first = self.first[slot]
+        upto = min(self._dead_before(position), first + self.held[slot])
+        if upto <= first:
+            return 0
+        self.alloc.free(int(p) for p in self.table[slot, first:upto])
+        self.table[slot, first:upto] = 0
+        self.first[slot] = upto
+        self.held[slot] -= upto - first
+        return upto - first
+
+    def release(self, slot: int) -> int:
+        """Free everything the slot holds; returns how many."""
+        n = self.held[slot]
+        if n:
+            self.alloc.free(self.slot_pages(slot))
+            self.table[slot, :] = 0
+        self.first[slot] = self.held[slot] = 0
+        return n
+
+    def slot_pages(self, slot: int) -> List[int]:
+        first = self.first[slot]
+        return self.table[slot, first:first + self.held[slot]].tolist()
+
+    def columns(self, slot: int, position: int,
+                width: int) -> Tuple[np.ndarray, int]:
+        """The table a prefill from ``position`` reads: ``width`` columns
+        from the first page its window reaches back to, and that page's
+        index (scratch where the slot holds nothing)."""
+        first = self._dead_before(position)
+        out = np.zeros((width,), np.int32)
+        row = self.table[slot, first:first + width]
+        out[:len(row)] = row
+        return out, first
 
 
 class _PageEntry:

@@ -158,10 +158,12 @@ class StepTimeline:
                queued: int, pages_free: Optional[int] = None,
                pages_pinned: Optional[int] = None,
                ctx_tokens: Optional[int] = None,
-               view_pages: Optional[int] = None) -> None:
+               view_pages: Optional[int] = None,
+               **counts: int) -> None:
         """One engine step: ``phases`` are the step's timed sub-slices
         ([{phase, t0, t1, ...attrs}]); occupancy is sampled at the step
-        boundary; queued events ride along and clear. The slices begun
+        boundary; queued events ride along and clear. ``counts`` are
+        further row keys (``pages_<kind>``: pages in use a page kind). The slices begun
         since ``step_begin`` close at ``t1`` and ride along too, and
         ``park`` opens for the time until the next step."""
         if not self.capacity:
@@ -180,6 +182,7 @@ class StepTimeline:
             row["ctx_tokens"] = ctx_tokens
         if view_pages is not None:
             row["view_pages"] = view_pages
+        row.update(counts)
         if self._open is not None:
             self._switch("park", t1, {"active": active})
             row["slices"] = self._slices[:-1]

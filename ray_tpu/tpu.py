@@ -22,6 +22,7 @@ import glob
 import os
 import subprocess
 import sys
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ray_tpu.core.errors import RayTpuError
@@ -84,27 +85,50 @@ def detect_chip_count(timeout_s: float = 120.0) -> Tuple[int, Optional[str]]:
     )
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "tpu"  # a TPU failure must not fall back to cpu
-    try:
-        out = subprocess.run(
-            [sys.executable, "-c", probe_src], capture_output=True,
-            timeout=timeout_s, text=True, env=env)
-    except subprocess.TimeoutExpired as e:
-        raise TpuProbeError(
-            f"TPU probe did not finish in {timeout_s:.0f}s on a machine "
-            f"with accelerator device files {dev_files}; stderr tail:\n"
-            f"{_tail(e.stderr)}") from None
-    if out.returncode != 0 or not out.stdout.strip().isdigit():
+    # A chip whose last holder has just exited can still be busy
+    # (``open(/dev/vfio/1): Device or resource busy``: a four-chip run
+    # started right after another's exit died here, PERF.md section 7):
+    # such a probe is tried again every ``_BUSY_RETRY_S`` inside the same
+    # ``timeout_s``; any other failure is final at once.
+    t0 = time.monotonic()
+    tries = 0
+    while True:
+        left = timeout_s - (time.monotonic() - t0)
+        try:
+            tries += 1
+            out = subprocess.run(
+                [sys.executable, "-c", probe_src], capture_output=True,
+                timeout=max(left, 1.0), text=True, env=env)
+        except subprocess.TimeoutExpired as e:
+            raise TpuProbeError(
+                f"TPU probe did not finish in {timeout_s:.0f}s on a "
+                f"machine with accelerator device files {dev_files} "
+                f"({tries} tries); stderr tail:\n{_tail(e.stderr)}"
+            ) from None
+        if out.returncode == 0 and out.stdout.strip().isdigit():
+            break
+        waited = time.monotonic() - t0
+        if _BUSY in out.stderr and waited + _BUSY_RETRY_S < timeout_s:
+            time.sleep(_BUSY_RETRY_S)
+            continue
         hint = ""
         if "lockfile" in out.stderr or "already in use" in out.stderr:
             hint = (" — another process (this driver, if it has already "
                     "touched JAX) holds the chip; a chip belongs to one "
                     "process, so either the driver stays off JAX or "
                     "everything runs in the driver")
+        elif _BUSY in out.stderr:
+            hint = (f" — the device stayed busy through {tries} tries "
+                    f"over {waited:.0f}s")
         raise TpuProbeError(
             f"TPU probe failed (exit {out.returncode}) on a machine with "
             f"accelerator device files {dev_files}{hint}; stderr tail:\n"
             f"{_tail(out.stderr)}")
     return int(out.stdout.strip()), pod_type
+
+
+_BUSY = "Device or resource busy"
+_BUSY_RETRY_S = 2.0
 
 
 def _tail(text, limit: int = 1500) -> str:

@@ -172,6 +172,38 @@ def test_probe_failure_is_an_error_not_zero_chips(monkeypatch):
     assert tpu.detect_chip_count() == (0, "v5litepod-4")
 
 
+def test_a_busy_device_is_probed_again_until_it_answers(monkeypatch):
+    """A chip whose last holder has just exited: the probe that says
+    ``Device or resource busy`` is tried again (every 2 s, inside the
+    function's own time limit), and the error that is finally raised says
+    how long it waited."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    monkeypatch.delenv("TPU_ACCELERATOR_TYPE", raising=False)
+    monkeypatch.setattr(tpu, "accelerator_device_files",
+                        lambda: ["/dev/vfio/1"])
+    naps = []
+    monkeypatch.setattr(tpu.time, "sleep", naps.append)
+    busy = subprocess.CompletedProcess(
+        [], 1, "", "open(/dev/vfio/1): Device or resource busy")
+    answers = [busy, busy, subprocess.CompletedProcess([], 0, "4", "")]
+    monkeypatch.setattr(tpu.subprocess, "run",
+                        lambda *a, **k: answers.pop(0))
+    assert tpu.detect_chip_count() == (4, None)
+    assert naps == [2.0, 2.0] and not answers
+    # Busy to the end: no retry once the next nap would pass the limit.
+    naps.clear()
+    monkeypatch.setattr(tpu.subprocess, "run", lambda *a, **k: busy)
+    with pytest.raises(tpu.TpuProbeError, match="stayed busy") as e:
+        tpu.detect_chip_count(timeout_s=1.0)
+    assert naps == [] and "1 tries" in str(e.value)
+    # Another failure is final at once.
+    other = subprocess.CompletedProcess([], 1, "", "no such device")
+    monkeypatch.setattr(tpu.subprocess, "run", lambda *a, **k: other)
+    with pytest.raises(tpu.TpuProbeError, match="no such device"):
+        tpu.detect_chip_count()
+    assert naps == []
+
+
 def test_peak_flops_unknown_kind_raises():
     assert tpu.peak_flops_per_chip("TPU v5 lite") == 197e12
     for kind in ("no such chip", "", "cpu", "TPU v5", "v5e"):

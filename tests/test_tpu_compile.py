@@ -100,3 +100,39 @@ def test_ragged_expert_matmul_is_one_kernel_without_a_copy_of_the_stack(
         shape(dtype=jnp.int32)).compile()
     assert compiled.as_text().count("ragged-dot") >= 3
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+@pytest.mark.parametrize("rows,kv,queries,keys,window", [
+    (1, 4, 2048, 32768, None),   # a full layer's chunk at 30k of context
+    (1, 8, 2048, 2240, 128),     # a window layer's: the chunk + its window
+    (1, 8, 16, 256, 128),        # the smallest suffix bucket
+    (4, 4, 256, 256, None),      # an admission wave of short prompts
+])
+def test_chunk_attention_kernel_compiles_at_mimos_widths(
+        one_chip, no_compile_cache, monkeypatch, rows, kv, queries, keys,
+        window):
+    """``ops/chunk_attention.py`` at MiMo-V2.5's published widths: 64 query
+    heads over 4 or 8 key heads, keys 192 wide (padded to 256 lanes in the
+    wrapper), values 128, a sink, both static variants."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import chunk_attention
+
+    monkeypatch.setattr(chunk_attention, "_interpret", lambda: False)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, qo, ko, s: chunk_attention.chunk_attention(
+            q, k, v, qo, ko, 192 ** -0.5, window, s)
+    ).lower(shape(rows, 64, queries, 192), shape(rows, kv, keys, 192),
+            shape(rows, kv, keys, 128), shape(rows, dtype=jnp.int32),
+            shape(rows, dtype=jnp.int32),
+            shape(64, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    name = "chunk_attn_full" if window is None else "chunk_attn_window"
+    assert "tpu_custom_call" in text and name in text
+    # The scores never exist outside the kernel.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
