@@ -345,10 +345,14 @@ def _embed_matmul(table: jax.Array, tokens: jax.Array,
         # scales).
         chunk = next(c for c in range(chunk, 0, -1) if n % c == 0)
 
+    # A chunk's columns are cut as the table's own ("embed"): against a
+    # table sharded so the partitioner picks this itself; against a table
+    # that every device holds whole (a hoisted compute copy) it would
+    # otherwise have every device compute every chunk, whole.
     @jax.checkpoint
     def one_chunk(tok_c):
         onehot = jax.nn.one_hot(tok_c, v, dtype=table.dtype)
-        return onehot @ table
+        return constrain(onehot @ table, (None, "embed"))
 
     def body(_, tok_c):
         return None, one_chunk(tok_c)
@@ -422,6 +426,116 @@ def _chunk_ce(x_c, targets_c, lm_head):
     return jnp.sum(logz - gold)
 
 
+def _chunks(x, targets, chunk):
+    """(B, S, ...) -> (S / chunk, B, chunk, ...): the scan's leading axis."""
+    b, s = targets.shape
+    n = s // chunk
+    return (x.reshape(b, n, chunk, -1).transpose(1, 0, 2, 3),
+            targets.reshape(b, n, chunk).transpose(1, 0, 2))
+
+
+def _ce_sum(x, targets, lm_head, chunk):
+    """Cross entropy summed over all tokens, the head matmul + CE run per
+    sequence chunk under remat."""
+
+    def body(total, xt):
+        x_c, t_c = xt
+        return total + jax.checkpoint(_chunk_ce)(x_c, t_c, lm_head), None
+
+    chunks = _chunks(x, targets, chunk)
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), chunks)
+    return total
+
+
+def _ce_sum_batch_sharded(x, targets, lm_head, chunk):
+    """:func:`_ce_sum` on a mesh that cuts the batch: each device runs the
+    chunk loop over its own sequences against the whole head, and the
+    head's gradient crosses the chips ONCE, after the loop, as a
+    reduce-scatter into the head's own shard. Left to the partitioner the
+    sum over the batch's shards is taken inside the loop, where the
+    gradient is accumulated: an all-reduce of the WHOLE head every chunk
+    (16 a step of 379 MB at internlm2-1.8b on four chips). Falls back to
+    :func:`_ce_sum` where no mesh is active, the batch is not cut, or
+    the mesh cuts the model too."""
+    from ray_tpu.parallel.sharding import (current_mesh, mesh_extent,
+                                           part_axes, resolved_spec)
+
+    mesh = current_mesh()
+    if mesh is None:
+        return _ce_sum(x, targets, lm_head, chunk)
+    batch_axes = tuple(a for part in resolved_spec(x, ("batch",))
+                       for a in part_axes(part))
+    if mesh_extent(mesh, batch_axes) == 1:
+        return _ce_sum(x, targets, lm_head, chunk)
+    if any(size > 1 for axis, size in mesh.shape.items()
+           if axis not in batch_axes):
+        # A mesh that also cuts the model (tensor, seq, expert) keeps the
+        # partitioner's program: the loop below would have to be manual
+        # over the batch's axes only, and XLA's CPU backend dies on a
+        # bfloat16 reduce-scatter there ("Invalid binary instruction
+        # opcode copy", jax 0.9).
+        return _ce_sum(x, targets, lm_head, chunk)
+    # The head's own dim that an axis of the batch also cuts (embed over
+    # fsdp): the gradient is scattered there; over the batch's other axes
+    # (data) it is summed.
+    scatter_dim, scatter_axes = 0, ()
+    for dim, part in enumerate(resolved_spec(lm_head, ("embed", "vocab"))):
+        axes = tuple(a for a in part_axes(part) if a in batch_axes)
+        if axes:
+            scatter_dim, scatter_axes = dim, axes
+            break
+    sum_axes = tuple(a for a in batch_axes if a not in scatter_axes)
+    rows = P(batch_axes)
+    grad_spec = P(*([None] * scatter_dim + [scatter_axes])) \
+        if scatter_axes else P()
+
+    def local(fn, in_specs, out_specs):
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
+
+    def forward(x, targets, lm_head):
+        return jax.lax.psum(_ce_sum(x, targets, lm_head, chunk), batch_axes)
+
+    def backward(ct, x, targets, lm_head):
+        def body(d_head, xt):
+            x_c, t_c = xt
+            _, vjp = jax.vjp(lambda x_c, w: _chunk_ce(x_c, t_c, w),
+                             x_c, lm_head)
+            dx_c, d_head_c = vjp(ct)
+            return d_head + d_head_c, dx_c
+
+        d_head, dx = jax.lax.scan(body, jnp.zeros_like(lm_head),
+                                  _chunks(x, targets, chunk))
+        if scatter_axes:
+            d_head = jax.lax.psum_scatter(
+                d_head, scatter_axes, scatter_dimension=scatter_dim,
+                tiled=True)
+        if sum_axes:
+            d_head = jax.lax.psum(d_head, sum_axes)
+        # The barrier keeps the reduction HERE. A caller that sums this
+        # gradient over a loop of microbatches would otherwise see XLA
+        # move the collective behind its loop and carry the whole
+        # unreduced float32 gradient through it (758 MB a chip at
+        # internlm2-1.8b's head, where 14.8 of 15.75 GB are spoken for).
+        d_head = jax.lax.optimization_barrier(d_head)
+        return dx.transpose(1, 0, 2, 3).reshape(x.shape), d_head
+
+    @jax.custom_vjp
+    def ce_sum(x, targets, lm_head):
+        return local(forward, (rows, rows, P()), P())(x, targets, lm_head)
+
+    def ce_sum_fwd(x, targets, lm_head):
+        return ce_sum(x, targets, lm_head), (x, targets, lm_head)
+
+    def ce_sum_bwd(saved, ct):
+        dx, d_head = local(backward, (P(), rows, rows, P()),
+                           (rows, grad_spec))(ct, *saved)
+        return dx, None, d_head
+
+    ce_sum.defvjp(ce_sum_fwd, ce_sum_bwd)
+    return ce_sum(x, targets, lm_head)
+
+
 def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
             config: LlamaConfig) -> jax.Array:
     """Next-token cross entropy. ``batch``: {"tokens": (B, S+1) int32} or
@@ -439,17 +553,7 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
     b, s, _ = x.shape
     chunk = c.loss_chunk
     if chunk and s % chunk == 0 and s > chunk:
-        n = s // chunk
-        x_chunks = x.reshape(b, n, chunk, -1).transpose(1, 0, 2, 3)
-        t_chunks = targets.reshape(b, n, chunk).transpose(1, 0, 2)
-
-        def body(total, xt):
-            x_c, t_c = xt
-            return total + jax.checkpoint(_chunk_ce)(x_c, t_c, lm_head), None
-
-        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
-                                (x_chunks, t_chunks))
-        loss = total / (b * s)
+        loss = _ce_sum_batch_sharded(x, targets, lm_head, chunk) / (b * s)
         if c.moe_experts:
             loss = loss + c.moe_aux_coef * aux / c.n_layers
         return loss

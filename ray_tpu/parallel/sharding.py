@@ -21,8 +21,12 @@ Rules = Dict[str, Union[str, Tuple[str, ...], None]]
 
 # Megatron-style transformer rules. The load-bearing choices:
 # * batch over (data, fsdp): gradients psum over both -> plain DP semantics.
-# * embed over fsdp: ZeRO-3 — params/optimizer state sharded, all-gathered
-#   per layer by XLA (with remat this is the standard FSDP schedule).
+# * embed over fsdp: ZeRO-3 — params/optimizer state sharded. XLA gathers
+#   a weight where it is used (inside the layer scan, again in the remat
+#   backward); a step that accumulates microbatches gathers its bf16
+#   compute copy once instead, outside that loop, and pins each gradient
+#   to its parameter's shard (train_step.build_train_step; docs/TRAIN.md
+#   "What crosses chips in an FSDP step, and when").
 # * heads/mlp over tensor: Megatron column->row pairs; XLA inserts the
 #   all-reduce at the row-parallel output exactly like hand-written TP.
 # * length over seq: context parallelism; attention uses ring_attention
@@ -213,10 +217,18 @@ def resolved_spec(x: jax.Array, logical_axes: Sequence[Optional[str]]) -> P:
     return P(*parts)
 
 
+def part_axes(part) -> Tuple[str, ...]:
+    """The mesh axes a PartitionSpec entry names (None, a name or a
+    tuple of names)."""
+    if part is None:
+        return ()
+    return part if isinstance(part, tuple) else (part,)
+
+
 def mesh_extent(mesh: Mesh, part) -> int:
     """Devices a PartitionSpec entry (an axis name or a tuple) spans."""
     size = 1
-    for ax in (part if isinstance(part, tuple) else (part,)):
+    for ax in part_axes(part):
         size *= mesh.shape.get(ax, 1)
     return size
 

@@ -16,8 +16,9 @@ function and XLA inserts the collectives). The builder:
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+import math
+import re
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,7 @@ from ray_tpu.parallel.sharding import (
     Rules,
     axis_rules,
     batch_sharding,
+    part_axes,
     spec_for,
     tree_shardings,
 )
@@ -70,6 +72,105 @@ def init_optimizer_state(optimizer: optax.GradientTransformation, params):
     return jax.jit(optimizer.init, out_shardings=shardings)(params)
 
 
+def _without(spec: P, axes) -> P:
+    """``spec`` with the mesh axes in ``axes`` taken out of every entry."""
+    parts = []
+    for part in spec:
+        kept = tuple(a for a in part_axes(part) if a not in axes)
+        parts.append(kept[0] if len(kept) == 1 else (kept or None))
+    while parts and parts[-1] is None:
+        parts.pop()
+    return P(*parts)
+
+
+def _pin(tree, shardings):
+    """``tree`` with each leaf constrained to its entry of the flat
+    ``shardings`` (``None`` = leave the leaf to the partitioner)."""
+    leaves, treedef = jax.tree.flatten(tree)
+    return treedef.unflatten([
+        x if sh is None else jax.lax.with_sharding_constraint(x, sh)
+        for x, sh in zip(leaves, shardings)])
+
+
+def _shard_bytes(x, sharding=None, itemsize=None) -> int:
+    """Bytes of ``x`` that one device holds under ``sharding`` (default:
+    its own)."""
+    sh = getattr(x, "sharding", None) if sharding is None else sharding
+    shape = sh.shard_shape(x.shape) if hasattr(sh, "shard_shape") else x.shape
+    return math.prod(shape) * (itemsize or jnp.dtype(x.dtype).itemsize)
+
+
+def _bytes_limit(device) -> Optional[int]:
+    """What the runtime lets a program hold on ``device``; None where it
+    does not say (the CPU, a chip that is described and not attached)."""
+    try:
+        return (device.memory_stats() or {}).get("bytes_limit")
+    except Exception:
+        return None
+
+
+class _TrainStep:
+    """What :func:`build_train_step` returns: the jitted step, called and
+    lowered like one. It reads the layout of the arguments it is handed
+    (their ``NamedSharding``s, concrete arrays or shape structs alike) and
+    passes it to the program as a static argument, so the step body can
+    say where a gradient lands and what the microbatch loop closes over
+    without the caller naming it."""
+
+    def __init__(self, jitted, batch_axes, accumulates: bool):
+        self._jitted, self._batch_axes = jitted, batch_axes
+        self._accumulates = accumulates
+        self._layouts: Dict[Tuple, Tuple] = {}
+
+    def _layout(self, params, opt_state, batch):
+        """Per leaf of ``params``: where its gradient is pinned (its own
+        sharding) and where its compute copy is (the same less the axes
+        the batch is cut over); ``None`` for a leaf that is replicated,
+        which says nothing about either (ZeRO-1's params, one device).
+        The copies are whole only if they fit the device beside what the
+        step holds anyway; else every weight stays a shard and is gathered
+        where it is used, as the partitioner places it."""
+        leaves = jax.tree.leaves(params)
+        key = tuple(getattr(leaf, "sharding", None) for leaf in leaves)
+        if key not in self._layouts:
+            grads, copies = [], []
+            for sh in key:
+                cut = (isinstance(sh, NamedSharding)
+                       and not sh.is_fully_replicated)
+                whole = NamedSharding(sh.mesh, _without(
+                    sh.spec, self._batch_axes)) if cut else None
+                grads.append(sh if cut else None)
+                copies.append(None if whole == sh else whole)
+            if not (self._accumulates and any(copies) and self._fits(
+                    (params, opt_state, batch), leaves, grads, copies)):
+                copies = [None] * len(leaves)
+            self._layouts[key] = tuple(grads), tuple(copies)
+        return self._layouts[key]
+
+    @staticmethod
+    def _fits(arguments, leaves, grads, copies) -> bool:
+        """Whether one device can hold the step's arguments, the float32
+        accumulator and the bfloat16 copy with ``copies`` whole; True
+        where the device does not say what it can hold."""
+        mesh = next(sh for sh in grads if sh is not None).mesh
+        limit = _bytes_limit(mesh.devices.flat[0])
+        if limit is None:
+            return True
+        held = sum(_shard_bytes(x) for x in jax.tree.leaves(arguments))
+        for leaf, sh, whole in zip(leaves, grads, copies):
+            held += _shard_bytes(leaf, sh, 4) + _shard_bytes(
+                leaf, whole or sh, 2)
+        return held <= limit
+
+    def __call__(self, params, opt_state, batch):
+        return self._jitted(self._layout(params, opt_state, batch), params,
+                            opt_state, batch)
+
+    def lower(self, params, opt_state, batch):
+        return self._jitted.lower(self._layout(params, opt_state, batch),
+                                  params, opt_state, batch)
+
+
 def build_train_step(
     loss_fn: Callable[[Any, Dict[str, jax.Array]], jax.Array],
     optimizer: optax.GradientTransformation,
@@ -80,49 +181,62 @@ def build_train_step(
     out_shardings=None,
 ):
     """Returns ``step(params, opt_state, batch) -> (params, opt_state,
-    metrics)``, jitted with donated state. ``out_shardings`` (a
-    ``(params, opt_state, metrics)`` sharding triple, None = let XLA
-    propagate) is how :func:`build_zero1_train_step` pins the ZeRO-1
-    layout without a second step body.
+    metrics)``, jitted with donated state (call it, or ``.lower(...)`` it).
+    ``out_shardings`` (a ``(params, opt_state, metrics)`` sharding triple,
+    None = let XLA propagate) is how :func:`build_zero1_train_step` pins
+    the ZeRO-1 layout without a second step body.
 
     ``accum_steps > 1`` splits the batch's leading axis into that many
     microbatches and accumulates fp32 gradients over a ``lax.scan`` before
-    ONE optimizer update. The fp32->bf16 parameter cast is hoisted out of
-    the microbatch loop, so both the cast and the (bandwidth-bound on TPU)
-    optimizer pass amortize over ``accum_steps`` times more tokens (its
-    worth on the chip is not measured yet — ROADMAP S6).
+    ONE optimizer update, so the (bandwidth-bound on TPU) optimizer pass
+    amortizes over ``accum_steps`` times more tokens.
+
+    What crosses chips follows the layout of the ``params`` the step is
+    handed (docs/TRAIN.md "What crosses chips in an FSDP step, and
+    when"). A gradient is pinned to its parameter's sharding, so the sum
+    over the batch's shards lands in the shard that accumulates it and
+    the accumulator is a shard, never a whole gradient. The bf16
+    compute copy is made ONCE a step, outside the microbatch loop, and
+    gathered there over the mesh axes the batch is also cut over (fsdp):
+    the loop closes over whole weights and moves none. That copy costs 2
+    bytes a parameter on every chip for the length of the step.
 
     On a multi-chip mesh keep ``batch_size / accum_steps`` a multiple of
     the batch-sharding mesh extent (data x fsdp), or XLA resorts to
     replicate-then-reshard on every microbatch slice."""
+    batch_axes = frozenset(
+        a for part in spec_for(("batch",), rules) for a in part_axes(part))
 
-    def _grads_accum(params, batch):
-        pbf = jax.tree.map(
+    def _grads_accum(params, batch, layout):
+        grad_sh, copy_sh = layout
+        pbf = _pin(jax.tree.map(
             lambda x: x.astype(jnp.bfloat16)
-            if x.dtype == jnp.float32 else x, params)
+            if x.dtype == jnp.float32 else x, params), copy_sh)
 
         def micro(g_acc, mb):
             loss, g = jax.value_and_grad(loss_fn)(pbf, mb)
             g_acc = jax.tree.map(lambda a, b: a + b.astype(a.dtype),
-                                 g_acc, g)
+                                 g_acc, _pin(g, grad_sh))
             return g_acc, loss
 
         mbs = jax.tree.map(
             lambda x: x.reshape((accum_steps, x.shape[0] // accum_steps)
                                 + x.shape[1:]), batch)
-        g0 = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), params)
+        g0 = _pin(jax.tree.map(
+            lambda x: jnp.zeros(x.shape, jnp.float32), params), grad_sh)
         grads, losses = jax.lax.scan(micro, g0, mbs)
         grads = jax.tree.map(lambda g: g / accum_steps, grads)
         return losses.mean(), grads
 
     # Jitted under its own name: the program is ``train_step`` in a
     # profiler trace and in the lowered module, whatever builds it.
-    def train_step(params, opt_state, batch):
+    def train_step(layout, params, opt_state, batch):
         with axis_rules(mesh, rules):
             if accum_steps > 1:
-                loss, grads = _grads_accum(params, batch)
+                loss, grads = _grads_accum(params, batch, layout)
             else:
                 loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+                grads = _pin(grads, layout[0])
             updates, new_opt_state = optimizer.update(grads, opt_state, params)
             new_params = optax.apply_updates(params, updates)
             metrics = {"loss": loss,
@@ -131,10 +245,10 @@ def build_train_step(
                 metrics.update(extra_metrics(new_params, batch))
         return new_params, new_opt_state, metrics
 
-    if out_shardings is not None:
-        return jax.jit(train_step, donate_argnums=(0, 1),
-                       out_shardings=out_shardings)
-    return jax.jit(train_step, donate_argnums=(0, 1))
+    pins = {} if out_shardings is None else {"out_shardings": out_shardings}
+    return _TrainStep(jax.jit(train_step, static_argnums=0,
+                              donate_argnums=(1, 2), **pins), batch_axes,
+                      accumulates=accum_steps > 1)
 
 
 # --------------------------------------------------------------- ZeRO-1
@@ -270,6 +384,92 @@ def per_replica_state_bytes(opt_state) -> int:
             per_device[shard.device] = (per_device.get(shard.device, 0)
                                         + shard.data.nbytes)
     return max(per_device.values()) if per_device else 0
+
+
+# What crosses chips in a compiled step: docs/TRAIN.md "What crosses chips
+# in an FSDP step, and when". Read from the optimized module's text, so it
+# counts what the compiler scheduled, not what the annotations asked for.
+_COLLECTIVE_OP = re.compile(
+    r" = (?P<type>.*?) (?P<kind>all-gather|all-reduce|reduce-scatter|"
+    r"all-to-all|collective-permute)(?P<start>-start)?\(")
+_ARRAY_TYPE = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_CALLED = re.compile(
+    r"\b(body|condition|calls|to_apply|true_computation|false_computation|"
+    r"branch_computations)=(%?[\w.\-]+|\{[^{}]*\})")
+
+
+def _hlo_itemsize(dtype: str) -> int:
+    """Bytes of one element of an HLO type name (``bf16``, ``s32``,
+    ``f8e4m3fn``, ``pred``)."""
+    bits = re.match(r"[a-z]+(\d+)", dtype)
+    return max(1, int(bits.group(1)) // 8) if bits else 1
+
+
+def collective_table(compiled) -> List[Dict[str, Any]]:
+    """The collectives of a compiled program (``jit(...).lower(...)
+    .compile()``, or its ``as_text()``), one row per distinct ``kind``,
+    ``dtype``, ``shape`` and ``depth``: ``{"kind", "dtype", "shape",
+    "bytes", "depth", "count"}``. ``shape`` and ``bytes`` are those of the
+    result a device holds afterwards (the gathered array of an all-gather,
+    the shard of a reduce-scatter); a combined collective gives a row an
+    array. ``depth`` is how many ``while`` loops enclose the instruction:
+    in a train step with ``accum_steps > 1`` the microbatch loop is depth
+    1 and a scanned model's layer loop depth 2, so a weight that is
+    gathered once a step reads depth 0. ``count`` is instructions in the
+    text, not executions: a row at depth 2 runs layers x microbatches
+    times. Rows come deepest first, then by ``bytes x count``."""
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    found: List[Tuple[str, str, str, Tuple[int, ...]]] = []
+    # computation -> [(computation that calls it, 1 if as a loop)]
+    callers: Dict[str, List[Tuple[str, int]]] = {}
+    entry = here = ""
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            here = head.group(1)
+            if line.startswith("ENTRY"):
+                entry = here
+            continue
+        for attr, names in _CALLED.findall(line):
+            for name in names.strip("{}").split(","):
+                callers.setdefault(name.strip().lstrip("%"), []).append(
+                    (here, int(attr in ("body", "condition"))))
+        op = _COLLECTIVE_OP.search(line)
+        if op is None:
+            continue
+        arrays = [(dtype, tuple(int(d) for d in dims.split(",") if d))
+                  for dtype, dims in _ARRAY_TYPE.findall(
+                      re.sub(r"\{[^{}]*\}", "", op.group("type")))]
+        kind = op.group("kind")
+        if op.group("start") and kind != "all-reduce":
+            # (operands..., results...), and a collective-permute's two
+            # context words behind them: keep the results
+            if kind == "collective-permute":
+                arrays = arrays[:-2]
+            arrays = arrays[len(arrays) // 2:]
+        found.extend((here, kind) + a for a in arrays)
+
+    depths: Dict[str, int] = {entry: 0}
+
+    def depth(name: str, seen=()) -> int:
+        if name not in depths:
+            depths[name] = max(
+                (depth(c, seen + (name,)) + loop
+                 for c, loop in callers.get(name, ()) if c not in seen),
+                default=0)
+        return depths[name]
+
+    rows: Dict[Tuple, Dict[str, Any]] = {}
+    for comp, kind, dtype, shape in found:
+        key = (kind, dtype, shape, depth(comp))
+        row = rows.setdefault(key, {
+            "kind": kind, "dtype": dtype, "shape": shape,
+            "bytes": math.prod(shape) * _hlo_itemsize(dtype),
+            "depth": key[3], "count": 0})
+        row["count"] += 1
+    return sorted(rows.values(), key=lambda r: (
+        -r["depth"], -r["bytes"] * r["count"], r["kind"], r["shape"]))
 
 
 def build_eval_step(loss_fn, mesh, rules=None):

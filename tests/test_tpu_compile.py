@@ -1,7 +1,8 @@
 """Compiles for a TPU v5e that is described and not attached (the chip's
 compiler is installed here): what interpret mode cannot show of the kernels
-on DeepSeek-V2's serving path at its published widths, about two seconds
-each, at no chip time. Nothing runs, so nothing here says anything about
+on DeepSeek-V2's and MiMo-V2.5's serving paths at their published widths,
+about two seconds each, and what the chip's partitioner makes of the
+four-chip FSDP train step (a quarter of a minute), at no chip time. Nothing runs, so nothing here says anything about
 results or times.
 
 The topology is described inside a fixture, never at import: only one
@@ -12,16 +13,22 @@ import pytest
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(four_chips[0])
 
 
 @pytest.fixture()
@@ -136,3 +143,92 @@ def test_chunk_attention_kernel_compiles_at_mimos_widths(
     assert "tpu_custom_call" in text and name in text
     # The scores never exist outside the kernel.
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+
+
+def test_fsdp4_step_gathers_its_weights_once_and_fits_the_chip(
+        four_chips, no_compile_cache, monkeypatch):
+    """``internlm2-1.8b.pretrain_fsdp4``'s step as the benchmark builds it
+    (its configuration, its traffic, ``build_train_step``), compiled for
+    the four described chips: every weight is gathered whole once, outside
+    the microbatch loop (depth 0); inside it no weight moves; the only
+    whole-parameter reduction left is the head's, once a microbatch and
+    not once a loss chunk (this compiler turns a reduce-scatter along a
+    first dim into an all-reduce and a slice); and the program fits the
+    chip's 15.75 GB with the whole bfloat16 copy in it."""
+    import json
+    import os
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks import families
+    from ray_tpu.ops import flash_attention
+    from ray_tpu.parallel import train_step as ts
+    from ray_tpu.parallel.mesh import MeshSpec
+    from ray_tpu.parallel.sharding import batch_sharding, tree_shardings
+
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    bench = os.path.join(root, "benchmarks")
+    with open(os.path.join(bench, "configs", "internlm2-1.8b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(bench, "traffic", "pretrain_fsdp4.json")) as f:
+        job = json.load(f)
+    fam = families.load(cfg["family"]).Train(cfg["model"], job,
+                                             cfg["train_flags"])
+    mesh = MeshSpec(**job["mesh"]).build(four_chips)
+    opt = optax.adamw(job["learning_rate"], weight_decay=job["weight_decay"])
+
+    def shaped(tree, shardings):
+        return jax.tree.map(
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+            tree, shardings)
+
+    shapes = jax.eval_shape(fam.init, jax.random.key(0))
+    layout = tree_shardings(mesh, fam.axes())
+    params_def = jax.tree.structure(shapes)
+
+    def like_params(node):
+        return jax.tree.structure(node) == params_def
+
+    state_shapes = jax.eval_shape(opt.init, shapes)
+    state_layout = jax.tree.map(
+        lambda node: layout if like_params(node)
+        else NamedSharding(mesh, P()), state_shapes, is_leaf=like_params)
+    params = shaped(shapes, layout)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (fam.items, fam.seq + 1), jnp.int32,
+        sharding=batch_sharding(mesh))}
+    step = ts.build_train_step(fam.loss, opt, mesh,
+                               accum_steps=int(job["accum"]))
+    compiled = step.lower(params, shaped(state_shapes, state_layout),
+                          batch).compile()
+    table = ts.collective_table(compiled)
+
+    def dims(shape):
+        return tuple(sorted(d for d in shape if d != 1))
+
+    weights = {path: leaf.shape for path, leaf in
+               jax.tree_util.tree_leaves_with_path(shapes) if leaf.ndim >= 2
+               and "norm" not in jax.tree_util.keystr(path)}
+    whole = {dims(shape) for shape in weights.values()}
+    sliced = {dims(shape[1:]) for path, shape in weights.items()
+              if "layers" in jax.tree_util.keystr(path)}
+    once = {dims(r["shape"]): r["count"] for r in table
+            if r["kind"] == "all-gather" and r["depth"] == 0}
+    assert all(once.get(d) == 1 for d in whole), once
+    moved = [r for r in table if r["kind"] == "all-gather" and r["depth"]
+             and dims(r["shape"]) in whole | sliced]
+    assert not moved, moved
+    reduced = [(r["shape"], r["depth"], r["count"]) for r in table
+               if r["kind"] == "all-reduce"
+               and dims(r["shape"]) in whole | sliced]
+    assert reduced in ([], [(shapes["lm_head"].shape, 1, 1)]), reduced
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75e9), memory
