@@ -241,16 +241,8 @@ def live_page_view(block_tables: Dict[str, Any], counts: Dict[str, Any],
     matches."""
     full = moe_decode.live_page_view(block_tables[FULL], counts[FULL],
                                      rows[FULL])
-    table = np.asarray(block_tables[WINDOW])
-    first, held = (np.asarray(a) for a in counts[WINDOW])
-    n = rows[WINDOW]
-    end = first + held
-    index = np.maximum(first, end - n)[:, None] + np.arange(n)[None, :]
-    real = index < end[:, None]
-    window = np.zeros((2,) + index.shape, np.int32)
-    window[0] = np.where(real, np.take_along_axis(
-        table, np.minimum(index, table.shape[1] - 1), axis=1), 0)
-    window[1] = np.where(real, index, -1)
+    window = moe_decode.window_page_view(
+        block_tables[WINDOW], *counts[WINDOW], rows[WINDOW])
     return {FULL: full, WINDOW: window}
 
 

@@ -1,8 +1,8 @@
-"""What the served models with expert layers share (``deepseek_decode``,
-``mimo_decode``), so that neither uses the other as a library: the cast of
-a replica's weights, the decode step's view of a slot's pages in whole
-groups, and the counters an expert layer adds to a step. Nothing here knows
-a model's config."""
+"""What the served models behind the engine's seam share (``deepseek_decode``,
+``mimo_decode``, ``phi4flash_decode``), so that none uses another as a
+library: the cast of a replica's weights, the decode step's view of a
+slot's pages in whole groups and of a window kind's pages, and the counters
+an expert layer adds to a step. Nothing here knows a model's config."""
 
 from __future__ import annotations
 
@@ -81,3 +81,23 @@ def live_page_view(block_tables, counts, rows: int):
     view[1, :n] = slot
     view[2, :n] = index
     return view
+
+
+def window_page_view(table, first, held, rows: int):
+    """A window kind's part of a decode step's view: ``(2, slots, rows)``
+    int32, for each slot the pool pages of its last ``rows`` window pages
+    and their indices in the sequence, the slot holding ``held`` pages
+    from index ``first`` on; a slot that does not step (0 held) and the
+    entries past a slot's pages are the scratch page at index -1, which no
+    position matches."""
+    table = np.asarray(table)
+    first, held = np.asarray(first), np.asarray(held)
+    end = first + held
+    index = np.maximum(first, end - rows)[:, None] \
+        + np.arange(rows)[None, :]
+    real = index < end[:, None]
+    window = np.zeros((2,) + index.shape, np.int32)
+    window[0] = np.where(real, np.take_along_axis(
+        table, np.minimum(index, table.shape[1] - 1), axis=1), 0)
+    window[1] = np.where(real, index, -1)
+    return window

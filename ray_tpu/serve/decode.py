@@ -144,7 +144,11 @@ class DecodeEngine:
     ``page_kinds(config)``: which kinds of page its pool has and what
     each keeps (all tokens, or the last ``window``); the engine then
     keeps an allocator and a block table a kind (``_windows``), and such
-    a model runs without the prefix index.
+    a model runs without the prefix index. And it may say
+    ``slot_state(config)``: the pool's leaves that are indexed by SLOT
+    (``[layers, slots + 1, ...]``, the last row scratch) and not by page,
+    a recurrent layer's state; its prefill programs are then told each
+    row's slot and whether its prompt ends there (``_prefill_tables``).
 
     ``slots`` concurrent sequences of up to ``capacity`` tokens share
     one paged KV pool. ``step()`` advances every active slot one token;
@@ -305,6 +309,17 @@ class DecodeEngine:
             self._windows[name] = WindowPages(
                 pages, slots, self.slot_pages_max, self.page_tokens,
                 kinds[name]["window"])
+        # Slot state. A model may keep leaves of its pool a SLOT and not a
+        # page (``slot_state``: a recurrent layer's state, ``[layers,
+        # slots + 1, ...]``). The engine owns no program for them: a row
+        # that starts at position 0 starts from zero in the model's own
+        # prefill (so seating, and a preemption's recompute, reset it), a
+        # chunk reads what the last chunk left, and a decode step leaves
+        # the state of a slot outside its view as it is. What the engine
+        # owes is each prefill row's slot, the scratch row ``slots`` for a
+        # pad row (``_prefill_tables``).
+        self._state_leaves = (tuple(ld.slot_state(config))
+                              if hasattr(ld, "slot_state") else ())
         # The pool is the model's own pytree (leaves ``[layers,
         # pages + 1, page_tokens, ...]``: K and V per head for llama,
         # one latent row a token for deepseek, K and V a kind for mimo);
@@ -313,7 +328,13 @@ class DecodeEngine:
         pool = ld.init_page_pool(
             config, {self._kind: self.pool_pages,
                      **{k: w.alloc.pages for k, w in self._windows.items()}}
-            if self._windows else self.pool_pages, self.page_tokens)
+            if self._windows else self.pool_pages, self.page_tokens,
+            **({"slots": slots} if self._state_leaves else {}))
+        # Bytes of state one slot holds (the step log's ``state_bytes``
+        # counts the seated slots').
+        self._slot_state_bytes = sum(
+            pool[name].nbytes // pool[name].shape[1]
+            for name in self._state_leaves)
         self._pool_names = tuple(pool)
         # Which kind's page ids index a leaf (the handoff's leaf map).
         self._leaf_kind = {
@@ -398,14 +419,16 @@ class DecodeEngine:
                       if prefix_match_min_tokens is None
                       else prefix_match_min_tokens)
         self.prefix = None
-        if entries > 0 and self._windows:
+        if entries > 0 and (self._windows or self._state_leaves):
             # A shared boundary would need the full pages up to it AND
             # the window pages that hold the ``window`` tokens before it,
-            # which the index does not pin: a model with a window kind
+            # which the index does not pin (and the state at it, which
+            # nothing keeps): a model with a window kind or slot state
             # runs without it (docs/SERVING.md, "Page kinds").
             logger.info(
-                "model %s has page kinds %s: this engine runs without the "
-                "prefix index", ld.__name__, list(kinds))
+                "model %s has page kinds %s and slot state %s: this engine "
+                "runs without the prefix index", ld.__name__, list(kinds),
+                list(self._state_leaves))
         elif entries > 0:
             pmax = (rt_config.kv_prefix_max_pages
                     if prefix_max_pages is None else prefix_max_pages)
@@ -789,6 +812,9 @@ class DecodeEngine:
         if not self.steplog.enabled:
             return call()
         attrs.setdefault("program", key[0])
+        if self._state_leaves and "view_pages" in attrs:
+            # The slots whose state this decode advances.
+            attrs["state_slots"] = len(self._active)
         if self._windows and "view_pages" in attrs:
             # A decode over page kinds: what the window kinds' lists hold
             # beside the first kind's, their length in pages and the
@@ -804,6 +830,15 @@ class DecodeEngine:
         if then is not None:
             self.steplog.begin(then)
         return out
+
+    def _prefill_attrs(self, cross_rows: int, **more: Any) -> Dict[str, Any]:
+        """What a prefill's ``launch`` says beside its tokens for a model
+        with slot state, whose prefill runs the layers behind its cache
+        for the rows that END their prompt only: ``cross_rows``, how many
+        those are (0 for a chunk that ends none)."""
+        if not self._state_leaves:
+            return {}
+        return {"cross_rows": cross_rows, **more}
 
     def _slice(self, name: str, **attrs: Any) -> None:
         """The step goes on in slice ``name`` (``serve/steplog.py``).
@@ -873,26 +908,39 @@ class DecodeEngine:
                                    free=w.alloc.free_count)
 
     def _prefill_tables(self, slots: List[int], positions: List[int],
-                        bt: np.ndarray, bucket: int):
+                        bt: np.ndarray, bucket: int,
+                        ends: Optional[List[bool]] = None):
         """The block tables a prefill program takes: ``bt`` itself for a
-        model of one kind; for one with window kinds a dict, the first
+        model of one kind and no slot state; else a dict, the first
         kind's ``bt`` under its name and, a window kind, the columns
         that cover ``bucket`` tokens from each row's position and its
         window before it, with the index of their first page
-        (``<kind>_first``). Pad rows repeat the last."""
+        (``<kind>_first``); for a model with slot state also ``slots``,
+        each row's slot, and ``ends``, whether the row's prompt ends in
+        this program (``None``: every row's does). A pad row repeats the
+        last row's pages of every kind, which it writes with the same
+        values, and names the scratch row ``self.slots`` of the state,
+        never a real slot."""
         import jax.numpy as jnp
 
-        if not self._windows:
+        if not self._windows and not self._state_leaves:
             return jnp.asarray(bt)
         out = {self._kind: jnp.asarray(bt)}
+        pads = len(bt) - len(slots)
         for kind, w in self._windows.items():
             width = -(-(bucket + w.window) // self.page_tokens) + 1
             cols = [w.columns(s, p, width)
                     for s, p in zip(slots, positions)]
-            cols += cols[-1:] * (len(bt) - len(cols))
+            cols += cols[-1:] * pads
             out[kind] = jnp.asarray(np.stack([c for c, _ in cols]))
             out[f"{kind}_first"] = jnp.asarray(
                 np.asarray([f for _, f in cols], np.int32))
+        if self._state_leaves:
+            out["slots"] = jnp.asarray(
+                np.asarray(list(slots) + [self.slots] * pads, np.int32))
+            ends = [True] * len(slots) if ends is None else list(ends)
+            out["ends"] = jnp.asarray(
+                np.asarray(ends + ends[-1:] * pads, bool))
         return out
 
     def _live_view(self, tables: np.ndarray,
@@ -1129,6 +1177,11 @@ class DecodeEngine:
         req.request_id = request_id or f"req-{next(_req_ids)}"
         req.prompt_len = len(req.tokens)
         req.prefill_only = bool(prefill_only)
+        if self._state_leaves and (prefill_only or adopt is not None):
+            raise ValueError(
+                f"model {self._ld.__name__} keeps slot state "
+                f"{list(self._state_leaves)}: a handoff carries pages and "
+                f"no state, so this engine neither publishes nor adopts one")
         if adopt is not None:
             self._validate_adopt(req, adopt)
             req.adopt = dict(adopt)
@@ -1601,7 +1654,16 @@ class DecodeEngine:
                          self.capacity)
             by_bucket.setdefault(bucket, []).append(req)
         T = self.page_tokens
+        # A model may bound the tokens of one prefill program
+        # (``PREFILL_TOKENS_MAX``: its temporaries grow with rows x
+        # bucket); a bucket's group then goes as several waves.
+        cap = getattr(ld, "PREFILL_TOKENS_MAX", None)
+        waves = []
         for bucket, group in by_bucket.items():
+            rows = max(1, cap // bucket) if cap else len(group)
+            waves += [(bucket, group[i:i + rows])
+                      for i in range(0, len(group), rows)]
+        for bucket, group in waves:
             n = 1
             while n < len(group):
                 n *= 2
@@ -1630,7 +1692,8 @@ class DecodeEngine:
                                          [0] * len(group), bt, bucket),
                     jnp.asarray(slot_ids), *self._draw_args(group, n),
                     n=n, bucket=bucket),
-                tokens=sum(len(r.tokens) for r in group))
+                tokens=sum(len(r.tokens) for r in group),
+                **self._prefill_attrs(len(group), prefix=0))
             for req in group:
                 self._trim_windows(req.slot, len(req.tokens))
             ids = self._fetch_ids(ids, "paged_prefill")
@@ -1693,7 +1756,8 @@ class DecodeEngine:
                     jnp.asarray(slot_ids),
                     *self._draw_args(group, n),
                     n=n, bucket=bucket, width=width),
-                tokens=sum(len(r.tokens) - r.prefix_len for r in group))
+                tokens=sum(len(r.tokens) - r.prefix_len for r in group),
+                **self._prefill_attrs(len(group)))
             for req in group:
                 self._trim_windows(req.slot, len(req.tokens))
             ids = self._fetch_ids(ids, "paged_suffix")
@@ -1739,6 +1803,7 @@ class DecodeEngine:
         rows[0, :step_tok] = req.tokens[req.prefilled:
                                         req.prefilled + step_tok]
         bt = self._block_tables[slot:slot + 1, :width]
+        ends = req.prefilled + step_tok >= len(req.tokens)
         self.prefill_chunks += 1
         t0 = time.time()
         ids, self.cache = self._dispatch_fresh(
@@ -1747,12 +1812,13 @@ class DecodeEngine:
                 self.params, self.cache, jnp.asarray(rows),
                 jnp.asarray([req.prefilled], np.int32),
                 jnp.asarray([req.prefilled + step_tok], np.int32),
-                self._prefill_tables([slot], [req.prefilled], bt, bucket),
+                self._prefill_tables([slot], [req.prefilled], bt, bucket,
+                                     ends=[ends]),
                 jnp.asarray([slot], np.int32),
                 *self._draw_args([req], 1),
                 n=1, bucket=bucket, width=width),
             then="admit", program="prefill_chunk", tokens=step_tok,
-            prefix=req.prefilled)
+            prefix=req.prefilled, **self._prefill_attrs(int(ends)))
         # The chunk's own window pages, but for the window of the next
         # position, are dead the moment the program is dispatched.
         self._trim_windows(slot, req.prefilled + step_tok)
@@ -2514,7 +2580,12 @@ class DecodeEngine:
                    for k, n in self.pages_in_use().items()},
                 "kv_tokens": self._ctx_tokens() - len(self._active) + sum(
                     r.prefilled for r in self._prefilling.values())}
-               if self._windows else {}))
+               if self._windows else {}),
+            # A model with slot state: the bytes of it the seated slots
+            # hold, decoding or between two prefill chunks.
+            **({"state_bytes": self._slot_state_bytes
+                * (len(self._active) + len(self._prefilling))}
+               if self._state_leaves else {}))
 
     def warm_decode(self) -> None:
         """Dispatch the step loop's one-token decode once at every rung
@@ -2706,6 +2777,8 @@ class DecodeEngine:
             out["pages_total_by_kind"] = {
                 self._kind: self._pages.pages,
                 **{k: w.alloc.pages for k, w in self._windows.items()}}
+        if self._state_leaves:
+            out["state_bytes_total"] = self._slot_state_bytes * self.slots
         out["page_tokens"] = self.page_tokens
         out["pages_pinned"] = (self.prefix.pinned_pages
                                if self.prefix is not None else 0)
@@ -2763,7 +2836,10 @@ class DecodeEngine:
             # holds them.
             "weights_bytes": sum(
                 w.nbytes for w in self._jax.tree.leaves(self.params)),
-            "weights_dtype": str(self.params["lm_head"].dtype),
+            # (a tied embedding is the head).
+            "weights_dtype": str(self.params[
+                "lm_head" if "lm_head" in self.params
+                else "tok_embed"].dtype),
             "pid": os.getpid(),
             **self._compile_watch.snapshot(),
         }
@@ -3261,3 +3337,18 @@ class MimoDecodeDeployment(LlamaDecodeDeployment):
         from ray_tpu.models import mimo, mimo_decode
 
         return mimo, mimo_decode
+
+
+class Phi4FlashDecodeDeployment(LlamaDecodeDeployment):
+    """The same deployment over Phi-4-mini-flash (``models/phi4flash.py``):
+    a full and a window kind of page, a recurrent state a slot, one full
+    layer's keys and values read by the cross-attention layers. The model
+    has none of the engine's optional programs, so ``decode_chunk > 1``,
+    ``spec_k > 0`` and a mesh are refused by the engine, and a handoff by
+    ``submit``; its window kind and its state turn the prefix index off."""
+
+    @staticmethod
+    def model_modules():
+        from ray_tpu.models import phi4flash, phi4flash_decode
+
+        return phi4flash, phi4flash_decode

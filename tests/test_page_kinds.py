@@ -244,7 +244,9 @@ def test_a_handoff_carries_both_kinds():
 
 # sha256 (first 16 hex) of the lowered text of the engine's programs at
 # the parent of PR 43 (cdaa02b), llama and deepseek at toy sizes: page
-# kinds, the router's score and its bias left them letter for letter.
+# kinds, the router's score and its bias left them letter for letter; and
+# mimo's at the parent of PR 45 (6eada8f), which slot state, the prefill
+# rows' slots and the wave's cap left so.
 LOWERED_AT_PARENT = {
     "llama.decode": "5ee1c9392ee387ff",
     "llama.paged_prefill": "e3bd72ad3a1c0d98",
@@ -252,16 +254,19 @@ LOWERED_AT_PARENT = {
     "deepseek.decode": "960c4371757e880d",
     "deepseek.paged_prefill": "95ae7bc75e2000da",
     "deepseek.paged_suffix": "2f96485ef153e703",
+    "mimo.decode": "a40ff6a77742dffe",
+    "mimo.paged_prefill": "f365075f30fb20ce",
+    "mimo.paged_suffix": "1da9344d4831f740",
 }
 
 
-@pytest.mark.parametrize("name", ["llama", "deepseek"])
+@pytest.mark.parametrize("name", ["llama", "deepseek", "mimo"])
 def test_one_kind_models_lower_to_the_text_they_had(name):
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import (deepseek, deepseek_decode, llama,
-                                llama_decode)
+                                llama_decode, mimo, mimo_decode)
     from ray_tpu.serve.decode import DecodeEngine
 
     mod, dec, cfg = {
@@ -269,6 +274,7 @@ def test_one_kind_models_lower_to_the_text_they_had(name):
             vocab_size=61, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
             mlp_dim=64, max_seq_len=128)),
         "deepseek": (deepseek, deepseek_decode, deepseek.PRESETS["debug"]),
+        "mimo": (mimo, mimo_decode, mimo.PRESETS["debug"]),
     }[name]
     eng = DecodeEngine(mod.init_params(cfg, jax.random.key(0)), cfg,
                        slots=4, capacity=128, page_tokens=16,
@@ -276,19 +282,25 @@ def test_one_kind_models_lower_to_the_text_they_had(name):
                        metrics_enabled=False, trace_spans=False)
     state = jnp.asarray(eng._host_state())
     temps = jnp.zeros((4,), jnp.float32)
-    bt = jnp.asarray(eng._block_tables)
-    view = jnp.asarray(eng._live_view(eng._block_tables, eng._slot_pages))
+    view = jax.tree.map(jnp.asarray, eng._live_view(eng._block_tables,
+                                                    eng._slot_pages))
     one = jnp.zeros((1,), jnp.int32)
+
+    def tables(width):
+        """A model of one kind takes the table itself."""
+        return eng._prefill_tables([0], [0], eng._block_tables[:1, :width],
+                                   16)
+
     draw = (jnp.zeros((1,), jnp.float32), jnp.asarray(0, jnp.int32))
     lowered = {
         "decode": eng._decode.lower(eng.params, eng.cache, state, view,
                                     temps),
         "paged_prefill": eng._paged_prefill.lower(
             eng.params, eng.cache, jnp.zeros((1, 16), jnp.int32), one,
-            bt[:1, :1], one, *draw, n=1, bucket=16),
+            tables(1), one, *draw, n=1, bucket=16),
         "paged_suffix": eng._paged_suffix.lower(
             eng.params, eng.cache, jnp.zeros((1, 16), jnp.int32), one, one,
-            bt[:1, :2], one, *draw, n=1, bucket=16, width=2),
+            tables(2), one, *draw, n=1, bucket=16, width=2),
     }
     got = {f"{name}.{key}": hashlib.sha256(
         low.as_text().encode()).hexdigest()[:16]

@@ -1,7 +1,9 @@
 """Compiles for a TPU v5e that is described and not attached (the chip's
 compiler is installed here): what interpret mode cannot show of the kernels
 on DeepSeek-V2's and MiMo-V2.5's serving paths at their published widths,
-about two seconds each, and what the chip's partitioner makes of the
+about two seconds each, Phi-4-mini-flash's decode and prefill programs
+whole at its published widths (ten seconds each, with what they take of
+the chip's memory), and what the chip's partitioner makes of the
 four-chip FSDP train step (a quarter of a minute), at no chip time. Nothing runs, so nothing here says anything about
 results or times.
 
@@ -232,3 +234,74 @@ def test_fsdp4_step_gathers_its_weights_once_and_fits_the_chip(
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.75e9), memory
+
+
+@pytest.mark.parametrize("program", ["decode:4096", "decode:8192",
+                                     "chunk:1x512x32", "wave:8x512x8"])
+def test_phi4flash_programs_compile_at_published_widths_inside_the_chip(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """``models/phi4flash_decode.py`` at Phi-4-mini-flash's published
+    widths and the cell's layout (64 slots, 8,192 full and 704 window pages
+    of 64, shapes only): the decode step at the rung that copies its view
+    (4,096 rows, 1.34 GB) and at the top rung (which gathers a block at a
+    time), a 512-token chunk over 32 pages and a whole-prefill wave of
+    ``PREFILL_TOKENS_MAX``. Arguments (12.45 GB: weights, both kinds of
+    page, the state) and temporaries fit the chip's 15.75 GB."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import phi4flash
+    from ray_tpu.models import phi4flash_decode as pd
+    from ray_tpu.ops import chunk_attention
+
+    monkeypatch.setattr(chunk_attention, "_interpret", lambda: False)
+    cfg = phi4flash.Phi4FlashConfig()
+    slots, T = 64, 64
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, spec: shape(
+            spec[0], jnp.float32 if str(path[-1].key)
+            in phi4flash.FLOAT32_LEAVES else jnp.bfloat16),
+        phi4flash._shapes(cfg), is_leaf=phi4flash._is_spec)
+    pool = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype), jax.eval_shape(
+            lambda: pd.init_page_pool(cfg, {"full": 8192, "window": 704}, T,
+                                      slots=slots)))
+    i32 = jnp.int32
+    kind, dims = program.split(":")
+    if kind == "decode":
+        view = {"full": shape((3, int(dims)), i32),
+                "window": shape((2, slots, 9), i32)}
+        compiled = jax.jit(
+            lambda p, pool, view, lens, toks: pd.paged_decode_step(
+                p, pool, view, lens, toks, cfg), donate_argnums=(1,)
+        ).lower(params, pool, view, shape((slots,), i32),
+                shape((slots,), i32)).compile()
+    else:
+        rows, bucket, width = (int(x) for x in dims.split("x"))
+        assert rows * bucket <= pd.PREFILL_TOKENS_MAX
+        tables = {"full": shape((rows, width), i32),
+                  "window": shape((rows, -(-(bucket + 512) // T) + 1), i32),
+                  "window_first": shape((rows,), i32),
+                  "slots": shape((rows,), i32),
+                  "ends": shape((rows,), jnp.bool_)}
+        compiled = jax.jit(
+            lambda p, toks, pool, bt, plens, lens: pd.paged_prefill_suffix(
+                p, toks, pool, bt, cfg, plens, lens), donate_argnums=(2,)
+        ).lower(params, shape((rows, bucket), i32), pool, tables,
+                shape((rows,), i32), shape((rows,), i32)).compile()
+        assert "chunk_attn_window" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.5e9
+    # The pool is written where it lies: the donated buffers are aliased.
+    assert mem.alias_size_in_bytes > 4.7e9
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 14.5e9, (program, peak)
+    if program == "decode:4096":
+        assert 1.3e9 < mem.temp_size_in_bytes < 1.6e9   # the view's copy
+    if program == "decode:8192":
+        assert mem.temp_size_in_bytes < 0.3e9           # no copy
