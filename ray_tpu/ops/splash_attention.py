@@ -1,9 +1,10 @@
 """Splash attention: schedule-driven block-sparse flash attention (Pallas).
 
-"Splash" = SParse fLASH. Where :mod:`ray_tpu.ops.flash_attention` iterates
-the full (q-tile, kv-tile) grid and *skips compute* on dead tiles, this
-module builds **per-head static mask schedules** (the defining structure of
-the reference-world splash kernel, cf. jax's
+"Splash" = SParse fLASH. :mod:`ray_tpu.ops.flash_attention` takes ONE mask
+algebra for every head (causal, a window, a query offset, segment ids) and
+walks a flat list of its live tiles; this module builds **per-head static
+mask schedules** (the defining structure of the reference-world splash
+kernel, cf. jax's
 ``splash_attention_kernel.py``/``splash_attention_mask_info.py`` — studied
 for the schedule idea, implemented independently on this repo's kernel
 style):
@@ -14,8 +15,10 @@ style):
   schedule lists, per q-tile, EXACTLY the live kv-tiles —
   ``kv_ids[nq, L]`` + ``lens[nq]`` ride to the kernel as scalar-prefetch
   operands, so the grid's minor axis walks the compacted schedule and dead
-  tiles are never even fetched (the flash kernel still pays their
-  pipelined loads);
+  tiles are never fetched. The schedule is a RECTANGLE (``L`` = the longest
+  row; shorter rows pad with steps that hold their last tile), where the
+  flash kernels' is flat: for a plain causal mask ``L`` is every KV tile,
+  so that call is better served by ``mask=None``;
 * the backward uses the same schedules (dQ walks the q-schedule, dK/dV the
   TRANSPOSED schedule: per kv-tile, its live q-tiles).
 
@@ -482,8 +485,8 @@ def splash_attention(
     window: Optional[int] = None,
     segment_ids: Optional[jax.Array] = None,
     kv_segment_ids: Optional[jax.Array] = None,
-    block_q: int = 256,
-    block_k: int = 256,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     scale: Optional[float] = None,
 ) -> jax.Array:
     """Block-sparse attention with per-head static mask schedules.
@@ -492,9 +495,9 @@ def splash_attention(
     (heads with equal masks share one compacted kernel launch — e.g.
     ``[LocalMask(1024)] * 6 + [FullMask()] * 2`` for a local/global
     stack). With ``mask=None`` the causal/window algebra (and data-
-    dependent ``segment_ids``) delegates to the shared flash kernel —
-    those patterns gain nothing from explicit schedules that tile
-    arithmetic doesn't already give.
+    dependent ``segment_ids``) delegates to the shared flash kernel, whose
+    flat schedule visits the same tiles; tiles left at ``None`` are then
+    that kernel's choice, and 256 for the schedules built here.
     """
     if mask is None:
         from ray_tpu.ops.flash_attention import flash_attention
@@ -514,7 +517,7 @@ def splash_attention(
         raise ValueError(f"{len(masks)} masks for {hq} heads")
     if scale is None:
         scale = d ** -0.5
-    bq, bk = min(block_q, sq), min(block_k, sk)
+    bq, bk = min(block_q or 256, sq), min(block_k or 256, sk)
     if sq % bq or sk % bk:
         raise ValueError(f"seq lengths ({sq}, {sk}) must divide blocks "
                          f"({bq}, {bk})")

@@ -95,8 +95,8 @@ def _merge_partial(o1, lse1, o2, lse2):
 
 def ring_flash_attention_local(q, k, v, axis_name: str = "seq",
                                causal: bool = True,
-                               block_q: int = 256,
-                               block_k: int = 256) -> jax.Array:
+                               block_q: Optional[int] = None,
+                               block_k: Optional[int] = None) -> jax.Array:
     """Ring attention whose per-hop block compute is the fused Pallas flash
     kernel (``flash_attention_stats``): each hop produces a normalized
     partial (out, lse) for the K/V shard currently held, merged across hops
@@ -106,7 +106,8 @@ def ring_flash_attention_local(q, k, v, axis_name: str = "seq",
 
     Per-device shapes: q/k/v (B, S_local, H, D), global sequence laid out
     contiguously around the ring. Differentiable: the flash VJP accepts an
-    lse cotangent, and ppermute autodiff reverses the rotation.
+    lse cotangent, and ppermute autodiff reverses the rotation. The hops'
+    tiles are the kernel's own choice for the shard's length unless given.
     """
     from ray_tpu.ops.flash_attention import flash_attention_stats
 
@@ -114,12 +115,6 @@ def ring_flash_attention_local(q, k, v, axis_name: str = "seq",
     rank = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     scale = d ** -0.5
-    bq = min(block_q, s_local)
-    bk = min(block_k, s_local)
-    if s_local % bq or s_local % bk:
-        raise ValueError(
-            f"per-device sequence shard {s_local} must divide flash blocks "
-            f"({bq}, {bk}); pick block sizes that divide S/seq_parallelism")
 
     # Lane-align head_dim for the kernel (exact: zero-pad).
     d_pad = (-d) % 128
@@ -147,15 +142,15 @@ def ring_flash_attention_local(q, k, v, axis_name: str = "seq",
         for them; here only the rotation cost remains)."""
         if not causal:
             return flash_attention_stats(qt, kt, vt, scale, False, None, 0,
-                                         bq, bk)
+                                         block_q, block_k)
         if step == 0:
             return flash_attention_stats(qt, kt, vt, scale, True, None, 0,
-                                         bq, bk)
+                                         block_q, block_k)
 
         def full(ops):
             kt_, vt_ = ops
             return flash_attention_stats(qt, kt_, vt_, scale, False, None,
-                                         0, bq, bk)
+                                         0, block_q, block_k)
 
         def dead(ops):
             return (jnp.zeros((b, h, s_local, d_full), q.dtype),

@@ -16,72 +16,266 @@ def _rand(key, shape):
     return jax.random.normal(key, shape, jnp.float32)
 
 
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_matches_xla_forward(causal):
-    key = jax.random.key(0)
-    kq, kk, kv = jax.random.split(key, 3)
-    b, s, h, d = 2, 256, 4, 64
-    q, k, v = _rand(kq, (b, s, h, d)), _rand(kk, (b, s, h, d)), \
-        _rand(kv, (b, s, h, d))
-    ref = attention(q, k, v, causal=causal, impl="xla")
-    out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_flash_gqa_forward():
-    key = jax.random.key(1)
-    kq, kk, kv = jax.random.split(key, 3)
-    b, s, hq, hkv, d = 1, 256, 8, 2, 64
-    q = _rand(kq, (b, s, hq, d))
-    k = _rand(kk, (b, s, hkv, d))
-    v = _rand(kv, (b, s, hkv, d))
-    ref = attention(q, k, v, causal=True, impl="xla")
-    out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_flash_grads_match():
-    key = jax.random.key(2)
-    kq, kk, kv = jax.random.split(key, 3)
-    b, s, h, d = 1, 256, 2, 64
-    q, k, v = _rand(kq, (b, s, h, d)), _rand(kk, (b, s, h, d)), \
-        _rand(kv, (b, s, h, d))
-
-    def loss_ref(q, k, v):
-        return jnp.sum(attention(q, k, v, causal=True, impl="xla") ** 2)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(
-            flash_attention(q, k, v, causal=True, block_q=128,
-                            block_k=128) ** 2)
-
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_out = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(g_out, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   atol=5e-4, rtol=5e-4)
-
-
-def _dense_masked(q, k, v, causal=True, window=None, seg=None):
-    """Reference: dense softmax attention with the splash mask algebra."""
-    b, s, h, d = q.shape
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (d ** -0.5)
-    rows = jnp.arange(s)[:, None]
-    cols = jnp.arange(s)[None, :]
-    mask = jnp.ones((s, s), bool)
+def _dense(q, k, v, causal=True, window=None, seg=None, q_offset=0):
+    """Plain attention over (B, S, H, D) with GQA, a query offset, a window
+    and segment ids: (out, lse) in float32."""
+    b, sq, hq, d = q.shape
+    sk, hkv = k.shape[1], k.shape[2]
+    k, v = (jnp.repeat(x, hq // hkv, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (d ** -0.5)
+    rows = q_offset + jnp.arange(sq)[:, None]
+    cols = jnp.arange(sk)[None, :]
+    mask = jnp.ones((sq, sk), bool)
     if causal:
         mask &= rows >= cols
     if window is not None:
         mask &= (rows - cols) < window
-    mask = jnp.broadcast_to(mask, (b, h, s, s))
+    mask = jnp.broadcast_to(mask, (b, hq, sq, sk))
     if seg is not None:
-        same = seg[:, None, :, None] == seg[:, None, None, :]
-        mask &= jnp.broadcast_to(same, (b, h, s, s))
-    scores = jnp.where(mask, scores, -1e30)
-    p = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        mask &= (seg[:, :sq] if sq == sk else seg[:, -sq:])[
+            :, None, :, None] == seg[:, None, None, :]
+    s = jnp.where(mask, s, -1e30)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    return out, jax.nn.logsumexp(s, axis=-1)
+
+
+def _qkv(seed, b, sq, sk, hq, hkv, d):
+    kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
+    return (_rand(kq, (b, sq, hq, d)), _rand(kk, (b, sk, hkv, d)),
+            _rand(kv, (b, sk, hkv, d)))
+
+
+# One row a case: (queries, keys, query heads, KV heads, head size), the
+# masks, and (block_q, block_k) with None = chosen from the shape. At 1,024
+# with tiles of 256 a causal call has dead, diagonal and interior tiles.
+_LONG = (1024, 1024, 4, 2, 32)      # 16 / 8 heads scaled down
+_CASES = {
+    "causal": ((256, 256, 4, 4, 64), dict(causal=True), (128, 128)),
+    "full": ((256, 256, 4, 4, 64), dict(causal=False), (128, 128)),
+    "gqa": ((256, 256, 8, 2, 64), dict(causal=True), (128, 128)),
+    "long-gqa-chosen": (_LONG, dict(causal=True), (None, None)),
+    "long-gqa-256": (_LONG, dict(causal=True), (256, 256)),
+    "long-gqa-128": (_LONG, dict(causal=True), (128, 128)),
+    "long-gqa-256x128": (_LONG, dict(causal=True), (256, 128)),
+    "long-gqa-128x512": (_LONG, dict(causal=True), (128, 512)),
+    "offset-256": ((512, 1024, 4, 2, 32), dict(causal=True, q_offset=512),
+                   (256, 256)),
+    "offset-chosen": ((512, 1024, 4, 2, 32), dict(causal=True, q_offset=512),
+                      (None, None)),
+    "window-256": (_LONG, dict(causal=True, window=300), (256, 256)),
+    "window-chosen": (_LONG, dict(causal=True, window=300), (None, None)),
+    "window-offset": ((512, 1024, 4, 2, 32),
+                      dict(causal=True, window=200, q_offset=512),
+                      (128, 256)),
+    "segments-256": (_LONG, dict(causal=True, seg=True), (256, 256)),
+    "segments-chosen": (_LONG, dict(causal=True, seg=True), (None, None)),
+    "full-long-256x128": (_LONG, dict(causal=False), (256, 128)),
+}
+
+
+def _case(name):
+    (sq, sk, hq, hkv, d), masks, tiles = _CASES[name]
+    masks = dict(masks)
+    q, k, v = _qkv(sorted(_CASES).index(name), 1, sq, sk, hq, hkv, d)
+    seg = None
+    if masks.pop("seg", False):
+        # Packed sequences whose edges fall inside tiles of every size.
+        seg = jnp.searchsorted(jnp.asarray([200, 520, 900]),
+                               jnp.arange(sk), side="right")[None, :]
+    return q, k, v, masks, seg, tiles
+
+
+def _flash(q, k, v, masks, seg, tiles):
+    return flash_attention(q, k, v, block_q=tiles[0], block_k=tiles[1],
+                           segment_ids=seg, **masks)
+
+
+@pytest.mark.parametrize("name", sorted(_CASES))
+def test_flash_matches_xla_forward(name):
+    q, k, v, masks, seg, tiles = _case(name)
+    ref, _ = _dense(q, k, v, seg=seg, **masks)
+    if seg is None and "window" not in masks and "q_offset" not in masks:
+        # The shapes the XLA path takes too: hold the plain reference to it.
+        np.testing.assert_allclose(
+            np.asarray(ref), np.asarray(attention(
+                q, k, v, causal=masks["causal"], impl="xla")),
+            atol=2e-5, rtol=2e-5)
+    out = _flash(q, k, v, masks, seg, tiles)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    if tiles != (128, 128):
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_flash(q, k, v, masks, seg,
+                                               (128, 128))),
+            atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("name", sorted(_CASES))
+def test_flash_grads_match(name):
+    q, k, v, masks, seg, tiles = _case(name)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(_dense(q, k, v, seg=seg, **masks)[0] ** 2)
+
+    def loss_flash(tiles):
+        return lambda q, k, v: jnp.sum(
+            _flash(q, k, v, masks, seg, tiles) ** 2)
+
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_out = jax.grad(loss_flash(tiles), argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(g_out, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=5e-4, rtol=5e-4)
+    if tiles != (128, 128):
+        g_128 = jax.grad(loss_flash((128, 128)), argnums=(0, 1, 2))(q, k, v)
+        for a, b_ in zip(g_out, g_128):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("causal,q_offset,tiles", [
+    (False, 0, (None, None)),      # a ring hop over an earlier shard
+    (False, 0, (256, 128)),
+    (True, 0, (256, 256)),         # the ring's local hop
+    (True, 512, (128, 256)),
+])
+def test_flash_stats_takes_a_cotangent_for_lse(causal, q_offset, tiles):
+    """``flash_attention_stats`` as ring attention calls it ((B, H, S, D),
+    both results used, so ``lse`` has a cotangent of its own) against the
+    plain reference and against the 128-tile run."""
+    from ray_tpu.ops.flash_attention import flash_attention_stats
+
+    sq, sk = (512, 1024) if q_offset else (1024, 1024)
+    q, k, v = _qkv(11, 1, sq, sk, 4, 2, 128)
+    w = _rand(jax.random.key(12), (1, 4, sq))
+
+    def loss_ref(q, k, v):
+        out, lse = _dense(q, k, v, causal=causal, q_offset=q_offset)
+        return jnp.sum(out ** 2) + jnp.sum(lse * w)
+
+    def loss_flash(tiles):
+        def loss(q, k, v):
+            out, lse = flash_attention_stats(
+                *(x.transpose(0, 2, 1, 3) for x in (q, k, v)), 128 ** -0.5,
+                causal, None, q_offset, *tiles)
+            return jnp.sum(out ** 2) + jnp.sum(lse * w)
+        return loss
+
+    ref = jax.value_and_grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    got = jax.value_and_grad(loss_flash(tiles), argnums=(0, 1, 2))(q, k, v)
+    small = jax.value_and_grad(loss_flash((128, 128)),
+                               argnums=(0, 1, 2))(q, k, v)
+    for a, b_, c in zip(jax.tree.leaves(got), jax.tree.leaves(ref),
+                        jax.tree.leaves(small)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=5e-4, rtol=5e-4)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   atol=1e-4, rtol=1e-4)
+
+
+def _tiles_by_hand(sq, sk, bq, bk, causal, window, q_offset):
+    """The live tiles and the tiles a band's edge crosses, over the (query
+    tile, KV tile) plane, from the dense mask itself."""
+    rows = q_offset + np.arange(sq)[:, None]
+    cols = np.arange(sk)[None, :]
+    mask = np.ones((sq, sk), bool)
+    if causal:
+        mask &= rows >= cols
+    if window is not None:
+        mask &= rows - cols < window
+    tiles = mask.reshape(sq // bq, bq, sk // bk, bk)
+    live = tiles.any(axis=(1, 3))
+    return live, live & ~tiles.all(axis=(1, 3))
+
+
+@pytest.mark.parametrize("masks", [
+    dict(causal=True),
+    dict(causal=True, window=512),
+    dict(causal=True, segmented=True),
+    dict(causal=True, window=512, q_offset=2048),
+    dict(causal=False),
+    dict(causal=True, block_q=256, block_k=1024),
+], ids=["causal", "window-512", "segments", "window-offset", "full",
+        "causal-256x1024"])
+def test_schedule_visits_live_tiles_and_masks_the_crossed_ones(masks):
+    """The trace-time count at the train cell's lengths: no kernel visits a
+    dead tile (a resident tile no band reaches keeps the one step that
+    writes its zeros), and the mask is built on exactly the tiles a band's
+    edge crosses (on every live tile with segment ids)."""
+    from ray_tpu.ops.flash_attention import schedule_stats
+
+    stats = schedule_stats(4096, 4096, head_dim=128, **masks)
+    assert set(stats) == {"flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"}
+    for name, s in stats.items():
+        live, crossed = _tiles_by_hand(
+            4096, 4096, s["block_q"], s["block_k"], masks["causal"],
+            masks.get("window"), masks.get("q_offset", 0))
+        empty = int((~live.any(axis=0 if name == "flash_bwd_dkv" else 1))
+                    .sum())
+        assert s["live"] == live.sum(), (name, s)
+        assert s["visited"] == s["live"] + empty, (name, s)
+        assert empty == 0 or "q_offset" in masks
+        assert s["total"] == live.size
+        want = live if masks.get("segmented") else crossed
+        assert s["masked"] == want.sum(), (name, s)
+        if "block_q" in masks:
+            assert (s["block_q"], s["block_k"]) == (256, 1024)
+    if masks == dict(causal=True):
+        s = stats["flash_fwd"]
+        assert s["live"] < s["total"] and 0 < s["masked"] < s["live"]
+    if masks == dict(causal=False):
+        assert all(s["masked"] == 0 and s["live"] == s["total"]
+                   for s in stats.values())
+
+
+def test_a_row_no_band_reaches_is_still_written():
+    """A query tile whose window lies wholly before the keys it is given
+    has no live tile: its one step writes zeros and an lse of -1e30."""
+    from ray_tpu.ops.flash_attention import (flash_attention_stats,
+                                             schedule_stats)
+
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in _qkv(13, 1, 256, 256, 2, 2,
+                                                     128))
+    s = schedule_stats(256, 256, causal=True, window=64, q_offset=512,
+                       block_q=128, block_k=128)["flash_fwd"]
+    assert (s["visited"], s["live"]) == (2, 0)
+    out, lse = flash_attention_stats(q, k, v, 1.0, True, 64, 512, 128, 128)
+    assert not np.asarray(out).any() and (np.asarray(lse) == -1e30).all()
+    grads = jax.grad(lambda q, k, v: jnp.sum(flash_attention_stats(
+        q, k, v, 1.0, True, 64, 512, 128, 128)[0]), argnums=(0, 1, 2))(
+            q, k, v)
+    assert not any(np.asarray(g).any() for g in grads)
+
+
+def test_the_grid_walks_the_schedule():
+    """The three calls' grids are (batch, heads, steps visited): the KV
+    heads for dK/dV, whose steps cover the group's query heads."""
+    from ray_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(14, 1, 1024, 1024, 4, 2, 128)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, block_q=256, block_k=256))))(q, k, v)
+    grids = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    live = fa.schedule_stats(1024, 1024, block_q=256, block_k=256)[
+        "flash_fwd"]["live"]
+    assert live == 10
+    assert grids == {"flash_fwd": (1, 4, live), "flash_bwd_dq": (1, 4, live),
+                     "flash_bwd_dkv": (1, 2, 2 * live)}, grids
+
+
+def _dense_masked(q, k, v, causal=True, window=None, seg=None):
+    """Reference: dense softmax attention with the splash mask algebra."""
+    return _dense(q, k, v, causal=causal, window=window, seg=seg)[0]
 
 
 @pytest.mark.parametrize("window", [32, 128])
@@ -175,29 +369,6 @@ def test_ring_flash_matches_dense_and_grads():
 
     g_ref = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     g_out = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
-    for a, b_ in zip(g_out, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   atol=5e-4, rtol=5e-4)
-
-
-def test_flash_gqa_grads_match():
-    key = jax.random.key(3)
-    kq, kk, kv = jax.random.split(key, 3)
-    b, s, hq, hkv, d = 1, 128, 4, 2, 32
-    q = _rand(kq, (b, s, hq, d))
-    k = _rand(kk, (b, s, hkv, d))
-    v = _rand(kv, (b, s, hkv, d))
-
-    def loss_ref(q, k, v):
-        return jnp.sum(attention(q, k, v, causal=True, impl="xla") ** 2)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(
-            flash_attention(q, k, v, causal=True, block_q=128,
-                            block_k=128) ** 2)
-
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_out = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g_out, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=5e-4, rtol=5e-4)

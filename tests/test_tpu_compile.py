@@ -4,7 +4,8 @@ on DeepSeek-V2's and MiMo-V2.5's serving paths at their published widths,
 about two seconds each, Phi-4-mini-flash's decode and prefill programs
 whole at its published widths (ten seconds each, with what they take of
 the chip's memory), and what the chip's partitioner makes of the
-four-chip FSDP train step (a quarter of a minute), at no chip time. Nothing runs, so nothing here says anything about
+four-chip FSDP train step (a quarter of a minute), and the three flash-attention training kernels at that step's
+shape under the names the benchmark reads, at no chip time. Nothing runs, so nothing here says anything about
 results or times.
 
 The topology is described inside a fixture, never at import: only one
@@ -234,6 +235,56 @@ def test_fsdp4_step_gathers_its_weights_once_and_fits_the_chip(
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.75e9), memory
+
+
+def test_flash_kernels_compile_at_the_train_cell_shape_under_their_names(
+        one_chip, no_compile_cache, monkeypatch):
+    """``ops/flash_attention.py`` forward and backward at
+    ``internlm2-1.8b.pretrain_fsdp4``'s call, ``[1,16,4096,128]`` with 8 KV
+    heads in bfloat16, causal, with the tiles the code chooses: the three
+    kernels lower through Mosaic inside the VMEM each asks for, and the
+    benchmark's readers (``progtrace.kernel_of`` / ``result_shape``) find
+    them by name, ``flash_bwd_dq``'s first result ``[1,16,4096,128]``."""
+    import os
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks import progtrace
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, 8, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32)),
+        argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    calls = {}
+    for line in compiled.as_text().splitlines():
+        kernel = progtrace.kernel_of(line.strip())
+        if kernel:
+            calls.setdefault(kernel, []).append(
+                progtrace.result_shape(line.strip()))
+    assert calls == {
+        "flash_fwd": [("bf16", (1, 16, 4096, 128))],
+        "flash_bwd_dq": [("bf16", (1, 16, 4096, 128))],
+        # The group's sum is written once, in the input's dtype.
+        "flash_bwd_dkv": [("bf16", (1, 8, 4096, 128))],
+    }, calls
+    stats = fa.schedule_stats(4096, 4096, 128, jnp.bfloat16)
+    for name, s in stats.items():
+        assert s["visited"] == s["live"] < s["total"], (name, s)
+        assert s["vmem_bytes"] <= fa._VMEM_BUDGET, (name, s)
+    # No float32 copy of dK / dV a query head, no lane-broadcast rows for
+    # dK/dV: what the three calls leave in HBM besides their results.
+    assert compiled.memory_analysis().temp_size_in_bytes < 150e6
 
 
 @pytest.mark.parametrize("program", ["decode:4096", "decode:8192",
