@@ -1,0 +1,343 @@
+"""Kernel-alone timings of the decode step's paged attention on the chip:
+``ray_tpu/ops/paged_decode_attention.py`` against the gather-then-attend it
+replaced, at the two shapes ``phi-4-mini-flash.reasoning_batch`` runs.
+
+    chiprun -- python3 microbench_paged.py                # both shapes
+    chiprun -- python3 microbench_paged.py --blocks 4,8,16 --check
+
+* **window**: 64 lists of 9 pages (one a slot), window 512, a pool of
+  8 x 705 pages of 64 tokens x 1,280 lanes, bfloat16;
+* **shared**: the view of 64 slots at 768-2,688 tokens of context
+  (``moe_decode.live_page_view``: groups of 16 pages, some 160 live lists
+  of a rung of 256, a quarter of their rows padding), a pool of 8,193
+  pages.
+
+A time is the host clock over ``--calls`` back-to-back calls closed by one
+``block_until_ready``. ``share`` is the tokens a query sees x their keys'
+and values' bytes over the time x 819 GB/s: the benchmark's count
+(``benchmarks/phi4flash_counts.py``), padding and masked tokens not
+credited. The XLA path is the parent's: a window layer's gather and
+``_attend_rows``; for the shared cache one copy of the live pages (``gather``,
+once a step for eight layers) and the loop over blocks of 32 groups
+(``attend``, a layer). ``--partials`` times the kernel with every list's
+partials written and a slot's lists added up by XLA. ``--check`` holds
+kernel and XLA path to plain float32 attention. Rows go to
+``chiprun_out/paged_sweep.jsonl``; nothing here runs off the chip.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.models import moe_decode, phi4flash
+from ray_tpu.models import phi4flash_decode as pd
+from ray_tpu.ops import paged_decode_attention as pda
+
+HBM = 819e9
+T, G = 64, moe_decode.VIEW_GROUP
+# The cell's layout: 64 slots, 704 window pages a layer, 8,192 full pages.
+SLOTS, WINDOW_POOL, SHARED_POOL, RUNG = 64, 705, 8193, 4096
+WINDOW_LAYER = 3    # the layer of the flat window pool the lists point into
+
+
+def _time(fn, args, calls):
+    jax.block_until_ready(fn(*args))
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    out = None
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / calls * 1e3
+
+
+def _pool(key, pages, c, dtype):
+    return jax.random.normal(key, (pages, T, c.kv_width),
+                             jnp.float32).astype(dtype)
+
+
+def _window_case(c, rng):
+    """One list a slot of its last ``keep`` window pages, a slot at
+    position ``pos``: ``(lists, owner, index, pos)``."""
+    keep = -(-(c.window - 1) // T) + 1
+    pos = rng.integers(600, 6000, size=SLOTS).astype(np.int32)
+    held = pos // T + 1
+    # A table from the sequence's page 0 on, its last ``keep`` filled.
+    table = np.zeros((SLOTS, int(held.max())), np.int32)
+    free = rng.permutation(np.arange(1, WINDOW_POOL)).tolist()
+    for s in range(SLOTS):
+        for i in range(held[s] - keep, held[s]):
+            table[s, i] = free.pop()
+    view = moe_decode.window_page_view(table, np.zeros(SLOTS, np.int32),
+                                       held, keep)
+    return (view[0] + WINDOW_LAYER * WINDOW_POOL,
+            np.arange(SLOTS, dtype=np.int32), view[1], pos)
+
+
+def _shared_case(rng):
+    pos = rng.integers(768, 2688, size=SLOTS).astype(np.int32)
+    counts = pos // T + 1
+    free = rng.permutation(np.arange(1, SHARED_POOL))
+    table = np.zeros((SLOTS, int(counts.max())), np.int32)
+    at = 0
+    for s in range(SLOTS):
+        table[s, :counts[s]] = free[at:at + counts[s]]
+        at += counts[s]
+    view = moe_decode.live_page_view(table, counts, RUNG)
+    return (view[0].reshape(-1, G), view[1].reshape(-1, G)[:, 0],
+            view[2].reshape(-1, G), pos)
+
+
+def _seen(owner, index, pos, window):
+    """(n, G, T) bool on the host: the op's validity rule."""
+    tok = index[:, :, None] * T + np.arange(T)[None, None, :]
+    at = pos[np.maximum(owner, 0)][:, None, None]
+    seen = (owner >= 0)[:, None, None] & (index >= 0)[:, :, None] \
+        & (tok <= at)
+    if window is not None:
+        seen &= tok > at - window
+    return seen
+
+
+def _kernel(c, window):
+    def run(q, k, v, lists, owner, index, pos):
+        _, total, part = pda.paged_decode_attention(
+            pd._flat_queries(q, c), k, v,
+            pda.page_lists(lists, owner, index, pos, T, window),
+            c.softmax_scale)
+        return pd._own_values(part, c) / jnp.where(
+            total > 0, total, 1.0)[..., None]
+    return jax.jit(run)
+
+
+def _kernel_partials(c, window):
+    """Every list's partials out of the kernel, a slot's lists added up
+    outside it (the 0/1 matrix at ``highest``)."""
+    def run(q, k, v, lists, owner, index, pos):
+        n, B = owner.shape[0], q.shape[0]
+        plan = pda.page_lists(lists, owner, index, pos, T, window)
+        order = jnp.arange(n, dtype=jnp.int32)
+        m, l, acc = pda._partials(pd._flat_queries(q, c), k, v, plan, order,
+                                  n, c.softmax_scale)
+        visited = (order < plan.count)[:, None]
+        m = jnp.where(visited, m[..., 0], -1e30)
+        l = jnp.where(visited, l[..., 0], 0.0)
+        part = jnp.where(visited[..., None], pd._own_values(acc, c), 0.0)
+        mine = (owner[None, :] == jnp.arange(B)[:, None])     # (B, n)
+        top = jnp.max(jnp.where(mine[:, :, None], m[None], -1e30), axis=1)
+        w = jnp.where(mine[:, :, None],
+                      jnp.exp(m[None] - top[:, None]), 0.0)   # (B, n, H)
+        high = jax.lax.Precision.HIGHEST
+        total = jnp.einsum("bnh,nh->bh", w, l, precision=high)
+        out = jnp.einsum("bnh,nhd->bhd", w, part, precision=high)
+        return out / jnp.where(total > 0, total, 1.0)[..., None]
+    return jax.jit(run)
+
+
+def _xla_window(c):
+    def run(q, k, v, lists, owner, index, pos):
+        B, R = lists.shape
+        kk = k[lists].reshape(B, R * T, c.kv_width)
+        vv = v[lists].reshape(B, R * T, c.kv_width)
+        w_pos = (index[:, :, None] * T
+                 + jnp.arange(T)[None, None, :]).reshape(B, R * T)
+        back = pos[:, None] - w_pos
+        seen = ((jnp.repeat(index, T, axis=1) >= 0) & (back >= 0)
+                & (back < c.window))
+        return pd._attend_rows(q, kk, vv, seen, c)
+    return jax.jit(run)
+
+
+def _xla_shared(c):
+    """The parent's ``paged_decode_step``: ``gather`` copies the live
+    blocks' pages once, ``attend`` is one reading layer."""
+    block = min(RUNG // G, 32)      # groups read at a time
+    def live_blocks(owner):
+        return -(-jnp.sum(owner >= 0) // block)
+
+    def gather(k, v, lists, owner):
+        n = owner.shape[0]
+        flat = lists.reshape(-1)
+
+        def copy(i, both):
+            rows = jax.lax.dynamic_slice_in_dim(flat, i * block * G,
+                                                block * G)
+            return tuple(jax.lax.dynamic_update_slice_in_dim(
+                rows_of, leaf[rows].reshape(block, G * T, c.kv_width),
+                i * block, 0) for rows_of, leaf in zip(both, (k, v)))
+
+        return jax.lax.fori_loop(
+            0, live_blocks(owner), copy, tuple(
+                jax.lax.empty((n, G * T, c.kv_width), k.dtype)
+                for _ in range(2)))
+
+    def attend(q, k_list, v_list, owner, index, pos):
+        B, H = q.shape[0], c.n_heads
+        n = owner.shape[0]
+        valid = ((owner >= 0)[:, None, None]
+                 & (index[:, :, None] * T + jnp.arange(T)[None, None, :]
+                    <= pos[jnp.maximum(owner, 0)][:, None, None]))
+        valid = valid.reshape(n, 1, G * T)
+        of_group = jnp.maximum(owner, 0)
+        mine = owner[None, :] == jnp.arange(B)[:, None]
+        high = jax.lax.Precision.HIGHEST
+        q_flat = pd._flat_queries(q, c)
+
+        def one(i, carry):
+            top, total, acc = carry
+            g0 = i * block
+            whose = jax.lax.dynamic_slice_in_dim(of_group, g0, block)
+            seen = jax.lax.dynamic_slice_in_dim(valid, g0, block)
+            part_of = jax.lax.dynamic_slice_in_dim(mine, g0, block, 1)
+            k = jax.lax.dynamic_slice_in_dim(k_list, g0, block)
+            v = jax.lax.dynamic_slice_in_dim(v_list, g0, block)
+            s = jnp.einsum("ghc,gtc->ght", q_flat[whose], k,
+                           preferred_element_type=jnp.float32)
+            s = jnp.where(seen, s * c.softmax_scale, -1e30)
+            new = jnp.maximum(top, jnp.max(jnp.where(
+                part_of[:, :, None], s.max(-1)[None], -1e30), axis=1))
+            e = jnp.where(seen, jnp.exp(s - new[whose][..., None]), 0.0)
+            part = pd._own_values(jnp.einsum(
+                "ght,gtc->ghc", e.astype(v.dtype), v,
+                preferred_element_type=jnp.float32), c)
+            adds = part_of.astype(jnp.float32)
+            shrink = jnp.exp(top - new)
+            total = total * shrink + jnp.einsum(
+                "bg,gh->bh", adds, e.sum(-1), precision=high)
+            acc = acc * shrink[..., None] + jnp.einsum(
+                "bg,ghd->bhd", adds, part, precision=high)
+            return new, total, acc
+
+        _, total, acc = jax.lax.fori_loop(
+            0, live_blocks(owner), one,
+            (jnp.full((B, H), -1e30, jnp.float32),
+             jnp.zeros((B, H), jnp.float32),
+             jnp.zeros((B, H, 2 * c.head_dim), jnp.float32)))
+        return acc / jnp.where(total > 0.0, total, 1.0)[..., None]
+
+    return jax.jit(gather), jax.jit(attend)
+
+
+def _reference(c, q, k, v, lists, owner, seen):
+    """Plain float32 attention of every slot over the tokens it sees, a
+    slot at a time on the device: (B, H, 2 D)."""
+    own, pair = pd._head_maps(c)
+
+    @jax.jit
+    def slot(qb, kk, vv, ok):
+        qh = qb.reshape(c.n_heads, c.head_dim).astype(jnp.float32)
+        kh = kk.reshape(-1, c.n_kv_heads, c.head_dim).astype(jnp.float32)
+        vh = vv.reshape(-1, c.kv_pairs, 2 * c.head_dim).astype(jnp.float32)
+        high = jax.lax.Precision.HIGHEST
+        s = jnp.einsum("hd,tkd,hk->ht", qh, kh, jnp.asarray(own),
+                       precision=high) * c.softmax_scale
+        s = jnp.where(ok[None, :], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("ht,tjd,hj->hd", p, vh, jnp.asarray(pair),
+                          precision=high)
+
+    out = []
+    for b in range(q.shape[0]):
+        mine = np.nonzero(owner == b)[0]
+        rows = lists[mine].reshape(-1)
+        out.append(slot(q[b], k[rows], v[rows],
+                        jnp.asarray(seen[mine].reshape(-1))))
+    return jnp.stack(out)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--blocks", default="",
+                    help="BLOCK_PAGES to time, e.g. 4,8,16")
+    ap.add_argument("--calls", type=int, default=50)
+    ap.add_argument("--partials", action="store_true")
+    ap.add_argument("--check", action="store_true")
+    ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--out", default="chiprun_out/paged_sweep.jsonl")
+    args = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        raise SystemExit("microbench_paged.py times the chip's kernel: "
+                         "run it through chiprun")
+    c = phi4flash.Phi4FlashConfig()
+    dtype = jnp.dtype(args.dtype)
+    rng = np.random.default_rng(0)
+    keys = jax.random.split(jax.random.key(0), 5)
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    blocks = [int(b) for b in args.blocks.split(",") if b] \
+        or [pda.BLOCK_PAGES]
+
+    def emit(row):
+        print(json.dumps(row), flush=True)
+        with open(args.out, "a") as f:
+            f.write(json.dumps(row) + "\n")
+
+    for shape, window, pool_pages, case in (
+            ("window", c.window, 8 * WINDOW_POOL, _window_case(c, rng)),
+            ("shared", None, SHARED_POOL, _shared_case(rng))):
+        lists, owner, index, pos = case
+        seen = _seen(owner, index, pos, window)
+        n = len(owner)
+        base = {"shape": shape, "lists": n, "pages_a_list": lists.shape[1],
+                "live_lists": int(seen.any((1, 2)).sum()),
+                "live_pages": int(seen.any(2).sum()),
+                "padded_pages": int((~seen.any(2))[seen.any((1, 2))].sum()),
+                "tokens_seen": int(seen.sum()), "dtype": dtype.name}
+        useful = base["tokens_seen"] * 2 * c.kv_width * dtype.itemsize
+        q = jax.random.normal(keys[0], (SLOTS, c.dim),
+                              jnp.float32).astype(dtype)
+        k = _pool(keys[1], pool_pages, c, dtype)
+        v = _pool(keys[2], pool_pages, c, dtype)
+        dev = [jnp.asarray(a) for a in (lists, owner, index, pos)]
+
+        def row(variant, ms, **more):
+            emit({**base, "variant": variant, "ms": round(ms, 4),
+                  "share_of_hbm_pct": round(
+                      100 * useful / (ms / 1e3) / HBM, 2), **more})
+
+        got = {}
+        for b in blocks:
+            pda.BLOCK_PAGES = b
+            fn = _kernel(c, window)
+            row("kernel", _time(fn, (q, k, v, *dev), args.calls),
+                block_pages=b)
+            got[f"kernel block_pages={b}"] = fn(q, k, v, *dev)
+            if args.partials:
+                fn = _kernel_partials(c, window)
+                row("kernel, partials a list",
+                    _time(fn, (q, k, v, *dev), args.calls), block_pages=b)
+                got[f"partials block_pages={b}"] = fn(q, k, v, *dev)
+        if shape == "window":
+            fn = _xla_window(c)
+            row("xla gather + attend",
+                _time(fn, (q, k, v, *dev), args.calls))
+            got["xla"] = fn(q, k, v, *dev)
+        else:
+            gather, attend = _xla_shared(c)
+            both = gather(k, v, dev[0], dev[1])
+            t_g = _time(gather, (k, v, dev[0], dev[1]), args.calls)
+            t_a = _time(attend, (q, *both, *dev[1:]), args.calls)
+            row("xla gather (once a step)", t_g)
+            row("xla attend (a layer)", t_a)
+            row("xla a layer of eight", t_a + t_g / 8)
+            got["xla"] = attend(q, *both, *dev[1:])
+        if args.check:
+            want = _reference(c, q, k, v, lists, owner, seen)
+            top = float(jnp.abs(want).max())
+            for who, out in got.items():
+                err = float(jnp.abs(out - want).max())
+                emit({"shape": shape, "check": who, "max_abs_err": err,
+                      "largest_value": top, "ok": err <= 2e-2 * top})
+                if err > 2e-2 * top:
+                    raise SystemExit(f"{shape}: {who} is {err} from plain "
+                                     f"float32 attention (largest {top})")
+
+
+if __name__ == "__main__":
+    main()

@@ -24,11 +24,12 @@ where they lie.
   position and only in a program some row of which ends its prompt
   (``block_tables["ends"]``): nothing reads them anywhere else.
 * **decode** (``paged_decode_step``): one token a slot through every
-  layer. The full kind's live pages are gathered ONCE a step for the eight
-  layers that read them (``full_gather``), in ``moe_decode``'s groups of
-  one slot's pages; a view too large to hold a copy of is gathered a block
-  at a time in every layer. A slot that owns no row of the view (idle, or
-  between two prefill chunks) keeps its state bit for bit.
+  layer. Both kinds' pages are read where they lie, by the kernel that
+  scores them (``ops/paged_decode_attention.py``): a window layer hands it
+  one list of ``keep`` pages a slot, the eight readers of the full kind
+  ``moe_decode``'s groups of one slot's pages; no program copies pages
+  first. A slot that owns no row of the view (idle, or between two prefill
+  chunks) keeps its state bit for bit.
 """
 
 from __future__ import annotations
@@ -48,16 +49,13 @@ from ray_tpu.models.moe_decode import VIEW_GROUP, view_rows  # noqa: F401
 from ray_tpu.models.phi4flash import FLOAT32_LEAVES, Phi4FlashConfig
 from ray_tpu.ops.chunk_attention import chunk_attention
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.paged_decode_attention import (page_lists,
+                                                paged_decode_attention)
 from ray_tpu.ops.selective_scan import selective_scan, selective_step
 
 Pool = Dict[str, jax.Array]
 FULL, WINDOW = "full", "window"
 
-# Rows of the full kind's list that a decode step reads at a time.
-VIEW_BLOCK = 512
-# The largest copy of the view's pages a decode step makes for its eight
-# reading layers; past it every layer gathers for itself, a block at a time.
-HOIST_BYTES = 3 << 29
 # The most tokens (rows x bucket) the engine gives one prefill program: a
 # wave's temporaries (the scan's float32 inputs and outputs, 60 KB a token a
 # layer) have to fit beside a pool that fills the chip.
@@ -495,7 +493,7 @@ def paged_decode_step(params: Dict[str, Any], pool: Pool,
     T = pool["full_k"].shape[2]
     pages, owner, index = view[FULL][0], view[FULL][1], view[FULL][2]
     w_pages, w_index = view[WINDOW][0], view[WINDOW][1]      # (B, R)
-    N, G, R = pages.shape[0], VIEW_GROUP, w_pages.shape[1]
+    N, G = pages.shape[0], VIEW_GROUP
     if N % G:
         raise ValueError(f"a view of {N} rows is not whole groups of {G}")
     pos = lengths
@@ -510,12 +508,26 @@ def paged_decode_step(params: Dict[str, Any], pool: Pool,
         pages[None, :], 0), axis=1)
     w_write = jnp.sum(jnp.where(w_index == (pos // T)[:, None],
                                 w_pages, 0), axis=1)
-    w_pos = (w_index[:, :, None] * T
-             + jnp.arange(T)[None, None, :]).reshape(B, R * T)
-    back = pos[:, None] - w_pos
-    w_seen = ((jnp.repeat(w_index, T, axis=1) >= 0)
-              & (back >= 0) & (back < c.window))             # (B, R T)
     flat, shapes = _flat(pool)
+    # Both kinds' pages as the kernel's lists, built once for every layer
+    # that reads them: a window layer one list a slot, the readers of the
+    # full kind ``moe_decode``'s groups of one slot's pages.
+    w_lists = page_lists(w_pages, jnp.arange(B, dtype=jnp.int32), w_index,
+                         pos, T, c.window)
+    f_lists = page_lists(pages.reshape(N // G, G),
+                         owner.reshape(N // G, G)[:, 0],
+                         index.reshape(N // G, G), pos, T)
+
+    def attend(q, k_pool, v_pool, lists, first_page=0):
+        """``q`` (B, H x D) over each slot's lists of pages, read where
+        they lie in ``k_pool`` / ``v_pool`` (``paged_decode_attention``).
+        Returns (B, H, 2 D) float32; a slot that sees nothing gets
+        zeros."""
+        _, total, part = paged_decode_attention(
+            _flat_queries(q, c), k_pool, v_pool, lists, c.softmax_scale,
+            first_page)
+        total = total[..., None]
+        return jnp.where(total > 0.0, _own_values(part, c) / total, 0.0)
 
     def front(carry, inp):
         x, wk, wv, ssm, conv = carry
@@ -527,11 +539,8 @@ def paged_decode_step(params: Dict[str, Any], pool: Pool,
             qkv[:, c.dim:c.dim + c.kv_width].astype(wk.dtype))
         wv = wv.at[base + w_write, off].set(
             qkv[:, c.dim + c.kv_width:].astype(wv.dtype))
-        with jax.named_scope("window_gather"):
-            k = wk[base + w_pages].reshape(B, R * T, c.kv_width)
-            v = wv[base + w_pages].reshape(B, R * T, c.kv_width)
         with jax.named_scope("window_attn"):
-            att = _attend_rows(qkv[:, :c.dim], k, v, w_seen, c)
+            att = attend(qkv[:, :c.dim], wk, wv, w_lists, base)
         x = _mlp(w_layer, _diff_out(w_layer, att, lam0, x, c), c)
         return (x, wk, wv, ssm, conv), None
 
@@ -551,97 +560,15 @@ def paged_decode_step(params: Dict[str, Any], pool: Pool,
         qkv[:, c.dim:c.dim + c.kv_width].astype(flat["full_k"].dtype))
     fv = flat["full_v"].at[f_write, off].set(
         qkv[:, c.dim + c.kv_width:].astype(flat["full_v"].dtype))
-    valid = ((owner >= 0)[:, None]
-             & (index[:, None] * T + jnp.arange(T)[None, :]
-                <= pos[jnp.maximum(owner, 0)][:, None]))     # (N, T)
-    valid = valid.reshape(N // G, 1, G * T)
-    group_owner = owner.reshape(N // G, G)[:, 0]             # (N / G,)
-    of_group = jnp.maximum(group_owner, 0)
-    mine = group_owner[None, :] == jnp.arange(B)[:, None]    # (B, N / G)
-    # The list is read in BLOCKS of whole groups, as many as hold live
-    # rows (the list's real rows come first): the rung a program was
-    # compiled for bounds the loop, the live pages set the work.
-    block = min(N // G, VIEW_BLOCK // G)                     # groups
-    live_blocks = -(-jnp.sum(group_owner >= 0) // block)
-    high = jax.lax.Precision.HIGHEST
-    hoist = 2 * N * T * c.kv_width * fk.dtype.itemsize <= HOIST_BYTES
-    if hoist:
-        # One gather a step serves the eight layers that read these pages:
-        # the live blocks' pages are copied into lists that nothing has
-        # written before (no block past them is ever read).
-        def copy(i, lists):
-            rows = jax.lax.dynamic_slice_in_dim(pages, i * block * G,
-                                                block * G)
-            return tuple(jax.lax.dynamic_update_slice_in_dim(
-                rows_of, leaf[rows].reshape(block, G * T, c.kv_width),
-                i * block, 0) for rows_of, leaf in zip(lists, (fk, fv)))
-
-        with jax.named_scope("full_gather"):
-            k_list, v_list = jax.lax.fori_loop(
-                0, live_blocks, copy, tuple(
-                    jax.lax.empty((N // G, G * T, c.kv_width), fk.dtype)
-                    for _ in range(2)))
-
-    def fetch(g0):
-        if hoist:
-            return (jax.lax.dynamic_slice_in_dim(k_list, g0, block),
-                    jax.lax.dynamic_slice_in_dim(v_list, g0, block))
-        rows = jax.lax.dynamic_slice_in_dim(pages, g0 * G, block * G)
-        with jax.named_scope("full_gather"):
-            return (fk[rows].reshape(block, G * T, c.kv_width),
-                    fv[rows].reshape(block, G * T, c.kv_width))
-
-    def shared_attend(q):
-        """``q`` (B, H x D) over the listed pages: every group of
-        ``VIEW_GROUP`` pages against its owner's queries in one matmul,
-        the softmax PER SLOT across its groups and blocks from the running
-        maximum and sum (``mimo_decode.full_attend``). Returns (B, H, 2 D)
-        float32."""
-        H = c.n_heads
-        q_flat = _flat_queries(q, c)                         # (B, H, KVD)
-
-        def one(i, carry):
-            top, total, acc = carry        # (B, H), (B, H), (B, H, KVD)
-            g0 = i * block
-            whose = jax.lax.dynamic_slice_in_dim(of_group, g0, block)
-            seen = jax.lax.dynamic_slice_in_dim(valid, g0, block)
-            part_of = jax.lax.dynamic_slice_in_dim(mine, g0, block, 1)
-            k, v = fetch(g0)
-            s = jnp.einsum("ghc,gtc->ght", q_flat[whose], k,
-                           preferred_element_type=jnp.float32)
-            s = jnp.where(seen, s * c.softmax_scale, -1e30)
-            new = jnp.maximum(top, jnp.max(jnp.where(
-                part_of[:, :, None], s.max(-1)[None], -1e30), axis=1))
-            e = jnp.where(seen, jnp.exp(s - new[whose][..., None]), 0.0)
-            part = _own_values(jnp.einsum(
-                "ght,gtc->ghc", e.astype(v.dtype), v,
-                preferred_element_type=jnp.float32), c)      # (g, H, 2D)
-            # A 0/1 matrix at full precision adds a slot's groups up in
-            # float32 and rounds nothing.
-            adds = part_of.astype(jnp.float32)
-            shrink = jnp.exp(top - new)
-            total = total * shrink + jnp.einsum(
-                "bg,gh->bh", adds, e.sum(-1), precision=high)
-            acc = acc * shrink[..., None] + jnp.einsum(
-                "bg,ghd->bhd", adds, part, precision=high)
-            return new, total, acc
-
-        _, total, acc = jax.lax.fori_loop(
-            0, live_blocks, one,
-            (jnp.full((B, H), -1e30, jnp.float32),
-             jnp.zeros((B, H), jnp.float32),
-             jnp.zeros((B, H, 2 * c.head_dim), jnp.float32)))
-        return acc / jnp.where(total > 0.0, total, 1.0)[..., None]
-
     with jax.named_scope("full_attn"):
-        att = shared_attend(qkv[:, :c.dim])
+        att = attend(qkv[:, :c.dim], fk, fv, f_lists)
     x = _mlp(full, _diff_out(full, att, c.lam0(c.half + 1), x, c), c)
 
     def back_pair(x, inp):
         g_layer, c_layer, lam0 = inp
         x = _gmu(g_layer, x, m, c)
         with jax.named_scope("cross_attn"):
-            att = shared_attend(_project(c_layer, x, c))
+            att = attend(_project(c_layer, x, c), fk, fv, f_lists)
         return _mlp(c_layer, _diff_out(c_layer, att, lam0, x, c), c), None
 
     x, _ = jax.lax.scan(back_pair, x, (

@@ -498,6 +498,7 @@ class DecodeEngine:
         # only when a slot's changed.
         self._state_dev = None
         self._temps_dev = None
+        self._live_pages = 0     # of the last view built (``_live_view``)
         # Suffix prefills bucket on a finer grid than full prefills: the
         # whole point is that the suffix is short, so padding it back up
         # to prefill_bucket would refund most of the win.
@@ -812,6 +813,10 @@ class DecodeEngine:
         if not self.steplog.enabled:
             return call()
         attrs.setdefault("program", key[0])
+        if "view_pages" in attrs:
+            # Of the rung's rows those that hold a page of a stepping
+            # slot; the others are padding a program need not read.
+            attrs["live_pages"] = self._live_pages
         if self._state_leaves and "view_pages" in attrs:
             # The slots whose state this decode advances.
             attrs["state_slots"] = len(self._active)
@@ -954,9 +959,12 @@ class DecodeEngine:
         counts = np.zeros((self.slots,), np.int32)
         for slot in self._active:
             counts[slot] = len(slot_pages[slot])
+        # The rows that hold a page: what the dispatch's ``launch`` says
+        # beside the rung.
+        self._live_pages = int(counts.sum())
         # A model may lay its rows out in groups (``view_rows``).
         rows_of = getattr(self._ld, "view_rows", None)
-        live = int(counts.sum()) if rows_of is None else rows_of(counts)
+        live = self._live_pages if rows_of is None else rows_of(counts)
         rung = next(n for n in self._view_ladder if n >= live)
         return self._view(tables, counts, rung)
 

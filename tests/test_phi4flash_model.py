@@ -183,21 +183,20 @@ def test_decode_steps_give_the_references_logits_and_idle_state_stays(model):
         assert np.array_equal(np.asarray(pool[k][:, 0]), was), k
 
 
-@pytest.mark.parametrize("view_block,hoist", [(16, True), (16, False)])
+@pytest.mark.parametrize("block_pages", [16, 3])
 def test_the_engine_serves_the_references_tokens(model, monkeypatch,
-                                                 view_block, hoist):
+                                                 block_pages):
     """Through ``DecodeEngine``: admission, chunks, window pages freed as
-    they are passed, five prompts over four slots; the decode reads its
-    list of full pages in blocks of one group (the running softmax across
-    blocks, a loop that stops at the live rows), from one copy a step and
-    gathered layer by layer."""
+    they are passed, five prompts over four slots; the decode's kernel
+    reads a slot's groups of full pages where they lie (the running
+    softmax across a slot's lists, a grid that stops at the live ones), a
+    list at once and in sub-blocks that do not divide it."""
     from benchmarks.reference import phi4flash_ref
     from ray_tpu.models import phi4flash_decode
+    from ray_tpu.ops import paged_decode_attention
     from ray_tpu.serve.decode import DecodeEngine
 
-    monkeypatch.setattr(phi4flash_decode, "VIEW_BLOCK", view_block)
-    if not hoist:
-        monkeypatch.setattr(phi4flash_decode, "HOIST_BYTES", 0)
+    monkeypatch.setattr(paged_decode_attention, "BLOCK_PAGES", block_pages)
     cfg, params = model
     eng = DecodeEngine(params, cfg, slots=4, capacity=256, page_tokens=4,
                        prefill_chunk_tokens=32, model=phi4flash_decode,

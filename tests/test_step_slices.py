@@ -251,6 +251,33 @@ def test_decode_reads_the_smallest_rung_that_holds_its_pages():
     assert widths == [n for _, n in spy.views]
 
 
+def test_a_decodes_launch_says_how_many_rows_of_its_view_hold_a_page():
+    """``live_pages`` beside ``view_pages``: the rows of the rung that hold
+    a page of a stepping slot (what a kernel over page lists fetches), so
+    ``view_pages - live_pages`` rows are padding and ``live_pages x
+    page_tokens - ctx_tokens`` tokens are fetched and masked."""
+    cfg, eng = _engine(page_tokens=4, capacity=128, pool_pages=128)
+    eng._ld = spy = _ViewSpy(eng._ld)
+    reqs = [eng.submit(p, max_new_tokens=10)
+            for p in _prompts(cfg, [3, 39, 88, 110])]
+    _drive(eng, reqs)
+    rows = eng.steplog.dump()["rows"]
+    eng.shutdown()
+    launches = [s for r in rows for s in r["slices"]
+                if s["name"] == "launch" and s.get("program") == "decode"]
+    assert launches and len(launches) == len(spy.views)
+    for s, (live, _) in zip(launches, spy.views):
+        assert s["live_pages"] == live
+        assert 0 < s["live_pages"] <= s["view_pages"]
+        # Every token of the context lies on a live page, and a slot's
+        # last page alone may be part empty.
+        assert s["ctx_tokens"] <= s["live_pages"] * 4
+        assert s["live_pages"] * 4 - s["ctx_tokens"] < 4 * s["batch"]
+    # No other program's launch carries it.
+    assert not any("live_pages" in s for r in rows for s in r["slices"]
+                   if s.get("program") != "decode")
+
+
 def test_a_deployment_meets_no_rung_for_the_first_time_under_traffic():
     """Built with the program's defaults, a deployment has dispatched
     ``decode`` at every rung before it takes a request: requests of mixed

@@ -1,7 +1,8 @@
 """Compiles for a TPU v5e that is described and not attached (the chip's
 compiler is installed here): what interpret mode cannot show of the kernels
 on DeepSeek-V2's and MiMo-V2.5's serving paths at their published widths,
-about two seconds each, Phi-4-mini-flash's decode and prefill programs
+about two seconds each, the decode's paged-attention kernel at
+Phi-4-mini-flash's widths and that model's decode and prefill programs
 whole at its published widths (ten seconds each, with what they take of
 the chip's memory), and what the chip's partitioner makes of the
 four-chip FSDP train step (a quarter of a minute), and the three flash-attention training kernels at that step's
@@ -287,25 +288,69 @@ def test_flash_kernels_compile_at_the_train_cell_shape_under_their_names(
     assert compiled.memory_analysis().temp_size_in_bytes < 150e6
 
 
+@pytest.mark.parametrize("lists,pages,pool,window,dtype", [
+    (64, 9, 8 * 705, 512, "bfloat16"),     # a window layer: a list a slot
+    (256, 16, 8193, None, "bfloat16"),     # the shared cache at its rung
+    (512, 16, 8193, None, "float32"),      # the top rung, a float32 pool
+])
+def test_paged_decode_kernel_compiles_at_phi4flashs_widths(
+        one_chip, no_compile_cache, monkeypatch, lists, pages, pool, window,
+        dtype):
+    """``ops/paged_decode_attention.py`` at Phi-4-mini-flash's widths (40
+    query rows over 1,280 flat lanes, pages of 64 tokens): one Mosaic
+    kernel whose grid's length is traced, whose pools stay where they are
+    (no temporary the size of a list of pages) and whose double buffer is
+    asked for (10.5 MB at 16 pages of bfloat16, over the 16 MiB a call
+    gets unasked in float32)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_decode_attention as pda
+
+    monkeypatch.setattr(pda, "_interpret", lambda: False)
+
+    def shape(*dims, dtype=jnp.dtype(dtype)):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+
+    def attend(q, k, v, lists, owner, index, pos):
+        return pda.paged_decode_attention(
+            q, k, v, pda.page_lists(lists, owner, index, pos, 64, window),
+            0.125)
+
+    compiled = jax.jit(attend).lower(
+        shape(64, 40, 1280), shape(pool, 64, 1280), shape(pool, 64, 1280),
+        shape(lists, pages, dtype=i32), shape(lists, dtype=i32),
+        shape(lists, pages, dtype=i32), shape(64, dtype=i32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and pda.NAME in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 30e6
+
+
 @pytest.mark.parametrize("program", ["decode:4096", "decode:8192",
                                      "chunk:1x512x32", "wave:8x512x8"])
 def test_phi4flash_programs_compile_at_published_widths_inside_the_chip(
         one_chip, no_compile_cache, monkeypatch, program):
     """``models/phi4flash_decode.py`` at Phi-4-mini-flash's published
     widths and the cell's layout (64 slots, 8,192 full and 704 window pages
-    of 64, shapes only): the decode step at the rung that copies its view
-    (4,096 rows, 1.34 GB) and at the top rung (which gathers a block at a
-    time), a 512-token chunk over 32 pages and a whole-prefill wave of
-    ``PREFILL_TOKENS_MAX``. Arguments (12.45 GB: weights, both kinds of
-    page, the state) and temporaries fit the chip's 15.75 GB."""
+    of 64, shapes only): the decode step at the cell's rung (4,096 rows)
+    and at the top one, whose kernel reads the pages where they lie (no
+    copy of the view, at any rung), a 512-token chunk over 32 pages and a
+    whole-prefill wave of ``PREFILL_TOKENS_MAX``. Arguments (12.45 GB:
+    weights, both kinds of page, the state) and temporaries fit the chip's
+    15.75 GB."""
+    import re
+
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import phi4flash
     from ray_tpu.models import phi4flash_decode as pd
-    from ray_tpu.ops import chunk_attention
+    from ray_tpu.ops import chunk_attention, paged_decode_attention
 
     monkeypatch.setattr(chunk_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(paged_decode_attention, "_interpret", lambda: False)
     cfg = phi4flash.Phi4FlashConfig()
     slots, T = 64, 64
 
@@ -352,7 +397,10 @@ def test_phi4flash_programs_compile_at_published_widths_inside_the_chip(
     peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert peak < 14.5e9, (program, peak)
-    if program == "decode:4096":
-        assert 1.3e9 < mem.temp_size_in_bytes < 1.6e9   # the view's copy
-    if program == "decode:8192":
-        assert mem.temp_size_in_bytes < 0.3e9           # no copy
+    if kind == "decode":
+        text = compiled.as_text()
+        assert text.count("paged_decode_attn") >= 3   # window, full, cross
+        # No list of gathered pages, neither the view's groups of 16 nor a
+        # slot's nine window pages, and no room for one.
+        assert not re.search(r"bf16\[\d+,(1024|576),1280\]", text)
+        assert mem.temp_size_in_bytes < 0.3e9
