@@ -188,6 +188,13 @@ class ShmStore:
                                f"{os.strerror(-rc)}", path)
         return ShmStore(path)
 
+    def _open(self):
+        """The C++ handle; it dereferences what it is given, so a closed
+        store is an error here and never reaches it."""
+        if not self._handle:
+            raise ValueError(f"shm store {self.path} is closed")
+        return self._handle
+
     # ------------------------------------------------------------ writer
 
     def put_bytes(self, object_id: bytes, payload, pin: bool = False):
@@ -195,16 +202,16 @@ class ShmStore:
         otherwise True, or a ShmPin when ``pin`` (the primary-copy pin the
         owner must hold until the object is freed)."""
         n = len(payload)
-        off = self._lib.shm_create(self._handle, object_id, n)
+        off = self._lib.shm_create(self._open(), object_id, n)
         if off == 0:
             return None
         self._mv[off:off + n] = payload
-        self._lib.shm_seal2(self._handle, object_id, 1 if pin else 0)
+        self._lib.shm_seal2(self._open(), object_id, 1 if pin else 0)
         return ShmPin(self, object_id) if pin else True
 
     def create_buffer(self, object_id: bytes, size: int):
         """Reserve a writable buffer; caller fills it then calls seal()."""
-        off = self._lib.shm_create(self._handle, object_id, size)
+        off = self._lib.shm_create(self._open(), object_id, size)
         if off == 0:
             return None
         return self._mv[off:off + size]
@@ -213,40 +220,44 @@ class ShmStore:
         """Seal a buffer created via create_buffer; with ``pin`` the primary
         copy stays unevictable and the returned ShmPin must be held."""
         if pin:
-            self._lib.shm_seal2(self._handle, object_id, 1)
+            self._lib.shm_seal2(self._open(), object_id, 1)
             return ShmPin(self, object_id)
-        self._lib.shm_seal(self._handle, object_id)
+        self._lib.shm_seal(self._open(), object_id)
         return None
 
     # ------------------------------------------------------------ reader
 
     def get_view(self, object_id: bytes) -> Optional[ShmView]:
         size = ctypes.c_uint64()
-        off = self._lib.shm_get(self._handle, object_id,
+        off = self._lib.shm_get(self._open(), object_id,
                                 ctypes.byref(size), 1)
         if off == 0:
             return None
         return ShmView(self, object_id, self._mv[off:off + size.value])
 
     def contains(self, object_id: bytes) -> bool:
-        return bool(self._lib.shm_contains(self._handle, object_id))
+        return bool(self._lib.shm_contains(self._open(), object_id))
 
     def _unpin(self, object_id: bytes) -> None:
-        self._lib.shm_unpin(self._handle, object_id)
+        # A pin or a view may outlive ``close`` (a ``ShmPin`` collected
+        # after ``Node.stop``): the C++ side dereferences its handle, so
+        # a closed store is never handed to it.
+        if self._handle:
+            self._lib.shm_unpin(self._handle, object_id)
 
     def delete(self, object_id: bytes) -> bool:
-        return self._lib.shm_delete(self._handle, object_id) == 0
+        return self._lib.shm_delete(self._open(), object_id) == 0
 
     # ------------------------------------------------------------- stats
 
     def used_bytes(self) -> int:
-        return self._lib.shm_used_bytes(self._handle)
+        return self._lib.shm_used_bytes(self._open())
 
     def capacity(self) -> int:
-        return self._lib.shm_capacity(self._handle)
+        return self._lib.shm_capacity(self._open())
 
     def num_objects(self) -> int:
-        return self._lib.shm_num_objects(self._handle)
+        return self._lib.shm_num_objects(self._open())
 
     def close(self) -> None:
         if self._handle:

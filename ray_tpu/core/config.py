@@ -241,37 +241,6 @@ _FLAG_DEFS: Dict[str, tuple] = {
         "decode steps — a long admission can stall active streams for at "
         "most ONE chunk instead of its whole prefill. 0 disables "
         "(one whole-prompt prefill at admission)."),
-    "spec_k": (int, 0,
-        "Speculative-decoding depth for DecodeEngines given a draft "
-        "model: a small draft model proposes k tokens per active slot "
-        "per step and the target model verifies all k+1 positions in "
-        "ONE batched forward (the paged ragged-position gather), so a "
-        "step emits 1..k+1 tokens per slot. Greedy output is that of "
-        "the same engine without a draft (longest-matching-prefix "
-        "acceptance); sampled (temperature > 0) requests fall back to "
-        "per-token decode. 0 disables."),
-    "spec_draft_model": (str, "",
-        "Draft-model preset name (models/llama.PRESETS) for "
-        "LlamaDecodeDeployment's speculative mode — a model a few times "
-        "smaller than the target preset. Empty disables spec mode at "
-        "the deployment level; engines constructed directly take draft "
-        "params/config explicitly."),
-    "spec_draft_pool_pages": (int, 0,
-        "Pages in the draft model's OWN paged KV pool (spec mode). The "
-        "draft tracks the same sequence positions as the target but at "
-        "draft-model width, so its pool is the same page count at a "
-        "fraction of the bytes. Size it >= kv_pool_pages or draft-pool "
-        "pressure preempts requests the target pool could still seat. "
-        "0 = match kv_pool_pages."),
-    "decode_warmup": (bool, False,
-        "Pre-dispatch a DecodeEngine's OPTIONAL steady-state programs "
-        "(decode-chunk grid, spec draft/verify, one admission bucket) "
-        "at deployment construction so jit compiles land before traffic "
-        "instead of under the first requests' latency. The one-token "
-        "decode's ladder of view widths is warmed whatever this says. "
-        "The steplog's jit-compile events then show only prefill "
-        "buckets (which stay lazy — their grid depends on the live "
-        "prompt mix)."),
     "decode_mesh_shape": (str, "",
         "Default (batch, model) decode mesh for DecodeEngines that are "
         "not given an explicit mesh_shape, e.g. '2x4': the engine spans "

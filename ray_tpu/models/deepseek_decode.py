@@ -33,9 +33,8 @@ One attention, two formulations of the same mathematics:
 The layers ride two ``scan``s (the leading dense ones, then the expert
 ones) with the flat pool in the carry, so every program writes its new
 rows into the donated buffer and none holds a second pool (PR 32).
-The engine's optional programs (``paged_decode_chunk``, ``paged_verify``,
-``paged_spec_draft``, ``shard_decode_state``) are not here: the engine
-refuses the options that need them.
+The engine's optional program (``shard_decode_state``) is not here: the
+engine refuses a mesh.
 """
 
 from __future__ import annotations

@@ -33,9 +33,8 @@ Design for the XLA/TPU execution model:
 Two sets of forwards. ``init_cache`` / ``prefill`` / ``decode_step`` /
 ``generate`` over a contiguous per-row cache are the plain reference the
 tests hold the engine to (``prefill`` is also the causal half of
-``paged_prefill``). ``serve/decode.py`` runs the six over the paged pool:
-``paged_prefill``, ``paged_prefill_suffix``, ``paged_decode_step``,
-``paged_decode_chunk``, ``paged_verify``, ``paged_spec_draft``.
+``paged_prefill``). ``serve/decode.py`` runs the three over the paged
+pool: ``paged_prefill``, ``paged_prefill_suffix``, ``paged_decode_step``.
 """
 
 from __future__ import annotations
@@ -133,8 +132,8 @@ def compute_weights(params: Dict[str, Any], config: LlamaConfig,
 def _paged_gather(k_p, v_p, block_tables, config: LlamaConfig):
     """Each row's pages back in logical order, ``(B, W * T, KV, D)``: the
     view the paged attention reads. A row is a request and ``W`` its
-    window (the chunk, the verify), or one live page and ``W`` = 1 (the
-    decode step's list, ``live_page_view``). Named for the device trace:
+    window (the chunk), or one live page and ``W`` = 1 (the decode
+    step's list, ``live_page_view``). Named for the device trace:
     one K and one V operation a layer, which is what the benchmark's
     roofline share counts."""
     B, W = block_tables.shape
@@ -597,150 +596,6 @@ def paged_decode_step(params: Dict[str, Any], pool: Cache,
                         _cast(params["lm_head"], c.dtype),
                         preferred_element_type=jnp.float32)
     return logits, pool, pos + 1
-
-
-def paged_decode_chunk(params: Dict[str, Any], pool: Cache,
-                       view: jax.Array, lengths: jax.Array,
-                       tokens: jax.Array, config: LlamaConfig, k: int
-                       ) -> Tuple[jax.Array, Cache, jax.Array]:
-    """``k`` greedy paged decode steps in ONE jitted program (the
-    dispatch-amortization lever, paged flavor). The view
-    (``live_page_view``) is static across the chunk: it must list pages
-    that cover ``length + k`` for every stepping slot."""
-    def body(carry, _):
-        pool, lens, tok = carry
-        logits, pool, lens = paged_decode_step(params, pool, view,
-                                               lens, tok, config)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (pool, lens, nxt), nxt
-
-    (pool, lengths, _), toks = jax.lax.scan(
-        body, (pool, lengths, tokens), None, length=k)
-    return toks, pool, lengths
-
-
-# --------------------------------------------- speculative decoding
-#
-# Two programs on top of the paged machinery: ``paged_verify`` scores a
-# (k+1)-token suffix per row in ONE target forward (the ragged-position
-# scatter/gather of ``paged_prefill_suffix``, but emitting logits at
-# EVERY query position instead of the last real one — the per-position
-# argmaxes are what the engine compares draft proposals against), and
-# ``paged_spec_draft`` runs the small draft model: ingest up to two
-# catch-up tokens (the tokens the target accepted since the draft's
-# last committed position — bounded at 2 by the acceptance protocol),
-# then greedily propose ``k`` tokens via a scanned decode. Greedy
-# acceptance of the longest matching prefix makes spec-mode output
-# provably identical to sequential greedy decode: position ``j``'s
-# verify logits condition on exactly the tokens sequential decode would
-# have conditioned on whenever proposals ``1..j`` were accepted.
-
-
-def paged_verify(params: Dict[str, Any], tokens: jax.Array, pool: Cache,
-                 block_tables: jax.Array, config: LlamaConfig,
-                 prefix_lens: jax.Array) -> Tuple[jax.Array, Cache]:
-    """Target-model verify forward: process right-padded rows ``tokens``
-    (B, S = spec_k + 1) from ``pos = prefix_lens`` against the paged
-    context and return logits at ALL ``S`` positions, shape (B, S, V).
-    Row layout is ``[last_emitted, draft_1, .., draft_k]``; K/V for
-    every position scatters into the row's pages (positions past the
-    page window go to the scratch page), so the accepted prefix is
-    committed by the same program that scores it — rejected tails are
-    plain junk past the rolled-back ``length`` cursor, masked exactly
-    like pad writes and overwritten by the next round's scatter before
-    any gather can see them. As in the other paged forwards, the pool is
-    the layer loop's carry and is written in place (``_scan_layers``)."""
-    c = config
-    B, S = tokens.shape
-    T = pool["k"].shape[2]
-    W = block_tables.shape[1]
-    C = W * T
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
-    x = _cast(params["tok_embed"], c.dtype)[tokens]          # (B, S, E)
-    abs_pos = prefix_lens[:, None] + jnp.arange(S)[None, :]  # (B, S)
-    kv_groups = c.n_heads // c.n_kv_heads
-    scale = c.head_dim ** -0.5
-    rows = jnp.arange(B)
-    pages = jnp.where(
-        abs_pos < C,
-        block_tables[rows[:, None], jnp.minimum(abs_pos // T, W - 1)], 0)
-    offs = abs_pos % T
-    valid = (jnp.arange(C)[None, None, :]
-             <= abs_pos[:, :, None])                         # (B, S, C)
-
-    def body(carry, inp):
-        x, k_p, v_p = carry                 # the flat pool: _scan_layers
-        layer, base = inp
-        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-        q, k_new, v_new = _qkv(layer, h, c)  # (B, S, H/KV, D)
-        q = apply_rope(q, cos, sin, positions=abs_pos)
-        k_new = apply_rope(k_new, cos, sin, positions=abs_pos)
-        q = constrain(q, ("batch", "length", "heads", "head_dim"))
-        k_new = constrain(k_new,
-                          ("batch", "length", "kv_heads", "head_dim"))
-        v_new = constrain(v_new,
-                          ("batch", "length", "kv_heads", "head_dim"))
-        k_p, v_p, k_c, v_c = _pool_store(k_p, v_p, base, pages, offs,
-                                         k_new, v_new, block_tables, c)
-        with jax.named_scope("paged_attn"):
-            qg = q.reshape(B, S, c.n_kv_heads, kv_groups, c.head_dim)
-            scores = jnp.einsum("bskgd,bckd->bkgsc", qg, k_c,
-                                preferred_element_type=jnp.float32) * scale
-            scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            att = jnp.einsum("bkgsc,bckd->bkgsd", probs.astype(v_c.dtype), v_c)
-        att = att.transpose(0, 3, 1, 2, 4).reshape(
-            B, S, c.n_heads, c.head_dim).astype(x.dtype)
-        att = constrain(att, ("batch", "length", "attn_heads", "head_dim"))
-        out = jnp.einsum("bshd,hde->bse", att, _cast(layer["wo"], x.dtype))
-        x = x + out
-        x = _mlp(layer, x, c)
-        return (x, k_p, v_p), None
-
-    x, pool = _scan_layers(body, x, params, pool)
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
-    logits = jnp.einsum("bse,ev->bsv", x,
-                        _cast(params["lm_head"], c.dtype),
-                        preferred_element_type=jnp.float32)
-    return logits, pool
-
-
-def paged_spec_draft(params: Dict[str, Any], pool: Cache,
-                     block_tables: jax.Array, view: jax.Array,
-                     lengths: jax.Array, catchup: jax.Array,
-                     catchup_lens: jax.Array, config: LlamaConfig, k: int
-                     ) -> Tuple[jax.Array, Cache]:
-    """Draft-model propose step: ingest the ragged ``catchup`` rows
-    (B, 2) — the true tokens the draft has not yet committed, 1 normally
-    or 2 after a fully-accepted round — writing their K/V at positions
-    ``lengths..lengths+catchup_lens-1``, then greedily roll ``k``
-    proposals. Returns ``(proposals (B, k) int32, pool)``. The caller
-    owns the draft ``length`` cursors (host-side rollback after
-    acceptance); pages must cover ``lengths + catchup_lens + k - 1``
-    positions, in ``block_tables`` (the ingest reads a window a slot, as
-    ``paged_verify`` does) and in ``view`` (the proposals are decode
-    steps: ``live_page_view``). A 1-long catch-up row's pad slot writes
-    junk one past the real token — the first proposal's decode step
-    rewrites that exact position before anything gathers it."""
-    logits, pool = paged_verify(params, catchup, pool, block_tables,
-                                config, lengths)
-    last = jnp.take_along_axis(
-        logits, (catchup_lens - 1)[:, None, None].astype(jnp.int32),
-        axis=1)[:, 0]                                        # (B, V)
-    tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    lens = lengths + catchup_lens
-
-    def body(carry, _):
-        pool, lens, tok = carry
-        logits, pool, lens = paged_decode_step(params, pool, view,
-                                               lens, tok, config)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (pool, lens, nxt), nxt
-
-    (pool, _, _), rest = jax.lax.scan(
-        body, (pool, lens, tok), None, length=k - 1)
-    toks = jnp.concatenate([tok[None], rest], axis=0).T      # (B, k)
-    return toks, pool
 
 
 # ------------------------------------------------- GSPMD serving (mesh)

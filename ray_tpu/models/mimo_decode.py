@@ -28,8 +28,8 @@ the pool).
 
 The layers ride one ``scan`` a segment (``MimoConfig.segments``) with the
 segment's kind of pool in the carry, flat, so every program writes its new
-rows into the donated buffer. The engine's optional programs are not here:
-the engine refuses the options that need them.
+rows into the donated buffer. The engine's optional program
+(``shard_decode_state``) is not here: the engine refuses a mesh.
 """
 
 from __future__ import annotations

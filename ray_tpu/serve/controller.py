@@ -43,38 +43,9 @@ def autoscale_load(stats: Dict[str, Any]) -> float:
 
     Base signal: ``max(ongoing, load)`` — HTTP concurrency vs the
     engine's own backlog (slots + queue + prefill backlog), whichever
-    is worse.
-
-    Speculative replicas would OVER-report headroom from that alone: a
-    spec engine's slots complete requests ``(1 + accept_rate * k)``
-    tokens per step instead of 1, so the same backlog clears faster at
-    high acceptance — but at LOW acceptance each slot still pays the
-    (k+1)-token verify forward per emitted token, and a draft pool
-    under pressure keeps new seats draftless (no speedup at full spec
-    cost). Scale the signal by the spec slowdown factor
-    ``(k + 1) / (1 + accept_rate * k)`` (1.0 at perfect acceptance =
-    the engine really does have spec-sized headroom; (k+1) at zero
-    acceptance = every slot is doing verify work for nothing), plus a
-    draft-pool-pressure bump when the pool is nearly exhausted."""
-    load = float(max(stats.get("ongoing", 0) or 0,
+    is worse."""
+    return float(max(stats.get("ongoing", 0) or 0,
                      stats.get("load", 0) or 0))
-    spec = stats.get("spec")
-    if not isinstance(spec, dict):
-        return load
-    k = float(spec.get("k", 0) or 0)
-    if k <= 0:
-        return load
-    accept = spec.get("accept_rate")
-    accept = 0.0 if accept is None else min(1.0, max(0.0, float(accept)))
-    load *= (k + 1.0) / (1.0 + accept * k)
-    total = float(spec.get("draft_pages_total", 0) or 0)
-    if total > 0:
-        occupancy = 1.0 - float(spec.get("draft_pages_free", 0)) / total
-        if occupancy > 0.75:
-            # Draft pool nearly dry: new admissions seat draftless and
-            # decode at 1 token/step while paying spec overheads.
-            load *= 1.0 + (occupancy - 0.75)
-    return load
 
 
 class ReplicaRecord:
@@ -1280,10 +1251,7 @@ class ServeController:
                 # Replica load = max(HTTP concurrency, replica-reported
                 # backlog): a decode engine with a full pending queue and
                 # every slot busy must scale OUT even when each request
-                # occupies only one "ongoing" call slot. autoscale_load
-                # additionally inflates speculative replicas' signal by
-                # their verify overhead at the observed accept rate, so
-                # spec engines don't over-report headroom.
+                # occupies only one "ongoing" call slot.
                 ongoing = sum(autoscale_load(r.last_stats)
                               for r in rec.replicas)
                 # A mesh-parallel replica is chips-many units of

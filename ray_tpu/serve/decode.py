@@ -35,7 +35,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -88,10 +88,6 @@ class _Request:
     admitted: bool = False                 # left the pending queue
     status: str = "pending"                # terminal: completed |
     #   cancelled | deadline_exceeded | error
-    # --------------------------------------------------- speculative mode
-    spec_proposed: int = 0                 # draft tokens proposed for this
-    #   request across its spec rounds
-    spec_accepted: int = 0                 # of those, verified-accepted
     # ------------------------------------------------------ observability
     trace: Optional[tuple] = None          # (trace_id, span_id) captured
     #   at submit: the engine's loop thread attributes queue-wait /
@@ -136,10 +132,8 @@ class DecodeEngine:
     ``compute_weights``, ``init_page_pool``, ``paged_prefill``,
     ``paged_prefill_suffix``, ``paged_decode_step``, ``live_page_view``,
     ``cache_bucket`` and ``sample_batch``, and may provide
-    ``paged_decode_chunk`` (``decode_chunk > 1``), ``paged_verify`` and
-    ``paged_spec_draft`` (``spec_k > 0``) and ``shard_decode_state`` (a
-    mesh): an option whose program the model lacks is refused at
-    construction. The pool is the model's pytree, carried whole
+    ``shard_decode_state``: a mesh is refused at construction for a
+    model that lacks it. The pool is the model's pytree, carried whole
     (docs/SERVING.md, "The model seam"). A model may also say
     ``page_kinds(config)``: which kinds of page its pool has and what
     each keeps (all tokens, or the last ``window``); the engine then
@@ -161,7 +155,6 @@ class DecodeEngine:
 
     def __init__(self, params, config, slots: int = 4,
                  capacity: int = 1024, prefill_bucket: int = 128,
-                 decode_chunk: int = 1,
                  prefix_pool_entries: Optional[int] = None,
                  prefix_match_min_tokens: Optional[int] = None,
                  queue_max: Optional[int] = None,
@@ -174,9 +167,6 @@ class DecodeEngine:
                  metrics_enabled: Optional[bool] = None,
                  trace_spans: Optional[bool] = None,
                  metrics_deployment: Optional[str] = None,
-                 spec_draft_params=None, spec_draft_config=None,
-                 spec_k: Optional[int] = None,
-                 spec_draft_pool_pages: Optional[int] = None,
                  model=None):
         import jax
 
@@ -188,21 +178,14 @@ class DecodeEngine:
             from ray_tpu.models import llama_decode as model
         self._jax = jax
         self._ld = ld = model
-        # A model may lack the optional programs; an option that needs
-        # one is refused here, before anything is built for it.
-        sk_asked = rt_config.spec_k if spec_k is None else spec_k
-        for asked, needs in (
-                (int(decode_chunk) > 1, ("paged_decode_chunk",)),
-                (int(sk_asked) > 0 and spec_draft_params is not None,
-                 ("paged_verify", "paged_spec_draft")),
-                (mesh is not None or mesh_shape is not None
-                 or bool(rt_config.decode_mesh_shape),
-                 ("shard_decode_state",))):
-            lacks = [n for n in needs if not hasattr(ld, n)]
-            if asked and lacks:
-                raise ValueError(
-                    f"model {ld.__name__} has no {' / '.join(lacks)}: "
-                    f"this engine cannot run the option that needs it")
+        # A model may lack the one optional program; a mesh, which needs
+        # it, is refused here, before anything is built for it.
+        if (mesh is not None or mesh_shape is not None
+                or rt_config.decode_mesh_shape) and not hasattr(
+                    ld, "shard_decode_state"):
+            raise ValueError(
+                f"model {ld.__name__} has no shard_decode_state: this "
+                f"engine cannot run it on a mesh")
         # Counters a model's decode step returns beside its logits
         # (a float32 vector of whole numbers, one entry a name); they
         # reach the host behind the token ids, in the same transfer.
@@ -436,57 +419,6 @@ class DecodeEngine:
                 self._pages, self.page_tokens,
                 max_pages=int(pmax) or max(1, self.pool_pages // 4),
                 min_tokens=min_tokens)
-        # ------------------------------------------- speculative decoding
-        # A draft model proposes spec_k tokens per active slot per step;
-        # the target verifies all k+1 positions in ONE batched forward
-        # (models.llama_decode.paged_verify) and the engine accepts the
-        # longest prefix whose proposals match the target's per-position
-        # argmax — greedy output is provably identical to sequential
-        # decode, a step just emits 1..k+1 tokens per slot. Draft KV
-        # lives in its OWN (smaller-bytes) page pool with its own block
-        # tables; rejected tails roll the page cursors back on the host
-        # (junk K/V past the cursor is masked and rewritten before any
-        # gather, exactly like pad writes).
-        sk = rt_config.spec_k if spec_k is None else spec_k
-        self.spec_k = int(sk)
-        self.spec = self.spec_k > 0 and spec_draft_params is not None
-        if self.spec:
-            self._draft_config = spec_draft_config
-            dpp = (rt_config.spec_draft_pool_pages
-                   if spec_draft_pool_pages is None
-                   else spec_draft_pool_pages)
-            self.draft_pool_pages = int(dpp) or self.pool_pages
-            self._draft_pages = PageAllocator(self.draft_pool_pages)
-            dpool = ld.init_page_pool(spec_draft_config,
-                                      self.draft_pool_pages,
-                                      self.page_tokens)
-            self._draft_cache = {
-                **dpool,
-                "length": jax.numpy.zeros((slots,), jax.numpy.int32)}
-            self._draft_bt = np.zeros((slots, self.slot_pages_max),
-                                      np.int32)
-            self._draft_slot_pages: List[List[int]] = [
-                [] for _ in range(slots)]
-            # Host-side committed draft length per slot; -1 = draftless
-            # (the draft pool could not seat it — the slot rides spec
-            # rounds with junk proposals that simply get rejected).
-            self._draft_committed = [0] * slots
-            self._draft_params = ld.compute_weights(
-                spec_draft_params, spec_draft_config)
-            self._draft_rules = None
-            self._draft_cache_sharding = None
-            if self.mesh is not None:
-                self._draft_params, dsh = ld.shard_decode_state(
-                    self._draft_params, spec_draft_config, mesh)
-                self._draft_rules = dsh["rules"]
-                self._draft_cache_sharding = dict(dsh["pool"])
-                self._draft_cache = jax.device_put(
-                    self._draft_cache, self._draft_cache_sharding)
-            self.spec_rounds = 0
-            self.spec_proposed = 0
-            self.spec_accepted = 0
-        else:
-            self.spec = False
         # A program that ends in a sample returns the sample: token ids
         # cross to the host, never (slots, vocab) logits. The decode's
         # whole result is one int32 vector, ``[ids | the model's
@@ -543,50 +475,13 @@ class DecodeEngine:
         # Disaggregated adopt: scatter handed-off page payloads into
         # the pool (pure data movement, no model math) and park the
         # slot cursor at the committed length. Cache-only output, so
-        # mesh engines pin just the cache sharding (the
-        # draft_cache_only precedent below).
+        # mesh engines pin just the cache sharding.
         self._adopt_pages = self._mesh_scoped(self._program(
             "adopt_pages", self._adopt_pages_impl,
             static_argnames=("width",),
             donate_argnums=(0,),
             **({"out_shardings": self._cache_sharding}
                if self.mesh is not None else {})))
-        # K greedy steps per device call (dispatch amortization); chunking
-        # only engages when no admissions are pending and every active
-        # request is greedy — sampling and joins stay per-token exact.
-        self.decode_chunk = max(1, int(decode_chunk))
-        self._decode_k = self._mesh_scoped(self._program(
-            "decode_k", self._paged_decode_chunk_impl,
-            static_argnames=("k",), donate_argnums=(1,), **cache_out))
-        # Speculative programs: target verify (all-position argmax over
-        # the slot's pages, donated KV) and the draft's own prefill +
-        # catch-up/propose programs against the draft pool. Both sample
-        # on device — a round moves (slots, k+1) int32 to the host, not
-        # logits.
-        if self.spec:
-            if self.mesh is not None:
-                draft_out = {"out_shardings": (
-                    rep, self._draft_cache_sharding)}
-                # _draft_prefill returns ONLY the draft cache (its
-                # logits are discarded in-program).
-                draft_cache_only = {
-                    "out_shardings": self._draft_cache_sharding}
-            else:
-                draft_out = {}
-                draft_cache_only = {}
-            self._spec_verify = self._mesh_scoped(self._program(
-                "spec_verify", self._spec_verify_impl,
-                donate_argnums=(1,),
-                **cache_out))
-            self._spec_draft = self._mesh_scoped(self._program(
-                "spec_draft", self._spec_draft_impl,
-                static_argnames=("k",),
-                donate_argnums=(1,), **draft_out),
-                rules=self._draft_rules)
-            self._draft_prefill = self._mesh_scoped(self._program(
-                "draft_prefill", self._draft_prefill_impl,
-                static_argnames=("n", "bucket"), donate_argnums=(1,),
-                **draft_cache_only), rules=self._draft_rules)
         self.steps = 0
         self.tokens_out = 0
         # ---------------------------------------------- observability
@@ -643,21 +538,16 @@ class DecodeEngine:
         program.__name__ = program.__qualname__ = f"engine_{name}"
         return jax.jit(program, **jit_kwargs)
 
-    def _mesh_scoped(self, fn, rules=None):
+    def _mesh_scoped(self, fn):
         """Mesh engines trace every program inside the decode axis-rules
         context (``constrain`` sites in the model resolve against it);
-        single-chip engines get the callable back untouched. ``rules``
-        overrides the table for programs of a DIFFERENT config than the
-        target — the spec draft model resolves its own divisibility
-        specialization of DECODE_RULES."""
+        single-chip engines get the callable back untouched."""
         if self.mesh is None:
             return fn
         from ray_tpu.parallel.sharding import axis_rules
 
-        table = self._rules if rules is None else rules
-
         def scoped(*args, **kwargs):
-            with axis_rules(self.mesh, table):
+            with axis_rules(self.mesh, self._rules):
                 return fn(*args, **kwargs)
 
         return scoped
@@ -744,55 +634,6 @@ class DecodeEngine:
                for name, pages in payload.items()},
             "length": cache["length"].at[slot_ids].set(lengths),
         }
-
-    def _paged_decode_chunk_impl(self, params, cache, tokens, view, k):
-        pool = _pool_of(cache)
-        toks, pool, lens = self._ld.paged_decode_chunk(
-            params, pool, view, cache["length"], tokens, self.config, k)
-        return toks, {**pool, "length": lens}
-
-    # ------------------------------------------- speculative jitted bodies
-
-    def _spec_verify_impl(self, params, cache, rows, bt):
-        """Target verify forward: rows (slots, k+1) laid out as
-        ``[last_emitted, draft_1..draft_k]`` per slot, scored from
-        ``pos = length`` against the slot's pages, argmax fused on
-        device — the host receives (slots, k+1) token ids, never
-        logits. ``length`` is returned UNCHANGED: the host owns the
-        cursor and rolls it forward only over the accepted run."""
-        import jax.numpy as jnp
-
-        pool = _pool_of(cache)
-        logits, pool = self._ld.paged_verify(
-            params, rows, pool, bt, self.config, cache["length"])
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return toks, {**pool, "length": cache["length"]}
-
-    def _spec_draft_impl(self, params, cache, catchup, catchup_lens,
-                         bt, view, k):
-        """Draft propose: ingest each slot's 1-2 catch-up tokens from
-        ``pos = length`` and greedily roll ``k`` proposals against the
-        draft pool. ``length`` is host-owned (rolled back with the
-        target's cursor after acceptance) — returned unchanged."""
-        pool = _pool_of(cache)
-        toks, pool = self._ld.paged_spec_draft(
-            params, pool, bt, view, cache["length"], catchup,
-            catchup_lens, self._draft_config, k)
-        return toks, {**pool, "length": cache["length"]}
-
-    def _draft_prefill_impl(self, params, cache, tokens_rows, lengths,
-                            bt, slot_ids, n, bucket):
-        """Draft-pool prompt prefill at admission: the draft must hold
-        K/V for the WHOLE prompt (target prefix-cache hits don't help
-        it — the draft pool has no prefix index), which is fine because
-        the draft is the model chosen to be cheap."""
-        ld = self._ld
-        pool = _pool_of(cache)
-        _, pool = ld.paged_prefill(params, tokens_rows[:, :bucket],
-                                   pool, bt, self._draft_config,
-                                   lengths=lengths)
-        return {**pool,
-                "length": cache["length"].at[slot_ids].set(lengths)}
 
     def _dispatch_fresh(self, key: tuple, call,
                         then: Optional[str] = None, **attrs):
@@ -986,8 +827,8 @@ class DecodeEngine:
             {self._kind: rung,
              **{k: w.keep for k, w in self._windows.items()}})
 
-    def _ensure_decode_pages(self, k: int) -> None:
-        """Every active slot can write its next ``k`` tokens. Oldest
+    def _ensure_decode_pages(self) -> None:
+        """Every active slot can write its next token. Oldest
         slots are served first; when the pool is dry even after
         reclaiming prefix pins, the YOUNGEST admitted request is
         preempted (recompute-style requeue) — the oldest request always
@@ -998,10 +839,10 @@ class DecodeEngine:
                 req = self._active.get(slot)
                 if req is None:
                     break  # preempted while serving an older slot
-                upto = req.prompt_len + req.generated - 1 + k
-                # The step's first query is at ``upto - k``: what lies
-                # behind its window goes back before anything is asked.
-                self._trim_windows(slot, upto - k)
+                upto = req.prompt_len + req.generated
+                # The step's query is at ``upto - 1``: what lies behind
+                # its window goes back before anything is asked.
+                self._trim_windows(slot, upto - 1)
                 need = self._seq_pages(upto) - len(self._slot_pages[slot])
                 dry = self._windows_missing(slot, upto)
                 if need <= 0 and not dry:
@@ -1014,99 +855,6 @@ class DecodeEngine:
                     break
                 if not self._preempt_one():
                     break  # nothing left to preempt: caller's slot only
-
-    # ------------------------------------------- draft-pool accounting
-    #
-    # The draft pool mirrors the target's block-table discipline at the
-    # draft model's (smaller) K/V width: same page size, its own
-    # allocator and tables, no prefix index. Freeing a slot frees both
-    # pools. Draft-pool pressure NEVER touches the target plane: a
-    # draft seat is opportunistic (it only buys speedup), so a dry
-    # draft pool evicts the youngest DRAFT seat — never preempts a
-    # request, which would requeue it through the suffix-continuation
-    # prefill and perturb greedy near-ties.
-
-    def _draft_grow_slot(self, slot: int, pages: List[int]) -> None:
-        have = self._draft_slot_pages[slot]
-        self._draft_bt[slot, len(have):len(have) + len(pages)] = pages
-        self._draft_slot_pages[slot] = have + pages
-
-    def _ensure_draft_pages(self, k: int) -> None:
-        """Every drafted active slot's draft can write catch-up + k-1
-        proposal positions (through ``L + k - 1``). Draftless slots (-1)
-        are skipped: their rows route to the scratch page and their junk
-        proposals are simply rejected by verification. A slot the pool
-        cannot cover even after evicting younger draft seats is demoted
-        to draftless the same way — spec rounds stay correct
-        (verification guarantees the output), the slot just stops
-        speculating usefully."""
-        for slot in sorted(self._active,
-                           key=lambda s: self._active[s].submitted_at):
-            req = self._active[slot]
-            while True:
-                if self._draft_committed[slot] < 0:
-                    break
-                need = self._seq_pages(req.prompt_len + req.generated
-                                       - 1 + k) \
-                    - len(self._draft_slot_pages[slot])
-                if need <= 0:
-                    break
-                got = self._draft_pages.alloc(need)
-                if got is not None:
-                    self._draft_grow_slot(slot, got)
-                    break
-                if not self._draft_evict_one(slot):
-                    self._draft_demote(slot, req)
-                    break
-
-    def _draft_demote(self, slot: int, req: _Request) -> None:
-        """Drop a slot's draft seat (freeing its draft pages): it keeps
-        riding spec rounds with junk proposals that verification
-        rejects — output stays correct, the slot just stops
-        contributing speedup until re-admission reseats it."""
-        self._draft_pages.free(self._draft_slot_pages[slot])
-        self._draft_slot_pages[slot] = []
-        self._draft_bt[slot, :] = 0
-        self._draft_committed[slot] = -1
-
-    def _draft_evict_one(self, keep: int) -> bool:
-        """Make room in the draft pool by demoting the youngest OTHER
-        drafted slot. Never touches the target plane — preempting a
-        request over draft pressure would requeue it through the
-        suffix-continuation prefill and perturb greedy near-ties,
-        breaking the spec-mode bit-exactness contract for pure
-        speedup bookkeeping."""
-        cands = [s for s in self._active
-                 if s != keep and self._draft_committed[s] >= 0
-                 and self._draft_slot_pages[s]]
-        if not cands:
-            return False
-        victim = max(cands, key=lambda s: self._active[s].submitted_at)
-        self._draft_demote(victim, self._active[victim])
-        return True
-
-    def _rollback_pages(self, slot: int, committed: int) -> None:
-        """Roll a slot's page cursors back to ``committed`` tokens after
-        a spec round: tail pages past the accepted run free in BOTH
-        pools (their junk K/V is provably dead — nothing attends past
-        the rolled-back ``length``, and a later owner's scatter runs
-        before its gather). Leading pages — including shared prefix
-        splices — are never touched: ``committed >= prefix_len``
-        always."""
-        keep = self._seq_pages(committed)
-        tail = self._slot_pages[slot][keep:]
-        if tail:
-            self._block_tables[slot, keep:keep + len(tail)] = 0
-            self._slot_pages[slot] = self._slot_pages[slot][:keep]
-            self._pages.free(tail)
-        keep_d = self._seq_pages(min(committed,
-                                     self._draft_committed[slot]))
-        dtail = self._draft_slot_pages[slot][keep_d:]
-        if dtail:
-            self._draft_bt[slot, keep_d:keep_d + len(dtail)] = 0
-            self._draft_slot_pages[slot] = \
-                self._draft_slot_pages[slot][:keep_d]
-            self._draft_pages.free(dtail)
 
     def _preempt_one(self) -> bool:
         """Requeue the youngest admitted request to free its pages
@@ -1201,9 +949,6 @@ class DecodeEngine:
                 f"prompt ({len(req.tokens)}) + max_new_tokens "
                 f"({req.max_new_tokens}) needs more pages than the pool "
                 f"holds ({self.pool_pages} x {self.page_tokens} tokens)")
-        # The spec draft pool is deliberately NOT an admission limit: a
-        # request the draft pool cannot seat decodes draftless (junk
-        # proposals, all rejected) — correct output, no speedup.
         if len(req.tokens) >= self.capacity:
             raise ValueError(
                 f"prompt ({len(req.tokens)}) must be shorter than the "
@@ -1400,16 +1145,6 @@ class DecodeEngine:
                     smetrics.INTER_TOKEN.observe(
                         (req.finished_at - req.first_token_at)
                         / (req.generated - 1), self._mtags)
-            if req.spec_proposed > 0:
-                # Acceptance per REQUEST (not per round): one histogram
-                # observation at terminal keeps the doctrine — nothing
-                # observability-side runs per token or per step.
-                smetrics.SPEC_PROPOSED.inc(float(req.spec_proposed),
-                                           self._mtags)
-                smetrics.SPEC_ACCEPTED.inc(float(req.spec_accepted),
-                                           self._mtags)
-                smetrics.SPEC_ACCEPT.observe(
-                    req.spec_accepted / req.spec_proposed, self._mtags)
         if self._obs_spans and req.trace is not None:
             from ray_tpu.util import tracing
 
@@ -1517,18 +1252,9 @@ class DecodeEngine:
                 for w in self._windows.values():
                     w.seat(slot, req.prefix_len)
                 req.prefilled = req.prefix_len
-                # Park the device cursor at the spliced length NOW: the
-                # slot may sit un-ticked for several steps (one chunk
-                # per step, FIFO), and a spec round's verify, which
-                # reads the whole block table, scribbles its idle-row
-                # junk at pos=length — at 0 that would land INSIDE a
-                # shared prefix page and corrupt it for every borrower.
-                # At prefix_len it lands in the slot's own (or scratch)
-                # territory, overwritten by the first chunk's scatter.
-                # (A plain decode step writes a slot outside its view to
-                # the scratch page, wherever the cursor is.)
-                self.cache["length"] = \
-                    self.cache["length"].at[slot].set(req.prefix_len)
+                # The cursor stays where the slot's release parked it:
+                # a decode step writes a slot outside its view to the
+                # scratch page, wherever the cursor is.
                 self._prefilling[slot] = req
                 seated.append(req)
                 continue
@@ -1648,8 +1374,6 @@ class DecodeEngine:
         if req.generated >= req.max_new_tokens or (
                 req.eos_id is not None and tok == req.eos_id):
             self._finish(slot)
-        elif self.spec:
-            self._draft_seat([req])
 
     def _admit_paged_full(self, reqs: List[_Request]) -> None:
         import jax.numpy as jnp
@@ -1955,8 +1679,6 @@ class DecodeEngine:
             if req.generated >= req.max_new_tokens or (
                     req.eos_id is not None and tok == req.eos_id):
                 self._finish(slots[i])
-        if self.spec:
-            self._draft_seat([r for r in group if not r.done.is_set()])
 
     def _capture_handoff(self, req: _Request, slot: int,
                          first_token: int) -> None:
@@ -1991,81 +1713,6 @@ class DecodeEngine:
             self._handoff_phases.append(
                 {"phase": "handoff", "t0": t0, "t1": time.time(),
                  "slot": slot, "pages": int(len(ids[self._kind]))})
-
-    def _draft_seat(self, reqs: List[_Request]) -> None:
-        """Give each freshly-admitted slot its draft-side state: draft
-        pages covering the prompt and a full draft prefill (prefix-hit
-        target admissions still draft-prefill the WHOLE prompt — the
-        draft pool has no prefix index, and the draft is cheap by
-        construction). A slot the draft pool cannot seat even after
-        evicting younger draft seats is marked draftless (-1): its spec
-        rounds run with junk proposals the verify forward simply
-        rejects — correct, just not faster — instead of wedging the
-        batch."""
-        for req in reqs:
-            self._draft_prefill_slot(req.slot, req,
-                                     np.asarray(req.tokens, np.int32))
-
-    def _draft_prefill_slot(self, slot: int, req: _Request,
-                            seq: np.ndarray) -> bool:
-        """Allocate draft pages covering ``seq`` and prefill it into the
-        slot's draft state; ``seq`` is the true committed token stream
-        (the whole prompt at admission, prompt+output on resync). False
-        = slot no longer owns the seat, or pool dry even after evicting
-        younger draft seats (slot demoted to draftless)."""
-        import jax.numpy as jnp
-
-        if self._active.get(slot) is not req:
-            return False  # finished/preempted inside this admission
-        got = self._draft_pages.alloc(self._seq_pages(len(seq)))
-        while got is None and self._draft_evict_one(slot):
-            got = self._draft_pages.alloc(self._seq_pages(len(seq)))
-        if got is None:
-            self._draft_demote(slot, req)
-            return False
-        self._draft_bt[slot, :] = 0
-        self._draft_bt[slot, :len(got)] = got
-        self._draft_slot_pages[slot] = got
-        bucket = min(self._ld.cache_bucket(len(seq),
-                                           self.prefill_bucket),
-                     self.capacity)
-        wp = max(1, -(-bucket // self.page_tokens))
-        rows = np.zeros((1, bucket), np.int32)
-        rows[0, :len(seq)] = seq
-        bt = self._draft_bt[slot:slot + 1, :wp]
-        self._draft_cache = self._dispatch_fresh(
-            ("draft_prefill", 1, bucket),
-            lambda: self._draft_prefill(
-                self._draft_params, self._draft_cache,
-                jnp.asarray(rows),
-                jnp.asarray([len(seq)], np.int32),
-                jnp.asarray(bt), jnp.asarray([slot], np.int32),
-                n=1, bucket=bucket),
-            then="admit", tokens=len(seq))
-        self._draft_committed[slot] = len(seq)
-        return True
-
-    def _draft_resync(self, slot: int, req: _Request) -> bool:
-        """Plain-decode interludes (mixed-temperature batches, chunked
-        greedy runs, draftless neighbours) advance the target while the
-        draft idles; once the draft is more than one round behind, its
-        bounded catch-up row can't close the gap — rebuild the slot's
-        draft state with one full draft prefill of the true sequence."""
-        L = req.prompt_len + req.generated - 1
-        self._draft_pages.free(self._draft_slot_pages[slot])
-        self._draft_slot_pages[slot] = []
-        self._draft_bt[slot, :] = 0
-        seq = np.asarray([self._token_at(req, p) for p in range(L)],
-                         np.int32)
-        return self._draft_prefill_slot(slot, req, seq)
-
-    @staticmethod
-    def _token_at(req: _Request, p: int) -> int:
-        """True committed token at absolute position p (prompt, then
-        generated output — valid for reabsorbed requests too, whose
-        prompt_len stays the ORIGINAL admission length)."""
-        return (int(req.tokens[p]) if p < req.prompt_len
-                else int(req.output[p - req.prompt_len]))
 
     def _seat(self, slot: int, tok: int, temperature: float = 0.0) -> None:
         """The HOST writes what ``slot`` decodes from next, token and
@@ -2140,14 +1787,6 @@ class DecodeEngine:
             if n and self.steplog.enabled:
                 self.steplog.event("page-free", n=n, page_kind=kind,
                                    free=w.alloc.free_count)
-        if self.spec:
-            dpages = self._draft_slot_pages[slot]
-            self._draft_slot_pages[slot] = []
-            self._draft_bt[slot, :] = 0
-            self._draft_pages.free(dpages)
-            self._draft_committed[slot] = 0
-            self._draft_cache["length"] = \
-                self._draft_cache["length"].at[slot].set(0)
         self._free.append(slot)
         # Park the freed slot at length 0 so idle slots don't walk their
         # cursor toward the capacity edge while others decode.
@@ -2211,28 +1850,6 @@ class DecodeEngine:
             elif req.deadline is not None and now > req.deadline:
                 self._finish(slot, "deadline_exceeded")
 
-    def _pick_chunk(self) -> int:
-        """Greedy decode steps fusable into one device call right now."""
-        # Chunking engages when the batch can't change mid-chunk anyway
-        # (no free slot for a pending request) or nothing is waiting —
-        # and never while a chunked prefill is mid-flight (the whole
-        # point of interleaving is a prefill chunk between EVERY step).
-        if (self.decode_chunk > 1
-                and (self._pending.empty() or not self._free)
-                and not self._requeue and not self._prefilling
-                and all(r.temperature <= 0.0
-                        for r in self._active.values())):
-            chunk = min(self.decode_chunk,
-                        min(r.max_new_tokens - r.generated
-                            for r in self._active.values()))
-            # Round down to a power of two: each distinct k is its own
-            # compiled program, so the program set must stay bounded
-            # ({1, 2, 4, ..., decode_chunk}), not one per remaining-count.
-            while chunk & (chunk - 1):
-                chunk &= chunk - 1
-            return chunk
-        return 1
-
     def step(self) -> int:
         """Admit pending prefills, run at most one interleaved prefill
         chunk, advance every active slot one token. Returns the number
@@ -2249,8 +1866,6 @@ class DecodeEngine:
         one profiler annotation per slice and one deque append per
         STEP; with the ring off this path is the uninstrumented
         loop."""
-        import jax.numpy as jnp
-
         rec = self.steplog.enabled
         sl = self.steplog
         phases: List[Dict[str, Any]] = []
@@ -2274,68 +1889,19 @@ class DecodeEngine:
         if not self._active:
             self._steplog_row(t_step0, phases)
             return 0
-        if self._spec_ready():
-            # Page both pools for the round up front (block tables are
-            # static across the draft/verify calls). The target ensure
-            # may preempt the youngest request; the draft ensure only
-            # ever demotes draft seats.
-            if rec:
-                sl.begin("pages")
-            self._ensure_decode_pages(self.spec_k + 1)
-            if not self._active:
-                self._steplog_row(t_step0, phases)
-                return 0
-            self._ensure_draft_pages(self.spec_k)
-            if self._spec_ready():
-                return self._spec_step(t_step0, phases, rec)
         if rec:
             sl.begin("pages")
-        chunk = self._pick_chunk()
-        # Page the next k tokens in BEFORE the program runs: the block
+        # Page the next token in BEFORE the program runs: the block
         # tables are static across the call. May preempt the youngest
         # request (and so shrink the active set).
-        self._ensure_decode_pages(chunk)
+        self._ensure_decode_pages()
         if not self._active:
             self._steplog_row(t_step0, phases)
             return 0
-        chunk = min(chunk, self._pick_chunk())
         stepped = len(self._active)
         ctx = self._ctx_tokens() if rec else None
         view = self._live_view(self._block_tables, self._slot_pages)
         rung = (view[self._kind] if self._windows else view).shape[1]
-        if chunk > 1:
-            t_d0 = time.time() if rec else 0.0
-            toks, self.cache = self._dispatch_fresh(
-                ("decode_k", chunk, rung),
-                lambda: self._decode_k(
-                    self.params, self.cache,
-                    jnp.asarray(self._tokens), jnp.asarray(view),
-                    k=chunk),
-                batch=stepped, ctx_tokens=ctx, view_pages=rung)
-            if rec:
-                sl.begin("fetch", program="decode_k")
-            toks = np.array(toks)  # (chunk, slots)
-            if rec:
-                sl.begin("sample_emit")
-            if rec:
-                phases.append({"phase": "decode", "t0": t_d0,
-                               "t1": time.time(), "batch": stepped,
-                               "k": chunk})
-            self.steps += chunk
-            for slot in list(self._active):
-                req = self._active[slot]
-                for i in range(chunk):
-                    tok = int(toks[i, slot])
-                    self._emit(req, tok)
-                    self._tokens[slot] = tok
-                    if req.generated >= req.max_new_tokens or (
-                            req.eos_id is not None
-                            and tok == req.eos_id):
-                        self._finish_in_step(slot)
-                        break
-            self._state_dev = None
-            self._steplog_row(t_step0, phases, ctx, rung)
-            return stepped
         t_d0 = time.time() if rec else 0.0
         # ``uploads``: the arrays this dispatch puts on the device. The
         # view always; the state and the temperatures only when the
@@ -2360,7 +1926,7 @@ class DecodeEngine:
             sl.amend("launch", **counted)
             sl.begin("sample_emit", **counted)
             phases.append({"phase": "decode", "t0": t_d0,
-                           "t1": time.time(), "batch": stepped, "k": 1})
+                           "t1": time.time(), "batch": stepped})
         self.steps += 1
         for slot in list(self._active):
             req = self._active[slot]
@@ -2414,147 +1980,6 @@ class DecodeEngine:
         self._slice("finish")
         self._finish(slot)
         self._slice("sample_emit")
-
-    def _spec_ready(self) -> bool:
-        """Spec rounds engage only when every active request is greedy
-        (the acceptance rule compares ARGMAX tokens, which is exactly
-        the sequential greedy choice — sampled requests take the plain
-        step, which draws on its own random stream) AND at least one
-        slot still holds a draft seat: an all-draftless batch would pay
-        the k+1-wide verify forward for guaranteed-rejected junk, so it
-        takes the plain path instead."""
-        return (self.spec and bool(self._active)
-                and all(r.temperature <= 0.0
-                        for r in self._active.values())
-                and any(self._draft_committed[s] >= 0
-                        for s in self._active))
-
-    def _spec_step(self, t_step0: float, phases: List[Dict[str, Any]],
-                   rec: bool) -> int:
-        """One speculative round: the draft proposes k tokens per active
-        slot (catching up on last round's accepted run first), the
-        target verifies all k+1 positions in ONE batched forward, the
-        longest proposal prefix matching the target's own argmax emits —
-        plus the target's correction token — and page cursors roll back
-        over the rejected tail. Emits 1..k+1 tokens per slot per round;
-        greedy output is bit-identical to sequential decode because
-        position j's verify logits condition on exactly the tokens
-        sequential decode would have committed whenever proposals 1..j
-        all accepted, and nothing past the first mismatch is used."""
-        import jax.numpy as jnp
-
-        k = self.spec_k
-        stepped = len(self._active)
-        ctx = self._ctx_tokens() if rec else None
-        # ---- draft: bounded catch-up rows + k proposals per slot
-        catchup = np.zeros((self.slots, 2), np.int32)
-        clens = np.ones((self.slots,), np.int32)
-        for slot, req in list(self._active.items()):
-            D = self._draft_committed[slot]
-            if D < 0:
-                continue  # draftless: junk proposals, still verified
-            L = req.prompt_len + req.generated - 1
-            if L - D + 1 > 2:
-                # _draft_resync may evict younger draft seats or demote
-                # this slot to draftless; both leave the round correct,
-                # so just re-read the state it settled on.
-                if not self._draft_resync(slot, req):
-                    continue
-                D = self._draft_committed[slot]
-            cl = L - D + 1
-            for j in range(cl):
-                catchup[slot, j] = self._token_at(req, D + j)
-            clens[slot] = cl
-        # The draft's proposals are decode steps over the DRAFT pool's
-        # live pages; a draftless slot holds none and writes to scratch.
-        dview = self._live_view(self._draft_bt, self._draft_slot_pages)
-        t_d0 = time.time() if rec else 0.0
-        toks_d, self._draft_cache = self._dispatch_fresh(
-            ("spec_draft", k, dview.shape[1]),
-            lambda: self._spec_draft(
-                self._draft_params, self._draft_cache,
-                jnp.asarray(catchup), jnp.asarray(clens),
-                jnp.asarray(self._draft_bt), jnp.asarray(dview), k=k),
-            batch=stepped, view_pages=dview.shape[1])
-        self._slice("fetch", program="spec_draft")
-        # np.array (never asarray): the next donated dispatch must not
-        # clobber an aliased host view of these tokens (PR 14 pin).
-        toks_d = np.array(toks_d)                          # (slots, k)
-        self._slice("launch", program="spec_verify")  # ... its rows
-        if rec:
-            phases.append({"phase": "draft", "t0": t_d0,
-                           "t1": time.time(), "batch": stepped, "k": k})
-        # ---- target: verify all k+1 positions in one batched forward
-        rows = np.zeros((self.slots, k + 1), np.int32)
-        for slot in self._active:
-            rows[slot, 0] = self._tokens[slot]
-            rows[slot, 1:] = toks_d[slot]
-        t_v0 = time.time() if rec else 0.0
-        toks_v, self.cache = self._dispatch_fresh(
-            ("spec_verify", k),
-            lambda: self._spec_verify(
-                self.params, self.cache, jnp.asarray(rows),
-                jnp.asarray(self._block_tables)),
-            batch=stepped, ctx_tokens=ctx)
-        self._slice("fetch", program="spec_verify")
-        toks_v = np.array(toks_v)                          # (slots, k+1)
-        self._slice("sample_emit")
-        # ---- host: longest-matching-prefix acceptance + rollback
-        self.steps += 1
-        self.spec_rounds += 1
-        self._state_dev = None
-        round_accepted = 0
-        upd: List[Tuple[int, int, int]] = []   # (slot, L', D')
-        for slot in list(self._active):
-            req = self._active[slot]
-            g = toks_v[slot]
-            n_acc = 0
-            while n_acc < k and rows[slot, n_acc + 1] == g[n_acc]:
-                n_acc += 1
-            if self._draft_committed[slot] >= 0:
-                req.spec_proposed += k
-                req.spec_accepted += n_acc
-                self.spec_proposed += k
-                self.spec_accepted += n_acc
-                round_accepted += n_acc
-            L = req.prompt_len + req.generated - 1
-            emitted = 0
-            finished = False
-            for j in range(n_acc + 1):
-                tok = int(g[j])
-                self._emit(req, tok)
-                self._tokens[slot] = tok
-                emitted += 1
-                if req.generated >= req.max_new_tokens or (
-                        req.eos_id is not None and tok == req.eos_id):
-                    finished = True
-                    break
-            if finished:
-                # frees both pools' tails wholesale
-                self._finish_in_step(slot)
-                continue
-            committed = L + emitted
-            if self._draft_committed[slot] >= 0:
-                # Draft K/V is valid through L + k (catch-up + its own
-                # proposals); past-the-acceptance junk rolls back with
-                # the pages below and the next catch-up row rewrites it.
-                self._draft_committed[slot] = L + min(emitted, k)
-            self._rollback_pages(slot, committed)
-            upd.append((slot, committed,
-                        max(0, self._draft_committed[slot])))
-        if upd:
-            ids = jnp.asarray([u[0] for u in upd], jnp.int32)
-            self.cache["length"] = self.cache["length"].at[ids].set(
-                jnp.asarray([u[1] for u in upd], jnp.int32))
-            self._draft_cache["length"] = \
-                self._draft_cache["length"].at[ids].set(
-                    jnp.asarray([u[2] for u in upd], jnp.int32))
-        if rec:
-            phases.append({"phase": "verify", "t0": t_v0,
-                           "t1": time.time(), "batch": stepped, "k": k,
-                           "accepted": round_accepted})
-        self._steplog_row(t_step0, phases, ctx)
-        return stepped
 
     def _steplog_row(self, t0: float, phases: List[Dict[str, Any]],
                      ctx_tokens: Optional[int] = None,
@@ -2618,59 +2043,6 @@ class DecodeEngine:
         for rung in self._view_ladder:
             yield rung, self._jax.tree.map(jnp.asarray, self._view(
                 self._block_tables, none, rung))
-
-    def warmup(self) -> None:
-        """Pre-dispatch the step-loop programs (``warm_decode``'s ladder,
-        and the optional ones: the chunk grid and the spec round at
-        every rung, one admission bucket) so the first real request
-        never pays their jit compiles. Safe on an idle engine: writes
-        route to the scratch page (idle block tables are all zeros, the
-        views are empty), and the parked KV lengths are restored
-        afterwards."""
-        import jax.numpy as jnp
-
-        toks = jnp.asarray(self._tokens)
-        bucket = self.prefill_bucket
-        wp = max(1, -(-bucket // self.page_tokens))
-        _, self.cache = self._dispatch_fresh(
-            ("paged_prefill", 1, bucket),
-            lambda: self._paged_prefill(
-                self.params, self.cache,
-                jnp.zeros((1, bucket), jnp.int32),
-                jnp.asarray([0], jnp.int32),
-                self._prefill_tables([0], [0],
-                                     self._block_tables[:1, :wp], bucket),
-                jnp.asarray([0], jnp.int32), np.zeros((1,), np.float32),
-                np.int32(0), n=1, bucket=bucket))
-        self.warm_decode()
-        for rung, view in self._empty_views():
-            c = 2
-            while c <= self.decode_chunk:
-                _, self.cache = self._dispatch_fresh(
-                    ("decode_k", c, rung),
-                    lambda: self._decode_k(self.params, self.cache,
-                                           toks, view, k=c))
-                c *= 2
-            if self.spec:
-                k = self.spec_k
-                _, self._draft_cache = self._dispatch_fresh(
-                    ("spec_draft", k, rung),
-                    lambda: self._spec_draft(
-                        self._draft_params, self._draft_cache,
-                        jnp.zeros((self.slots, 2), jnp.int32),
-                        jnp.ones((self.slots,), jnp.int32),
-                        jnp.asarray(self._draft_bt), view, k=k))
-        if self.spec:
-            k = self.spec_k
-            _, self.cache = self._dispatch_fresh(
-                ("spec_verify", k),
-                lambda: self._spec_verify(
-                    self.params, self.cache,
-                    jnp.zeros((self.slots, k + 1), jnp.int32),
-                    jnp.asarray(self._block_tables)))
-            self._draft_cache["length"] = \
-                self._draft_cache["length"].at[:].set(0)
-        self.cache["length"] = self.cache["length"].at[:].set(0)
 
     def serve_forever(self, idle_wait_s: float = 0.05) -> None:
         """Decode loop for a replica thread: steps while work exists,
@@ -2793,21 +2165,6 @@ class DecodeEngine:
         out["kv_fragmentation"] = self._fragmentation()
         out["handoffs_published"] = self.handoffs_published
         out["handoffs_adopted"] = self.handoffs_adopted
-        if self.spec:
-            # Fleet-visible acceptance: proposed/accepted feed the same
-            # counters Prometheus sees; accept_rate is the cumulative
-            # ratio (per-request distribution lives in the histogram).
-            out["spec"] = {
-                "k": self.spec_k,
-                "rounds": self.spec_rounds,
-                "proposed_tokens": self.spec_proposed,
-                "accepted_tokens": self.spec_accepted,
-                "accept_rate": (
-                    round(self.spec_accepted / self.spec_proposed, 4)
-                    if self.spec_proposed else None),
-                "draft_pages_total": self._draft_pages.pages,
-                "draft_pages_free": self._draft_pages.free_count,
-            }
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()
         if self.steplog.enabled:
@@ -2838,10 +2195,9 @@ class DecodeEngine:
             "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
             "bytes_in_use": [m.get("bytes_in_use") for m in mem],
             "peak_bytes_in_use": [m.get("peak_bytes_in_use") for m in mem],
-            # The tree the programs read (the draft's not counted): its
-            # bytes over all devices, and the dtype of the leaves the
-            # model casts, which is the compute dtype once the engine
-            # holds them.
+            # The tree the programs read: its bytes over all devices,
+            # and the dtype of the leaves the model casts, which is the
+            # compute dtype once the engine holds them.
             "weights_bytes": sum(
                 w.nbytes for w in self._jax.tree.leaves(self.params)),
             # (a tied embedding is the head).
@@ -2866,7 +2222,6 @@ class DecodeEngine:
         out["deployment"] = self._mtags["deployment"]
         out["replica_id"] = self._replica_id
         out["slots"] = self.slots
-        out["spec_k"] = self.spec_k if self.spec else 0
         return out
 
     def _fragmentation(self) -> float:
@@ -2918,21 +2273,16 @@ class LlamaDecodeDeployment:
 
     def __init__(self, preset: str = "debug", slots: int = 4,
                  capacity: int = 1024, seed: int = 0,
-                 config=None, decode_chunk: int = 1,
+                 config=None,
                  prefix_pool_entries: Optional[int] = None,
                  prefix_match_min_tokens: Optional[int] = None,
                  queue_max: Optional[int] = None,
                  kv_page_tokens: Optional[int] = None,
                  kv_pool_pages: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
-                 mesh_shape=None,
-                 spec_draft_model: Optional[str] = None,
-                 spec_k: Optional[int] = None,
-                 spec_draft_pool_pages: Optional[int] = None,
-                 warmup: Optional[bool] = None):
+                 mesh_shape=None):
         import jax
 
-        from ray_tpu.core.config import config as rt_config
         from ray_tpu.util.compile_cache import compile_watch
 
         llama, ld = self.model_modules()
@@ -2945,45 +2295,17 @@ class LlamaDecodeDeployment:
         # device as soon as its compute-dtype copy exists.
         params = ld.compute_weights(
             llama.init_params(cfg, jax.random.key(seed)), cfg, donate=True)
-        # Draft model for speculative decoding: a (smaller) preset named
-        # by knob. Seeded independently of the target — the contract
-        # never depends on draft quality, only on verification.
-        draft_name = (rt_config.spec_draft_model
-                      if spec_draft_model is None else spec_draft_model)
-        sk = rt_config.spec_k if spec_k is None else int(spec_k)
-        draft_params = draft_cfg = None
-        if draft_name and sk > 0:
-            draft_cfg = llama.PRESETS[draft_name]
-            if draft_cfg.vocab_size != cfg.vocab_size:
-                raise ValueError(
-                    f"spec_draft_model {draft_name!r} vocab "
-                    f"({draft_cfg.vocab_size}) != target vocab "
-                    f"({cfg.vocab_size}) — proposals must share the "
-                    f"token space the target verifies")
-            draft_params = ld.compute_weights(
-                llama.init_params(draft_cfg, jax.random.key(seed + 1)),
-                draft_cfg, donate=True)
         self.engine = DecodeEngine(
             params, cfg, slots=slots, capacity=capacity,
-            decode_chunk=decode_chunk,
             prefix_pool_entries=prefix_pool_entries,
             prefix_match_min_tokens=prefix_match_min_tokens,
             queue_max=queue_max,
             page_tokens=kv_page_tokens, pool_pages=kv_pool_pages,
             prefill_chunk_tokens=prefill_chunk_tokens,
-            mesh_shape=mesh_shape,
-            spec_draft_params=draft_params, spec_draft_config=draft_cfg,
-            spec_k=sk if draft_params is not None else 0,
-            spec_draft_pool_pages=spec_draft_pool_pages, model=ld)
-        # The decode ladder is warmed whatever the knob says: which rung
-        # a step takes follows the traffic, and a rung met first under
-        # load is seconds of compile in one request's latency. The knob
-        # governs the optional programs (chunk grid, spec round, one
-        # admission bucket), which ``warmup`` adds to the ladder.
-        if (rt_config.decode_warmup if warmup is None else warmup):
-            self.engine.warmup()
-        else:
-            self.engine.warm_decode()
+            mesh_shape=mesh_shape, model=ld)
+        # Which rung a step takes follows the traffic, and a rung met
+        # first under load is seconds of compile in one request's latency.
+        self.engine.warm_decode()
         # Prefill->decode handoff lease ledger (disaggregated serving):
         # tracks published-but-undischarged KV-page handoffs so the TTL
         # sweep (riding replica_metrics) can return refs nobody claimed.
@@ -3031,8 +2353,6 @@ class LlamaDecodeDeployment:
         for key in ("pages_total", "pages_free", "pages_in_use",
                     "pages_pinned", "kv_fragmentation", "preempted"):
             out[key] = s[key]
-        if self.engine.spec:
-            out["spec"] = s["spec"]
         if self.engine.prefix is not None:
             out["prefix"] = s.get("prefix", {})
             out["prefixes"] = self.engine.prefix.hashes()
@@ -3322,9 +2642,8 @@ class LlamaDecodeDeployment:
 
 class DeepseekDecodeDeployment(LlamaDecodeDeployment):
     """The same deployment over DeepSeek-V2 (``models/deepseek.py``): a
-    latent paged pool, absorbed decode, held experts. The model has none
-    of the engine's optional programs, so ``decode_chunk > 1``, ``spec_k
-    > 0`` and a mesh are refused by the engine."""
+    latent paged pool, absorbed decode, held experts. The model has no
+    ``shard_decode_state``, so a mesh is refused by the engine."""
 
     @staticmethod
     def model_modules():
@@ -3336,9 +2655,8 @@ class DeepseekDecodeDeployment(LlamaDecodeDeployment):
 class MimoDecodeDeployment(LlamaDecodeDeployment):
     """The same deployment over MiMo-V2 (``models/mimo.py``): full and
     window layers over two kinds of page, held experts behind a sigmoid
-    router. The model has none of the engine's optional programs, so
-    ``decode_chunk > 1``, ``spec_k > 0`` and a mesh are refused by the
-    engine; its window kind turns the prefix index off."""
+    router. The model has no ``shard_decode_state``, so a mesh is refused
+    by the engine; its window kind turns the prefix index off."""
 
     @staticmethod
     def model_modules():
@@ -3351,9 +2669,9 @@ class Phi4FlashDecodeDeployment(LlamaDecodeDeployment):
     """The same deployment over Phi-4-mini-flash (``models/phi4flash.py``):
     a full and a window kind of page, a recurrent state a slot, one full
     layer's keys and values read by the cross-attention layers. The model
-    has none of the engine's optional programs, so ``decode_chunk > 1``,
-    ``spec_k > 0`` and a mesh are refused by the engine, and a handoff by
-    ``submit``; its window kind and its state turn the prefix index off."""
+    has no ``shard_decode_state``, so a mesh is refused by the engine, and
+    a handoff by ``submit``; its window kind and its state turn the prefix
+    index off."""
 
     @staticmethod
     def model_modules():

@@ -112,27 +112,6 @@ PREEMPTIONS = Counter(
     "Engine recompute-preemptions under page pressure.",
     tag_keys=("deployment",))
 
-# Speculative decoding: proposal volume + acceptance. Counters give the
-# fleet-wide accepted/proposed ratio (the speedup predictor); the
-# histogram gives the per-request distribution (a bimodal accept rate
-# means one traffic class defeats the draft). Observed once per request
-# at its terminal step, per the per-REQUEST doctrine above.
-SPEC_PROPOSED = Counter(
-    "serve_spec_proposed_tokens_total",
-    "Draft tokens proposed to the verify forward.",
-    tag_keys=("deployment",))
-
-SPEC_ACCEPTED = Counter(
-    "serve_spec_accepted_tokens_total",
-    "Draft tokens accepted by target verification.",
-    tag_keys=("deployment",))
-
-SPEC_ACCEPT = Histogram(
-    "serve_spec_accept_rate",
-    "Per-request draft acceptance rate (accepted / proposed).",
-    boundaries=(0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0),
-    tag_keys=("deployment",))
-
 # Disaggregated prefill/decode handoff (ROADMAP #3). Descriptor bytes
 # prove the handoff rides the object plane by reference: the descriptor
 # is block-table metadata (~hundreds of bytes), never the KV payload
@@ -212,7 +191,6 @@ _HISTOGRAMS = {
     "stream_pull_items": "serve_stream_pull_items",
     "stream_delivery_s": "serve_stream_delivery_s",
     "ingress_s": "serve_ingress_s",
-    "spec_accept_rate": "serve_spec_accept_rate",
     "handoff_bytes": "serve_handoff_bytes",
     "handoff_latency_s": "serve_handoff_latency_s",
 }
@@ -243,10 +221,6 @@ def slo_summary(aggregated: Dict[str, List[Dict[str, Any]]]
             tags.get("outcome", "?")] = int(total)
     for name, field in (("serve_router_retries_total", "retries"),
                         ("serve_preemptions_total", "preempted"),
-                        ("serve_spec_proposed_tokens_total",
-                         "spec_proposed_tokens"),
-                        ("serve_spec_accepted_tokens_total",
-                         "spec_accepted_tokens"),
                         ("serve_http_requests_total", "http_responses")):
         for key, total in counter_totals(aggregated, name).items():
             tags = dict(key)

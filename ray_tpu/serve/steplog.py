@@ -4,8 +4,7 @@ slow?".
 The SLO histograms say a p99 token took 300 ms; this recorder says what
 the engine was doing at that moment: one row per ``DecodeEngine.step()``
 with the step's phases (admission prefill, interleaved prefill chunk,
-decode, in spec mode ``draft``/``verify`` — the verify phase carries
-the round's accepted-token count — and on a disaggregated decode fleet
+decode, and on a disaggregated decode fleet
 ``handoff``, the adopt splice of a prefill fleet's published KV pages,
 tagged with the page count) and batch occupancy, plus
 the discrete events that explain latency cliffs — page alloc/free,

@@ -740,9 +740,6 @@ def test_cli_jobs_parallel_matches_serial():
     assert [f.to_json() for f in serial] == [f.to_json() for f in parallel]
 
 
-@pytest.mark.slow  # 7s: full-repo diff run; diff-mode coverage stays
-# via v3's diff_mode_covers_new_families + diff_one_file_stays_fast;
-# PR 18 rebudget
 def test_cli_diff_mode(tmp_path, capsys):
     from ray_tpu.analysis.__main__ import main
 
@@ -756,7 +753,6 @@ def test_cli_diff_mode(tmp_path, capsys):
     assert rc == 2
 
 
-@pytest.mark.slow  # 7s: full-repo stats run; PR 16 rebudget
 def test_cli_stats_json_artifact(tmp_path, capsys):
     from ray_tpu.analysis.__main__ import main
 

@@ -371,25 +371,24 @@ def test_mutation_dropped_anchor_caught():
         [f.render() for f in found]
 
 
-def test_mutation_verify_rules_partition_caught():
+def test_mutation_suffix_rules_partition_caught():
     """Acceptance: sharding the wo-contraction axis in the REAL
-    DECODE_RULES is caught at the speculative verify forward too — the
-    spec-mode verify program sits under the same bit-exactness
-    contract as the decode steps."""
+    DECODE_RULES is caught at the paged suffix forward too: the
+    chunked-prefill program sits under the same bit-exactness contract
+    as the decode steps."""
     project = repo_project_with(
         "ray_tpu/parallel/sharding.py",
         '"attn_heads": None,', '"attn_heads": "model",')
     found = run_checker(sharding_safety.check, project)
     hits = [f for f in found if f.rule == rules.SHARDING_CONTRACTION]
     assert hits, [f.render() for f in found]
-    assert any(f.symbol == "paged_verify.body" for f in hits), \
+    assert any(f.symbol == "paged_prefill_suffix.body" for f in hits), \
         sorted({f.symbol for f in hits})
 
 
-def test_mutation_verify_dropped_anchor_caught():
-    """The S-shaped attention anchor line is shared verbatim by the
-    paged suffix and spec verify forwards: dropping it loses the pre-wo
-    anchor in both."""
+def test_mutation_suffix_dropped_anchor_caught():
+    """Dropping the S-shaped attention anchor line of the paged suffix
+    forward loses its pre-wo anchor."""
     project = repo_project_with(
         "ray_tpu/models/llama_decode.py",
         '        att = att.transpose(0, 3, 1, 2, 4).reshape(\n'
@@ -401,12 +400,12 @@ def test_mutation_verify_dropped_anchor_caught():
     found = run_checker(sharding_safety.check, project)
     hits = [f for f in found if f.rule == rules.SHARDING_ANCHOR]
     assert sorted({f.symbol for f in hits}) == [
-        "paged_prefill_suffix.body", "paged_verify.body"], \
+        "paged_prefill_suffix.body"], \
         [f.render() for f in found]
 
 
-def test_spec_programs_clean_under_decode_rules():
-    """TN: the unmutated verify / draft / device-sampler programs carry
+def test_decode_model_module_clean_under_decode_rules():
+    """TN: the unmutated paged forwards and the device sampler carry
     their anchors and contract only unsharded axes — no sharding
     findings anywhere in the decode model module."""
     found = run_checker(sharding_safety.check,

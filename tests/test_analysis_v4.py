@@ -456,11 +456,12 @@ def test_mutation_decode_unwrapped_dispatch():
 
 
 def test_mutation_decode_asarray_flip():
-    """Flip a draft-token copy back to np.asarray ->
-    donation-asarray-alias (the PR 16 clobbered-tokens bug)."""
+    """Flip the decode's fetch of its ids back to np.asarray ->
+    donation-asarray-alias (a host view of a buffer the next donated
+    dispatch may clobber)."""
     found, _ = mutant_findings(
         donation_safety.check, "ray_tpu/serve/decode.py",
-        "toks_d = np.array(toks_d)", "toks_d = np.asarray(toks_d)")
+        "got = np.array(state)", "got = np.asarray(state)")
     hits = [f for f in found
             if f.rule == rules.DONATION_ASARRAY_ALIAS]
     assert hits and hits[0].path == "ray_tpu/serve/decode.py"
@@ -491,8 +492,8 @@ def test_donation_index_sees_the_repo():
         in index.owner_classes
     attrs = {attr for (mod, cls, attr) in index.donated_attrs
              if mod == "ray_tpu.serve.decode"}
-    assert "_decode" in attrs
-    assert len(attrs) >= 6, sorted(attrs)
+    assert attrs == {"_decode", "_paged_prefill", "_paged_suffix",
+                     "_adopt_pages"}
 
 
 # ============================= repo-clean gates + strict-path wiring

@@ -123,8 +123,6 @@ def test_named_actor_survives_disconnect(client_pair):
         client2.disconnect()
 
 
-@pytest.mark.slow  # PR 20 rebudget (5.1s): reap soak rides the
-# session-GC timer; disconnect/reconnect behavior stays tier-1
 @pytest.mark.timeout_s(120)
 def test_stale_session_reaped(ray_start_regular):
     """A crashed client (keepalive stops, no disconnect) gets its session

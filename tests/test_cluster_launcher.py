@@ -152,8 +152,6 @@ def test_tpu_vm_provider_slice_gang_bootstrap():
 # -------------------------------------------------------------- end-to-end
 
 
-@pytest.mark.slow  # PR 20 rebudget (5.4s): end-to-end launcher soak;
-# the autoscaler decision units stay tier-1
 @pytest.mark.timeout_s(170)
 def test_up_fake_multinode_autoscales_end_to_end(tmp_path):
     """``ray_tpu up`` on a fake_multinode YAML boots a real autoscaling

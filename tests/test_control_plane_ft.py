@@ -569,8 +569,6 @@ def test_chaos_sigkill_controller_mid_decode(slice_faults_cluster):
 
 @pytest.mark.chaos
 @pytest.mark.timeout_s(240)
-@pytest.mark.slow  # 11s: outage soak; chaos sigkill test keeps the
-# controller-FT path in tier-1 (PR 16 rebudget)
 def test_serve_during_outage_http_and_soft_status(slice_faults_cluster):
     """Satellite: routers and proxies keep serving from their cached
     snapshot while the controller is DOWN (restart stretched to a

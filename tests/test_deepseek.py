@@ -135,15 +135,9 @@ def test_a_prefix_hit_splices_latent_pages_and_matches_the_reference(model):
     assert max(_margins(model, prompts, reqs)) < MARGIN
 
 
-def test_the_engine_refuses_options_whose_program_the_model_lacks(model):
-    with pytest.raises(ValueError, match="paged_decode_chunk"):
-        _engine(model, decode_chunk=2)
+def test_the_engine_refuses_a_mesh_whose_program_the_model_lacks(model):
     with pytest.raises(ValueError, match="shard_decode_state"):
         _engine(model, mesh_shape=(1, 2))
-    cfg, params = model
-    with pytest.raises(ValueError, match="paged_verify"):
-        _engine(model, spec_k=2, spec_draft_params=params,
-                spec_draft_config=cfg)
 
 
 def test_the_step_log_carries_the_expert_counters(model):

@@ -269,41 +269,14 @@ def test_handoff_ledger_lease_discipline():
     assert led.live() == 0
 
 
-def test_autoscale_load_spec_signals():
-    """The autoscaler's per-replica load folds in speculative-decoding
-    health: a collapsed accept rate inflates load toward (k+1)x, and
-    draft-pool pressure past 75% occupancy bumps it further; a healthy
-    replica's load is untouched."""
+def test_autoscale_load_is_the_worse_of_ongoing_and_backlog():
+    """The autoscaler's per-replica load: HTTP concurrency or the
+    engine's own backlog, whichever is worse."""
     from ray_tpu.serve.controller import autoscale_load
 
     assert autoscale_load({"ongoing": 2, "load": 5}) == 5.0
     assert autoscale_load({"ongoing": 3}) == 3.0
     assert autoscale_load({}) == 0.0
-
-    # accept=1.0: spec at full speed, no inflation.
-    healthy = {"load": 4, "spec": {"k": 3, "accept_rate": 1.0,
-                                   "draft_pages_total": 100,
-                                   "draft_pages_free": 80}}
-    assert autoscale_load(healthy) == pytest.approx(4.0)
-    # accept=0: every verify round yields one token for k+1 steps of
-    # work -> load inflates by (k+1).
-    collapsed = {"load": 4, "spec": {"k": 3, "accept_rate": 0.0,
-                                     "draft_pages_total": 100,
-                                     "draft_pages_free": 80}}
-    assert autoscale_load(collapsed) == pytest.approx(16.0)
-    # unknown accept (no rounds yet) counts as 0 — scale-out-safe.
-    assert autoscale_load(
-        {"load": 4, "spec": {"k": 3, "accept_rate": None,
-                             "draft_pages_total": 100,
-                             "draft_pages_free": 80}}
-    ) == pytest.approx(16.0)
-    # draft pool nearly full: occupancy 0.95 -> x1.2 bump on top.
-    squeezed = {"load": 4, "spec": {"k": 3, "accept_rate": 1.0,
-                                    "draft_pages_total": 100,
-                                    "draft_pages_free": 5}}
-    assert autoscale_load(squeezed) == pytest.approx(4.0 * 1.2)
-    # no spec block / k=0: legacy load, untouched.
-    assert autoscale_load({"load": 4, "spec": {"k": 0}}) == 4.0
 
 
 def test_deployment_role_validation_and_config():

@@ -20,13 +20,11 @@ import pytest
 
 NORM_LEAVES = ("attn_norm", "mlp_norm")
 
-# name: engine arguments (the draft's and the mesh's are added in _build).
+# name: engine arguments.
 KINDS = {
     "paged_chunked": dict(page_tokens=16, pool_pages=40,
                           prefill_chunk_tokens=16, prefix_pool_entries=0),
     "paged_prefix": dict(page_tokens=16, pool_pages=40),
-    "speculative": dict(page_tokens=16, pool_pages=40, spec_k=3,
-                        prefix_pool_entries=0),
     "mesh_1x2": dict(page_tokens=16, pool_pages=40, mesh_shape=(1, 2),
                      prefix_pool_entries=0),
 }
@@ -42,33 +40,19 @@ def model():
     return cfg, llama.init_params(cfg, jax.random.key(0))
 
 
-@pytest.fixture(scope="module")
-def draft():
-    import jax
-
-    from ray_tpu.models import llama
-
-    cfg = dataclasses.replace(llama.PRESETS["debug"], n_layers=1)
-    return cfg, llama.init_params(cfg, jax.random.key(1))
-
-
-def _build(kind, model, draft, tree="held"):
+def _build(kind, model, tree="held"):
     """An engine of ``kind``. ``tree="masters"`` puts the caller's float32
     tree back under the same programs: the parent's per-step cast."""
     from ray_tpu.models import llama_decode as ld
     from ray_tpu.serve.decode import DecodeEngine
 
     cfg, params = model
-    kw = dict(slots=4, capacity=128, prefill_bucket=16, **KINDS[kind])
-    if kind == "speculative":
-        kw.update(spec_draft_config=draft[0], spec_draft_params=draft[1])
-    eng = DecodeEngine(params, cfg, **kw)
+    eng = DecodeEngine(params, cfg, slots=4, capacity=128,
+                       prefill_bucket=16, **KINDS[kind])
     if tree == "masters":
         eng.params = params
         if eng.mesh is not None:
             eng.params = ld.shard_decode_state(params, cfg, eng.mesh)[0]
-        if eng.spec:
-            eng._draft_params = draft[1]
     return eng
 
 
@@ -82,16 +66,14 @@ def _cast_leaves(params):
             yield k, params["layers"][k]
 
 
-PROGRAMS = ("_decode", "_decode_k", "_paged_prefill", "_paged_suffix",
-            "_spec_verify", "_spec_draft")
+PROGRAMS = ("_decode", "_paged_prefill", "_paged_suffix")
 
 
 def _serve(eng):
     """Drive a fixed set of greedy requests; returns their streams and the
-    first output (logits, or the sampled tokens of a speculative program)
-    of every program call, in order."""
+    first output (the sampled tokens) of every program call, in order."""
     calls = []
-    programs = {n: getattr(eng, n) for n in PROGRAMS if hasattr(eng, n)}
+    programs = {n: getattr(eng, n) for n in PROGRAMS}
     for name, prog in programs.items():
 
         def recorded(*a, _prog=prog, _name=name, **kw):
@@ -129,13 +111,13 @@ def _drive_waves(eng):
 
 
 @pytest.fixture(scope="module")
-def served(model, draft):
+def served(model):
     """kind -> (engine, streams, calls), each engine built and driven once."""
     cache = {}
 
     def get(kind):
         if kind not in cache:
-            eng = _build(kind, model, draft)
+            eng = _build(kind, model)
             cache[kind] = (eng,) + _serve(eng)
         return cache[kind]
 
@@ -148,31 +130,27 @@ def served(model, draft):
 
 
 @pytest.mark.parametrize("kind", list(KINDS))
-def test_engine_holds_cast_leaves_in_compute_dtype(kind, model, draft,
-                                                   served):
+def test_engine_holds_cast_leaves_in_compute_dtype(kind, model, served):
     import jax
     import jax.numpy as jnp
 
     cfg, params = model
     eng = served(kind)[0]
-    trees = [(eng.params, params)]
-    if kind == "speculative":
-        trees.append((eng._draft_params, draft[1]))
-    for held, came in trees:
-        assert jax.tree.structure(held) == jax.tree.structure(came)
-        cast = dict(_cast_leaves(held))
-        assert set(cast) == {"tok_embed", "lm_head", "wq", "wk", "wv", "wo",
-                             "w_gate", "w_up", "w_down"}
-        for name, w in cast.items():
-            assert w.dtype == jnp.bfloat16, name
-        for name in NORM_LEAVES:
-            assert held["layers"][name].dtype == jnp.float32, name
-        assert held["final_norm"].dtype == jnp.float32
-        # Rounded once, to the value the per-step cast gave.
-        assert np.array_equal(
-            np.asarray(held["lm_head"].astype(jnp.float32)),
-            np.asarray(came["lm_head"].astype(jnp.bfloat16)
-                       .astype(jnp.float32)))
+    held, came = eng.params, params
+    assert jax.tree.structure(held) == jax.tree.structure(came)
+    cast = dict(_cast_leaves(held))
+    assert set(cast) == {"tok_embed", "lm_head", "wq", "wk", "wv", "wo",
+                         "w_gate", "w_up", "w_down"}
+    for name, w in cast.items():
+        assert w.dtype == jnp.bfloat16, name
+    for name in NORM_LEAVES:
+        assert held["layers"][name].dtype == jnp.float32, name
+    assert held["final_norm"].dtype == jnp.float32
+    # Rounded once, to the value the per-step cast gave.
+    assert np.array_equal(
+        np.asarray(held["lm_head"].astype(jnp.float32)),
+        np.asarray(came["lm_head"].astype(jnp.bfloat16)
+                   .astype(jnp.float32)))
     if eng.mesh is not None:
         # Same rules, half the bytes a chip: the head is split by column.
         shard = eng.params["lm_head"].addressable_shards[0].data
@@ -254,19 +232,16 @@ def test_rule_names_exactly_the_leaves_the_programs_cast():
 
 
 @pytest.mark.parametrize("kind", list(KINDS))
-def test_callers_float32_arrays_outlive_the_engine(kind, model, draft,
-                                                   served):
+def test_callers_float32_arrays_outlive_the_engine(kind, model, served):
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import llama_decode as ld
 
     served(kind)
-    owned = [model[1]] + ([draft[1]] if kind == "speculative" else [])
-    for tree in owned:
-        for w in jax.tree.leaves(tree):
-            assert w.dtype == jnp.float32 and not w.is_deleted()
-            assert np.isfinite(np.asarray(w)).all()
+    for w in jax.tree.leaves(model[1]):
+        assert w.dtype == jnp.float32 and not w.is_deleted()
+        assert np.isfinite(np.asarray(w)).all()
     # ...and still serve as a reference's weights.
     out = ld.generate(model[1], [[1, 2, 3]], model[0], max_new_tokens=4)
     assert np.asarray(out).shape == (1, 4)
@@ -304,8 +279,7 @@ def test_deployment_frees_its_masters_and_serves_the_same_tokens(model):
 
     cfg, params = model                       # seed 0, as the deployment's
     dep = LlamaDecodeDeployment(preset="debug", slots=2, capacity=64,
-                                seed=0, kv_page_tokens=16, kv_pool_pages=8,
-                                warmup=False)
+                                seed=0, kv_page_tokens=16, kv_pool_pages=8)
     try:
         assert not hasattr(dep, "params")
         for name, w in _cast_leaves(dep.engine.params):
@@ -330,14 +304,14 @@ def test_deployment_frees_its_masters_and_serves_the_same_tokens(model):
 
 @pytest.mark.parametrize("kind", list(KINDS))
 def test_streams_and_logits_are_those_of_the_float32_tree(kind, model,
-                                                          draft, served):
+                                                          served):
     """The programs on the held tree against the same programs on the
     float32 tree, which is what the parent ran every step. Bit for bit on
     this backend: both read the same bfloat16 values, rounded once here
     and once a call there (f32 -> bf16 is round-to-nearest-even in the
     eager convert and in the compiled one alike)."""
     _, streams, calls = served(kind)
-    ref = _build(kind, model, draft, tree="masters")
+    ref = _build(kind, model, tree="masters")
     try:
         want_streams, want_calls = _serve(ref)
     finally:
@@ -349,7 +323,6 @@ def test_streams_and_logits_are_those_of_the_float32_tree(kind, model,
     names = {n for n, _ in calls}
     need = {"paged_chunked": {"_paged_prefill", "_paged_suffix", "_decode"},
             "paged_prefix": {"_paged_prefill", "_paged_suffix", "_decode"},
-            "speculative": {"_paged_prefill", "_spec_verify", "_spec_draft"},
             "mesh_1x2": {"_paged_prefill", "_decode"}}[kind]
     assert need <= names, names
 

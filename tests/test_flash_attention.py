@@ -340,7 +340,6 @@ def test_splash_window_plus_segments_grads_match():
                                    atol=5e-4, rtol=5e-4)
 
 
-@pytest.mark.slow  # 11s: ring collective grads; PR 16 rebudget
 def test_ring_flash_matches_dense_and_grads():
     """Ring attention with the Pallas flash inner kernel == dense, incl.
     gradients through the cross-shard lse merge."""

@@ -34,8 +34,8 @@ def test_prefill_matches_forward(small_model):
     assert int(cache["length"][0]) == 10
 
 
-@pytest.mark.slow  # 18.9s: step-by-step re-forward; paged + spec
-# bit-exactness tests keep decode parity in tier-1 (PR 16 rebudget)
+@pytest.mark.slow  # 18.9s: step-by-step re-forward; the paged
+# tests keep decode parity in tier-1 (PR 16 rebudget)
 def test_decode_step_matches_incremental_forward(small_model):
     """Greedy decode through the cache == greedy decode by re-running the
     full forward on the growing sequence (the no-cache oracle)."""

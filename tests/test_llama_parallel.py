@@ -154,7 +154,6 @@ def test_mesh_spec_inference():
         MeshSpec(data=3).sizes(8)
 
 
-@pytest.mark.slow  # 7s: embed-parity sweep; PR 16 rebudget
 def test_embed_via_matmul_matches_gather():
     import dataclasses
 

@@ -67,6 +67,39 @@ def test_oversized_object_rejected(store):
     assert not store.put_bytes(os.urandom(16), bytes(64 << 20))
 
 
+def test_a_closed_store_never_reaches_the_native_side(tmp_path):
+    """The C++ side dereferences the handle it is given, and ``close``
+    clears it: a pin released after ``close`` (a ``ShmPin`` collected
+    after ``Node.stop``) is a no-op and any other call raises. In a child
+    process, because what this guards against is a segfault."""
+    import subprocess
+    import sys
+
+    script = f"""
+import os
+from ray_tpu._native.objstore import ShmStore
+s = ShmStore.create({str(tmp_path / "closed.store")!r}, 8 << 20)
+oid = os.urandom(16)
+pin = s.put_bytes(oid, b"x" * 1024, pin=True)
+s.close()
+pin.release()
+for call in (s.used_bytes, s.capacity, s.num_objects,
+             lambda: s.contains(oid), lambda: s.get_view(oid),
+             lambda: s.delete(oid), lambda: s.put_bytes(os.urandom(16), b"y")):
+    try:
+        call()
+    except ValueError as e:
+        assert "closed" in str(e)
+    else:
+        raise SystemExit("a call on a closed store returned")
+print("ok")
+"""
+    out = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", (
+        out.returncode, out.stderr[-2000:])
+
+
 def test_large_results_cross_node(ray_start_cluster):
     """A large result produced on node A is readable from node B via the
     node object server (reference: ObjectManager pull path)."""

@@ -106,11 +106,9 @@ def test_a_model_of_one_kind_has_no_window_tables():
     eng.shutdown()
 
 
-@pytest.mark.parametrize("option", [{"decode_chunk": 2},
-                                    {"mesh_shape": (1, 1)}])
-def test_an_option_whose_program_the_model_lacks_is_refused(option):
-    with pytest.raises(ValueError, match="has no"):
-        _engine(**option)
+def test_a_mesh_whose_program_the_model_lacks_is_refused():
+    with pytest.raises(ValueError, match="has no shard_decode_state"):
+        _engine(mesh_shape=(1, 1))
 
 
 def test_window_pages_stay_bounded_and_both_allocators_come_back():
@@ -246,7 +244,8 @@ def test_a_handoff_carries_both_kinds():
 # the parent of PR 43 (cdaa02b), llama and deepseek at toy sizes: page
 # kinds, the router's score and its bias left them letter for letter; and
 # mimo's at the parent of PR 45 (6eada8f), which slot state, the prefill
-# rows' slots and the wave's cap left so.
+# rows' slots and the wave's cap left so; and phi4flash's at the parent of
+# PR 48 (3fd825d), which the engine's two other ways to step left so.
 LOWERED_AT_PARENT = {
     "llama.decode": "5ee1c9392ee387ff",
     "llama.paged_prefill": "e3bd72ad3a1c0d98",
@@ -257,16 +256,20 @@ LOWERED_AT_PARENT = {
     "mimo.decode": "a40ff6a77742dffe",
     "mimo.paged_prefill": "f365075f30fb20ce",
     "mimo.paged_suffix": "1da9344d4831f740",
+    "phi4flash.decode": "dd5296a9fa92db6c",
+    "phi4flash.paged_prefill": "fd2c27caaad9f7db",
+    "phi4flash.paged_suffix": "f21c37416e6c07e7",
 }
 
 
-@pytest.mark.parametrize("name", ["llama", "deepseek", "mimo"])
+@pytest.mark.parametrize("name", ["llama", "deepseek", "mimo", "phi4flash"])
 def test_one_kind_models_lower_to_the_text_they_had(name):
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import (deepseek, deepseek_decode, llama,
-                                llama_decode, mimo, mimo_decode)
+                                llama_decode, mimo, mimo_decode, phi4flash,
+                                phi4flash_decode)
     from ray_tpu.serve.decode import DecodeEngine
 
     mod, dec, cfg = {
@@ -275,6 +278,8 @@ def test_one_kind_models_lower_to_the_text_they_had(name):
             mlp_dim=64, max_seq_len=128)),
         "deepseek": (deepseek, deepseek_decode, deepseek.PRESETS["debug"]),
         "mimo": (mimo, mimo_decode, mimo.PRESETS["debug"]),
+        "phi4flash": (phi4flash, phi4flash_decode,
+                      phi4flash.PRESETS["debug"]),
     }[name]
     eng = DecodeEngine(mod.init_params(cfg, jax.random.key(0)), cfg,
                        slots=4, capacity=128, page_tokens=16,

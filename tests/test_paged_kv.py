@@ -249,8 +249,7 @@ def test_paged_suffix_prefill_token_exact():
 # a row (one a row for the single decode step); row 2's run past its page
 # window, where the scratch-page rule takes over.
 
-IN_PLACE = ("paged_decode_step", "paged_prefill_suffix", "paged_verify",
-            "paged_decode_chunk")
+IN_PLACE = ("paged_decode_step", "paged_prefill_suffix")
 _BT = np.array([[1, 2, 3, 0], [5, 6, 0, 0], [7, 8, 9, 10]], np.int32)
 _LENS = np.array([9, 5, 14], np.int32)
 _PAGES, _T = 12, 4
@@ -271,14 +270,8 @@ def _in_place_call(name, cfg):
     if name == "paged_decode_step":
         return (lambda params, pool: ld.paged_decode_step(
             params, pool, view, lens, jnp.asarray(rows[:, 0]), cfg)), 1
-    if name == "paged_decode_chunk":
-        return (lambda params, pool: ld.paged_decode_chunk(
-            params, pool, view, lens, jnp.asarray(rows[:, 0]), cfg, 3)), 3
-    if name == "paged_prefill_suffix":
-        return (lambda params, pool: ld.paged_prefill_suffix(
-            params, jnp.asarray(rows), pool, bt, cfg, lens, lens + 3)), 3
-    return (lambda params, pool: ld.paged_verify(
-        params, jnp.asarray(rows), pool, bt, cfg, lens)), 3
+    return (lambda params, pool: ld.paged_prefill_suffix(
+        params, jnp.asarray(rows), pool, bt, cfg, lens, lens + 3)), 3
 
 
 def _random_pool(cfg, dtype=None):
@@ -547,10 +540,10 @@ def _lp_idle_and_prefilling(cfg, params):
         assert (after[:, 0] != before[n][:, 0]).any()
 
 
-def _lp_decode_k_crosses_page(cfg, params):
-    """``paged_decode_chunk`` over a view that lists the pages of
-    ``length + k``: both slots cross a page boundary inside the chunk,
-    and the chunk's tokens are the reference's."""
+def _lp_decode_crosses_page(cfg, params):
+    """Four greedy steps, each on the view of the pages its write needs:
+    both slots cross a page boundary on the way (8 and 12), and their
+    tokens are the reference's."""
     import jax.numpy as jnp
 
     from ray_tpu.models import llama_decode as ld
@@ -561,15 +554,20 @@ def _lp_decode_k_crosses_page(cfg, params):
     logits, pool = ld.paged_prefill(
         params, jnp.asarray(rows), ld.init_page_pool(cfg, 64, _LP_T),
         jnp.asarray(tables), cfg, lengths=jnp.asarray(lens))
-    first = jnp.argmax(logits, -1).astype(jnp.int32)
-    view, _ = _lp_view(tables, lens, 4)
-    assert (view[1] == 0).sum() == 3 and (view[1] == 1).sum() == 4
-    toks, _, lens_out = ld.paged_decode_chunk(
-        params, pool, jnp.asarray(view), jnp.asarray(lens), first, cfg, 4)
-    assert np.asarray(lens_out).tolist() == [10, 15]
+    lens = jnp.asarray(lens)
+    served, held = [], []
+    for _ in range(5):
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        served.append(np.asarray(tok))
+        view, _ = _lp_view(tables, np.asarray(lens), 1)
+        held.append([(view[1] == b).sum() for b in (0, 1)])
+        logits, pool, lens = ld.paged_decode_step(
+            params, pool, jnp.asarray(view), lens, tok, cfg)
+    assert held[0] == [2, 3] and held[-1] == [3, 4]
+    assert np.asarray(lens).tolist() == [11, 16]
     for b, prompt in enumerate(prompts):
-        served = [int(first[b])] + np.asarray(toks)[:, b].tolist()
-        assert_stream_is_the_references(params, cfg, prompt, served)
+        assert_stream_is_the_references(params, cfg, prompt,
+                                        [int(t[b]) for t in served])
 
 
 def _lp_preempted_returns(cfg, params):
@@ -601,7 +599,7 @@ LIVE_PAGE_CASES = {
     "ragged_two_rungs": _lp_ragged_two_rungs,
     "shared_page": _lp_shared_page,
     "idle_and_prefilling": _lp_idle_and_prefilling,
-    "decode_k_crosses_page": _lp_decode_k_crosses_page,
+    "decode_crosses_page": _lp_decode_crosses_page,
     "preempted_returns": _lp_preempted_returns,
 }
 
@@ -702,6 +700,55 @@ def test_engine_paged_prefix_hit_zero_copy_and_exact():
     assert r2.output == _solo(params, cfg, p2, 5)
     st = eng.prefix.stats()
     assert st["hits"] == 1 and st["prefill_tokens_saved"] == 32
+    eng.shutdown()
+
+
+def test_a_slot_seated_behind_a_shared_prefix_leaves_it_until_its_chunks():
+    """A request seated for a chunked prefill behind a spliced prefix
+    waits, un-ticked, while an older prompt takes the one chunk a step:
+    its cursor lies INSIDE the first shared page all that time, and the
+    decode steps of the other borrower leave the prefix's pages bit for
+    bit (a slot outside the view writes to the scratch page). Both
+    borrowers then stream the reference's tokens."""
+    from ray_tpu.serve.decode import DecodeEngine
+
+    cfg, params = _tiny()
+    rng = np.random.default_rng(17)
+    head = rng.integers(0, cfg.vocab_size, 16).tolist()
+    first = head + rng.integers(0, cfg.vocab_size, 3).tolist()
+    older = rng.integers(0, cfg.vocab_size, 40).tolist()   # five chunks
+    second = head + rng.integers(0, cfg.vocab_size, 12).tolist()
+    eng = DecodeEngine(params, cfg, slots=3, capacity=128, page_tokens=4,
+                       prefill_chunk_tokens=8, prefix_pool_entries=8,
+                       prefix_match_min_tokens=8)
+    r1 = eng.submit(first, max_new_tokens=24)
+    while not r1.generated:
+        eng.step()                  # its own three chunks
+    assert eng.stats()["active"] == 1 and eng.stats()["pages_pinned"] >= 4
+    r3 = eng.submit(older, max_new_tokens=3)
+    r2 = eng.submit(second, max_new_tokens=6)
+    eng.step()                      # both seated; the older takes the chunk
+    shared = list(r2.prefix_pages)
+    assert r2.prefix_len == 16 and shared == eng._slot_pages[r1.slot][:4]
+    before = {n: np.asarray(eng.cache[n][:, shared], np.float32)
+              for n in ("k", "v")}
+    waited = 0
+    while r2.prefilled == r2.prefix_len and r2.slot in eng._prefilling:
+        assert int(np.asarray(eng.cache["length"])[r2.slot]) < 16
+        stepped = eng.step()
+        assert stepped >= 1         # the first borrower decodes meanwhile
+        waited += 1
+        for n in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(eng.cache[n][:, shared], np.float32), before[n])
+    assert waited >= 4
+    _drive(eng, [r1, r2, r3])
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(eng.cache[n][:, shared], np.float32), before[n])
+    for prompt, req in ((first, r1), (second, r2), (older, r3)):
+        assert req.status == "completed"
+        assert_stream_is_the_references(params, cfg, prompt, req.output)
     eng.shutdown()
 
 

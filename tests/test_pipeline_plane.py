@@ -102,9 +102,6 @@ def _setup(seed=0, n_steps=3, n_micro=4, batch=8, seq=17):
 # -------------------------------------------- schedule + loss parity
 
 
-@pytest.mark.slow  # 9s: parity sweep; 2-stage parity stays via
-# one_stage_degenerate_bitexact + zero1_state_bytes_and_parity (the
-# 4-stage sweep is already marked); PR 18 rebudget
 def test_window_invariance_and_parity_2_stages(pipe_cluster):
     """Windows 1/2/4 all complete (no deadlock — the step timeout in
     pipe_step_timeout_s would convert one into a typed PipelineError)
@@ -377,9 +374,6 @@ def _agg(source="n1/node/pid1"):
     return {source: _Registry.get().snapshot()}
 
 
-@pytest.mark.slow  # PR 20 rebudget (7.1s): doctor observability on
-# an injected stall; stall detection itself stays covered by the
-# chaos bench
 @pytest.mark.chaos
 def test_doctor_names_pipeline_stall_straggler(pipe_cluster):
     """Delay stage 1's forward (faultinject at the pipeline.stage site)

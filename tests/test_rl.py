@@ -48,8 +48,6 @@ def test_ppo_single_iteration(ray_start_regular):
         algo.stop()
 
 
-@pytest.mark.slow  # PR 20 rebudget (6.2s): learning soak; the PPO
-# update math keeps its fast unit gates
 @pytest.mark.timeout_s(420)
 def test_ppo_learns_cartpole(ray_start_regular):
     """Run-to-reward: PPO should clearly improve on CartPole within a small
@@ -172,8 +170,6 @@ def test_appo_single_iteration(ray_start_regular):
         algo.stop()
 
 
-@pytest.mark.slow  # 10s: run-to-reward soak; APPO machinery stays via
-# test_appo_single_iteration, PPO soak stays in tier-1; PR 18 rebudget
 @pytest.mark.timeout_s(420)
 def test_appo_learns_cartpole(ray_start_regular):
     """Run-to-reward: async clipped-surrogate learning clearly beats the

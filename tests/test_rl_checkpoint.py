@@ -125,8 +125,6 @@ def test_connector_state_survives_checkpoint(ray_start_regular, tmp_path):
 
 
 @pytest.mark.timeout_s(300)
-@pytest.mark.slow  # 8s: full ASHA sweep; kill/resume checkpoint
-# tests stay in tier-1 (PR 16 rebudget)
 def test_ppo_lr_sweep_under_asha(ray_start_regular):
     """RL-under-Tune: an Algorithm config as a Tune trainable, swept by
     ASHA (reference: any RLlib algorithm under ``Tuner``)."""

@@ -225,7 +225,6 @@ def test_distributed_impala_learns_cartpole(ray_start_regular):
 
 
 @pytest.mark.timeout_s(240)
-@pytest.mark.slow  # 10s: shutdown leak soak; PR 16 rebudget
 def test_distributed_shutdown_frees_objects():
     """Zero leaked ObjectRefs: after stop(), the published weights
     object is freed from the driver-side store (the hub's pinned handle
@@ -323,8 +322,6 @@ def test_mutation_shard_queue_unlocked_put_caught():
         f.render() for f in found)
 
 
-@pytest.mark.slow  # 6s: full-repo lock-family run; the strict repo
-# gate covers these files (see docstring); PR 18 rebudget
 def test_shard_queue_lock_idiom_clean_tn():
     """TN: the committed plane is clean under the lock families (the
     strict repo gate covers this too; this pins the specific files so a

@@ -60,8 +60,6 @@ def test_shared_policy_mapping(ray_start_regular):
 
 
 @pytest.mark.timeout_s(400)
-@pytest.mark.slow  # 6s: run-to-reward soak; multi-agent machinery
-# stays via runner_maps_policies + shared_policy_mapping; PR 18 rebudget
 def test_multi_agent_ppo_learns_guide_follow(ray_start_regular):
     """Run-to-reward: both policies approach optimal (6.0 each) — the
     follower can only score by learning the guide's pattern, so this fails

@@ -141,8 +141,6 @@ def test_dqn_learns_cartpole(ray_start_regular):
     assert algo.last_leak_report["intake_alive"] is False
 
 
-@pytest.mark.slow  # 7s: offline-clone soak; offpolicy machinery stays
-# via the single-iteration + connector tests; PR 18 rebudget
 @pytest.mark.timeout_s(420)
 def test_bc_clones_policy_offline(ray_start_regular):
     """Offline pipeline: train PPO briefly, record its experience into a

@@ -71,7 +71,6 @@ def test_control_plane_500_nodes_heartbeat_storm():
 
 
 @pytest.mark.timeout_s(170)
-@pytest.mark.slow  # 8s: 50-raylet storm soak; PR 16 rebudget
 def test_50_raylets_task_pg_storms(ray_start_cluster):
     """50 live raylets: 600-task storm completes with sane scheduling
     latency; 120 simultaneous placement groups all reserve and release."""
@@ -144,9 +143,6 @@ def test_actor_wave_across_nodes(ray_start_cluster):
         ray_tpu.kill(a)
 
 
-@pytest.mark.slow  # 6s: 100-actor surge soak; envelope stays via the
-# cross-node actor wave (the raylet storm is already marked);
-# PR 18 rebudget
 @pytest.mark.timeout_s(170)
 def test_actor_surge_forkserver(ray_start_regular):
     """A burst of 100 actors — the Serve-replica-surge shape — must come up

@@ -422,7 +422,6 @@ def test_trace_context_survives_router_retry(ray_start_regular):
 # ------------------------------- proxy /metrics + status slo (e2e)
 
 
-@pytest.mark.slow  # 7s: full proxy metrics sweep; PR 16 rebudget
 def test_proxy_metrics_route_and_status_slo(ray_start_regular):
     """One decode deployment behind the real HTTP proxy: /metrics
     serves Prometheus text with per-deployment TTFT and inter-token
@@ -589,7 +588,7 @@ def test_build_chrome_trace_links_and_engine_merge():
     timelines = {"dep": {"dep#0": {"rows": [
         {"t0": t0, "t1": t0 + 0.01,
          "phases": [{"phase": "decode", "t0": t0, "t1": t0 + 0.01,
-                     "batch": 2, "k": 1}],
+                     "batch": 2}],
          "active": 2, "prefilling": 0, "queued": 0,
          "events": [{"kind": "page-alloc", "ts": t0, "n": 1}]},
     ]}}}

@@ -280,8 +280,6 @@ def mesh_serve_cluster(monkeypatch):
 
 
 @pytest.mark.timeout_s(420)
-@pytest.mark.slow  # 9s: serve-plane mesh replica; engine-level mesh
-# parity tests stay in tier-1 (PR 16 rebudget)
 def test_mesh_replica_serves_end_to_end(mesh_serve_cluster, model):
     """Acceptance: a deployment with mesh_shape=(2, 4) spawns ONE
     replica spanning all 8 virtual devices, streams through proxy ->

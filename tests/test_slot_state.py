@@ -231,12 +231,9 @@ def test_a_slot_reused_after_a_finish_serves_what_a_fresh_engine_serves(
     eng.shutdown()
 
 
-@pytest.mark.parametrize("option", [
-    {"decode_chunk": 4}, {"mesh_shape": (1, 2)},
-    {"spec_k": 2, "spec_draft_params": {}, "spec_draft_config": object()}])
-def test_an_option_whose_program_the_model_lacks_is_refused(model, option):
-    with pytest.raises(ValueError, match="has no"):
-        _engine(model, **option)
+def test_a_mesh_whose_program_the_model_lacks_is_refused(model):
+    with pytest.raises(ValueError, match="has no shard_decode_state"):
+        _engine(model, mesh_shape=(1, 2))
 
 
 @pytest.mark.parametrize("how", ["prefill_only", "adopt"])

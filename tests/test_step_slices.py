@@ -60,7 +60,6 @@ SCENARIOS = {
         {"pool_pages": 12, "prefill_chunk_tokens": 32}, [10, 70, 30, 90],
         20, {}),
     "sampled_path": ({}, [10, 30], 12, {"temperature": 0.7}),
-    "decode_chunks": ({"decode_chunk": 4}, [10, 14], 16, {}),
     "prefix_hit": ({"slots": 1, "prefix_pool_entries": 4,
                     "prefix_match_min_tokens": 16}, [40, 44, 38], 6, {}, 32),
 }
@@ -129,7 +128,7 @@ def test_phases_are_what_they_were(name):
                 # From the step's start, over reap and the whole admission.
                 assert ph["t0"] == row["t0"] and ph["waves"] >= 1
             if ph["phase"] == "decode":
-                assert ph["k"] == 1 and ph["batch"] >= 1
+                assert ph["batch"] >= 1
                 run = [s for s in row["slices"]
                        if s.get("program") == "decode"]
                 # From before the dispatch to after the blocking fetch.
@@ -397,7 +396,7 @@ def test_ring_off_records_and_annotates_nothing():
 
 def test_warmup_dispatches_belong_to_no_row():
     cfg, eng = _engine()
-    eng.warmup()
+    eng.warm_decode()
     reqs = [eng.submit(p, max_new_tokens=4) for p in _prompts(cfg, [10])]
     _drive(eng, reqs)
     first = eng.steplog.dump()["rows"][0]
@@ -462,8 +461,7 @@ def test_engine_programs_are_jitted_under_their_keys_name():
     import jax
     import jax.numpy as jnp
 
-    cfg, eng = _engine(prefill_chunk_tokens=32, decode_chunk=2)
-    toks = jnp.zeros((4,), jnp.int32)
+    cfg, eng = _engine(prefill_chunk_tokens=32)
     state = jnp.asarray(eng._host_state())
     temps = jnp.zeros((4,), jnp.float32)
     bt = jnp.asarray(eng._block_tables)
@@ -473,8 +471,6 @@ def test_engine_programs_are_jitted_under_their_keys_name():
     lowered = {
         "decode": eng._decode.lower(eng.params, eng.cache, state, view,
                                     temps),
-        "decode_k": eng._decode_k.lower(eng.params, eng.cache, toks, view,
-                                        k=2),
         "paged_prefill": eng._paged_prefill.lower(
             eng.params, eng.cache, jnp.zeros((1, 16), jnp.int32), one,
             bt[:1, :1], one, *draw, n=1, bucket=16),
@@ -487,7 +483,7 @@ def test_engine_programs_are_jitted_under_their_keys_name():
         head = low.as_text().split("\n", 1)[0]
         assert f"@jit_engine_{key} " in head, head
     # A program that ends in a sample returns the sample: on the program
-    # text, no result of the four is as wide as the vocabulary (the
+    # text, no result of the three is as wide as the vocabulary (the
     # logits stay inside), and the decode's is ids + the step counter.
     for key, low in lowered.items():
         main = next(line for line in low.as_text().splitlines()
@@ -510,26 +506,9 @@ def test_engine_programs_are_jitted_under_their_keys_name():
     text = eng._decode.lower(masters, eng.cache, state, view,
                              temps).as_text(debug_info=True)
     assert re.search(r'loc\("[^"]*weight_cast', text)
-    for key in ("paged_suffix", "decode_k"):
-        text = lowered[key].as_text(debug_info=True)
-        assert "paged_gather" in text and "paged_attn" in text
-    eng.shutdown()
-
-
-def test_spec_programs_are_named_and_scoped():
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models import llama_decode as ld
-
-    cfg, params = _tiny()
-    pool = ld.init_page_pool(cfg, 8, 16)
-    bt = jnp.zeros((2, 4), jnp.int32)
-    low = jax.jit(lambda p, pool: ld.paged_verify(
-        p, jnp.zeros((2, 3), jnp.int32), pool, bt, cfg,
-        jnp.zeros((2,), jnp.int32))).lower(params, pool)
-    text = low.as_text(debug_info=True)
+    text = lowered["paged_suffix"].as_text(debug_info=True)
     assert "paged_gather" in text and "paged_attn" in text
+    eng.shutdown()
 
 
 def test_flash_kernels_and_the_train_step_carry_their_names():

@@ -247,7 +247,7 @@ class _SlowDecode(LlamaDecodeDeployment):
     def __init__(self, step_s=STEP_S, **kwargs):
         super().__init__(config=_tiny_cfg(), capacity=64,
                          prefix_pool_entries=0, kv_page_tokens=8,
-                         warmup=False, **kwargs)
+                         **kwargs)
         self.emitted = {}  # request_id -> wall time of each token
         inner = self.engine._decode
 

@@ -51,7 +51,7 @@ class _Paced(LlamaDecodeDeployment):
     def __init__(self, step_s=STEP_S, **kwargs):
         super().__init__(config=_tiny_cfg(), capacity=64,
                          prefix_pool_entries=0, kv_page_tokens=8,
-                         warmup=False, **kwargs)
+                         **kwargs)
         inner = self.engine._decode
 
         def paced(*a, **k):
@@ -193,19 +193,27 @@ def test_bookkeeping_costs_under_two_microseconds_an_item():
                 return items, self._ended and not self._items
 
     def per_item_us(cls, n=20000):
-        best = float("inf")
-        for _ in range(7):
-            q = cls()
-            acked_at = None
-            a = time.perf_counter()
-            for i in range(n):
-                q.put(i)
-                q.take(16, acked_at)
-                acked_at = a  # a float, as the router's
-            best = min(best, (time.perf_counter() - a) / n * 1e6)
-        return best
+        q = cls()
+        acked_at = None
+        stamp = time.perf_counter()  # a float, as the router's
+        a = time.thread_time()
+        for i in range(n):
+            q.put(i)
+            q.take(16, acked_at)
+            acked_at = stamp
+        return (time.thread_time() - a) / n * 1e6
 
-    extra = per_item_us(StreamQueue) - per_item_us(Bare)
+    # What the test owns: this thread's CPU time, the two queues turn by
+    # turn so that both meet the same machine, the best of seven turns
+    # behind one that warms the loop. (All of one queue's turns before the
+    # other's read 2.36 us beside five other test workers, 1.2-1.4 so.)
+    best = {StreamQueue: float("inf"), Bare: float("inf")}
+    for turn in range(8):
+        for cls in best:
+            took = per_item_us(cls)
+            if turn:
+                best[cls] = min(best[cls], took)
+    extra = best[StreamQueue] - best[Bare]
     assert extra < 2.0, f"{extra:.2f} us an item"
 
 

@@ -293,7 +293,7 @@ def test_flash_kernels_compile_at_the_train_cell_shape_under_their_names(
     (256, 16, 8193, None, "bfloat16"),     # the shared cache at its rung
     (512, 16, 8193, None, "float32"),      # the top rung, a float32 pool
 ])
-def test_paged_decode_kernel_compiles_at_phi4flashs_widths(
+def test_paged_decode_attention_kernel_compiles_at_phi4flashs_widths(
         one_chip, no_compile_cache, monkeypatch, lists, pages, pool, window,
         dtype):
     """``ops/paged_decode_attention.py`` at Phi-4-mini-flash's widths (40

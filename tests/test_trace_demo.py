@@ -10,11 +10,7 @@ standalone)."""
 import json
 import os
 
-import pytest
 
-
-@pytest.mark.slow  # 11s: end-to-end trace demo; span/link coverage
-# stays via test_serve_observability (PR 16 rebudget)
 def test_trace_demo_emits_causally_linked_trace(ray_start_regular,
                                                 tmp_path):
     from ray_tpu.serve.trace_demo import run_demo

@@ -4,8 +4,6 @@ py-spy reporter)."""
 
 import time
 
-import pytest
-
 import ray_tpu
 from ray_tpu.util import tracing
 
@@ -69,8 +67,6 @@ def test_dump_stacks_local():
     assert "thread" in text and "test_dump_stacks_local" in text
 
 
-@pytest.mark.slow  # PR 20 rebudget (5.1s): remote stack-dump
-# surface; local dump coverage stays tier-1
 def test_worker_stack_dump_rpc(ray_start_regular):
     from ray_tpu.core import api as api_mod
     from ray_tpu.core.rpc import RpcClient

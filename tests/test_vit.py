@@ -17,8 +17,6 @@ def _synthetic_batch(cfg, n=64, seed=0):
     return {"images": jnp.asarray(images), "labels": jnp.asarray(labels)}
 
 
-@pytest.mark.slow  # 8s: overfit soak; ViT exactness stays via
-# sharded-loss parity + pad_tokens_to + trainer tests; PR 18 rebudget
 def test_vit_overfits_synthetic():
     cfg = vit.PRESETS["debug"]
     params = vit.init_params(cfg, jax.random.key(0))
