@@ -23,6 +23,16 @@ once a step for eight layers) and the loop over blocks of 32 groups
 partials written and a slot's lists added up by XLA. ``--check`` holds
 kernel and XLA path to plain float32 attention. Rows go to
 ``chiprun_out/paged_sweep.jsonl``; nothing here runs off the chip.
+
+``--model cohere2`` times the same kernel at Command A+'s widths instead
+(``command-a-plus.grounded_docs_batch``: 24 slots, 128 query heads over 8
+key heads x 128 = 1,024 flat lanes): a window layer's 65 pages a slot in
+lists of 16, and the full layer's view at 8k-32k tokens of context. A query
+head laid over all 1,024 lanes does 8 x the useful operations, so each row
+also says what share of the bf16 peak the kernel's matmuls as executed
+reach (``flat_ops_share_of_peak_pct``): near 100 the flat-lane form is
+compute-bound and a grouped-head variant would pay; well under it the
+bytes bound the kernel.
 """
 
 from __future__ import annotations
@@ -252,8 +262,111 @@ def _reference(c, q, k, v, lists, owner, seen):
     return jnp.stack(out)
 
 
+def cohere2(args, emit):
+    """The kernel at Command A+'s widths (module docstring)."""
+    from ray_tpu.models import cohere2_moe
+    from ray_tpu.models import cohere2_moe_decode as cd
+
+    c = cohere2_moe.Cohere2MoeConfig()
+    dtype = jnp.dtype(args.dtype)
+    rng = np.random.default_rng(0)
+    keys = jax.random.split(jax.random.key(0), 4)
+    slots, keep = 24, c.window // T + 1
+    window_pool, full_pool = slots * keep + 6 * 32 + 1, 9217
+    pos = rng.integers(8192, 32000, size=slots).astype(np.int32)
+    held = pos // T + 1
+
+    def table(pool, first):
+        out = np.zeros((slots, int(held.max())), np.int32)
+        free = rng.permutation(np.arange(1, pool)).tolist()
+        for s_ in range(slots):
+            for i in range(first[s_], held[s_]):
+                out[s_, i] = free.pop()
+        return out
+
+    w_first = np.maximum(0, pos - c.window + 1) // T
+    w = moe_decode.window_page_view(table(window_pool, w_first), w_first,
+                                    held - w_first, keep)
+    # A slot's ``keep`` pages as whole lists of G, as the model cuts them;
+    # the lists point into the third layer of the flat window pool.
+    per = -(-keep // G)
+    short = ((0, 0), (0, per * G - keep))
+    w_index = np.pad(w[1], short, constant_values=-1).reshape(-1, G)
+    w_pages = np.pad(w[0], short).reshape(-1, G) \
+        + 2 * window_pool * (w_index >= 0)
+    full = moe_decode.live_page_view(
+        table(full_pool, np.zeros(slots, np.int32)), held, 8192)
+    cases = (
+        ("window", c.window, 3 * window_pool,
+         (w_pages, np.repeat(np.arange(slots, dtype=np.int32), per),
+          w_index, pos)),
+        ("full", None, full_pool,
+         (full[0].reshape(-1, G), full[1].reshape(-1, G)[:, 0],
+          full[2].reshape(-1, G), pos)))
+    for shape, window, pool_pages, (lists, owner, index, pos_) in cases:
+        seen = _seen(owner, index, pos_, window)
+        tokens = int(seen.sum())
+        q = jax.random.normal(keys[0], (slots, c.n_heads, c.head_dim),
+                              jnp.float32).astype(dtype)
+        k = jax.random.normal(keys[1], (pool_pages, T, c.kv_width),
+                              jnp.float32).astype(dtype)
+        v = jax.random.normal(keys[2], (pool_pages, T, c.kv_width),
+                              jnp.float32).astype(dtype)
+        dev = [jnp.asarray(a) for a in (lists, owner, index, pos_)]
+
+        def run(q, k, v, lists, owner, index, pos, window=window):
+            _, total, part = pda.paged_decode_attention(
+                cd._flat_queries(q, c), k, v,
+                pda.page_lists(lists, owner, index, pos, T, window),
+                c.softmax_scale)
+            return cd._own_values(part, c) / jnp.where(
+                total > 0, total, 1.0)[..., None]
+
+        fn = jax.jit(run)
+        ms = _time(fn, (q, k, v, *dev), args.calls)
+        useful = tokens * 2 * c.kv_width * dtype.itemsize
+        # As executed: fetched pages x (scores + values) over all lanes.
+        fetched = int(seen.any(2).sum()) * T
+        flat_ops = 2.0 * 2 * c.n_heads * c.kv_width * fetched
+        emit({"model": "cohere2", "shape": shape, "lists": len(owner),
+              "live_pages": int(seen.any(2).sum()), "tokens_seen": tokens,
+              "dtype": dtype.name, "block_pages": pda.BLOCK_PAGES,
+              "ms": round(ms, 4),
+              "share_of_hbm_pct": round(100 * useful / (ms / 1e3) / HBM, 2),
+              "flat_ops_share_of_peak_pct": round(
+                  100 * flat_ops / (ms / 1e3) / 197e12, 2)})
+        if args.check:
+            got = np.asarray(fn(q, k, v, *dev))
+            worst = top = 0.0
+            for b in range(slots):
+                mine = np.nonzero(owner == b)[0]
+                ok = seen[mine].reshape(-1)
+                rows = np.maximum(lists[mine].reshape(-1), 0)
+                kk = np.asarray(k[rows], np.float32).reshape(
+                    -1, c.n_kv_heads, c.head_dim)[ok]
+                vv = np.asarray(v[rows], np.float32).reshape(
+                    -1, c.n_kv_heads, c.head_dim)[ok]
+                qq = np.asarray(q[b], np.float32).reshape(
+                    c.n_kv_heads, -1, c.head_dim)
+                sc = np.einsum("khd,tkd->kht", qq, kk) * c.softmax_scale
+                pr = np.exp(sc - sc.max(-1, keepdims=True))
+                pr /= pr.sum(-1, keepdims=True)
+                want = np.einsum("kht,tkd->khd", pr, vv).reshape(
+                    c.n_heads, c.head_dim)
+                worst = max(worst, float(np.abs(got[b] - want).max()))
+                top = max(top, float(np.abs(want).max()))
+            emit({"model": "cohere2", "shape": shape, "check": "kernel",
+                  "max_abs_err": worst, "largest_value": top,
+                  "ok": worst <= 2e-2 * top})
+            if worst > 2e-2 * top:
+                raise SystemExit(f"cohere2 {shape}: the kernel is {worst} "
+                                 f"from plain float32 attention")
+
+
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--model", default="phi4flash",
+                    choices=("phi4flash", "cohere2"))
     ap.add_argument("--blocks", default="",
                     help="BLOCK_PAGES to time, e.g. 4,8,16")
     ap.add_argument("--calls", type=int, default=50)
@@ -278,6 +391,11 @@ def main():
         with open(args.out, "a") as f:
             f.write(json.dumps(row) + "\n")
 
+    if args.model == "cohere2":
+        for b in blocks:
+            pda.BLOCK_PAGES = b
+            cohere2(args, emit)
+        return
     for shape, window, pool_pages, case in (
             ("window", c.window, 8 * WINDOW_POOL, _window_case(c, rng)),
             ("shared", None, SHARED_POOL, _shared_case(rng))):

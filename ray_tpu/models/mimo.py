@@ -26,12 +26,12 @@ segment (7 layers ``[0,1,1,1,1,0,1]`` are 4 segments, 48 layers 17)."""
 from __future__ import annotations
 
 import dataclasses
-import zlib
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import moe_decode
 from ray_tpu.ops.moe import Router
 
 FULL, WINDOW = "full", "window"
@@ -191,51 +191,13 @@ def _shapes(c: MimoConfig) -> Dict[str, Any]:
 
 
 def init_params(config: MimoConfig, key: jax.Array) -> Dict[str, Any]:
-    """Seeded random weights, made LEAF BY LEAF in ``config.dtype``, a
-    stacked leaf one layer at a time (``deepseek.init_params``): a float32
-    tree of the served cut would be 14 GB and never exists."""
-    dtype = jnp.dtype(config.dtype)
-
-    def leaf(path, spec):
-        shape, fan_in = spec
-        if fan_in is None:
-            return jnp.ones(shape, jnp.float32)
-        k = jax.random.fold_in(key, zlib.crc32(path.encode()) % (2 ** 31))
-        if fan_in in ("sink", "bias"):
-            return jax.random.normal(k, shape, jnp.float32) * (
-                1.0 if fan_in == "sink" else 0.1)
-        scale = float(fan_in) ** -0.5
-
-        def one(k, shape):
-            return (jax.random.normal(k, shape, jnp.float32)
-                    * scale).astype(dtype)
-
-        if len(shape) < 3:
-            return jax.jit(one, static_argnums=1)(k, shape)
-
-        def fill(k):
-            return jax.lax.fori_loop(
-                0, shape[0],
-                lambda i, buf: buf.at[i].set(
-                    one(jax.random.fold_in(k, i), shape[1:])),
-                jnp.zeros(shape, dtype))
-
-        return jax.jit(fill)(k)
-
-    def walk(tree, prefix):
-        if isinstance(tree, list):
-            return [walk(sub, f"{prefix}{i}/") for i, sub in enumerate(tree)]
-        return {name: (walk(sub, prefix + name + "/")
-                       if isinstance(sub, (dict, list))
-                       else leaf(prefix + name, sub))
-                for name, sub in tree.items()}
-
-    return walk(_shapes(config), "")
+    """Seeded random weights, made leaf by leaf in ``config.dtype``
+    (``moe_decode.init_leaves``): a float32 tree of the served cut would be
+    14 GB and never exists. The sink logits draw float32 ``N(0, 1)``, the
+    selection bias ``N(0, 0.01)``."""
+    return moe_decode.init_leaves(_shapes(config), key, config.dtype,
+                                  {"sink": 1.0, "bias": 0.1})
 
 
 def param_count(config: MimoConfig) -> int:
-    import math
-
-    return sum(math.prod(spec[0]) for spec in jax.tree.leaves(
-        _shapes(config), is_leaf=lambda x: isinstance(x, tuple)
-        and len(x) == 2 and isinstance(x[0], tuple)))
+    return moe_decode.count_leaves(_shapes(config))

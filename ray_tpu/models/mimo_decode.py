@@ -179,40 +179,12 @@ def _ffn(layer, x, c: MimoConfig, seg: Segment, keep):
 
 def _scan_segments(body, x, params: Dict[str, Any], pool: Pool,
                    c: MimoConfig):
-    """One ``scan`` a segment with the pool of the segment's kind in its
-    CARRY, flat (``llama_decode._scan_layers``): ``body(seg, x, k_pool,
-    v_pool, layer, base) -> (x, k_pool, v_pool, stats)``, page ``p`` of the
-    layer at row ``base + p``. Returns ``(x, pool, stats)``, the expert
-    layers' ``STEP_STATS`` summed."""
-    shapes = {name: leaf.shape for name, leaf in pool.items()}
-    flat = {name: leaf.reshape((leaf.shape[0] * leaf.shape[1],)
-                               + leaf.shape[2:])
-            for name, leaf in pool.items()}
-    stats = jnp.zeros((len(STEP_STATS),), jnp.float32)
-    for seg, leaves in zip(c.segments(), params["segments"]):
-        k_name, v_name = f"{seg.kind}_k", f"{seg.kind}_v"
-        bases = (seg.first + jnp.arange(seg.layers, dtype=jnp.int32)) \
-            * shapes[k_name][1]
-        # The experts do not ride the scan's ``xs``: a layer of them
-        # sliced out for the grouped matmul would be a copy
-        # (``ops.moe.held_experts_ffn``); the stack goes in whole.
-        rest = {k: v for k, v in leaves.items() if k != "experts"}
-
-        def step(carry, inp, seg=seg, leaves=leaves):
-            x, k_pool, v_pool, stats = carry
-            layer, base, at = inp
-            if seg.moe:
-                layer = {**layer, "experts": leaves["experts"],
-                         "expert_layer": at}
-            x, k_pool, v_pool, more = body(seg, x, k_pool, v_pool, layer,
-                                           base)
-            return (x, k_pool, v_pool, stats + more), None
-
-        (x, flat[k_name], flat[v_name], stats), _ = jax.lax.scan(
-            step, (x, flat[k_name], flat[v_name], stats),
-            (rest, bases, jnp.arange(seg.layers, dtype=jnp.int32)))
-    return x, {name: flat[name].reshape(shapes[name])
-               for name in pool}, stats
+    """``moe_decode.scan_segments`` over this model's segments: one
+    ``scan`` a segment with the pool of the segment's kind in its carry.
+    Returns ``(x, pool, stats)``, the expert layers' ``STEP_STATS``
+    summed."""
+    return moe_decode.scan_segments(body, x, c.segments(),
+                                    params["segments"], pool)
 
 
 def _head(params, x, c: MimoConfig):
@@ -239,11 +211,7 @@ def live_page_view(block_tables: Dict[str, Any], counts: Dict[str, Any],
     a slot; a slot that does not step (0 held) and the entries past a
     slot's pages are the scratch page at index -1, which no position
     matches."""
-    full = moe_decode.live_page_view(block_tables[FULL], counts[FULL],
-                                     rows[FULL])
-    window = moe_decode.window_page_view(
-        block_tables[WINDOW], *counts[WINDOW], rows[WINDOW])
-    return {FULL: full, WINDOW: window}
+    return moe_decode.kinds_page_view(block_tables, counts, rows)
 
 
 # ------------------------------------------------------------------ prefill

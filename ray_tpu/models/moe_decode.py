@@ -1,12 +1,17 @@
 """What the served models behind the engine's seam share (``deepseek_decode``,
-``mimo_decode``, ``phi4flash_decode``), so that none uses another as a
-library: the cast of a replica's weights, the decode step's view of a
-slot's pages in whole groups and of a window kind's pages, and the counters
-an expert layer adds to a step. Nothing here knows a model's config."""
+``mimo_decode``, ``phi4flash_decode``, ``cohere2_moe_decode``; ``mimo`` and
+``cohere2_moe`` for their weights), so that none uses another as a
+library: a replica's seeded weights made leaf by leaf and their cast, the
+decode step's view of a slot's pages in whole groups and of a window
+kind's pages, the layer loop over segments of one kind with the kind's
+pool in its carry, and the counters an expert layer adds to a step.
+Nothing here knows a model's config."""
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+import math
+import zlib
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +21,65 @@ VIEW_GROUP = 16   # pages of ONE slot that a decode step scores together
 # What a ``paged_decode_step`` counts beside its logits, summed over the
 # expert layers (the step log's ``launch`` slice carries them).
 MOE_STEP_STATS = ("moe_pairs", "moe_experts_hit", "moe_max_load")
+
+
+def is_spec(x) -> bool:
+    """A leaf of a model's ``_shapes`` tree: ``(shape, how it is drawn)``."""
+    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
+
+
+def count_leaves(shapes) -> int:
+    """The numbers in a tree of ``is_spec`` leaves."""
+    return sum(math.prod(spec[0])
+               for spec in jax.tree.leaves(shapes, is_leaf=is_spec))
+
+
+def init_leaves(shapes, key: jax.Array, dtype,
+                float32_std: Optional[Dict[str, float]] = None):
+    """Seeded random weights for a tree (dicts and lists) of ``(shape,
+    fan_in)`` leaves, made LEAF BY LEAF in ``dtype``, a stacked leaf
+    (three axes or more) one layer at a time, so the float32 transient is
+    one layer of one leaf (``deepseek.init_params``). ``fan_in`` a number
+    draws ``N(0, 1 / fan_in)``; ``None`` is a norm scale (float32 ones); a
+    name of ``float32_std`` draws float32 ``N(0, std^2)``. A leaf's key is
+    folded from its path, so a tree's other leaves do not move it."""
+    dtype = jnp.dtype(dtype)
+
+    def leaf(path, spec):
+        shape, fan_in = spec
+        if fan_in is None:
+            return jnp.ones(shape, jnp.float32)
+        k = jax.random.fold_in(key, zlib.crc32(path.encode()) % (2 ** 31))
+        if isinstance(fan_in, str):
+            return jax.random.normal(k, shape, jnp.float32) \
+                * float32_std[fan_in]
+        scale = float(fan_in) ** -0.5
+
+        def one(k, shape):
+            return (jax.random.normal(k, shape, jnp.float32)
+                    * scale).astype(dtype)
+
+        if len(shape) < 3:
+            return jax.jit(one, static_argnums=1)(k, shape)
+
+        def fill(k):
+            return jax.lax.fori_loop(
+                0, shape[0],
+                lambda i, buf: buf.at[i].set(
+                    one(jax.random.fold_in(k, i), shape[1:])),
+                jnp.zeros(shape, dtype))
+
+        return jax.jit(fill)(k)
+
+    def walk(tree, prefix):
+        if isinstance(tree, list):
+            return [walk(sub, f"{prefix}{i}/") for i, sub in enumerate(tree)]
+        return {name: (walk(sub, prefix + name + "/")
+                       if isinstance(sub, (dict, list))
+                       else leaf(prefix + name, sub))
+                for name, sub in tree.items()}
+
+    return walk(shapes, "")
 
 
 def cast_weights(params: Dict[str, Any], dtype,
@@ -101,3 +165,63 @@ def window_page_view(table, first, held, rows: int):
         table, np.minimum(index, table.shape[1] - 1), axis=1), 0)
     window[1] = np.where(real, index, -1)
     return window
+
+
+def kinds_page_view(block_tables: Dict[str, Any], counts: Dict[str, Any],
+                    rows: Dict[str, int], full: str = "full",
+                    window: str = "window") -> Dict[str, np.ndarray]:
+    """The decode step's view of a pool of two kinds, built on the host.
+
+    ``full``: ``live_page_view`` of the full kind's tables and counts on
+    ``rows[full]`` rows (the engine's ladder): a slot's pages in whole
+    groups of ``VIEW_GROUP``.
+
+    ``window``: ``window_page_view``, ``(2, slots, rows[window])`` int32,
+    ``counts[window]`` being ``(first held index, pages held)`` a slot."""
+    return {full: live_page_view(block_tables[full], counts[full],
+                                 rows[full]),
+            window: window_page_view(block_tables[window], *counts[window],
+                                     rows[window])}
+
+
+def scan_segments(body: Callable, x, segments: Sequence[Any],
+                  leaves: Sequence[Dict[str, Any]],
+                  pool: Dict[str, jax.Array]):
+    """One ``scan`` a segment (consecutive layers of one kind of page,
+    stacked) with the pool of the segment's kind in its CARRY, flat
+    (``llama_decode._scan_layers``): ``body(seg, x, k_pool, v_pool, layer,
+    base) -> (x, k_pool, v_pool, stats)``, page ``p`` of the layer at row
+    ``base + p`` of the kind's leaves ``<kind>_k`` / ``<kind>_v``. A
+    segment is ``(kind, layers, first)`` by name, ``first`` its first
+    layer's index among the layers of its kind; one without experts says
+    ``moe`` False. Returns ``(x, pool, stats)``, the expert layers'
+    ``MOE_STEP_STATS`` summed."""
+    shapes = {name: leaf.shape for name, leaf in pool.items()}
+    flat = {name: leaf.reshape((leaf.shape[0] * leaf.shape[1],)
+                               + leaf.shape[2:])
+            for name, leaf in pool.items()}
+    stats = jnp.zeros((len(MOE_STEP_STATS),), jnp.float32)
+    for seg, stacked in zip(segments, leaves):
+        k_name, v_name = f"{seg.kind}_k", f"{seg.kind}_v"
+        bases = (seg.first + jnp.arange(seg.layers, dtype=jnp.int32)) \
+            * shapes[k_name][1]
+        # The experts do not ride the scan's ``xs``: a layer of them
+        # sliced out for the grouped matmul would be a copy
+        # (``ops.moe.held_experts_ffn``); the stack goes in whole.
+        rest = {k: v for k, v in stacked.items() if k != "experts"}
+
+        def step(carry, inp, seg=seg, stacked=stacked):
+            x, k_pool, v_pool, stats = carry
+            layer, base, at = inp
+            if getattr(seg, "moe", True):
+                layer = {**layer, "experts": stacked["experts"],
+                         "expert_layer": at}
+            x, k_pool, v_pool, more = body(seg, x, k_pool, v_pool, layer,
+                                           base)
+            return (x, k_pool, v_pool, stats + more), None
+
+        (x, flat[k_name], flat[v_name], stats), _ = jax.lax.scan(
+            step, (x, flat[k_name], flat[v_name], stats),
+            (rest, bases, jnp.arange(seg.layers, dtype=jnp.int32)))
+    return x, {name: flat[name].reshape(shapes[name])
+               for name in pool}, stats

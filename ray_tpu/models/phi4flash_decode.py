@@ -323,10 +323,7 @@ def live_page_view(block_tables: Dict[str, Any], counts: Dict[str, Any],
     (``mimo_decode.live_page_view``): ``"full"`` a slot's pages in whole
     groups of ``VIEW_GROUP`` on ``rows["full"]`` rows, ``"window"`` the
     ``rows["window"]`` last window pages of each stepping slot."""
-    return {FULL: moe_decode.live_page_view(
-                block_tables[FULL], counts[FULL], rows[FULL]),
-            WINDOW: moe_decode.window_page_view(
-                block_tables[WINDOW], *counts[WINDOW], rows[WINDOW])}
+    return moe_decode.kinds_page_view(block_tables, counts, rows)
 
 
 # ------------------------------------------------------------------ prefill

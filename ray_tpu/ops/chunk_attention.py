@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,8 +40,25 @@ NO_SINK = -1e30
 _LANE = 128
 BLOCK_Q = 512
 BLOCK_K = 1024
-# The window variant's tiles: a tile of 256 queries has 383 live keys.
+# The window variant's smallest tiles: under a window of 128 a tile of 256
+# queries has 383 live keys.
 WINDOW_BLOCK = 256
+
+
+def tiles(queries: int, window: Optional[int]) -> Tuple[int, int]:
+    """``(block_q, block_k)`` for ``queries`` queries a row. The full
+    variant takes the largest tiles; the window variant takes key tiles of
+    about a quarter of the window, within ``WINDOW_BLOCK`` .. ``BLOCK_K``:
+    a window of 128 or 512 keeps tiles of 256 x 256 (little of a larger
+    tile would be live), a window of 4,096 the full variant's 512 x 1,024
+    (6 steps a query tile, three quarters of them live; at 256 x 256 it
+    would be 18 steps of a sixth of the work each, under the grid's cost a
+    step)."""
+    if window is None:
+        return math.gcd(queries, BLOCK_Q), BLOCK_K
+    quarter = 1 << max(window // 4, 1).bit_length() - 1
+    block_k = min(BLOCK_K, max(WINDOW_BLOCK, quarter))
+    return math.gcd(queries, min(BLOCK_Q, block_k)), block_k
 
 
 def _first_tile(q0, k0, window, block_k):
@@ -139,11 +156,7 @@ def chunk_attention(q, k, v, q_offsets, k_offsets, scale: float,
     B, H, S, _ = q.shape
     KV, dv = k.shape[1], v.shape[-1]
     groups = H // KV
-    if window is None:
-        block_q, block_k = math.gcd(S, BLOCK_Q), BLOCK_K
-    else:
-        block_q = math.gcd(S, WINDOW_BLOCK)
-        block_k = WINDOW_BLOCK
+    block_q, block_k = tiles(S, window)
     block_k = min(block_k, -(-k.shape[2] // _LANE) * _LANE)
     q, k = _pad_to(q, 3, _LANE), _pad_to(_pad_to(k, 3, _LANE), 2, block_k)
     v = _pad_to(v, 2, block_k)

@@ -2678,3 +2678,17 @@ class Phi4FlashDecodeDeployment(LlamaDecodeDeployment):
         from ray_tpu.models import phi4flash, phi4flash_decode
 
         return phi4flash, phi4flash_decode
+
+
+class Cohere2MoeDecodeDeployment(LlamaDecodeDeployment):
+    """The same deployment over Command A+ (``models/cohere2_moe.py``):
+    parallel blocks, full and window(4,096) layers over two kinds of page
+    of one size, held experts behind a sigmoid router beside averaged
+    shared ones. The model has no ``shard_decode_state``, so a mesh is
+    refused by the engine; its window kind turns the prefix index off."""
+
+    @staticmethod
+    def model_modules():
+        from ray_tpu.models import cohere2_moe, cohere2_moe_decode
+
+        return cohere2_moe, cohere2_moe_decode

@@ -238,6 +238,93 @@ def test_a_handoff_carries_both_kinds():
         b.submit(prompt, max_new_tokens=2, adopt=bad)
 
 
+# ------------------------------- a window kind as large as the full kind
+
+
+def _wide_engine(**kw):
+    """Command A+'s debug preset with a window of 256 tokens over pages
+    of 4: 65 window pages a slot, as the served model keeps at a window
+    of 4,096 over pages of 64."""
+    import dataclasses
+
+    import jax
+
+    from ray_tpu.models import cohere2_moe, cohere2_moe_decode
+    from ray_tpu.serve.decode import DecodeEngine
+
+    cfg = dataclasses.replace(cohere2_moe.PRESETS["debug"], window=256)
+    params = cohere2_moe.init_params(cfg, jax.random.key(0))
+    args = dict(slots=3, capacity=512, page_tokens=4,
+                prefill_chunk_tokens=64, model=cohere2_moe_decode,
+                step_timeline=4096, metrics_enabled=False,
+                trace_spans=False)
+    args.update(kw)
+    return DecodeEngine(params, cfg, **args), cfg
+
+
+def test_sixty_five_window_pages_a_slot_are_kept_trimmed_and_viewed():
+    """The window kind at the size of the full kind: a slot keeps 65
+    pages between steps, a chunk's table has the chunk's and the window's
+    columns, the decode's view lists 65 pages a slot, and the streams are
+    the reference's."""
+    from benchmarks.reference import cohere2_moe_ref
+
+    eng, cfg = _wide_engine()
+    w = eng._windows["window"]
+    assert w.keep == 65
+    # What the slots keep and four chunks in flight, written through.
+    assert w.alloc.pages == 3 * 65 + 4 * 16
+    tables = eng._prefill_tables([0], [0], eng._block_tables[:1, :16], 64)
+    assert tables["window"].shape == (1, (64 + 256) // 4 + 1)
+    most = [0]
+
+    def each():
+        for slot in range(eng.slots):
+            assert int(w.held[slot]) <= w.keep, (slot, w.held)
+        most[0] = max(most[0], int(max(w.held)))
+
+    prompts = _prompts(cfg, [300, 30, 410])
+    reqs = [eng.submit(p, max_new_tokens=m)
+            for p, m in zip(prompts, [12, 20, 9])]
+    _run(eng, reqs, each=each)
+    assert most[0] == 65
+    rows = eng.steplog.dump()["rows"]
+    launches = [s for r in rows for s in r["slices"]
+                if s["name"] == "launch" and s.get("program") == "decode"]
+    assert launches and all(s["window_pages"] == 3 * 65 for s in launches)
+    # A context under the window counts whole, one over it the window.
+    assert max(s["window_tokens"] for s in launches) <= 2 * 256 + 50
+    assert any(r.get("pages_window", 0) > 100 for r in rows)
+    margins = cohere2_moe_ref.served_token_margins(
+        eng.params, cfg, prompts, [r.output for r in reqs])
+    assert len(margins) == 41 and max(margins) < 2e-4
+    assert eng._pages.in_use == 0 and w.alloc.in_use == 0
+    eng.shutdown()
+
+
+def test_a_handoff_carries_sixty_five_window_pages():
+    a, cfg = _wide_engine()
+    b, _ = _wide_engine()
+    c, _ = _wide_engine()
+    prompt = _prompts(cfg, [330], seed=5)[0]
+    pre = a.submit(prompt, max_new_tokens=1, prefill_only=True)
+    _run(a, [pre])
+    h = pre.handoff
+    # Keys 75..329 are the next query's window: pages 18..82.
+    assert a._windows["window"].span(330) == (18, 65)
+    assert h["full_k"].shape[1] == 83 and h["window_k"].shape[1] == 65
+    adopt = {k: v for k, v in h.items() if k != "nbytes"}
+    got = b.submit(prompt, max_new_tokens=6, adopt=adopt)
+    want = c.submit(prompt, max_new_tokens=6)
+    _run(b, [got])
+    _run(c, [want])
+    assert got.output == want.output and len(got.output) == 6
+    for eng in (a, b, c):
+        assert eng._pages.in_use == 0
+        assert eng._windows["window"].alloc.in_use == 0
+        eng.shutdown()
+
+
 # ------------------------------------- the models of one kind, unchanged
 
 # sha256 (first 16 hex) of the lowered text of the engine's programs at
@@ -245,7 +332,9 @@ def test_a_handoff_carries_both_kinds():
 # kinds, the router's score and its bias left them letter for letter; and
 # mimo's at the parent of PR 45 (6eada8f), which slot state, the prefill
 # rows' slots and the wave's cap left so; and phi4flash's at the parent of
-# PR 48 (3fd825d), which the engine's two other ways to step left so.
+# PR 48 (3fd825d), which the engine's two other ways to step left so (and
+# PR 49, which moved their layer loop and view into ``moe_decode.py`` and
+# gave the chunk kernel tiles from its shape); cohere2's as PR 49 made it.
 LOWERED_AT_PARENT = {
     "llama.decode": "5ee1c9392ee387ff",
     "llama.paged_prefill": "e3bd72ad3a1c0d98",
@@ -259,17 +348,21 @@ LOWERED_AT_PARENT = {
     "phi4flash.decode": "dd5296a9fa92db6c",
     "phi4flash.paged_prefill": "fd2c27caaad9f7db",
     "phi4flash.paged_suffix": "f21c37416e6c07e7",
+    "cohere2.decode": "b0399019312b96dd",
+    "cohere2.paged_prefill": "8d740fda8f8c74e6",
+    "cohere2.paged_suffix": "125758967be83996",
 }
 
 
-@pytest.mark.parametrize("name", ["llama", "deepseek", "mimo", "phi4flash"])
+@pytest.mark.parametrize("name", ["llama", "deepseek", "mimo", "phi4flash",
+                                  "cohere2"])
 def test_one_kind_models_lower_to_the_text_they_had(name):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import (deepseek, deepseek_decode, llama,
-                                llama_decode, mimo, mimo_decode, phi4flash,
-                                phi4flash_decode)
+    from ray_tpu.models import (cohere2_moe, cohere2_moe_decode, deepseek,
+                                deepseek_decode, llama, llama_decode, mimo,
+                                mimo_decode, phi4flash, phi4flash_decode)
     from ray_tpu.serve.decode import DecodeEngine
 
     mod, dec, cfg = {
@@ -280,6 +373,8 @@ def test_one_kind_models_lower_to_the_text_they_had(name):
         "mimo": (mimo, mimo_decode, mimo.PRESETS["debug"]),
         "phi4flash": (phi4flash, phi4flash_decode,
                       phi4flash.PRESETS["debug"]),
+        "cohere2": (cohere2_moe, cohere2_moe_decode,
+                    cohere2_moe.PRESETS["debug"]),
     }[name]
     eng = DecodeEngine(mod.init_params(cfg, jax.random.key(0)), cfg,
                        slots=4, capacity=128, page_tokens=16,

@@ -404,3 +404,126 @@ def test_phi4flash_programs_compile_at_published_widths_inside_the_chip(
         # slot's nine window pages, and no room for one.
         assert not re.search(r"bf16\[\d+,(1024|576),1280\]", text)
         assert mem.temp_size_in_bytes < 0.3e9
+
+
+def test_chunk_attention_tiles_follow_the_window():
+    """Tiles from the shape: mimo's window of 128 and phi-4-mini-flash's
+    of 512 keep the 256 x 256 they had, Command A+'s 4,096 takes the full
+    variant's 512 x 1,024 (6 steps a query tile where 256 x 256 has 18)."""
+    from ray_tpu.ops.chunk_attention import tiles
+
+    assert tiles(2048, None) == (512, 1024) and tiles(16, None) == (16, 1024)
+    assert tiles(2048, 128) == tiles(2048, 512) == (256, 256)
+    assert tiles(16, 128) == (16, 256) and tiles(32, 12) == (32, 256)
+    assert tiles(2048, 4096) == (512, 1024) and tiles(16, 4096) == (16, 1024)
+
+
+@pytest.mark.parametrize("queries,keys,window", [
+    (2048, 32768, None),    # the full layer's chunk at 30k of context
+    (2048, 6208, 4096),     # a window layer's: the chunk + its window
+    (16, 4224, 4096),       # the smallest suffix bucket
+])
+def test_chunk_attention_kernel_compiles_at_command_a_widths(
+        one_chip, no_compile_cache, monkeypatch, queries, keys, window):
+    """``ops/chunk_attention.py`` at Command A+'s published widths: 128
+    query heads of 128 over 8 key heads, keys as wide as values, no sink,
+    both static variants, the window variant at the tiles its window of
+    4,096 chooses."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import chunk_attention
+
+    monkeypatch.setattr(chunk_attention, "_interpret", lambda: False)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, qo, ko: chunk_attention.chunk_attention(
+            q, k, v, qo, ko, 128 ** -0.5, window)
+    ).lower(shape(1, 128, queries, 128), shape(1, 8, keys, 128),
+            shape(1, 8, keys, 128), shape(1, dtype=jnp.int32),
+            shape(1, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    name = "chunk_attn_full" if window is None else "chunk_attn_window"
+    assert "tpu_custom_call" in text and name in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+
+
+@pytest.mark.parametrize("program", ["decode:8192", "decode:12288",
+                                     "chunk:1x2048x512", "chunk:1x16x128"])
+def test_command_a_programs_compile_at_published_widths_inside_the_chip(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """``models/cohere2_moe_decode.py`` at Command A+'s published widths
+    and the cell's layout (24 slots, 9,216 full and 1,752 window pages of
+    64, shapes only): the decode step at the cell's rung and at the top
+    one, both kinds' pages read where they lie by ``paged_decode_attn``
+    (128 query rows over 1,024 flat lanes, a slot's 65 window pages in
+    lists of 16: no list of gathered pages and no room for one), and a
+    2,048-token chunk at 32k of context over the 97 window columns.
+    Arguments (13.26 GB: 9.47 GB of weights, 2.42 + 1.38 GB of pages) and
+    temporaries fit the chip's 15.75 GB."""
+    import dataclasses
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import cohere2_moe, moe_decode
+    from ray_tpu.models import cohere2_moe_decode as md
+    from ray_tpu.ops import chunk_attention, paged_decode_attention
+
+    monkeypatch.setattr(chunk_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(paged_decode_attention, "_interpret", lambda: False)
+    cfg = dataclasses.replace(cohere2_moe.Cohere2MoeConfig(), n_layers=4,
+                              experts_held=(0, 16), vocab_size=32768)
+    slots, T = 24, 64
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, spec: shape(
+            spec[0], jnp.float32 if str(path[-1].key)
+            in cohere2_moe.FLOAT32_LEAVES else jnp.bfloat16),
+        cohere2_moe._shapes(cfg), is_leaf=moe_decode.is_spec)
+    pool = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype), jax.eval_shape(
+            lambda: md.init_page_pool(
+                cfg, {"full": 9216, "window": 24 * 65 + 6 * 32}, T)))
+    i32 = jnp.int32
+    kind, dims = program.split(":")
+    if kind == "decode":
+        view = {"full": shape((3, int(dims)), i32),
+                "window": shape((2, slots, 65), i32)}
+        compiled = jax.jit(
+            lambda p, pool, view, lens, toks: md.paged_decode_step(
+                p, pool, view, lens, toks, cfg), donate_argnums=(1,)
+        ).lower(params, pool, view, shape((slots,), i32),
+                shape((slots,), i32)).compile()
+    else:
+        rows, bucket, width = (int(x) for x in dims.split("x"))
+        tables = {"full": shape((rows, width), i32),
+                  "window": shape((rows, -(-(bucket + 4096) // T) + 1), i32),
+                  "window_first": shape((rows,), i32)}
+        compiled = jax.jit(
+            lambda p, toks, pool, bt, plens, lens: md.paged_prefill_suffix(
+                p, toks, pool, bt, cfg, plens, lens), donate_argnums=(2,)
+        ).lower(params, shape((rows, bucket), i32), pool, tables,
+                shape((rows,), i32), shape((rows,), i32)).compile()
+        text = compiled.as_text()
+        assert "chunk_attn_window" in text and "chunk_attn_full" in text
+    mem = compiled.memory_analysis()
+    assert 13.2e9 < mem.argument_size_in_bytes < 13.3e9
+    # The pool is written where it lies: the donated buffers are aliased.
+    assert mem.alias_size_in_bytes > 3.7e9
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 14.2e9, (program, peak)
+    if kind == "decode":
+        text = compiled.as_text()
+        assert text.count("paged_decode_attn") >= 2    # window, full
+        # No slot's 65 (or 80) window pages and no view's groups gathered.
+        assert not re.search(r"bf16\[\d+,(1024|4160|5120),1024\]", text)
+        assert mem.temp_size_in_bytes < 0.3e9
