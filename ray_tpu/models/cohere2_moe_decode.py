@@ -163,7 +163,8 @@ def _ffn(layer, normed, c: Cohere2MoeConfig, keep):
     with jax.named_scope("moe_experts"):
         routed, sizes = moe.held_experts_ffn(
             h.reshape(-1, shape[-1]), idx, weights, layer["experts"],
-            c.held, keep.reshape(-1), layer=layer["expert_layer"])
+            c.held, keep.reshape(-1), layer=layer["expert_layer"],
+            router=c.router())
     with jax.named_scope("moe_shared"):
         # The leaf is the shared experts side by side, so one SwiGLU is
         # their sum; the quarter makes it their average, in float32.

@@ -163,7 +163,8 @@ def _moe_ffn(layer, x, c: DeepseekConfig, keep):
     with jax.named_scope("moe_experts"):
         routed, sizes = moe.held_experts_ffn(
             flat, idx, weights, layer["experts"], c.held,
-            keep.reshape(-1), layer=layer["expert_layer"])
+            keep.reshape(-1), layer=layer["expert_layer"],
+            router=c.router())
     with jax.named_scope("moe_shared"):
         shared = _swiglu(layer["shared"], flat.reshape(shape))
     return x + routed.reshape(shape) + shared, sizes

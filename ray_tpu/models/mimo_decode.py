@@ -173,7 +173,8 @@ def _ffn(layer, x, c: MimoConfig, seg: Segment, keep):
     with jax.named_scope("moe_experts"):
         routed, sizes = moe.held_experts_ffn(
             flat, idx, weights, layer["experts"], c.held,
-            keep.reshape(-1), layer=layer["expert_layer"])
+            keep.reshape(-1), layer=layer["expert_layer"],
+            router=c.router())
     return x + routed.reshape(shape), moe_decode.moe_step_stats(sizes)
 
 

@@ -18,11 +18,13 @@ standard Switch behavior. Gates are renormalized over the selected top-k.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.parallel.sharding import constrain
 
 
@@ -204,11 +206,45 @@ def route(logits: jax.Array, router: Router,
     return idx.astype(jnp.int32), weights * r.scale
 
 
+# Rows beyond what a balanced router gives a held expert, and tiles beyond
+# one a held expert, before a second pass: a quarter more.
+_SLACK = 1.25
+_ROW_TILES = (128, 256, 512)
+# A program that takes the held pairs' way carries three more kernels a
+# scan segment: ~1 s of a warm set-up and ~5 s of a cold one a program (PR
+# 50, command-a-plus: 12 such programs read +11 and +57 s), and an engine
+# warms one for every chunk bucket and block-table width. So only
+# chunk-sized calls take it: a 2,048-token chunk has 12,288-16,384 pairs in
+# the three served models, a prompt's last piece at most this many.
+_SMALL_CALL_PAIRS = 8192
+
+
+def held_rows(pairs: int, held: int, width: int
+              ) -> Optional[Tuple[int, int]]:
+    """``(cap, tile)``: how many rows a pass of the held pairs takes and the
+    row tile of its grouped matmuls, for a call of ``pairs`` (token, expert)
+    pairs routed over ``width`` experts of which ``held`` are here. The tile
+    is the power of two that holds a balanced expert's rows and ``_SLACK``
+    more (160 -> 256 in command-a-plus, 96 and 80 -> 128 in deepseek-v2 and
+    mimo), each expert's rows start on a tile of their own, and a pass has a
+    tile a held expert and ``_SLACK`` more. ``None`` where that is no fewer
+    rows than the call has, or the call is small (``_SMALL_CALL_PAIRS``): a
+    decode step's 192-256 pairs ride on the read of the held experts'
+    weights, and keep every pair. A function of shapes alone."""
+    if pairs <= _SMALL_CALL_PAIRS:
+        return None
+    want = pairs * _SLACK / width
+    tile = next((m for m in _ROW_TILES if m >= want), _ROW_TILES[-1])
+    cap = math.ceil(held * _SLACK) * math.ceil(want / tile) * tile
+    return (cap, tile) if cap < pairs else None
+
+
 def held_experts_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
                      experts: Dict[str, jax.Array],
                      held: Tuple[int, int],
                      keep: Optional[jax.Array] = None,
-                     layer: Optional[jax.Array] = None
+                     layer: Optional[jax.Array] = None,
+                     router: Optional[Router] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """The part of a routed SwiGLU layer that the held experts give.
 
@@ -220,39 +256,76 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
     HELD experts of weight x expert(x), and how many pairs each held
     expert computed.
 
-    The (token, expert) pairs are sorted by expert, the pairs of absent
-    experts last, and the three matmuls are ragged over the groups
-    (``jax.lax.ragged_dot``): every pair is computed whatever the load
-    looks like. The rows past the last group (the absent experts' pairs)
-    belong to no group, and what the chip's kernel leaves there is not
-    defined: they are SELECTED away below, never multiplied by a zero
-    weight (on a TPU v5e one run in eleven met a NaN there, PR 36).
-
     The experts' leaves may be stacked over layers, ``(L, H, ...)``, with
     ``layer`` (a traced index) naming the one to use: the matmuls then run
-    ragged over all ``L * H`` groups, the other layers' groups empty. A
-    layer loop can so hand the stack in whole; a layer sliced out of it to
-    feed the matmul's kernel is a copy (1.9 GB a layer at DeepSeek-V2's
-    served size)."""
-    t, k = idx.shape
+    over all ``L * H`` groups, the other layers' groups empty. A layer loop
+    can so hand the stack in whole; a layer sliced out of it to feed the
+    matmul's kernel is a copy (1.9 GB a layer at DeepSeek-V2's served
+    size).
+
+    ``router`` is the ``Router`` that made ``idx``: its width says what
+    share of the ``T * k`` pairs a chip that holds ``H`` experts owns. The
+    work of a call follows that share (``held_rows``): a chunk's thousands
+    of pairs are cut to the held ones before a row is gathered, multiplied
+    or brought back (``_held_pairs``); a decode step's few pairs, and any
+    call without a ``router``, keep every pair (``_all_pairs``). Either
+    way every held pair is computed, whatever the load looks like."""
+    plan = None if router is None else held_rows(
+        idx.shape[0] * idx.shape[1], held[1], router.experts)
+    if plan is None:
+        return _all_pairs(x, idx, weights, experts, held, keep, layer)
+    with jax.named_scope(f"held_rows_{plan[0]}"):
+        return _held_pairs(x, idx, weights, experts, held, keep, layer,
+                           *plan)
+
+
+def _held_groups(idx, held, keep):
+    """``(mine (T, k) bool, group (T * k,) int32)``: which pairs are a held
+    expert's and a kept token's, and each pair's held expert, ``held[1]``
+    for every other pair."""
     first, count = held
     local = idx - first
     mine = (local >= 0) & (local < count)
     if keep is not None:
         mine &= keep[:, None]
-    group = jnp.where(mine, local, count).reshape(-1)          # (T * k,)
+    return mine, jnp.where(mine, local, count).reshape(-1)
+
+
+def _as_groups(experts, count):
+    """A stack's ``(L, H, ...)`` leaves seen as ``L * H`` groups."""
+    n_layers = experts["w_gate"].shape[0]
+    return {name: w.reshape((n_layers * count,) + w.shape[2:])
+            for name, w in experts.items()}
+
+
+def _stacked(experts, sizes, layer, count):
+    """The experts' leaves and a call's group sizes as ``ragged_dot`` takes
+    them: as they are, or a stack's leaves as groups of which only
+    ``layer``'s have rows."""
+    if layer is None:
+        return experts, sizes
+    stack = _as_groups(experts, count)
+    return stack, jax.lax.dynamic_update_slice(
+        jnp.zeros((stack["w_gate"].shape[0],), jnp.int32), sizes,
+        (layer * count,))
+
+
+def _all_pairs(x, idx, weights, experts, held, keep, layer):
+    """Every pair of the call in one sort: the (token, expert) pairs are
+    sorted by expert, the pairs of absent experts last, and the three
+    matmuls are ragged over the groups (``jax.lax.ragged_dot``). The rows
+    past the last group (the absent experts' pairs) belong to no group, and
+    what the chip's kernel leaves there is not defined: they are SELECTED
+    away below, never multiplied by a zero weight (on a TPU v5e one run in
+    eleven met a NaN there, PR 36)."""
+    t, k = idx.shape
+    count = held[1]
+    mine, group = _held_groups(idx, held, keep)
     order = jnp.argsort(group, stable=True)
     token = order // k
     sizes = jnp.bincount(group, length=count + 1)[:count].astype(jnp.int32)
     xs = x[token]                                              # (T * k, D)
-    groups = sizes
-    if layer is not None:
-        n_layers = experts["w_gate"].shape[0]
-        experts = {name: w.reshape((n_layers * count,) + w.shape[2:])
-                   for name, w in experts.items()}
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_layers * count,), jnp.int32), sizes,
-            (layer * count,))
+    experts, groups = _stacked(experts, sizes, layer, count)
     gate = jax.lax.ragged_dot(xs, experts["w_gate"], groups)
     up = jax.lax.ragged_dot(xs, experts["w_up"], groups)
     out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, experts["w_down"],
@@ -261,4 +334,69 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
     # then a token's k pairs are added up in float32 under its weights.
     back = out[jnp.argsort(order)].reshape(t, k, -1).astype(jnp.float32)
     y = jnp.where(mine[..., None], back * weights[..., None], 0.0).sum(1)
+    return y.astype(x.dtype), sizes
+
+
+def _tiled(xs, stack, tile_group, live_tiles, tile):
+    """The three matmuls over tiles that belong to one expert each."""
+    hidden = grouped_matmul.grouped_swiglu(
+        xs, stack["w_gate"], stack["w_up"], tile_group, live_tiles, tile)
+    return grouped_matmul.grouped_matmul(
+        hidden, stack["w_down"], tile_group, live_tiles, tile)
+
+
+def _held_pairs(x, idx, weights, experts, held, keep, layer, cap, tile,
+                matmuls=_tiled):
+    """The held pairs only, ``cap`` rows a pass in tiles of ``tile``. The
+    pairs are ranked by expert, the absent experts' last; each held
+    expert's rows then start on a row tile of their own (the last one's
+    rest is padding), so that a tile's matmuls read one expert's matrices.
+    Pass ``p`` takes the tiles ``[p x cap / tile, (p + 1) x cap / tile)`` of
+    that layout: it gathers their tokens' rows, runs the gate, up and down
+    projections (``ops/grouped_matmul.py``) and adds each live row, under
+    its pair's weight, into its token's float32 sum. A balanced call is one
+    pass; when one expert takes every token the body runs again until no
+    tile is left, so nothing is dropped and one body is compiled. Padding
+    rows are computed from some pair's token and selected away, never
+    multiplied by a zero."""
+    t, k = idx.shape
+    n, count = t * k, held[1]
+    _, group = _held_groups(idx, held, keep)
+    # A comparison a held expert, summed (``bincount`` is a scatter of
+    # ones, which the chip walks).
+    sizes = jnp.sum(group[:, None] == jnp.arange(count, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes
+    # One key a pair, its expert then its place in the call: one operand
+    # to sort, and the held pairs come first, by expert.
+    ranked = jnp.sort(group * n + jnp.arange(n, dtype=jnp.int32)) % n
+    tiles_of = -(-sizes // tile)
+    tile_ends = jnp.cumsum(tiles_of)
+    tile_starts, all_tiles = tile_ends - tiles_of, tile_ends[-1]
+    stack, base = experts, 0
+    if layer is not None:
+        stack, base = _as_groups(experts, count), layer * count
+    flat_weights = weights.reshape(-1)
+    per_pass = cap // tile
+
+    def one_pass(carry):
+        p, y = carry
+        tile_id = p * per_pass + jnp.arange(per_pass, dtype=jnp.int32)
+        owner = jnp.minimum(
+            jnp.searchsorted(tile_ends, tile_id, side="right"), count - 1)
+        first_row = (tile_id - tile_starts[owner]) * tile
+        tile_rows = jnp.where(tile_id < all_tiles,
+                              jnp.clip(sizes[owner] - first_row, 0, tile), 0)
+        rank = (starts[owner] + first_row)[:, None] \
+            + jnp.arange(tile, dtype=jnp.int32)
+        pair = ranked[jnp.minimum(rank, n - 1)].reshape(-1)    # (cap,)
+        token = pair // k
+        live_tiles = jnp.clip(all_tiles - p * per_pass, 0, per_pass)
+        out = matmuls(x[token], stack, base + owner, live_tiles, tile)
+        return p + 1, y + grouped_matmul.add_rows(
+            out, flat_weights[pair], token, tile_rows, live_tiles, t, tile)
+
+    _, y = jax.lax.while_loop(
+        lambda carry: carry[0] * per_pass < all_tiles, one_pass,
+        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))
     return y.astype(x.dtype), sizes

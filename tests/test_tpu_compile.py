@@ -113,6 +113,54 @@ def test_ragged_expert_matmul_is_one_kernel_without_a_copy_of_the_stack(
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
+@pytest.mark.parametrize("held,width,k,dim,mlp,layers,plan", [
+    (16, 128, 8, 4096, 4096, 4, (5120, 256)),     # command-a-plus
+    (40, 160, 6, 5120, 1536, 4, (6400, 128)),     # deepseek-v2
+    (16, 256, 8, 4096, 2048, 6, (2560, 128)),     # mimo-v2.5
+])
+def test_a_chunks_held_pairs_compile_at_published_widths(
+        one_chip, no_compile_cache, monkeypatch, held, width, k, dim, mlp,
+        layers, plan):
+    """A chunk's call of ``held_experts_ffn`` with its router (PR 50): the
+    three kernels of ``ops/grouped_matmul.py`` pass Mosaic at the served
+    widths with their column blocks in fast memory, once each in the one
+    body the passes share, read the stack where it lies, and nothing of
+    the call is as large as the ``tokens x k`` rows were."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import grouped_matmul, moe
+
+    monkeypatch.setattr(grouped_matmul, "_interpret", lambda: False)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    tokens = 2048
+    router = moe.Router(experts=width, top_k=k)
+    experts = {"w_gate": shape(layers, held, dim, mlp),
+               "w_up": shape(layers, held, dim, mlp),
+               "w_down": shape(layers, held, mlp, dim)}
+
+    def layer(x, idx, w, experts, which):
+        return moe.held_experts_ffn(x, idx, w, experts, (0, held),
+                                    layer=which, router=router)
+
+    assert moe.held_rows(tokens * k, held, width) == plan
+    compiled = jax.jit(layer).lower(
+        shape(tokens, dim), shape(tokens, k, dtype=jnp.int32),
+        shape(tokens, k, dtype=jnp.float32), experts,
+        shape(dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text
+    for kernel in (grouped_matmul.SWIGLU, grouped_matmul.MATMUL,
+                   grouped_matmul.ADD_ROWS):
+        assert kernel in text
+    # ``tokens x k`` rows of the model's width in bfloat16 are 126-134 MB
+    # and the path before held four of them and a float32 one.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e8
+
+
 @pytest.mark.parametrize("rows,kv,queries,keys,window", [
     (1, 4, 2048, 32768, None),   # a full layer's chunk at 30k of context
     (1, 8, 2048, 2240, 128),     # a window layer's: the chunk + its window
@@ -472,10 +520,12 @@ def test_command_a_programs_compile_at_published_widths_inside_the_chip(
 
     from ray_tpu.models import cohere2_moe, moe_decode
     from ray_tpu.models import cohere2_moe_decode as md
-    from ray_tpu.ops import chunk_attention, paged_decode_attention
+    from ray_tpu.ops import (chunk_attention, grouped_matmul,
+                             paged_decode_attention)
 
     monkeypatch.setattr(chunk_attention, "_interpret", lambda: False)
     monkeypatch.setattr(paged_decode_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "_interpret", lambda: False)
     cfg = dataclasses.replace(cohere2_moe.Cohere2MoeConfig(), n_layers=4,
                               experts_held=(0, 16), vocab_size=32768)
     slots, T = 24, 64
@@ -514,6 +564,8 @@ def test_command_a_programs_compile_at_published_widths_inside_the_chip(
                 shape((rows,), i32), shape((rows,), i32)).compile()
         text = compiled.as_text()
         assert "chunk_attn_window" in text and "chunk_attn_full" in text
+        # A chunk of 2,048 is cut to the held pairs (PR 50), one of 16 is not.
+        assert ("moe_grouped_swiglu" in text) == (bucket == 2048)
     mem = compiled.memory_analysis()
     assert 13.2e9 < mem.argument_size_in_bytes < 13.3e9
     # The pool is written where it lies: the donated buffers are aliased.
