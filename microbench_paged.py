@@ -33,6 +33,22 @@ also says what share of the bf16 peak the kernel's matmuls as executed
 reach (``flat_ops_share_of_peak_pct``): near 100 the flat-lane form is
 compute-bound and a grouped-head variant would pay; well under it the
 bytes bound the kernel.
+
+``--chunk`` times the PREFILL chunk's kernel instead,
+``ray_tpu/ops/chunk_attention.py``, alone at the three served models'
+shapes (``--model cohere2|mimo|phi4flash|all``): Command A+'s 2,048
+queries of 128 heads over 8 at prefixes 0 / 8,192 / 30,720 (the full
+variant over the engine's table of 4,096 / 16,384 / 32,768 keys and over
+exactly the live keys, the window variant over its 6,208), MiMo-V2.5's 64
+heads over 4 / 8, keys 192 against values 128, a window of 128 with a
+sink, phi-4-mini-flash's pairs under a window of 512. ``share`` is there
+the live (query, key) pairs x 2 x heads x (D + Dv) over the time x 197
+TFLOP/s, the benchmark's count (``benchmarks/cohere2_moe_counts.py``).
+``--against name=path`` (repeatable) times another copy of the module,
+the parent's or an ablated one, beside the tree's in the same process;
+``--tiles QxK`` and ``--group g`` override what the tree's module would
+choose from the shape (a sweep); rows go to
+``chiprun_out/chunk_sweep.jsonl``.
 """
 
 from __future__ import annotations
@@ -363,18 +379,184 @@ def cohere2(args, emit):
                                  f"from plain float32 attention")
 
 
+PEAK = 197e12
+# (model, variant, heads, key heads, D, Dv, window, sink, queries,
+#  [(prefix, keys handed in, their first position)])
+CHUNK_CASES = (
+    ("cohere2", "full", 128, 8, 128, 128, None, False, 2048,
+     [(0, 4096, 0), (8192, 16384, 0), (30720, 32768, 0),
+      (0, 2048, 0), (8192, 10240, 0)]),        # the last two: live keys only
+    ("cohere2", "window", 128, 8, 128, 128, 4096, False, 2048,
+     [(8192, 6208, 4096), (30720, 6208, 26624)]),
+    ("mimo", "full", 64, 4, 192, 128, None, False, 2048,
+     [(0, 4096, 0), (8192, 16384, 0), (30720, 32768, 0), (8192, 10240, 0)]),
+    ("mimo", "window", 64, 8, 192, 128, 128, True, 2048,
+     [(8192, 2240, 8064)]),
+    ("phi4flash", "window", 40, 20, 64, 128, 512, False, 512,
+     [(1024, 1088, 512)]),
+)
+
+
+def _load_chunk_module(path):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "chunk_attention_" + str(abs(hash(path))), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _chunk_reference(q, k, v, prefix, k0, scale, window, sink):
+    """Plain float32 attention, a key head and 256 queries at a time on the
+    device: (1, H, S, Dv) float32."""
+    H, S = q.shape[1], q.shape[2]
+    KV, C = k.shape[1], k.shape[2]
+    g = H // KV
+    high = jax.lax.Precision.HIGHEST
+
+    @jax.jit
+    def slab(qs, kk, vv, rows, sk):
+        s = jnp.einsum("gqd,cd->gqc", qs.astype(jnp.float32),
+                       kk.astype(jnp.float32), precision=high) * scale
+        cols = k0 + jnp.arange(C)[None, :]
+        seen = rows[:, None] >= cols
+        if window is not None:
+            seen &= rows[:, None] - cols < window
+        s = jnp.where(seen[None], s, -jnp.inf)
+        m = jnp.maximum(s.max(-1), sk[:, None])
+        p = jnp.exp(s - m[..., None])
+        den = p.sum(-1) + jnp.exp(sk[:, None] - m)
+        return jnp.einsum("gqc,cd->gqd", p, vv.astype(jnp.float32),
+                          precision=high) / den[..., None]
+
+    sk = (sink if sink is not None
+          else jnp.full((H,), -jnp.inf, jnp.float32)).reshape(KV, g)
+    out = []
+    step = min(S, 256)
+    for h in range(KV):
+        out.append(jnp.concatenate([
+            slab(q[0, h * g:(h + 1) * g, a:a + step], k[0, h], v[0, h],
+                 prefix + jnp.arange(a, a + step), sk[h])
+            for a in range(0, S, step)], axis=1))
+    return jnp.concatenate(out)[None]
+
+
+def _live_pairs(S, C, prefix, k0, window):
+    rows = prefix + np.arange(S, dtype=np.int64)
+    hi = np.minimum(rows, k0 + C - 1)
+    lo = np.full_like(rows, k0) if window is None \
+        else np.maximum(rows - window + 1, k0)
+    return int(np.maximum(hi - lo + 1, 0).sum())
+
+
+def chunk(args, emit):
+    """The prefill chunk's kernel alone (module docstring)."""
+    import math
+
+    from ray_tpu.ops import chunk_attention as tree
+
+    dtype = jnp.dtype(args.dtype)
+    mods = [("tree", tree)]
+    for item in args.against:
+        name, _, path = item.rpartition("=")
+        mods.append((name or "against", _load_chunk_module(path)))
+    # "" is what the module chooses from the shape.
+    sweeps = [(t, g) for t in args.tiles.split(",")
+              for g in args.group.split(",")]
+    chosen = tree.tiles, tree.heads_a_step
+    keys = jax.random.split(jax.random.key(0), 4)
+    for (model, variant, H, KV, D, dv, window, has_sink, S,
+         places) in CHUNK_CASES:
+        if args.model not in ("all", model):
+            continue
+        scale = D ** -0.5
+        q = jax.random.normal(keys[0], (1, H, S, D), jnp.float32).astype(dtype)
+        sink = jax.random.normal(keys[3], (H,), jnp.float32) \
+            if has_sink else None
+        for prefix, C, k0 in places:
+            if args.prefix is not None and prefix != args.prefix:
+                continue
+            k = jax.random.normal(keys[1], (1, KV, C, D),
+                                  jnp.float32).astype(dtype)
+            v = jax.random.normal(keys[2], (1, KV, C, dv),
+                                  jnp.float32).astype(dtype)
+            qo = jnp.asarray([prefix], jnp.int32)
+            ko = jnp.asarray([k0], jnp.int32)
+            pairs = _live_pairs(S, C, prefix, k0, window)
+            ops = 2.0 * pairs * H * (D + dv)
+            want = None
+            if args.check:
+                want = _chunk_reference(q, k, v, prefix, k0, scale, window,
+                                        sink)
+            for name, mod in mods:
+                for t, g in (sweeps if mod is tree else [("", "")]):
+                    tree.tiles, tree.heads_a_step = chosen
+                    if t:
+                        bq, bk = (int(x) for x in t.split("x"))
+                        tree.tiles = lambda queries, window, bq=bq, bk=bk: (
+                            math.gcd(queries, bq), bk)
+                    if g:
+                        tree.heads_a_step = \
+                            lambda groups, *a, g=int(g): math.gcd(groups, g)
+                    fn = jax.jit(lambda q, k, v, qo, ko, s, mod=mod:
+                                 mod.chunk_attention(q, k, v, qo, ko, scale,
+                                                     window, s))
+                    row = {"chunk": model, "variant": variant,
+                           "module": name, "heads": H, "kv_heads": KV,
+                           "queries": S, "keys": C, "prefix": prefix,
+                           "k_offset": k0, "live_pairs": pairs,
+                           "tiles": t or "chosen", "group": g or "chosen",
+                           "dtype": dtype.name}
+                    try:
+                        ms = _time(fn, (q, k, v, qo, ko, sink), args.calls)
+                    except Exception as e:   # a sweep point Mosaic refuses
+                        emit({**row, "error": str(e).splitlines()[0][:300]})
+                        continue
+                    emit({**row, "ms": round(ms, 4),
+                          "share_of_peak_pct": round(
+                              100 * ops / (ms / 1e3) / PEAK, 2)})
+                    if want is not None:
+                        got = fn(q, k, v, qo, ko, sink).astype(jnp.float32)
+                        err = float(jnp.abs(got - want).max())
+                        top = float(jnp.abs(want).max())
+                        emit({**row, "check": name, "max_abs_err": err,
+                              "largest_value": top, "ok": err <= 2e-2 * top})
+                        if err > 2e-2 * top and not args.keep_going:
+                            raise SystemExit(
+                                f"{model} {variant} prefix {prefix}: {name} "
+                                f"is {err} from plain float32 attention")
+    tree.tiles, tree.heads_a_step = chosen
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="phi4flash",
-                    choices=("phi4flash", "cohere2"))
+                    choices=("phi4flash", "cohere2", "mimo", "all"))
+    ap.add_argument("--chunk", action="store_true",
+                    help="time ops/chunk_attention.py, the prefill's kernel")
+    ap.add_argument("--against", action="append", default=[],
+                    metavar="NAME=PATH", help="--chunk: another copy of "
+                    "chunk_attention.py to time beside the tree's")
+    ap.add_argument("--tiles", default="", help="--chunk: QxK,... to sweep")
+    ap.add_argument("--group", default="",
+                    help="--chunk: query heads a grid step, e.g. 1,2,4")
+    ap.add_argument("--prefix", type=int, default=None,
+                    help="--chunk: only the places at this prefix")
+    ap.add_argument("--keep-going", action="store_true",
+                    help="--chunk --check: report a failed check and go on "
+                    "(an ablated copy is wrong by design)")
     ap.add_argument("--blocks", default="",
                     help="BLOCK_PAGES to time, e.g. 4,8,16")
     ap.add_argument("--calls", type=int, default=50)
     ap.add_argument("--partials", action="store_true")
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--dtype", default="bfloat16")
-    ap.add_argument("--out", default="chiprun_out/paged_sweep.jsonl")
+    ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    if args.out is None:
+        args.out = "chiprun_out/%s_sweep.jsonl" % (
+            "chunk" if args.chunk else "paged")
     if jax.default_backend() != "tpu":
         raise SystemExit("microbench_paged.py times the chip's kernel: "
                          "run it through chiprun")
@@ -391,6 +573,11 @@ def main():
         with open(args.out, "a") as f:
             f.write(json.dumps(row) + "\n")
 
+    if args.chunk:
+        chunk(args, emit)
+        return
+    if args.model not in ("phi4flash", "cohere2"):
+        raise SystemExit("--model mimo / all go with --chunk")
     if args.model == "cohere2":
         for b in blocks:
             pda.BLOCK_PAGES = b

@@ -1,7 +1,8 @@
 """Pallas TPU kernel for a CHUNK's attention over plain (not latent) paged
-keys and values, as ``models/mimo_decode.py`` prefills: grouped-query
-heads, keys wider than values (192 against 128), queries at a traced
-offset, and two static variants of one body:
+keys and values, as ``models/mimo_decode.py``, ``phi4flash_decode.py`` and
+``cohere2_moe_decode.py`` prefill: grouped-query heads, keys wider than
+values (192 against 128), queries at a traced offset, and two static
+variants of one body:
 
 * **full**: causal over every key; the tiles above a query tile's
   frontier are skipped and their fetch is not issued (the block index is
@@ -13,16 +14,46 @@ offset, and two static variants of one body:
   queries over 2,304 keys that is 3 steps a query tile where the absolute
   axis has 9, most of them dead).
 
+**The schedule of tiles (PR 51).** A live tile is *interior* when every
+query of its query tile sees every one of its keys (``_inside``: two
+scalar comparisons on the prefetched offsets) and takes a body with no
+iota, compare or select; only a tile an edge crosses (the diagonal, a
+window's trailing edge) builds a mask, once for all the heads of the
+program. A program takes ``heads_a_step`` query heads of ONE key head (a
+divisor of the group under ``VMEM_BUDGET``, from the tile and head sizes
+alone: 8 of the 16 of Command A+ and of MiMo's full layers at 512 x
+1,024, all 8 of MiMo's window layers at 256 x 256, phi-4-mini-flash's 2)
+and walks them in a loop over one fetched key
+tile: grid steps, dead steps and the keys' traffic fall by that factor,
+each head keeps its own ``m``, ``l`` and accumulator. ``m`` stands in
+all 128 lanes of its column and ``l`` as a partial sum a lane, so the
+only reduction across the lanes in a step is the row maximum's. Alone on
+a v5e at Command A+'s chunk (2,048 queries, 128 heads over 8; PERF.md
+section 5): full at a prefix of 8,192 12.15 -> 8.88 ms (52 -> 71% of the
+bf16 peak by live pairs), window 6.83 -> 4.85 (41 -> 58%). What is left:
+with no softmax at all the same schedule reads 7.76 ms; the exponential
+costs nothing beside the matmuls, the scale 5%, and the row maximum's
+reduction across the lanes, 64 a head and tile, ~1.5 ms of the 8.88 (a
+branch that moves ``m`` only when a score outgrows it costs more than it
+saves; keys on the sublanes would make it whole-register work and is the
+next thing to try here and in ``ops/latent_attention.py``).
+
 Both take a **sink**: one learned logit a head that joins the softmax's
 denominator and takes no value, ``p_ij = exp(a_ij - m) / (exp(s_h - m) +
 sum_j exp(a_ij - m))``. The running softmax starts from it (``m = s_h``,
 ``l = 1``, nothing accumulated); a head without one starts from ``-1e30``,
-which the first live tile's correction wipes out.
+which the first live tile's correction wipes out (``l`` lies as 1 / 128
+in each of its 128 lanes: ``_lane_sums``).
 
 The keys of row ``b`` start at absolute position ``k_offsets[b]`` (a window
 layer hands in the few pages round its chunk, not the sequence from 0) and
 its queries at ``q_offsets[b]``; both are scalar-prefetched, so one
 compiled program serves every prefix.
+
+The two ``pallas_call`` names, ``chunk_attn_full`` and
+``chunk_attn_window``, are a contract with the benchmark, which finds the
+kernels in a device trace by their HLO instruction names
+(``benchmarks/cohere2_moe_counts.py``, ``mimo_counts.py``).
 """
 
 from __future__ import annotations
@@ -43,6 +74,13 @@ BLOCK_K = 1024
 # The window variant's smallest tiles: under a window of 128 a tile of 256
 # queries has 383 live keys.
 WINDOW_BLOCK = 256
+# What a program may keep in fast memory (of a v5e core's 128 MiB; past
+# ``_VMEM_DEFAULT``, Mosaic's scoped limit, the call asks for it), and the
+# bytes of temporaries a (query, key) pair of ONE head's tile is reckoned
+# at.
+VMEM_BUDGET = 24 << 20
+_VMEM_DEFAULT = 16 << 20
+_SCORE_BYTES = 16
 
 
 def tiles(queries: int, window: Optional[int]) -> Tuple[int, int]:
@@ -53,12 +91,38 @@ def tiles(queries: int, window: Optional[int]) -> Tuple[int, int]:
     tile would be live), a window of 4,096 the full variant's 512 x 1,024
     (6 steps a query tile, three quarters of them live; at 256 x 256 it
     would be 18 steps of a sixth of the work each, under the grid's cost a
-    step)."""
+    step). Swept again with several heads a step (PR 51): every smaller
+    tile is slower at Command A+'s and MiMo's full shapes (256 x 1,024
+    +24%, 512 x 512 +65%), so the answers stand."""
     if window is None:
         return math.gcd(queries, BLOCK_Q), BLOCK_K
     quarter = 1 << max(window // 4, 1).bit_length() - 1
     block_k = min(BLOCK_K, max(WINDOW_BLOCK, quarter))
     return math.gcd(queries, min(BLOCK_Q, block_k)), block_k
+
+
+def _vmem_bytes(heads: int, block_q: int, block_k: int, d: int, dv: int,
+                itemsize: int) -> int:
+    """What a program of ``heads`` query heads keeps in fast memory: the
+    q, out, K and V blocks twice (the pipeline's two buffers), the
+    accumulator and the 128-lane ``m`` / ``l`` columns a head, and ONE
+    head's (block_q, block_k) float32 temporaries (scores, probabilities,
+    their rounded copy, an edge tile's mask), which the heads' loop
+    reuses."""
+    blocks = 2 * itemsize * (heads * block_q * (d + dv)
+                             + block_k * (d + dv))
+    scratch = 4 * heads * block_q * (dv + 2 * _LANE)
+    return blocks + scratch + _SCORE_BYTES * block_q * block_k
+
+
+def heads_a_step(groups: int, block_q: int, block_k: int, d: int, dv: int,
+                 itemsize: int) -> int:
+    """How many of the ``groups`` query heads that share a key head one
+    program takes: the largest divisor of ``groups`` that fits
+    ``VMEM_BUDGET``. From the shapes alone."""
+    return max(g for g in range(1, groups + 1)
+               if groups % g == 0 and (g == 1 or _vmem_bytes(
+                   g, block_q, block_k, d, dv, itemsize) <= VMEM_BUDGET))
 
 
 def _first_tile(q0, k0, window, block_k):
@@ -67,6 +131,37 @@ def _first_tile(q0, k0, window, block_k):
     if window is None:
         return 0
     return jnp.maximum(q0 - (window - 1) - k0, 0) // block_k
+
+
+def _inside(q0, c0, block_q: int, block_k: int, window: Optional[int]):
+    """Whether every query of the tile at ``q0`` sees every key of the
+    tile at ``c0``: the last key at or before the first query and, under a
+    window, the first key inside the LAST query's window. Such a tile
+    needs no mask."""
+    inside = c0 + block_k - 1 <= q0
+    if window is not None:
+        inside &= c0 > q0 + block_q - 1 - window
+    return inside
+
+
+def _lanes(col, width: int):
+    """A (rows, 128) column whose lanes all hold the row's value, as wide
+    as ``width``: whole copies where the lanes divide it."""
+    if width % _LANE == 0:
+        return pltpu.repeat(col, width // _LANE, 1)
+    return jnp.broadcast_to(col[:, :1], (col.shape[0], width))
+
+
+def _lane_sums(p):
+    """(rows, 128): lane ``j`` holds the sum of ``p``'s columns ``j``, ``j
+    + 128``, ...: whole-register adds, where a row's one sum would cross
+    the lanes once a tile (a seventh of the kernel's time, PERF.md
+    section 5). ``l`` is kept so, a partial sum a lane, and summed over
+    the lanes once, at the end. A key tile is whole lanes wide."""
+    out = p[:, :_LANE]
+    for c in range(_LANE, p.shape[-1], _LANE):
+        out = out + p[:, c:c + _LANE]
+    return out
 
 
 def _kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
@@ -81,18 +176,53 @@ def _kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.broadcast_to(sink_ref[0], m_ref.shape)
-        l_ref[...] = jnp.ones_like(l_ref)
+        m_ref[...] = jnp.broadcast_to(sink_ref[...], m_ref.shape)
+        l_ref[...] = jnp.full_like(l_ref, 1.0 / _LANE)
 
     live = (tile < key_tiles) & (q0 + block_q - 1 >= c0)
     if window is not None:
         live &= c0 + block_k - 1 > q0 - window
+    inside = _inside(q0, c0, block_q, block_k, window)
 
-    @pl.when(live)
-    def _tile():
-        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    def step(seen):
+        """The tile's part of the running softmax of each of the program's
+        heads in turn; ``seen`` is the tile's mask, one for all heads, or
+        ``None`` for a tile that needs none. ``m`` stands in all 128 lanes
+        of its column and ``l`` is a partial sum a lane (``_lane_sums``),
+        so a head's update is whole loads and stores and one reduction
+        across the lanes, the maximum's."""
+        def head(h, carry):
+            s = jax.lax.dot_general(q_ref[0, h], k_ref[0, 0],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if seen is not None:
+                s = jnp.where(seen, s, NO_SINK)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, block_k))
+            if seen is not None:
+                # A row that has seen no key yet and has no sink stands at
+                # ``NO_SINK``, where a masked score's exp would be 1.
+                p = jnp.where(seen, p, 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + _lane_sums(p)
+            m_ref[h] = m_new
+            v = v_ref[0, 0]
+            acc_ref[h] = acc_ref[h] * _lanes(
+                corr, v.shape[-1]) + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, q_ref.shape[1], head, 0)
+
+    @pl.when(live & inside)
+    def _interior():
+        step(None)
+
+    @pl.when(live & jnp.logical_not(inside))
+    def _edge():
         rows = q0 + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         cols = c0 + jax.lax.broadcasted_iota(
@@ -100,26 +230,14 @@ def _kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
         seen = rows >= cols
         if window is not None:
             seen &= rows - cols < window
-        s = jnp.where(seen, s * scale, NO_SINK)
-        m_prev = m_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # A row that has seen no key yet and has no sink stands at
-        # ``NO_SINK``, where a masked score's exp would be 1.
-        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:, 0:1] = l_ref[:, 0:1] * corr + jnp.sum(p, axis=-1,
-                                                       keepdims=True)
-        m_ref[:, 0:1] = m_new
-        v = v_ref[0, 0]
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        step(seen)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _final():
         # ``l`` is 1 or more where there is a sink and where no key was
         # seen; nothing divides by zero.
-        o_ref[0, 0] = (acc_ref[...] / l_ref[:, 0:1]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.sum(
+            l_ref[...], axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
 def _interpret() -> bool:
@@ -161,6 +279,8 @@ def chunk_attention(q, k, v, q_offsets, k_offsets, scale: float,
     q, k = _pad_to(q, 3, _LANE), _pad_to(_pad_to(k, 3, _LANE), 2, block_k)
     v = _pad_to(v, 2, block_k)
     d, key_tiles = q.shape[-1], k.shape[2] // block_k
+    heads = heads_a_step(groups, block_q, block_k, d, dv, q.dtype.itemsize)
+    need = _vmem_bytes(heads, block_q, block_k, d, dv, q.dtype.itemsize)
     steps = key_tiles
     if window is not None:
         steps = min(key_tiles, -(-(block_q + window - 1) // block_k) + 1)
@@ -177,7 +297,7 @@ def chunk_attention(q, k, v, q_offsets, k_offsets, scale: float,
         last = jnp.clip((q0 + block_q - 1 - koff[b]) // block_k, 0,
                         key_tiles - 1)
         tile = _first_tile(q0, koff[b], window, block_k) + j
-        return b, h // groups, jnp.minimum(tile, last), 0
+        return b, h * heads // groups, jnp.minimum(tile, last), 0
 
     def spec(shape, index_map):
         return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
@@ -191,24 +311,25 @@ def chunk_attention(q, k, v, q_offsets, k_offsets, scale: float,
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
-                grid=(B, H, S // block_q, steps),
+                grid=(B, H // heads, S // block_q, steps),
                 in_specs=[
-                    spec((1, 1, block_q, d), q_map),
+                    spec((1, heads, block_q, d), q_map),
                     spec((1, 1, block_k, d), k_map),
                     spec((1, 1, block_k, dv), k_map),
-                    spec((1, 1, _LANE),
+                    spec((heads, 1, _LANE),
                          lambda b, h, i, j, qoff, koff: (h, 0, 0)),
                 ],
-                out_specs=spec((1, 1, block_q, dv), q_map),
+                out_specs=spec((1, heads, block_q, dv), q_map),
                 scratch_shapes=[
-                    pltpu.VMEM((block_q, dv), jnp.float32),
-                    pltpu.VMEM((block_q, _LANE), jnp.float32),
-                    pltpu.VMEM((block_q, _LANE), jnp.float32),
+                    pltpu.VMEM((heads, block_q, dv), jnp.float32),
+                    pltpu.VMEM((heads, block_q, _LANE), jnp.float32),
+                    pltpu.VMEM((heads, block_q, _LANE), jnp.float32),
                 ]),
             out_shape=jax.ShapeDtypeStruct((B, H, S, dv), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary")),
+                                     "arbitrary"),
+                vmem_limit_bytes=need if need > _VMEM_DEFAULT else None),
             interpret=_interpret(),
             name=name,
         )(q_offsets.astype(jnp.int32), k_offsets.astype(jnp.int32),

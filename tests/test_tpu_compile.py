@@ -161,15 +161,27 @@ def test_a_chunks_held_pairs_compile_at_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 2.6e8
 
 
-@pytest.mark.parametrize("rows,kv,queries,keys,window", [
-    (1, 4, 2048, 32768, None),   # a full layer's chunk at 30k of context
-    (1, 8, 2048, 2240, 128),     # a window layer's: the chunk + its window
-    (1, 8, 16, 256, 128),        # the smallest suffix bucket
-    (4, 4, 256, 256, None),      # an admission wave of short prompts
+def _chunk_program_fits(module, groups, queries, keys, window, d, dv, heads):
+    """The heads a program of ``ops/chunk_attention.py`` takes at this
+    shape, and its reckoned fast memory inside the module's budget (Mosaic
+    refuses a program past the limit it was given, so the compile above
+    holds the reckoning to the truth from the other side)."""
+    block_q, block_k = module.tiles(queries, window)
+    block_k = min(block_k, -(-keys // 128) * 128)
+    assert module.heads_a_step(groups, block_q, block_k, d, dv, 2) == heads
+    assert module._vmem_bytes(heads, block_q, block_k, d, dv, 2) \
+        <= module.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("rows,kv,queries,keys,window,heads", [
+    (1, 4, 2048, 32768, None, 8),  # a full layer's chunk at 30k of context
+    (1, 8, 2048, 2240, 128, 8),    # a window layer's: the chunk + its window
+    (1, 8, 16, 256, 128, 8),       # the smallest suffix bucket
+    (4, 4, 256, 256, None, 16),    # an admission wave of short prompts
 ])
 def test_chunk_attention_kernel_compiles_at_mimos_widths(
         one_chip, no_compile_cache, monkeypatch, rows, kv, queries, keys,
-        window):
+        window, heads):
     """``ops/chunk_attention.py`` at MiMo-V2.5's published widths: 64 query
     heads over 4 or 8 key heads, keys 192 wide (padded to 256 lanes in the
     wrapper), values 128, a sink, both static variants."""
@@ -195,6 +207,11 @@ def test_chunk_attention_kernel_compiles_at_mimos_widths(
     assert "tpu_custom_call" in text and name in text
     # The scores never exist outside the kernel.
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+    # A program takes 8 of a full layer's 16 query heads a key head at
+    # 512 x 1,024 tiles (keys 256 lanes wide), every one of them at the
+    # smaller tiles, and all 8 of a window layer's.
+    _chunk_program_fits(chunk_attention, 64 // kv, queries, keys, window,
+                        256, 128, heads=heads)
 
 
 def test_fsdp4_step_gathers_its_weights_once_and_fits_the_chip(
@@ -466,17 +483,20 @@ def test_chunk_attention_tiles_follow_the_window():
     assert tiles(2048, 4096) == (512, 1024) and tiles(16, 4096) == (16, 1024)
 
 
-@pytest.mark.parametrize("queries,keys,window", [
-    (2048, 32768, None),    # the full layer's chunk at 30k of context
-    (2048, 6208, 4096),     # a window layer's: the chunk + its window
-    (16, 4224, 4096),       # the smallest suffix bucket
+@pytest.mark.parametrize("queries,keys,window,heads", [
+    (2048, 32768, None, 8),    # the full layer's chunk at 30k of context
+    (2048, 6208, 4096, 8),     # a window layer's: the chunk + its window
+    (16, 4224, 4096, 16),      # the smallest suffix bucket
 ])
 def test_chunk_attention_kernel_compiles_at_command_a_widths(
-        one_chip, no_compile_cache, monkeypatch, queries, keys, window):
+        one_chip, no_compile_cache, monkeypatch, queries, keys, window,
+        heads):
     """``ops/chunk_attention.py`` at Command A+'s published widths: 128
     query heads of 128 over 8 key heads, keys as wide as values, no sink,
     both static variants, the window variant at the tiles its window of
-    4,096 chooses."""
+    4,096 chooses; a program takes ``heads`` of a key head's 16 query
+    heads (what the budget allows at 512 x 1,024 tiles, all of them at 16
+    queries)."""
     import jax
     import jax.numpy as jnp
 
@@ -497,6 +517,8 @@ def test_chunk_attention_kernel_compiles_at_command_a_widths(
     name = "chunk_attn_full" if window is None else "chunk_attn_window"
     assert "tpu_custom_call" in text and name in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+    _chunk_program_fits(chunk_attention, 16, queries, keys, window, 128,
+                        128, heads=heads)
 
 
 @pytest.mark.parametrize("program", ["decode:8192", "decode:12288",
