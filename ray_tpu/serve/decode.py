@@ -143,6 +143,10 @@ class DecodeEngine:
     (``[layers, slots + 1, ...]``, the last row scratch) and not by page,
     a recurrent layer's state; its prefill programs are then told each
     row's slot and whether its prompt ends there (``_prefill_tables``).
+    A model whose ``page_kinds`` names NO kind keeps slot state alone: the
+    engine then has no allocator, no block table and no view, its decode
+    is one program that is told which slots step, and the slots alone
+    bind admission (docs/SERVING.md, "A model with no page kind").
 
     ``slots`` concurrent sequences of up to ``capacity`` tokens share
     one paged KV pool. ``step()`` advances every active slot one token;
@@ -261,7 +265,6 @@ class DecodeEngine:
         pp = (rt_config.kv_pool_pages if pool_pages is None
               else pool_pages)
         self.pool_pages = int(pp) or slots * self.slot_pages_max
-        self._pages = PageAllocator(self.pool_pages)
         # Page kinds. A model that names none has ONE, which keeps every
         # token (``_pages``, ``_block_tables``, ``_slot_pages``: all of
         # the engine before kinds). A model whose ``page_kinds`` names
@@ -275,12 +278,29 @@ class DecodeEngine:
         # chunk's, or an admission wave's whole prompts.
         kinds = (ld.page_kinds(config) if hasattr(ld, "page_kinds")
                  else {self.DEFAULT_KIND: {"window": None, "leaves": None}})
-        self._kind, *others = kinds
-        if kinds[self._kind]["window"] is not None or any(
+        # NO kind: every leaf of the model's pool is slot state,
+        # ``[layers, slots + 1, ...]`` whatever a sequence's length, so
+        # there is nothing to page. ``_kind`` is None, there is no
+        # allocator, no block table and no view, one rung of no rows (ONE
+        # decode program, told which slots step), and every later mention
+        # of a page is behind ``_kind``: a free slot seats a request whose
+        # prompt and answer fit ``capacity``, and nothing is ever
+        # preempted for memory. (``page_tokens`` stays the engine's
+        # argument and sizes nothing.)
+        self._kind, *others = kinds or (None,)
+        if self._kind is None:
+            self.pool_pages = self.slot_pages_max = 0
+            self._pages = self._block_tables = self._slot_pages = None
+        elif kinds[self._kind]["window"] is not None or any(
                 kinds[k]["window"] is None for k in others):
             raise ValueError(
                 f"model {ld.__name__}: the first page kind keeps every "
                 f"token and the others a window, got {kinds}")
+        else:
+            self._pages = PageAllocator(self.pool_pages)
+            self._block_tables = np.zeros(
+                (slots, self.slot_pages_max), np.int32)
+            self._slot_pages: List[List[int]] = [[] for _ in range(slots)]
         self._windows: Dict[str, WindowPages] = {}
         for name in others:
             keep = -(-int(kinds[name]["window"]) // self.page_tokens) + 1
@@ -303,14 +323,19 @@ class DecodeEngine:
         # pad row (``_prefill_tables``).
         self._state_leaves = (tuple(ld.slot_state(config))
                               if hasattr(ld, "slot_state") else ())
+        if self._kind is None and not self._state_leaves:
+            raise ValueError(
+                f"model {ld.__name__} names no page kind and no slot "
+                f"state: it would cache nothing")
         # The pool is the model's own pytree (leaves ``[layers,
         # pages + 1, page_tokens, ...]``: K and V per head for llama,
         # one latent row a token for deepseek, K and V a kind for mimo);
         # the engine carries it whole beside the slots' cursors and
         # never looks inside.
         pool = ld.init_page_pool(
-            config, {self._kind: self.pool_pages,
-                     **{k: w.alloc.pages for k, w in self._windows.items()}}
+            config, {} if self._kind is None
+            else {self._kind: self.pool_pages,
+                  **{k: w.alloc.pages for k, w in self._windows.items()}}
             if self._windows else self.pool_pages, self.page_tokens,
             **({"slots": slots} if self._state_leaves else {}))
         # Bytes of state one slot holds (the step log's ``state_bytes``
@@ -327,9 +352,6 @@ class DecodeEngine:
         self.cache = {**pool,
                       "length": jax.numpy.zeros((slots,),
                                                 jax.numpy.int32)}
-        self._block_tables = np.zeros(
-            (slots, self.slot_pages_max), np.int32)
-        self._slot_pages: List[List[int]] = [[] for _ in range(slots)]
         # The widths a decode step's view of the pool may take: the step
         # reads the pages its slots hold, padded up to the next rung, and
         # each rung is one compiled program. Powers of two from 64 rows
@@ -339,6 +361,7 @@ class DecodeEngine:
         rungs = [64]
         while rungs[-1] * 2 < top:
             rungs.append(rungs[-1] * 2)
+        # (With no page kind: one rung of no rows, one decode program.)
         self._view_ladder = tuple(n for n in rungs if n < top) + (top,)
         if self.mesh is not None:
             # Commit the KV state onto the mesh: the shared page pool
@@ -431,6 +454,7 @@ class DecodeEngine:
         self._state_dev = None
         self._temps_dev = None
         self._live_pages = 0     # of the last view built (``_live_view``)
+        self._rung = 0           # and the rung it was built on
         # Suffix prefills bucket on a finer grid than full prefills: the
         # whole point is that the suffix is short, so padding it back up
         # to prefill_bucket would refund most of the win.
@@ -754,8 +778,9 @@ class DecodeEngine:
                                    free=w.alloc.free_count)
 
     def _prefill_tables(self, slots: List[int], positions: List[int],
-                        bt: np.ndarray, bucket: int,
-                        ends: Optional[List[bool]] = None):
+                        bt: Optional[np.ndarray], bucket: int,
+                        ends: Optional[List[bool]] = None,
+                        n: Optional[int] = None):
         """The block tables a prefill program takes: ``bt`` itself for a
         model of one kind and no slot state; else a dict, the first
         kind's ``bt`` under its name and, a window kind, the columns
@@ -763,16 +788,19 @@ class DecodeEngine:
         window before it, with the index of their first page
         (``<kind>_first``); for a model with slot state also ``slots``,
         each row's slot, and ``ends``, whether the row's prompt ends in
-        this program (``None``: every row's does). A pad row repeats the
-        last row's pages of every kind, which it writes with the same
-        values, and names the scratch row ``self.slots`` of the state,
-        never a real slot."""
+        this program (``None``: every row's does); for a model with no
+        page kind ``slots`` and ``ends`` alone (``bt`` is None). ``n`` is
+        the program's rows where no ``bt`` says it: those past ``slots``
+        are pad rows. A pad row
+        repeats the last row's pages of every kind, which it writes with
+        the same values, and names the scratch row ``self.slots`` of the
+        state, never a real slot."""
         import jax.numpy as jnp
 
         if not self._windows and not self._state_leaves:
             return jnp.asarray(bt)
-        out = {self._kind: jnp.asarray(bt)}
-        pads = len(bt) - len(slots)
+        out = {} if bt is None else {self._kind: jnp.asarray(bt)}
+        pads = (len(bt) if n is None else n) - len(slots)
         for kind, w in self._windows.items():
             width = -(-(bucket + w.window) // self.page_tokens) + 1
             cols = [w.columns(s, p, width)
@@ -796,7 +824,13 @@ class DecodeEngine:
         the ACTIVE slots, on the smallest rung of the ladder that holds
         them. Count after ``_ensure_decode_pages``: the pages must cover
         every token the program writes. Idle and mid-prefill slots own
-        no row of it."""
+        no row of it. A model with no page kind is given, in a view's
+        place, which slots step, (slots,) bool: the others keep their
+        state bit for bit."""
+        if self._kind is None:
+            steps = np.zeros((self.slots,), bool)
+            steps[list(self._active)] = True
+            return steps
         counts = np.zeros((self.slots,), np.int32)
         for slot in self._active:
             counts[slot] = len(slot_pages[slot])
@@ -806,8 +840,8 @@ class DecodeEngine:
         # A model may lay its rows out in groups (``view_rows``).
         rows_of = getattr(self._ld, "view_rows", None)
         live = self._live_pages if rows_of is None else rows_of(counts)
-        rung = next(n for n in self._view_ladder if n >= live)
-        return self._view(tables, counts, rung)
+        self._rung = next(n for n in self._view_ladder if n >= live)
+        return self._view(tables, counts, self._rung)
 
     def _view(self, tables: np.ndarray, counts: np.ndarray, rung: int):
         """The model's ``live_page_view`` on ``rung`` rows. For a model
@@ -832,7 +866,10 @@ class DecodeEngine:
         slots are served first; when the pool is dry even after
         reclaiming prefix pins, the YOUNGEST admitted request is
         preempted (recompute-style requeue) — the oldest request always
-        makes progress, so this terminates."""
+        makes progress, so this terminates. (With no page kind there is
+        nothing to ensure.)"""
+        if self._kind is None:
+            return
         for slot in sorted(self._active,
                            key=lambda s: self._active[s].submitted_at):
             while True:
@@ -941,7 +978,7 @@ class DecodeEngine:
         if adopt is not None:
             self._validate_adopt(req, adopt)
             req.adopt = dict(adopt)
-        if self._seq_pages(
+        if self._kind is not None and self._seq_pages(
                 len(req.tokens) + req.max_new_tokens) > self.pool_pages:
             # A request no amount of preemption can seat must fail fast,
             # not live forever in the requeue list.
@@ -1214,6 +1251,8 @@ class DecodeEngine:
         interleaver instead of running one monolithic program. Returns
         False when the pool ran dry mid-wave (unseated requests are
         pushed back in order; admission pauses until pages free)."""
+        if self._kind is None:
+            return self._admit_slots(live)
         chunk = self.prefill_chunk_tokens
         full_group: List[_Request] = []
         suffix_group: List[_Request] = []
@@ -1290,6 +1329,24 @@ class DecodeEngine:
         self._admit_paged_full(full_group)
         self._admit_paged_suffix(suffix_group)
         return not self._requeue
+
+    def _admit_slots(self, live: List[_Request]) -> bool:
+        """``_admit_paged`` of a model with no page kind: the wave is no
+        longer than the free slots (``_admit``), so every request is seated; a
+        prompt longer than a chunk goes to the chunked-prefill
+        interleaver, the others prefill whole. Always True: nothing can
+        run dry."""
+        chunk = self.prefill_chunk_tokens
+        whole: List[_Request] = []
+        for req in live:
+            req.slot = slot = self._free.pop()
+            if chunk > 0 and len(req.tokens) > chunk:
+                self._prefilling[slot] = req
+            else:
+                whole.append(req)
+        self._mark_admitted(live)
+        self._admit_paged_full(whole)
+        return True
 
     def _seat_adopted(self, req: _Request) -> bool:
         """Seat one adopted (handed-off) request: allocate pages for the
@@ -1403,16 +1460,16 @@ class DecodeEngine:
             rows = np.zeros((n, bucket), np.int32)
             lengths = np.zeros((n,), np.int32)
             slot_ids = np.full((n,), group[-1].slot, np.int32)
-            bt = np.zeros((n, wp), np.int32)
             for i, req in enumerate(group):
                 rows[i, :len(req.tokens)] = req.tokens
                 lengths[i] = len(req.tokens)
                 slot_ids[i] = req.slot
-                bt[i] = self._block_tables[req.slot, :wp]
             for i in range(len(group), n):  # idempotent pad rows
                 rows[i] = rows[len(group) - 1]
                 lengths[i] = lengths[len(group) - 1]
-                bt[i] = bt[len(group) - 1]
+            bt = None if self._kind is None else self._block_tables[
+                [r.slot for r in group]
+                + [group[-1].slot] * (n - len(group)), :wp]
             self._prefill_waves += 1
             t0 = time.time()
             ids, self.cache = self._dispatch_fresh(
@@ -1421,7 +1478,8 @@ class DecodeEngine:
                     self.params, self.cache, jnp.asarray(rows),
                     jnp.asarray(lengths),
                     self._prefill_tables([r.slot for r in group],
-                                         [0] * len(group), bt, bucket),
+                                         [0] * len(group), bt, bucket,
+                                         n=n),
                     jnp.asarray(slot_ids), *self._draw_args(group, n),
                     n=n, bucket=bucket),
                 tokens=sum(len(r.tokens) for r in group),
@@ -1517,24 +1575,29 @@ class DecodeEngine:
         step_tok = min(self.prefill_chunk_tokens, remaining)
         bucket = min(ld.cache_bucket(step_tok, self._suffix_bucket_min),
                      self.prefill_chunk_tokens)
-        need = self._seq_pages(req.prefilled + step_tok) \
-            - len(self._slot_pages[slot])
-        if self._windows_missing(slot, req.prefilled + step_tok):
-            return
-        if need > 0:
-            got = self._alloc_pages(need)
-            if got is None:
+        if self._kind is None:
+            # No page kind: nothing to allocate, and one program a bucket
+            # (``width`` counts the columns of a table there is not).
+            width, bt = 0, None
+        else:
+            need = self._seq_pages(req.prefilled + step_tok) \
+                - len(self._slot_pages[slot])
+            if self._windows_missing(slot, req.prefilled + step_tok):
                 return
-            self._grow_slot(slot, got)
-        self._grow_windows(slot, req.prefilled + step_tok)
-        width = 1
-        while width * T < req.prefilled + bucket:
-            width *= 2
-        width = min(width, self.slot_pages_max)
+            if need > 0:
+                got = self._alloc_pages(need)
+                if got is None:
+                    return
+                self._grow_slot(slot, got)
+            self._grow_windows(slot, req.prefilled + step_tok)
+            width = 1
+            while width * T < req.prefilled + bucket:
+                width *= 2
+            width = min(width, self.slot_pages_max)
+            bt = self._block_tables[slot:slot + 1, :width]
         rows = np.zeros((1, bucket), np.int32)
         rows[0, :step_tok] = req.tokens[req.prefilled:
                                         req.prefilled + step_tok]
-        bt = self._block_tables[slot:slot + 1, :width]
         ends = req.prefilled + step_tok >= len(req.tokens)
         self.prefill_chunks += 1
         t0 = time.time()
@@ -1545,7 +1608,7 @@ class DecodeEngine:
                 jnp.asarray([req.prefilled], np.int32),
                 jnp.asarray([req.prefilled + step_tok], np.int32),
                 self._prefill_tables([slot], [req.prefilled], bt, bucket,
-                                     ends=[ends]),
+                                     ends=[ends], n=1),
                 jnp.asarray([slot], np.int32),
                 *self._draw_args([req], 1),
                 n=1, bucket=bucket, width=width),
@@ -1774,14 +1837,15 @@ class DecodeEngine:
         slot's page references (shared prefix pages survive on the
         index's pins; exclusively-owned pages recycle immediately) and
         parks the block-table row on the scratch page."""
-        pages = self._slot_pages[slot]
-        self._slot_pages[slot] = []
-        self._block_tables[slot, :] = 0
-        self._pages.free(pages)
-        if pages and self.steplog.enabled:
-            self.steplog.event("page-free", n=len(pages),
-                               page_kind=self._kind,
-                               free=self._pages.free_count)
+        if self._kind is not None:
+            pages = self._slot_pages[slot]
+            self._slot_pages[slot] = []
+            self._block_tables[slot, :] = 0
+            self._pages.free(pages)
+            if pages and self.steplog.enabled:
+                self.steplog.event("page-free", n=len(pages),
+                                   page_kind=self._kind,
+                                   free=self._pages.free_count)
         for kind, w in self._windows.items():
             n = w.release(slot)
             if n and self.steplog.enabled:
@@ -1901,7 +1965,7 @@ class DecodeEngine:
         stepped = len(self._active)
         ctx = self._ctx_tokens() if rec else None
         view = self._live_view(self._block_tables, self._slot_pages)
-        rung = (view[self._kind] if self._windows else view).shape[1]
+        rung = self._rung
         t_d0 = time.time() if rec else 0.0
         # ``uploads``: the arrays this dispatch puts on the device. The
         # view always; the state and the temperatures only when the
@@ -2003,7 +2067,8 @@ class DecodeEngine:
             active=len(self._active), prefilling=len(self._prefilling),
             queued=max(0, self._pending.qsize() + len(self._requeue)
                        - self._queued_cancelled),
-            pages_free=self._pages.free_count,
+            pages_free=(0 if self._kind is None
+                        else self._pages.free_count),
             pages_pinned=(self.prefix.pinned_pages
                           if self.prefix is not None else None),
             ctx_tokens=ctx_tokens, view_pages=view_pages,
@@ -2013,7 +2078,7 @@ class DecodeEngine:
                    for k, n in self.pages_in_use().items()},
                 "kv_tokens": self._ctx_tokens() - len(self._active) + sum(
                     r.prefilled for r in self._prefilling.values())}
-               if self._windows else {}),
+               if self._windows or self._kind is None else {}),
             # A model with slot state: the bytes of it the seated slots
             # hold, decoding or between two prefill chunks.
             **({"state_bytes": self._slot_state_bytes
@@ -2041,8 +2106,9 @@ class DecodeEngine:
 
         none = np.zeros((self.slots,), np.int32)
         for rung in self._view_ladder:
-            yield rung, self._jax.tree.map(jnp.asarray, self._view(
-                self._block_tables, none, rung))
+            yield rung, self._jax.tree.map(
+                jnp.asarray, none.astype(bool) if self._kind is None
+                else self._view(self._block_tables, none, rung))
 
     def serve_forever(self, idle_wait_s: float = 0.05) -> None:
         """Decode loop for a replica thread: steps while work exists,
@@ -2104,6 +2170,8 @@ class DecodeEngine:
         return backlog
 
     def stats(self) -> Dict[str, Any]:
+        from ray_tpu.serve.paging import NO_PAGES_STATS
+
         active = len(self._active)
         prefilling = len(self._prefilling)
         # Live queue depth: cancelled-but-undequeued entries are dead
@@ -2151,7 +2219,8 @@ class DecodeEngine:
                                                                  denom),
             "device": self.device_stats(),
         }
-        out.update(self._pages.stats())
+        out.update(NO_PAGES_STATS if self._kind is None
+                   else self._pages.stats())
         if self._windows:
             out["pages_in_use_by_kind"] = self.pages_in_use()
             out["pages_total_by_kind"] = {
@@ -2174,6 +2243,8 @@ class DecodeEngine:
 
     def pages_in_use(self) -> Dict[str, int]:
         """Pool pages handed out, a page kind."""
+        if self._kind is None:
+            return {}
         return {self._kind: self._pages.in_use,
                 **{k: w.alloc.in_use for k, w in self._windows.items()}}
 
@@ -2230,6 +2301,8 @@ class DecodeEngine:
         are interchangeable, so EXTERNAL fragmentation is structurally
         zero — waste is partial tail pages and dead junk, and this is
         the number that says whether page_tokens is sized right."""
+        if self._kind is None:
+            return 0.0
         valid: Dict[int, int] = {}
         T = self.page_tokens
         rows = ([(s, r.prompt_len + r.generated)
@@ -2692,3 +2765,18 @@ class Cohere2MoeDecodeDeployment(LlamaDecodeDeployment):
         from ray_tpu.models import cohere2_moe, cohere2_moe_decode
 
         return cohere2_moe, cohere2_moe_decode
+
+
+class BrumbyDecodeDeployment(LlamaDecodeDeployment):
+    """The same deployment over Brumby (``models/brumby.py``): every layer
+    a power-retention layer, whose only cache is a state a slot. The model
+    names NO page kind, so the engine builds no allocator, table or view
+    for it and its slots alone bind admission; it has no
+    ``shard_decode_state``, so a mesh is refused by the engine, and a
+    handoff by ``submit``; its state turns the prefix index off."""
+
+    @staticmethod
+    def model_modules():
+        from ray_tpu.models import brumby, brumby_decode
+
+        return brumby, brumby_decode

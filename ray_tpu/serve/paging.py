@@ -142,6 +142,13 @@ class PageAllocator:
                 "pages_in_use": self.in_use}
 
 
+# What ``PageAllocator.stats`` reads for an engine whose model names no
+# page kind (``DecodeEngine``, ``_kind`` None): it has no allocator, and
+# whoever reads a replica's page health (``replica_metrics``, the
+# benchmark's marks) finds the keys all the same.
+NO_PAGES_STATS = {"pages_total": 0, "pages_free": 0, "pages_in_use": 0}
+
+
 class WindowPages:
     """Host state of a page KIND that is read no further back than
     ``window`` - 1 tokens (a model's sliding-window layers;

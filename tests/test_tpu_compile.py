@@ -601,3 +601,67 @@ def test_command_a_programs_compile_at_published_widths_inside_the_chip(
         # No slot's 65 (or 80) window pages and no view's groups gathered.
         assert not re.search(r"bf16\[\d+,(1024|4160|5120),1024\]", text)
         assert mem.temp_size_in_bytes < 0.3e9
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk:1x2048", "chunk:1x16"])
+def test_brumby_programs_compile_at_published_widths_inside_the_chip(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """``models/brumby_decode.py`` at Brumby's published widths and the
+    cell's layout (8 layers, 16 slots, no page of any kind; shapes only):
+    the ONE decode step, whose state tiles go through ``retention_step``
+    where they lie (no copy of a 5.17 GB leaf), and a 2,048-token chunk.
+    Arguments (13.57 GB: 8.40 GB of weights, 5.17 GB of state) and
+    temporaries fit the chip's 15.75 GB."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import brumby, moe_decode
+    from ray_tpu.models import brumby_decode as bd
+    from ray_tpu.ops import power_retention
+
+    monkeypatch.setattr(power_retention, "_interpret", lambda: False)
+    cfg = dataclasses.replace(brumby.BrumbyConfig(), n_layers=8)
+    slots = 16
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, spec: shape(
+            spec[0], jnp.float32 if str(path[-1].key)
+            in brumby.FLOAT32_LEAVES else jnp.bfloat16),
+        brumby._shapes(cfg), is_leaf=moe_decode.is_spec)
+    pool = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype), jax.eval_shape(
+            lambda: bd.init_page_pool(cfg, {}, 64, slots=slots)))
+    assert pool["S"].shape == (8, 17, 8, 128, 9216)
+    i32 = jnp.int32
+    if program == "decode":
+        compiled = jax.jit(
+            lambda p, pool, view, lens, toks: bd.paged_decode_step(
+                p, pool, view, lens, toks, cfg), donate_argnums=(1,)
+        ).lower(params, pool, shape((slots,), jnp.bool_),
+                shape((slots,), i32), shape((slots,), i32)).compile()
+    else:
+        rows, bucket = (int(x) for x in program.split(":")[1].split("x"))
+        tables = {"slots": shape((rows,), i32),
+                  "ends": shape((rows,), jnp.bool_)}
+        compiled = jax.jit(
+            lambda p, toks, pool, bt, plens, lens: bd.paged_prefill_suffix(
+                p, toks, pool, bt, cfg, plens, lens), donate_argnums=(2,)
+        ).lower(params, shape((rows, bucket), i32), pool, tables,
+                shape((rows,), i32), shape((rows,), i32)).compile()
+    mem = compiled.memory_analysis()
+    assert 13.5e9 < mem.argument_size_in_bytes < 13.65e9
+    # The state is written where it lies: the donated leaves are aliased.
+    assert mem.alias_size_in_bytes > 5.1e9
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes, "peak", peak)
+    assert peak < 15.0e9, (program, peak)
+    if program == "decode":
+        assert "retention_step" in compiled.as_text()
+        assert mem.temp_size_in_bytes < 0.3e9
