@@ -35,7 +35,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -414,6 +414,8 @@ class DecodeEngine:
         self.deadline_exceeded = 0  # requests ended by their deadline
         self.preempted = 0          # requests requeued by page pressure
         self.prefill_chunks = 0     # chunked-prefill programs dispatched
+        self.prefill_chunks_ahead = 0  # ... of them behind a decode
+        #   whose ids were yet to be fetched (``_prefill_ahead``)
         self._ema_request_s = 0.0   # EMA of admitted-request service time
         self._last_purge = 0.0      # dead-entry queue-purge throttle
         # Prefix KV cache: the index pins PAGE RANGES of the shared pool
@@ -537,6 +539,10 @@ class DecodeEngine:
         self._inbox: deque = deque()
         self._compiled: set = set()  # program keys dispatched once
         self._prefill_waves = 0      # prefill programs dispatched
+        # The chunk dispatched behind the last decode (``_prefill_ahead``)
+        # until the next step's tick has met it: ``(slot, ids)``, the ids
+        # a device array where the chunk ends its prompt, else None.
+        self._ahead: Optional[Tuple[int, Any]] = None
         # Disaggregated handoff accounting (engine side; the per-replica
         # lease ledger lives on the deployment wrapper).
         self.handoffs_published = 0  # prefill_only captures completed
@@ -1561,9 +1567,56 @@ class DecodeEngine:
         so the decode step runs. A 4k-token admission thus costs active
         streams one chunk of latency per token, never its whole
         prefill. Page allocation is chunk-by-chunk; a dry pool skips
-        the tick (decode keeps draining; the chunk retries next step)."""
-        if not self._prefilling:
-            return
+        the tick (decode keeps draining; the chunk retries next step).
+
+        Where the last step sent this step's chunk ahead
+        (``_prefill_ahead``), nothing is dispatched: one chunk lies
+        between two decodes on the device. If that chunk ended its
+        prompt, its ids are fetched here and the slot is seated, so it
+        joins this step's decode as it would have."""
+        went, self._ahead = self._ahead, None
+        if went is None:
+            if self._prefilling:
+                self._dispatch_chunk(ahead=False)
+        elif went[1] is not None:
+            self._end_prefill(*went)
+
+    def _end_prefill(self, slot: int, ids) -> None:
+        """The slot's last chunk has been dispatched: fetch the first
+        token it sampled and seat the slot for the decode."""
+        req = self._prefilling.pop(slot)
+        self._post_admit(
+            [req], [slot], self._fetch_ids(ids, "prefill_chunk"))
+
+    def _prefill_ahead(self) -> None:
+        """The NEXT step's prefill tick, run behind this step's decode
+        once that is dispatched and before its ids are fetched: the
+        chunk's inputs (the prompt's own tokens, its slot, its pages)
+        wait for no id, so the device runs it while the host samples,
+        emits, reaps, admits and launches the next decode, and not
+        after. It takes what is free and nothing else
+        (``_free_lists_cover``): where that is short it stands back,
+        and the tick runs at its usual place in the next step, after
+        this step's finishes have returned their pages."""
+        if self._prefilling:
+            self._slice("admit")
+            self._dispatch_chunk(ahead=True)
+
+    def _free_lists_cover(self, slot: int, tokens: int) -> bool:
+        """Whether the pages FREE now, no prefix pin reclaimed and
+        nobody preempted, cover the slot's first ``tokens`` positions
+        in every kind."""
+        if self._kind is None:
+            return True
+        need = self._seq_pages(tokens) - len(self._slot_pages[slot])
+        return (need <= self._pages.free_count
+                and not self._windows_missing(slot, tokens))
+
+    def _dispatch_chunk(self, ahead: bool) -> None:
+        """Dispatch the next chunk of the oldest mid-prefill slot (there
+        is one), if its pages can be had. ``ahead``: behind a decode
+        whose ids are yet to be fetched; the chunk's own ids, if its
+        prompt ends, then wait for the next step's tick."""
         import jax.numpy as jnp
 
         ld = self._ld
@@ -1575,6 +1628,9 @@ class DecodeEngine:
         step_tok = min(self.prefill_chunk_tokens, remaining)
         bucket = min(ld.cache_bucket(step_tok, self._suffix_bucket_min),
                      self.prefill_chunk_tokens)
+        if ahead and not self._free_lists_cover(
+                slot, req.prefilled + step_tok):
+            return
         if self._kind is None:
             # No page kind: nothing to allocate, and one program a bucket
             # (``width`` counts the columns of a table there is not).
@@ -1600,6 +1656,15 @@ class DecodeEngine:
                                         req.prefilled + step_tok]
         ends = req.prefilled + step_tok >= len(req.tokens)
         self.prefill_chunks += 1
+        attrs = self._prefill_attrs(int(ends))
+        if ahead:
+            self.prefill_chunks_ahead += 1
+            attrs["ahead"] = 1
+            if self.steplog.enabled:
+                # The chunk's run on the device ends under the NEXT
+                # fetch, not under the decode's that opens behind this
+                # launch (``StepTimeline.enclose``).
+                self.steplog.enclose("fetch", program="decode")
         t0 = time.time()
         ids, self.cache = self._dispatch_fresh(
             ("paged_suffix", 1, bucket, width),
@@ -1612,8 +1677,8 @@ class DecodeEngine:
                 jnp.asarray([slot], np.int32),
                 *self._draw_args([req], 1),
                 n=1, bucket=bucket, width=width),
-            then="admit", program="prefill_chunk", tokens=step_tok,
-            prefix=req.prefilled, **self._prefill_attrs(int(ends)))
+            then=None if ahead else "admit", program="prefill_chunk",
+            tokens=step_tok, prefix=req.prefilled, **attrs)
         # The chunk's own window pages, but for the window of the next
         # position, are dead the moment the program is dispatched.
         self._trim_windows(slot, req.prefilled + step_tok)
@@ -1621,10 +1686,10 @@ class DecodeEngine:
                         prefilled=req.prefilled + step_tok,
                         prompt=len(req.tokens))
         req.prefilled += step_tok
-        if req.prefilled >= len(req.tokens):
-            self._prefilling.pop(slot)
-            self._post_admit(
-                [req], [slot], self._fetch_ids(ids, "prefill_chunk"))
+        if ahead:
+            self._ahead = (slot, ids if ends else None)
+        elif ends:
+            self._end_prefill(slot, ids)
 
     def _retire(self, req: _Request, status: str) -> None:
         """Terminal exit for a request that never held a slot."""
@@ -1852,6 +1917,9 @@ class DecodeEngine:
                 self.steplog.event("page-free", n=n, page_kind=kind,
                                    free=w.alloc.free_count)
         self._free.append(slot)
+        if self._ahead is not None and self._ahead[0] == slot:
+            # Its chunk is in flight: nobody fetches what it samples.
+            self._ahead = (slot, None)
         # Park the freed slot at length 0 so idle slots don't walk their
         # cursor toward the capacity edge while others decode.
         self.cache["length"] = self.cache["length"].at[slot].set(0)
@@ -1919,6 +1987,14 @@ class DecodeEngine:
         chunk, advance every active slot one token. Returns the number
         of active slots stepped.
 
+        The host's order: reap, admit, the prefill tick, pages and view,
+        the decode's dispatch, the NEXT step's chunk behind it
+        (``_prefill_ahead``), and only then the wait for the decode's
+        ids and the per-slot emit and finish; so the device has a chunk
+        to run while the host does all of that for the next step. Every
+        decode's ids are read and every finish applied before the next
+        decode is dispatched.
+
         When the step recorder is on (``decode_step_timeline``), the
         step's phases (admission prefills, interleaved prefill chunk,
         decode) land as one ring row with batch occupancy — the "why
@@ -1977,6 +2053,13 @@ class DecodeEngine:
             batch=stepped, ctx_tokens=ctx, view_pages=rung,
             uploads=1 + (self._state_dev is None)
             + (self._temps_dev is None))
+        # The NEXT step's chunk goes behind the decode, before the host
+        # waits for the decode's ids.
+        t0, sent = time.time() if rec else 0.0, self.prefill_chunks_ahead
+        self._prefill_ahead()
+        if rec and self.prefill_chunks_ahead > sent:
+            phases.append({"phase": "prefill_chunk", "t0": t0,
+                           "t1": time.time()})
         if rec:
             sl.begin("fetch", program="decode", bytes=int(state.nbytes))
         got = np.array(state)  # ids, the model's counters, the counter
@@ -1987,7 +2070,7 @@ class DecodeEngine:
             # profiler trace they ride on the slice after the fetch.
             counted = {name: int(v) for name, v in zip(
                 self._step_stats, got[self.slots:])}
-            sl.amend("launch", **counted)
+            sl.amend("launch", "decode", **counted)
             sl.begin("sample_emit", **counted)
             phases.append({"phase": "decode", "t0": t_d0,
                            "t1": time.time(), "batch": stepped})
@@ -2209,6 +2292,7 @@ class DecodeEngine:
             "deadline_exceeded": self.deadline_exceeded,
             "preempted": self.preempted,
             "prefill_chunks": self.prefill_chunks,
+            "prefill_chunks_ahead": self.prefill_chunks_ahead,
             "prefill_backlog_tokens": backlog,
             # Decode backlog as replica load: occupied slots + pending
             # queue depth + prefill-backlog tokens (in chunk-steps). A

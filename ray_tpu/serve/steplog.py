@@ -24,7 +24,10 @@ idle gap of the device has an owner. Every slice is also entered as a
 the same slices lie on the profiler's clock beside the device
 operations (attributes such as ``program`` and ``tokens`` become the
 event's stats), and the jitted programs there are named
-``jit_engine_<program>``. A row also counts what the step worked on:
+``jit_engine_<program>``. One pair of annotations nests where the row's
+slices tile: the decode's ``engine:fetch`` opens round the
+``engine:launch`` of a chunk dispatched ahead of it (``enclose``). A row
+also counts what the step worked on:
 ``ctx_tokens`` (KV positions the decode really needs), ``view_pages``
 (pages wide the view it read: the rung of the engine's ladder) and
 ``pages_pinned`` (pages the prefix index holds).
@@ -57,7 +60,7 @@ class StepTimeline:
     from the actor RPC thread (rows are immutable once appended)."""
 
     __slots__ = ("capacity", "_rows", "_events", "dropped", "_slices",
-                 "_open", "_ann", "_annotate")
+                 "_open", "_ann", "_annotate", "_enclose", "_outer")
 
     def __init__(self, capacity: int = 256):
         self.capacity = max(0, int(capacity))
@@ -67,6 +70,8 @@ class StepTimeline:
         self._slices: List[Dict[str, Any]] = []  # the row being built
         self._open: Optional[Dict[str, Any]] = None  # slice being timed
         self._ann = None  # ... and its annotation on the profiler's clock
+        self._enclose = None  # (name, attrs) asked for by ``enclose``
+        self._outer = None    # (name, annotation) opened early for it
         self._annotate = None
         if self.capacity:
             # Only a recording engine pays the import; the timeline CLI
@@ -106,17 +111,35 @@ class StepTimeline:
         self._switch(name, now, attrs)
         return now
 
-    def amend(self, name: str, **attrs: Any) -> None:
-        """Add ``attrs`` to the newest slice ``name`` of the row being
-        built: what a launch counted is known only once its output is
-        fetched. The slice's annotation has closed by then, so the
+    def amend(self, name: str, program: str, **attrs: Any) -> None:
+        """Add ``attrs`` to the newest slice ``name`` of ``program`` in
+        the row being built: what a launch counted is known only once
+        its output is fetched (and another program's launch may lie in
+        between). The slice's annotation has closed by then, so the
         profiler's trace does not get them from here."""
         if not attrs:
             return
         for s in reversed(self._slices):
-            if s["name"] == name:
+            if s["name"] == name and s.get("program") == program:
                 s.update(attrs)
                 return
+
+    def enclose(self, name: str, **attrs: Any) -> None:
+        """The slice begun next lies, ON THE PROFILER'S CLOCK ONLY,
+        inside the annotation of the slice ``name`` that follows it:
+        that annotation opens (with ``attrs``) when the next slice
+        begins and is the one ``begin(name)`` goes on in. The row's
+        slices tile as ever.
+
+        For a dispatch whose program the device runs AFTER the one the
+        host is about to wait for (a prefill chunk sent ahead, behind a
+        decode whose ids are yet to be fetched): a reader of the trace
+        looks for a run on the device between its ``engine:launch`` and
+        the end of the first ``engine:fetch`` that starts after it
+        (``benchmarks/progtrace.py::launches``). With the decode's
+        ``engine:fetch`` open round the chunk's ``engine:launch`` that
+        is the next step's fetch, under which the chunk does end."""
+        self._enclose = (name, attrs)
 
     def _switch(self, name: str, now: float, attrs: Dict[str, Any]
                 ) -> None:
@@ -129,6 +152,17 @@ class StepTimeline:
             s.update(attrs)
         self._slices.append(s)
         self._open = s
+        outer, self._outer = self._outer, None
+        if outer is not None:
+            if outer[0] == name:
+                self._ann = outer[1]    # open already: go on in it
+                return
+            outer[1].__exit__(None, None, None)
+        ask, self._enclose = self._enclose, None
+        if ask is not None and ask[0] != name:
+            early = self._annotate("engine:" + ask[0], **ask[1])
+            early.__enter__()
+            self._outer = (ask[0], early)
         self._ann = ann = self._annotate("engine:" + name, **attrs)
         ann.__enter__()
 

@@ -9,6 +9,8 @@ CPU."""
 import numpy as np
 import pytest
 
+import chunk_ahead_cases as cases
+
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
 
@@ -205,6 +207,7 @@ def test_a_decode_step_between_two_chunks_leaves_the_state_bit_for_bit(
     b = eng.submit(long_, max_new_tokens=3)
     # One chunk a step is the engine's rule; hold every other one back so
     # that decode-only steps fall between two chunks of b's.
+    eng._prefill_ahead = lambda: None   # none behind a decode either
     tick, held = eng._prefill_tick, []
     eng._prefill_tick = lambda: held.append(1) if len(held) % 2 == 0 \
         else (held.append(1), tick())
@@ -249,3 +252,11 @@ def test_the_deployment_serves_it_through_the_one_engine():
         assert dep.engine._kind is None
     finally:
         dep.engine.shutdown()
+
+
+def test_greedy_streams_are_those_of_an_engine_that_stands_back(model):
+    """A chunk sent ahead of the fetch (PR 53) with nothing to allocate:
+    tests/chunk_ahead_cases.py on the model with no page kind (its
+    ``_free_lists_cover`` is always true; the seam stands it back)."""
+    cases.greedy_streams_are_those_of_an_engine_that_stands_back(
+        lambda **kw: (_engine(model, **kw), model[0]))

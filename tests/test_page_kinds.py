@@ -8,6 +8,8 @@ import hashlib
 import numpy as np
 import pytest
 
+import chunk_ahead_cases as cases
+
 from ray_tpu.serve.paging import WindowPages
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
@@ -411,3 +413,32 @@ def test_one_kind_models_lower_to_the_text_they_had(name):
     assert got == {k: v for k, v in LOWERED_AT_PARENT.items()
                    if k.startswith(name + ".")}, got
     eng.shutdown()
+
+
+# ------------------------------------------- a chunk sent ahead (PR 53)
+# The cases of tests/chunk_ahead_cases.py on a model with page kinds: a
+# window page is a page that can leak.
+
+
+def _make(**kw):
+    return _engine(**kw)
+
+
+def test_greedy_streams_are_those_of_an_engine_that_stands_back():
+    cases.greedy_streams_are_those_of_an_engine_that_stands_back(_make)
+
+
+def test_no_two_chunks_lie_between_two_decodes():
+    cases.no_two_chunks_lie_between_two_decodes(_make)
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline", "preempt",
+                                 "shutdown"])
+def test_a_request_that_goes_with_its_chunk_in_flight_leaks_nothing(how):
+    cases.a_request_that_goes_with_its_chunk_in_flight_leaks_nothing(
+        _make, how)
+
+
+def test_a_dry_window_kind_stands_the_ahead_tick_back():
+    cases.a_dry_free_list_stands_the_ahead_tick_back(
+        _make, lambda eng: eng._windows["window"].alloc)

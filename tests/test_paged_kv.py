@@ -3,7 +3,7 @@ programs against the plain reference at the model and engine layers
 (``tests/stream_reference.py`` states the tolerance), page-granular
 refcount/evict under the PR 3 cancel/deadline paths, overcommitted-pool
 concurrency (the >= 1.5x acceptance bar), recompute preemption, and the
-chunked-prefill no-starvation invariant (step-count based — the 1-core
+chunked-prefill no-starvation invariant (dispatch-order based — the 1-core
 CPU rig makes wall-clock invariants meaningless). All CPU, tiny
 configs — tier-1 safe."""
 
@@ -869,12 +869,15 @@ def test_paged_preemption_recovers_exact_streams():
 
 
 def test_chunked_prefill_never_starves_active_slots():
-    """The no-decode-starvation invariant, step-count based: while a
-    long prompt chunk-prefills, EVERY active slot emits a token on
-    every step that ran a chunk — a 4k-class admission can cost active
-    streams at most one chunk between tokens, never its whole prefill.
-    Un-chunked, the same admission stalls actives for the entire
-    monolithic prefill (one step)."""
+    """The no-decode-starvation invariant, by the ORDER OF DISPATCHES
+    (the order the device runs them in): while a long prompt
+    chunk-prefills, a decode lies between any two of its chunks, and
+    EVERY active slot emits a token on every step — a 4k-class admission
+    can cost active streams at most one chunk between tokens, never its
+    whole prefill. A host step may dispatch two chunks, its own and the
+    next step's ahead of the fetch (PR 53), never two with no decode
+    between them. Un-chunked, the same admission stalls actives for the
+    entire monolithic prefill (one step)."""
     from ray_tpu.serve.decode import DecodeEngine
 
     cfg, params = _tiny(max_seq_len=1024)
@@ -888,21 +891,28 @@ def test_chunked_prefill_never_starves_active_slots():
     long_req = eng.submit(
         rng.integers(0, cfg.vocab_size, 400).tolist(),  # 13 chunks
         max_new_tokens=2)
-    chunk_steps = 0
+    order = []
+    dispatch = eng._dispatch_fresh
+
+    def spy(key, call, then=None, **attrs):
+        order.append(attrs.get("program", key[0]))
+        return dispatch(key, call, then, **attrs)
+
+    eng._dispatch_fresh = spy
     while not long_req.done.is_set():
         before = [r.generated for r in actives]
         chunks_before = eng.prefill_chunks
-        eng.step()
-        if eng.prefill_chunks > chunks_before:
-            # A prefill chunk ran this step: the invariant is that the
-            # chunk count rose by AT MOST one and every active slot
-            # still emitted its token.
-            assert eng.prefill_chunks == chunks_before + 1
-            chunk_steps += 1
-            after = [r.generated for r in actives]
-            for b, a in zip(before, after):
-                assert a == b + 1, "active slot starved by a prefill"
-    assert chunk_steps >= 13  # the long prompt really was chunked
+        assert eng.step() >= 2
+        # Its own chunk (where none went ahead) and the next step's.
+        assert eng.prefill_chunks <= chunks_before + 2
+        for b, r in zip(before, actives):
+            assert r.generated == b + 1, "active slot starved by a prefill"
+    chunks = [i for i, p in enumerate(order) if p == "prefill_chunk"]
+    assert len(chunks) >= 13  # the long prompt really was chunked
+    for a, b in zip(chunks, chunks[1:]):
+        assert "decode" in order[a + 1:b], order[a:b + 1]
+    # All but the first went ahead of a fetch.
+    assert eng.prefill_chunks_ahead == eng.prefill_chunks - 1
     _drive(eng, actives + [long_req])
     # Interleaving preserved exactness for everyone.
     assert long_req.generated == 2

@@ -7,6 +7,8 @@ GMU-cross pair; window 12), on the CPU."""
 import numpy as np
 import pytest
 
+import chunk_ahead_cases as cases
+
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
 STATE = ("ssm", "conv")
@@ -105,6 +107,9 @@ def test_a_decode_step_between_two_chunks_leaves_the_state_bit_for_bit(
     eng.step()
     assert first.slot in eng._active
     second = eng.submit(long, max_new_tokens=4)
+    # No chunk goes ahead of a fetch here (PR 53): a decode step has to
+    # fall between two chunks, with none of them behind it.
+    eng._prefill_ahead = lambda: None
     eng.step()                       # seats it: chunk 1 and a decode step
     slot = second.slot
     assert slot in eng._prefilling and second.prefilled == 32
@@ -267,3 +272,11 @@ def test_the_rows_and_the_launches_carry_the_state(model):
     held = [r["state_bytes"] for r in rows if "state_bytes" in r]
     assert max(held) == 2 * eng._slot_state_bytes and held[-1] == 0
     eng.shutdown()
+
+
+def test_greedy_streams_are_those_of_an_engine_that_stands_back(model):
+    """A chunk sent ahead of the fetch (PR 53) hands its state to the next
+    as one at the tick's usual place does: tests/chunk_ahead_cases.py on a
+    model with slot state."""
+    cases.greedy_streams_are_those_of_an_engine_that_stands_back(
+        lambda **kw: (_engine(model, **kw), model[0]))

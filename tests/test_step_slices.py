@@ -454,6 +454,141 @@ def test_slices_are_annotations_on_the_profilers_clock(tmp_path):
                for line in p.lines for n in {e.name for e in line.events})
 
 
+# ------------------------------------------- a chunk sent ahead (PR 53)
+
+
+def test_a_step_that_dispatched_ahead_still_tiles():
+    """The chunk of the NEXT step is a ``launch`` slice with ``ahead=1``
+    between the decode's launch and its fetch (its preparation is
+    ``admit``, as at the tick's usual place); the row carries the
+    ``prefill_chunk`` phase; what the decode counted goes to the decode's
+    launch, not to the newest one."""
+    _, reqs, rows = _run("chunked_prefill")
+    went = [r for r in rows if any(s.get("ahead") for s in r["slices"])]
+    assert len(went) >= 4
+    for row in went:
+        names = [(s["name"], s.get("program")) for s in row["slices"]]
+        at = next(i for i, s in enumerate(row["slices"]) if s.get("ahead"))
+        assert names[at - 2:at + 2] == [
+            ("launch", "decode"), ("admit", None),
+            ("launch", "prefill_chunk"), ("fetch", "decode")]
+        ahead, fetch = row["slices"][at], row["slices"][at + 1]
+        assert ahead["ahead"] == 1 and ahead["tokens"] >= 1
+        assert ahead["t1"] == fetch["t0"] and fetch["bytes"] == 4 * 5
+        assert "uploads" in row["slices"][at - 2]
+        assert "uploads" not in ahead
+        body = [s for s in row["slices"] if s["name"] != "park"]
+        for a, b in zip(body, body[1:]):
+            assert a["t1"] == b["t0"]
+        assert body[0]["t0"] == row["t0"] and body[-1]["t1"] == row["t1"]
+        kinds = [p["phase"] for p in row["phases"]]
+        assert "prefill_chunk" in kinds and "decode" in kinds
+        for ph in row["phases"]:
+            assert row["t0"] <= ph["t0"] <= ph["t1"] <= row["t1"]
+    # A step that only met the chunk sent to it carries no chunk phase of
+    # its own unless it sent the next one.
+    for row in rows:
+        chunks = [s for s in row["slices"] if s["name"] == "launch"
+                  and s.get("program") == "prefill_chunk"]
+        assert len(chunks) == sum(p["phase"] == "prefill_chunk"
+                                  for p in row["phases"])
+
+
+def test_enclose_opens_the_next_slices_annotation_round_the_one_between():
+    """On the profiler's clock the decode's ``engine:fetch`` opens round
+    the ``engine:launch`` of a chunk sent ahead; the row's slices tile."""
+    from ray_tpu.serve.steplog import StepTimeline
+
+    log = []
+
+    class Ann:
+        def __init__(self, name, **attrs):
+            self.name, self.attrs = name, attrs
+
+        def __enter__(self):
+            log.append(("enter", self.name, self.attrs))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    sl = StepTimeline(8)
+    sl._annotate = Ann
+    t0 = sl.step_begin()
+    sl.begin("launch", program="decode")
+    sl.begin("admit")
+    sl.enclose("fetch", program="decode")
+    sl.begin("launch", program="prefill_chunk", ahead=1)
+    sl.begin("fetch", program="decode", bytes=20)
+    sl.begin("sample_emit")
+    sl.record(t0, time.time(), [], active=1, prefilling=1, queued=0)
+    assert [e[:2] for e in log] == [
+        ("enter", "engine:reap"), ("exit", "engine:reap"),
+        ("enter", "engine:launch"), ("exit", "engine:launch"),
+        ("enter", "engine:admit"), ("exit", "engine:admit"),
+        ("enter", "engine:fetch"), ("enter", "engine:launch"),
+        ("exit", "engine:launch"), ("exit", "engine:fetch"),
+        ("enter", "engine:sample_emit"), ("exit", "engine:sample_emit"),
+        ("enter", "engine:park")]
+    assert log[7][2] == {"program": "prefill_chunk", "ahead": 1}
+    row = sl.dump()["rows"][0]
+    assert [s["name"] for s in row["slices"]] == [
+        "reap", "launch", "admit", "launch", "fetch", "sample_emit"]
+    assert row["slices"][4]["bytes"] == 20
+    for a, b in zip(row["slices"], row["slices"][1:]):
+        assert a["t1"] == b["t0"]
+    # Asked for and not needed (the slice itself comes next), or another
+    # slice comes where the enclosed one was to: nothing is left open.
+    del log[:]
+    sl.step_begin()
+    sl.enclose("fetch")
+    sl.begin("fetch")
+    sl.enclose("fetch")
+    sl.begin("launch")
+    sl.begin("admit")
+    assert [e[:2] for e in log] == [
+        ("exit", "engine:park"), ("enter", "engine:reap"),
+        ("exit", "engine:reap"), ("enter", "engine:fetch"),
+        ("exit", "engine:fetch"), ("enter", "engine:fetch"),
+        ("enter", "engine:launch"), ("exit", "engine:launch"),
+        ("exit", "engine:fetch"), ("enter", "engine:admit")]
+
+
+def test_in_a_trace_the_decodes_fetch_lies_round_a_launch_sent_ahead(
+        tmp_path):
+    """Read back with JAX's own reader: every ``engine:launch`` that says
+    ``ahead`` lies inside an ``engine:fetch``, so the first fetch that
+    STARTS after it is the one its run on the device ends under (what
+    ``benchmarks/progtrace.py::launches`` brackets a run with)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    cfg, eng = _engine(prefill_chunk_tokens=32)
+    warm = [eng.submit(p, max_new_tokens=8) for p in _prompts(cfg, [9, 100])]
+    _drive(eng, warm)
+    jax.profiler.start_trace(str(tmp_path))
+    reqs = [eng.submit(p, max_new_tokens=8) for p in _prompts(cfg, [9, 100])]
+    _drive(eng, reqs)
+    jax.profiler.stop_trace()
+    eng.shutdown()
+    path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0]
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+              for p in ProfileData.from_file(path).planes
+              for line in p.lines for e in line.events
+              if e.name in ("engine:launch", "engine:fetch")]
+    ahead = [e for e in events if e[0] == "engine:launch"
+             and e[3].get("ahead")]
+    fetches = sorted(e for e in events if e[0] == "engine:fetch")
+    assert len(ahead) >= 2
+    for _, a, b, stats in ahead:
+        assert stats["program"] == "prefill_chunk"
+        round_it = [f for f in fetches if f[1] <= a and b <= f[2]]
+        assert len(round_it) == 1 and round_it[0][3]["program"] == "decode"
+        # No fetch starts between this launch and the end of that one.
+        assert not [f for f in fetches if a <= f[1] <= round_it[0][2]]
+
+
 # ------------------------------------------------------------------ names
 
 
