@@ -33,12 +33,13 @@ node) carry a pragma with the justification.
 ``ray_tpu.util.flightrec`` — any other ``record`` is never confused):
 one event name, one ATTR-KEY SCHEMA (``doctor.post_mortem`` merges
 events by name; a site recording the same name with different keys
-silently breaks every grouping — flagged as metrics-name-collision),
-and id-shaped attr values flagged as metrics-label-cardinality —
-bounded schedule ints (``rules.FLIGHTREC_BOUNDED_ATTRS``: step, mb,
-stage, epoch, …) are exempt, and genuinely-bounded subject ids (gang
-ids die with the gang) carry the same justification pragma as metric
-labels.
+silently breaks every grouping — flagged as metrics-name-collision;
+an event whose ``phase=`` is a literal, ``setup.phase``, has one schema
+a phase), and id-shaped attr values flagged as
+metrics-label-cardinality — bounded schedule ints
+(``rules.FLIGHTREC_BOUNDED_ATTRS``: step, mb, stage, epoch, …) are
+exempt, and genuinely-bounded subject ids (gang ids die with the gang)
+carry the same justification pragma as metric labels.
 """
 
 from __future__ import annotations
@@ -243,7 +244,15 @@ def _check_flightrec(project: Project, emit_files=None) -> List[Finding]:
                 return
             keys = tuple(sorted(kw.arg for kw in node.keywords
                                 if kw.arg is not None))
-            sites.setdefault(name_arg.value, []).append({
+            # An event that names its ``phase`` in a literal is one
+            # schema a PHASE (``setup.phase``: each phase of a start
+            # carries what its own site knows).
+            phase = next((kw.value.value for kw in node.keywords
+                          if kw.arg == "phase"
+                          and isinstance(kw.value, ast.Constant)), None)
+            event = (name_arg.value if phase is None
+                     else f"{name_arg.value}[{phase}]")
+            sites.setdefault(event, []).append({
                 "relpath": f.relpath, "line": node.lineno,
                 "symbol": qualname_of(stack), "keys": keys})
             if emit_files is not None and f.relpath not in emit_files:

@@ -13,6 +13,7 @@ from __future__ import annotations
 import atexit
 import inspect
 import os
+import time
 import uuid
 from typing import Any, Dict, Optional, Sequence
 
@@ -29,6 +30,7 @@ from ray_tpu.core.runtime import (
     is_initialized,  # noqa: F401
     set_core_worker,
 )
+from ray_tpu.util import flightrec
 
 _local_cluster = None  # (controller, node) started by init()
 _config_snapshot = None  # config state to restore on shutdown
@@ -50,6 +52,7 @@ def init(
     process never joins the cluster and needs one outbound connection only
     (reference: Ray Client, ``util/client/``)."""
     global _local_cluster
+    t_entry = time.time()
     if isinstance(address, str) and address.startswith("ray-tpu://"):
         from ray_tpu import client as client_mod
 
@@ -107,6 +110,9 @@ def init(
 
         _log_streamer = LogStreamer(core.controller)
     atexit.register(shutdown)
+    flightrec.record(
+        "setup.phase", phase="runtime_start", t0=t_entry, t1=time.time(),
+        chips=int(node_resources.get("TPU", 0)) if address is None else 0)
     return core
 
 

@@ -11,6 +11,7 @@ Here every flag is declared once in ``_FLAG_DEFS`` with a type and default;
 from __future__ import annotations
 
 import os
+import tempfile
 from typing import Any, Dict
 
 _FLAG_DEFS: Dict[str, tuple] = {
@@ -399,12 +400,15 @@ _FLAG_DEFS: Dict[str, tuple] = {
         "oldest evicted first). The ring records control-plane facts, "
         "not data-plane traffic — 512 covers minutes of gang/pipeline "
         "lifecycle at production cadences."),
-    "flightrec_dir": (str, f"/tmp/ray_tpu_flightrec_{os.getuid()}",
+    "flightrec_dir": (str, os.path.join(tempfile.gettempdir(),
+                                        f"ray_tpu_flightrec_{os.getuid()}"),
         "Per-HOST directory the flight recorder persists per-process "
         "rings into (fr-<pid>.json, atomic replace). fr_dump / doctor "
         "--post-mortem merge every file here; on multi-host rigs "
-        "collect each host's dir. Per-uid default so shared dev hosts "
-        "don't collide."),
+        "collect each host's dir. Under the process's temporary "
+        "directory (TMPDIR, else /tmp; workers inherit it), per uid, so "
+        "shared dev hosts don't collide and two runs given a TMPDIR "
+        "each never meet."),
     "flightrec_flush_s": (float, 0.5,
         "Period of the flight recorder's background flush to "
         "flightrec_dir while events keep arriving. A SIGKILL keeps "

@@ -103,6 +103,15 @@ def get_core_worker() -> "CoreWorker":
     return _core_worker
 
 
+def cluster_address() -> str:
+    """``host:port`` of this process's controller: what every process of
+    one cluster shares and no other cluster on the host has ("" outside a
+    cluster). The set-up record joins an asking process to its workers
+    on it."""
+    core = _core_worker
+    return "" if core is None else "%s:%d" % core.controller_addr
+
+
 def set_core_worker(worker: Optional["CoreWorker"]) -> None:
     global _core_worker
     with _core_worker_lock:

@@ -771,16 +771,52 @@ def post_mortem(dumps: Dict[str, Any],
     return findings
 
 
+def setup_phases(dumps: Dict[str, Any]) -> List[str]:
+    """One line a process that left ``setup.phase`` events (the set-up
+    record, docs/OBSERVABILITY.md "Set-up phases"): its phases in the
+    order they began with the host's seconds in each, the first
+    dispatches counted and summed, with the compiler's share of them. The
+    intervals as recorded: a ``warm_decode`` still holds the first
+    dispatches inside it. A replica's restart reads from here with no
+    benchmark at hand."""
+    lines = []
+    for source in sorted(dumps or {}):
+        events = sorted((e for e in dumps[source].get("events") or []
+                         if isinstance(e, dict)
+                         and e.get("ev") == "setup.phase"),
+                        key=lambda e: e["t0"])
+        if not events:
+            continue
+        firsts = [e for e in events if e["phase"] == "first_dispatch"]
+        parts = [e["phase"] if e["t1"] == e["t0"]
+                 else f"{e['phase']} {e['t1'] - e['t0']:.1f}s"
+                 + (f" (imports {e['import_s']:.1f}s)"
+                    if "import_s" in e else "")
+                 for e in events if e["phase"] != "first_dispatch"]
+        if firsts:
+            parts.append(
+                f"first_dispatch x{len(firsts)} "
+                f"{sum(e['t1'] - e['t0'] for e in firsts):.1f}s (compiler "
+                f"{sum(e['compile_s'] for e in firsts):.1f}s, "
+                f"{sum(e['cache_hits'] for e in firsts)}/"
+                f"{sum(e['compiles'] for e in firsts)} from the cache)")
+        started = time.strftime("%H:%M:%S", time.localtime(events[0]["t0"]))
+        lines.append(f"  {source} from {started}: " + ", ".join(parts))
+    return lines
+
+
 def render_post_mortem(findings: List[Dict[str, Any]],
                        dumps: Dict[str, Any]) -> str:
     head = (f"post-mortem over {len(dumps)} recorder dump(s), "
             f"{sum(len(d.get('events') or []) for d in dumps.values())} "
             f"events")
+    setup = setup_phases(dumps)
+    tail = "\n".join(["", "set-up on record:"] + setup) if setup else ""
     if not findings:
         return (f"{head}\nno deaths or stalls on record (checked: "
                 f"gang-death, stage-clock-stop, double-apply-guard, "
-                f"fault-injection)")
-    return f"{head}\n{render(findings)}"
+                f"fault-injection){tail}")
+    return f"{head}\n{render(findings)}{tail}"
 
 
 def collect(client, interval_s: float = 2.0

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import math
 import re
+import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -34,6 +35,7 @@ from ray_tpu.parallel.sharding import (
     spec_for,
     tree_shardings,
 )
+from ray_tpu.util import flightrec
 
 
 def init_sharded_params(init_fn: Callable[[jax.Array], Any],
@@ -41,10 +43,21 @@ def init_sharded_params(init_fn: Callable[[jax.Array], Any],
                         rules: Optional[Rules] = None):
     """Run ``init_fn(key)`` with outputs materialized under the mesh's param
     shardings — each device only ever holds its shard."""
+    t0 = time.time()
     shardings = tree_shardings(mesh, axes_tree, rules)
     with jax.transfer_guard("allow"):
         init = jax.jit(init_fn, out_shardings=shardings)
-        return init(key)
+        params = init(key)
+    _record_weights(t0, params)
+    return params
+
+
+def _record_weights(t0: float, tree) -> None:
+    """The set-up record's ``weights`` phase: the host's time since ``t0``
+    to build and dispatch ``tree``'s initialisation (the device may still
+    be filling it), and the bytes it will hold."""
+    flightrec.record("setup.phase", phase="weights", t0=t0, t1=time.time(),
+                     bytes=sum(x.nbytes for x in jax.tree.leaves(tree)))
 
 
 def init_optimizer_state(optimizer: optax.GradientTransformation, params):
@@ -56,9 +69,16 @@ def init_optimizer_state(optimizer: optax.GradientTransformation, params):
     has nothing to propagate from and an unpinned jit leaves the WHOLE
     state on device 0 (seen on a four-chip v5e: device-0 peak 16.4 GB for
     a 1.2B model, 3.5 GB on the others)."""
+    t0 = time.time()
+    state = _optimizer_init(optimizer, params)(params)
+    _record_weights(t0, state)
+    return state
+
+
+def _optimizer_init(optimizer: optax.GradientTransformation, params):
     sharding = jax.tree.leaves(params)[0].sharding
     if not isinstance(sharding, NamedSharding):
-        return jax.jit(optimizer.init)(params)
+        return jax.jit(optimizer.init)
     param_shardings = jax.tree.map(lambda p: p.sharding, params)
     params_def = jax.tree.structure(params)
     replicated_sh = NamedSharding(sharding.mesh, P())
@@ -69,7 +89,7 @@ def init_optimizer_state(optimizer: optax.GradientTransformation, params):
     shardings = jax.tree.map(
         lambda node: param_shardings if like_params(node) else replicated_sh,
         jax.eval_shape(optimizer.init, params), is_leaf=like_params)
-    return jax.jit(optimizer.init, out_shardings=shardings)(params)
+    return jax.jit(optimizer.init, out_shardings=shardings)
 
 
 def _without(spec: P, axes) -> P:

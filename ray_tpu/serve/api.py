@@ -16,9 +16,10 @@ import time
 from typing import Any, Dict, Optional, Tuple
 
 import ray_tpu
-from ray_tpu.core import serialization
+from ray_tpu.core import runtime, serialization
 from ray_tpu.serve.controller import get_or_create_controller
 from ray_tpu.serve.deployment import Deployment, DeploymentHandle, _Router
+from ray_tpu.util import flightrec
 
 
 def run(app: Deployment, name: Optional[str] = None,
@@ -29,6 +30,10 @@ def run(app: Deployment, name: Optional[str] = None,
 
     _usage.record_feature("serve.run")
     name = name or app.name
+    asked = time.time()
+    flightrec.record("setup.phase", phase="placement.begin", t0=asked,
+                     t1=asked, name=name,
+                     cluster=runtime.cluster_address())
     controller = get_or_create_controller()
     version = ray_tpu.get(controller.deploy.remote(
         name, serialization.dumps_function(app.cls), app._init_args,

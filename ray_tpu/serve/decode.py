@@ -41,6 +41,7 @@ import numpy as np
 
 from ray_tpu.core.errors import (DeadlineExceededError, OverloadedError,
                                  RequestCancelledError)
+from ray_tpu.util import flightrec
 
 logger = logging.getLogger(__name__)
 
@@ -172,6 +173,7 @@ class DecodeEngine:
                  trace_spans: Optional[bool] = None,
                  metrics_deployment: Optional[str] = None,
                  model=None):
+        t_entry = time.time()
         import jax
 
         from ray_tpu.core.config import config as rt_config
@@ -549,6 +551,10 @@ class DecodeEngine:
         self.handoffs_adopted = 0    # adopted seats completed
         self._handoff_phases: List[Dict[str, Any]] = []  # pending steplog
         #   phase rows, drained into the next _steplog_row
+        flightrec.record(
+            "setup.phase", phase="engine_build", t0=t_entry, t1=time.time(),
+            slots=slots, pool_bytes=sum(
+                x.nbytes for x in jax.tree.leaves(self.cache)))
 
     @staticmethod
     def _program(name: str, impl, **jit_kwargs):
@@ -680,9 +686,20 @@ class DecodeEngine:
         one program serves two (``prefill_chunk``) — and ``attrs``. A
         dispatch whose output nobody fetches names in ``then`` the
         slice the step goes on with."""
-        self._mark_compile(key)
+        # ``call()`` is made from THIS frame, first dispatch or not: the
+        # Python stack under a program is part of its lowered text's
+        # locations, so a frame between here and the program gives every
+        # program another compile-cache key and a slower lowering (mixed:
+        # +0.46 s a prefill key on a v5e, PERF.md section 6, PR 55).
+        fresh = key not in self._compiled
+        if fresh:
+            self._compiled.add(key)
+            before, t0 = self._compile_watch.snapshot(), time.time()
         if not self.steplog.enabled:
-            return call()
+            out = call()
+            if fresh:
+                self._first_dispatched(key, t0, before)
+            return out
         attrs.setdefault("program", key[0])
         if "view_pages" in attrs:
             # Of the rung's rows those that hold a page of a stepping
@@ -703,6 +720,8 @@ class DecodeEngine:
                 for w in self._windows.values())
         self.steplog.begin("launch", **attrs)
         out = call()
+        if fresh:
+            self._first_dispatched(key, t0, before)
         if then is not None:
             self.steplog.begin(then)
         return out
@@ -1154,14 +1173,26 @@ class DecodeEngine:
                 tracing.record_span(name, t0_wall, t1, ctx=req.trace,
                                     request=req.request_id, **attrs)
 
-    def _mark_compile(self, key: tuple) -> None:
+    def _first_dispatched(self, key: tuple, t0: float, before) -> None:
         """First dispatch of a program key = a jit compile on this
-        engine; later dispatches of the same key are cache hits."""
-        if key not in self._compiled:
-            self._compiled.add(key)
-            if self.steplog.enabled:
-                self.steplog.event("jit-compile", key="/".join(
-                    str(k) for k in key))
+        engine; later dispatches of the same key are cache hits.
+        ``_dispatch_fresh``, the one place that knows, calls this when
+        the program's ``call()`` has returned: it leaves the set-up
+        record's ``first_dispatch`` phase and the step log's
+        ``jit-compile`` event. The interval is the HOST's, round
+        ``call()`` as it stands (trace, lower, compile or load from the
+        cache, enqueue): the program's run is waited for by whoever next
+        fetches. ``before``: the compile counters at ``t0``."""
+        t1 = time.time()
+        after = self._compile_watch.snapshot()
+        name = "/".join(str(k) for k in key)
+        flightrec.record(
+            "setup.phase", phase="first_dispatch", t0=t0, t1=t1, key=name,
+            compiles=after["compiles"] - before["compiles"],
+            compile_s=round(after["compile_s"] - before["compile_s"], 3),
+            cache_hits=after["cache_hits"] - before["cache_hits"])
+        if self.steplog.enabled:
+            self.steplog.event("jit-compile", key=name, dt=t1 - t0)
 
     def post_event(self, kind: str, **attrs: Any) -> None:
         """A step-log event from ANY thread: it rides on the row of the
@@ -2175,13 +2206,18 @@ class DecodeEngine:
         traffic, but the ladder is fixed by the engine's geometry, and
         whatever builds an engine for traffic calls this first. On an
         idle engine: the view is empty, so every write goes to the
-        scratch page, and the cursors are parked at 0 afterwards."""
+        scratch page, and the cursors are parked at 0 afterwards. Nothing
+        here waits for a rung to have run: the phase ``warm_decode`` of
+        the set-up record is the host's time to dispatch them."""
+        t0 = time.time()
         for rung, view in self._empty_views():
             _, self.cache = self._dispatch_fresh(
                 ("decode", rung),
                 lambda: self._decode(self.params, self.cache,
                                      *self._decode_inputs(view)))
         self.cache["length"] = self.cache["length"].at[:].set(0)
+        flightrec.record("setup.phase", phase="warm_decode", t0=t0,
+                         t1=time.time(), rungs=len(self._view_ladder))
 
     def _empty_views(self):
         """``(rung, view)`` up the ladder, each view listing no page."""
@@ -2438,20 +2474,27 @@ class LlamaDecodeDeployment:
                  kv_pool_pages: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
                  mesh_shape=None):
+        t_entry = time.time()
         import jax
 
+        from ray_tpu import tpu
         from ray_tpu.util.compile_cache import compile_watch
 
         llama, ld = self.model_modules()
-        compile_watch()  # count the replica's compiles from its first one
+        watch = compile_watch()  # counts the replica's compiles from its first
+        tpu.init_devices(since=t_entry)
         cfg = config or llama.PRESETS[preset]
         self.cfg = cfg
         self._sub_slice: Optional[Dict[str, Any]] = None
         # A replica never updates its weights, so the float32 masters
         # serve nothing here: this process owns them, and each leaves the
         # device as soon as its compute-dtype copy exists.
+        t0 = time.time()
         params = ld.compute_weights(
             llama.init_params(cfg, jax.random.key(seed)), cfg, donate=True)
+        flightrec.record("setup.phase", phase="weights", t0=t0,
+                         t1=time.time(), bytes=sum(
+                             w.nbytes for w in jax.tree.leaves(params)))
         self.engine = DecodeEngine(
             params, cfg, slots=slots, capacity=capacity,
             prefix_pool_entries=prefix_pool_entries,
@@ -2472,6 +2515,11 @@ class LlamaDecodeDeployment:
         self._thread = threading.Thread(target=self.engine.serve_forever,
                                         name="decode-loop", daemon=True)
         self._thread.start()
+        now, snap = time.time(), watch.snapshot()
+        flightrec.record("setup.phase", phase="ready", t0=now, t1=now,
+                         compiles=snap["compiles"],
+                         compile_s=snap["compile_s"],
+                         cache_hits=snap["cache_hits"])
 
     def set_topology(self, assignment: Dict[str, Any]) -> None:
         """Sub-slice assignment pushed by the serve controller after it

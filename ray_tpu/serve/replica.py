@@ -14,7 +14,9 @@ import time
 from collections import OrderedDict, deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ray_tpu.core import runtime
 from ray_tpu.core.errors import RequestCancelledError
+from ray_tpu.util import flightrec
 
 logger = logging.getLogger(__name__)
 
@@ -357,6 +359,12 @@ class ReplicaActor:
             _replica_ident["replica_id"] = replica_id
             _replica_ident["deployment"] = replica_id.rsplit("#", 1)[0]
         cls = serialization.loads_function(cls_blob)
+        # Where placement ends: a worker holds the lease and the class,
+        # and what the class's ``__init__`` does has phases of its own.
+        placed = time.time()
+        flightrec.record("setup.phase", phase="placement.end", t0=placed,
+                         t1=placed, name=_replica_ident["deployment"],
+                         cluster=runtime.cluster_address())
         self._instance = cls(*args, **kwargs)
         self._sub_slice: Optional[Dict[str, Any]] = None
         self._ongoing = 0

@@ -19,6 +19,7 @@ worker to the CPU platform (``core/node.py::_spawn_env``).
 from __future__ import annotations
 
 import glob
+import logging
 import os
 import subprocess
 import sys
@@ -26,6 +27,9 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ray_tpu.core.errors import RayTpuError
+from ray_tpu.util import flightrec
+
+logger = logging.getLogger(__name__)
 
 # chips per TPU-VM host for common generations (v4/v5p: 4 chips/host;
 # v5e/v6e: up to 8 chips/host depending on slice shape).
@@ -90,8 +94,9 @@ def detect_chip_count(timeout_s: float = 120.0) -> Tuple[int, Optional[str]]:
     # started right after another's exit died here, PERF.md section 7):
     # such a probe is tried again every ``_BUSY_RETRY_S`` inside the same
     # ``timeout_s``; any other failure is final at once.
-    t0 = time.monotonic()
+    t0, wall0 = time.monotonic(), time.time()
     tries = 0
+    busy_wait_s = 0.0
     while True:
         left = timeout_s - (time.monotonic() - t0)
         try:
@@ -109,7 +114,11 @@ def detect_chip_count(timeout_s: float = 120.0) -> Tuple[int, Optional[str]]:
             break
         waited = time.monotonic() - t0
         if _BUSY in out.stderr and waited + _BUSY_RETRY_S < timeout_s:
+            logger.info("TPU probe: device busy at try %d, %.1f s waited so "
+                        "far; next try in %.0f s", tries, waited,
+                        _BUSY_RETRY_S)
             time.sleep(_BUSY_RETRY_S)
+            busy_wait_s += _BUSY_RETRY_S
             continue
         hint = ""
         if "lockfile" in out.stderr or "already in use" in out.stderr:
@@ -124,11 +133,31 @@ def detect_chip_count(timeout_s: float = 120.0) -> Tuple[int, Optional[str]]:
             f"TPU probe failed (exit {out.returncode}) on a machine with "
             f"accelerator device files {dev_files}{hint}; stderr tail:\n"
             f"{_tail(out.stderr)}")
+    flightrec.record("setup.phase", phase="probe", t0=wall0, t1=time.time(),
+                     tries=tries, busy_wait_s=busy_wait_s)
     return int(out.stdout.strip()), pod_type
 
 
 _BUSY = "Device or resource busy"
 _BUSY_RETRY_S = 2.0
+
+
+def init_devices(since: float) -> list:
+    """``jax.devices()`` of a worker that holds chips, as the
+    ``device_init`` phase of the set-up record (docs/OBSERVABILITY.md,
+    "Set-up phases"): the process's backend comes up here, by name, and
+    not inside whichever operation would have met it first. ``since`` is
+    when the caller began importing what it runs (``time.time()``); the
+    phase starts there, and ``import_s`` is its share before this call."""
+    import jax
+
+    t_open = time.time()
+    devices = jax.devices()
+    flightrec.record("setup.phase", phase="device_init", t0=since,
+                     t1=time.time(), import_s=t_open - since,
+                     platform=devices[0].platform,
+                     device_count=len(devices))
+    return devices
 
 
 def _tail(text, limit: int = 1500) -> str:

@@ -19,6 +19,9 @@ class ScalingConfig:
     num_workers: int = 1
     resources_per_worker: Dict[str, float] = field(
         default_factory=lambda: {"CPU": 1.0})
+    # A worker whose lease names chips opens them BEFORE the loop function
+    # runs (``TrainWorker.start``, the set-up record's ``device_init``):
+    # what must precede the backend goes in the worker's ``runtime_env``.
     use_tpu: bool = False
     tpu_chips_per_worker: int = 0
     placement_strategy: str = "PACK"

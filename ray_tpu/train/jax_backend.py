@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import os
 import socket
+import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -183,6 +184,7 @@ def init_process(
 ) -> int:
     """Initialize this process's slice of the global JAX runtime. Returns
     the global device count. Idempotent per process."""
+    t_entry = time.time()
     _filter_native_output()
     if local_device_count:
         flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
@@ -209,7 +211,9 @@ def init_process(
             num_processes=num_processes,
             process_id=process_id,
         )
-    return len(jax.devices())
+    from ray_tpu import tpu
+
+    return len(tpu.init_devices(since=t_entry))
 
 
 def shutdown_process() -> None:

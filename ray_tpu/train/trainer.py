@@ -17,15 +17,18 @@ trainers instead.
 
 from __future__ import annotations
 
+import time
 import uuid
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.core import runtime
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train.worker_group import (GangReservationError, WorkerGroup,
                                         launch_gang)
+from ray_tpu.util import flightrec
 
 
 @dataclass
@@ -78,6 +81,10 @@ class JaxTrainer:
         from ray_tpu import usage as _usage
 
         _usage.record_feature("train.JaxTrainer")
+        asked = time.time()
+        flightrec.record("setup.phase", phase="placement.begin", t0=asked,
+                         t1=asked, name=self._name,
+                         cluster=runtime.cluster_address())
         max_failures = self.run_config.failure_config.max_failures
         attempts = 0
         latest_checkpoint: Optional[str] = None

@@ -11,17 +11,19 @@ results back to the driver by polling.
 from __future__ import annotations
 
 import threading
+import time
 import traceback
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
-from ray_tpu.core import serialization
+from ray_tpu.core import runtime, serialization
 from ray_tpu.core.placement import (
     PlacementGroup,
     PlacementGroupSchedulingStrategy,
     placement_group,
     remove_placement_group,
 )
+from ray_tpu.util import flightrec
 
 
 class GangReservationError(ray_tpu.RayTpuError):
@@ -44,7 +46,18 @@ class TrainWorker:
         init_session(self._session)
         self._thread: Optional[threading.Thread] = None
 
-    def start(self, fn_blob: bytes, config: Optional[Dict]) -> bool:
+    def start(self, fn_blob: bytes, config: Optional[Dict],
+              holds_chips: bool = False) -> bool:
+        """Run the loop function in its thread. ``holds_chips``: the
+        group's lease names chips (a worker cannot read its own: a
+        whole-host lease sets nothing in its environment). Such a worker
+        OPENS ITS CHIPS BEFORE THE LOOP FUNCTION RUNS, as the set-up
+        record's ``device_init`` phase: the loop's first JAX operation
+        would have opened them, so a loop finds the backend up at its
+        entry. What must precede that (``XLA_FLAGS``,
+        ``LIBTPU_INIT_ARGS``) belongs in the worker's ``runtime_env``,
+        and ``jax.distributed`` to ``JaxConfig(distributed=True)``, which
+        forms the runtime before this call."""
         from ray_tpu.train.session import init_session
 
         fn = serialization.loads_function(fn_blob)
@@ -52,7 +65,16 @@ class TrainWorker:
 
         def runner():
             init_session(session)  # session is thread-local; bind in-thread
+            placed = time.time()
+            flightrec.record("setup.phase", phase="placement.end",
+                             t0=placed, t1=placed,
+                             name=session.experiment_name,
+                             cluster=runtime.cluster_address())
             try:
+                if holds_chips:
+                    from ray_tpu import tpu
+
+                    tpu.init_devices(since=placed)
                 if config is None:
                     fn()
                 else:
@@ -241,7 +263,9 @@ class WorkerGroup:
             fn_blob: Optional[bytes] = None) -> None:
         if fn_blob is None:
             fn_blob = serialization.dumps_function(train_fn)
-        ray_tpu.get([w.start.remote(fn_blob, config) for w in self.workers])
+        holds_chips = bool(self.resources.get("TPU"))
+        ray_tpu.get([w.start.remote(fn_blob, config, holds_chips)
+                     for w in self.workers])
 
     def shutdown(self) -> None:
         self._leave_jax_distributed()
