@@ -34,6 +34,17 @@ reach (``flat_ops_share_of_peak_pct``): near 100 the flat-lane form is
 compute-bound and a grouped-head variant would pay; well under it the
 bytes bound the kernel.
 
+``--model deepseek`` times the ONE-POOL form at DeepSeek-V2's widths
+(``deepseek-v2.longdocs_batch``: 26 of 32 slots at 2k-14k tokens of
+context on the 4,096-row rung, 128 absorbed query rows over the 640 lanes
+of a latent row that is key and value at once, values its first 512), in
+the third layer of the flat pool of five, against the gather-then-attend
+it replaced (``models/deepseek_decode.py`` before PR 56: a copy of the
+rung's rows of pages, then XLA's einsums over it). ``share_of_hbm_pct``
+credits 576 x 2 B a token a query sees, the benchmark's count
+(``benchmarks/deepseek_counts.py``); ``flat_ops_share_of_peak_pct`` is 2 x
+128 x (640 + 512) a FETCHED token over the bf16 peak.
+
 ``--chunk`` times the PREFILL chunk's kernel instead,
 ``ray_tpu/ops/chunk_attention.py``, alone at the three served models'
 shapes (``--model cohere2|mimo|phi4flash|all``): Command A+'s 2,048
@@ -54,6 +65,7 @@ choose from the shape (a sweep); rows go to
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import time
@@ -121,10 +133,11 @@ def _shared_case(rng):
             view[2].reshape(-1, G), pos)
 
 
-def _seen(owner, index, pos, window):
-    """(n, G, T) bool on the host: the op's validity rule."""
-    tok = index[:, :, None] * T + np.arange(T)[None, None, :]
-    at = pos[np.maximum(owner, 0)][:, None, None]
+def _seen(owner, index, pos, window, xp=np):
+    """(n, G, T) bool: the op's validity rule, on the host or (``xp`` =
+    ``jnp``) on traced arrays."""
+    tok = index[:, :, None] * T + xp.arange(T)[None, None, :]
+    at = pos[xp.maximum(owner, 0)][:, None, None]
     seen = (owner >= 0)[:, None, None] & (index >= 0)[:, :, None] \
         & (tok <= at)
     if window is not None:
@@ -379,6 +392,143 @@ def cohere2(args, emit):
                                  f"from plain float32 attention")
 
 
+def deepseek(args, emit, slots=32, live=26, rung=4096, pool_pages=4097,
+             context=(2048, 14336)):
+    """The one-pool form at DeepSeek-V2's widths against the parent's
+    gather-then-attend (module docstring); the keywords are the cell's
+    layout."""
+    from ray_tpu.models import deepseek as ds
+
+    c = ds.DeepseekConfig()
+    dtype = jnp.dtype(args.dtype)
+    rng = np.random.default_rng(0)
+    keys = jax.random.split(jax.random.key(0), 2)
+    layer = 2
+    H, W, R = c.n_heads, c.latent_row, c.kv_lora_rank
+    pos = np.zeros(slots, np.int32)
+    pos[rng.permutation(slots)[:live]] = rng.integers(*context, size=live)
+    counts = np.where(pos > 0, pos // T + 1, 0)
+    table = np.zeros((slots, int(counts.max())), np.int32)
+    free = rng.permutation(np.arange(1, pool_pages))
+    at = 0
+    for s_ in range(slots):
+        table[s_, :counts[s_]] = free[at:at + counts[s_]]
+        at += counts[s_]
+    view = moe_decode.live_page_view(table, counts, rung)
+    lists, owner, index = (view[0].reshape(-1, G),
+                           view[1].reshape(-1, G)[:, 0],
+                           view[2].reshape(-1, G))
+    seen = _seen(owner, index, pos, None)
+    tokens, fetched = int(seen.sum()), int(seen.any(2).sum()) * T
+    # Rows as the model caches them: zeros past the 576 numbers in use.
+    @functools.partial(jax.jit, static_argnums=(1, 2))
+    def draw(key, *shape):
+        x = jax.random.normal(key, shape + (W,), jnp.float32)
+        return jnp.where(jnp.arange(W) < c.latent_dim, x, 0.0).astype(dtype)
+
+    pool = draw(keys[1], 5 * pool_pages, T)
+    q = draw(keys[0], slots, H)
+    base = jnp.asarray(layer * pool_pages, jnp.int32)
+    dev = [jnp.asarray(a) for a in (lists, owner, index, pos)]
+    scale = c.softmax_scale
+
+    def kernel(q, pool, base, lists, owner, index, pos):
+        _, total, acc = pda.paged_decode_attention(
+            q, pool, None, pda.page_lists(lists, owner, index, pos, T),
+            scale, base, value_width=R)
+        total = total[..., None]
+        return jnp.where(total > 0, acc / total, 0.0)
+
+    def gather(pool, base, lists):
+        return pool[base + lists.reshape(-1)]                # (N, T, W)
+
+    def attend(q, lat, owner, index, pos):
+        """``paged_decode_step``'s ``attend`` at the parent of PR 56."""
+        n, B = owner.shape[0], q.shape[0]
+        valid = _seen(owner, index, pos, None, jnp).reshape(n, 1, G * T)
+        of_group = jnp.maximum(owner, 0)
+        mine = owner[None, :] == jnp.arange(B)[:, None]
+        high = jax.lax.Precision.HIGHEST
+        lat = lat.reshape(n, G * T, W)
+        s = jnp.einsum("ghr,gkr->ghk", q[of_group], lat,
+                       preferred_element_type=jnp.float32)
+        s = jnp.where(valid, s * scale, -1e30)
+        top = jnp.max(jnp.where(mine[:, :, None], s.max(-1)[None], -1e30),
+                      axis=1)
+        e = jnp.where(valid, jnp.exp(s - top[of_group][..., None]), 0.0)
+        part = jnp.einsum("ghk,gkr->ghr", e.astype(lat.dtype),
+                          lat[..., :R], preferred_element_type=jnp.float32)
+        adds = mine.astype(jnp.float32)
+        total = jnp.einsum("bg,gh->bh", adds, e.sum(-1), precision=high)
+        acc = jnp.einsum("bg,ghr->bhr", adds, part, precision=high)
+        return acc / jnp.where(total > 0.0, total, 1.0)[..., None]
+
+    def xla(q, pool, base, lists, owner, index, pos):
+        return attend(q, gather(pool, base, lists), owner, index, pos)
+
+    base_row = {"model": "deepseek", "shape": "latent", "slots": live,
+                "lists": len(owner), "live_pages": int(seen.any(2).sum()),
+                "tokens_seen": tokens, "dtype": dtype.name}
+    useful = tokens * c.latent_dim * dtype.itemsize
+    flat_ops = 2.0 * H * (W + R) * fetched
+
+    def row(variant, ms, **more):
+        emit({**base_row, "variant": variant, "ms": round(ms, 4),
+              "share_of_hbm_pct": round(100 * useful / (ms / 1e3) / HBM, 2),
+              **more})
+
+    fn = jax.jit(kernel)      # a list is scored whole: no ``--blocks``
+    ms = _time(fn, (q, pool, base, *dev), args.calls)
+    row("kernel, one pool", ms, flat_ops_share_of_peak_pct=round(
+        100 * flat_ops / (ms / 1e3) / PEAK, 2))
+    got = {"kernel": fn(q, pool, base, *dev)}
+    lat = jax.jit(gather)(pool, base, dev[0])
+    t_g = _time(jax.jit(gather), (pool, base, dev[0]), args.calls)
+    t_a = _time(jax.jit(attend), (q, lat, *dev[1:]), args.calls)
+    del lat
+    row("xla gather (a layer)", t_g)
+    row("xla attend (a layer)", t_a)
+    row("xla gather + attend, one program",
+        _time(jax.jit(xla), (q, pool, base, *dev), args.calls))
+    got["xla"] = jax.jit(xla)(q, pool, base, *dev)
+    if args.check:
+        want = _latent_reference(c, q, pool, int(base), lists, owner, seen,
+                                 scale)
+        top = float(jnp.abs(want).max())
+        for who, out in got.items():
+            err = float(jnp.abs(out - want).max())
+            emit({"model": "deepseek", "check": who, "max_abs_err": err,
+                  "largest_value": top, "ok": err <= 2e-2 * top})
+            if err > 2e-2 * top:
+                raise SystemExit(f"deepseek: {who} is {err} from plain "
+                                 f"float32 attention (largest {top})")
+
+
+def _latent_reference(c, q, pool, base, lists, owner, seen, scale):
+    """Plain float32 attention of every slot's 128 absorbed queries over
+    the latent rows it sees, a slot at a time on the device: (B, H, R)."""
+    high = jax.lax.Precision.HIGHEST
+
+    @jax.jit
+    def slot(qb, rows, ok):
+        rows = rows.reshape(-1, rows.shape[-1]).astype(jnp.float32)
+        s = jnp.einsum("hw,tw->ht", qb.astype(jnp.float32), rows,
+                       precision=high) * scale
+        p = jax.nn.softmax(jnp.where(ok[None, :], s, -jnp.inf), axis=-1)
+        return jnp.einsum("ht,tr->hr", p, rows[:, :c.kv_lora_rank],
+                          precision=high)
+
+    out = []
+    for b in range(q.shape[0]):
+        mine = np.nonzero(owner == b)[0]
+        if not len(mine):
+            out.append(jnp.zeros((q.shape[1], c.kv_lora_rank), jnp.float32))
+            continue
+        out.append(slot(q[b], pool[base + lists[mine].reshape(-1)],
+                        jnp.asarray(seen[mine].reshape(-1))))
+    return jnp.stack(out)
+
+
 PEAK = 197e12
 # (model, variant, heads, key heads, D, Dv, window, sink, queries,
 #  [(prefix, keys handed in, their first position)])
@@ -532,7 +682,8 @@ def chunk(args, emit):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="phi4flash",
-                    choices=("phi4flash", "cohere2", "mimo", "all"))
+                    choices=("phi4flash", "cohere2", "deepseek", "mimo",
+                             "all"))
     ap.add_argument("--chunk", action="store_true",
                     help="time ops/chunk_attention.py, the prefill's kernel")
     ap.add_argument("--against", action="append", default=[],
@@ -576,8 +727,11 @@ def main():
     if args.chunk:
         chunk(args, emit)
         return
-    if args.model not in ("phi4flash", "cohere2"):
+    if args.model not in ("phi4flash", "cohere2", "deepseek"):
         raise SystemExit("--model mimo / all go with --chunk")
+    if args.model == "deepseek":
+        deepseek(args, emit)
+        return
     if args.model == "cohere2":
         for b in blocks:
             pda.BLOCK_PAGES = b

@@ -24,11 +24,16 @@ One attention, two formulations of the same mathematics:
   heads x 2,048 x 16,384 float32 would be 17 GB), which skips the tiles
   above a row's causal frontier.
 * **decode** (``paged_decode_step``) absorbs ``W_UK`` into the query and
-  ``W_UV`` into the output and attends over the latent rows themselves:
-  ``score = (W_UK^T q_nope) . c_kv + q_pe . k_pe``, ``o_h = W_UV,h (P
-  c_kv)``. The view lists a slot's pages in whole groups of
-  ``VIEW_GROUP``, so a group's scores and its part of the output are one
-  matmul each against one slot's queries.
+  ``W_UV`` into the output and attends over the latent rows themselves,
+  WHERE THEY LIE: ``score = (W_UK^T q_nope) . c_kv + q_pe . k_pe``, ``o_h
+  = W_UV,h (P c_kv)``. The view lists a slot's pages in whole groups of
+  ``VIEW_GROUP``, which are the lists ``ops/paged_decode_attention.py``
+  takes: its one-pool form is handed the flat latent leaf with the
+  layer's offset and a slot's 128 absorbed queries over all 640 lanes of a
+  row, copies each live page once into VMEM, scores the row and weighs its
+  first ``kv_lora_rank`` lanes, and adds a slot's groups up. No program
+  copies the view's pages out of the pool (before PR 56 every layer did,
+  335 MB at the served size).
 
 The layers ride two ``scan``s (the leading dense ones, then the expert
 ones) with the flat pool in the carry, so every program writes its new
@@ -59,6 +64,8 @@ from ray_tpu.models.llama_decode import (cache_bucket,  # noqa: F401
 from ray_tpu.ops import moe
 from ray_tpu.ops.latent_attention import latent_prefill_attention
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.paged_decode_attention import (page_lists,
+                                                paged_decode_attention)
 from ray_tpu.ops.rotary import rope_at, rotate_pairs
 from ray_tpu.parallel.sharding import constrain
 
@@ -314,16 +321,15 @@ def paged_decode_step(params: Dict[str, Any], pool: Pool, view: jax.Array,
     """One token per slot in the absorbed form. ``tokens`` (B,) are
     written at ``lengths[b]``; ``view`` is ``live_page_view``'s ``(3, N)``
     list of the pages the stepping slots hold, in groups of ``VIEW_GROUP``
-    rows of one slot. Each layer gathers those ``N`` pages of latent rows
-    once (``(N, T, 640)``), scores every group against its owner's 128
-    absorbed queries, takes the softmax PER SLOT across its groups from
-    the usual two statistics (reduced over a slot's groups through the
-    membership mask, in float32), multiplies a group's probabilities by
-    its ``c_kv`` rows in one matmul and adds a slot's groups up; ``W_UV``
-    and ``W_O`` follow once a slot. A slot that owns no row writes to the scratch
-    page, is left out of the expert layers' pairs and gets finite junk
-    logits. Returns ``(logits, pool, lengths + 1, stats)``, ``stats`` the
-    float32 vector ``STEP_STATS`` names."""
+    rows of one slot: the lists of ``paged_decode_attention``, built once
+    for every layer. Each layer hands the kernel the flat latent leaf and
+    a slot's 128 absorbed queries; the kernel reads the live pages where
+    they lie and returns the softmax's sum and the weighted ``c_kv`` rows
+    a slot, in float32; ``W_UV`` and ``W_O`` follow once a slot. A slot
+    that owns no row (it sees nothing: a sum of 0, selected away) writes
+    to the scratch page, is left out of the expert layers' pairs and gets
+    finite junk logits. Returns ``(logits, pool, lengths + 1, stats)``,
+    ``stats`` the float32 vector ``STEP_STATS`` names."""
     c = config
     B = tokens.shape[0]
     T = pool["latent"].shape[2]
@@ -343,38 +349,10 @@ def paged_decode_step(params: Dict[str, Any], pool: Pool, view: jax.Array,
         member & (index[None, :] == (pos // T)[:, None]),
         pages[None, :], 0), axis=1)
     off = pos % T
-    valid = ((owner >= 0)[:, None]
-             & (index[:, None] * T + jnp.arange(T)[None, :]
-                <= pos[jnp.maximum(owner, 0)][:, None]))     # (N, T)
-    valid = valid.reshape(N // G, 1, G * T)
-    # A group's owner is its first row's (``live_page_view``); a group of
-    # padding reads slot 0's queries, and ``valid`` masks it whole.
-    group_owner = owner.reshape(N // G, G)[:, 0]             # (N / G,)
-    of_group = jnp.maximum(group_owner, 0)
-    mine = group_owner[None, :] == jnp.arange(B)[:, None]    # (B, N / G)
-    mine_f32 = mine.astype(jnp.float32)
-    scale = c.softmax_scale
-    high = jax.lax.Precision.HIGHEST
-
-    def attend(q_lat, lat):
-        """``q_lat`` (B, H, 640) absorbed queries, ``lat`` (N, T, 640)."""
-        lat = lat.reshape(N // G, G * T, lat.shape[-1])
-        s = jnp.einsum("ghr,gkr->ghk", q_lat[of_group], lat,
-                       preferred_element_type=jnp.float32)
-        s = jnp.where(valid, s * scale, -1e30)
-        # The two statistics of a slot's softmax, over its groups.
-        top = jnp.max(jnp.where(mine[:, :, None], s.max(-1)[None], -1e30),
-                      axis=1)                                # (B, H)
-        e = jnp.where(valid, jnp.exp(s - top[of_group][..., None]), 0.0)
-        part = jnp.einsum("ghk,gkr->ghr", e.astype(lat.dtype),
-                          lat[..., :R],
-                          preferred_element_type=jnp.float32)
-        # A 0/1 matrix at full precision adds a slot's groups up in
-        # float32 and rounds nothing.
-        total = jnp.einsum("bg,gh->bh", mine_f32, e.sum(-1),
-                           precision=high)
-        acc = jnp.einsum("bg,ghr->bhr", mine_f32, part, precision=high)
-        return acc / jnp.where(total > 0.0, total, 1.0)[..., None]
+    # A group's owner is its first row's (``live_page_view``).
+    lists = page_lists(pages.reshape(N // G, G),
+                       owner.reshape(N // G, G)[:, 0],
+                       index.reshape(N // G, G), pos, T)
 
     def body(x, flat, layer, base, moe_layer):
         h = _normed(x, layer["attn_norm"], c)
@@ -383,15 +361,17 @@ def paged_decode_step(params: Dict[str, Any], pool: Pool, view: jax.Array,
                             interleaved=True).astype(h.dtype)
         new = _latent(layer, h, c, cos, sin)[:, 0]        # (B, 640)
         flat = flat.at[base + page, off].set(new.astype(flat.dtype))
-        with jax.named_scope("latent_gather"):
-            lat = flat[base + pages]                      # (N, T, 640)
         with jax.named_scope("latent_attn"):
             w_uk, w_uv = layer["kv_b"][..., :nope], layer["kv_b"][..., nope:]
             q_abs = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
             q_lat = jnp.concatenate([q_abs, q_pe[:, 0], jnp.zeros(
                 q_abs.shape[:2] + (c.latent_row - c.latent_dim,),
                 q_abs.dtype)], -1)
-            o_lat = attend(q_lat, lat).astype(h.dtype)
+            _, total, acc = paged_decode_attention(
+                q_lat.astype(flat.dtype), flat, None, lists,
+                c.softmax_scale, base, value_width=R)
+            total = total[..., None]
+            o_lat = jnp.where(total > 0.0, acc / total, 0.0).astype(h.dtype)
             att = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv)
         att = constrain(att, ("batch", "attn_heads", "head_dim"))
         x = x + jnp.einsum("bhd,hde->be", att, layer["wo"])[:, None]

@@ -25,6 +25,17 @@ program gathers pages into a contiguous copy first.
 * The lists of one owner that follow each other are added up IN the
   kernel: the running maximum, sum and weighted values live in the output
   block, which stays in VMEM while the owner does not change.
+* **One pool or two.** Called with a key pool and a value pool (the
+  models that cache K and V), a grid step copies a live page from each.
+  Called with NO value pool and the lanes of a key row that are its value
+  (a latent row, ``models/deepseek_decode.py``: the compressed key-value
+  is key and value at once), it copies a page ONCE into one buffer, scores
+  the whole row and weighs its first lanes. Which runs is what the caller
+  passed. One copy a page is short enough that the kernel's own
+  instructions bound it, not the copies, so that form scores a list whole
+  (a sub-block's fixed cost is paid once) and walks the dead pages only
+  in a list that has one; the two-pool program is copy-bound and is
+  letter for letter what it was.
 
 Roundings: scores float32 from the cache dtype's operands, x ``scale``,
 masked to ``-1e30``; exponentials and their sum float32; probabilities
@@ -70,8 +81,14 @@ def _blocks(pages: int, page_tokens: int) -> Tuple[int, int]:
 
 
 def _kernel(pages_ref, q_rows_ref, out_rows_ref, first_ref, q_ref, seen_ref,
-            k_hbm, v_hbm, m_ref, l_ref, acc_ref, k_buf, v_buf, sems, *,
-            scale, page_tokens, blocks, block_pages):
+            *refs, scale, page_tokens, blocks, block_pages, value_lanes):
+    # One pool (``value_lanes`` set): the values are the first lanes of
+    # the key rows, so a page is copied once and lies in one buffer.
+    one_pool = value_lanes is not None
+    pools = 1 if one_pool else 2
+    hbms, (m_ref, l_ref, acc_ref) = refs[:pools], refs[pools:pools + 3]
+    bufs, sems = refs[pools + 3:-1], refs[-1]
+    k_buf, v_buf = bufs[0], bufs[-1]
     i = pl.program_id(0)
     T, G = page_tokens, blocks * block_pages
     half = i % 2
@@ -80,14 +97,14 @@ def _kernel(pages_ref, q_rows_ref, out_rows_ref, first_ref, q_ref, seen_ref,
         return pages_ref[j * G + g]
 
     def copies(j, into, g):
-        """The two copies (keys, values) of page ``g`` of list ``j``."""
+        """The copies of page ``g`` of list ``j``, one a pool (keys,
+        values)."""
         src = jnp.maximum(page(j, g), 0) + first_ref[0]
         rows = pl.ds(pl.multiple_of(g * T, T), T)
         return [pltpu.make_async_copy(
             hbm.at[src], buf.at[into, rows],
             sems.at[side, into, g // block_pages])
-            for side, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                               (v_hbm, v_buf)))]
+            for side, (hbm, buf) in enumerate(zip(hbms, bufs))]
 
     def each_page(j, first, last, live, then):
         """``then(g)`` for the pages ``first <= g < last`` of list ``j``
@@ -138,10 +155,14 @@ def _kernel(pages_ref, q_rows_ref, out_rows_ref, first_ref, q_ref, seen_ref,
         @pl.when(live > 0)
         def _block():
             each_page(i, first, last, True, wait)
-            each_page(i, first, last, False, wipe)
+            if one_pool:    # most lists have no dead page to wipe
+                pl.when(live < block_pages)(
+                    lambda: each_page(i, first, last, False, wipe))
+            else:
+                each_page(i, first, last, False, wipe)
             rows = pl.ds(first * T, block_pages * T)
             k = k_buf[half, rows]
-            v = v_buf[half, rows]
+            v = k[:, :value_lanes] if one_pool else v_buf[half, rows]
             seen = seen_ref[0, b:b + 1, :] != 0
             s = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
@@ -207,19 +228,36 @@ def page_lists(pages, owner, index, pos, page_tokens: int,
 
 
 def _partials(q, k_pool, v_pool, lists: PageLists, out_rows, n_out: int,
-              scale: float, first_page=0):
+              scale: float, first_page=0, value_width: Optional[int] = None):
     """The kernel: list ``i`` reads the queries ``q[lists.rows[i]]`` and
     adds to the output row ``out_rows[i]`` (equal ``out_rows`` adjacent).
     Returns the ``n_out`` rows' ``(m, l)`` (n_out, H, 1) and ``acc`` (n_out,
-    H, W), float32; a row no visited list names is not written."""
+    H, W), float32; a row no visited list names is not written. With no
+    ``v_pool`` the values are the first ``value_width`` lanes of the key
+    rows and ``acc`` is that wide, or all ``W`` lanes wide where
+    ``value_width`` is not whole lane tiles (the rest is the caller's to
+    cut)."""
+    if (v_pool is None) == (value_width is None):
+        raise ValueError("pass a value pool, or which lanes of a key row "
+                         "are its value: one of the two")
     _, H, W = q.shape
     T = k_pool.shape[1]
-    n, blocks, block_tokens = lists.seen.shape
+    seen = lists.seen
+    pools, value_lanes = [k_pool, v_pool], None
+    if v_pool is None:
+        pools = [k_pool]
+        value_lanes = value_width if value_width % _LANE == 0 else W
+        # No copy hides a sub-block's fixed cost here: a list is scored
+        # whole (a tenth of a layer's call at DeepSeek-V2's shape, PR 56).
+        seen = seen.reshape(seen.shape[0], 1, -1)
+    n, blocks, block_tokens = seen.shape
     G = blocks * block_tokens // T
     itemsize = jnp.dtype(k_pool.dtype).itemsize
-    # Two halves of a list's pages, both sides; the query and output
-    # blocks twice; the sub-block's float32 scores and exponentials.
-    need = (4 * G * T * W * itemsize + 2 * H * W * (itemsize + 4)
+    Wv = value_lanes or W
+    # Two halves of a list's pages, a pool; the query and output blocks
+    # twice; the sub-block's float32 scores and exponentials.
+    need = (2 * len(pools) * G * T * W * itemsize
+            + 2 * H * W * (itemsize + 4)
             + 4 * H * block_tokens * 4 + (2 << 20))
 
     def q_map(i, pages, q_rows, out_rows, first):
@@ -232,7 +270,8 @@ def _partials(q, k_pool, v_pool, lists: PageLists, out_rows, n_out: int,
         return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
     kernel = functools.partial(_kernel, scale=scale, page_tokens=T,
-                               blocks=blocks, block_pages=G // blocks)
+                               blocks=blocks, block_pages=G // blocks,
+                               value_lanes=value_lanes)
     with jax.named_scope(NAME):
         return pl.pallas_call(
             kernel,
@@ -243,46 +282,46 @@ def _partials(q, k_pool, v_pool, lists: PageLists, out_rows, n_out: int,
                     vmem((1, H, W), q_map),
                     vmem((1, blocks, block_tokens),
                          lambda i, *_: (i, 0, 0)),
-                    pl.BlockSpec(memory_space=pl.ANY),
-                    pl.BlockSpec(memory_space=pl.ANY),
-                ],
+                ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
                 out_specs=[vmem((1, H, 1), out_map),
                            vmem((1, H, 1), out_map),
-                           vmem((1, H, W), out_map)],
+                           vmem((1, H, Wv), out_map)],
                 scratch_shapes=[
-                    pltpu.VMEM((2, G * T, W), k_pool.dtype),
-                    pltpu.VMEM((2, G * T, W), v_pool.dtype),
-                    pltpu.SemaphoreType.DMA((2, 2, blocks)),
-                ]),
+                    pltpu.VMEM((2, G * T, W), pool.dtype) for pool in pools
+                ] + [pltpu.SemaphoreType.DMA((len(pools), 2, blocks))]),
             out_shape=[jax.ShapeDtypeStruct((n_out, H, 1), jnp.float32),
                        jax.ShapeDtypeStruct((n_out, H, 1), jnp.float32),
-                       jax.ShapeDtypeStruct((n_out, H, W), jnp.float32)],
+                       jax.ShapeDtypeStruct((n_out, H, Wv), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=need if need > _VMEM_DEFAULT else None),
             interpret=_interpret(),
             name=NAME,
         )(lists.pages, lists.rows, out_rows.astype(jnp.int32),
-          jnp.asarray(first_page, jnp.int32).reshape(1), q, lists.seen,
-          k_pool, v_pool)
+          jnp.asarray(first_page, jnp.int32).reshape(1), q, seen, *pools)
 
 
 def paged_decode_attention(q, k_pool, v_pool, lists: PageLists,
-                           scale: float, first_page=0):
+                           scale: float, first_page=0,
+                           value_width: Optional[int] = None):
     """Softmax partials of each owner's queries over its lists of pages.
 
     ``q`` (B, H, W) in the pool's dtype, row ``b`` the queries of owner
     ``b``, each head over all ``W`` lanes; ``k_pool`` / ``v_pool`` (P, T,
     W), a page a row, row ``first_page`` (a layer's offset in a pool of
     several, traced) the page the lists call 0; ``lists`` from
-    ``page_lists`` with ``T`` for ``page_tokens``.
+    ``page_lists`` with ``T`` for ``page_tokens``. Where a row of
+    ``k_pool`` is key and value at once (a latent row), pass None for
+    ``v_pool`` and the ``value_width`` lanes, from 0, that are the value:
+    a page is then copied once and read for both.
 
     Returns ``(m, l, acc)``: (B, H) the largest score seen, (B, H) the sum
-    of ``exp(score - m)``, (B, H, W) those weights times the values, all
-    float32. An owner that sees nothing gets ``m`` -1e30 and ``l`` 0, and
-    its rows of ``acc`` may not have been written: select by ``l``."""
+    of ``exp(score - m)``, (B, H, W), or (B, H, ``value_width``), those
+    weights times the values, all float32. An owner that sees nothing gets
+    ``m`` -1e30 and ``l`` 0, and its rows of ``acc`` may not have been
+    written: select by ``l``."""
     m, l, acc = _partials(q, k_pool, v_pool, lists, lists.rows, q.shape[0],
-                          scale, first_page)
+                          scale, first_page, value_width)
     sees = lists.sees[:, None]
     return (jnp.where(sees, m[..., 0], _MASKED),
-            jnp.where(sees, l[..., 0], 0.0), acc)
+            jnp.where(sees, l[..., 0], 0.0), acc[..., :value_width])

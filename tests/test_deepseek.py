@@ -247,6 +247,130 @@ def test_absorbed_decode_equals_up_projected_prefill_on_one_cache(model):
     assert float(stats[0]) <= cfg.top_k * cfg.n_moe_layers
 
 
+def _two_slots_of_four(model, T=4):
+    """Slots 0 and 2 of four prefilled (37 and 9 tokens), 1 and 3 empty:
+    ``(pool, tables, counts, lengths, tokens)`` for one decode step."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import deepseek_decode as dd
+
+    cfg, params = model
+    rng = np.random.default_rng(8)
+    pool = dd.init_page_pool(cfg, 32, T)
+    lengths = np.asarray([37, 0, 9, 0], np.int32)
+    tables = np.zeros((4, 16), np.int32)
+    tables[0], tables[2] = np.arange(1, 17), np.arange(17, 33)
+    for slot in (0, 2):
+        n = int(lengths[slot])
+        prompt = np.zeros((1, 64), np.int32)
+        prompt[0, :n] = _prompt(rng, n)
+        _, pool = dd.paged_prefill(params, jnp.asarray(prompt), pool,
+                                   jnp.asarray(tables[slot:slot + 1]), cfg,
+                                   jnp.asarray([n], jnp.int32))
+    counts = np.where(lengths > 0, lengths // T + 1, 0)
+    tokens = jnp.asarray(rng.integers(0, 128, 4), jnp.int32)
+    return pool, tables, counts, jnp.asarray(lengths), tokens
+
+
+def test_a_slot_with_no_row_and_the_rungs_padding_change_no_other_slots_logits(
+        model):
+    """The kernel reads a slot's live pages and nothing else: with NaN in
+    the scratch page (what the rows that pad a group, the lists of nobody
+    and a slot that owns no row all name), the stepping slots' logits are
+    bit for bit what a clean scratch page gives, the others' are finite,
+    and each stepping slot reads as it does alone in the view."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import deepseek_decode as dd
+
+    cfg, params = model
+    pool, tables, counts, lengths, tokens = _two_slots_of_four(model)
+    # 10 and 3 pages in two groups of 16, then two lists of nobody.
+    view = dd.live_page_view(tables, counts, 64)
+    assert (view[1].reshape(-1, 16)[:, 0] == [0, 2, -1, -1]).all()
+    assert (view[0] == 0).sum() == 64 - 13
+
+    def step(pool, counts):
+        logits, *_ = dd.paged_decode_step(
+            params, dict(pool), jnp.asarray(
+                dd.live_page_view(tables, counts, 64)), lengths, tokens, cfg)
+        return np.asarray(logits)
+
+    clean = step(pool, counts)
+    poisoned = step({"latent": pool["latent"].at[:, 0].set(jnp.nan)}, counts)
+    assert np.isfinite(poisoned).all()
+    assert np.array_equal(poisoned[[0, 2]], clean[[0, 2]])
+    for slot in (0, 2):
+        alone = step(pool, np.where(np.arange(4) == slot, counts, 0))
+        np.testing.assert_allclose(clean[slot], alone[slot], atol=1e-5)
+        assert np.abs(clean[slot] - clean[2 - slot]).max() > 1e-2
+
+
+def _avals(jaxpr):
+    """Every value of ``jaxpr`` and of the jaxprs inside its equations
+    (the layer scans, the kernel's body)."""
+    import jax.extend.core as jex
+
+    def inside(x):
+        if isinstance(x, jex.ClosedJaxpr):
+            yield x.jaxpr
+        elif isinstance(x, jex.Jaxpr):
+            yield x
+        elif isinstance(x, (tuple, list)):
+            for y in x:
+                yield from inside(y)
+
+    for v in list(jaxpr.invars) + list(jaxpr.constvars):
+        yield None, v.aval
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield eqn, v.aval
+        for value in eqn.params.values():
+            for sub in inside(value):
+                yield from _avals(sub)
+
+
+def test_the_decode_step_holds_no_copy_of_the_views_pages(model):
+    """``paged_decode_step`` over a view of MORE rows than the pool has
+    pages: nothing in its jaxpr, the layer scans and the kernel's body
+    included, is as large as ``view rows x T x latent_row`` (the parent's
+    ``flat[base + pages]``; the pool itself is smaller here), and each of
+    the two layer loops calls the kernel once, under ``latent_attn``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import deepseek_decode as dd
+    from ray_tpu.ops import paged_decode_attention as pda
+
+    cfg, params = model
+    pool, tables, counts, lengths, tokens = _two_slots_of_four(model)
+    rows, T = 128, pool["latent"].shape[2]
+    copy = rows * T * cfg.latent_row
+    assert pool["latent"].size < copy
+    view = jnp.asarray(dd.live_page_view(tables, counts, rows))
+    jaxpr = jax.make_jaxpr(
+        lambda pool, view, lengths, tokens: dd.paged_decode_step(
+            params, pool, view, lengths, tokens, cfg))(
+                pool, view, lengths, tokens).jaxpr
+    seen = list(_avals(jaxpr))
+    largest = max(int(np.prod(a.shape)) for _, a in seen
+                  if hasattr(a, "shape"))
+    assert largest == pool["latent"].size < copy
+    kernels = [e for e, _ in seen if e is not None
+               and e.primitive.name == "pallas_call"]
+    assert len({id(e) for e in kernels}) == 2
+    for e in kernels:
+        assert e.params["name"] == pda.NAME
+        assert "latent_attn" in str(e.source_info.name_stack)
+        # ONE pool handed in: the flat latent leaf, keys and values both.
+        flat = (cfg.n_layers * 33, T, cfg.latent_row)
+        assert [v.aval.shape for v in e.invars
+                if v.aval.shape[1:] == flat[1:] and v.aval.dtype
+                == pool["latent"].dtype and v.aval.shape[0] > 4] == [flat]
+    assert not any("latent_gather" in str(e.source_info.name_stack)
+                   for e, _ in seen if e is not None)
+
+
 # ----------------------------------------------------------- (c) YaRN
 
 

@@ -340,11 +340,14 @@ def test_a_handoff_carries_sixty_five_window_pages():
 # The three DECODE programs of the models that prefill through
 # ``ops/chunk_attention.py`` stand as they were; their two prefill
 # programs are PR 51's, whose kernel takes several heads a program.
+# deepseek's decode is PR 56's, which reads the latent pages through
+# ``ops/paged_decode_attention.py``'s one-pool form; the two-pool form
+# that phi4flash's and cohere2's decode run stayed letter for letter.
 LOWERED_AT_PARENT = {
     "llama.decode": "5ee1c9392ee387ff",
     "llama.paged_prefill": "e3bd72ad3a1c0d98",
     "llama.paged_suffix": "3a16d93924c32163",
-    "deepseek.decode": "960c4371757e880d",
+    "deepseek.decode": "a20d3c25fd5b6e39",
     "deepseek.paged_prefill": "95ae7bc75e2000da",
     "deepseek.paged_suffix": "2f96485ef153e703",
     "mimo.decode": "a40ff6a77742dffe",

@@ -2,7 +2,9 @@
 float32 gather-and-softmax: the kernel copies each live page out of the
 pool itself, skips the pages nobody sees, adds a slot's lists up in its
 output block and stops at the last live list. Pages of 16 tokens, 4 query
-heads over 2 key heads of 16 lanes, flat."""
+heads over 2 key heads of 16 lanes, flat; and the ONE-POOL form, a row key
+and value at once (a latent row), at a toy width and at DeepSeek-V2's 128
+query rows over 640 lanes with the first 512 the value."""
 
 import numpy as np
 import pytest
@@ -10,6 +12,15 @@ import pytest
 T, H, KV, D = 16, 4, 2, 16
 W = KV * D
 SCALE = 0.25
+# ``(query rows, lanes, value lanes or None = a value pool, scale)``: the
+# toy latent row's value is not whole lane tiles, so the kernel weighs the
+# whole row and the wrapper cuts; 512 of 640 are, so it weighs those.
+FORMS = {
+    "two pools": (H, W, None, SCALE),
+    "one pool": (H, W, 24, SCALE),
+    "one pool, 128 x 640": (128, 640, 512, 640 ** -0.5),
+}
+TWO, TOY, WIDE = FORMS
 
 
 def _case(name):
@@ -76,12 +87,15 @@ def _case(name):
     raise KeyError(name)
 
 
-def _reference(q, k, v, lists, owner, index, pos, window):
-    """Each owner's queries over every token it sees, float32:
-    ``(sees (B,), out (B, H, W))`` with ``out = softmax(q k) v`` over all
-    ``W`` lanes."""
+def _reference(q, k, v, lists, owner, index, pos, window, form=TWO):
+    """Each owner's queries over every token it sees, float64:
+    ``(sees (B,), out (B, H, Wv))`` with ``out = softmax(q k) v`` over all
+    the value's lanes (``v`` None: the first of the key's)."""
+    scale = FORMS[form][3]
+    if v is None:
+        v = k[..., :FORMS[form][2]]
     B = q.shape[0]
-    out = np.zeros((B, H, W), np.float32)
+    out = np.zeros(q.shape[:2] + v.shape[-1:], np.float32)
     sees = np.zeros(B, bool)
     for b in range(B):
         keys, values = [], []
@@ -98,61 +112,77 @@ def _reference(q, k, v, lists, owner, index, pos, window):
         if not keys:
             continue
         sees[b] = True
-        s = q[b] @ np.asarray(keys).T * SCALE
+        s = q[b].astype(np.float64) @ np.asarray(keys, np.float64).T * scale
         p = np.exp(s - s.max(-1, keepdims=True))
-        out[b] = (p / p.sum(-1, keepdims=True)) @ np.asarray(values)
+        out[b] = (p / p.sum(-1, keepdims=True)) @ np.asarray(values,
+                                                             np.float64)
     return sees, out
 
 
-def _inputs(case, dtype, pool_pages=128, seed=0):
+def _inputs(case, dtype, pool_pages=128, seed=0, form=TWO):
+    """``v`` is None in a one-pool form: the keys' first lanes."""
     import jax.numpy as jnp
 
     lists, owner, index, pos, window = _case(case)
     rng = np.random.default_rng(seed)
     lists = np.where(lists > 0, lists % (pool_pages - 1) + 1, lists)
     B = len(pos)
+    heads, lanes, value_lanes, _ = FORMS[form]
     q, k, v = (np.array(jnp.asarray(
         rng.normal(size=shape), jnp.float32).astype(dtype).astype(
-            jnp.float32)) for shape in ((B, H, W), (pool_pages, T, W),
-                                        (pool_pages, T, W)))
-    return q, k, v, lists, owner, index, pos, window
+            jnp.float32)) for shape in ((B, heads, lanes),
+                                        (pool_pages, T, lanes),
+                                        (pool_pages, T, lanes)))
+    return (q, k, v if value_lanes is None else None, lists, owner, index,
+            pos, window)
 
 
-def _run(q, k, v, lists, owner, index, pos, window, dtype):
+def _run(q, k, v, lists, owner, index, pos, window, dtype, form=TWO):
     import jax
     import jax.numpy as jnp
 
-    m, l, acc = jax.jit(_attend(window))(
-        jnp.asarray(q, dtype), jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+    m, l, acc = jax.jit(_attend(window, form))(
+        jnp.asarray(q, dtype), jnp.asarray(k, dtype),
+        None if v is None else jnp.asarray(v, dtype),
         jnp.asarray(lists), jnp.asarray(owner), jnp.asarray(index),
         jnp.asarray(pos))
     return np.asarray(m), np.asarray(l), np.asarray(acc)
 
 
-def _attend(window):
+def _attend(window, form=TWO):
     """The op as a model calls it: the lists from the view, then the
-    kernel."""
+    kernel (with no value pool, told which lanes of a row are its
+    value)."""
     from ray_tpu.ops.paged_decode_attention import (page_lists,
                                                     paged_decode_attention)
 
+    _, _, value_lanes, scale = FORMS[form]
+
     def run(q, k, v, lists, owner, index, pos):
         return paged_decode_attention(
-            q, k, v, page_lists(lists, owner, index, pos, T, window), SCALE)
+            q, k, v, page_lists(lists, owner, index, pos, T, window), scale,
+            value_width=value_lanes)
     return run
 
 
+CASES = ["groups", "groups, G 9", "interleaved", "window", "nobody"]
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("case", ["groups", "groups, G 9", "interleaved",
-                                  "window", "nobody"])
-def test_kernel_matches_plain_attention(case, dtype):
+@pytest.mark.parametrize("case,form", [(c, TWO) for c in CASES]
+                         + [(c, TOY) for c in CASES]
+                         + [(c, WIDE) for c in ("groups", "interleaved")])
+def test_kernel_matches_plain_attention(case, form, dtype):
     """Lists with padding pages, a list of nobody, a last page one token
     full, ``G`` 9 and 16, a window, two slots whose groups lie between
     lists of nobody; pools in float32 and bfloat16 (the reference reads
-    the rounded pool in float32: what is left is the probabilities'
-    rounding for the value product)."""
-    *args, window = _inputs(case, dtype)
-    m, l, acc = _run(*args, window, dtype)
-    sees, want = _reference(*args, window)
+    the rounded pool in float64: what is left is the probabilities'
+    rounding for the value product); a key pool and a value pool, and one
+    pool whose rows are both."""
+    *args, window = _inputs(case, dtype, form=form)
+    m, l, acc = _run(*args, window, dtype, form)
+    sees, want = _reference(*args, window, form)
+    assert acc.shape == want.shape
     assert np.array_equal(l.max(-1) > 0, sees)
     assert np.all(l[~sees] == 0) and np.all(m[~sees] == -1e30)
     got = acc[sees] / l[sees][..., None]
@@ -179,12 +209,15 @@ def test_sub_blocks_of_any_size_give_the_same_answer(monkeypatch,
                       - want[sees]).max() < 2e-5
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("case", ["groups", "window"])
-def test_a_page_nobody_sees_is_never_read(case):
+def test_a_page_nobody_sees_is_never_read(case, form):
     """Every pool page that no query may see (the scratch page the padding
     rows name, the pages of other sequences) holds NaN: a copy of one, or a
-    stale value under a zero weight, would show."""
-    q, k, v, lists, owner, index, pos, window = _inputs(case, "float32")
+    stale value under a zero weight, would show. In a one-pool form the
+    wiped buffer is the keys' own."""
+    q, k, v, lists, owner, index, pos, window = _inputs(case, "float32",
+                                                        form=form)
     live = np.zeros(len(k), bool)
     for i, o in enumerate(owner):
         for g in range(lists.shape[1]):
@@ -193,10 +226,12 @@ def test_a_page_nobody_sees_is_never_read(case):
                     window is None or first + T - 1 > pos[o] - window):
                 live[lists[i, g]] = True
     assert not live[0] and 0 in lists     # padding names the scratch page
-    sees, want = _reference(q, k, v, lists, owner, index, pos, window)
+    sees, want = _reference(q, k, v, lists, owner, index, pos, window, form)
     k[~live] = np.nan
-    v[~live] = np.nan
-    _, l, acc = _run(q, k, v, lists, owner, index, pos, window, "float32")
+    if v is not None:
+        v[~live] = np.nan
+    _, l, acc = _run(q, k, v, lists, owner, index, pos, window, "float32",
+                     form)
     got = acc[sees] / l[sees][..., None]
     assert np.isfinite(got).all() and np.abs(got - want[sees]).max() < 2e-5
 
@@ -230,16 +265,18 @@ def test_partials_a_list_add_up_to_the_slots():
     assert np.allclose(acc[2], acc_all[2], rtol=1e-6)
 
 
-def test_the_grid_ends_at_the_last_live_list():
+@pytest.mark.parametrize("form", list(FORMS))
+def test_the_grid_ends_at_the_last_live_list(form):
     """What is visited is a traced count: the same compiled program serves
     a view whose live lists end early, and writes nothing for the lists
     behind them (``l`` 0 for a slot whose only list lies there)."""
     import jax
     import jax.numpy as jnp
 
-    q, k, v, lists, owner, index, pos, _ = _inputs("interleaved", "float32")
-    run = jax.jit(_attend(None))
-    fixed = [jnp.asarray(a) for a in (q, k, v, lists)]
+    q, k, v, lists, owner, index, pos, _ = _inputs("interleaved", "float32",
+                                                   form=form)
+    run = jax.jit(_attend(None, form))
+    fixed = [None if a is None else jnp.asarray(a) for a in (q, k, v, lists)]
     _, l, _ = run(*fixed, jnp.asarray(owner), jnp.asarray(index),
                   jnp.asarray(pos))
     assert (np.asarray(l)[[0, 2]] > 0).all()
@@ -251,5 +288,26 @@ def test_the_grid_ends_at_the_last_live_list():
     assert run._cache_size() == 1
     l = np.asarray(l)
     assert (l[0] > 0).all() and (l[1:] == 0).all()
-    sees, want = _reference(q, k, v, lists, owner, index, early, None)
+    sees, want = _reference(q, k, v, lists, owner, index, early, None, form)
     assert np.abs(np.asarray(acc)[0] / l[0][:, None] - want[0]).max() < 2e-5
+
+
+@pytest.mark.parametrize("form,v,value_width", [(TWO, "keys", 24),
+                                                (TOY, None, None)])
+def test_the_values_are_a_pool_or_lanes_of_the_keys_not_both_or_neither(
+        form, v, value_width):
+    """Which form runs is what the caller passed; a call that says both,
+    or neither, is refused before anything is traced."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_decode_attention import (page_lists,
+                                                    paged_decode_attention)
+
+    q, k, _, lists, owner, index, pos, _ = _inputs("groups", "float32",
+                                                   form=form)
+    plan = page_lists(*(jnp.asarray(a) for a in (lists, owner, index, pos)),
+                      T)
+    with pytest.raises(ValueError, match="one of the two"):
+        paged_decode_attention(jnp.asarray(q), jnp.asarray(k),
+                               None if v is None else jnp.asarray(k), plan,
+                               SCALE, value_width=value_width)

@@ -2,7 +2,9 @@
 compiler is installed here): what interpret mode cannot show of the kernels
 on DeepSeek-V2's and MiMo-V2.5's serving paths at their published widths,
 about two seconds each, the decode's paged-attention kernel at
-Phi-4-mini-flash's widths and that model's decode and prefill programs
+Phi-4-mini-flash's widths and, in its one-pool form, at DeepSeek-V2's,
+DeepSeek-V2's decode step whole (eight seconds: it holds no copy of its
+view's pages), Phi-4-mini-flash's decode and prefill programs
 whole at its published widths (ten seconds each, with what they take of
 the chip's memory), and what the chip's partitioner makes of the
 four-chip FSDP train step (a quarter of a minute), and the three flash-attention training kernels at that step's
@@ -391,6 +393,99 @@ def test_paged_decode_attention_kernel_compiles_at_phi4flashs_widths(
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1 and pda.NAME in text
     assert compiled.memory_analysis().temp_size_in_bytes < 30e6
+
+
+@pytest.mark.parametrize("lists,dtype", [
+    (256, "bfloat16"),     # the cell's rung: 4,096 rows of the view
+    (512, "float32"),      # the top rung, a float32 pool
+])
+def test_paged_decode_attention_one_pool_compiles_at_deepseeks_widths(
+        one_chip, no_compile_cache, monkeypatch, lists, dtype):
+    """The ONE-POOL form at DeepSeek-V2's widths (128 absorbed query rows
+    over the 640 lanes of a latent row, values its first 512, pages of 64
+    tokens, a layer's offset in the flat pool of five): one Mosaic kernel
+    that is handed the latent leaf alone, holds one double buffer and
+    scores a list of 16 pages whole."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_decode_attention as pda
+
+    monkeypatch.setattr(pda, "_interpret", lambda: False)
+
+    def shape(*dims, dtype=jnp.dtype(dtype)):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+
+    def attend(q, pool, lists, owner, index, pos, base):
+        return pda.paged_decode_attention(
+            q, pool, None, pda.page_lists(lists, owner, index, pos, 64),
+            0.1, base, value_width=512)
+
+    compiled = jax.jit(attend).lower(
+        shape(32, 128, 640), shape(5 * 4097, 64, 640),
+        shape(lists, 16, dtype=i32), shape(lists, dtype=i32),
+        shape(lists, 16, dtype=i32), shape(32, dtype=i32),
+        shape(dtype=i32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and pda.NAME in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 30e6
+    m, l, acc = compiled.out_info
+    assert acc.shape == (32, 128, 512) and l.shape == (32, 128)
+
+
+@pytest.mark.parametrize("rows", [4096, 8192])
+def test_deepseek_decode_compiles_at_published_widths_with_no_copy_of_the_view(
+        one_chip, no_compile_cache, monkeypatch, rows):
+    """``models/deepseek_decode.py::paged_decode_step`` at DeepSeek-V2's
+    published widths and the cell's cut and layout (5 layers, 40 held
+    experts, 32 slots, 4,096 latent pages of 64; shapes only) at the
+    cell's rung and at the top one: every layer's attention is the kernel
+    ``paged_decode_attn`` over the latent pool where it lies, so the
+    program's temporaries are a few MB (the parent's copy of the view's
+    rows and its float32 scores were 0.51 GB at 4,096 rows and 1.11 GB at
+    8,192, by this same analysis)."""
+    import dataclasses
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import deepseek, moe_decode
+    from ray_tpu.models import deepseek_decode as dd
+    from ray_tpu.ops import paged_decode_attention
+
+    monkeypatch.setattr(paged_decode_attention, "_interpret", lambda: False)
+    cfg = dataclasses.replace(deepseek.DeepseekConfig(), n_layers=5,
+                              experts_held=(0, 40), vocab_size=25600)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, spec: shape(
+            spec[0], jnp.float32 if spec[1] is None else jnp.bfloat16),
+        deepseek._shapes(cfg), is_leaf=moe_decode.is_spec)
+    pool = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: dd.init_page_pool(cfg, 4096, 64)))
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda p, pool, view, lens, toks: dd.paged_decode_step(
+            p, pool, view, lens, toks, cfg), donate_argnums=(1,)
+    ).lower(params, pool, shape((3, rows), i32), shape((32,), i32),
+            shape((32,), i32)).compile()
+    mem = compiled.memory_analysis()
+    # 10.33 GB of weights and the 1.68 GB pool, written where it lies.
+    assert 12.0e9 < mem.argument_size_in_bytes < 12.02e9
+    assert mem.alias_size_in_bytes > 1.67e9
+    assert mem.temp_size_in_bytes < 30e6
+    text = compiled.as_text()
+    assert "paged_decode_attn" in text
+    # No array of the view's rows of pages, as pages or as groups.
+    assert not re.search(r"bf16\[%d,64,640\]|bf16\[%d,1024,640\]"
+                         % (rows, rows // 16), text)
 
 
 @pytest.mark.parametrize("program", ["decode:4096", "decode:8192",
