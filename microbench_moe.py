@@ -1,5 +1,6 @@
 """Call-alone timings of ``ops/moe.py::held_experts_ffn`` on the chip at the
-three served models' CHUNK shapes: what decided the compact path's layout
+served models' CHUNK shapes (and, for Nemotron-H, whose decode step makes
+2,112 pairs, at that step's too): what decided the compact path's layout
 and row tile.
 
     chiprun -- python3 microbench_moe.py                   # the table
@@ -39,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models import moe_decode
 from ray_tpu.ops import grouped_matmul, moe
 
 # The cells' chunk shapes: tokens, the router, the held experts, the widths
@@ -56,6 +58,17 @@ SHAPES = {
         tokens=2048, dim=4096, mlp=2048, held=16, layers=6,
         router=moe.Router(experts=256, top_k=8, renormalise=True,
                           score="sigmoid")),
+    # Experts that are NOT SwiGLUs (two leaves, a squared ReLU) in a latent
+    # width of 1,024: a chunk's 45,056 pairs and a decode step's 2,112 (96
+    # slots), of which a quarter are held.
+    "nemotron_h": dict(
+        tokens=2048, dim=1024, mlp=2688, held=128, layers=5, gated=False,
+        router=moe.Router(experts=512, top_k=22, renormalise=True,
+                          scale=5.0, score="sigmoid")),
+    "nemotron_h.decode": dict(
+        tokens=96, dim=1024, mlp=2688, held=128, layers=5, gated=False,
+        router=moe.Router(experts=512, top_k=22, renormalise=True,
+                          scale=5.0, score="sigmoid")),
 }
 ROW_TILES = (64, 128, 256, 512)
 HBM_BYTES_PER_S = 819e9
@@ -80,12 +93,15 @@ def _inputs(shape, seed, skew, dtype=jnp.bfloat16):
                           jnp.float32).astype(dtype)
 
     def leaf(key, a, b):
-        return (jax.random.normal(key, (s["layers"], s["held"], a, b),
-                                  jnp.float32) / a ** 0.5).astype(dtype)
+        # A layer at a time in ``dtype``: Nemotron-H's stack is 3.5 GB a
+        # leaf, and a float32 draw of it whole would not fit.
+        return moe_decode.init_leaves(
+            {"w": ((s["layers"], s["held"], a, b), a)}, key, dtype)["w"]
 
-    experts = {"w_gate": leaf(keys[1], s["dim"], s["mlp"]),
-               "w_up": leaf(keys[2], s["dim"], s["mlp"]),
+    experts = {"w_up": leaf(keys[2], s["dim"], s["mlp"]),
                "w_down": leaf(keys[3], s["mlp"], s["dim"])}
+    if s.get("gated", True):
+        experts["w_gate"] = leaf(keys[1], s["dim"], s["mlp"])
     logits = jax.random.normal(keys[4], (s["tokens"], s["router"].experts),
                                jnp.float32)
     if skew:
@@ -97,11 +113,14 @@ def _inputs(shape, seed, skew, dtype=jnp.bfloat16):
 def _plan(shape, tile=None):
     """``(cap, tile)`` as ``held_rows`` gives them, or at another tile: a
     tile a held expert for every ``tile`` rows of its balanced share and a
-    quarter more, and a quarter more tiles."""
+    quarter more, and a quarter more tiles. Where ``held_rows`` keeps every
+    pair (a decode step's call) the smallest tile stands in, so that the
+    other way can be timed beside it."""
     s = shape
     pairs, held = s["tokens"] * s["router"].top_k, s["held"]
     if tile is None:
-        return moe.held_rows(pairs, held, s["router"].experts)
+        return moe.held_rows(pairs, held, s["router"].experts) \
+            or _plan(shape, ROW_TILES[0])
     want = pairs * moe._SLACK / s["router"].experts
     return (math.ceil(held * moe._SLACK) * math.ceil(want / tile) * tile,
             tile)
@@ -111,15 +130,20 @@ def _padded_groups(stack, tile_group, live_tiles, tile):
     """The tiles' owners as ``ragged_dot``'s group sizes: every live tile
     whole, padding and all."""
     tiles = tile_group.shape[0]
-    return jnp.zeros((stack["w_gate"].shape[0],), jnp.int32).at[
+    return jnp.zeros((stack["w_down"].shape[0],), jnp.int32).at[
         tile_group].add(jnp.where(jnp.arange(tiles) < live_tiles, tile, 0))
 
 
 def _ragged_three(xs, stack, groups):
-    gate = jax.lax.ragged_dot(xs, stack["w_gate"], groups)
+    """An expert's matmuls, ragged: three of a SwiGLU, two of a squared
+    ReLU."""
     up = jax.lax.ragged_dot(xs, stack["w_up"], groups)
-    return jax.lax.ragged_dot(jax.nn.silu(gate) * up, stack["w_down"],
-                              groups)
+    if "w_gate" in stack:
+        hidden = jax.nn.silu(
+            jax.lax.ragged_dot(xs, stack["w_gate"], groups)) * up
+    else:
+        hidden = jnp.square(jax.nn.relu(up))
+    return jax.lax.ragged_dot(hidden, stack["w_down"], groups)
 
 
 def _ragged(xs, stack, tile_group, live_tiles, tile):
@@ -140,10 +164,12 @@ def variants(shape):
                                    *plan, **kw)
         return fn
 
+    tiled = moe._tiled if shape.get("gated", True) else moe._tiled_relu2
     out = {"all_pairs": all_pairs,
            "held_ragged": held_pairs(_plan(shape), matmuls=_ragged)}
     for tile in ROW_TILES:
-        out[f"held_tile_{tile}"] = held_pairs(_plan(shape, tile))
+        out[f"held_tile_{tile}"] = held_pairs(_plan(shape, tile),
+                                              matmuls=tiled)
     return out
 
 
@@ -200,8 +226,10 @@ def pieces(shape, x, idx, weights, experts, layer):
                                    (x[order // k], experts, sizes, layer)),
         "three_ragged_held": (functools.partial(held_three, _ragged),
                               (xs, experts, tile_group, live_tiles)),
-        "three_tiled_held": (functools.partial(held_three, moe._tiled),
-                             (xs, experts, tile_group, live_tiles)),
+        "three_tiled_held": (
+            functools.partial(held_three, moe._tiled if s.get("gated", True)
+                              else moe._tiled_relu2),
+            (xs, experts, tile_group, live_tiles)),
         "add_rows_held": (
             functools.partial(grouped_matmul.add_rows, tokens=t,
                               block_m=tile),
@@ -220,10 +248,14 @@ def _reference(x, idx, weights, experts, layer, count):
     y = jnp.zeros(x.shape, jnp.float32)
     for e in range(count):
         w = jnp.where(idx == e, weights, 0.0).sum(-1)           # (T,)
-        gate, up, down = (experts[n][layer, e].astype(jnp.float32)
-                          for n in ("w_gate", "w_up", "w_down"))
+        up, down = (experts[n][layer, e].astype(jnp.float32)
+                    for n in ("w_up", "w_down"))
         with jax.default_matmul_precision("highest"):
-            h = jax.nn.silu(x @ gate) * (x @ up)
+            if "w_gate" in experts:
+                h = jax.nn.silu(x @ experts["w_gate"][layer, e].astype(
+                    jnp.float32)) * (x @ up)
+            else:
+                h = jnp.square(jax.nn.relu(x @ up))
             y = y + w[:, None] * (h @ down)
     return y
 
@@ -283,7 +315,8 @@ def main():
     for name in names:
         shape = SHAPES[name]
         args = _inputs(shape, a.seed, a.skew)
-        weights_ms = (3 * shape["held"] * shape["dim"] * shape["mlp"] * 2
+        weights_ms = ((3 if shape.get("gated", True) else 2)
+                      * shape["held"] * shape["dim"] * shape["mlp"] * 2
                       / HBM_BYTES_PER_S * 1e3)
         todo = {v: (fn, args) for v, fn in variants(shape).items()}
         if a.pieces:
@@ -297,7 +330,9 @@ def main():
                 continue
             emit({"shape": name, "variant": variant, "ms": round(ms, 4),
                   "pairs": shape["tokens"] * shape["router"].top_k,
-                  "chosen": list(_plan(shape)),
+                  "chosen": list(moe.held_rows(
+                      shape["tokens"] * shape["router"].top_k,
+                      shape["held"], shape["router"].experts) or ()),
                   "weights_ms": round(weights_ms, 4)})
         args = todo = fn = fn_args = None   # the next stack needs the room
 

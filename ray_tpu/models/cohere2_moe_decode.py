@@ -184,25 +184,14 @@ def _head(params, x, c: Cohere2MoeConfig):
 
 
 def _flat_queries(q, c: Cohere2MoeConfig):
-    """``q`` (B, H, D) -> (B, H, KV x D): each head's query laid out over
-    ALL key heads' lanes, zero but on its own, so that a score is one
-    matmul against the keys as they are cached, flat
-    (``phi4flash_decode._flat_queries``)."""
-    B, kv = q.shape[0], c.n_kv_heads
-    own = jnp.eye(kv, dtype=q.dtype)
-    flat = jnp.einsum("bkhd,kj->bkhjd",
-                      q.reshape(B, kv, c.n_heads // kv, c.head_dim), own)
-    return flat.reshape(B, c.n_heads, c.kv_width)
+    """``q`` (B, H, D) -> (B, H, KV x D), each head's query over all key
+    heads' lanes (``moe_decode.flat_queries``)."""
+    return moe_decode.flat_queries(q, c.n_kv_heads, c.n_heads, c.head_dim)
 
 
 def _own_values(part, c: Cohere2MoeConfig):
-    """(B, H, KV x D) weighted values over all lanes -> (B, H, D): each
-    head's own key head's block."""
-    B, kv = part.shape[0], c.n_kv_heads
-    part = part.reshape(B, kv, c.n_heads // kv, kv, c.head_dim)
-    own = jnp.einsum("bkhjd,kj->bkhd", part, jnp.eye(kv, dtype=part.dtype),
-                     precision=jax.lax.Precision.HIGHEST)
-    return own.reshape(B, c.n_heads, c.head_dim)
+    """(B, H, KV x D) weighted values over all lanes -> (B, H, D)."""
+    return moe_decode.own_values(part, c.n_kv_heads, c.n_heads, c.head_dim)
 
 
 # ------------------------------------------------------------------ prefill

@@ -1,9 +1,10 @@
 """What the served models behind the engine's seam share (``deepseek_decode``,
-``mimo_decode``, ``phi4flash_decode``, ``cohere2_moe_decode``; ``mimo`` and
-``cohere2_moe`` for their weights), so that none uses another as a
+``mimo_decode``, ``phi4flash_decode``, ``cohere2_moe_decode``,
+``nemotron_h_decode``; ``mimo``, ``cohere2_moe`` and ``nemotron_h`` for
+their weights), so that none uses another as a
 library: a replica's seeded weights made leaf by leaf and their cast, the
 decode step's view of a slot's pages in whole groups and of a window
-kind's pages, the layer loop over segments of one kind with the kind's
+kind's pages, a decode step's queries laid flat over the cached lanes, the layer loop over segments of one kind with the kind's
 pool in its carry, and the counters an expert layer adds to a step.
 Nothing here knows a model's config."""
 
@@ -184,6 +185,32 @@ def kinds_page_view(block_tables: Dict[str, Any], counts: Dict[str, Any],
                                      rows[window])}
 
 
+def flat_queries(q, kv_heads: int, heads: int, head_dim: int):
+    """``q`` (B, H, D) -> (B, H, KV x D): each head's query laid out over
+    ALL key heads' lanes, zero but on its own, so that a decode step's
+    score is one matmul against the keys as they are cached, flat
+    (``ops/paged_decode_attention.py``: KV times the operations, on rows
+    that are nothing; a per-head view of the pages would be a transposed
+    copy)."""
+    B = q.shape[0]
+    own = jnp.eye(kv_heads, dtype=q.dtype)
+    flat = jnp.einsum("bkhd,kj->bkhjd",
+                      q.reshape(B, kv_heads, heads // kv_heads, head_dim),
+                      own)
+    return flat.reshape(B, heads, kv_heads * head_dim)
+
+
+def own_values(part, kv_heads: int, heads: int, head_dim: int):
+    """(B, H, KV x D) weighted values over all lanes -> (B, H, D): each
+    head's own key head's block."""
+    B = part.shape[0]
+    part = part.reshape(B, kv_heads, heads // kv_heads, kv_heads, head_dim)
+    own = jnp.einsum("bkhjd,kj->bkhd", part,
+                     jnp.eye(kv_heads, dtype=part.dtype),
+                     precision=jax.lax.Precision.HIGHEST)
+    return own.reshape(B, heads, head_dim)
+
+
 def scan_segments(body: Callable, x, segments: Sequence[Any],
                   leaves: Sequence[Dict[str, Any]],
                   pool: Dict[str, jax.Array]):
@@ -194,7 +221,10 @@ def scan_segments(body: Callable, x, segments: Sequence[Any],
     ``base + p`` of the kind's leaves ``<kind>_k`` / ``<kind>_v``. A
     segment is ``(kind, layers, first)`` by name, ``first`` its first
     layer's index among the layers of its kind; one without experts says
-    ``moe`` False. Returns ``(x, pool, stats)``, the expert layers'
+    ``moe`` False. A segment may name the two leaves it carries itself
+    (``carries``: a recurrent kind's state leaves, ``[layers, slots + 1,
+    ...]``, row ``base + slot``), or none, ``()``, and is then handed
+    ``None`` for both. Returns ``(x, pool, stats)``, the expert layers'
     ``MOE_STEP_STATS`` summed."""
     shapes = {name: leaf.shape for name, leaf in pool.items()}
     flat = {name: leaf.reshape((leaf.shape[0] * leaf.shape[1],)
@@ -202,9 +232,10 @@ def scan_segments(body: Callable, x, segments: Sequence[Any],
             for name, leaf in pool.items()}
     stats = jnp.zeros((len(MOE_STEP_STATS),), jnp.float32)
     for seg, stacked in zip(segments, leaves):
-        k_name, v_name = f"{seg.kind}_k", f"{seg.kind}_v"
+        names = getattr(seg, "carries",
+                        (f"{seg.kind}_k", f"{seg.kind}_v"))
         bases = (seg.first + jnp.arange(seg.layers, dtype=jnp.int32)) \
-            * shapes[k_name][1]
+            * (shapes[names[0]][1] if names else 0)
         # The experts do not ride the scan's ``xs``: a layer of them
         # sliced out for the grouped matmul would be a copy
         # (``ops.moe.held_experts_ffn``); the stack goes in whole.
@@ -220,8 +251,10 @@ def scan_segments(body: Callable, x, segments: Sequence[Any],
                                            base)
             return (x, k_pool, v_pool, stats + more), None
 
-        (x, flat[k_name], flat[v_name], stats), _ = jax.lax.scan(
-            step, (x, flat[k_name], flat[v_name], stats),
+        carried = tuple(flat[n] for n in names) or (None, None)
+        (x, *carried, stats), _ = jax.lax.scan(
+            step, (x, *carried, stats),
             (rest, bases, jnp.arange(seg.layers, dtype=jnp.int32)))
+        flat.update(zip(names, carried))
     return x, {name: flat[name].reshape(shapes[name])
                for name in pool}, stats

@@ -217,6 +217,17 @@ _ROW_TILES = (128, 256, 512)
 # chunk-sized calls take it: a 2,048-token chunk has 12,288-16,384 pairs in
 # the three served models, a prompt's last piece at most this many.
 _SMALL_CALL_PAIRS = 8192
+# Where a chip holds many SMALL experts a small call does not ride on the
+# read of their weights: ``ragged_dot`` tiles 512 rows a group whatever it
+# holds, and a decode step of 2,112 pairs over 128 held experts of 5.5 M
+# parameters (Nemotron-H: 4.1 pairs an expert) took 4.74 ms that way
+# against 2.36 over tiles of 64 rows, the weights' read being 1.72
+# (``microbench_moe.py``, PR 57). From this many held experts and this many
+# pairs up every call takes the held pairs' way, and its tile may be this
+# small. The other served models hold 16-40 experts.
+_MANY_HELD = 64
+_STEP_CALL_PAIRS = 1024
+_STEP_TILE = 64
 
 
 def held_rows(pairs: int, held: int, width: int
@@ -230,13 +241,17 @@ def held_rows(pairs: int, held: int, width: int
     tile a held expert and ``_SLACK`` more. ``None`` where that is no fewer
     rows than the call has, or the call is small (``_SMALL_CALL_PAIRS``): a
     decode step's 192-256 pairs ride on the read of the held experts'
-    weights, and keep every pair. A function of shapes alone."""
-    if pairs <= _SMALL_CALL_PAIRS:
+    weights, and keep every pair; but a small call of a thousand pairs over
+    many held experts (``_MANY_HELD``) gets tiles of ``_STEP_TILE`` rows.
+    A function of shapes alone."""
+    many = held >= _MANY_HELD and pairs >= _STEP_CALL_PAIRS
+    if pairs <= _SMALL_CALL_PAIRS and not many:
         return None
     want = pairs * _SLACK / width
-    tile = next((m for m in _ROW_TILES if m >= want), _ROW_TILES[-1])
+    tiles = ((_STEP_TILE,) if many else ()) + _ROW_TILES
+    tile = next((m for m in tiles if m >= want), tiles[-1])
     cap = math.ceil(held * _SLACK) * math.ceil(want / tile) * tile
-    return (cap, tile) if cap < pairs else None
+    return (cap, tile) if many or cap < pairs else None
 
 
 def held_experts_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
@@ -246,12 +261,15 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
                      layer: Optional[jax.Array] = None,
                      router: Optional[Router] = None
                      ) -> Tuple[jax.Array, jax.Array]:
-    """The part of a routed SwiGLU layer that the held experts give.
+    """The part of a routed layer that the held experts give.
 
     ``x`` (T, D); ``idx``/``weights`` (T, k) from ``route``; ``experts``
     holds ``w_gate``/``w_up`` (H, D, M) and ``w_down`` (H, M, D) of the
     ``H = held[1]`` experts ``held[0] .. held[0] + H - 1``; ``keep`` (T,)
     bool leaves a token's pairs out (padding, a slot that does not step).
+    The expert's FORM is read off its leaves: three make a SwiGLU,
+    ``(silu(x w_gate) * (x w_up)) w_down``; two, ``w_up`` and ``w_down``
+    alone, the squared ReLU ``relu(x w_up)^2 w_down`` (Nemotron-H).
     Returns ``(y (T, D), group_sizes (H,) int32)``: the sum over a token's
     HELD experts of weight x expert(x), and how many pairs each held
     expert computed.
@@ -276,7 +294,8 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
         return _all_pairs(x, idx, weights, experts, held, keep, layer)
     with jax.named_scope(f"held_rows_{plan[0]}"):
         return _held_pairs(x, idx, weights, experts, held, keep, layer,
-                           *plan)
+                           *plan, matmuls=_tiled if _gated(experts)
+                           else _tiled_relu2)
 
 
 def _held_groups(idx, held, keep):
@@ -291,9 +310,15 @@ def _held_groups(idx, held, keep):
     return mine, jnp.where(mine, local, count).reshape(-1)
 
 
+def _gated(experts) -> bool:
+    """Whether the experts are SwiGLUs (three leaves) or squared ReLUs
+    (``w_up`` and ``w_down`` alone)."""
+    return "w_gate" in experts
+
+
 def _as_groups(experts, count):
     """A stack's ``(L, H, ...)`` leaves seen as ``L * H`` groups."""
-    n_layers = experts["w_gate"].shape[0]
+    n_layers = experts["w_down"].shape[0]
     return {name: w.reshape((n_layers * count,) + w.shape[2:])
             for name, w in experts.items()}
 
@@ -306,13 +331,13 @@ def _stacked(experts, sizes, layer, count):
         return experts, sizes
     stack = _as_groups(experts, count)
     return stack, jax.lax.dynamic_update_slice(
-        jnp.zeros((stack["w_gate"].shape[0],), jnp.int32), sizes,
+        jnp.zeros((stack["w_down"].shape[0],), jnp.int32), sizes,
         (layer * count,))
 
 
 def _all_pairs(x, idx, weights, experts, held, keep, layer):
     """Every pair of the call in one sort: the (token, expert) pairs are
-    sorted by expert, the pairs of absent experts last, and the three
+    sorted by expert, the pairs of absent experts last, and the expert's
     matmuls are ragged over the groups (``jax.lax.ragged_dot``). The rows
     past the last group (the absent experts' pairs) belong to no group, and
     what the chip's kernel leaves there is not defined: they are SELECTED
@@ -326,10 +351,14 @@ def _all_pairs(x, idx, weights, experts, held, keep, layer):
     sizes = jnp.bincount(group, length=count + 1)[:count].astype(jnp.int32)
     xs = x[token]                                              # (T * k, D)
     experts, groups = _stacked(experts, sizes, layer, count)
-    gate = jax.lax.ragged_dot(xs, experts["w_gate"], groups)
-    up = jax.lax.ragged_dot(xs, experts["w_up"], groups)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, experts["w_down"],
-                             groups)
+    if _gated(experts):
+        gate = jax.lax.ragged_dot(xs, experts["w_gate"], groups)
+        up = jax.lax.ragged_dot(xs, experts["w_up"], groups)
+        hidden = jax.nn.silu(gate) * up
+    else:
+        hidden = jnp.square(jax.nn.relu(
+            jax.lax.ragged_dot(xs, experts["w_up"], groups)))
+    out = jax.lax.ragged_dot(hidden, experts["w_down"], groups)
     # Back in the tokens' order by a gather (the inverse permutation),
     # then a token's k pairs are added up in float32 under its weights.
     back = out[jnp.argsort(order)].reshape(t, k, -1).astype(jnp.float32)
@@ -343,6 +372,17 @@ def _tiled(xs, stack, tile_group, live_tiles, tile):
         xs, stack["w_gate"], stack["w_up"], tile_group, live_tiles, tile)
     return grouped_matmul.grouped_matmul(
         hidden, stack["w_down"], tile_group, live_tiles, tile)
+
+
+def _tiled_relu2(xs, stack, tile_group, live_tiles, tile):
+    """The two matmuls of a squared-ReLU expert over the same tiles, the
+    square between them XLA's (a dead tile's rows hold anything, before and
+    after it; ``add_rows`` never adds them)."""
+    up = grouped_matmul.grouped_matmul(xs, stack["w_up"], tile_group,
+                                       live_tiles, tile)
+    return grouped_matmul.grouped_matmul(
+        jnp.square(jax.nn.relu(up)), stack["w_down"], tile_group,
+        live_tiles, tile)
 
 
 def _held_pairs(x, idx, weights, experts, held, keep, layer, cap, tile,

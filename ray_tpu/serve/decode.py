@@ -2192,7 +2192,7 @@ class DecodeEngine:
                    for k, n in self.pages_in_use().items()},
                 "kv_tokens": self._ctx_tokens() - len(self._active) + sum(
                     r.prefilled for r in self._prefilling.values())}
-               if self._windows or self._kind is None else {}),
+               if self._windows or self._state_leaves else {}),
             # A model with slot state: the bytes of it the seated slots
             # hold, decoding or between two prefill chunks.
             **({"state_bytes": self._slot_state_bytes
@@ -2912,3 +2912,18 @@ class BrumbyDecodeDeployment(LlamaDecodeDeployment):
         from ray_tpu.models import brumby, brumby_decode
 
         return brumby, brumby_decode
+
+
+class NemotronHDecodeDeployment(LlamaDecodeDeployment):
+    """The same deployment over Nemotron-H (``models/nemotron_h.py``):
+    layers of ONE part each, Mamba-2 with a matrix state a slot, attention
+    over one full kind of page, held experts in a latent width behind a
+    sigmoid router. The model has no ``shard_decode_state``, so a mesh is
+    refused by the engine, and a handoff by ``submit``; its state turns
+    the prefix index off."""
+
+    @staticmethod
+    def model_modules():
+        from ray_tpu.models import nemotron_h, nemotron_h_decode
+
+        return nemotron_h, nemotron_h_decode

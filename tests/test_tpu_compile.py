@@ -760,3 +760,88 @@ def test_brumby_programs_compile_at_published_widths_inside_the_chip(
     if program == "decode":
         assert "retention_step" in compiled.as_text()
         assert mem.temp_size_in_bytes < 0.3e9
+
+
+@pytest.mark.parametrize("program", ["decode:8192", "decode:30720",
+                                     "chunk:1x2048:512", "chunk:1x16:64",
+                                     "wave:2x2048"])
+def test_nemotron_h_programs_compile_at_published_widths_inside_the_chip(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """``models/nemotron_h_decode.py`` at Nemotron-3-Super's published
+    widths and the cell's layout (11 layers ``MEMEMEM*EME``, 96 slots,
+    24,576 pages; shapes only): the decode step at two rungs of its view,
+    whose state tiles go through ``ssd_step`` where they lie (no copy of a
+    2.03 GB leaf), a 2,048-token chunk behind 30k tokens of pages (the held
+    pairs' way: 20,480 rows of 45,056), the smallest suffix bucket, and a
+    whole-prefill wave at the wave cap. Arguments (13.0 GB: 9.30 GB of
+    weights, 1.61 of pages, 2.06 of state) and temporaries fit the chip's
+    15.75 GB."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import moe_decode, nemotron_h
+    from ray_tpu.models import nemotron_h_decode as nd
+    from ray_tpu.ops import (chunk_attention, grouped_matmul,
+                             paged_decode_attention, ssd)
+
+    for module in (chunk_attention, grouped_matmul, paged_decode_attention,
+                   ssd):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    cfg = dataclasses.replace(nemotron_h.NemotronHConfig(), n_layers=11,
+                              vocab_size=32768, experts_held=(0, 128))
+    slots, pages, T = 96, 24576, 64
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, spec: shape(
+            spec[0], jnp.float32 if str(path[-1].key)
+            in nemotron_h.FLOAT32_LEAVES else jnp.bfloat16),
+        nemotron_h._shapes(cfg), is_leaf=moe_decode.is_spec)
+    pool = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype), jax.eval_shape(
+            lambda: nd.init_page_pool(cfg, pages, T, slots=slots)))
+    assert pool["ssm"].shape == (5, 97, 128, 64, 128)
+    assert pool["conv"].shape == (5, 97, 3, 10240)
+    assert pool["full_k"].shape == (1, 24577, 64, 256)
+    i32 = jnp.int32
+    kind, *rest = program.split(":")
+    if kind == "decode":
+        rows = int(rest[0])
+        compiled = jax.jit(
+            lambda p, pool, view, lens, toks: nd.paged_decode_step(
+                p, pool, view, lens, toks, cfg), donate_argnums=(1,)
+        ).lower(params, pool, shape((3, rows), i32), shape((slots,), i32),
+                shape((slots,), i32)).compile()
+    else:
+        n, bucket = (int(x) for x in rest[0].split("x"))
+        width = int(rest[1]) if kind == "chunk" else bucket // T
+        tables = {"full": shape((n, width), i32), "slots": shape((n,), i32),
+                  "ends": shape((n,), jnp.bool_)}
+        compiled = jax.jit(
+            lambda p, toks, pool, bt, plens, lens: nd.paged_prefill_suffix(
+                p, toks, pool, bt, cfg, plens, lens), donate_argnums=(2,)
+        ).lower(params, shape((n, bucket), i32), pool, tables,
+                shape((n,), i32), shape((n,), i32)).compile()
+    mem = compiled.memory_analysis()
+    assert 12.9e9 < mem.argument_size_in_bytes < 13.1e9
+    # State and pages are written where they lie: the donated leaves are
+    # aliased.
+    assert mem.alias_size_in_bytes > 3.6e9
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes, "peak", peak)
+    assert peak < 15.0e9, (program, peak)
+    text = compiled.as_text()
+    if kind == "decode":
+        assert "ssd_step" in text and "paged_decode_attn" in text
+        # 2,112 pairs over 128 small held experts: tiles of 64 rows.
+        assert "moe_grouped_matmul" in text and "ragged-dot" not in text
+        assert mem.temp_size_in_bytes < 0.5e9
+    elif bucket == 2048 and n == 1:
+        assert "moe_grouped_matmul" in text and "moe_add_rows" in text
+        assert "chunk_attn" in text
