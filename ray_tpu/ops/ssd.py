@@ -19,7 +19,21 @@ the served widths, which a scan over time would move once a position.
       S_out = exp(a_last) S_in + sum_i exp(a_last - a_i) dt_i x_i B_i^T
 
   and the state goes from one sub-chunk to the next. The operands of every
-  matmul are in ``x``'s dtype; decays, state and accumulation float32.
+  matmul are in ``x``'s dtype (three roundings: the mask times ``C B^T``,
+  ``x`` times what is left of its decay, the state before it is read);
+  decays, running sums, state and accumulation float32. A Pallas kernel
+  over ``(row, group, sub-chunk)``, the sub-chunks in order: the tensors are
+  read through BlockSpecs as the layer leaves them (heads flat on the
+  lanes), the group's ``C B^T`` is formed once a grid step, a head's mask
+  (64 KB) lives and dies in VMEM, the group's state (16 heads, 0.5 MB) stays
+  in VMEM scratch, transposed (channels on the lanes), from the row's first
+  sub-chunk to its last, and ``y`` is written once where it belongs. Two heads of 64 share a product's 128
+  lanes: the state's read and its update are one full product a pair, a
+  head's masked product runs on the pair's inputs and keeps its own lanes.
+  A sub-chunk wholly past a row's ``lengths`` is skipped: nothing is
+  fetched, the state passes, its rows of ``y`` are zeros. (XLA's form, a
+  ``lax.scan`` that hands the mask and a stacked ``y`` through HBM, lives on
+  as ``tests/test_ssd.py::chunk_jnp``.)
 * ``ssd_step``: the decode's one token a stepping slot, a Pallas kernel
   over ``(slot, block of heads)`` that fetches a state tile once, scales
   it, adds ``dt x B^T``, reads it by ``C`` while it is in VMEM and writes it
@@ -37,6 +51,7 @@ nothing, so the state passes it bit for bit (``lengths`` masks ``dt``)."""
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -62,6 +77,119 @@ def _interpret() -> bool:
 # ------------------------------------------------------------------ a chunk
 
 
+def _running_sum(v):
+    """``cumsum`` down the sublanes of ``v`` (C, H) float32: log2(C) rolls,
+    each added where it has not wrapped."""
+    C = v.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    zero = jnp.zeros_like(v)
+    d = 1
+    while d < C:
+        v = v + jax.lax.select(row >= d, pltpu.roll(v, d, 0), zero)
+        d *= 2
+    return v
+
+
+def _chunk_kernel(n_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, s_ref,
+                  y_ref, s_out, S, *, sub: int, per_group: int,
+                  head_dim: int, pack: int):
+    b, g, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    C, K, P = sub, per_group, head_dim
+    W = pack * P                        # lanes of one product: ``pack`` heads
+    H = dt_ref.shape[1]
+    f32 = jnp.float32
+    n = n_ref[b]
+
+    # The group's state, TRANSPOSED (N, K P): a head's channels on the lanes
+    # as ``x``'s and ``y``'s are, so its read and its update are plain
+    # products and its decay a row.
+    @pl.when(s == 0)
+    def _():
+        S[...] = s_ref[...].T
+
+    # A sub-chunk wholly past the row's last real position: the state
+    # passes, its rows of ``y`` are zeros.
+    @pl.when(s * C >= n)
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(s * C < n)
+    def _():
+        dtype = x_ref.dtype
+        exact = (jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+
+        def dot(p, q, contract):
+            return jax.lax.dot_general(p, q, ((contract, ((), ()))),
+                                       precision=exact,
+                                       preferred_element_type=f32)
+
+        def to_front(v):
+            """The group's heads to lanes 0 .. K - 1 of ``v`` (.., H),
+            where a static index finds them."""
+            return pltpu.roll(v, jax.lax.rem(H - g * K, H), 1)
+
+        pos = s * C + jax.lax.broadcasted_iota(jnp.int32, (C, H), 0)
+        dt = dt_ref[...]
+        dt = to_front(jax.lax.select(pos < n, dt, jnp.zeros_like(dt)))
+        a = _running_sum(dt * to_front(a_ref[...]))             # (C, H)
+        ea = jnp.exp(a)
+        left = jnp.exp(a[C - 1:C] - a) * dt
+        aT, dtT = a.T, dt.T                                     # (H, C)
+        bm, cm = b_ref[...], c_ref[...]                         # (C, N)
+        bmT = bm.astype(f32).T.astype(dtype)                    # (N, C)
+        cb = dot(cm, bm, ((1,), (1,)))                          # (t, i)
+        causal = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+                  >= jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
+        never = jnp.full((C, C), -jnp.inf, f32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (C, W), 1)
+
+        def of_head(tiles):
+            """(C, W): ``tiles[u]`` on the lanes of head ``u`` of the
+            pack."""
+            out = tiles[0]
+            for u, tile in enumerate(tiles[1:], 1):
+                out = jax.lax.select(lane >= u * P, tile, out)
+            return out
+
+        def column(v, k):
+            return jnp.broadcast_to(v[:, k:k + 1], (C, W))
+
+        def masked(k, xp):
+            """Head ``k`` inside the sub-chunk: its mask over the group's
+            ``C B^T``, rounded, times the pack's inputs."""
+            decay = jnp.exp(jax.lax.select(
+                causal, a[:, k:k + 1] - aT[k:k + 1, :], never))
+            w = cb * (decay * dtT[k:k + 1, :])
+            return dot(w.astype(dtype), xp, ((1,), (0,)))
+
+        # The packs written out one after another (as the step kernel's
+        # heads are, ``UNROLL``): every lane index is static, and a pack's
+        # products run under the next one's masks. As a ``fori_loop`` of one
+        # pack an iteration, its decays rolled to lane 0, a 2,048-token
+        # chunk took 0.58 ms a layer; written out 0.30 (PR 58).
+        for j in range(K // pack):
+            heads = range(j * pack, (j + 1) * pack)
+            lanes = slice(j * W, (j + 1) * W)
+            xp = x_ref[:, lanes]                                # (C, W)
+            # A head keeps its own lanes of the product on the pack's.
+            y = of_head([masked(k, xp) for k in heads])
+            # What came before it: the state, decayed up to each position.
+            Sp = S[:, lanes]                                    # (N, W)
+            decayed = of_head([column(ea, k) for k in heads])
+            y = y + decayed * dot(cm, Sp.astype(dtype), ((1,), (0,)))
+            y_ref[:, lanes] = y + d_ref[:, lanes] * xp.astype(f32)
+            # The state after it: each position decayed to the end.
+            xl = xp.astype(f32) * of_head([column(left, k) for k in heads])
+            S[:, lanes] = decayed[C - 1:C] * Sp \
+                + dot(bmT, xl.astype(dtype), ((1,), (0,)))
+
+    @pl.when(s == pl.num_programs(2) - 1)
+    def _():
+        s_out[...] = S[...].T
+
+
+@functools.partial(jax.jit, static_argnames=("sub_chunk",))
 def ssd_chunk(x, dt, A, Bm, Cm, D, state, lengths=None,
               sub_chunk: int = SUB_CHUNK) -> Tuple[jax.Array, jax.Array]:
     """``T`` positions a row from the state before the first. ``x`` (B, T,
@@ -69,66 +197,62 @@ def ssd_chunk(x, dt, A, Bm, Cm, D, state, lengths=None,
     negative; ``Bm``, ``Cm`` (B, T, G, N); ``D`` (H,); ``state`` (B, H, P,
     N) float32 (zeros for a row that starts at position 0); ``lengths``
     (B,) the real positions of each row: the rest leave the state as it is
-    and their output is junk. Returns ``(y (B, T, H, P) float32, the state
-    after each row's last real position)``."""
-    with jax.named_scope(CHUNK):
-        return _chunk(x, dt, A, Bm, Cm, D, state, lengths, sub_chunk)
-
-
-def _chunk(x, dt, A, Bm, Cm, D, state, lengths, sub_chunk):
+    and their output is junk (zeros in a sub-chunk that holds none).
+    Returns ``(y (B, T, H, P) float32, the state after each row's last
+    real position)``."""
     B, T, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
-    K = H // G
+    K, C = H // G, sub_chunk
+    pack = math.gcd(K, max(1, 128 // P))
     f32 = jnp.float32
-    dt = dt.astype(f32)
-    if lengths is not None:
-        dt = jnp.where(jnp.arange(T)[None, :, None] < lengths[:, None, None],
-                       dt, 0.0)
-    C = min(sub_chunk, T)
-    short = -T % C
-    if short:
-        x, dt, Bm, Cm = (jnp.pad(a, [(0, 0), (0, short)]
-                                 + [(0, 0)] * (a.ndim - 2))
-                         for a in (x, dt, Bm, Cm))
-    causal = jnp.tril(jnp.ones((C, C), bool))
-    A = A.astype(f32)
+    with jax.named_scope(CHUNK):
+        n = jnp.full((B,), T, jnp.int32) if lengths is None \
+            else jnp.minimum(lengths.astype(jnp.int32), T)
+        # Tensors as they lie, heads and groups flat on the lanes.
+        x, dt = x.reshape(B, T, H * P), dt.astype(f32)
+        Bm, Cm = (m.astype(x.dtype).reshape(B, T, G * N) for m in (Bm, Cm))
+        short = -T % C
+        if short:
+            x, dt, Bm, Cm = (jnp.pad(m, [(0, 0), (0, short), (0, 0)])
+                             for m in (x, dt, Bm, Cm))
 
-    def cut(a):
-        """(B, T, ...) -> (T / C, B, C, ...), sub-chunks leading."""
-        return jnp.moveaxis(a.reshape((B, -1, C) + a.shape[2:]), 1, 0)
+        def at(b, g, s, n):
+            # A sub-chunk past the row's last names the last's blocks
+            # again: nothing is fetched for it.
+            last = jax.lax.div(jnp.maximum(n[b] - 1, 0), C)
+            return b, jnp.minimum(s, last), g
 
-    def body(S, inp):
-        xc, dtc, bc, cc = inp                     # (B, C, ...)
-        a = jnp.cumsum(dtc * A, axis=1)                         # (B, C, H)
-        last = a[:, -1]                                         # (B, H)
-        xg = xc.reshape(B, C, G, K, P)
-        # Inside the sub-chunk: pairs (t, i), i <= t, a group's C B^T once.
-        cb = jnp.einsum("btgn,bign->bgti", cc, bc,
-                        preferred_element_type=f32)
-        decay = jnp.exp(jnp.where(
-            causal[None, :, :, None],
-            a[:, :, None, :] - a[:, None, :, :], -jnp.inf))     # b t i h
-        w = cb[:, :, None] * (decay * dtc[:, None]).transpose(
-            0, 3, 1, 2).reshape(B, G, K, C, C)                  # b g k t i
-        y = jnp.einsum("bgkti,bigkp->btgkp", w.astype(xc.dtype), xg,
-                       preferred_element_type=f32)
-        # What came before it: the state, decayed up to each position.
-        Sg = S.reshape(B, G, K, P, N)
-        read = jnp.einsum("btgn,bgkpn->btgkp", cc, Sg.astype(cc.dtype),
-                          preferred_element_type=f32)
-        y = y + jnp.exp(a).reshape(B, C, G, K)[..., None] * read
-        # The state after it: each position decayed to the sub-chunk's end.
-        left = (jnp.exp(last[:, None] - a) * dtc).reshape(B, C, G, K)
-        Sg = jnp.exp(last).reshape(B, G, K)[..., None, None] * Sg \
-            + jnp.einsum("bigkp,bign->bgkpn",
-                         (xg.astype(f32) * left[..., None]).astype(xc.dtype),
-                         bc, preferred_element_type=f32)
-        return Sg.reshape(B, H, P, N), y.reshape(B, C, H, P)
-
-    state, y = jax.lax.scan(body, state.astype(f32),
-                            tuple(cut(a) for a in (x, dt, Bm, Cm)))
-    y = jnp.moveaxis(y, 0, 1).reshape(B, -1, H, P)[:, :T]
-    return y + D.astype(f32)[:, None] * x[:, :T].astype(f32), state
+        seq = pl.BlockSpec((None, C, K * P), at)
+        group = pl.BlockSpec((None, C, N), at)
+        heads = pl.BlockSpec((None, K * P, N), lambda b, g, s, n: (b, g, 0))
+        y, state = pl.pallas_call(
+            functools.partial(_chunk_kernel, sub=C, per_group=K, head_dim=P,
+                              pack=pack),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B, G, (T + short) // C),
+                in_specs=[seq,
+                          pl.BlockSpec((None, C, H),
+                                       lambda b, g, s, n: at(b, 0, s, n)),
+                          pl.BlockSpec((1, H), lambda b, g, s, n: (0, 0)),
+                          group, group,
+                          pl.BlockSpec((1, K * P),
+                                       lambda b, g, s, n: (0, g)), heads],
+                out_specs=[pl.BlockSpec((None, C, K * P),
+                                        lambda b, g, s, n: (b, s, g)),
+                           heads],
+                scratch_shapes=[pltpu.VMEM((N, K * P), f32)]),
+            out_shape=[jax.ShapeDtypeStruct((B, T + short, H * P), f32),
+                       jax.ShapeDtypeStruct((B, H * P, N), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+            interpret=_interpret(),
+            name=CHUNK,
+        )(n, x, dt, A.astype(f32)[None], Bm, Cm,
+          jnp.repeat(D.astype(f32), P)[None],
+          state.astype(f32).reshape(B, H * P, N))
+        return (y[:, :T].reshape(B, T, H, P), state.reshape(B, H, P, N))
 
 
 # -------------------------------------------------------------- one token

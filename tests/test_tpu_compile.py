@@ -842,6 +842,59 @@ def test_nemotron_h_programs_compile_at_published_widths_inside_the_chip(
         # 2,112 pairs over 128 small held experts: tiles of 64 rows.
         assert "moe_grouped_matmul" in text and "ragged-dot" not in text
         assert mem.temp_size_in_bytes < 0.5e9
-    elif bucket == 2048 and n == 1:
-        assert "moe_grouped_matmul" in text and "moe_add_rows" in text
-        assert "chunk_attn" in text
+    else:
+        # Each Mamba-2 layer's chunk is the one kernel: no scan over
+        # sub-chunks stacks a float32 ``y``.
+        assert text.count('custom_call_target="tpu_custom_call"') >= 5
+        assert "ssd_chunk" in text and "f32[16,1,128,128,64]" not in text
+        if bucket == 2048 and n == 1:
+            assert "moe_grouped_matmul" in text and "moe_add_rows" in text
+            assert "chunk_attn" in text
+
+
+@pytest.mark.parametrize("rows,bucket", [
+    (1, 2048),      # a prefill chunk
+    (1, 16),        # the smallest suffix bucket: one padded sub-chunk
+    (4, 1024),      # a whole-prefill wave at the wave cap
+])
+def test_ssd_chunk_kernel_compiles_at_published_widths(
+        one_chip, no_compile_cache, monkeypatch, rows, bucket):
+    """``ops/ssd.py::ssd_chunk`` at Nemotron-3-Super's widths (128 heads of
+    64 over 8 groups, a state of 128, bfloat16 operands, sub-chunks of 128)
+    through the chip's compiler: the dynamic lane rotations that bring a
+    group's and a pair's decays to lane 0, the transposed products, 0.5 MB
+    of state scratch. With the tensors flat as the layer holds them nothing
+    but the kernel touches ``y``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "_interpret", lambda: False)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    H, P, G, N = 128, 64, 8, 128
+    f32 = jnp.float32
+
+    def layer(xbc, dt, A, D, state, lengths):
+        x = xbc[..., :H * P].reshape(rows, bucket, H, P)
+        bm = xbc[..., H * P:H * P + G * N].reshape(rows, bucket, G, N)
+        cm = xbc[..., H * P + G * N:].reshape(rows, bucket, G, N)
+        y, s1 = ssd.ssd_chunk(x, dt, A, bm, cm, D, state, lengths)
+        return y.reshape(rows, bucket, H * P), s1
+
+    compiled = jax.jit(layer, donate_argnums=(4,)).lower(
+        shape(rows, bucket, H * P + 2 * G * N),
+        shape(rows, bucket, H, dtype=f32), shape(H, dtype=f32),
+        shape(H, dtype=f32), shape(rows, H, P, N, dtype=f32),
+        shape(rows, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssd_chunk" in text
+    mem = compiled.memory_analysis()
+    tokens = rows * max(bucket, ssd.SUB_CHUNK)
+    # The kernel's inputs cut from the flat projection (bfloat16) and, under
+    # one sub-chunk, the pad: no float32 copy of ``y`` (32 KB a token).
+    assert mem.temp_size_in_bytes < tokens * (H * P + 2 * G * N) * 2 * 1.5
